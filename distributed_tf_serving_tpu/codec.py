@@ -136,7 +136,7 @@ def to_ndarray(tp: fw.TensorProto) -> np.ndarray:
         )
 
     # Bind ONCE: every upb bytes-field access copies the payload (~9 us per
-    # half-MB on this rig); the frombuffer view below aliases this specific
+    # half-MB); the frombuffer view below aliases this specific
     # bytes object, keeping the decode zero-copy end to end.
     content = tp.tensor_content
     if content:

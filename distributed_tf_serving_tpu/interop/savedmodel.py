@@ -711,9 +711,7 @@ def _graph_servable(
                 shape = (None,)  # last resort: a flat 1-D probe
         dims = (2,) + tuple(d or 1 for d in shape[1:]) if shape else (2,)
         probe[spec.name] = np.zeros(dims, _codec.dtype_to_numpy(spec.dtype))
-    from ..utils.compat import enable_x64
-
-    ctx = enable_x64() if model.needs_x64 else contextlib.nullcontext()
+    ctx = jax.enable_x64() if model.needs_x64 else contextlib.nullcontext()
     with ctx:
         outputs = model.apply(params, probe)  # eager: no compile cost
     log.info(

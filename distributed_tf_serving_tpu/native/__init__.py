@@ -1,15 +1,20 @@
 """ctypes bindings for the native host-ops library, with lazy build.
 
-The shared library builds from hostops.cc on first use (g++ -O3, cached in
-native/build/). Absence of a compiler or DTS_TPU_NO_NATIVE=1 degrades
-gracefully to the numpy implementations in ops/transfer.py — callers probe
-`available()` and fall back. Bindings use ctypes because pybind11 is not in
-this image; the C ABI keeps them trivial.
+The shared library builds from hostops.cc on first use (g++ -O3) into
+native/build/, under a name that carries a hash of the source's bytes: only
+a library built from the hostops.cc on disk is ever loaded, whatever a
+copied tree did to file times. DTS_TPU_NO_NATIVE=1 is the explicit opt-out
+to the numpy implementations in ops/transfer.py. A build or load that FAILS
+raises NativeBuildError from ensure() (the CLI server calls it at start-up)
+— it is never a silent change of host path; hot-path callers probe
+`available()`, which never blocks or raises. Bindings use ctypes because
+pybind11 is not in this image; the C ABI keeps them trivial.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import pathlib
@@ -22,51 +27,68 @@ log = logging.getLogger("dts_tpu.native")
 
 _DIR = pathlib.Path(__file__).resolve().parent
 _SRC = _DIR / "hostops.cc"
-_SO = _DIR / "build" / "libhostops.so"
+_BUILD_DIR = _DIR / "build"
 
 _lib: ctypes.CDLL | None = None
+_error: "NativeBuildError | None" = None
 _tried = False
 _lock = threading.Lock()
 
 
-def _build() -> bool:
-    _SO.parent.mkdir(exist_ok=True)
+class NativeBuildError(RuntimeError):
+    """hostops.cc did not compile, or the built library did not load."""
+
+
+def _so_path() -> pathlib.Path:
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    return _BUILD_DIR / f"libhostops-{digest}.so"
+
+
+def _build(so: pathlib.Path) -> None:
+    so.parent.mkdir(exist_ok=True)
     # Build to a temp path + atomic rename: a killed/failed compile must
-    # never leave a partial .so that later passes the staleness check.
-    tmp = _SO.with_suffix(f".tmp{os.getpid()}.so")
+    # never leave a partial .so under the final name.
+    tmp = so.with_suffix(f".tmp{os.getpid()}.so")
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-o", str(tmp), str(_SRC)]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _SO)
-        return True
+        os.replace(tmp, so)
     except (OSError, subprocess.SubprocessError) as e:
-        log.warning("native hostops build failed (%s); using numpy fallback", e)
         tmp.unlink(missing_ok=True)
-        return False
+        detail = getattr(e, "stderr", b"") or b""
+        raise NativeBuildError(
+            f"native hostops build failed ({e}): "
+            f"{detail.decode(errors='replace')[-2000:]}"
+        ) from e
+    # Libraries of other source revisions are dead weight now.
+    for old in so.parent.glob("libhostops*.so"):
+        if old != so:
+            old.unlink(missing_ok=True)
 
 
 def _load() -> ctypes.CDLL | None:
-    global _lib, _tried
-    if _tried:
-        return _lib
+    global _lib, _error, _tried
     with _lock:
-        if _tried:
-            return _lib
-        lib = _load_locked()
-        # _tried flips only after the outcome is final, under the lock, so
-        # concurrent first callers cannot race the compile or CDLL a
-        # half-written file.
-        _lib = lib
-        _tried = True
-        return _lib
+        if not _tried:
+            # _tried flips only after the outcome is final, under the lock,
+            # so concurrent first callers cannot race the compile or CDLL a
+            # half-written file.
+            try:
+                _lib = _load_locked()
+            except NativeBuildError as e:
+                _error = e
+            _tried = True
+    if _error is not None:
+        raise _error
+    return _lib
 
 
 def _probe() -> ctypes.CDLL | None:
     """Non-blocking, non-building _load: never compiles (that is exclusively
     warm_async/_load territory — a g++ run on the dispatch thread would stall
     every in-flight request) and never waits on the build lock. Until a
-    fresh .so exists, hot-path callers fall back to numpy; _tried stays
-    unset so they pick the library up once the build lands."""
+    library for this source exists, hot-path callers fall back to numpy;
+    _tried stays unset so they pick the library up once the build lands."""
     global _lib, _tried
     if _tried:
         return _lib
@@ -75,7 +97,10 @@ def _probe() -> ctypes.CDLL | None:
     try:
         if _tried:
             return _lib
-        lib = _load_locked(build=False)
+        try:
+            lib = _load_locked(build=False)
+        except NativeBuildError:
+            return None  # _load (ensure / warm_async) reports it
         if lib is not None:
             # Only a successful load is final here; a missing .so may still
             # be produced by an in-flight/future warm_async build.
@@ -89,17 +114,18 @@ def _probe() -> ctypes.CDLL | None:
 def _load_locked(build: bool = True) -> ctypes.CDLL | None:
     if os.environ.get("DTS_TPU_NO_NATIVE") == "1":
         return None
-    if not _SO.exists() or _SO.stat().st_mtime < _SRC.stat().st_mtime:
-        if not build or not _build():
+    so = _so_path()
+    if not so.exists():
+        if not build:
             return None
+        _build(so)
     try:
-        lib = ctypes.CDLL(str(_SO))
+        lib = ctypes.CDLL(str(so))
     except OSError as e:
-        log.warning("native hostops load failed (%s); using numpy fallback", e)
         # A cached .so that will not load is useless; drop it so the next
         # process attempts a fresh build instead of failing forever.
-        _SO.unlink(missing_ok=True)
-        return None
+        so.unlink(missing_ok=True)
+        raise NativeBuildError(f"native hostops load failed: {e}") from e
     lib.fold_i32.argtypes = [
         ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p,
     ]
@@ -137,16 +163,24 @@ def available() -> bool:
 
 def ensure() -> bool:
     """Blocking availability: builds the library if needed (seconds of g++).
-    For tests and setup paths that need a definite answer, never for the
-    serving hot path."""
+    False only under the DTS_TPU_NO_NATIVE=1 opt-out; a failed build or load
+    raises NativeBuildError. For start-up, tests and setup paths that need a
+    definite answer, never for the serving hot path."""
     return _load() is not None
+
+
+def _load_logged() -> None:
+    try:
+        _load()
+    except NativeBuildError:
+        log.exception("native hostops unavailable; numpy host path in use")
 
 
 def warm_async() -> None:
     """Kick the (possibly compiling) load off-thread so no request pays the
     first-use g++ latency; callers keep using the numpy fallback until the
     native path is ready."""
-    threading.Thread(target=_load, name="native-build", daemon=True).start()
+    threading.Thread(target=_load_logged, name="native-build", daemon=True).start()
 
 
 def _ptr(arr: np.ndarray) -> ctypes.c_void_p:

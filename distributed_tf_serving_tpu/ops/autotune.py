@@ -1,25 +1,25 @@
-"""Per-bucket kernel autotune harness (ISSUE 12) — the machinery that
-turned the BENCH_r05 lesson ("the Pallas kernel loses to XLA; leave it
-dead") into an enforced invariant: **no execution variant serves live
-traffic unless it measured faster than the baseline on THIS device at
-THIS bucket and passed the accuracy gates.**
+"""Per-bucket kernel autotune harness (ISSUE 12) — the machinery behind
+one invariant: **no execution variant serves live traffic unless it
+measured faster than the baseline on THIS device at THIS bucket and passed
+the accuracy gates.**
 
 Variants per (servable, bucket), all minted through the batcher's OWN
 jitted entries so measurement and serving share compiled executables:
 
   - baseline:   XLA, float params (today's path — always available)
   - xla_int8:   XLA, ops/quantize.py int8 weight-only params
-  - pallas:     ops/cross_kernel.py fused gather+cross+MLP kernel, float
+  - pallas:     ops/cross_kernel.py fused cross+MLP+head kernel, float
   - pallas_int8: the fused kernel with int8 weight operands
 
 Gates (config, [kernels] section): measured speedup >= min_speedup AND
 max |Δscore| vs the f32 baseline <= max_abs_delta AND — when a labeled
 eval set is supplied (bench.py's trained-model block, the CI smoke) —
-|AUC_f32 - AUC_variant| <= auc_margin. A variant that fails to compile,
-lower, or gate is recorded with its reason and left DISABLED; in
-measure_only mode everything is recorded and nothing is enabled (the CI
-smoke's contract). The per-bucket decision picks the fastest enabled
-variant.
+|AUC_f32 - AUC_variant| <= auc_margin. A variant that errors or fails a
+gate is recorded with its reason and left DISABLED — except a Pallas
+variant the accelerator's compiler refuses, which raises
+KernelLoweringError and stops start-up; in measure_only mode everything is
+recorded and nothing is enabled (the CI smoke's contract). The per-bucket
+decision picks the fastest enabled variant.
 
 The decision table persists to artifacts/kernel_autotune.json keyed by
 (model, version, PARAMS DIGEST, device kind, gate fingerprint) so a
@@ -83,12 +83,18 @@ _VARIANT_FLAGS = {
 
 
 def _device_kind() -> str:
+    """Keys the persisted decision table: a device jax cannot name raises —
+    measurements must never be filed under (or adopted from) "unknown"."""
     import jax
 
-    try:
-        return jax.devices()[0].device_kind
-    except Exception:  # noqa: BLE001 — a label, never a dependency
-        return "unknown"
+    return jax.devices()[0].device_kind
+
+
+class KernelLoweringError(RuntimeError):
+    """A Pallas variant the operator asked for ([kernels] pallas = true) did
+    not compile on this accelerator. Unlike a lost speed gate — a row in the
+    table — this stops start-up with the compiler's message: a kernel that
+    cannot run on the only device it is for must not hide behind XLA."""
 
 
 def params_digest(params) -> str:
@@ -380,6 +386,7 @@ class KernelManager:
                         batcher, servable, eval_data, _VARIANT_FLAGS[name]
                     )
                 except Exception as exc:  # noqa: BLE001
+                    self._raise_if_lowering(name, servable, on_cpu, exc)
                     auc_errors[name] = f"{type(exc).__name__}: {exc}"[:200]
 
         table: dict = {
@@ -459,8 +466,9 @@ class KernelManager:
                     if enabled and (best is None or entry["speedup"] > best[0]):
                         best = (entry["speedup"], name)
                 except Exception as exc:  # noqa: BLE001 — a variant that
-                    # fails to compile/lower is a disabled variant, never
-                    # a serving error.
+                    # fails is a disabled variant, never a serving error —
+                    # except a Pallas kernel refused by the accelerator.
+                    self._raise_if_lowering(name, servable, on_cpu, exc)
                     entry["error"] = f"{type(exc).__name__}: {exc}"[:300]
                     entry["enabled"] = False
                 row[name] = entry
@@ -484,6 +492,17 @@ class KernelManager:
             )
         self._save_table()
         return table
+
+    @staticmethod
+    def _raise_if_lowering(name: str, servable, on_cpu: bool, exc) -> None:
+        """Off the CPU (where the kernel only ever runs interpreted), a
+        Pallas variant that raises is the compiler refusing the kernel."""
+        if _VARIANT_FLAGS[name][1] and not on_cpu:
+            raise KernelLoweringError(
+                f"{name} did not compile for {servable.name} "
+                f"v{servable.version} on {_device_kind()}: "
+                f"{type(exc).__name__}: {exc}"
+            ) from exc
 
     def _warm_enabled(self, batcher, servable, decisions: dict) -> None:
         """Compile the entry variants LIVE traffic hits for every enabled

@@ -30,9 +30,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128
 DEFAULT_ROW_TILE = 256
-# Conservative per-core VMEM working budget (v4/v5e have ~16 MB; leave room
-# for Mosaic's own scratch and double-buffered DMA).
-VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+# The scoped-VMEM limit both kernels ask Mosaic for, and the resident set
+# the fits_* guards admit under it (the rest is Mosaic's own scratch). The
+# limit is passed explicitly so the guards and the compiler agree on one
+# number instead of on a per-generation default.
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+VMEM_BUDGET_BYTES = 24 * 1024 * 1024
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def fits_vmem(
@@ -44,15 +48,16 @@ def fits_vmem(
     """Whether the fused kernel's resident set fits in VMEM.
 
     The constant-index weight BlockSpec keeps ALL L (dp x dp) matrices
-    resident at once; past the budget Mosaic fails to lower (or thrashes),
-    so callers must fall back to the per-layer XLA path."""
+    resident at once — twice, since the pipeline double-buffers every
+    blocked operand; past the budget Mosaic fails to lower, so callers must
+    fall back to the per-layer XLA path."""
     dp = _pad_to(d, LANE)
     itemsize = jnp.dtype(compute_dtype).itemsize
     weights = num_layers * dp * dp * itemsize
-    biases = num_layers * dp * 4
-    # x0 tile (cd) + x0_f32 + f32 layer temps + out tile ~ 12 bytes/elem.
-    tiles = row_tile * dp * 12
-    return weights + biases + tiles <= VMEM_BUDGET_BYTES
+    biases = _pad_to(num_layers, 8) * dp * 4
+    tiles = 2 * row_tile * dp * itemsize  # x0 in + out
+    temps = row_tile * dp * 12  # x0_f32 + f32 layer temps
+    return 2 * (weights + biases + tiles) + temps <= VMEM_BUDGET_BYTES
 
 
 def _cross_kernel(x0_ref, w_ref, b_ref, out_ref, *, num_layers: int, compute_dtype):
@@ -123,6 +128,7 @@ def fused_cross_apply(
         ],
         out_specs=pl.BlockSpec((bn, dp), lambda i: (i, 0), memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((np_, dp), cd),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(x0p, wp, bp)
     return out[:n, :d]
@@ -139,33 +145,34 @@ def cross_params_to_stacked(cross_layers: list) -> tuple[jax.Array, jax.Array]:
 
 
 # ===========================================================================
-# Fused SERVING kernel (ISSUE 12): embedding-gather + cross + MLP head
+# Fused SERVING kernel (ISSUE 12): cross + MLP + output head in one kernel
 # ===========================================================================
 #
-# The cross-only kernel above lost to XLA on-chip (BENCH r2-r5: 0.81-0.96x)
-# because it fused the one stage XLA already runs near the roofline. This
-# rework fuses the WHOLE per-candidate serving step into one kernel so the
-# intermediate activations (the [n, F, D] gathered embeddings, the [n, d]
-# cross/MLP activations) never round-trip through HBM at all:
+# The cross-only kernel above fuses the one stage XLA already runs well. This
+# kernel fuses everything AFTER the embedding lookup, so the [n, d] cross/MLP
+# activations never round-trip through HBM:
 #
-#   ids --(per-row DMA gather from the HBM-resident table)--> x0 in VMEM
-#      --> L cross layers --> MLP stack --> output head --> sigmoid
+#   ids --(XLA gather, models/embeddings.py field_embed)--> x0 [n, d] in HBM
+#   x0 tile in VMEM --> L cross layers --> MLP stack --> output head --> sigmoid
+#
+# The lookup stays XLA's. The first version gathered inside the kernel — the
+# whole [bucket, F] id matrix scalar-prefetched into SMEM, one (1, D) DMA per
+# (row, field) from the HBM table, started and waited serially — and Mosaic
+# refuses that on the v5e ("Not implemented: dynamic store with unaligned
+# indices": the (1, 16) store of each gathered row at a dynamic sublane and
+# an unaligned lane offset; PR 21, CHANGES.md). x0 therefore crosses HBM
+# once, written by XLA's gather and read here as a lane-padded tile.
 #
 # int8 weights are FIRST-CLASS operands: the quantized variant streams the
 # ops/quantize.py per-channel int8 matrices (4x fewer weight bytes than
 # f32) and folds the per-output-channel scale into the f32 accumulator —
 # the same algebra as models/base.py dense_apply, inside the kernel.
 #
-# Mosaic/interpret caveats, stated honestly: the gather issues one small
-# (1, D) DMA per (row, field) pair — correct everywhere (interpret mode
-# included; CPU tests run it), but on real hardware its win depends on the
-# DMA engine hiding the latency, which is exactly why ops/autotune.py
-# enables this kernel per bucket ONLY where it measures faster than the
-# XLA path on the live device (a kernel that fails to lower or loses is
-# recorded and left disabled — the BENCH_r05 lesson, now enforced by
-# machinery instead of a docstring).
+# ops/autotune.py enables this kernel per bucket ONLY where it measures
+# faster than the XLA path on the live device; on a TPU backend a failure to
+# lower stops start-up instead of becoming a table row.
 
-_SERVE_ROW_TILE = 128
+_SERVE_ROW_TILE = 256
 
 
 def serve_fits_vmem(
@@ -177,20 +184,25 @@ def serve_fits_vmem(
     quantized: bool = False,
 ) -> bool:
     """Whether the fused serving kernel's VMEM-resident set fits: all cross
-    + MLP + head weights (int8 when quantized) plus the per-tile activation
-    scratch. The embedding table stays in HBM and never counts."""
+    + MLP + head weights (int8 when quantized) plus the per-tile
+    activations. Blocked operands count twice — the pipeline double-buffers
+    them, constant index map or not."""
     dp = _pad_to(d, LANE)
-    itemsize = 1 if quantized else jnp.dtype(compute_dtype).itemsize
-    weights = num_layers * dp * dp * itemsize + num_layers * dp * 8
+    cd_size = jnp.dtype(compute_dtype).itemsize
+    itemsize = 1 if quantized else cd_size
+    rows = 2 * 8 * 4  # scale + bias: a (1, n) f32 row occupies 8 sublanes
+    weights = num_layers * dp * (dp * itemsize + rows)
     d_in = dp
     for m in mlp_dims:
         mp = _pad_to(m, LANE)
-        weights += d_in * mp * itemsize + mp * 8
+        weights += mp * (d_in * itemsize + rows)
         d_in = mp
     weights += (dp + d_in) * LANE * 4  # output head (f32 col block)
-    # x0 f32 + compute-dtype copy + cross/mlp f32 temps + two out tiles.
-    tiles = row_tile * dp * 16 + row_tile * LANE * 8
-    return weights + tiles <= VMEM_BUDGET_BYTES
+    tiles = row_tile * dp * cd_size + 2 * row_tile * LANE * 4  # x0 in, 2 out
+    # f32 x0 + xw/update temps + the compute-dtype carried activation, and
+    # one dequantized (dp, dp) weight when the operands are int8.
+    temps = row_tile * dp * 16 + (dp * dp * (4 + cd_size) if quantized else 0)
+    return 2 * (weights + tiles) + temps <= VMEM_BUDGET_BYTES
 
 
 def serve_params_supported(params) -> bool:
@@ -225,25 +237,32 @@ def _pad2(arr, rows: int, cols: int, dtype) -> jnp.ndarray:
     return out.at[: a.shape[0], : a.shape[1]].set(a.astype(dtype))
 
 
-def _pad1(arr, cols: int, dtype=jnp.float32) -> jnp.ndarray:
-    a = jnp.asarray(arr)
-    return jnp.zeros((cols,), dtype).at[: a.shape[0]].set(a.astype(dtype))
+def _pad_row(arr, cols: int, fill: float = 0.0) -> jnp.ndarray:
+    """A per-channel vector as a (1, cols) f32 row: Mosaic wants 2-D
+    operands, and a row broadcasts over the tile's sublanes as is."""
+    a = jnp.asarray(arr, jnp.float32)
+    return jnp.full((1, cols), fill, jnp.float32).at[0, : a.shape[0]].set(a)
 
 
 def _prep_dense(p: dict, rows: int, cols: int, cd):
-    """(w_padded, scale_padded_or_None, b_padded) for one dense layer in
-    either param form. int8 weights stay int8 (the operand win); scales
-    pad with ONES so padded output channels stay exactly zero after the
-    zero-padded weights."""
+    """(w_padded, scale_row_or_None, b_row) for one dense layer in either
+    param form. int8 weights stay int8 (the operand win); scales pad with
+    ONES so padded output channels stay exactly zero after the zero-padded
+    weights."""
     if "qw" in p:
         w = _pad2(p["qw"], rows, cols, jnp.int8)
-        s = jnp.ones((cols,), jnp.float32).at[: p["qscale"].shape[0]].set(
-            jnp.asarray(p["qscale"], jnp.float32)
-        )
+        s = _pad_row(p["qscale"], cols, fill=1.0)
     else:
         w = _pad2(p["w"], rows, cols, cd)
         s = None
-    return w, s, _pad1(p["b"], cols)
+    return w, s, _pad_row(p["b"], cols)
+
+
+def _const_spec(shape) -> pl.BlockSpec:
+    """Whole-array VMEM block with a constant index map: DMA'd once,
+    resident across every row tile."""
+    zeros = (0,) * len(shape)
+    return pl.BlockSpec(shape, lambda i: zeros, memory_space=pltpu.VMEM)
 
 
 def build_fused_serve(params, config, *, interpret: bool = False,
@@ -253,12 +272,16 @@ def build_fused_serve(params, config, *, interpret: bool = False,
 
     Returns apply_fn(params, batch) -> {"prediction_node", "logits"} with
     the model.apply contract the batcher's jitted entries expect. The
-    weight operands are prepared (stacked/padded/cast) HERE, once, and
+    dense weight operands are prepared (padded/cast) HERE, once, and
     closed over — they enter the jaxpr as constants, so per-call tracing
-    never re-pads the parameter set; the `params` argument is accepted for
-    signature compatibility and deliberately unused (ops/autotune.py
-    rebuilds this callable when a servable's params object is swapped).
-    `batch` must carry host-folded int32 feat_ids and feat_wts."""
+    never re-pads them (ops/autotune.py rebuilds this callable when a
+    servable's params object is swapped). The embedding table alone is
+    read from the call's `params`: the one big operand stays an executable
+    ARGUMENT, not a vocab-sized constant baked into every bucket's
+    executable and persistent-cache entry. `batch` must carry host-folded
+    int32 feat_ids and feat_wts."""
+    from ..models.embeddings import field_embed
+
     cfg = config
     cd = cfg.cdtype
     F, D = cfg.num_fields, cfg.embed_dim
@@ -277,19 +300,8 @@ def build_fused_serve(params, config, *, interpret: bool = False,
             f"the {VMEM_BUDGET_BYTES >> 20} MB VMEM budget"
         )
 
-    table = jnp.asarray(params["embedding"], jnp.float32)  # HBM-resident
-    # Cross stack: [L, dp, dp] (+ [L, dp] scales when quantized) + biases.
-    if quantized:
-        wc = jnp.stack([_pad2(p["qw"], dp, dp, jnp.int8) for p in params["cross"]])
-        sc = jnp.stack([
-            jnp.ones((dp,), jnp.float32).at[: p["qscale"].shape[0]].set(
-                jnp.asarray(p["qscale"], jnp.float32))
-            for p in params["cross"]
-        ])
-    else:
-        wc = jnp.stack([_pad2(p["w"], dp, dp, cd) for p in params["cross"]])
-        sc = None
-    bc = jnp.stack([_pad1(p["b"], dp) for p in params["cross"]])
+    # Cross stack, one (w[, scale], bias) per layer, each a 2-D operand.
+    cross_ops = [_prep_dense(p, dp, dp, cd) for p in params["cross"]]
     # MLP stack: per-layer padded operands (dims differ per layer).
     mlp_ops = []
     d_in = dp
@@ -315,87 +327,52 @@ def build_fused_serve(params, config, *, interpret: bool = False,
     bo = jnp.zeros((1, LANE), jnp.float32).at[0, 0].set(
         jnp.asarray(out_p["b"], jnp.float32)[0]
     )
+    dense_args = [a for op in cross_ops + mlp_ops for a in op if a is not None]
 
-    def kernel(ids_ref, *refs):
-        # Positional layout mirrors in_specs + out_specs + scratch_shapes:
-        # wts, cross (w[, s], b), per-mlp-layer (w[, s], b), head (w, b),
-        # table, then the two out tiles and the three scratch operands.
+    def dense(x, refs):
+        """x @ w (int8 dequantized to the compute dtype on the way into
+        the MXU), per-channel scale folded into the f32 result, + bias."""
+        w_ref, s_ref, b_ref = refs
+        w = w_ref[:, :]
+        if s_ref is not None:
+            w = w.astype(jnp.float32)
+        y = jax.lax.dot_general(
+            x, w.astype(cd), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        if s_ref is not None:
+            y = y * s_ref[:, :]
+        return y + b_ref[:, :]
+
+    def kernel(x0_ref, *refs):
+        # Positional layout mirrors in_specs + out_specs: per cross layer
+        # then per MLP layer (w[, scale], bias), head (w, b), two out tiles.
         it = iter(refs)
-        wts_ref = next(it)
-        wc_ref = next(it)
-        sc_ref = next(it) if quantized else None
-        bc_ref = next(it)
-        mlp_refs = []
-        for _, s, _ in mlp_ops:
-            wr = next(it)
-            sr = next(it) if s is not None else None
-            br = next(it)
-            mlp_refs.append((wr, sr, br))
+        layers = [
+            (next(it), next(it) if s is not None else None, next(it))
+            for _, s, _ in cross_ops + mlp_ops
+        ]
         wo_ref, bo_ref = next(it), next(it)
-        table_ref = next(it)
         pred_ref, logit_ref = next(it), next(it)
-        x0_s, emb_s, sem = next(it), next(it), next(it)
-        i = pl.program_id(0)
-        bn = x0_s.shape[0]
 
-        # ---- embedding gather: one (1, D) DMA per (row, field) from the
-        # HBM table, weighted into the VMEM-resident x0 tile. Fields are a
-        # static Python loop (F is small and the f*D slice start must be
-        # static); rows ride fori_loop. The scalar-prefetched ids (SMEM)
-        # are exactly what computes the DMA source index.
-        def gather_row(r, carry):
-            wrow = wts_ref[pl.ds(r, 1), :]  # (1, F_pad) f32
-            for f in range(F):
-                idx = ids_ref[i * bn + r, f]
-                copy = pltpu.make_async_copy(
-                    table_ref.at[pl.ds(idx, 1), :], emb_s, sem
-                )
-                copy.start()
-                copy.wait()
-                x0_s[pl.ds(r, 1), pl.ds(f * D, D)] = (
-                    emb_s[:, :] * wrow[0, f]
-                )
-            return carry
+        x0 = x0_ref[:, :]
+        x0_f32 = x0.astype(jnp.float32)
 
-        # Scratch arrives uninitialized: the padded lane tail [d, dp) must
-        # be EXACTLY zero (garbage there rides NaN*0=NaN through the
-        # zero-padded weights), and only [0, d) is written by the gather.
-        x0_s[:, :] = jnp.zeros_like(x0_s)
-        jax.lax.fori_loop(0, bn, gather_row, 0)
-
-        x0_f32 = x0_s[:, :]
-        x0 = x0_f32.astype(cd)
-
-        # ---- cross stack (the existing _cross_kernel math, quantized-
-        # aware: per-channel scale folds into the f32 xw).
-        def cross_layer(l, x):
-            xw = jax.lax.dot_general(
-                x, wc_ref[l].astype(cd), (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            if sc_ref is not None:
-                xw = xw * sc_ref[pl.ds(l, 1), :]
-            nxt = x0_f32 * (xw + bc_ref[pl.ds(l, 1), :]) + x.astype(jnp.float32)
-            return nxt.astype(cd)
-
-        xc = jax.lax.fori_loop(0, L, cross_layer, x0)
+        # ---- cross stack (models/dcn.py cross_apply math, quantized-
+        # aware). L is small and static: unrolled, every index static.
+        x = x0
+        for refs_l in layers[:L]:
+            x = (x0_f32 * dense(x, refs_l) + x.astype(jnp.float32)).astype(cd)
 
         # ---- MLP stack over x0 (models/base.py mlp_apply, final relu).
         h = x0
-        for wr, sr, br in mlp_refs:
-            y = jax.lax.dot_general(
-                h, wr[:, :].astype(cd), (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            if sr is not None:
-                y = y * sr[:].reshape(1, -1)
-            y = y + br[:].reshape(1, -1)
-            h = jax.nn.relu(y).astype(cd)
+        for refs_l in layers[L:]:
+            h = jax.nn.relu(dense(h, refs_l)).astype(cd)
 
         # ---- output head: logit = [xc | xd] @ w_out + b (col 0 real).
         lo = (
             jax.lax.dot_general(
-                xc.astype(jnp.float32), wo_ref[:dp, :],
+                x.astype(jnp.float32), wo_ref[:dp, :],
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
@@ -409,73 +386,33 @@ def build_fused_serve(params, config, *, interpret: bool = False,
         logit_ref[:, :] = lo
         pred_ref[:, :] = jax.nn.sigmoid(lo)
 
-    def apply_fn(_params, batch):
-        ids = batch["feat_ids"].astype(jnp.int32)
-        wts = batch["feat_wts"].astype(jnp.float32)
-        n = ids.shape[0]
-        bn = min(row_tile, _pad_to(n, 8))
+    def apply_fn(params, batch):
+        emb = field_embed(
+            params["embedding"], batch["feat_ids"], batch["feat_wts"], cd
+        )
+        n = emb.shape[0]
+        bn = min(row_tile, _pad_to(n, 16))
         np_ = _pad_to(n, bn)
-        f_pad = _pad_to(F, LANE)
-        ids_p = jnp.zeros((np_, F), jnp.int32).at[:n, :].set(ids)
-        wts_p = jnp.zeros((np_, f_pad), jnp.float32).at[:n, :F].set(wts)
+        # Zero lane tail [d, dp) and pad rows: the zero-padded weights keep
+        # them exactly zero through every layer.
+        x0 = jnp.zeros((np_, dp), cd).at[:n, :d].set(emb.reshape(n, d))
 
-        weight_args = [wc] + ([sc] if quantized else []) + [bc]
-        in_specs = [
-            pl.BlockSpec((bn, f_pad), lambda i, *_: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((L, dp, dp), lambda i, *_: (0, 0, 0),
-                         memory_space=pltpu.VMEM),
-        ]
-        if quantized:
-            in_specs.append(pl.BlockSpec((L, dp), lambda i, *_: (0, 0),
-                                         memory_space=pltpu.VMEM))
-        in_specs.append(pl.BlockSpec((L, dp), lambda i, *_: (0, 0),
-                                     memory_space=pltpu.VMEM))
-        for (w, s, b) in mlp_ops:
-            weight_args.append(w)
-            in_specs.append(pl.BlockSpec(w.shape, lambda i, *_: (0, 0),
-                                         memory_space=pltpu.VMEM))
-            if s is not None:
-                weight_args.append(s)
-                in_specs.append(pl.BlockSpec(s.shape, lambda i, *_: (0,),
-                                             memory_space=pltpu.VMEM))
-            weight_args.append(b)
-            in_specs.append(pl.BlockSpec(b.shape, lambda i, *_: (0,),
-                                         memory_space=pltpu.VMEM))
-        weight_args += [wo, bo]
-        in_specs += [
-            pl.BlockSpec(wo.shape, lambda i, *_: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec(bo.shape, lambda i, *_: (0, 0), memory_space=pltpu.VMEM),
-        ]
-        # The table: whole-array, compiler-placed (HBM) — gathered by DMA.
-        weight_args.append(table)
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
-
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(np_ // bn,),
-            in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec((bn, LANE), lambda i, *_: (i, 0),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((bn, LANE), lambda i, *_: (i, 0),
-                             memory_space=pltpu.VMEM),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((bn, dp), jnp.float32),
-                pltpu.VMEM((1, D), jnp.float32),
-                pltpu.SemaphoreType.DMA,
-            ],
+        row_spec = lambda cols: pl.BlockSpec(  # noqa: E731
+            (bn, cols), lambda i: (i, 0), memory_space=pltpu.VMEM
         )
         pred, logit = pl.pallas_call(
             kernel,
-            grid_spec=grid_spec,
+            grid=(np_ // bn,),
+            in_specs=[row_spec(dp)]
+            + [_const_spec(a.shape) for a in dense_args + [wo, bo]],
+            out_specs=[row_spec(LANE), row_spec(LANE)],
             out_shape=[
                 jax.ShapeDtypeStruct((np_, LANE), jnp.float32),
                 jax.ShapeDtypeStruct((np_, LANE), jnp.float32),
             ],
+            compiler_params=_COMPILER_PARAMS,
             interpret=interpret,
-        )(ids_p, wts_p, *weight_args)
+        )(x0, *dense_args, wo, bo)
         return {
             "prediction_node": pred[:n, 0],
             "logits": logit[:n, 0],

@@ -22,8 +22,8 @@ import re
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from ..utils.compat import shard_map
 
 from .mesh import DATA_AXIS, MODEL_AXIS
 

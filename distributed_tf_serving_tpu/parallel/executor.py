@@ -52,8 +52,8 @@ from typing import Any
 
 import jax
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from ..utils.compat import shard_map
 
 from ..models.registry import Servable
 from ..ops.transfer import (
@@ -287,6 +287,13 @@ class ShardedExecutor:
                 "data_pad_rows": self.data_pad_rows,
                 "placed_servables": len(self._placed),
                 "layout": dict(self.rules_used),
+                # Where every split parameter's shards actually sit — the
+                # check that a vocab-sharded table is spread over the
+                # model axis and not stacked on device 0.
+                "param_shards": {
+                    sv.name: _sharded_leaves(placed)
+                    for sv, (_, placed) in self._placed.items()
+                },
             }
         return {
             "enabled": True,
@@ -299,6 +306,26 @@ class ShardedExecutor:
             ),
             "executor": counters,
         }
+
+
+def _sharded_leaves(placed) -> dict:
+    """{param path: [{device, index}]} for the leaves that are not fully
+    replicated; index is each shard's [start, stop) per dimension."""
+    out = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(placed)[0]:
+        if leaf.sharding.is_fully_replicated:
+            continue
+        out[jax.tree_util.keystr(path)] = [
+            {
+                "device": str(shard.device),
+                "index": [
+                    [sl.start or 0, dim if sl.stop is None else sl.stop]
+                    for sl, dim in zip(shard.index, leaf.shape)
+                ],
+            }
+            for shard in leaf.addressable_shards
+        ]
+    return out
 
 
 def shard_map_score(servable: Servable, mesh: Mesh):

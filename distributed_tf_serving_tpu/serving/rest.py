@@ -38,7 +38,7 @@ model server's version_labels map):
 Requests are converted to the SAME PredictRequest protos the gRPC path
 parses and handed to PredictionServiceImpl.predict_async — one
 implementation of resolution, validation, widening, batching, and error
-taxonomy; the gateway only translates JSON<->tensors and ServiceError
+classification; the gateway only translates JSON<->tensors and ServiceError
 codes onto HTTP statuses (TF-Serving's own REST error shape:
 `{"error": "..."}`).
 """
@@ -230,7 +230,7 @@ class RestGateway:
         self, model: str, version, signature_name: str, label=None,
         criticality=None,
     ):
-        # ONE lookup-error taxonomy, shared with the gRPC path. The
+        # ONE lookup-error classification, shared with the gRPC path. The
         # lifecycle plane's canary router overrides DEFAULT resolutions
         # here too — the gateway pins the CONCRETE resolved version into
         # the proto it hands the impl, so routing must happen at this
@@ -641,6 +641,7 @@ class RestGateway:
             "integrity": self.impl.integrity_stats,
             "versions": self.impl.versions_stats,
             "pipeline": self.impl.pipeline_stats,
+            "runtime": self.impl.runtime_stats,
             "request_log": request_log,
             "draining": lambda: bool(getattr(self.impl, "draining", False)),
         }
@@ -674,7 +675,7 @@ class RestGateway:
         for name in ("cache", "row_cache", "overload", "utilization",
                      "quality", "lifecycle", "recovery", "kernels", "mesh",
                      "elastic", "fleet", "cascade", "integrity", "versions",
-                     "pipeline"):
+                     "pipeline", "runtime"):
             if name == "mesh":
                 block = self.impl.mesh_stats(
                     utilization=snap.get("utilization")
@@ -1003,7 +1004,7 @@ class RestGateway:
             return _json_error(e.code, str(e))
         except ValueError as e:
             # e.g. a /versions/{v} segment past int64: client error, same
-            # JSON taxonomy as every other route.
+            # JSON classification as every other route.
             return _json_error("INVALID_ARGUMENT", str(e))
         except Exception as e:  # noqa: BLE001 — surface as 500, keep serving
             log.exception("internal error serving REST status")

@@ -8,7 +8,7 @@ validation against the signature, output_filter selection
 The gRPC layer (server.py) is a thin adapter over this class, so the same
 logic is testable without sockets and reusable from an in-process client.
 
-Error taxonomy (per-RPC status codes — the failure-detection obligation from
+Error codes (per-RPC status codes — the failure-detection obligation from
 SURVEY.md §5): unknown model/version -> NOT_FOUND; malformed tensors,
 signature mismatches, bad Examples -> INVALID_ARGUMENT; oversized batches ->
 RESOURCE_EXHAUSTED (wired to codes in server.py via ServiceError.code).
@@ -170,6 +170,12 @@ class PredictionServiceImpl:
         # chunk message. Off by default = historical allocate-per-call.
         self.response_arena = False
         self._arenas = threading.local()
+        # Start-up facts for /monitoring's `runtime` block: build_stack
+        # records the load-time compile wall (ladder warmup + kernel
+        # autotune); serve() attaches the persistent-compile-cache counter
+        # (utils/runtime.py CompileCacheStats). None = not recorded.
+        self.warmup_s: float | None = None
+        self.compile_cache = None
 
     def _arena(self):
         """The calling thread's EncodeArena, or None when the plane is
@@ -189,6 +195,25 @@ class PredictionServiceImpl:
         not a gated plane."""
         fn = getattr(self.batcher, "pipeline_stats", None)
         return fn() if callable(fn) else None
+
+    def runtime_stats(self) -> dict:
+        """What this process runs on, as jax reports it — platform,
+        device_kind, device count, library versions — plus the load-time
+        compile wall, persistent-cache traffic and whether the native host
+        ops are loaded: the `runtime` block in /monitoring. jax falls back
+        to the CPU with only a warning when it finds no accelerator; this
+        block is where an operator (and chip_smoke.py) sees that."""
+        from .. import native
+        from ..utils.runtime import describe_devices
+
+        block = describe_devices()
+        block["warmup_s"] = self.warmup_s
+        block["compile_cache"] = (
+            self.compile_cache.snapshot()
+            if self.compile_cache is not None else None
+        )
+        block["native_hostops"] = native.available()
+        return block
 
     def _log_request(self, kind: str, request) -> None:
         if self.request_logger is not None:
@@ -1145,7 +1170,7 @@ class PredictionServiceImpl:
         carry offset/count for the client's incremental merge), so the
         caller's first scores decouple from the slowest sub-batch. Unary
         Predict semantics otherwise: same resolution/validation/encode
-        path, same error taxonomy — a failed sub-batch aborts the stream
+        path, same error classification — a failed sub-batch aborts the stream
         with the translated status after cancelling its siblings. A
         deadline expiring mid-stream cancels the remaining sub-batches
         and aborts DEADLINE_EXCEEDED."""

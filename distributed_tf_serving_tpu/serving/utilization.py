@@ -1,15 +1,13 @@
 """Device-utilization attribution plane: occupancy ledger, gap waterfall,
 on-demand deep capture.
 
-The salvaged TPU bench (BENCH_r04/r05) says the chip could sustain ~43k
-QPS (MFU 0.70) while the served path achieves ~1% of it
-(`achieved_fraction_of_device_limit: 0.011`) — but that number exists only
-as an offline bench artifact, and the aggregate `phases_us` sums cannot
-say *when* the device sat idle or *why*. ROADMAP item 1 (close the 100x
-gap) needs a live, continuously-served decomposition of wall time before
-the serving-path overhaul can be driven by data; "Scaling TensorFlow to
-300 million predictions per second" (PAPERS.md) finds its batching and
-transport amortization wins by attributing exactly this idle time.
+A bench's `achieved_fraction_of_device_limit` exists only as an offline
+artifact, and the aggregate `phases_us` sums cannot say *when* the device
+sat idle or *why*. Closing the gap between the device's limit and the
+served path needs a live, continuously-served decomposition of wall time;
+"Scaling TensorFlow to 300 million predictions per second" (PAPERS.md)
+finds its batching and transport amortization wins by attributing exactly
+this idle time.
 
 Three layers, all off by default and armed by the `[utilization]` config
 section (one attribute read per batcher hot-path hook when off — the
@@ -22,8 +20,8 @@ tracing/cache/overload precedent):
   triple, so the busy union splits into host-dispatch/H2D, device
   compute, and D2H wait. The idle time BETWEEN busy intervals is
   attributed to its blocking cause from cheap wait-interval records the
-  batcher leaves while it idles: `queue_empty` (no work arrived — on
-  this rig, the transport/client-bound share), `host_pack` (the host was
+  batcher leaves while it idles: `queue_empty` (no work arrived — the
+  transport/client-bound share), `host_pack` (the host was
   assembling/coalescing while the device starved), `readback_wait`
   (pipeline saturated behind in-flight readbacks), `admission_shed`
   (traffic existed but admission refused it). An in-flight
@@ -97,7 +95,7 @@ def _overlap_with_union(union: list[tuple[float, float]], t0: float, t1: float) 
 
 def _normalize_step_table(table: dict | None) -> dict[int, float]:
     """ONE normalization of a per-bucket device-step table: accepts
-    {bucket: us} or the envelope's {bucket: [lo, hi]} (midpoint); skips
+    {bucket: us} or the range form {bucket: [lo, hi]} (midpoint); skips
     non-positive entries (a 0.0 step can only divide-by-zero downstream).
     Shared by load_calibration and set_calibration so the two install
     paths can never disagree on the same artifact."""
@@ -111,9 +109,9 @@ def _normalize_step_table(table: dict | None) -> dict[int, float]:
 
 
 def load_calibration(path: str) -> dict[int, float]:
-    """Per-bucket pure device step (us) from a bench artifact: either the
-    healthy-weather envelope (`device_step_us: {bucket: [lo, hi]}` —
-    midpoint used) or a measured table (`{bucket: us}`). Empty dict on
+    """Per-bucket pure device step (us) from a JSON table: either a range
+    form (`device_step_us: {bucket: [lo, hi]}` — midpoint used) or a
+    measured table (`{bucket: us}`). Empty dict on
     any trouble — calibration is an enrichment, never a dependency."""
     try:
         with open(path) as f:
@@ -266,7 +264,7 @@ class OccupancyLedger:
 
     def set_calibration(self, table: dict) -> None:
         """Install/refresh the per-bucket device-step table (us). Accepts
-        {bucket: us} or the envelope's {bucket: [lo, hi]} form;
+        {bucket: us} or the range {bucket: [lo, hi]} form;
         non-positive values are skipped (same normalizer as
         load_calibration)."""
         clean = _normalize_step_table(table)

@@ -98,11 +98,17 @@ def load_servable(
             from ..parallel.sharding import param_shardings
 
             shardings = param_shardings(target, mesh, tensor_parallel)
-            target = jax.tree.map(
-                lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
-                target,
-                shardings,
-            )
+        else:
+            # Name the placement: without one orbax replays the sharding
+            # file, i.e. the SAVING process's devices — a checkpoint written
+            # by a CPU process would not restore on the chip.
+            here = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+            shardings = jax.tree.map(lambda _: here, target)
+        target = jax.tree.map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            target,
+            shardings,
+        )
         with ocp.StandardCheckpointer() as ckptr:
             params = ckptr.restore((path / PARAMS_DIR).absolute(), target)
 

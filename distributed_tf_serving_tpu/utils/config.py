@@ -602,9 +602,8 @@ class UtilizationConfig:
     # Default waterfall window for /utilz and /monitoring.
     window_seconds: float = 60.0
     # Optional per-bucket pure-device-step table (us) calibrating the
-    # live achieved_fraction_of_device_limit estimate — the bench's
-    # artifacts/device_envelope.json format ({bucket: us} or
-    # {bucket: [lo, hi]}). "" = uncalibrated (busy-fraction fallback,
+    # live achieved_fraction_of_device_limit estimate: a JSON table of
+    # device-step times ({bucket: us} or {bucket: [lo, hi]}). "" = uncalibrated (busy-fraction fallback,
     # labeled as such in the waterfall).
     calibration_file: str = ""
     # Where POST /profilez/start drops capture artifacts (jax profiler
@@ -852,7 +851,7 @@ class RecoveryConfig:
 class KernelsConfig:
     """Kernel/quantization plane knobs (ops/quantize.py + ops/autotune.py
     + ops/cross_kernel.py fused serving kernel, ISSUE 12): post-training
-    int8 weight quantization, the fused Pallas gather+cross+MLP serving
+    int8 weight quantization, the fused Pallas cross+MLP+head serving
     kernel, and the per-bucket autotune harness that enables each variant
     ONLY where it measured faster than the XLA/f32 baseline on the live
     device AND passed the accuracy gates. Everything defaults OFF; when
@@ -874,7 +873,8 @@ class KernelsConfig:
     measure_only: bool = False
     # Decision-table persistence: restarts with the same (model, version,
     # device, gates) adopt their prior measurements instead of re-tuning.
-    # "" disables persistence.
+    # A relative path resolves against the checkout (utils/runtime.py
+    # CHECKOUT), never the working directory; "" disables persistence.
     table_file: str = "artifacts/kernel_autotune.json"
     # Enablement gates: a variant serves a bucket only when measured
     # speedup >= min_speedup AND max |Δscore| vs the f32 baseline <=
@@ -913,6 +913,12 @@ class KernelsConfig:
                     "[kernels] autotune_buckets must be positive integers, "
                     f"got {self.autotune_buckets!r}"
                 )
+        if self.table_file:
+            from .runtime import CHECKOUT
+
+            object.__setattr__(
+                self, "table_file", str(CHECKOUT / self.table_file)
+            )
 
     def build(self):
         """KernelManager per this config, or None when disabled. The

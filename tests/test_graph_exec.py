@@ -19,7 +19,7 @@ import pytest
 
 jax = pytest.importorskip("jax")
 
-from distributed_tf_serving_tpu.utils.compat import enable_x64  # noqa: E402
+enable_x64 = jax.enable_x64
 
 from distributed_tf_serving_tpu.client import ShardedPredictClient
 from distributed_tf_serving_tpu.interop.graph_exec import (

@@ -215,3 +215,27 @@ def test_row_label_keys_native_equals_fallback(monkeypatch):
     monkeypatch.undo()
     assert with_native == without
     assert all(len(k) == 32 for k in with_native)  # 16-byte hex
+
+
+def test_library_of_other_source_is_not_loaded(tmp_path, monkeypatch):
+    """The built file is keyed on the source's bytes, not on file times (a
+    copied tree does not preserve them): once hostops.cc differs from what
+    the library on disk was built from, that library is not a candidate."""
+    built = native._so_path()
+    assert built.exists()  # the fixture's ensure() built it
+    other = tmp_path / "hostops.cc"
+    other.write_bytes(native._SRC.read_bytes() + b"\n// edited\n")
+    monkeypatch.setattr(native, "_SRC", other)
+    assert native._so_path() != built
+    assert native._load_locked(build=False) is None
+
+
+def test_failed_build_raises(tmp_path, monkeypatch):
+    """A compile failure is an error the caller sees (the CLI server calls
+    ensure() at start-up), never a quiet switch to the numpy host path."""
+    broken = tmp_path / "hostops.cc"
+    broken.write_text("this is not C++\n")
+    monkeypatch.setattr(native, "_SRC", broken)
+    monkeypatch.setattr(native, "_BUILD_DIR", tmp_path / "build")
+    with pytest.raises(native.NativeBuildError, match="build failed"):
+        native._load_locked()

@@ -317,7 +317,7 @@ def test_batcher_refusal_carries_retry_after_and_maps_resource_exhausted(servabl
                 err = e
                 break
         assert err is not None, "adaptive limit never refused"
-        # Status taxonomy: subclassing QueueOverloadError keeps the
+        # Status mapping: subclassing QueueOverloadError keeps the
         # RESOURCE_EXHAUSTED mapping and every existing handler.
         assert isinstance(err, QueueOverloadError)
         assert err.retry_after_ms is not None and err.retry_after_ms >= 25

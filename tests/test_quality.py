@@ -665,7 +665,7 @@ def test_qualityz_disabled_surface(servable):
 
         out = _run_rest(impl, drive)
         assert out["qualityz"] == {"enabled": False}
-        assert out["labelz_status"] == 500  # FAILED_PRECONDITION taxonomy
+        assert out["labelz_status"] == 500  # FAILED_PRECONDITION classification
         assert out["snapshot_status"] == 500
         assert out["section"] == {"quality": None}
     finally:

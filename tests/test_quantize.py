@@ -7,7 +7,7 @@ autotune harness tests (ISSUE 12):
   sidecars round-tripping through the batcher completer, and the
   response-wire bit path (service encode -> codec client dequant);
 - quantized-entry AUC on a genuinely TRAINED model within the 0.005 gate;
-- the fused serving kernel (interpret mode): gather + cross + MLP parity
+- the fused serving kernel (interpret mode): cross + MLP + head parity
   against model.apply, f32 and int8 weight operands;
 - the autotune harness: gates, measure-only, persistence + stale-table
   invalidation on version swap, decision routing through live submits,
@@ -241,7 +241,7 @@ def test_quantize_scores_numpy_roundtrip():
 
 @pytest.mark.parametrize("quantized", [False, True])
 def test_fused_serve_kernel_parity(servable, quantized):
-    """The fused gather+cross+MLP kernel (interpret mode) matches
+    """The fused cross+MLP+head kernel (interpret mode) matches
     model.apply over the same params — float and int8 weight operands."""
     from distributed_tf_serving_tpu.ops.cross_kernel import build_fused_serve
 
@@ -390,6 +390,27 @@ def test_forced_pallas_variant_on_cpu(servable, monkeypatch):
         assert row["max_abs_delta"] <= 0.005
         # Interpret mode is orders slower: measured, recorded, NOT chosen.
         assert row["speedup"] < 1.0 or row["enabled"] in (True, False)
+    finally:
+        batcher.stop()
+
+
+def test_pallas_refused_by_an_accelerator_stops_the_tune(servable, monkeypatch):
+    """Off the CPU a Pallas variant that raises is the device's compiler
+    refusing the kernel the operator asked for: KernelLoweringError carries
+    the message out (server start-up stops on it) instead of the failure
+    becoming a disabled row while XLA serves on. Faked here by reporting a
+    "tpu" backend: the kernel then lowers for real (interpret off), which
+    the CPU backend refuses."""
+    from distributed_tf_serving_tpu.ops.autotune import KernelLoweringError
+
+    batcher = _batcher(buckets=(16,))
+    try:
+        batcher.warmup(servable)
+        km = _manager(measure_iters=1, quantize=False)
+        batcher.kernels = km
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(KernelLoweringError, match="pallas_f32 did not compile"):
+            km.autotune(batcher, servable, buckets=(16,))
     finally:
         batcher.stop()
 
@@ -603,7 +624,10 @@ def test_pallas_int8_apply_builds_without_deadlock(servable):
         "feat_ids": fold_ids_host(arrays["feat_ids"], CFG.vocab_size),
         "feat_wts": arrays["feat_wts"],
     }
-    got = np.asarray(out["fn"](None, batch)["prediction_node"])
+    # Called as the batcher calls it: with the variant's own param tree
+    # (the kernel reads the embedding table from it).
+    qparams = km.params_for(servable, True)
+    got = np.asarray(out["fn"](qparams, batch)["prediction_node"])
     want = golden(servable, arrays, params=quantize_params(servable.params))
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 

@@ -1,5 +1,5 @@
 """REST gateway (serving/rest.py): TF-Serving's :8501 surface — row and
-columnar predict formats, error taxonomy onto HTTP statuses, status and
+columnar predict formats, error classification onto HTTP statuses, status and
 metadata routes — over a real aiohttp server, scored against the model's
 own forward."""
 
@@ -111,7 +111,7 @@ def test_predict_columnar_inputs(stack):
     np.testing.assert_allclose(got, _native_scores(sv, ids, wts), rtol=1e-5)
 
 
-def test_error_taxonomy_maps_to_http(stack):
+def test_error_kinds_maps_to_http(stack):
     impl, _sv = stack
 
     async def handler(session):
@@ -149,14 +149,14 @@ def test_error_taxonomy_maps_to_http(stack):
     assert res["bad_json"][0] == 400
     assert res["missing_input"][0] == 400
     assert res["both_formats"][0] == 400
-    assert res["bad_version"][0] == 400  # not 500: client error taxonomy
+    assert res["bad_version"][0] == 400  # not 500: client error classification
     for status, body in res.values():
         assert "error" in body
 
 
 def test_label_routes(stack):
     """/labels/{l} routes resolve through the registry's label map for all
-    three POST verbs; unknown labels take the NOT_FOUND taxonomy."""
+    three POST verbs; unknown labels take the NOT_FOUND classification."""
     impl, sv = stack
     impl.registry.set_label("DCN", "stable", 1)
     rng = np.random.RandomState(9)
@@ -337,7 +337,7 @@ def test_regress_route_with_context(stack):
     np.testing.assert_allclose(out["results"], grpc_vals, rtol=1e-6)
 
 
-def test_classify_regress_error_taxonomy(stack):
+def test_classify_regress_error_kinds(stack):
     impl, _sv = stack
 
     async def handler(session):

@@ -230,7 +230,7 @@ def test_aio_server_classify_regress_async_path(stack):
 
 def test_model_service_get_model_status(stack):
     """tensorflow.serving.ModelService/GetModelStatus over the wire: all
-    loaded versions AVAILABLE, version/label pinning, NOT_FOUND taxonomy."""
+    loaded versions AVAILABLE, version/label pinning, NOT_FOUND classification."""
     registry, _impl, port = stack
     from distributed_tf_serving_tpu.proto import ModelServiceStub
 
@@ -665,7 +665,7 @@ def test_channels_per_host_stripes_and_scores(three_backends):
 def test_closed_loop_mp_smoke(three_backends):
     """Spawn-context load generators: end-to-end report over a real socket.
     Single process x small load — the multi-core fan-out is exercised on
-    real hosts, not this 1-core rig."""
+    real hosts."""
     from distributed_tf_serving_tpu.client import run_closed_loop_mp
 
     payload = make_payload(candidates=12, num_fields=CFG.num_fields)

@@ -1,6 +1,6 @@
 """Model-warmup replay (serving/warmup.py): TFRecord framing + CRC32C
 against known vectors AND TensorFlow's own writer, PredictionLog replay
-through the real impl/batcher, failure taxonomy, watcher integration."""
+through the real impl/batcher, failure classification, watcher integration."""
 
 import subprocess
 import sys

@@ -93,8 +93,8 @@ def main() -> None:
     # and no edge client ever saw it.
     if fl.get("errors", 0) != 0:
         failures.append(
-            f"{fl.get('errors')} edge-visible error(s) — taxonomy: "
-            f"{fl.get('error_taxonomy')}"
+            f"{fl.get('errors')} edge-visible error(s) — kinds: "
+            f"{fl.get('error_kinds')}"
         )
     ratio = fl.get("min_chaos_window_ratio")
     if ratio is None or ratio < MIN_GOODPUT_RATIO:

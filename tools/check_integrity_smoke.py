@@ -10,7 +10,7 @@ tees it to a file) and asserts the acceptance criteria end to end:
   response-side wire flip was caught by the score-CRC verify before
   merge (corrupt_responses >= 1 proves the detector fired), no NaN row
   was ever merged into a ranking (nan_scores_merged == 0), and every
-  client-visible error in the taxonomy is an integrity
+  client-visible error in the classification is an integrity
   rejection/retry — never silently-wrong data;
 - each DETECTION LAYER fired on its own fault site: the server rejected
   request-side wire corruption (wire.inputs_rejected >= 1) while clean
@@ -103,9 +103,9 @@ def main() -> None:
             "client verify never caught a response-side wire flip "
             "(corrupt_responses=0) — the detector did not fire"
         )
-    taxonomy = line.get("error_taxonomy") or {}
+    kinds = line.get("error_kinds") or {}
     unexplained = {
-        k: v for k, v in taxonomy.items()
+        k: v for k, v in kinds.items()
         if not any(m in k for m in ALLOWED_ERROR_MARKERS)
     }
     if unexplained:

@@ -124,7 +124,7 @@ def main() -> None:
     if grpc_err != 0:
         failures.append(
             f"swaps must not fail traffic: grpc_err={grpc_err} "
-            f"(taxonomy={line.get('error_taxonomy')})"
+            f"(kinds={line.get('error_kinds')})"
         )
     if not lc.get("lifecyclez_enabled"):
         failures.append("live /lifecyclez did not answer enabled=true")

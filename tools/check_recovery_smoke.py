@@ -101,7 +101,7 @@ def main() -> None:
     if line.get("grpc_err", 0) != 0:
         failures.append(
             f"{line.get('grpc_err')} client-visible request failure(s) — "
-            f"taxonomy: {line.get('error_taxonomy')}"
+            f"kinds: {line.get('error_kinds')}"
         )
     poison = rec.get("poison") or {}
     if not poison.get("poisoned"):

@@ -1,15 +1,12 @@
 #!/usr/bin/env python
-"""Elastic mesh serving A/B child (ISSUE 15): pinned-split vs elastic
+"""Elastic mesh serving A/B (ISSUE 15): pinned-split vs elastic
 serving of the SAME seeded ramped stream, printed as one JSON line.
 
-Run standalone, or by bench.py's `elastic` block (DTS_BENCH_ELASTIC=1) —
-the parent decides the device substrate and records it: on a live slice
-with >= ELASTIC_AB_DEVICES chips this measures real hardware
-(emulated=false); on CPU the parent forces
-``XLA_FLAGS=--xla_force_host_platform_device_count=N`` so the numbers
-are EMULATED-DEVICE trajectory points (emulated=true — the PR-13
-standing-debt field: a CPU run is a functional trajectory point, never a
-throughput claim; the live-TPU round flips the flag).
+Run standalone in one process, or in-process by bench.py's `elastic`
+block (DTS_BENCH_ELASTIC=1, real chips only). On a host with chips this
+measures real hardware (emulated=false). ELASTIC_AB_FORCE_CPU=1 (or an
+already-CPU environment) runs it on N emulated CPU devices instead: a
+functional check, never a throughput claim (emulated=true).
 
 The stream is three pressure phases over one seeded payload cycle, both
 runs replaying the SAME schedule:
@@ -47,10 +44,6 @@ if os.environ.get("JAX_PLATFORMS") == "cpu":
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax  # noqa: E402
-
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
 import numpy as np  # noqa: E402
 
 from distributed_tf_serving_tpu.models import (  # noqa: E402
@@ -71,6 +64,7 @@ from distributed_tf_serving_tpu.utils.config import (  # noqa: E402
     ElasticConfig,
     OverloadConfig,
 )
+from distributed_tf_serving_tpu.utils.runtime import enable_compile_cache  # noqa: E402
 
 NUM_FIELDS = int(os.environ.get("ELASTIC_AB_FIELDS", "16"))
 HEAVY_CANDIDATES = int(os.environ.get("ELASTIC_AB_CANDIDATES", "512"))
@@ -292,6 +286,7 @@ def main() -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     result = main()
     print(json.dumps(result))
     sys.exit(0 if result.get("ok") else 1)

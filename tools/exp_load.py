@@ -2,9 +2,9 @@
 """Load-shape experiment: sweep client concurrency against the real serving
 stack and report QPS / p50 / host-CPU utilization per point.
 
-Decides the round-3 tuning question: is the rig Little's-law latency-bound
-(QPS scales with concurrency) or single-core host-CPU-bound (QPS flat, CPU
-util ~1.0)? Run directly; not part of the bench contract.
+Decides one tuning question: is the stack Little's-law latency-bound (QPS
+scales with concurrency) or host-CPU-bound (QPS flat, CPU util ~1.0)? Run
+directly; not part of the bench contract.
 """
 
 import asyncio
@@ -21,9 +21,6 @@ NUM_FIELDS = 43
 
 def main() -> None:
     import jax
-
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
 
     from distributed_tf_serving_tpu.client import (
         ShardedPredictClient,
@@ -82,8 +79,8 @@ def main() -> None:
     results = []
 
     # EXP_COMPACT=1: the framework-native wire (client-side fold + bf16,
-    # half the bytes, bit-identical scores) — the round-4 on-rig A/B knob,
-    # composable with EXP_UNIQUE. DTS_TPU_NO_FUSED=1 (batcher env) isolates
+    # half the bytes, bit-identical scores) — an A/B knob composable with
+    # EXP_UNIQUE. DTS_TPU_NO_FUSED=1 (batcher env) isolates
     # the native fused pack in the same sweeps.
     compact = os.environ.get("EXP_COMPACT", "0") == "1"
     if compact:

@@ -1,14 +1,12 @@
 #!/usr/bin/env python
-"""Mesh serving A/B child (ISSUE 13): single-chip vs data-parallel vs
+"""Mesh serving A/B (ISSUE 13): single-chip vs data-parallel vs
 data×model serving throughput of ONE process, printed as one JSON line.
 
-Run standalone, or by bench.py's `mesh` block (DTS_BENCH_MESH=1) — the
-parent decides the device substrate and records it: on a live slice with
->= MESH_AB_DEVICES chips this measures real hardware (emulated=false); on
-CPU the parent forces
-``XLA_FLAGS=--xla_force_host_platform_device_count=N`` so the numbers are
-EMULATED-DEVICE trajectory points (emulated=true — the standing-debt
-field that lets the next live-TPU round tell the two apart).
+Run standalone in one process, or in-process by bench.py's `mesh` block
+(DTS_BENCH_MESH=1, real chips only). On a host with >= 2 chips this
+measures real hardware (emulated=false). MESH_AB_FORCE_CPU=1 (or an
+already-CPU environment) runs it on N emulated CPU devices instead:
+a functional check whose rates say nothing about a chip (emulated=true).
 
 Modes (all serving the SAME params through a DynamicBatcher, so the A/B
 isolates the execution substrate, not the batching logic):
@@ -29,12 +27,9 @@ import sys
 import time
 
 # Backend selection must happen BEFORE importing jax, and it must NOT
-# default to CPU: on a live slice the parent (bench.py mesh_ab_block)
-# passes the env through untouched so this child measures real hardware.
-# Only an explicit emulation request (MESH_AB_FORCE_CPU=1, which the
-# parent sets when no live slice is available — also the standalone
-# CPU-run knob) or an already-CPU environment forces the emulated
-# N-device mesh.
+# default to CPU: on a host with chips this measures real hardware. Only an
+# explicit emulation request (MESH_AB_FORCE_CPU=1) or an already-CPU
+# environment gives the emulated N-device mesh.
 _need = int(os.environ.get("MESH_AB_DEVICES", "8"))
 if os.environ.get("MESH_AB_FORCE_CPU") == "1":
     os.environ["JAX_PLATFORMS"] = "cpu"
@@ -47,10 +42,6 @@ if os.environ.get("JAX_PLATFORMS") == "cpu":
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax  # noqa: E402
-
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
 import numpy as np  # noqa: E402
 
 from distributed_tf_serving_tpu.client import make_payload  # noqa: E402
@@ -65,6 +56,7 @@ from distributed_tf_serving_tpu.parallel import (  # noqa: E402
     make_mesh,
 )
 from distributed_tf_serving_tpu.serving.batcher import DynamicBatcher  # noqa: E402
+from distributed_tf_serving_tpu.utils.runtime import enable_compile_cache  # noqa: E402
 
 NUM_FIELDS = int(os.environ.get("MESH_AB_FIELDS", "16"))
 CANDIDATES = int(os.environ.get("MESH_AB_CANDIDATES", "512"))
@@ -183,6 +175,7 @@ def main() -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     result = main()
     print(json.dumps(result))
     sys.exit(0 if result.get("ok") else 1)
