@@ -160,10 +160,11 @@ def mlp_init(rng: jax.Array, in_dim: int, dims: tuple[int, ...], dtype) -> list:
 
 
 def mlp_apply(layers: list, x: jax.Array, compute_dtype, final_relu: bool = True) -> jax.Array:
-    for i, p in enumerate(layers):
-        x = dense_apply(p, x, compute_dtype)
-        if final_relu or i + 1 < len(layers):
-            x = jax.nn.relu(x)
+    with jax.named_scope("mlp"):
+        for i, p in enumerate(layers):
+            x = dense_apply(p, x, compute_dtype)
+            if final_relu or i + 1 < len(layers):
+                x = jax.nn.relu(x)
     return x
 
 

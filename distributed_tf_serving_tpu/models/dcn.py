@@ -99,18 +99,17 @@ def _build(config: ModelConfig) -> Model:
             # Oversized stacks (all L weight matrices are VMEM-resident in
             # the fused kernel) fall back to the per-layer XLA path.
             use_fused = fits_vmem(d, config.num_cross_layers, cd)
-        if use_fused:
-            import jax as _jax
+        with jax.named_scope("cross"):
+            if use_fused:
+                from ..ops.cross_kernel import cross_params_to_stacked, fused_cross_apply
 
-            from ..ops.cross_kernel import cross_params_to_stacked, fused_cross_apply
-
-            w, b = cross_params_to_stacked(params["cross"])
-            # interpret mode keeps the kernel runnable on the CPU test mesh.
-            xc = fused_cross_apply(
-                x0, w, b, compute_dtype=cd, interpret=_jax.default_backend() == "cpu"
-            )
-        else:
-            xc = cross_apply(params["cross"], x0, cd)
+                w, b = cross_params_to_stacked(params["cross"])
+                # interpret mode keeps the kernel runnable on the CPU test mesh.
+                xc = fused_cross_apply(
+                    x0, w, b, compute_dtype=cd, interpret=jax.default_backend() == "cpu"
+                )
+            else:
+                xc = cross_apply(params["cross"], x0, cd)
         xd = mlp_apply(params["mlp"], x0, cd)
         h = jnp.concatenate([xc.astype(jnp.float32), xd.astype(jnp.float32)], axis=-1)
         logit = dense_apply(params["out"], h, cd)[:, 0]

@@ -52,13 +52,14 @@ def build_dlrm(config: ModelConfig) -> Model:
             dense = jnp.zeros((n, config.num_dense_features), jnp.float32)
         bot = mlp_apply(params["bottom_mlp"], dense, cd)  # [n, D]
         emb = field_embed(params["embedding"], batch["feat_ids"], batch["feat_wts"], cd)
-        z = jnp.concatenate([bot[:, None, :].astype(cd), emb], axis=1)  # [n, F+1, D]
-        # Pairwise dot interactions: upper triangle of Z Z^T (excl. diagonal).
-        zzt = jax.lax.dot_general(
-            z, z, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
-        )  # [n, F+1, F+1]
-        iu, ju = jnp.triu_indices(num_feat, k=1)
-        inter = zzt[:, iu, ju]  # [n, num_pairs]
+        with jax.named_scope("interact"):
+            z = jnp.concatenate([bot[:, None, :].astype(cd), emb], axis=1)  # [n, F+1, D]
+            # Pairwise dot interactions: upper triangle of Z Z^T (excl. diagonal).
+            zzt = jax.lax.dot_general(
+                z, z, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
+            )  # [n, F+1, F+1]
+            iu, ju = jnp.triu_indices(num_feat, k=1)
+            inter = zzt[:, iu, ju]  # [n, num_pairs]
         top = jnp.concatenate([bot.astype(jnp.float32), inter], axis=-1)
         logit = dense_apply(params["out"], mlp_apply(params["top_mlp"], top, cd), cd)[:, 0]
         return {"prediction_node": jax.nn.sigmoid(logit), "logits": logit}

@@ -68,6 +68,7 @@ def field_embed(
     feat_wts  [n, F] float
     returns   [n, F, D] in compute_dtype
     """
-    rows = fold_ids(feat_ids, table.shape[0])
-    emb = jnp.take(table, rows, axis=0)  # [n, F, D]
-    return emb.astype(compute_dtype) * feat_wts[..., None].astype(compute_dtype)
+    with jax.named_scope("embed"):
+        rows = fold_ids(feat_ids, table.shape[0])
+        emb = jnp.take(table, rows, axis=0)  # [n, F, D]
+        return emb.astype(compute_dtype) * feat_wts[..., None].astype(compute_dtype)

@@ -22,6 +22,7 @@ workload (DCNClient.java:98-108 shapes).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import ml_dtypes
 import numpy as np
@@ -76,6 +77,7 @@ def pack_host(arrays: dict[str, np.ndarray], spec: dict[str, str]) -> dict[str, 
     return out
 
 
+@jax.named_scope("unpack")
 def unpack_device(packed: dict[str, jnp.ndarray], spec: dict[str, str]) -> dict[str, jnp.ndarray]:
     """Inverse of pack_host, traced inside the jitted executable."""
     out = {}
@@ -157,6 +159,7 @@ def quantize_output_device(v: jnp.ndarray):
     return q.astype(jnp.int8), scale.reshape(1), mn.reshape(1)
 
 
+@jax.named_scope("wire")
 def compact_outputs_device(
     outputs: dict[str, jnp.ndarray], wire_dt
 ) -> dict[str, jnp.ndarray]:
@@ -334,6 +337,7 @@ def pack_host_combined(
     return np.concatenate(segs) if len(segs) > 1 else segs[0]
 
 
+@jax.named_scope("unpack")
 def unpack_device_combined(buf: jnp.ndarray, layout: tuple) -> dict[str, jnp.ndarray]:
     """Inverse of pack_host_combined, traced inside the jitted executable.
     Slices are static (n derives from the buffer length and the layout's

@@ -47,6 +47,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import hashlib
+import re
 import threading
 import time
 import weakref
@@ -656,6 +657,9 @@ class _WorkItem:
     # score vector instead of full outputs. Prune submits are forced
     # solo — the survivor indices address the request's own rows.
     prune_k: int = 0
+    # When the collector closed the group this item rides (left its
+    # coalesce loop): the end of `req.queue`, the start of `req.assemble`.
+    closed_t: float | None = None
 
 
 def _replay_group_phases(group: list["_WorkItem"], phases: list) -> None:
@@ -667,6 +671,52 @@ def _replay_group_phases(group: list["_WorkItem"], phases: list) -> None:
     for it in group:
         if it.span is not None:
             tracing.replay_phases(it.span, phases)
+
+
+class _Timeline:
+    """One batch's share of the request timeline `req.*` (request_trace).
+
+    Six stamps on perf_counter tile a request's way through a batch with
+    no gap and no overlap: `enqueue_t` (submit) -> `closed_t` (the
+    collector closed its group) -> `stage_t0` (device stage entered) ->
+    `issue_t0` (readback issued) -> `done_t` (fetch returned) ->
+    `resolved_t` (this request's set_result) -> the handler running again.
+    The first five differences are summed here over the batch's requests
+    and added once; `resolved_t` rides the future (`dts_resolved_t`) and
+    the handler adds the sixth, `req.resume`. Warm-up items and requests
+    that fail or were withdrawn add nothing."""
+
+    __slots__ = ("stage_t0", "issue_t0", "done_t", "count", "queue_s",
+                 "assemble_s", "deliver_s")
+
+    def __init__(self, stage_t0: float, issue_t0: float, done_t: float):
+        self.stage_t0, self.issue_t0, self.done_t = stage_t0, issue_t0, done_t
+        self.count = 0
+        self.queue_s = self.assemble_s = self.deliver_s = 0.0
+
+    def resolve(self, it: "_WorkItem", result) -> None:
+        """`it.future.set_result(result)`, stamped. InvalidStateError (the
+        waiter withdrew) propagates before anything is counted."""
+        if it.warmup or it.closed_t is None:
+            it.future.set_result(result)
+            return
+        resolved_t = it.future.dts_resolved_t = time.perf_counter()
+        it.future.set_result(result)
+        self.count += 1
+        self.queue_s += it.closed_t - it.enqueue_t
+        self.assemble_s += self.stage_t0 - it.closed_t
+        self.deliver_s += resolved_t - self.done_t
+
+    def flush(self) -> None:
+        n = self.count
+        if n:
+            request_trace.add_many((
+                ("req.queue", self.queue_s, n),
+                ("req.assemble", self.assemble_s, n),
+                ("req.dispatch", n * (self.issue_t0 - self.stage_t0), n),
+                ("req.device", n * (self.done_t - self.issue_t0), n),
+                ("req.deliver", self.deliver_s, n),
+            ))
 
 
 @dataclasses.dataclass
@@ -1822,6 +1872,16 @@ class DynamicBatcher:
 
         variants: dict[tuple, Callable] = {}
 
+        def named(run, topk, prune):
+            # The executable's name in a profiler trace (`jit_<name>`, the
+            # host plane's `PjitFunction(<name>)`): model and variant. The
+            # donating twin keeps its twin's name: the name is part of the
+            # persistent compile cache's key and donation is not, so the
+            # two share one compiled entry there, as they always have.
+            variant = "prune" if prune else "topk" if topk else "score"
+            run.__name__ = re.sub(r"\W", "_", f"{servable.name}_{variant}")
+            return run
+
         if combined:
             # One uint8 buffer per batch = ONE host->device transfer
             # instead of one per input; the layout split + bitcasts are
@@ -1864,7 +1924,9 @@ class DynamicBatcher:
                     else:
                         def run(p, b, _l=layout, _ok=out_keys, _ap=ap):
                             return finish(_ap(p, unpack_device_combined(b, _l)), _ok)
-                    jfn = _cache[key] = jax.jit(run, donate_argnums=donargs)
+                    jfn = _cache[key] = jax.jit(
+                        named(run, topk, prune), donate_argnums=donargs
+                    )
                 return jfn(params, buf, n_valid) if topk else jfn(params, buf)
         else:
             def fn(
@@ -1891,7 +1953,7 @@ class DynamicBatcher:
                             # lookup's index arithmetic.
                             batch = unpack_device(b, spec) if spec else b
                             return finish(_ap(p, batch), _ok)
-                    jfn = _cache[key] = jax.jit(run)
+                    jfn = _cache[key] = jax.jit(named(run, topk, prune))
                 return jfn(params, packed, n_valid) if topk else jfn(params, packed)
 
         if model.needs_x64:
@@ -2221,18 +2283,46 @@ class DynamicBatcher:
                     return it
                 if self._stopping:
                     return None
-                if (util := self.utilization) is not None:
-                    # Idle-cause record for the gap waterfall: the device
-                    # sat idle because no work arrived (the transport/
-                    # client-bound share of wall time). Clock reads only
-                    # on the idle path.
-                    token = util.wait_begin("queue_empty")
-                    try:
-                        self._cv.wait()
-                    finally:
-                        util.wait_end(token)
-                else:
-                    self._cv.wait()
+                # No work arrived: the transport/client-bound share.
+                self._wait(
+                    "queue_empty", until=lambda: self._items or self._stopping
+                )
+
+    # The utilization ledger's gap cause for a wait (its names predate the
+    # `wait.*` phases; /utilz keeps them). `window` is the dispatch
+    # thread's and has none: the ledger follows the collector.
+    _LEDGER_CAUSE = {
+        "queue_empty": "queue_empty",
+        "coalesce": "host_pack",
+        "pipeline": "readback_wait",
+    }
+
+    def _wait(
+        self, cause: str, timeout: float | None = None,
+        until: Callable | None = None,
+    ) -> None:
+        """One wait on the batcher's condition (the caller holds `_cv`),
+        timed once: phase `wait.<cause>` of request_trace, the same name
+        on the profiler's clock, and, under `_LEDGER_CAUSE`'s name for it,
+        the utilization ledger when armed. With `until`, the wait is taken
+        again, `timeout` at a time, until `until()` holds: every completion
+        notifies the condition, and a wait cut into the pieces between them
+        would cover no idle gap of the device. The caller tests its
+        condition again either way. Clock reads only on the waiting path."""
+        phase = "wait." + cause
+        ledger_cause = self._LEDGER_CAUSE.get(cause)
+        util = self.utilization if ledger_cause is not None else None
+        token = util.wait_begin(ledger_cause) if util is not None else None
+        t0 = time.perf_counter()
+        try:
+            with tracing.annotation(phase):
+                self._cv.wait(timeout)
+                while until is not None and not until():
+                    self._cv.wait(timeout)
+        finally:
+            request_trace.add(phase, time.perf_counter() - t0)
+            if token is not None:
+                util.wait_end(token)
 
     def _coalesce_next(self, item: _WorkItem, total: int, deadline: float) -> _WorkItem | None:
         """Next same-target item within the (pipeline-extended) window, or
@@ -2254,18 +2344,10 @@ class DynamicBatcher:
                     if self._stopping:
                         return None
                     if now < deadline:
-                        if (util := self.utilization) is not None:
-                            # Coalesce fill: the host deliberately holds
-                            # the batch open — device idle charged to
-                            # host_pack (clamped out where the pipeline
-                            # keeps the device busy underneath).
-                            token = util.wait_begin("host_pack")
-                            try:
-                                self._cv.wait(deadline - now)
-                            finally:
-                                util.wait_end(token)
-                        else:
-                            self._cv.wait(deadline - now)
+                        # Coalesce fill: the host deliberately holds the
+                        # batch open (the ledger charges host_pack, clamped
+                        # out where the pipeline keeps the device busy).
+                        self._wait("coalesce", deadline - now)
                         continue
                     busy = len(self._inflight) + self._dispatch_pending
                     if busy < self.pipeline_depth or self._wedged_for(now):
@@ -2277,16 +2359,17 @@ class DynamicBatcher:
                     if not free_ride_counted:
                         self.stats.fill_waits += 1
                         free_ride_counted = True
-                    if (util := self.utilization) is not None:
-                        # Pipeline saturated: dispatch blocked behind
-                        # in-flight readbacks (idle cause readback_wait).
-                        token = util.wait_begin("readback_wait")
-                        try:
-                            self._cv.wait(0.005)
-                        finally:
-                            util.wait_end(token)
-                    else:
-                        self._cv.wait(0.005)
+                    # Pipeline saturated: dispatch blocked behind in-flight
+                    # readbacks (the ledger's idle cause readback_wait).
+                    self._wait(
+                        "pipeline", 0.005,
+                        until=lambda: (
+                            self._items or self._stopping
+                            or len(self._inflight) + self._dispatch_pending
+                            < self.pipeline_depth
+                            or self._wedged_for(time.perf_counter())
+                        ),
+                    )
                 nxt = self._items[0]
                 if nxt.future.cancelled() or (
                     nxt.deadline_t is not None
@@ -2340,6 +2423,9 @@ class DynamicBatcher:
                     break
                 group.append(nxt)
                 total += nxt.n
+            closed_t = time.perf_counter()
+            for it in group:
+                it.closed_t = closed_t
             self._dispatch(group, total)
 
     def _dispatch(self, group: list[_WorkItem], total: int) -> None:
@@ -2592,11 +2678,11 @@ class DynamicBatcher:
         # assembly against the stage. Bounded waits: the wedge clock
         # advances on wall time.
         with self._cv:
-            while (
-                self._dispatch_pending >= self.pipeline_depth
-                and not self._stopping
-            ):
-                self._cv.wait(0.005)
+            if self._dispatch_pending >= self.pipeline_depth and not self._stopping:
+                self._wait("pipeline", 0.005, until=lambda: (
+                    self._dispatch_pending < self.pipeline_depth
+                    or self._stopping
+                ))
 
     def _plan_rows(
         self, rc, group: list[_WorkItem], total: int,
@@ -2713,7 +2799,8 @@ class DynamicBatcher:
         self._finish_row_batch(group, row_ctx, None)
 
     def _finish_row_batch(
-        self, group: list[_WorkItem], row_ctx, host: dict | None
+        self, group: list[_WorkItem], row_ctx, host: dict | None,
+        timeline: "_Timeline | None" = None,
     ) -> None:
         """Deliver a row-cache batch once every foreign fill it joined has
         resolved. Never blocks a completer thread: when foreign waiters
@@ -2722,7 +2809,7 @@ class DynamicBatcher:
         by construction, whatever the completer pool's size."""
         pending = [f for f in row_ctx.plan.waiters.values() if not f.done()]
         if not pending:
-            self._deliver_row_batch(group, row_ctx, host)
+            self._deliver_row_batch(group, row_ctx, host, timeline)
             return
         lock = threading.Lock()
         state = {"left": len(pending)}
@@ -2733,7 +2820,7 @@ class DynamicBatcher:
                 if state["left"]:
                     return
             try:
-                self._deliver_row_batch(group, row_ctx, host)
+                self._deliver_row_batch(group, row_ctx, host, timeline)
             except Exception as exc:  # noqa: BLE001 — waiters must resolve
                 for it in group:
                     if not it.future.done():
@@ -2746,7 +2833,8 @@ class DynamicBatcher:
             f.add_done_callback(_on_done)
 
     def _deliver_row_batch(
-        self, group: list[_WorkItem], row_ctx, host: dict | None
+        self, group: list[_WorkItem], row_ctx, host: dict | None,
+        timeline: "_Timeline | None" = None,
     ) -> None:
         """Scatter (device + cached + foreign-filled) rows back into every
         request's original slice and resolve the futures. A request any
@@ -2803,10 +2891,16 @@ class DynamicBatcher:
                     )
             sliced = {k: v[sl] for k, v in full.items()}
             try:
-                if not it.future.cancelled():
+                if it.future.cancelled():
+                    continue
+                if timeline is not None:
+                    timeline.resolve(it, sliced)
+                else:
                     it.future.set_result(sliced)
             except InvalidStateError:
                 pass
+        if timeline is not None:
+            timeline.flush()
 
     def _run_stage(
         self,
@@ -2892,17 +2986,16 @@ class DynamicBatcher:
                 # finally). Bounded waits, and a wedged readback breaks
                 # the gate — the jit call would queue behind the wedged
                 # device anyway, and the breaker owns that failure mode.
-                waited_for_window = False
                 with self._cv:
-                    while (
-                        len(self._inflight) >= window
-                        and not self._stopping
-                        and not self._wedged_for(time.perf_counter())
-                    ):
-                        if not waited_for_window:
-                            self.stats.inflight_window_waits += 1
-                            waited_for_window = True
-                        self._cv.wait(0.005)
+                    def window_open() -> bool:
+                        return (
+                            len(self._inflight) < window or self._stopping
+                            or bool(self._wedged_for(time.perf_counter()))
+                        )
+
+                    if not window_open():
+                        self.stats.inflight_window_waits += 1
+                        self._wait("window", 0.005, until=window_open)
             with self._cv:
                 # An all-warmup group is exempt from the wedge clock:
                 # hot-load warmup (warmup_via_queue during a version
@@ -2939,11 +3032,13 @@ class DynamicBatcher:
                     ov.note_queue_waits(waits)
             if phases is not None:
                 # Queue wait is per-item (each enqueued at its own time);
-                # attached directly, not through the shared batch sink.
-                now = time.perf_counter()
+                # attached directly, not through the shared batch sink. The
+                # stamps are the timeline's: `req.queue` + `req.assemble`.
                 for it in group:
                     if it.span is not None:
-                        it.span.add_interval("batch.queue_wait", it.enqueue_t, now)
+                        it.span.add_interval(
+                            "batch.queue_wait", it.enqueue_t, stage_t0
+                        )
             with sink_ctx():
                 # Named fault site (faults.py): delay/error/wedge the device
                 # stage of this batch — the stuck-device scenario the circuit
@@ -3074,13 +3169,14 @@ class DynamicBatcher:
             if self.async_readback:
                 # Start the device->host readback now; the completer thread
                 # then finds the bytes already (or sooner) on host.
-                for v in fetch.values():
-                    if hasattr(v, "copy_to_host_async"):
-                        v.copy_to_host_async()
-                if shadow_fetch is not None:
-                    for v in shadow_fetch.values():
+                with tracing.annotation("readback.issue"):
+                    for v in fetch.values():
                         if hasattr(v, "copy_to_host_async"):
                             v.copy_to_host_async()
+                    if shadow_fetch is not None:
+                        for v in shadow_fetch.values():
+                            if hasattr(v, "copy_to_host_async"):
+                                v.copy_to_host_async()
                 with sink_ctx():
                     request_trace.add(
                         "readback.issue", time.perf_counter() - issue_t0
@@ -3218,6 +3314,8 @@ class DynamicBatcher:
             tracing.collect_phases(phases) if phases is not None else _NULL_CTX
         )
         taken_by_recovery = False
+        timeline = None
+        delivery = contextlib.ExitStack()  # holds the `batch.deliver` span
         try:
             with trace_ctx:
                 # Named fault sites (faults.py): a readback that stalls or
@@ -3230,14 +3328,20 @@ class DynamicBatcher:
                 # flight (issued at dispatch), so this measures the residual
                 # WAIT, not a full synchronous transfer — the split the
                 # phase names carry.
+                fetch_phase = (
+                    "readback.wait" if self.async_readback else "batch.readback"
+                )
                 wait_t0 = time.perf_counter()
-                host = {k: np.asarray(v) for k, v in outputs.items()}
+                with tracing.annotation(fetch_phase):
+                    host = {k: np.asarray(v) for k, v in outputs.items()}
                 done_t = time.perf_counter()
                 waited = done_t - wait_t0
-                request_trace.add(
-                    "readback.wait" if self.async_readback else "batch.readback",
-                    waited,
-                )
+                request_trace.add(fetch_phase, waited)
+            # Everything from here to the last set_result is this batch's
+            # delivery: one span, closed in the finally below.
+            delivery.enter_context(request_trace.span("batch.deliver"))
+            if stage_t0 is not None and issue_t0 is not None:
+                timeline = _Timeline(stage_t0, issue_t0, done_t)
             integ = self.integrity  # capture: detachable mid-flight
             if (
                 integ is not None
@@ -3287,6 +3391,9 @@ class DynamicBatcher:
                     d2h_wait_s=waited,
                 )
             window = max(done_t - issue_t0 if issue_t0 is not None else waited, waited)
+            # The closing span of the pair: `readback.wait` over
+            # `readback.window` is the completers' blocked share.
+            request_trace.add("readback.window", window)
             with self._cv:  # counters race across completer threads otherwise
                 self.stats.bytes_downloaded += downloaded
                 self.stats.readback_window_s += window
@@ -3362,7 +3469,7 @@ class DynamicBatcher:
                     # cache-served scores, and the plane's contract
                     # sketches only fresh ones (cache hits are excluded
                     # the same way).
-                    self._finish_row_batch(group, row_ctx, host)
+                    self._finish_row_batch(group, row_ctx, host, timeline)
                     return
             screened: dict[int, str] = {}
             if integ is not None and integ.config.screen:
@@ -3417,6 +3524,8 @@ class DynamicBatcher:
                             f"readback screen failed this request's rows: "
                             f"{screened[idx]}"
                         ))
+                    elif timeline is not None:
+                        timeline.resolve(it, sliced)
                     else:
                         it.future.set_result(sliced)
                 except InvalidStateError:
@@ -3424,6 +3533,8 @@ class DynamicBatcher:
                     # and set_result; that waiter is gone, but its race must
                     # not poison co-batched requests via the except below.
                     pass
+            if timeline is not None:
+                timeline.flush()
             if integ is not None:
                 # Screen-trip burst -> recovery escalation, AFTER delivery:
                 # the tripped rows already failed individually; the cycle
@@ -3448,6 +3559,7 @@ class DynamicBatcher:
                     if not it.future.done():
                         it.future.set_exception(exc)
         finally:
+            delivery.close()
             if util is not None:
                 util.depth_dec()
             if run_token is not None and run_fn is not None:

@@ -1774,6 +1774,10 @@ def build_stack(
         utilization=utilization_ledger,
         quality=quality_monitor,
     ).start()
+    # The batcher's phases also go into an open jax.profiler capture from
+    # here on (utils/tracing.py imports no jax, so the server hands it
+    # the annotation class).
+    tracing.bind_annotation(jax.profiler.TraceAnnotation)
     impl = PredictionServiceImpl(registry, batcher)
     if run_fn is not None and hasattr(run_fn, "snapshot"):
         # Mesh serving surface: /monitoring's `mesh` block and the
@@ -2042,6 +2046,7 @@ def build_stack(
         _prepare_kernels(servable)
         impl.warmup_complete = True
         return registry, batcher, impl, servable, mesh, watcher
+    load_t0 = time.perf_counter()
     if savedmodel:
         from ..interop import import_savedmodel
         from .warmup import warmup_file_for
@@ -2073,7 +2078,12 @@ def build_stack(
             config=model_config,
             num_fields=cfg.num_fields,
         )
+    # Parameter init / checkpoint load is asynchronous on the device:
+    # blocked here, where the warm-up would block on it anyway, so that
+    # the two stamps do not share it.
+    jax.block_until_ready(servable.params)
     warmup_t0 = time.perf_counter()
+    impl.startup["params_init_s"] = round(warmup_t0 - load_t0, 3)
     if cfg.warmup:
         log.info("warming bucket ladder %s", cfg.buckets)
         batcher.warmup(servable)
@@ -2093,6 +2103,7 @@ def build_stack(
 
 
 def serve(argv=None) -> None:
+    serve_t0 = time.perf_counter()
     parser = argparse.ArgumentParser(description="TPU-native PredictionService")
     parser.add_argument("--config", help="TOML config file ([server] section)")
     parser.add_argument("--checkpoint", help="servable checkpoint dir (train.save_servable)")
@@ -2507,8 +2518,12 @@ def serve(argv=None) -> None:
 
     # A host-ops library that fails to build is a start-up error, not a
     # silent switch to the numpy host path (DTS_TPU_NO_NATIVE=1 opts out).
+    native_t0 = time.perf_counter()
     native.ensure()
     compile_cache = enable_compile_cache()
+    backend_t0 = time.perf_counter()
+    jax.devices()  # the first call brings the backend (the TPU runtime) up
+    backend_init_s = time.perf_counter() - backend_t0
     registry, batcher, impl, servable, mesh, watcher = build_stack(
         cfg,
         checkpoint=args.checkpoint,
@@ -2530,6 +2545,8 @@ def serve(argv=None) -> None:
         integrity_config=integrity_config,
     )
     impl.compile_cache = compile_cache
+    impl.startup["native_build_s"] = round(backend_t0 - native_t0, 3)
+    impl.startup["backend_init_s"] = round(backend_init_s, 3)
     if impl.lifecycle is not None:
         # The CLI server drives the controller with its background thread
         # (ticks + the fine-tune publisher cadence); embedded callers and
@@ -2575,6 +2592,8 @@ def serve(argv=None) -> None:
         uds_path=transport_config.uds_path or None,
     )
     server.start()
+    # Health answers SERVING from here (the warm-up is complete).
+    impl.startup["to_serving_s"] = round(time.perf_counter() - serve_t0, 3)
     if transport_config.uds_path:
         log.info("gRPC also on unix:%s (co-located transport)",
                  transport_config.uds_path)

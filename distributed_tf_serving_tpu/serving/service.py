@@ -176,6 +176,11 @@ class PredictionServiceImpl:
         # (utils/runtime.py CompileCacheStats). None = not recorded.
         self.warmup_s: float | None = None
         self.compile_cache = None
+        # Start-up as stamps, in seconds, for the runtime block's `startup`
+        # (which adds `warmup_s` above): build_stack writes `params_init_s`,
+        # serve() `backend_init_s`, `native_build_s` and `to_serving_s`. A
+        # stamp nobody took (an embedded stack has no serve()) is absent.
+        self.startup: dict[str, float] = {}
 
     def _arena(self):
         """The calling thread's EncodeArena, or None when the plane is
@@ -199,8 +204,9 @@ class PredictionServiceImpl:
     def runtime_stats(self) -> dict:
         """What this process runs on, as jax reports it — platform,
         device_kind, device count, library versions — plus the load-time
-        compile wall, persistent-cache traffic and whether the native host
-        ops are loaded: the `runtime` block in /monitoring. jax falls back
+        compile wall, the start-up's stamps (`startup`), persistent-cache
+        traffic and whether the native host ops are loaded: the `runtime`
+        block in /monitoring. jax falls back
         to the CPU with only a warning when it finds no accelerator; this
         block is where an operator (and chip_smoke.py) sees that."""
         from .. import native
@@ -208,6 +214,7 @@ class PredictionServiceImpl:
 
         block = describe_devices()
         block["warmup_s"] = self.warmup_s
+        block["startup"] = {**self.startup, "warmup_s": self.warmup_s}
         block["compile_cache"] = (
             self.compile_cache.snapshot()
             if self.compile_cache is not None else None
@@ -764,6 +771,7 @@ class PredictionServiceImpl:
                 criticality=criticality, _prune_k=prune_k,
             )
             out = fut.result(timeout=timeout)
+            self._note_resumed(fut)
             self._consume_future_degraded(fut)
             return out
         except Exception as e:  # noqa: BLE001 — translator re-raises non-batcher
@@ -797,10 +805,21 @@ class PredictionServiceImpl:
             out = await asyncio.wait_for(
                 asyncio.wrap_future(fut), timeout=timeout
             )
+            self._note_resumed(fut)
             self._consume_future_degraded(fut)
             return out
         except Exception as e:  # noqa: BLE001 — translator re-raises non-batcher
             raise self._translate_batcher_error(e, fut) from e
+
+    @staticmethod
+    def _note_resumed(fut) -> None:
+        """The last segment of the request timeline (batcher._Timeline):
+        `req.resume`, from the completer's set_result to this handler
+        running again. A future no batch resolved (a score-cache hit, a
+        coalesced waiter) carries no stamp and adds nothing."""
+        resolved_t = getattr(fut, "dts_resolved_t", None)
+        if resolved_t is not None:
+            request_trace.add("req.resume", time.perf_counter() - resolved_t)
 
     @staticmethod
     def _consume_future_degraded(fut) -> None:

@@ -748,6 +748,13 @@ class ServerMetrics:
                 "batches": batcher_stats.batches,
                 "requests": batcher_stats.requests,
                 "mean_occupancy": round(batcher_stats.mean_occupancy, 3),
+                # The ratios' raw terms (mean_occupancy above,
+                # readback_overlap_fraction below), so that a scraper can
+                # take either over a window of its own as a delta.
+                "candidates": batcher_stats.candidates,
+                "padded_candidates": batcher_stats.padded_candidates,
+                "readback_window_s": round(batcher_stats.readback_window_s, 6),
+                "readback_blocked_s": round(batcher_stats.readback_blocked_s, 6),
                 "mean_requests_per_batch": round(batcher_stats.mean_requests_per_batch, 2),
                 "max_queue_depth": batcher_stats.max_queue_depth,
                 # D2H transfer attribution (output compaction + async

@@ -31,9 +31,18 @@ Tracing is OFF by default and gated on one module bool: every hot-path
 hook is a single global read when disabled (the bench gate is <=1%
 overhead with tracing off).
 
-PhaseTrace keeps its original role (aggregate phase means with ~50ns
-overhead), and profile_trace() still wraps a block in a jax.profiler trace
-for XLA-level deep dives.
+PhaseTrace keeps its original role (aggregate phase means, always on,
+served by /monitoring?section=phases). It also speaks on the profiler's
+clock: once a server has bound jax's TraceAnnotation (`bind_annotation`,
+called by serving.server.build_stack; this module imports no jax, the
+jax-free client imports it), the phases of the batcher's own threads
+(`wait.*`, `batch.*`, `readback.*`, `cache.*`: all synchronous, none spans
+an `await`) are also written into an open jax.profiler capture under their
+own names, so a device idle gap can be read against what the host was doing.
+`predict.*`, `cascade.*` and `req.*` stay on perf_counter alone: the first
+two wrap an `await` in the coroutine servers, where an annotation would
+mis-nest on the event-loop thread, and `req.*` are differences of stamps
+taken on several threads.
 """
 
 from __future__ import annotations
@@ -55,6 +64,56 @@ from collections import defaultdict, deque
 
 _ENABLED = False  # per-request tracing; flipped by enable()/disable()
 
+# jax.profiler.TraceAnnotation once a server has bound it, else None: one
+# global read per span when unbound, and when bound with no capture open
+# one call of its native `is_enabled()`. The annotation object itself is
+# made only inside a capture: made for every span, capture or not, it
+# cost dcn_v2_ref43-rank 1.8% of its p50 (PERF.md section 6, PR 24).
+_ANNOTATION = None
+_ON_PROFILER = ("wait.", "batch.", "readback.", "cache.")
+
+
+def bind_annotation(annotation_cls) -> None:
+    """Let the phases of `_ON_PROFILER` open `annotation_cls(name)` (jax's
+    TraceAnnotation) around themselves while `annotation_cls.is_enabled()`
+    says a capture is open. Process-wide, like the capture it writes into;
+    None unbinds."""
+    global _ANNOTATION
+    _ANNOTATION = annotation_cls
+
+
+def annotation(phase: str):
+    """Context manager that puts `phase` on the profiler's clock and
+    nothing else: for the callers that time the interval themselves and
+    `add` it (the batcher's waits, its readback fetch, its delivery)."""
+    cls = _ANNOTATION
+    if cls is None or not phase.startswith(_ON_PROFILER) or not cls.is_enabled():
+        return _NOOP
+    return cls(phase)
+
+
+class _PhaseSpan:
+    """PhaseTrace.span's context manager: one pair of clock reads, the
+    annotation outside them so that its own cost is not the phase's."""
+
+    __slots__ = ("_trace", "_phase", "_annotation", "_t0")
+
+    def __init__(self, trace: "PhaseTrace", phase: str):
+        self._trace = trace
+        self._phase = phase
+        self._annotation = annotation(phase)
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return None
+
+    def __exit__(self, *exc):
+        seconds = time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc)
+        self._trace.add(self._phase, seconds)
+        return False
+
 
 class PhaseTrace:
     """Accumulates wall time per named phase, aggregated across requests."""
@@ -64,13 +123,8 @@ class PhaseTrace:
         self._counts: dict[str, int] = defaultdict(int)
         self._lock = threading.Lock()
 
-    @contextlib.contextmanager
-    def span(self, phase: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add(phase, time.perf_counter() - t0)
+    def span(self, phase: str) -> _PhaseSpan:
+        return _PhaseSpan(self, phase)
 
     def add(self, phase: str, seconds: float) -> None:
         """Record an externally timed duration under `phase`. For callers
@@ -96,6 +150,15 @@ class PhaseTrace:
                 if cur is not None:
                     cur.add_interval(phase, end - seconds, end)
 
+    def add_many(self, entries) -> None:
+        """Record (phase, total seconds, count) triples under one lock:
+        sums a caller has already formed over several requests (the
+        batcher's `req.*` timeline of one batch). Aggregate only."""
+        with self._lock:
+            for phase, seconds, count in entries:
+                self._totals[phase] += seconds
+                self._counts[phase] += count
+
     def snapshot(self) -> dict[str, dict]:
         with self._lock:
             return {
@@ -113,18 +176,6 @@ class PhaseTrace:
         with self._lock:
             self._totals.clear()
             self._counts.clear()
-
-
-@contextlib.contextmanager
-def profile_trace(log_dir: str):
-    """jax.profiler trace around a block (XLA + host timeline)."""
-    import jax
-
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
 
 
 # Process-wide default trace used by the serving path.
@@ -323,7 +374,8 @@ def enabled() -> bool:
 
 
 class _NoopSpanCtx:
-    """Returned by start_span/start_root when tracing is disabled: one
+    """Returned by start_span/start_root when tracing is disabled, and by
+    annotation() for a phase that is not on the profiler's clock: one
     shared instance, no allocation on the disabled hot path."""
 
     __slots__ = ()
