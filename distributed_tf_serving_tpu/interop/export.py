@@ -139,6 +139,7 @@ def export_servable(checkpoint_dir: str, out_dir: str, validate: bool = True) ->
     import numpy as np
     from jax.experimental import jax2tf
 
+    from ..models.embeddings import unpack_params
     from ..train.checkpoint import load_servable
 
     servable = load_servable(checkpoint_dir)
@@ -171,7 +172,9 @@ def export_servable(checkpoint_dir: str, out_dir: str, validate: bool = True) ->
         )
     F = config.num_fields
     vocab = config.vocab_size
-    params = jax.tree.map(np.asarray, servable.params)
+    # The artifact holds the logical [V, D] table, as import_savedmodel's
+    # templates expect it; load_servable hands it over in the serving shape.
+    params = jax.tree.map(np.asarray, unpack_params(servable.params, config.embed_dim))
 
     def forward(p, ids32, wts, dense=None):
         batch = {"feat_ids": ids32, "feat_wts": wts}

@@ -39,6 +39,7 @@ import numpy as np
 log = logging.getLogger("dts_tpu.interop")
 
 from ..models.base import ModelConfig, build_model
+from ..models.embeddings import pack_params
 from ..models.registry import Servable, Signature, TensorSpec
 
 SERVE_TAG = "serve"
@@ -828,6 +829,8 @@ def import_savedmodel(
             kind, exc, generic_config.num_fields, generic_config.embed_dim,
             generic_config.mlp_dims,
         )
+    # Host arrays still: the table takes its serving shape by reshape.
+    params = pack_params(params, model.config.embed_dim)
     return Servable(
         name=name, version=version, model=model, params=params, signatures=signatures
     )

@@ -67,12 +67,14 @@ def cross_apply(layers, x0: jax.Array, compute_dtype) -> jax.Array:
 def _build(config: ModelConfig) -> Model:
     d = config.num_fields * config.embed_dim
 
-    def init(rng):
+    def init(rng, packed: bool = False):
         k_emb, k_cross, k_mlp, k_out = jax.random.split(rng, 4)
         mlp = mlp_init(k_mlp, d, config.mlp_dims, config.pdtype)
         out_in = d + (config.mlp_dims[-1] if config.mlp_dims else 0)
         return {
-            "embedding": embedding_init(k_emb, config.vocab_size, config.embed_dim, config.pdtype),
+            "embedding": embedding_init(
+                k_emb, config.vocab_size, config.embed_dim, config.pdtype, packed
+            ),
             "cross": _cross_init(
                 k_cross, config.num_cross_layers, d, config.cross_full_matrix, config.pdtype
             ),
@@ -82,7 +84,9 @@ def _build(config: ModelConfig) -> Model:
 
     def apply(params, batch):
         cd = config.cdtype
-        emb = field_embed(params["embedding"], batch["feat_ids"], batch["feat_wts"], cd)
+        emb = field_embed(
+            params["embedding"], batch["feat_ids"], batch["feat_wts"], cd, config.embed_dim
+        )
         x0 = emb.reshape(emb.shape[0], d)  # [n, F*D]
         use_fused = (
             config.use_pallas_cross

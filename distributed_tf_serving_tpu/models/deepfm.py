@@ -28,12 +28,14 @@ def fm_second_order(emb: jax.Array) -> jax.Array:
 def build_deepfm(config: ModelConfig) -> Model:
     d = config.num_fields * config.embed_dim
 
-    def init(rng):
+    def init(rng, packed: bool = False):
         k_lin, k_emb, k_mlp, k_out = jax.random.split(rng, 4)
         return {
             "linear": jax.random.normal(k_lin, (config.vocab_size,), config.pdtype) * 0.01,
             "bias": jnp.zeros((), config.pdtype),
-            "embedding": embedding_init(k_emb, config.vocab_size, config.embed_dim, config.pdtype),
+            "embedding": embedding_init(
+                k_emb, config.vocab_size, config.embed_dim, config.pdtype, packed
+            ),
             "mlp": mlp_init(k_mlp, d, config.mlp_dims, config.pdtype),
             "out": dense_init(k_out, config.mlp_dims[-1], 1, config.pdtype),
         }
@@ -42,7 +44,7 @@ def build_deepfm(config: ModelConfig) -> Model:
         cd = config.cdtype
         ids, wts = batch["feat_ids"], batch["feat_wts"]
         first = sparse_linear(params["linear"], ids, wts)
-        emb = field_embed(params["embedding"], ids, wts, cd)
+        emb = field_embed(params["embedding"], ids, wts, cd, config.embed_dim)
         second = fm_second_order(emb)
         deep = dense_apply(params["out"], mlp_apply(params["mlp"], emb.reshape(emb.shape[0], d), cd), cd)[:, 0]
         logit = first + second + deep + params["bias"].astype(jnp.float32)

@@ -35,10 +35,10 @@ def build_dlrm(config: ModelConfig) -> Model:
     num_pairs = num_feat * (num_feat - 1) // 2
     top_in = D + num_pairs
 
-    def init(rng):
+    def init(rng, packed: bool = False):
         k_emb, k_bot, k_top, k_out = jax.random.split(rng, 4)
         return {
-            "embedding": embedding_init(k_emb, config.vocab_size, D, config.pdtype),
+            "embedding": embedding_init(k_emb, config.vocab_size, D, config.pdtype, packed),
             "bottom_mlp": mlp_init(k_bot, config.num_dense_features, config.bottom_mlp_dims, config.pdtype),
             "top_mlp": mlp_init(k_top, top_in, config.mlp_dims, config.pdtype),
             "out": dense_init(k_out, config.mlp_dims[-1], 1, config.pdtype),
@@ -51,7 +51,9 @@ def build_dlrm(config: ModelConfig) -> Model:
         if dense is None:
             dense = jnp.zeros((n, config.num_dense_features), jnp.float32)
         bot = mlp_apply(params["bottom_mlp"], dense, cd)  # [n, D]
-        emb = field_embed(params["embedding"], batch["feat_ids"], batch["feat_wts"], cd)
+        emb = field_embed(
+            params["embedding"], batch["feat_ids"], batch["feat_wts"], cd, config.embed_dim
+        )
         with jax.named_scope("interact"):
             z = jnp.concatenate([bot[:, None, :].astype(cd), emb], axis=1)  # [n, F+1, D]
             # Pairwise dot interactions: upper triangle of Z Z^T (excl. diagonal).

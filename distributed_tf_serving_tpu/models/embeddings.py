@@ -5,23 +5,51 @@ with per-feature weights (feat_ids/feat_wts, DCNClient.java:98-108). Here the
 embedding bag is explicit: a single [vocab, dim] table, ids folded into the
 vocab by modulo, gathered with jnp.take, and scaled by the feature weight.
 
-TPU notes: the gather lowers to a dynamic-gather XLA op that is
-HBM-bandwidth-bound; ids arrive [n, F] and the gather is batched over both
-axes at once (one gather of n*F rows) so XLA can tile it. The vocab axis is
-the sharding axis for the EP analog (SURVEY.md §2.4): under shard_map each
-chip owns vocab/num_chips rows and out-of-shard ids contribute zero, summed
-back with psum — see parallel/embedding_sharding.py.
+TPU notes (v5e, PERF.md PR 25): the gather is bound by rows, not by HBM
+bandwidth. One lookup of a whole 128-lane row costs 8-10 ns whatever its
+bytes (512-byte rows at 52-63 GB/s of the chip's 819); a [V, 16] float32
+table cannot be tiled row-major without padding 16 lanes to 128, so XLA
+stores it dimension 0 minor and a lookup of 16 strided floats costs 20-25 ns
+(2.5-3 GB/s). So a table narrower than a lane row is SERVED lane-packed,
+[V/P, 128] with P = 128 // D logical rows side by side (pack_table; the same
+bytes in the same order), and lookup_rows gathers the packed row and keeps
+its lanes. Model.init, checkpoints and exports keep the logical [V, D]; the
+loaders pack once, on the host or at init, never per request; field_embed
+reads P off the table's shape, so model.apply serves both trees. ids arrive
+[n, F] and the gather is batched over both axes at once (one gather of n*F
+rows). The vocab axis is the sharding axis for the EP analog (SURVEY.md
+§2.4): under shard_map each chip owns vocab/num_chips rows, packed or not,
+and out-of-shard ids contribute zero, summed back with psum — see
+parallel/embedding_sharding.py.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+LANES = 128  # one lane row of the TPU's (8, 128) tile
 
 
-def embedding_init(rng: jax.Array, vocab_size: int, embed_dim: int, dtype) -> jax.Array:
+def pack_factor(vocab_size: int, embed_dim: int) -> int:
+    """Logical rows per lane row: 128 // D when D is narrower than a lane
+    row and divides it and the factor divides V, else 1 (left as it is)."""
+    if embed_dim >= LANES or LANES % embed_dim:
+        return 1
+    p = LANES // embed_dim
+    return p if vocab_size % p == 0 else 1
+
+
+def embedding_init(
+    rng: jax.Array, vocab_size: int, embed_dim: int, dtype, packed: bool = False
+) -> jax.Array:
+    """[V, D] normal table; packed=True draws it in its serving shape
+    (pack_table's), the same values: the generator depends on the flat index
+    alone, and a table of gigabytes must not exist twice to be reshaped."""
+    p = pack_factor(vocab_size, embed_dim) if packed else 1
     # 1/sqrt(dim) scale keeps dot-product magnitudes O(1) for FM/two-tower.
-    return jax.random.normal(rng, (vocab_size, embed_dim), dtype) / jnp.asarray(
+    return jax.random.normal(rng, (vocab_size // p, p * embed_dim), dtype) / jnp.asarray(
         embed_dim**0.5, dtype
     )
 
@@ -55,20 +83,89 @@ def sparse_linear(
     )
 
 
+def pack_table(table, embed_dim: int):
+    """Logical [V, D] -> serving shape [V/P, P*D] (P logical rows side by
+    side in one lane row). Row-major, both are the same bytes in the same
+    order, so a host array packs by reshape at no cost. A table that is
+    packed already, or that pack_factor leaves alone, is returned as is."""
+    vocab, width = table.shape
+    p = pack_factor(vocab, embed_dim) if width == embed_dim else 1
+    return table.reshape(vocab // p, p * width) if p > 1 else table
+
+
+def unpack_table(table, embed_dim: int):
+    """Serving shape -> logical [V, D], what checkpoints and exports hold. A
+    packed table goes through the host, where the reshape is free (on the
+    device it is a relayout: a second copy of the table)."""
+    if table.shape[1] == embed_dim:
+        return table
+    return np.asarray(table).reshape(-1, embed_dim)
+
+
+def _convert_table(params, convert, embed_dim: int):
+    """`params` with convert(table, embed_dim) for its embedding table, every
+    other leaf as it is; a tree without one (an imported graph) passes through."""
+    if not isinstance(params, dict) or "embedding" not in params:
+        return params
+    return {**params, "embedding": convert(params["embedding"], embed_dim)}
+
+
+def pack_params(params, embed_dim: int):
+    """A zoo model's params as they are served: the table lane-packed."""
+    return _convert_table(params, pack_table, embed_dim)
+
+
+def unpack_params(params, embed_dim: int):
+    """pack_params undone: the tree as checkpoints and exports hold it."""
+    return _convert_table(params, unpack_table, embed_dim)
+
+
+def lookup_rows(table: jax.Array, rows: jax.Array, embed_dim: int, dtype) -> jax.Array:
+    """table[rows] in `dtype`, for a logical or a packed table, bit for bit.
+
+    table  [V/P, P*D]; P is read off the shape (1: a logical table)
+    rows   [...] int32 in [0, V)
+    returns [..., D]
+
+    Packed, row r is lanes (r % P) * D ... + D of packed row r // P: one
+    gather of whole lane rows (cast on the way out, so the [..., 128]
+    intermediate is in `dtype`), a lane mask, and one [128, D] 0/1 matmul
+    that folds the kept lanes onto D columns. Each output is one kept value
+    plus zeros, so the sum is exact in any dtype (`highest`: float32 passes
+    whole through the MXU). The variants measured against this one are in
+    PERF.md (PR 25)."""
+    p = table.shape[1] // embed_dim
+    if p == 1:
+        return jnp.take(table, rows, axis=0).astype(dtype)
+    # P is a power of two (D divides 128): shift and mask, not divide.
+    wide = jnp.take(table, rows >> (p.bit_length() - 1), axis=0).astype(dtype)
+    lane = jnp.arange(LANES, dtype=rows.dtype)
+    keep = lane // embed_dim == (rows & (p - 1))[..., None]
+    fold = (lane[:, None] % embed_dim == jnp.arange(embed_dim, dtype=rows.dtype)).astype(dtype)
+    return jnp.einsum(
+        "...l,ld->...d",
+        jnp.where(keep, wide, jnp.zeros((), dtype)),
+        fold,
+        preferred_element_type=dtype,
+        precision="highest",
+    )
+
+
 def field_embed(
     table: jax.Array,
     feat_ids: jax.Array,
     feat_wts: jax.Array,
     compute_dtype,
+    embed_dim: int,
 ) -> jax.Array:
     """Weighted per-field embedding lookup.
 
-    table     [V, D]
+    table     [V, D], or packed [V/P, P*D] (pack_table)
     feat_ids  [n, F] int
     feat_wts  [n, F] float
     returns   [n, F, D] in compute_dtype
     """
     with jax.named_scope("embed"):
-        rows = fold_ids(feat_ids, table.shape[0])
-        emb = jnp.take(table, rows, axis=0)  # [n, F, D]
-        return emb.astype(compute_dtype) * feat_wts[..., None].astype(compute_dtype)
+        vocab = table.shape[0] * (table.shape[1] // embed_dim)
+        emb = lookup_rows(table, fold_ids(feat_ids, vocab), embed_dim, compute_dtype)
+        return emb * feat_wts[..., None].astype(compute_dtype)

@@ -37,11 +37,11 @@ from .embeddings import embedding_init, field_embed
 def build_generic(config: ModelConfig) -> Model:
     d = config.num_fields * config.embed_dim
 
-    def init(rng):
+    def init(rng, packed: bool = False):
         k_emb, k_mlp, k_out = jax.random.split(rng, 3)
         return {
             "embedding": embedding_init(
-                k_emb, config.vocab_size, config.embed_dim, config.pdtype
+                k_emb, config.vocab_size, config.embed_dim, config.pdtype, packed
             ),
             "mlp": mlp_init(k_mlp, d, config.mlp_dims, config.pdtype),
             "out": dense_init(
@@ -51,7 +51,9 @@ def build_generic(config: ModelConfig) -> Model:
 
     def apply(params, batch):
         cd = config.cdtype
-        emb = field_embed(params["embedding"], batch["feat_ids"], batch["feat_wts"], cd)
+        emb = field_embed(
+            params["embedding"], batch["feat_ids"], batch["feat_wts"], cd, config.embed_dim
+        )
         x0 = emb.reshape(emb.shape[0], d)
         h = mlp_apply(params["mlp"], x0, cd) if config.mlp_dims else x0
         logit = dense_apply(params["out"], h, cd)[:, 0]

@@ -158,6 +158,16 @@ class Servable:
     def __call__(self, batch: Batch) -> dict[str, jnp.ndarray]:
         return self.model.apply(self.params, batch)
 
+    @property
+    def embedding_pack(self) -> int | None:
+        """Logical rows per row of the embedding table as it is held
+        (models/embeddings.py pack_table): 1 for a logical table, None for
+        a tree without one (imported graphs)."""
+        table = self.params.get("embedding") if isinstance(self.params, dict) else None
+        if getattr(table, "ndim", 0) != 2:
+            return None
+        return table.shape[1] // self.model.config.embed_dim
+
     def signature_def_map(self) -> dict:
         return {k: v.to_signature_def() for k, v in self.signatures.items()}
 
@@ -277,6 +287,12 @@ class ServableRegistry:
     def models(self) -> dict[str, list[int]]:
         with self._lock:
             return {k: sorted(v) for k, v in self._servables.items()}
+
+    def embedding_packs(self) -> dict[str, int | None]:
+        """"name:version" -> Servable.embedding_pack of every loaded servable."""
+        with self._lock:
+            loaded = [s for versions in self._servables.values() for s in versions.values()]
+        return {f"{s.name}:{s.version}": s.embedding_pack for s in loaded}
 
     def labels(self, name: str) -> dict[str, int]:
         with self._lock:

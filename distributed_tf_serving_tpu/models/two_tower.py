@@ -27,10 +27,12 @@ def build_two_tower(config: ModelConfig) -> Model:
         raise ValueError(f"num_user_fields={nu} must be < num_fields={config.num_fields}")
     du, di = nu * config.embed_dim, ni * config.embed_dim
 
-    def init(rng):
+    def init(rng, packed: bool = False):
         k_emb, k_user, k_item = jax.random.split(rng, 3)
         return {
-            "embedding": embedding_init(k_emb, config.vocab_size, config.embed_dim, config.pdtype),
+            "embedding": embedding_init(
+                k_emb, config.vocab_size, config.embed_dim, config.pdtype, packed
+            ),
             "user_mlp": mlp_init(k_user, du, config.mlp_dims, config.pdtype),
             "item_mlp": mlp_init(k_item, di, config.mlp_dims, config.pdtype),
             "temperature": jnp.asarray(10.0, config.pdtype),
@@ -43,7 +45,9 @@ def build_two_tower(config: ModelConfig) -> Model:
 
     def apply(params, batch):
         cd = config.cdtype
-        emb = field_embed(params["embedding"], batch["feat_ids"], batch["feat_wts"], cd)
+        emb = field_embed(
+            params["embedding"], batch["feat_ids"], batch["feat_wts"], cd, config.embed_dim
+        )
         u = _tower(params["user_mlp"], emb[:, :nu], cd)
         v = _tower(params["item_mlp"], emb[:, nu:], cd)
         score = jnp.sum(u * v, axis=-1) * params["temperature"].astype(jnp.float32)

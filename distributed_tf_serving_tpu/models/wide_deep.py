@@ -19,12 +19,14 @@ from .embeddings import embedding_init, field_embed, sparse_linear
 def build_wide_deep(config: ModelConfig) -> Model:
     d = config.num_fields * config.embed_dim
 
-    def init(rng):
+    def init(rng, packed: bool = False):
         k_wide, k_emb, k_mlp, k_out = jax.random.split(rng, 4)
         return {
             "wide": jax.random.normal(k_wide, (config.vocab_size,), config.pdtype) * 0.01,
             "wide_bias": jnp.zeros((), config.pdtype),
-            "embedding": embedding_init(k_emb, config.vocab_size, config.embed_dim, config.pdtype),
+            "embedding": embedding_init(
+                k_emb, config.vocab_size, config.embed_dim, config.pdtype, packed
+            ),
             "mlp": mlp_init(k_mlp, d, config.mlp_dims, config.pdtype),
             "out": dense_init(k_out, config.mlp_dims[-1], 1, config.pdtype),
         }
@@ -35,7 +37,7 @@ def build_wide_deep(config: ModelConfig) -> Model:
         # Wide: sum of per-id scalar weights, feature-weighted (f32).
         wide = sparse_linear(params["wide"], ids, wts) + params["wide_bias"].astype(jnp.float32)
         # Deep: MLP over flattened weighted embeddings.
-        emb = field_embed(params["embedding"], ids, wts, cd)
+        emb = field_embed(params["embedding"], ids, wts, cd, config.embed_dim)
         xd = mlp_apply(params["mlp"], emb.reshape(emb.shape[0], d), cd)
         logit = dense_apply(params["out"], xd, cd)[:, 0] + wide
         return {"prediction_node": jax.nn.sigmoid(logit), "logits": logit}

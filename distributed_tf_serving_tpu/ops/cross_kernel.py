@@ -388,7 +388,7 @@ def build_fused_serve(params, config, *, interpret: bool = False,
 
     def apply_fn(params, batch):
         emb = field_embed(
-            params["embedding"], batch["feat_ids"], batch["feat_wts"], cd
+            params["embedding"], batch["feat_ids"], batch["feat_wts"], cd, D
         )
         n = emb.shape[0]
         bn = min(row_tile, _pad_to(n, 16))

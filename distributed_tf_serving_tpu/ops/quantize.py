@@ -31,9 +31,12 @@ f32 and int8 executables coexist per bucket (the autotune harness in
 ops/autotune.py decides per bucket which one live traffic gets).
 
 Embedding tables are deliberately NOT quantized: the gather is
-row-sparse (HBM reads only the looked-up rows), so int8 tables save
-little live bandwidth while adding a dequant to the dominant op; the
-dense matmuls are where the bytes-per-step win is.
+row-sparse (HBM reads only the looked-up rows) and, on the v5e, bound by
+the number of rows, not by their bytes (8-10 ns a lookup of a whole lane
+row at 6-8% of the HBM bandwidth, PERF.md PR 25), so int8 tables would
+save no time while adding a dequant to the dominant op; what pays there is
+the table's layout (models/embeddings.py pack_table). The dense matmuls
+are where the bytes-per-step win is.
 """
 
 from __future__ import annotations

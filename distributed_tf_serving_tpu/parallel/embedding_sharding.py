@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..models.embeddings import lookup_rows
 from .mesh import DATA_AXIS, MODEL_AXIS
 
 
@@ -133,32 +134,39 @@ def sharded_field_embed(
     feat_wts: jax.Array,
     mesh: Mesh,
     compute_dtype,
+    embed_dim: int,
 ) -> jax.Array:
     """Weighted field lookup with the table sharded over the model axis and
     candidates sharded over the data axis.
 
-    table     [V, D] (V divisible by mesh model-axis size)
+    table     [V, D], or lane-packed [V/P, P*D] (models/embeddings.py
+              pack_table: still vocab-major, a shard still owns a contiguous
+              range of logical rows); rows divisible by the model-axis size
     feat_ids  [n, F] int32, already folded into [0, V)
     feat_wts  [n, F] float
     returns   [n, F, D] in compute_dtype, candidate-sharded
     """
-    vocab = table.shape[0]
     k = mesh.shape[MODEL_AXIS]
-    if vocab % k != 0:
-        raise ValueError(f"vocab {vocab} not divisible by model-axis size {k}")
+    if table.shape[0] % k != 0:
+        raise ValueError(
+            f"table rows {table.shape[0]} not divisible by model-axis size {k}"
+        )
+    pack = table.shape[1] // embed_dim
 
     def local(table_shard, ids_blk, wts_blk):
-        # table_shard: [V/k, D] — this chip's contiguous vocab rows.
-        vshard = table_shard.shape[0]
+        # table_shard: this chip's contiguous vocab rows, [V/k, D] logical.
+        vshard = table_shard.shape[0] * pack
         lo = jax.lax.axis_index(MODEL_AXIS) * vshard
         local_ids = ids_blk - lo
         in_shard = (local_ids >= 0) & (local_ids < vshard)
         # Clipped gather stays in-bounds; the mask zeroes out-of-shard rows,
         # so the psum over the model axis reassembles exact lookups.
-        emb = jnp.take(table_shard, jnp.clip(local_ids, 0, vshard - 1), axis=0)
+        emb = lookup_rows(
+            table_shard, jnp.clip(local_ids, 0, vshard - 1), embed_dim, compute_dtype
+        )
         emb = jnp.where(in_shard[..., None], emb, jnp.zeros((), emb.dtype))
         emb = jax.lax.psum(emb, MODEL_AXIS)
-        return emb.astype(compute_dtype) * wts_blk[..., None].astype(compute_dtype)
+        return emb * wts_blk[..., None].astype(compute_dtype)
 
     return shard_map(
         local,

@@ -55,6 +55,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..models.embeddings import unpack_params
 from ..models.registry import Servable
 from ..ops.transfer import (
     compact_outputs_device,
@@ -63,7 +64,7 @@ from ..ops.transfer import (
     transfer_spec,
     unpack_device,
 )
-from .mesh import DATA_AXIS, candidate_sharding
+from .mesh import DATA_AXIS, MODEL_AXIS, candidate_sharding
 from .sharding import batch_shardings, place_params
 
 
@@ -140,10 +141,17 @@ class ShardedExecutor:
                     if partition_rules_for(model_kind) is not None
                     else "generic"
                 )
+                params = servable.params
+                table = params.get("embedding") if isinstance(params, dict) else None
+                if table is not None and table.shape[0] % self.mesh.shape[MODEL_AXIS]:
+                    # A lane-packed table (models/embeddings.py) whose
+                    # packed rows the model axis does not divide is placed
+                    # logical, where the vocab may still divide.
+                    params = unpack_params(params, servable.model.config.embed_dim)
                 self._placed[key] = (
                     servable.params,
                     place_params(
-                        servable.params, self.mesh, self.tensor_parallel,
+                        params, self.mesh, self.tensor_parallel,
                         model_kind=model_kind or None,
                     ),
                 )

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import json
 import logging
 import signal
@@ -913,7 +914,8 @@ def load_demo_servable(
     wins over keyword overrides."""
     config = config or ModelConfig(name=name, **config_overrides)
     model = build_model(kind, config)
-    params = jax.jit(model.init)(jax.random.PRNGKey(seed))
+    # Drawn in its serving shape: a table of gigabytes never exists twice.
+    params = jax.jit(functools.partial(model.init, packed=True))(jax.random.PRNGKey(seed))
     jax.block_until_ready(params)
     dense = config.num_dense_features if kind == "dlrm" else None
     servable = Servable(
@@ -2084,6 +2086,10 @@ def build_stack(
     jax.block_until_ready(servable.params)
     warmup_t0 = time.perf_counter()
     impl.startup["params_init_s"] = round(warmup_t0 - load_t0, 3)
+    log.info(
+        "loaded %s v%d in %.3fs: embedding_pack %s",
+        servable.name, servable.version, warmup_t0 - load_t0, servable.embedding_pack,
+    )
     if cfg.warmup:
         log.info("warming bucket ladder %s", cfg.buckets)
         batcher.warmup(servable)

@@ -204,7 +204,8 @@ class PredictionServiceImpl:
     def runtime_stats(self) -> dict:
         """What this process runs on, as jax reports it — platform,
         device_kind, device count, library versions — plus the load-time
-        compile wall, the start-up's stamps (`startup`), persistent-cache
+        compile wall, the start-up's stamps (`startup`), the pack factor of
+        each loaded servable's embedding table (`embedding_pack`), persistent-cache
         traffic and whether the native host ops are loaded: the `runtime`
         block in /monitoring. jax falls back
         to the CPU with only a warning when it finds no accelerator; this
@@ -215,6 +216,7 @@ class PredictionServiceImpl:
         block = describe_devices()
         block["warmup_s"] = self.warmup_s
         block["startup"] = {**self.startup, "warmup_s": self.warmup_s}
+        block["embedding_pack"] = self.registry.embedding_packs()
         block["compile_cache"] = (
             self.compile_cache.snapshot()
             if self.compile_cache is not None else None

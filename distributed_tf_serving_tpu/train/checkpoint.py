@@ -12,6 +12,7 @@ layouts; restore_args can re-place onto a mesh).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import pathlib
 
@@ -19,6 +20,7 @@ import jax
 import orbax.checkpoint as ocp
 
 from ..models.base import ModelConfig, build_model
+from ..models.embeddings import pack_params, unpack_params
 from ..models.registry import Servable, ctr_signatures
 
 MANIFEST = "servable.json"
@@ -26,7 +28,9 @@ PARAMS_DIR = "params"
 
 
 def save_servable(path, servable: Servable, kind: str) -> None:
-    """Write params + manifest. `kind` is the model-zoo family name.
+    """Write params + manifest. `kind` is the model-zoo family name. The
+    file holds the logical [V, D] table whatever shape the servable serves
+    (models/embeddings.py pack_table): a packed table is unpacked on the way.
 
     Write order is a commit protocol: params first, manifest LAST — the
     manifest's existence marks the checkpoint complete, so a concurrent
@@ -41,14 +45,21 @@ def save_servable(path, servable: Servable, kind: str) -> None:
         "config": dataclasses.asdict(servable.model.config),
     }
     with ocp.StandardCheckpointer() as ckptr:
-        ckptr.save((path / PARAMS_DIR).absolute(), servable.params, force=True)
+        ckptr.save(
+            (path / PARAMS_DIR).absolute(),
+            unpack_params(servable.params, servable.model.config.embed_dim),
+            force=True,
+        )
     (path / MANIFEST).write_text(json.dumps(manifest, indent=2))
 
 
 def load_servable(
     path, mesh=None, tensor_parallel: bool = False, host: bool = False
 ) -> Servable:
-    """Reconstruct a Servable; with a mesh, params restore pre-placed
+    """Reconstruct a Servable, its embedding table in the serving shape
+    (pack_table: the table is read to the host, reshaped there at no cost
+    and placed once, so it never exists twice on the device; interop/export.py
+    unpacks). With a mesh, params restore pre-placed
     (vocab tables over the model axis; dense weights model-axis split too
     when tensor_parallel) instead of replicated — restoring straight into
     the serving layout avoids a second full-tree resharding pass.
@@ -93,24 +104,31 @@ def load_servable(
                     lambda _: ocp.RestoreArgs(restore_type=np.ndarray), target
                 ),
             )
+        params = pack_params(params, config.embed_dim)
     else:
+        # Shapes as served; a table whose packed rows the model axis does
+        # not divide stays logical, as the mesh executor would make it.
+        served = jax.eval_shape(functools.partial(model.init, packed=True), jax.random.PRNGKey(0))
         if mesh is not None:
+            from ..parallel.mesh import MODEL_AXIS
             from ..parallel.sharding import param_shardings
 
-            shardings = param_shardings(target, mesh, tensor_parallel)
+            if served["embedding"].shape[0] % mesh.shape[MODEL_AXIS]:
+                served = target
+            shardings = param_shardings(served, mesh, tensor_parallel)
         else:
             # Name the placement: without one orbax replays the sharding
             # file, i.e. the SAVING process's devices — a checkpoint written
             # by a CPU process would not restore on the chip.
             here = jax.sharding.SingleDeviceSharding(jax.devices()[0])
-            shardings = jax.tree.map(lambda _: here, target)
-        target = jax.tree.map(
-            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
-            target,
-            shardings,
+            shardings = jax.tree.map(lambda _: here, served)
+        restore_args = jax.tree.map(lambda sh: ocp.ArrayRestoreArgs(sharding=sh), shardings)
+        restore_args["embedding"] = ocp.RestoreArgs(restore_type=np.ndarray)
+        with ocp.Checkpointer(ocp.PyTreeCheckpointHandler()) as ckptr:
+            params = ckptr.restore((path / PARAMS_DIR).absolute(), restore_args=restore_args)
+        params["embedding"] = jax.device_put(
+            params["embedding"].reshape(served["embedding"].shape), shardings["embedding"]
         )
-        with ocp.StandardCheckpointer() as ckptr:
-            params = ckptr.restore((path / PARAMS_DIR).absolute(), target)
 
     dense = config.num_dense_features if manifest["kind"] == "dlrm" else None
     return Servable(

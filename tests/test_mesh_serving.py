@@ -169,6 +169,30 @@ def test_executor_pads_non_divisible_batches(rows):
         assert snap["executor"]["pad_batches"] == 0
 
 
+@pytest.mark.parametrize(
+    "vocab,dim,placed_rows",
+    # 1024 x 4: 32 packed rows, split in two. 1000 x 16: the 125 packed
+    # rows do not split in two, so the executor places the logical table.
+    [(1024, 4, 32), (1000, 16, 1000)],
+    ids=["packed", "packed_rows_do_not_divide"],
+)
+def test_executor_serves_lane_packed_table_bit_identical(vocab, dim, placed_rows):
+    from distributed_tf_serving_tpu.models.embeddings import pack_params
+
+    cfg = dataclasses.replace(CFG, vocab_size=vocab, embed_dim=dim)
+    logical = _servable(cfg=cfg)
+    served = dataclasses.replace(logical, params=pack_params(logical.params, dim))
+    assert logical.embedding_pack == 1 and served.embedding_pack == 128 // dim
+    mesh = make_mesh(8, model_parallel=2)
+    ex = ShardedExecutor(mesh)
+    arrays = _arrays(24, seed=12, cfg=cfg)
+    want = np.asarray(ex(logical, _prepared(arrays, cfg))["prediction_node"])
+    got = np.asarray(ex(served, _prepared(arrays, cfg))["prediction_node"])
+    np.testing.assert_array_equal(got, want)
+    table = ex._prepare(served)[1]["embedding"]
+    assert table.shape[0] == placed_rows and table.sharding.spec == P(MODEL_AXIS, None)
+
+
 def test_batcher_arbitrary_buckets_over_mesh_bit_identical():
     """A bucket ladder with NON-mesh-shaped rungs serves over the mesh
     with scores identical to the single-device execution."""

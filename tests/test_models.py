@@ -129,3 +129,84 @@ def test_two_tower_user_fields_shared():
         ]
     )
     assert out[0] == pytest.approx(out[2], rel=1e-6)
+
+
+# ---------------------------------------------------- lane-packed tables
+
+# (vocab, dim, pack factor the serving shape must have): every width that
+# divides a lane row packs; 128 is a lane row already; 24 does not divide
+# 128 and 1001 rows are not a multiple of 8, so both stay logical.
+PACK_CASES = [
+    (1024, 8, 16), (1024, 16, 8), (1024, 32, 4), (1024, 64, 2), (1024, 128, 1),
+    (1024, 24, 1), (1001, 16, 1),
+]
+
+
+@pytest.mark.parametrize("table_dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("vocab,dim,pack", PACK_CASES)
+def test_packed_lookup_is_bit_identical(vocab, dim, pack, table_dtype):
+    from distributed_tf_serving_tpu.models.embeddings import (
+        field_embed, pack_factor, pack_table, unpack_table,
+    )
+    from distributed_tf_serving_tpu.serving.batcher import fold_ids_host
+
+    table = jax.random.normal(jax.random.PRNGKey(5), (vocab, dim), table_dtype)
+    packed = pack_table(table, dim)
+    assert pack_factor(vocab, dim) == pack
+    assert packed.shape == (vocab // pack, dim * pack)
+    assert pack_table(packed, dim) is packed  # packing twice changes nothing
+    np.testing.assert_array_equal(np.asarray(unpack_table(packed, dim)), np.asarray(table))
+    # A host array packs by reshape, no copy.
+    host = np.asarray(table)
+    assert pack == 1 or np.shares_memory(pack_table(host, dim), host)
+
+    rng = np.random.RandomState(6)
+    wire = rng.randint(0, 1 << 40, size=(9, 5)).astype(np.int64)  # beyond V: the host fold
+    wire[0, :3] = [0, vocab - 1, vocab + 3]
+    ids = np.concatenate([
+        fold_ids_host(wire, vocab),
+        np.asarray([[0, vocab - 1, vocab, vocab + 3, (1 << 31) - 1]], np.int32),  # the device fold
+    ])
+    wts = jnp.asarray(rng.rand(*ids.shape), jnp.float32)
+    want = np.asarray(jnp.take(table, jnp.asarray(ids % vocab), axis=0))
+    for cd in (jnp.float32, jnp.bfloat16):
+        look = jax.jit(lambda t, cd=cd: field_embed(t, jnp.asarray(ids), wts, cd, dim))
+        logical, served = look(table), look(packed)
+        assert served.dtype == cd and served.shape == (*ids.shape, dim)
+        np.testing.assert_array_equal(np.asarray(served), np.asarray(logical))
+        np.testing.assert_array_equal(
+            np.asarray(logical),
+            np.asarray(jnp.asarray(want).astype(cd) * wts[..., None].astype(cd)),
+        )
+
+
+@pytest.mark.parametrize("kind", model_kinds())
+def test_apply_on_packed_tree_equals_logical(kind):
+    """Every registered family serves both trees, bit for bit: init drawn in
+    the serving shape holds the values of the logical init, and pack_params
+    of the logical tree is the same tree."""
+    from distributed_tf_serving_tpu.models.embeddings import pack_params, unpack_params
+
+    cfg = ModelConfig(
+        num_fields=43, vocab_size=1024, embed_dim=8, mlp_dims=(32, 16),
+        bottom_mlp_dims=(16, 8), num_cross_layers=2,
+    )
+    model = build_model(kind, cfg)
+    logical = jax.jit(model.init)(jax.random.PRNGKey(7))
+    drawn = jax.jit(lambda k: model.init(k, packed=True))(jax.random.PRNGKey(7))
+    packed = pack_params(logical, cfg.embed_dim)
+    assert logical["embedding"].shape == (1024, 8)
+    assert packed["embedding"].shape == drawn["embedding"].shape == (64, 128)
+    jax.tree.map(
+        lambda a, b: np.testing.assert_array_equal(np.asarray(a), np.asarray(b)), packed, drawn
+    )
+    np.testing.assert_array_equal(
+        np.asarray(unpack_params(packed, cfg.embed_dim)["embedding"]),
+        np.asarray(logical["embedding"]),
+    )
+    batch = make_batch(16)
+    want = jax.jit(model.apply)(logical, batch)
+    got = jax.jit(model.apply)(packed, batch)
+    assert set(got) == set(want)
+    for key in want:
+        np.testing.assert_array_equal(np.asarray(got[key]), np.asarray(want[key]))

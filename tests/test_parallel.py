@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from distributed_tf_serving_tpu.models import ModelConfig, Servable, build_model, ctr_signatures
-from distributed_tf_serving_tpu.models.embeddings import field_embed, fold_ids
+from distributed_tf_serving_tpu.models.embeddings import field_embed, fold_ids, pack_table
 from distributed_tf_serving_tpu.parallel import (
     DATA_AXIS,
     MODEL_AXIS,
@@ -123,26 +123,32 @@ def test_shard_map_score_order_preserved():
     np.testing.assert_allclose(out, _golden(sv, arrays), rtol=1e-6)
 
 
+@pytest.mark.parametrize("packed", [False, True], ids=["logical", "packed"])
 @pytest.mark.parametrize("model_parallel", [2, 4, 8])
-def test_sharded_field_embed_exact(model_parallel):
+def test_sharded_field_embed_exact(model_parallel, packed):
     """Explicit EP lookup (masked local gather + psum) must equal the
-    single-device lookup exactly."""
+    single-device lookup exactly, on the logical table and on the
+    lane-packed one (32 logical rows a packed row, 32 packed rows: a shard
+    still owns a contiguous range of logical rows)."""
     mesh = make_mesh(8, model_parallel=model_parallel)
     rng = np.random.RandomState(0)
     table = jnp.asarray(rng.randn(1024, 4), jnp.float32)
     ids = jnp.asarray(rng.randint(0, 1024, size=(16, 8)), jnp.int32)
     wts = jnp.asarray(rng.rand(16, 8), jnp.float32)
 
-    want = np.asarray(field_embed(table, ids, wts, jnp.float32))
+    want = np.asarray(field_embed(table, ids, wts, jnp.float32, 4))
+    if packed:
+        table = pack_table(table, 4)
+        assert table.shape == (32, 128)
     table_sharded = jax.device_put(
         table, jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec(MODEL_AXIS, None))
     )
     got = np.asarray(
         jax.jit(
-            lambda t, i, w: sharded_field_embed(t, i, w, mesh, jnp.float32)
+            lambda t, i, w: sharded_field_embed(t, i, w, mesh, jnp.float32, 4)
         )(table_sharded, ids, wts)
     )
-    np.testing.assert_allclose(got, want, rtol=1e-6)
+    np.testing.assert_array_equal(got, want)
 
 
 def test_annotation_path_matches_explicit_path():
@@ -169,11 +175,13 @@ def test_annotation_path_matches_explicit_path():
         jnp.asarray(prepared["feat_wts"]),
         mesh,
         jnp.float32,
+        CFG.embed_dim,
     )
     np.testing.assert_allclose(
         np.asarray(emb),
         np.asarray(field_embed(table, jnp.asarray(prepared["feat_ids"]),
-                               jnp.asarray(prepared["feat_wts"]), jnp.float32)),
+                               jnp.asarray(prepared["feat_wts"]), jnp.float32,
+                               CFG.embed_dim)),
         rtol=1e-6,
     )
     np.testing.assert_allclose(annotated, _golden(sv, arrays), rtol=1e-6)

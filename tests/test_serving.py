@@ -876,3 +876,54 @@ def test_closed_loop_prepared_mode(three_backends):
 
     with pytest.raises(ValueError):
         asyncio.run(prepared_pool_rejected())
+
+
+# --------------------------------------------------- lane-packed tables
+
+
+@pytest.mark.parametrize(
+    "kind,embed_dim,pack",
+    # The two benchmark shapes at a small vocab: DCN-v2's 16-wide rows pack
+    # eight to a lane row; DLRM's 128-wide rows are lane rows already.
+    [("dcn_v2", 16, 8), ("dlrm", 128, 1)],
+    ids=["dcn_v2_d16", "dlrm_d128"],
+)
+def test_demo_servable_is_served_lane_packed(kind, embed_dim, pack):
+    """build_stack's demo servable holds its table in the serving shape,
+    the values of the logical init; it scores like the logical tree; the
+    runtime block's `embedding_pack` says which shape is held."""
+    from distributed_tf_serving_tpu.serving.server import build_stack
+    from distributed_tf_serving_tpu.utils.config import ServerConfig
+
+    vocab = 4096
+    model_config = ModelConfig(
+        name="M", num_fields=8, vocab_size=vocab, embed_dim=embed_dim, mlp_dims=(16,),
+        bottom_mlp_dims=(16, embed_dim), num_cross_layers=1,
+    )
+    cfg = ServerConfig(
+        model_kind=kind, model_name="M", num_fields=8, buckets=(16,), warmup=False
+    )
+    _registry, batcher, impl, sv, _mesh, _watcher = build_stack(cfg, model_config=model_config)
+    try:
+        table = sv.params["embedding"]
+        assert table.shape == (vocab // pack, 128)
+        assert sv.embedding_pack == pack
+        assert impl.runtime_stats()["embedding_pack"] == {"M:1": pack}
+        logical = jax.jit(sv.model.init)(jax.random.PRNGKey(0))
+        assert logical["embedding"].shape == (vocab, embed_dim)
+        np.testing.assert_array_equal(
+            np.asarray(table).reshape(vocab, embed_dim), np.asarray(logical["embedding"])
+        )
+        rng = np.random.RandomState(4)
+        arrays = {
+            "feat_ids": rng.randint(0, 1 << 40, size=(11, 8)).astype(np.int64),
+            "feat_wts": rng.rand(11, 8).astype(np.float32),
+        }
+        if kind == "dlrm":
+            arrays["dense_features"] = rng.rand(11, 13).astype(np.float32)
+        got = batcher.submit(sv, arrays).result(timeout=120)["prediction_node"]
+        batch = dict(arrays, feat_ids=fold_ids_host(arrays["feat_ids"], vocab))
+        want = jax.jit(sv.model.apply)(logical, batch)["prediction_node"]
+        np.testing.assert_allclose(got, np.asarray(want), rtol=1e-6, atol=1e-6)
+    finally:
+        batcher.stop()

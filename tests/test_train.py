@@ -105,6 +105,17 @@ def test_checkpoint_roundtrip(tmp_path):
     save_servable(tmp_path / "ckpt", sv, kind="dcn_v2")
     loaded = load_servable(tmp_path / "ckpt")
     assert loaded.name == "DCN" and loaded.version == 7
+    # Saved logical, loaded in the serving shape (lane-packed), same scores;
+    # saved again from the packed servable, the file is the logical one.
+    vocab, dim = CFG.vocab_size, CFG.embed_dim
+    assert sv.embedding_pack == 1 and loaded.embedding_pack == 128 // dim
+    assert loaded.params["embedding"].shape == (vocab * dim // 128, 128)
+    save_servable(tmp_path / "again", loaded, kind="dcn_v2")
+    host = load_servable(tmp_path / "again", host=True)
+    assert isinstance(host.params["embedding"], np.ndarray) and host.embedding_pack == 128 // dim
+    np.testing.assert_array_equal(
+        host.params["embedding"].reshape(vocab, dim), np.asarray(sv.params["embedding"])
+    )
     # Compare to the built model's config (build_model("dcn_v2") flips
     # cross_full_matrix on), not the pre-build CFG.
     assert loaded.model.config == sv.model.config
@@ -134,7 +145,14 @@ def test_checkpoint_restores_onto_mesh(tmp_path):
     loaded = load_servable(tmp_path / "ckpt", mesh=mesh)
     emb = loaded.params["embedding"]
     assert emb.sharding.spec == jax.sharding.PartitionSpec(MODEL_AXIS, None)
-    np.testing.assert_array_equal(np.asarray(emb), np.asarray(sv.params["embedding"]))
+    # Lane-packed and split over the model axis: each shard a contiguous
+    # range of logical rows.
+    assert emb.shape == (CFG.vocab_size * CFG.embed_dim // 128, 128)
+    assert emb.addressable_shards[0].data.shape == (emb.shape[0] // 4, 128)
+    np.testing.assert_array_equal(
+        np.asarray(emb).reshape(CFG.vocab_size, CFG.embed_dim),
+        np.asarray(sv.params["embedding"]),
+    )
 
 
 def test_trainer_cli_writes_servable_checkpoint(tmp_path):
