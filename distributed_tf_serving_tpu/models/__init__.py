@@ -2,7 +2,7 @@
 
 Model families cover every BASELINE.json config: dcn / dcn_v2 (the
 reference's served model, DCNClient.java:33), wide_deep, deepfm, two_tower,
-dlrm. All share the reference serving contract feat_ids/feat_wts [n, F] ->
+dlrm, dlrm_dcnv2. All share the reference serving contract feat_ids/feat_wts [n, F] ->
 prediction_node [n].
 """
 

@@ -46,6 +46,13 @@ class ModelConfig:
     # DCN / DCN-v2
     num_cross_layers: int = 3
     cross_full_matrix: bool = False  # False => DCN-v1 rank-1 cross, True => DCN-v2
+    # dlrm_dcnv2: rank r of the low-rank cross layers (the [d, d] matrix is
+    # the product V_l W_l, V_l [d, r], W_l [r, d]); 0 => a full matrix.
+    cross_low_rank: int = 0
+    # Embedding bags (dlrm_dcnv2): ids per bag, laid end to end across the
+    # num_fields wire columns in field order (columns 0..h_0-1 are bag 0,
+    # ...), so sum(multi_hot_sizes) == num_fields. Empty => one id a field.
+    multi_hot_sizes: tuple[int, ...] = ()
     # two-tower
     num_user_fields: int = 8
     # DLRM
@@ -105,6 +112,9 @@ class Model:
     # (parallel/embedding_sharding.MODEL_PARTITION_RULES) — unknown kinds
     # fall back to the generic path-name layout.
     kind: str = ""
+    # True when the signature carries `dense_features` [n, num_dense_features]
+    # beside the id/weight pair (the DLRM families).
+    takes_dense: bool = False
 
 
 # ---------------------------------------------------------------------------
@@ -185,7 +195,7 @@ def register_model(kind: str):
 
 def build_model(kind: str, config: ModelConfig | None = None, **overrides) -> Model:
     """Instantiate a model family by kind: dcn, dcn_v2, wide_deep, deepfm,
-    two_tower, dlrm."""
+    two_tower, dlrm, dlrm_dcnv2."""
     if kind not in _BUILDERS:
         raise KeyError(f"unknown model kind {kind!r}; have {sorted(_BUILDERS)}")
     if config is None:

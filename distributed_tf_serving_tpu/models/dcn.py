@@ -9,6 +9,8 @@ embedding bag.
 Cross layers (per Wang et al.):
   v1 (rank-1):     x_{l+1} = x0 * (x_l . w_l) + b_l + x_l       w_l: [d]
   v2 (full-rank):  x_{l+1} = x0 * (x_l @ W_l + b_l) + x_l       W_l: [d, d]
+  v2 (low-rank):   x_{l+1} = x0 * ((x_l @ V_l) @ W_l + b_l) + x_l  V_l: [d, r], W_l: [r, d]
+                   (torchrec LowRankCrossNet; the dlrm_dcnv2 family, models/dlrm.py)
 
 The v2 matmul is the MXU hot op; it runs in compute_dtype (bf16 default) with
 f32 accumulation. The fused-elementwise Pallas variant lives in
@@ -24,10 +26,18 @@ from .base import Model, ModelConfig, dense_apply, dense_init, mlp_apply, mlp_in
 from .embeddings import embedding_init, field_embed
 
 
-def _cross_init(rng, num_layers: int, d: int, full_matrix: bool, dtype):
+def _cross_init(rng, num_layers: int, d: int, full_matrix: bool, dtype, low_rank: int = 0):
     layers = []
     for _ in range(num_layers):
         rng, sub = jax.random.split(rng)
+        if low_rank:
+            kv, kw = jax.random.split(sub)
+            layers.append({
+                "v": jax.random.normal(kv, (d, low_rank), dtype) / jnp.asarray(d**0.5, dtype),
+                "w": jax.random.normal(kw, (low_rank, d), dtype) / jnp.asarray(low_rank**0.5, dtype),
+                "b": jnp.zeros((d,), dtype),
+            })
+            continue
         if full_matrix:
             w = jax.random.normal(sub, (d, d), dtype) / jnp.asarray(d**0.5, dtype)
         else:
@@ -38,16 +48,24 @@ def _cross_init(rng, num_layers: int, d: int, full_matrix: bool, dtype):
 
 def cross_apply(layers, x0: jax.Array, compute_dtype) -> jax.Array:
     """Apply the stack of cross layers; x0 is [n, d] in compute_dtype.
-    Accepts both the float {"w","b"} layers and the int8 weight-only
-    quantized {"qw","qscale","b"} form (ops/quantize.py): the per-channel
+    Accepts the float {"w","b"} layers, the low-rank {"v","w","b"} form
+    (xw = (x @ v) @ w, the [n, r] intermediate rounded to compute_dtype for
+    the second matmul) and the int8 weight-only quantized
+    {"qw","qscale","b"} form (ops/quantize.py): the per-channel
     scale folds into the f32 xw before the elementwise update, so the
     quantized stack differs from f32 only by the weight rounding."""
     x = x0
     for p in layers:
         b = p["b"].astype(jnp.float32)
+        h = x
+        if "v" in p:  # low-rank: down to [n, r] first
+            h = jax.lax.dot_general(
+                x, p["v"].astype(compute_dtype),
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            ).astype(compute_dtype)
         if "qw" in p:  # quantized DCN-v2 (v1 rank-1 layers never quantize)
             xw = jax.lax.dot_general(
-                x, p["qw"].astype(compute_dtype),
+                h, p["qw"].astype(compute_dtype),
                 (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
             ) * p["qscale"].astype(jnp.float32)
             x = (x0.astype(jnp.float32) * (xw + b) + x.astype(jnp.float32)).astype(compute_dtype)
@@ -55,7 +73,7 @@ def cross_apply(layers, x0: jax.Array, compute_dtype) -> jax.Array:
         w = p["w"].astype(compute_dtype)
         if w.ndim == 2:  # DCN-v2
             xw = jax.lax.dot_general(
-                x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+                h, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
             )
             x = (x0.astype(jnp.float32) * (xw + b) + x.astype(jnp.float32)).astype(compute_dtype)
         else:  # DCN-v1

@@ -21,6 +21,11 @@ rows). The vocab axis is the sharding axis for the EP analog (SURVEY.md
 §2.4): under shard_map each chip owns vocab/num_chips rows, packed or not,
 and out-of-shard ids contribute zero, summed back with psum — see
 parallel/embedding_sharding.py.
+
+A multi-hot field (ModelConfig.multi_hot_sizes, the dlrm_dcnv2 family) is an
+embedding bag: its ids take as many wire columns as the bag holds, the
+lookup is the same one gather over all columns, and pool_bags sums each
+bag's weighted rows to one vector.
 """
 
 from __future__ import annotations
@@ -151,21 +156,49 @@ def lookup_rows(table: jax.Array, rows: jax.Array, embed_dim: int, dtype) -> jax
     )
 
 
+def pool_bags(emb: jax.Array, wts: jax.Array, bag_sizes: tuple[int, ...]) -> jax.Array:
+    """Weighted sum of each bag's rows.
+
+    emb   [n, F, D], the rows as looked up, F = sum(bag_sizes)
+    wts   [n, F] in emb's dtype
+    returns [n, len(bag_sizes), D] in emb's dtype
+
+    One [B, F] x [F, D] matmul a candidate row whose left operand holds bag
+    b's weights in bag b's columns and zero elsewhere: the weighting rides the
+    MXU with the sum, each product is exact in the float32 accumulator, and
+    the result is rounded once (a bag of 100 rows summed in bfloat16 would
+    lose a digit). Of the formulations timed on the chip THROUGH the upload's
+    unpack (static slices, a [F, B] 0/1 matmul, a segment-sum, one gather a
+    bag) this is the fastest at every bucket (PERF.md, PR 26)."""
+    with jax.named_scope("pool"):
+        bag_of = np.repeat(np.arange(len(bag_sizes)), bag_sizes)
+        member = np.arange(len(bag_sizes))[:, None] == bag_of[None, :]  # [B, F]
+        mix = wts[:, None, :] * jnp.asarray(member, emb.dtype)  # [n, B, F]
+        return jnp.einsum(
+            "nbf,nfd->nbd", mix, emb, preferred_element_type=jnp.float32
+        ).astype(emb.dtype)
+
+
 def field_embed(
     table: jax.Array,
     feat_ids: jax.Array,
     feat_wts: jax.Array,
     compute_dtype,
     embed_dim: int,
+    bag_sizes: tuple[int, ...] = (),
 ) -> jax.Array:
-    """Weighted per-field embedding lookup.
+    """Weighted per-field embedding lookup, pooled where a field is a bag.
 
     table     [V, D], or packed [V/P, P*D] (pack_table)
     feat_ids  [n, F] int
     feat_wts  [n, F] float
-    returns   [n, F, D] in compute_dtype
+    bag_sizes ids per bag, the bags laid end to end over the F columns
+              (ModelConfig.multi_hot_sizes); empty or all ones: one id a field
+    returns   [n, F, D] in compute_dtype, or [n, len(bag_sizes), D] pooled
     """
     with jax.named_scope("embed"):
         vocab = table.shape[0] * (table.shape[1] // embed_dim)
         emb = lookup_rows(table, fold_ids(feat_ids, vocab), embed_dim, compute_dtype)
+        if any(size != 1 for size in bag_sizes):
+            return pool_bags(emb, feat_wts.astype(compute_dtype), bag_sizes)
         return emb * feat_wts[..., None].astype(compute_dtype)

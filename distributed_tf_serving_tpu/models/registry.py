@@ -168,6 +168,21 @@ class Servable:
             return None
         return table.shape[1] // self.model.config.embed_dim
 
+    @property
+    def lookups_per_row(self) -> int | None:
+        """Embedding rows one candidate row reads: the wire's id columns
+        (None where embedding_pack is)."""
+        return None if self.embedding_pack is None else self.model.config.num_fields
+
+    @property
+    def bags(self) -> int | None:
+        """Vectors those rows are pooled to: the embedding bags of
+        multi_hot_sizes, else one a column."""
+        config = self.model.config
+        if self.embedding_pack is None:
+            return None
+        return len(config.multi_hot_sizes) or config.num_fields
+
     def signature_def_map(self) -> dict:
         return {k: v.to_signature_def() for k, v in self.signatures.items()}
 
@@ -288,11 +303,12 @@ class ServableRegistry:
         with self._lock:
             return {k: sorted(v) for k, v in self._servables.items()}
 
-    def embedding_packs(self) -> dict[str, int | None]:
-        """"name:version" -> Servable.embedding_pack of every loaded servable."""
+    def per_servable(self, attr: str) -> dict:
+        """"name:version" -> that property of every loaded servable
+        (embedding_pack, lookups_per_row, bags)."""
         with self._lock:
             loaded = [s for versions in self._servables.values() for s in versions.values()]
-        return {f"{s.name}:{s.version}": s.embedding_pack for s in loaded}
+        return {f"{s.name}:{s.version}": getattr(s, attr) for s in loaded}
 
     def labels(self, name: str) -> dict[str, int]:
         with self._lock:

@@ -84,7 +84,8 @@ def quantize_params(params, _top: bool = True):
     {"w": [in,out], "b": [out]} for its int8 weight-only form
     {"qw", "qscale", "b"}. Covers the DCN cross stack (full-matrix v2
     layers), MLP lists, and output heads across the zoo; everything else —
-    embedding tables, DCN-v1 rank-1 cross vectors, biases — passes through
+    embedding tables, DCN-v1 rank-1 cross vectors, low-rank cross layers
+    ({"v", "w", "b"}: two matrices, one dict), biases — passes through
     unchanged (shared by reference, not copied: quantization never mutates
     the servable's live params)."""
     if isinstance(params, dict):
@@ -92,6 +93,7 @@ def quantize_params(params, _top: bool = True):
         if (
             w is not None
             and "b" in params
+            and "v" not in params
             and getattr(w, "ndim", 0) == 2
             and np.issubdtype(np.asarray(w).dtype, np.floating)
         ):

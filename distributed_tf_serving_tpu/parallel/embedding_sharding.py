@@ -64,6 +64,7 @@ MODEL_PARTITION_RULES: dict[str, tuple[tuple[str, P], ...]] = {
     "dcn": (("^embedding$", P(MODEL_AXIS, None)),),
     "dcn_v2": (("^embedding$", P(MODEL_AXIS, None)),),
     "dlrm": (("^embedding$", P(MODEL_AXIS, None)),),
+    "dlrm_dcnv2": (("^embedding$", P(MODEL_AXIS, None)),),
     "two_tower": (
         ("^embedding$", P(MODEL_AXIS, None)),
         ("^temperature$", P()),  # scalar: explicit, never sharded

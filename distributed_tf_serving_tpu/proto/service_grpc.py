@@ -27,6 +27,19 @@ LARGE_MESSAGE_CHANNEL_OPTIONS = (
     ("grpc.max_send_message_length", 64 * 1024 * 1024),
     ("grpc.http2.max_frame_size", 1 * 1024 * 1024),
     ("grpc.optimization_target", "throughput"),
+    # Re-dial a refused or lost connection about once a second, and give a
+    # dial a second (grpc calls that bound min_reconnect_backoff). grpc's
+    # defaults back off from 1 s by 1.6 up to 120 s and give a dial 20 s; a
+    # channel in back-off fails its RPCs at once, and a channel that no
+    # thread is polling notices a finished dial only at grpc's background
+    # poll, every 5 s. So a client that began dialing before its replica
+    # listened, or whose replica restarts, reached it 7-17 s after it
+    # listened; with these, at the next background poll (PERF.md, PR 26: on
+    # the chip's machine, 5.5-12 s between the server's SERVING and the
+    # generators' first answer). Client-side; a server ignores them.
+    ("grpc.initial_reconnect_backoff_ms", 1000),
+    ("grpc.min_reconnect_backoff_ms", 1000),
+    ("grpc.max_reconnect_backoff_ms", 1000),
 )
 
 # Server-side tolerance for client keepalive pings (the client channels run

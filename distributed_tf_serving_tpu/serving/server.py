@@ -917,7 +917,7 @@ def load_demo_servable(
     # Drawn in its serving shape: a table of gigabytes never exists twice.
     params = jax.jit(functools.partial(model.init, packed=True))(jax.random.PRNGKey(seed))
     jax.block_until_ready(params)
-    dense = config.num_dense_features if kind == "dlrm" else None
+    dense = config.num_dense_features if model.takes_dense else None
     servable = Servable(
         name=name,
         version=version,
@@ -1373,8 +1373,13 @@ def build_stack(
     elastic_config=None,
     cascade_config=None,
     integrity_config=None,
+    on_impl=None,
 ):
     """Registry + batcher (+ mesh executor) + impl from a ServerConfig.
+    on_impl(impl), when given, is called once the impl exists and BEFORE
+    the parameters are made and the ladder is warmed: serve() listens from
+    there, health NOT_SERVING and inference refused UNAVAILABLE until the
+    warm-up is complete.
     model_config (the TOML [model] section) pins the architecture for the
     demo and SavedModel-import paths; checkpoints carry their own.
     model_base_path switches to TF-Serving's versioned-directory lifecycle
@@ -1881,6 +1886,8 @@ def build_stack(
     # NOT_SERVING until the load+warmup phase below completes (standard
     # probes and the client's half-open probing key off this).
     impl.warmup_complete = False
+    if on_impl is not None:
+        on_impl(impl)
 
     if cascade_armed:
         # Multi-stage ranking cascade (serving/cascade.py, ISSUE 19): the
@@ -2530,26 +2537,52 @@ def serve(argv=None) -> None:
     backend_t0 = time.perf_counter()
     jax.devices()  # the first call brings the backend (the TPU runtime) up
     backend_init_s = time.perf_counter() - backend_t0
-    registry, batcher, impl, servable, mesh, watcher = build_stack(
-        cfg,
-        checkpoint=args.checkpoint,
-        savedmodel=args.savedmodel,
-        model_config=model_config,
-        model_base_path=args.model_base_path,
-        cache_config=cache_config,
-        overload_config=overload_config,
-        utilization_config=utilization_config,
-        quality_config=quality_config,
-        lifecycle_config=lifecycle_config,
-        batching_config=batching_config,
-        transport_config=transport_config,
-        recovery_config=recovery_config,
-        kernels_config=kernels_config,
-        mesh_config=mesh_config,
-        elastic_config=elastic_config,
-        cascade_config=cascade_config,
-        integrity_config=integrity_config,
-    )
+    metrics = ServerMetrics(window_s=obs.window_seconds)
+    transport: dict = {}
+
+    def listen(impl: PredictionServiceImpl) -> None:
+        # Listen before the parameters are made and the ladder is warmed
+        # (health NOT_SERVING, inference refused UNAVAILABLE until then): a
+        # client that has been dialing since before this process existed is
+        # deep in its reconnect back-off and notices a connection only when
+        # its channel is next polled, so the seconds of load and warm-up
+        # are the time it gets to connect in (PERF.md, PR 26).
+        transport["server"], transport["port"] = create_server(
+            impl, f"{cfg.host}:{cfg.port}", cfg.max_workers, metrics,
+            credentials=credentials,
+            uds_path=transport_config.uds_path or None,
+        )
+        transport["server"].start()
+
+    try:
+        registry, batcher, impl, servable, mesh, watcher = build_stack(
+            cfg,
+            on_impl=listen,
+            checkpoint=args.checkpoint,
+            savedmodel=args.savedmodel,
+            model_config=model_config,
+            model_base_path=args.model_base_path,
+            cache_config=cache_config,
+            overload_config=overload_config,
+            utilization_config=utilization_config,
+            quality_config=quality_config,
+            lifecycle_config=lifecycle_config,
+            batching_config=batching_config,
+            transport_config=transport_config,
+            recovery_config=recovery_config,
+            kernels_config=kernels_config,
+            mesh_config=mesh_config,
+            elastic_config=elastic_config,
+            cascade_config=cascade_config,
+            integrity_config=integrity_config,
+        )
+    except BaseException:
+        # A load or warm-up that fails must not leave a listening server
+        # (its threads would keep the process alive).
+        if "server" in transport:
+            transport["server"].stop(0)
+        raise
+    server, port = transport["server"], transport["port"]
     impl.compile_cache = compile_cache
     impl.startup["native_build_s"] = round(backend_t0 - native_t0, 3)
     impl.startup["backend_init_s"] = round(backend_init_s, 3)
@@ -2591,14 +2624,8 @@ def serve(argv=None) -> None:
             " — GET /tracez on the REST surface",
             obs.trace_buffer, obs.trace_sample_rate, obs.trace_slowest_n,
         )
-    metrics = ServerMetrics(window_s=obs.window_seconds)
-    server, port = create_server(
-        impl, f"{cfg.host}:{cfg.port}", cfg.max_workers, metrics,
-        credentials=credentials,
-        uds_path=transport_config.uds_path or None,
-    )
-    server.start()
-    # Health answers SERVING from here (the warm-up is complete).
+    # The server has listened since before the load (`listen` above); health
+    # has answered SERVING since build_stack returned.
     impl.startup["to_serving_s"] = round(time.perf_counter() - serve_t0, 3)
     if transport_config.uds_path:
         log.info("gRPC also on unix:%s (co-located transport)",

@@ -204,7 +204,9 @@ class PredictionServiceImpl:
     def runtime_stats(self) -> dict:
         """What this process runs on, as jax reports it — platform,
         device_kind, device count, library versions — plus the load-time
-        compile wall, the start-up's stamps (`startup`), the pack factor of
+        compile wall, the start-up's stamps (`startup`, with each loaded
+        servable's embedding rows a candidate row, `lookups_per_row`, and the
+        `bags` they pool to), the pack factor of
         each loaded servable's embedding table (`embedding_pack`), persistent-cache
         traffic and whether the native host ops are loaded: the `runtime`
         block in /monitoring. jax falls back
@@ -215,8 +217,13 @@ class PredictionServiceImpl:
 
         block = describe_devices()
         block["warmup_s"] = self.warmup_s
-        block["startup"] = {**self.startup, "warmup_s": self.warmup_s}
-        block["embedding_pack"] = self.registry.embedding_packs()
+        block["startup"] = {
+            **self.startup,
+            "warmup_s": self.warmup_s,
+            "lookups_per_row": self.registry.per_servable("lookups_per_row"),
+            "bags": self.registry.per_servable("bags"),
+        }
+        block["embedding_pack"] = self.registry.per_servable("embedding_pack")
         block["compile_cache"] = (
             self.compile_cache.snapshot()
             if self.compile_cache is not None else None
@@ -489,6 +496,14 @@ class PredictionServiceImpl:
                 "UNAVAILABLE",
                 "server is draining (shutdown in progress); retry against "
                 "another backend",
+            )
+        if not self.warmup_complete:
+            # serve() listens before the load and the warm-up (whose direct
+            # executions must not race live batches): health NOT_SERVING.
+            raise ServiceError(
+                "UNAVAILABLE",
+                "server is loading and warming up; retry against another "
+                "backend",
             )
 
     def is_configured(self, name: str) -> bool:

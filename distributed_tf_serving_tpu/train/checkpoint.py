@@ -74,8 +74,9 @@ def load_servable(
 
     path = pathlib.Path(path)
     manifest = json.loads((path / MANIFEST).read_text())
-    config = ModelConfig(**{**manifest["config"], "mlp_dims": tuple(manifest["config"]["mlp_dims"]),
-                            "bottom_mlp_dims": tuple(manifest["config"]["bottom_mlp_dims"])})
+    config = ModelConfig(**{
+        k: tuple(v) if isinstance(v, list) else v for k, v in manifest["config"].items()
+    })
     model = build_model(manifest["kind"], config)
 
     target = jax.eval_shape(model.init, jax.random.PRNGKey(0))
@@ -130,7 +131,7 @@ def load_servable(
             params["embedding"].reshape(served["embedding"].shape), shardings["embedding"]
         )
 
-    dense = config.num_dense_features if manifest["kind"] == "dlrm" else None
+    dense = config.num_dense_features if model.takes_dense else None
     return Servable(
         name=manifest["name"],
         version=manifest["version"],

@@ -347,8 +347,8 @@ def _monitoring(port, section):
 
 def test_cli_server_reports_startup_stamps_and_raw_counters(tmp_path):
     """The CLI server's /monitoring: `runtime.startup` holds the five
-    stamps serve() and build_stack take, `metrics.batcher` the raw terms
-    of the two ratios."""
+    stamps serve() and build_stack take (and each servable's lookups a row
+    and bags), `metrics.batcher` the raw terms of the two ratios."""
     grpc = pytest.importorskip("grpc")
     from distributed_tf_serving_tpu.client import build_predict_request
     from distributed_tf_serving_tpu.proto import PredictionServiceStub
@@ -377,7 +377,10 @@ def test_cli_server_reports_startup_stamps_and_raw_counters(tmp_path):
             except OSError:
                 time.sleep(0.5)
         assert runtime is not None, "the server never came up"
-        startup = runtime["startup"]
+        startup = dict(runtime["startup"])
+        # Beside the stamps: what each loaded servable looks up a candidate row.
+        assert startup.pop("lookups_per_row") == {"DCN:1": F}
+        assert startup.pop("bags") == {"DCN:1": F}
         assert set(startup) == {
             "backend_init_s", "params_init_s", "native_build_s", "warmup_s", "to_serving_s",
         }

@@ -545,6 +545,52 @@ def test_health_service_sync_server():
         batcher.stop()
 
 
+def test_server_started_from_build_stacks_hook_refuses_until_warm():
+    """serve() listens from build_stack's `on_impl` hook, before the load
+    and the warm-up: from there health answers NOT_SERVING and Predict is
+    refused UNAVAILABLE (the warm-up executes beside the batcher's thread,
+    so no live batch may run yet); once build_stack returns, both serve."""
+    from distributed_tf_serving_tpu.client import build_predict_request
+    from distributed_tf_serving_tpu.proto import PredictionServiceStub
+    from distributed_tf_serving_tpu.serving.server import build_stack
+    from distributed_tf_serving_tpu.utils.config import ServerConfig
+
+    cfg = ServerConfig(
+        model_kind="dcn_v2", model_name="DCN", num_fields=CFG.num_fields, buckets=(16,)
+    )
+    started: dict = {}
+    request = build_predict_request(_arrays(n=5), "DCN")
+
+    def listen(impl):
+        started["server"], port = create_server(impl, "127.0.0.1:0")
+        started["server"].start()
+        started["channel"] = grpc.insecure_channel(f"127.0.0.1:{port}")
+        status = health_proto.HealthStub(started["channel"]).Check(
+            health_proto.HealthCheckRequest(""), timeout=5
+        ).status
+        with pytest.raises(grpc.RpcError) as refused:
+            PredictionServiceStub(started["channel"]).Predict(request, timeout=5)
+        started["during"] = (status, refused.value.code(), refused.value.details())
+
+    _registry, batcher, impl, _sv, _mesh, _watcher = build_stack(
+        cfg, model_config=CFG, on_impl=listen
+    )
+    try:
+        status, code, details = started["during"]
+        assert status == health_proto.NOT_SERVING
+        assert code == grpc.StatusCode.UNAVAILABLE and "warming up" in details
+        assert impl.warmup_complete
+        assert health_proto.HealthStub(started["channel"]).Check(
+            health_proto.HealthCheckRequest(""), timeout=5
+        ).status == health_proto.SERVING
+        response = PredictionServiceStub(started["channel"]).Predict(request, timeout=60)
+        assert response.outputs["prediction_node"].tensor_shape.dim[0].size == 5
+    finally:
+        started["channel"].close()
+        started["server"].stop(0)
+        batcher.stop()
+
+
 def test_health_service_aio_server():
     from distributed_tf_serving_tpu.serving.server import create_server_async
 
@@ -623,6 +669,26 @@ def test_keepalive_channel_options():
     assert opts["grpc.keepalive_timeout_ms"] == 3_000
     assert opts["grpc.http2.max_pings_without_data"] == 0
     assert opts["grpc.keepalive_permit_without_calls"] == 1
+
+
+def test_channel_options_cap_the_reconnect_backoff():
+    """Every in-tree channel re-dials a refused or lost connection about
+    once a second and gives a dial a second: grpc's defaults (back-off from
+    1 s by 1.6 up to 120 s, 20 s for a dial) left the benchmark's generators
+    5.5-12 s between the server's SERVING and their first answer (PERF.md,
+    PR 26; measured on the chip's machine, where a channel nobody polls
+    notices a connection only every 5 s)."""
+    from distributed_tf_serving_tpu.proto.service_grpc import (
+        LARGE_MESSAGE_CHANNEL_OPTIONS,
+    )
+
+    assert isinstance(LARGE_MESSAGE_CHANNEL_OPTIONS, tuple)  # callers append to it
+    opts = dict(LARGE_MESSAGE_CHANNEL_OPTIONS)
+    assert opts["grpc.initial_reconnect_backoff_ms"] == 1000
+    assert opts["grpc.min_reconnect_backoff_ms"] == 1000  # the time one dial may take
+    assert opts["grpc.max_reconnect_backoff_ms"] == 1000
+    # grpc accepts them on a channel and on a server alike.
+    grpc.insecure_channel("127.0.0.1:1", options=LARGE_MESSAGE_CHANNEL_OPTIONS).close()
 
 
 def test_client_from_config_resilience_knobs():

@@ -88,3 +88,32 @@ def test_step_gathers_whole_lane_rows_of_the_packed_table(
     ), [line for line in text.splitlines() if "p__embedding__" in line][:6]
     # No table-sized temporary: nothing converts or copies the whole table.
     assert compiled.memory_analysis().temp_size_in_bytes < GIB // 8
+
+
+def test_bags_pool_in_one_matmul_fused_with_their_weights(one_chip, no_compile_cache):
+    """MLPerf DLRM-DCNv2 at its published widths (214 ids a row in 26 bags,
+    an 8 GiB table), bucket 4096: ONE gather of whole lane rows, cast on the
+    way out, and a pooling matmul that takes the weights in; no float32 copy
+    of the [n, 214, 128] rows (static slices summed in float32 kept one:
+    0.68 GB of temporaries at this bucket against 0.45, PERF.md, PR 26)."""
+    bags = (3, 2, 1, 2, 6, 1, 1, 1, 1, 7, 3, 8, 1, 6, 9, 5, 1, 1, 1, 12, 100, 27, 10, 3, 1, 1)
+    bucket = 4096
+    bag_model = build_model("dlrm_dcnv2", ModelConfig(
+        name="DCN", num_fields=sum(bags), multi_hot_sizes=bags, vocab_size=1 << 24, embed_dim=128,
+        bottom_mlp_dims=(512, 256, 128), mlp_dims=(1024, 1024, 512, 256), cross_low_rank=512,
+    ))
+    shapes = jax.eval_shape(functools.partial(bag_model.init, packed=True), jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), shapes
+    )
+    batch = {
+        "feat_ids": jax.ShapeDtypeStruct((bucket, sum(bags)), jnp.int32, sharding=one_chip),
+        "feat_wts": jax.ShapeDtypeStruct((bucket, sum(bags)), jnp.bfloat16, sharding=one_chip),
+        "dense_features": jax.ShapeDtypeStruct((bucket, 13), jnp.float32, sharding=one_chip),
+    }
+    compiled = (
+        jax.jit(lambda p, b: bag_model.apply(p, b)["prediction_node"]).lower(params, batch).compile()
+    )
+    text = compiled.as_text()
+    assert re.search(rf"bf16\[{sum(bags) * bucket},128\]\S* fusion\(%p__embedding__", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < GIB // 2
