@@ -60,9 +60,11 @@ def _build(so: pathlib.Path) -> None:
             f"native hostops build failed ({e}): "
             f"{detail.decode(errors='replace')[-2000:]}"
         ) from e
-    # Libraries of other source revisions are dead weight now.
+    # Libraries of other source revisions are dead weight now. Not the
+    # `.tmp<pid>.so` files: another process (a test worker, a second server)
+    # may be between its link and its rename of one.
     for old in so.parent.glob("libhostops*.so"):
-        if old != so:
+        if old != so and ".tmp" not in old.name:
             old.unlink(missing_ok=True)
 
 
@@ -131,6 +133,10 @@ def _load_locked(build: bool = True) -> ctypes.CDLL | None:
     ]
     lib.pack_u24_i32.argtypes = [ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p]
     lib.f32_to_bf16.argtypes = [ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p]
+    for form in _PLANE_FORMS.values():
+        getattr(lib, form).argtypes = [
+            ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p,
+        ]
     lib.hash128.argtypes = [ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p]
     lib.hash128_rows.argtypes = [
         ctypes.c_char_p, ctypes.c_int64,
@@ -140,7 +146,8 @@ def _load_locked(build: bool = True) -> ctypes.CDLL | None:
         ctypes.POINTER(ctypes.c_void_p), ctypes.c_void_p,
         ctypes.POINTER(ctypes.c_void_p), ctypes.c_void_p,
         ctypes.c_void_p, ctypes.c_int64,
-        ctypes.c_int64, ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p,
+        ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+        ctypes.c_void_p, ctypes.c_void_p,
     ]
     return lib
 
@@ -218,6 +225,34 @@ def pack_u24_i32(ids: np.ndarray) -> np.ndarray:
     return out
 
 
+# (packed bits, what is read: a 4-byte dtype by name, else the value's bytes)
+# -> the exported plane form.
+_PLANE_FORMS = {
+    (24, "int32"): "pack_planes_u24_i32",
+    (16, "float32"): "pack_planes_bf16_f32",
+    (16, 2): "pack_planes_raw16",
+    (8, 1): "pack_planes_raw8",
+}
+
+
+def pack_planes(arr: np.ndarray, bits: int, out: np.ndarray) -> None:
+    """One padded [n, ...] array -> its segment `out` (contiguous uint32) of
+    the combined upload, as row planes in whole words (hostops.cc
+    pack_planes; the numpy form of the same bytes is ops/transfer.py
+    pack_planes_numpy). bits 24: int32 ids to u24; bits 16: float32 to bf16
+    (RNE), any 2-byte dtype as it is; bits 8: any 1-byte dtype as it is.
+    One pass, GIL released."""
+    lib = _load()
+    assert lib is not None
+    arr = np.ascontiguousarray(arr)
+    wide = arr.dtype.itemsize == 4
+    form = _PLANE_FORMS.get((bits, arr.dtype.name if wide else arr.dtype.itemsize))
+    if form is None:
+        raise ValueError(f"pack_planes: {arr.dtype} cannot travel as {bits} bits")
+    n = arr.shape[0]
+    getattr(lib, form)(_ptr(arr), n, arr.size // max(n, 1), _ptr(out))
+
+
 def hash128(arr: np.ndarray) -> bytes:
     """16-byte content digest of a contiguous array's bytes (one pass)."""
     lib = _load()
@@ -270,8 +305,9 @@ def pack_batch_u24_bf16(
     vocab: int,
 ) -> np.ndarray:
     """Fused batch assembly (see hostops.cc): per-request [n_p, F] id/weight
-    arrays -> the final padded combined uint8 buffer
-    [bucket*F*3 u24 | bucket*F*2 bf16] in one pass per input, zero padding
+    arrays -> the final padded combined uint32 buffer
+    [four row planes of u24 ids in 3 words | two row planes of bf16 wts in 1]
+    (ops/transfer.py's word format) in one pass per input, zero padding
     included. ids int64 are folded mod vocab; int32 (compact wire) pass
     through; wts f32 are RNE-cast; bf16 copied. The per-part arrays must be
     C-contiguous [n, fields] (the batcher's prepare_inputs guarantees it
@@ -310,9 +346,12 @@ def pack_batch_u24_bf16(
     ns = np.fromiter((a.shape[0] for a in ids_c), np.int64, nparts)
     if int(ns.sum()) > bucket:
         raise ValueError(f"{int(ns.sum())} rows exceed bucket {bucket}")
-    out = np.empty(bucket * fields * 5, np.uint8)  # 3 (u24) + 2 (bf16)
+    # 3 words a position of the ids' four planes, 1 of the weights' two.
+    words = (3 * -(-bucket // 4) + -(-bucket // 2)) * fields
+    out = np.empty(words, np.uint32)
+    scratch = np.empty(4 * fields, np.uint32)
     lib.pack_batch_u24_bf16(
         ids_ptrs, _ptr(ids_is64), wts_ptrs, _ptr(wts_isf32),
-        _ptr(ns), nparts, fields, bucket, vocab, _ptr(out),
+        _ptr(ns), nparts, fields, bucket, vocab, _ptr(scratch), _ptr(out),
     )
     return out

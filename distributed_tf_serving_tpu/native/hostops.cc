@@ -14,6 +14,7 @@
 
 #include <cstdint>
 #include <cstring>
+#include <type_traits>
 
 namespace {
 
@@ -34,12 +35,6 @@ inline int64_t fold1(int64_t v, int64_t vocab, bool pow2, int64_t mask) {
   return r < 0 ? r + vocab : r;
 }
 
-inline void write_u24(uint8_t* dst, uint32_t v) {
-  dst[0] = static_cast<uint8_t>(v);
-  dst[1] = static_cast<uint8_t>(v >> 8);
-  dst[2] = static_cast<uint8_t>(v >> 16);
-}
-
 // f32 bits -> bf16 bits, round-to-nearest-even with NaN quieting (the one
 // rounding rule, shared by the exported f32_to_bf16 and the fused pack).
 inline uint16_t bf16_bits(uint32_t u) {
@@ -48,6 +43,61 @@ inline uint16_t bf16_bits(uint32_t u) {
   }
   uint32_t rounding = 0x7fffu + ((u >> 16) & 1u);
   return static_cast<uint16_t>((u + rounding) >> 16);
+}
+
+// The combined upload's word format (ops/transfer.py): a segment of
+// BITS-wide values travels as PLANES of whole rows, the planes' values at one
+// position concatenated little-endian into whole 32-bit words, so the device
+// rebuilds each plane with element-wise shifts and masks on uint32 arrays and
+// never sees a sub-word tensor with a 2-, 3- or 4-wide minor dimension.
+//   32 bits: 1 plane, 1 word    16 bits: 2 planes, 1 word
+//    8 bits: 4 planes, 1 word   24 bits: 4 planes, 3 words
+// pack_words writes `count` positions: word g of position i goes to
+// out[g * stride + i]; load(p, i) is plane p's value there (its low BITS
+// bits are kept).
+template <int BITS, class Load>
+inline void pack_words(Load load, int64_t count, uint32_t* out,
+                       int64_t stride) {
+  static_assert(BITS == 8 || BITS == 16 || BITS == 24, "sub-word widths");
+  for (int64_t i = 0; i < count; ++i) {
+    if constexpr (BITS == 16) {
+      out[i] = (load(0, i) & 0xffffu) | (load(1, i) << 16);
+    } else if constexpr (BITS == 8) {
+      out[i] = (load(0, i) & 0xffu) | ((load(1, i) & 0xffu) << 8) |
+               ((load(2, i) & 0xffu) << 16) | (load(3, i) << 24);
+    } else {
+      const uint32_t v0 = load(0, i) & 0xffffffu, v1 = load(1, i) & 0xffffffu,
+                     v2 = load(2, i) & 0xffffffu, v3 = load(3, i);
+      out[i] = v0 | (v1 << 24);
+      out[stride + i] = (v1 >> 8) | (v2 << 16);
+      out[2 * stride + i] = (v2 >> 16) | (v3 << 8);
+    }
+  }
+}
+
+constexpr int planes_of(int bits) { return bits == 16 ? 2 : 4; }
+
+// One padded [n_rows, inner] array -> its segment of words. Plane p holds
+// rows [p * q, (p + 1) * q), q = ceil(n_rows / planes); rows past n_rows
+// read as zero. value(k) is the BITS-wide value of flat element k. The
+// positions every plane has a row for take the loop without bounds checks.
+template <int BITS, class Value>
+inline void pack_planes(Value value, int64_t n_rows, int64_t inner,
+                        uint32_t* out) {
+  constexpr int P = planes_of(BITS);
+  const int64_t q = (n_rows + P - 1) / P;
+  const int64_t stride = q * inner, total = n_rows * inner;
+  int64_t full = total - (P - 1) * stride;  // the last plane's elements
+  if (full < 0) full = 0;
+  pack_words<BITS>(
+      [&](int p, int64_t i) { return value(p * stride + i); }, full, out,
+      stride);
+  pack_words<BITS>(
+      [&](int p, int64_t i) {
+        const int64_t k = p * stride + full + i;
+        return k < total ? value(k) : 0u;
+      },
+      stride - full, out + full, stride);
 }
 
 // ----------------------------------------------------------------------
@@ -229,8 +279,9 @@ void fold_i32(const int64_t* ids, int64_t n, int64_t vocab, int32_t* out) {
   }
 }
 
-// Already-folded int32 ids -> 3 little-endian bytes each (the u24 transfer
-// packing of ops/transfer.py, one pass, no intermediate view/copy).
+// Already-folded int32 ids -> 3 little-endian bytes each (the u24 packing
+// of ops/transfer.py's PER-KEY path, pack_host: one pass, no intermediate
+// view/copy; the combined upload takes pack_planes_u24_i32 below).
 // Requires 0 <= ids[i] < 2^24.
 void pack_u24_i32(const int32_t* ids, int64_t n, uint8_t* out) {
   for (int64_t i = 0; i < n; ++i) {
@@ -250,76 +301,128 @@ void f32_to_bf16(const float* in, int64_t n, uint16_t* out) {
   }
 }
 
+// The plane forms of the two packs above, and of 1- and 2-byte values as
+// they are, for ops/transfer.py's combined upload (pack_host_combined):
+// one padded [n_rows, inner] array in, its whole-word segment out, each
+// read and written once.
+void pack_planes_u24_i32(const int32_t* ids, int64_t n_rows, int64_t inner,
+                         uint32_t* out) {
+  pack_planes<24>([=](int64_t k) { return static_cast<uint32_t>(ids[k]); },
+                  n_rows, inner, out);
+}
+
+void pack_planes_bf16_f32(const float* in, int64_t n_rows, int64_t inner,
+                          uint32_t* out) {
+  const uint32_t* bits = reinterpret_cast<const uint32_t*>(in);
+  pack_planes<16>(
+      [=](int64_t k) { return static_cast<uint32_t>(bf16_bits(bits[k])); },
+      n_rows, inner, out);
+}
+
+void pack_planes_raw16(const uint16_t* in, int64_t n_rows, int64_t inner,
+                       uint32_t* out) {
+  pack_planes<16>([=](int64_t k) { return static_cast<uint32_t>(in[k]); },
+                  n_rows, inner, out);
+}
+
+void pack_planes_raw8(const uint8_t* in, int64_t n_rows, int64_t inner,
+                      uint32_t* out) {
+  pack_planes<8>([=](int64_t k) { return static_cast<uint32_t>(in[k]); },
+                 n_rows, inner, out);
+}
+
 // Fused batch assembly for the flagship combined layout
 // ({feat_ids: u24, feat_wts: bf16}, key-sorted so the ids segment precedes
 // the weights segment): reads each request's arrays ONCE and writes the
-// final padded device buffer directly —
-//   out = [bucket*F*3 bytes u24(fold(ids))][bucket*F*2 bytes bf16(wts)]
-// replacing the python path's pad copy + fold pass + pack pass + concat
-// (4 full passes and 3 temporaries per batch, serving/batcher.py _dispatch
-// + ops/transfer.py). Per part p: ids_ptrs[p] is int64 (wide wire; folded
-// here) or int32 when ids_is64[p]==0 (compact wire, pre-folded by the
-// client and range-checked by the service; low 3 bytes taken either way,
-// matching the numpy path's truncation semantics). wts_ptrs[p] is f32
-// (cast here, RNE) or bf16 bits when wts_isf32[p]==0 (compact; copied).
-// Rows [total..bucket) are zero in both segments. Thread-safe; ctypes
-// releases the GIL for the whole call.
+// final padded device buffer directly, in the word format above —
+//   out = [3 * ceil(bucket/4) * F words: four row planes of u24(fold(ids))]
+//         [ceil(bucket/2) * F words: two row planes of bf16(wts)]
+// replacing the python path's pad copy + fold pass + pack pass (3 full
+// passes and 2 temporaries per batch, serving/batcher.py _dispatch +
+// ops/transfer.py). An output position takes one row of every plane, and
+// those rows lie in different requests, so each segment is written by
+// walking its planes' source rows side by side: row r of the padded batch
+// is row (r - start of its part) of that part, zero past the last part.
+// Per part p: ids_ptrs[p] is int64 (wide wire; folded here) or int32 when
+// ids_is64[p]==0 (compact wire, pre-folded by the client and range-checked
+// by the service; low 3 bytes taken either way, matching the numpy path's
+// truncation semantics: for OUT-of-contract ids in a MIXED group the python
+// path widens to int64 and folds while this path truncates — an
+// intentional, documented divergence reachable only by direct submit()
+// callers violating the compact contract). wts_ptrs[p] is f32 (cast here,
+// RNE) or bf16 bits when wts_isf32[p]==0 (compact; copied). `scratch` holds
+// 4 * fields words. Thread-safe; ctypes releases the GIL for the whole call.
 void pack_batch_u24_bf16(const void** ids_ptrs, const uint8_t* ids_is64,
                          const void** wts_ptrs, const uint8_t* wts_isf32,
                          const int64_t* ns, int64_t num_parts,
                          int64_t fields, int64_t bucket, int64_t vocab,
-                         uint8_t* out) {
-  uint8_t* ids_base = out;
-  uint8_t* wts_base = out + bucket * fields * 3;
+                         uint32_t* scratch, uint32_t* out) {
   const bool pow2 = (vocab & (vocab - 1)) == 0;
   const int64_t mask = vocab - 1;
-  int64_t row = 0;
-  for (int64_t p = 0; p < num_parts; ++p) {
-    const int64_t n = ns[p] * fields;
-    uint8_t* idst = ids_base + row * fields * 3;
-    if (ids_is64[p]) {
-      const int64_t* ids = static_cast<const int64_t*>(ids_ptrs[p]);
-      for (int64_t i = 0; i < n; ++i) {
-        write_u24(idst + 3 * i,
-                  static_cast<uint32_t>(fold1(ids[i], vocab, pow2, mask)));
+  // Row `row` of part `part` (part == num_parts: padding) as `fields`
+  // values in dst: folded ids, or bf16 bits.
+  auto ids_row = [&](int64_t part, int64_t row, uint32_t* dst) {
+    if (part >= num_parts) {
+      std::memset(dst, 0, static_cast<size_t>(fields) * 4);
+    } else if (ids_is64[part]) {
+      const int64_t* src =
+          static_cast<const int64_t*>(ids_ptrs[part]) + row * fields;
+      for (int64_t f = 0; f < fields; ++f) {
+        dst[f] = static_cast<uint32_t>(fold1(src[f], vocab, pow2, mask));
       }
     } else {
-      // int32 (compact wire): pre-folded by contract (service-validated
-      // range [0, vocab)), so the low 3 bytes ARE the value — plain
-      // truncation, exactly what the python generic path does for an
-      // all-int32 group. (For OUT-of-contract ids in a MIXED group the
-      // python path widens to int64 and folds while this path truncates —
-      // an intentional, documented divergence reachable only by direct
-      // submit() callers violating the compact contract.)
-      const int32_t* ids = static_cast<const int32_t*>(ids_ptrs[p]);
-      for (int64_t i = 0; i < n; ++i) {
-        write_u24(idst + 3 * i, static_cast<uint32_t>(ids[i]));
-      }
+      std::memcpy(dst,
+                  static_cast<const int32_t*>(ids_ptrs[part]) + row * fields,
+                  static_cast<size_t>(fields) * 4);
     }
-    // Byte-granular stores: the weights segment starts at bucket*fields*3,
-    // which is ODD for odd bucket*fields — a uint16_t* store there would be
-    // misaligned UB (unreachable with the shipped pow2 buckets, but the
-    // layout must be correct for arbitrary configs). memcpy of 2 bytes
-    // compiles to a single unaligned store on x86/arm.
-    uint8_t* wdst = wts_base + row * fields * 2;
-    if (wts_isf32[p]) {
-      const uint32_t* bits =
-          static_cast<const uint32_t*>(wts_ptrs[p]);
-      for (int64_t i = 0; i < n; ++i) {
-        uint16_t v = bf16_bits(bits[i]);
-        std::memcpy(wdst + 2 * i, &v, 2);
-      }
+  };
+  auto wts_row = [&](int64_t part, int64_t row, uint32_t* dst) {
+    if (part >= num_parts) {
+      std::memset(dst, 0, static_cast<size_t>(fields) * 4);
+    } else if (wts_isf32[part]) {
+      const uint32_t* src =
+          static_cast<const uint32_t*>(wts_ptrs[part]) + row * fields;
+      for (int64_t f = 0; f < fields; ++f) dst[f] = bf16_bits(src[f]);
     } else {
-      std::memcpy(wdst, wts_ptrs[p], static_cast<size_t>(n) * 2);
+      const uint16_t* src =
+          static_cast<const uint16_t*>(wts_ptrs[part]) + row * fields;
+      for (int64_t f = 0; f < fields; ++f) dst[f] = src[f];
     }
-    row += ns[p];
-  }
-  if (row < bucket) {
-    std::memset(ids_base + row * fields * 3, 0,
-                static_cast<size_t>(bucket - row) * fields * 3);
-    std::memset(wts_base + row * fields * 2, 0,
-                static_cast<size_t>(bucket - row) * fields * 2);
-  }
+  };
+  // One cursor a plane: the part and the row inside it of padded row
+  // plane * q + j, advanced with j.
+  struct Cursor { int64_t part, row; };
+  auto seek = [&](int64_t r) {
+    Cursor c{0, r};
+    while (c.part < num_parts && c.row >= ns[c.part]) c.row -= ns[c.part++];
+    return c;
+  };
+  auto step = [&](Cursor& c) {
+    if (c.part < num_parts && ++c.row >= ns[c.part]) {
+      c.row = 0;
+      do { ++c.part; } while (c.part < num_parts && ns[c.part] == 0);
+    }
+  };
+  auto segment = [&](auto bits_tag, auto row_fn, uint32_t* seg) {
+    constexpr int BITS = decltype(bits_tag)::value;
+    constexpr int P = planes_of(BITS);
+    const int64_t q = (bucket + P - 1) / P;
+    Cursor cur[P];
+    for (int p = 0; p < P; ++p) cur[p] = seek(p * q);
+    for (int64_t j = 0; j < q; ++j) {
+      for (int p = 0; p < P; ++p) {
+        row_fn(cur[p].part, cur[p].row, scratch + p * fields);
+        step(cur[p]);
+      }
+      pack_words<BITS>(
+          [&](int p, int64_t f) { return scratch[p * fields + f]; }, fields,
+          seg + j * fields, q * fields);
+    }
+    return seg + (P * BITS / 32) * q * fields;
+  };
+  uint32_t* wts_seg =
+      segment(std::integral_constant<int, 24>{}, ids_row, out);
+  segment(std::integral_constant<int, 16>{}, wts_row, wts_seg);
 }
 
 }  // extern "C"

@@ -1,15 +1,13 @@
 """Host->device transfer compression for the serving hot path.
 
-Host<->device link bandwidth is the serving bottleneck once compute is
-batched: the wire pays bytes-per-candidate, so
-the batcher shrinks what crosses the host<->device boundary and undoes it
-on-device inside the jitted executable (free: fuses into the embedding
-lookup's index arithmetic).
+The wire pays bytes per candidate, and every upload has a fixed submit
+cost, so the batcher shrinks what crosses the host<->device boundary,
+sends it as ONE buffer a batch, and undoes both on-device inside the jitted
+executable (the entry's `unpack` scope).
 
 Two lossless-under-the-model transforms:
 - feat_ids: folded ids are < vocab_size; when vocab_size <= 2^24 the int32
-  rows travel as 3 little-endian bytes each (u24), -25% id bytes. Unpack is
-  three shifts+ors on device.
+  rows travel as 3 bytes each (u24), -25% id bytes.
 - feat_wts: when the model's compute dtype is bfloat16 AND the model
   consumes weights only through that cast (Model.wts_in_compute_dtype — true
   for dcn/dcn_v2/two_tower/dlrm via field_embed, false for wide_deep/deepfm
@@ -18,6 +16,16 @@ Two lossless-under-the-model transforms:
 
 Together: 344 -> 215 bytes/candidate at 43 fields for the reference
 workload (DCNClient.java:98-108 shapes).
+
+What the unpack costs depends on the FORMAT, and is not free (PERF.md
+section 6, PRs 26-28): as a byte buffer rebuilt by `reshape((n, F, 3))` it
+took 23-36% of the device's busy time in the bulk cells, because a byte
+tensor with a 2-, 3- or 4-wide minor dimension tiles with that dimension
+padded to 128 lanes. The combined buffer is therefore `uint32` words, with
+sub-word values in planes of whole rows (see "combined single buffer"
+below). The per-key path (`pack_host` / `unpack_device`: the mesh executor
+and the rare servable whose inputs cannot ride the buffer) keeps the plain
+`[..., 3]` byte form.
 """
 
 from __future__ import annotations
@@ -94,11 +102,24 @@ def unpack_device(packed: dict[str, jnp.ndarray], spec: dict[str, str]) -> dict[
 # ------------------------------------------------- combined single buffer
 #
 # Beyond shrinking bytes, the number of host->device TRANSFERS matters:
-# each transfer has a fixed submit cost. The combined path concatenates every
-# (already spec-packed) input's bytes into ONE uint8 buffer — one upload
-# per batch — and splits it back inside the jitted executable with static
-# slices + bitcasts (free: fuses with the consumers).
-
+# each transfer has a fixed submit cost. The combined path packs every input
+# into ONE uint32 buffer — one upload per batch — and splits it back inside
+# the jitted executable. Each input's segment is whole words. A value of
+# `bits` < 32 bits travels in PLANES of whole rows: plane p holds rows
+# [p * q, (p + 1) * q) of the padded [n, ...] array, q = ceil(n / planes)
+# (rows past n are zero), and the planes' values at one position are
+# concatenated, little-endian, into whole words:
+#    32 bits: 1 plane, 1 word (the word is the value)
+#    16 bits: 2 planes, 1 word   lo | hi << 16
+#     8 bits: 4 planes, 1 word   v0 | v1 << 8 | v2 << 16 | v3 << 24
+#    24 bits: 4 planes, 3 words  v0 | v1 << 24, v1 >> 8 | v2 << 16,
+#                                v2 >> 16 | v3 << 8
+# so the device rebuilds every plane with element-wise shifts and masks on a
+# uint32 array of the plane's own shape and joins the planes along the row
+# axis: no tensor narrower than 32 bits with a minor dimension of 2, 3 or 4
+# (which would tile 128 lanes wide) exists in the unpack. The bytes a row
+# over the link are the packed widths' (3 an id, 2 a weight), plus the zero
+# rows of the last plane where n is no multiple of the plane count.
 
 # --------------------------------------------------- output compaction
 #
@@ -301,66 +322,143 @@ def combined_supported(arrays: dict[str, np.ndarray]) -> bool:
     )
 
 
-def combined_layout(arrays: dict[str, np.ndarray], spec: dict[str, str]) -> tuple:
-    """Pure-metadata layout for the combined buffer: a hashable tuple of
-    per-input entries (key, kind, trailing_shape, per_candidate_bytes,
-    packed_dtype_str), key-sorted. Static under jit (rides static_argnums)
+# Planes a word group holds, by the packed width in bits.
+_PLANES = {32: 1, 16: 2, 8: 4, 24: 4}
+
+
+def _plane_shifts(bits: int):
+    """(plane, word, shift) for every word a plane's value lies in: the
+    value is word >> shift where shift >= 0, else word << -shift."""
+    planes = _PLANES[bits]
+    return [
+        (p, g, p * bits - 32 * g)
+        for p in range(planes)
+        for g in range(planes * bits // 32)
+        if -bits < p * bits - 32 * g < 32
+    ]
+
+
+def _segment_words(n: int, trailing: tuple, bits: int) -> int:
+    planes = _PLANES[bits]
+    inner = int(np.prod(trailing)) if trailing else 1
+    return -(-n // planes) * inner * planes * bits // 32
+
+
+def combined_layout(
+    arrays: dict[str, np.ndarray], spec: dict[str, str], rows: int | None = None
+) -> tuple:
+    """Pure-metadata layout for the combined buffer: (n, entries), n the
+    padded batch's rows (`rows` where `arrays` are one request's, not the
+    batch's) and entries a key-sorted tuple of (key, bits, trailing_shape,
+    dtype_str) per input: the packed width of one value and the dtype it
+    unpacks to. Hashable and static under jit (the entry closes over it),
     and computable WITHOUT packing — the content cache derives its key from
     the raw arrays plus this layout, so a hit skips the pack entirely."""
-    layout = []
+    entries = []
     for key in sorted(arrays):
         arr = arrays[key]
         kind = spec.get(key, "raw")
         trailing = tuple(int(t) for t in arr.shape[1:])
-        inner = int(np.prod(trailing)) if trailing else 1
         if kind == "u24":
-            layout.append((key, "u24", trailing, inner * 3, "u24"))
+            entries.append((key, 24, trailing, "int32"))
         elif kind == "bf16":
-            layout.append((key, "raw", trailing, inner * 2, "bfloat16"))
+            entries.append((key, 16, trailing, "bfloat16"))
         else:
-            layout.append(
-                (key, "raw", trailing, inner * arr.dtype.itemsize, arr.dtype.name)
-            )
-    return tuple(layout)
+            entries.append((key, arr.dtype.itemsize * 8, trailing, arr.dtype.name))
+    n = next(iter(arrays.values())).shape[0] if rows is None else rows
+    return (int(n), tuple(entries))
+
+
+def combined_words(layout: tuple) -> int:
+    """Length of the layout's buffer, in 32-bit words."""
+    n, entries = layout
+    return sum(_segment_words(n, e[2], e[1]) for e in entries)
+
+
+def describe_layout(layout: tuple) -> str:
+    """The format as /monitoring's `startup.upload_format` names it."""
+    return "uint32 words, row planes: " + ", ".join(
+        f"{key} {dtype_str}/{bits}b x{_PLANES[bits]}"
+        for key, bits, _trailing, dtype_str in layout[1]
+    )
+
+
+def pack_planes_numpy(arr: np.ndarray, bits: int, out: np.ndarray) -> None:
+    """The numpy form of native.pack_planes, byte for byte: the low `bits`
+    bits of every value of the padded [n, ...] array `arr`, as row planes in
+    whole words."""
+    planes = _PLANES[bits]
+    n = arr.shape[0]
+    q = -(-n // planes)
+    v = np.zeros((planes * q, arr.size // max(n, 1)), np.uint32)
+    v[:n] = arr.view(f"u{arr.dtype.itemsize}").reshape(n, -1) & ((1 << bits) - 1)
+    v = v.reshape(planes, -1)
+    words = out.reshape(planes * bits // 32, -1)
+    words[:] = 0
+    for p, g, shift in _plane_shifts(bits):
+        words[g] |= v[p] << np.uint32(shift) if shift >= 0 else v[p] >> np.uint32(-shift)
 
 
 def pack_host_combined(
     arrays: dict[str, np.ndarray], spec: dict[str, str]
 ) -> np.ndarray:
-    """Spec-pack each input, then concatenate the raw bytes into one uint8
-    buffer (same sorted key order as combined_layout)."""
-    packed = pack_host(arrays, spec)
-    segs = [
-        np.ascontiguousarray(packed[key]).view(np.uint8).ravel()
-        for key in sorted(packed)
-    ]
-    return np.concatenate(segs) if len(segs) > 1 else segs[0]
+    """Spec-pack every input straight into its segment of ONE uint32 buffer
+    (same sorted key order as combined_layout): a native pass an input
+    (native/hostops.cc pack_planes), numpy with the same bytes otherwise."""
+    from .. import native
+
+    layout = combined_layout(arrays, spec)
+    n, entries = layout
+    out = np.empty(combined_words(layout), np.uint32)
+    use_native = native.available()
+    off = 0
+    for key, bits, trailing, _dtype_str in entries:
+        arr = np.ascontiguousarray(arrays[key])
+        seg = out[off:off + _segment_words(n, trailing, bits)]
+        off += seg.size
+        kind = spec.get(key)
+        if kind == "u24" and arr.dtype != np.int32:
+            raise ValueError(f"u24 packing expects folded int32 ids, got {arr.dtype}")
+        if kind == "bf16" and arr.dtype != ml_dtypes.bfloat16:
+            # float32 weights: the native pass casts them (RNE) as it packs.
+            # (bf16 already: a compact-wire client cast it.)
+            arr = arr.astype(np.float32, copy=False)
+            if not use_native:
+                arr = arr.astype(ml_dtypes.bfloat16)
+        if bits == 32:
+            seg[:] = arr.reshape(-1).view(np.uint32)
+        elif use_native:
+            native.pack_planes(arr, bits, seg)
+        else:
+            pack_planes_numpy(arr, bits, seg)
+    return out
 
 
 @jax.named_scope("unpack")
 def unpack_device_combined(buf: jnp.ndarray, layout: tuple) -> dict[str, jnp.ndarray]:
-    """Inverse of pack_host_combined, traced inside the jitted executable.
-    Slices are static (n derives from the buffer length and the layout's
-    per-candidate byte totals), bitcasts collapse the byte dim."""
+    """Inverse of pack_host_combined, traced inside the jitted executable:
+    static slices of the word buffer, each reshaped to its planes' own
+    [words, rows, ...] shape, shifted and masked element-wise, joined along
+    the row axis and bitcast (or narrowed, then bitcast) to the input's
+    dtype."""
     from jax import lax
 
-    total_pcb = sum(e[3] for e in layout)
-    n = buf.shape[0] // total_pcb
+    n, entries = layout
     out = {}
     off = 0
-    for key, kind, trailing, per_cand, dtype_str in layout:
-        nb = n * per_cand
-        seg = buf[off:off + nb]
-        off += nb
-        if kind == "u24":
-            b = seg.reshape((n, *trailing, 3)).astype(jnp.int32)
-            out[key] = b[..., 0] | (b[..., 1] << 8) | (b[..., 2] << 16)
-        else:
-            dt = jnp.dtype(dtype_str)
-            if dt.itemsize == 1:
-                out[key] = lax.bitcast_convert_type(seg.reshape((n, *trailing)), dt)
-            else:
-                out[key] = lax.bitcast_convert_type(
-                    seg.reshape((n, *trailing, dt.itemsize)), dt
-                )
+    for key, bits, trailing, dtype_str in entries:
+        planes = _PLANES[bits]
+        nw = _segment_words(n, trailing, bits)
+        w = buf[off:off + nw].reshape((planes * bits // 32, -(-n // planes), *trailing))
+        off += nw
+        vals = [None] * planes
+        for p, g, shift in _plane_shifts(bits):
+            part = w[g] if shift == 0 else w[g] >> shift if shift > 0 else w[g] << -shift
+            vals[p] = part if vals[p] is None else vals[p] | part
+        v = vals[0] if planes == 1 else jnp.concatenate(vals, axis=0)[:n]
+        if bits < 32:
+            v = v & jnp.uint32((1 << bits) - 1)
+        if bits in (8, 16):
+            v = v.astype(f"uint{bits}")
+        out[key] = lax.bitcast_convert_type(v, jnp.dtype(dtype_str))
     return out

@@ -70,6 +70,7 @@ from ..ops.transfer import (
     combined_layout,
     combined_supported,
     compact_outputs_device,
+    describe_layout,
     is_wire_sidecar,
     output_wire_dtype as _wire_dtype_of,
     pack_host,
@@ -1014,6 +1015,12 @@ class DynamicBatcher:
         self._out_row_bytes: weakref.WeakKeyDictionary[Servable, list] = (
             weakref.WeakKeyDictionary()
         )
+        # servable -> the upload formats its entry has been traced for
+        # (ops/transfer.py describe_layout; "per key ..." off the combined
+        # buffer): /monitoring's `startup.upload_format`.
+        self._upload_formats: weakref.WeakKeyDictionary[Servable, list] = (
+            weakref.WeakKeyDictionary()
+        )
         # _jit_for is reached from the batcher thread (fused-path
         # eligibility) AND the dispatch thread; one lock keeps the entry
         # build single-shot.
@@ -1554,7 +1561,7 @@ class DynamicBatcher:
         `servable` with — public so measurement harnesses (bench.py's
         device-limited decomposition) can time the EXACT serving executable,
         warm caches included, instead of compiling a lookalike. When
-        `combined` is True the fn signature is (params, uint8_buffer,
+        `combined` is True the fn signature is (params, uint32_buffer,
         layout) with layout static (ops/transfer.py combined_layout); both
         shapes accept optional keywords (out_keys, donate, topk, n_valid)
         selecting the output-compaction variant — defaults reproduce the
@@ -1572,6 +1579,16 @@ class DynamicBatcher:
                 self._queued_candidates + self._staged_candidates,
                 self.queue_capacity_candidates,
             )
+
+    def upload_formats(self) -> dict[str, str]:
+        """"name:version" -> how that servable's batches cross to the
+        device, for every servable an entry was built for (after the
+        ladder's warm-up: every loaded one)."""
+        with self._jit_lock:
+            return {
+                f"{sv.name}:{sv.version}": "; ".join(sorted(formats))
+                for sv, formats in self._upload_formats.items()
+            }
 
     def pipeline_stats(self) -> dict:
         """Continuous-batching pipeline snapshot (ISSUE 9): configured
@@ -1853,6 +1870,12 @@ class DynamicBatcher:
         wire = None if model.needs_x64 else self._wire_dt
         score_key = model.score_output
         rowbytes = self._out_row_bytes.setdefault(servable, [0])
+        # A list, appended to at trace time and read by upload_formats()
+        # from another thread: an append never breaks a reader's iteration.
+        formats = self._upload_formats[servable] = []
+        if not combined:
+            formats.append("per key: " + (", ".join(
+                f"{k} {v}" for k, v in sorted(spec.items())) or "as sent"))
 
         def finish(out, out_keys):
             # Runs at TRACE time: record the full-fp32 readback baseline
@@ -1883,20 +1906,21 @@ class DynamicBatcher:
             return run
 
         if combined:
-            # One uint8 buffer per batch = ONE host->device transfer
-            # instead of one per input; the layout split + bitcasts are
-            # traced into the executable and fuse with consumers.
+            # One uint32 buffer per batch = ONE host->device transfer
+            # instead of one per input; the layout split, the planes'
+            # shifts and masks and the bitcasts are traced into the
+            # executable (its `unpack` scope; ops/transfer.py).
             # (x64 models keep the per-key path: their int64 inputs
             # must cross the boundary as int64, not raw bytes plus an
             # in-graph bitcast that enable_x64 scoping complicates.)
             #
             # The layout is CLOSED OVER per distinct variant key (a
-            # handful per servable — bucket-independent metadata) instead
+            # handful per servable and bucket: it names the batch's rows,
+            # which the buffer's length alone does not) instead
             # of riding static_argnums: hashing that nested tuple on
             # every call cost ~175 us/batch of pure dispatch overhead
             # (round-4 microbench: 426 -> 251 us/call arg processing),
-            # and the inner jit cache keys on buffer shape exactly as
-            # before.
+            # and each variant's jit compiles for its one buffer shape.
             def fn(
                 params, buf, layout, out_keys=None, donate=False,
                 topk=0, n_valid=None, k_apply=None, prune=False,
@@ -1911,6 +1935,8 @@ class DynamicBatcher:
                 key = (layout, out_keys, donate, topk, k_apply, prune)
                 jfn = _cache.get(key)
                 if jfn is None:
+                    if (fmt := describe_layout(layout)) not in formats:
+                        formats.append(fmt)
                     donargs = (1,) if donate else ()
                     ap = k_apply or apply
                     if topk:
@@ -1949,8 +1975,7 @@ class DynamicBatcher:
                     else:
                         def run(p, b, _ok=out_keys, _ap=ap):
                             # Transfer decompression is traced into the
-                            # executable, so it fuses with the embedding
-                            # lookup's index arithmetic.
+                            # executable (its `unpack` scope).
                             batch = unpack_device(b, spec) if spec else b
                             return finish(_ap(p, batch), _ok)
                     jfn = _cache[key] = jax.jit(named(run, topk, prune))
@@ -1979,10 +2004,9 @@ class DynamicBatcher:
         pipeline.
 
         hostops.cc pack_batch_u24_bf16 reads each request's arrays once and
-        writes the final padded [u24 ids | bf16 wts] device buffer directly
-        — the generic path makes 4 full passes (pad copy, fold, pack,
-        concat) with 3 temporaries per batch (~1.25 ms/batch at the 16k
-        bucket on this host, round-3 phases). The buffer is bit-identical
+        writes the final padded [u24 ids | bf16 wts] word buffer directly
+        — the generic path makes 3 full passes (pad copy, fold, pack)
+        with 2 temporaries per batch. The buffer is bit-identical
         to pack_host_combined over the padded batch (pinned by
         tests/test_batcher.py), so it shares the same compiled executables
         and the same content-cache semantics (keyed per-part here; distinct
@@ -2023,7 +2047,7 @@ class DynamicBatcher:
             ):
                 return None
         layout = combined_layout(
-            {k: first[k] for k in ("feat_ids", "feat_wts")}, spec
+            {k: first[k] for k in ("feat_ids", "feat_wts")}, spec, rows=bucket
         )
         return {
             "servable": servable,
@@ -2161,7 +2185,7 @@ class DynamicBatcher:
         )
         fn, spec, combined = self._jit_for(servable)
         if combined and not combined_supported(arrays):
-            # Rare servable whose inputs cannot ride a byte buffer (string/
+            # Rare servable whose inputs cannot ride the word buffer (string/
             # bool/8-byte tensors): rebuild the per-key entry once and pin
             # it (same spec — only the transfer packaging changes).
             with self._jit_lock:

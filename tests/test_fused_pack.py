@@ -1,5 +1,5 @@
 """Native fused batch assembly (hostops.cc pack_batch_u24_bf16): the final
-padded [u24 ids | bf16 wts] device buffer must be BIT-identical to the
+padded [u24 ids | bf16 wts] word buffer must be BIT-identical to the
 generic path's pad -> fold -> pack_host_combined pipeline for every input
 mix (wide int64/f32, compact int32/bf16, coalesced mixtures, padding), and
 the serving path must produce identical scores with the fused path on or
@@ -71,6 +71,26 @@ def test_buffer_bit_identical(vocab):
         F, bucket, vocab,
     )
     want = _reference_buffer(parts, bucket, vocab)
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("sizes,bucket", [
+    ((1,), 4), ((1, 2, 3), 8), ((3, 0, 7, 1), 16), ((5, 6), 11), ((2, 9, 1, 1), 13),
+    ((7, 7, 7, 7, 3), 32), ((31,), 32), ((1, 1, 1, 1, 1, 1, 1), 7),
+])
+def test_buffer_bit_identical_at_rows_off_the_planes(sizes, bucket):
+    """Requests that start at rows no multiple of 4 (or of 2), a bucket
+    that does not fill its last plane, an empty request: a word of the
+    buffer holds rows of up to four requests, and is still the generic
+    path's."""
+    parts = [_wide(n, 10 + i) for i, n in enumerate(sizes)]
+    parts[-1] = compact_payload(parts[-1], VOCAB) if sizes[-1] else parts[-1]
+    got = native.pack_batch_u24_bf16(
+        [p["feat_ids"] for p in parts], [p["feat_wts"] for p in parts],
+        F, bucket, VOCAB,
+    )
+    want = _reference_buffer(parts, bucket, VOCAB)
+    assert got.dtype == want.dtype == np.uint32
     np.testing.assert_array_equal(got, want)
 
 

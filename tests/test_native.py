@@ -107,6 +107,75 @@ def test_pack_host_native_equals_numpy_path():
         )
 
 
+@pytest.mark.parametrize("fields", [13, 26, 43, 214])
+@pytest.mark.parametrize("n", [1, 6, 7, 512])
+@pytest.mark.parametrize("source,bits", [
+    ("int32", 24), ("float32", 16), ("uint16", 16), ("uint8", 8),
+])
+def test_pack_planes_native_equals_numpy_byte_for_byte(source, bits, n, fields):
+    """The plane forms of the combined upload (hostops.cc pack_planes): the
+    same words as ops/transfer.py's numpy form, whether or not the rows
+    fill the last plane."""
+    from distributed_tf_serving_tpu.ops.transfer import _segment_words, pack_planes_numpy
+
+    rng = np.random.RandomState(n * 1000 + fields + bits)
+    if source == "float32":
+        arr = (rng.randn(n, fields) * rng.lognormal(0, 6, (n, fields))).astype(np.float32)
+        arr.flat[:5] = [np.inf, -0.0, 65504.0, 1e-40, 3.0000001]
+        values = arr.astype(ml_dtypes.bfloat16).view(np.uint16)
+    else:
+        top = (1 << 24) if bits == 24 else (1 << bits)
+        arr = rng.randint(0, top, size=(n, fields)).astype(source)
+        arr[0, 0], arr[-1, -1] = 0, top - 1
+        values = arr.view(np.uint32) if bits == 24 else arr
+    words = _segment_words(n, (fields,), bits)
+    got = np.full(words, 0xDEADBEEF, np.uint32)
+    native.pack_planes(arr, bits, got)
+    want = np.full(words, 0xDEADBEEF, np.uint32)
+    pack_planes_numpy(values, bits, want)
+    np.testing.assert_array_equal(got, want)
+
+
+def test_pack_planes_u24_keeps_the_low_three_bytes():
+    """Out-of-contract ids (past 2^24, negative) are truncated to their low
+    three bytes, as the per-key byte pack does: neighbours in the word are
+    not touched."""
+    from distributed_tf_serving_tpu.ops.transfer import pack_planes_numpy
+
+    ids = np.array([[-1, 1 << 24, (1 << 31) - 1, -(1 << 31)], [5, 6, 7, 8],
+                    [9, 10, 11, 12], [-2, -3, -4, -5]], np.int32)
+    got = np.empty(12, np.uint32)
+    native.pack_planes(ids, 24, got)
+    want = np.empty(12, np.uint32)
+    pack_planes_numpy(ids.view(np.uint32), 24, want)
+    np.testing.assert_array_equal(got, want)
+    assert got[0] == 0xFFFFFF | (5 << 24)  # row 0 low three bytes, row 1's first
+
+
+def test_pack_host_combined_native_equals_numpy_path(monkeypatch):
+    from distributed_tf_serving_tpu.ops.transfer import pack_host_combined
+
+    rng = np.random.RandomState(4)
+    arrays = {
+        "feat_ids": rng.randint(0, 1 << 24, size=(30, 43)).astype(np.int32),
+        "feat_wts": rng.rand(30, 43).astype(np.float32),
+        "dense_features": rng.rand(30, 13).astype(np.float32),
+        "flags": rng.randint(-128, 128, size=(30,)).astype(np.int8),
+        "half": rng.rand(30, 7).astype(np.float16),
+    }
+    spec = {"feat_ids": "u24", "feat_wts": "bf16"}
+    native_out = pack_host_combined(arrays, spec)
+    monkeypatch.setattr(native, "available", lambda: False)
+    numpy_out = pack_host_combined(arrays, spec)
+    assert native_out.dtype == numpy_out.dtype == np.uint32
+    np.testing.assert_array_equal(native_out, numpy_out)
+    # bf16 from a compact-wire client travels as it is: the same words.
+    arrays["feat_wts"] = arrays["feat_wts"].astype(ml_dtypes.bfloat16)
+    np.testing.assert_array_equal(pack_host_combined(arrays, spec), numpy_out)
+    monkeypatch.undo()
+    np.testing.assert_array_equal(pack_host_combined(arrays, spec), numpy_out)
+
+
 def test_hash128_content_addressing():
     """Equal bytes -> equal digest (any buffer), any flipped bit -> new
     digest; shape/dtype enter the cache key elsewhere, so the digest only
@@ -239,3 +308,20 @@ def test_failed_build_raises(tmp_path, monkeypatch):
     monkeypatch.setattr(native, "_BUILD_DIR", tmp_path / "build")
     with pytest.raises(native.NativeBuildError, match="build failed"):
         native._load_locked()
+
+
+def test_build_leaves_another_process_its_unrenamed_library(tmp_path, monkeypatch):
+    """Several processes may build at once (test workers on a fresh
+    checkout, two servers): a finished build clears libraries of other
+    source revisions, but not a `.tmp<pid>.so` that another build has
+    linked and not yet renamed (that build then failed, one run in three)."""
+    build = tmp_path / "build"
+    build.mkdir()
+    stale = build / "libhostops-0000000000000000.so"
+    in_flight = build / "libhostops-1111111111111111.tmp4242.so"
+    stale.write_bytes(b"old")
+    in_flight.write_bytes(b"being linked")
+    monkeypatch.setattr(native, "_BUILD_DIR", build)
+    so = native._so_path()
+    native._build(so)
+    assert so.exists() and in_flight.exists() and not stale.exists()

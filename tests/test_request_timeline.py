@@ -347,8 +347,8 @@ def _monitoring(port, section):
 
 def test_cli_server_reports_startup_stamps_and_raw_counters(tmp_path):
     """The CLI server's /monitoring: `runtime.startup` holds the five
-    stamps serve() and build_stack take (and each servable's lookups a row
-    and bags), `metrics.batcher` the raw terms of the two ratios."""
+    stamps serve() and build_stack take (and each servable's lookups a row,
+    bags and upload format), `metrics.batcher` the raw terms of the two ratios."""
     grpc = pytest.importorskip("grpc")
     from distributed_tf_serving_tpu.client import build_predict_request
     from distributed_tf_serving_tpu.proto import PredictionServiceStub
@@ -381,6 +381,11 @@ def test_cli_server_reports_startup_stamps_and_raw_counters(tmp_path):
         # Beside the stamps: what each loaded servable looks up a candidate row.
         assert startup.pop("lookups_per_row") == {"DCN:1": F}
         assert startup.pop("bags") == {"DCN:1": F}
+        # And how its batches cross to the device: the ladder's warm-up
+        # traced the one-buffer entry (ops/transfer.py describe_layout).
+        assert startup.pop("upload_format") == {
+            "DCN:1": "uint32 words, row planes: feat_ids int32/24b x4, feat_wts bfloat16/16b x2"
+        }
         assert set(startup) == {
             "backend_init_s", "params_init_s", "native_build_s", "warmup_s", "to_serving_s",
         }

@@ -2,6 +2,8 @@
 is bit-identical to the model's own bf16 cast, and the batcher produces the
 same scores with compression on and off."""
 
+import re
+
 import jax
 import numpy as np
 import pytest
@@ -96,6 +98,7 @@ def test_combined_roundtrip(spec):
     from distributed_tf_serving_tpu.ops.transfer import (
         combined_layout,
         combined_supported,
+        combined_words,
         pack_host_combined,
         unpack_device_combined,
     )
@@ -109,8 +112,14 @@ def test_combined_roundtrip(spec):
     assert combined_supported(arrays)
     layout = combined_layout(arrays, spec)
     buf = pack_host_combined(arrays, spec)
-    assert buf.dtype == np.uint8 and buf.ndim == 1
-    assert buf.nbytes == 6 * sum(e[3] for e in layout)
+    assert buf.dtype == np.uint32 and buf.ndim == 1
+    assert buf.size == combined_words(layout)
+    # 6 rows: two planes of 3 (bf16), four of 2 (u24, in 3 words a position).
+    want_words = {"u24": 3 * 2 * 5, "bf16": 3 * 5}
+    assert buf.size == sum(
+        want_words.get(spec.get(k), 6 * w)
+        for k, w in (("dense_features", 3), ("feat_ids", 5), ("feat_wts", 5))
+    )
     out = jax.jit(
         lambda b: unpack_device_combined(b, layout), static_argnums=()
     )(buf)
@@ -125,6 +134,127 @@ def test_combined_roundtrip(spec):
         )
     else:
         np.testing.assert_array_equal(np.asarray(out["feat_wts"]), arrays["feat_wts"])
+
+
+_KINDS = {
+    # kind -> (host dtype, spec entry, values drawn from)
+    "u24": (np.int32, "u24", (0, 1 << 24)),
+    "bf16": (np.float32, "bf16", None),
+    "int32": (np.int32, None, (-(1 << 31), (1 << 31) - 1)),
+    "float32": (np.float32, None, None),
+    "int8": (np.int8, None, (-128, 128)),
+}
+
+
+def _kind_arrays(kind, n, fields, seed=0):
+    """One input of `kind`, between two others so that its segment starts
+    and ends inside the buffer."""
+    dtype, how, span = _KINDS[kind]
+    rng = np.random.RandomState(seed + n * 1000 + fields)
+    x = (
+        rng.randint(*span, size=(n, fields)).astype(dtype) if span
+        else (rng.randn(n, fields) * 100).astype(dtype)
+    )
+    if kind == "u24":
+        x[0, 0], x[-1, -1] = 0, (1 << 24) - 1
+    arrays = {
+        "a_dense": rng.randn(n, 13).astype(np.float32),
+        "b_under_test": x,
+        "c_ids": rng.randint(0, 1 << 24, size=(n, 3)).astype(np.int32),
+    }
+    spec = {"c_ids": "u24"}
+    if how:
+        spec["b_under_test"] = how
+    return arrays, spec
+
+
+@pytest.mark.parametrize("fields", [13, 26, 43, 214])
+@pytest.mark.parametrize("n", [1, 6, 7, 512])
+@pytest.mark.parametrize("kind", sorted(_KINDS))
+def test_combined_roundtrip_bit_exact(kind, n, fields):
+    """Every kind the word buffer carries, at row counts that fill the last
+    plane and that do not, comes back bit for bit (bf16: the RNE cast)."""
+    import ml_dtypes
+
+    from distributed_tf_serving_tpu.ops.transfer import (
+        combined_layout,
+        combined_words,
+        pack_host_combined,
+        unpack_device_combined,
+    )
+
+    arrays, spec = _kind_arrays(kind, n, fields)
+    layout = combined_layout(arrays, spec)
+    buf = pack_host_combined(arrays, spec)
+    assert buf.dtype == np.uint32 and buf.shape == (combined_words(layout),)
+    out = jax.jit(lambda b: unpack_device_combined(b, layout))(buf)
+    assert sorted(out) == sorted(arrays)
+    for key, sent in arrays.items():
+        want = sent.astype(ml_dtypes.bfloat16) if spec.get(key) == "bf16" else sent
+        got = np.asarray(out[key])
+        assert got.dtype == want.dtype and got.shape == want.shape, key
+        np.testing.assert_array_equal(got.view(np.uint8), want.view(np.uint8), err_msg=key)
+
+
+def test_combined_layout_names_the_padded_rows():
+    """The buffer's length does not give the rows back (7 and 8 rows of
+    sub-word values fill the same planes), so the layout carries them; a
+    layout made from one request's arrays takes the bucket through `rows`."""
+    from distributed_tf_serving_tpu.ops.transfer import (
+        combined_layout,
+        combined_words,
+        describe_layout,
+    )
+
+    spec = {"feat_ids": "u24", "feat_wts": "bf16"}
+    seven, eight = (
+        {"feat_ids": np.zeros((n, 5), np.int32), "feat_wts": np.zeros((n, 5), np.float32)}
+        for n in (7, 8)
+    )
+    assert combined_words(combined_layout(seven, spec)) == combined_words(
+        combined_layout(eight, spec)
+    )
+    assert combined_layout(seven, spec) != combined_layout(eight, spec)
+    assert combined_layout(seven, spec, rows=8) == combined_layout(eight, spec)
+    assert hash(combined_layout(eight, spec)) is not None  # a jit-variant key
+    assert describe_layout(combined_layout(eight, spec)) == (
+        "uint32 words, row planes: feat_ids int32/24b x4, feat_wts bfloat16/16b x2"
+    )
+
+
+_SUBWORD = re.compile(r"tensor<([0-9x]*)x(ui8|i8|ui16|i16|bf16|f16|i1)>")
+
+
+@pytest.mark.parametrize("kinds", [("u24", "bf16", "float32"), ("int32", "bf16"), ("int8", "bf16")])
+@pytest.mark.parametrize("n", [512, 8192])
+def test_unpack_is_lane_dense_in_the_lowered_text(kinds, n):
+    """What the jitted entry's unpack is made of: the argument is uint32, no
+    byte tensor appears but under a 1-byte kind, and no tensor narrower than
+    32 bits has a minor dimension of 2, 3 or 4 (the parent's [n, F, 3] bytes
+    tiled 128 lanes wide: 36% of the device's busy time, PERF.md, PR 26)."""
+    from distributed_tf_serving_tpu.ops.transfer import (
+        combined_layout,
+        combined_words,
+        unpack_device_combined,
+    )
+
+    arrays, spec = {}, {}
+    for i, kind in enumerate(kinds):
+        dtype, how, _ = _KINDS[kind]
+        arrays[f"x{i}"] = np.zeros((n, 13 if kind == "float32" else 214), dtype)
+        if how:
+            spec[f"x{i}"] = how
+    layout = combined_layout(arrays, spec)
+    text = jax.jit(lambda b: unpack_device_combined(b, layout)).lower(
+        jax.ShapeDtypeStruct((combined_words(layout),), np.uint32)
+    ).as_text()
+    assert re.search(rf"@main\(%arg0: tensor<{combined_words(layout)}xui32>", text), text[:400]
+    narrow = _SUBWORD.findall(text)
+    assert narrow, "the bf16 weights are narrower than a word"
+    for dims, dtype in narrow:
+        assert dims.split("x")[-1] not in ("2", "3", "4"), (dims, dtype)
+        assert dtype not in ("ui8", "i8") or "int8" in kinds, (dims, dtype)
+    assert ("i8" in {d for _, d in narrow}) == ("int8" in kinds)
 
 
 def test_combined_not_supported_for_strings_bool_and_8byte():
