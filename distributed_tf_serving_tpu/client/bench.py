@@ -62,27 +62,6 @@ class BenchReport:
         }
 
 
-def transfer_counters(stats) -> dict:
-    """D2H attribution block for bench artifacts, from a BatcherStats-like
-    object (duck-typed: this client package stays jax/batcher-import-free).
-    Pairs the actual wire bytes fetched (post output-compaction dtype, post
-    output filter) with the full-fp32 all-outputs baseline, plus how much
-    of the in-flight readback window the completer threads actually
-    blocked on (1.0 = the transfer hid entirely behind other work)."""
-    down = getattr(stats, "bytes_downloaded", 0)
-    full = getattr(stats, "bytes_download_full_f32", 0)
-    return {
-        "bytes_downloaded_mb": round(down / 1e6, 3),
-        "bytes_full_f32_mb": round(full / 1e6, 3),
-        "bytes_saved_mb": round(max(full - down, 0) / 1e6, 3),
-        "compaction_ratio": round(full / down, 2) if down else None,
-        "readback_overlap_fraction": round(
-            getattr(stats, "readback_overlap_fraction", 0.0), 3
-        ),
-        "topk_batches": getattr(stats, "topk_batches", 0),
-    }
-
-
 def make_payload(candidates: int = 1500, num_fields: int = 43, seed: int = 7):
     """The reference workload point: [candidateNum, FIELD_NUM] int64 ids +
     float weights (DCNClient.java:25,29,57-74)."""

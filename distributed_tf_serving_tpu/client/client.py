@@ -73,7 +73,7 @@ def keepalive_channel_options(
 
 @dataclasses.dataclass
 class ResilienceCounters:
-    """Client-side resilience events (bench.py / soak report these)."""
+    """Client-side resilience events (tools/soak.py reports these)."""
 
     hedges_fired: int = 0
     hedges_won: int = 0
@@ -1258,7 +1258,7 @@ class ShardedPredictClient:
 
     def resilience_counters(self) -> dict:
         """Client-side resilience events + per-backend scoreboard state —
-        the block bench.py and tools/soak.py report."""
+        the block the fleet router and tools/soak.py report."""
         out = dataclasses.asdict(self.counters)
         if self.scoreboard is not None:
             out["scoreboard"] = self.scoreboard.snapshot()

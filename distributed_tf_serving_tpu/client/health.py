@@ -152,7 +152,7 @@ class BackendScoreboard:
         self._clock = clock
         self._lock = threading.Lock()
         self._states = [_HostState() for _ in self.hosts]
-        # Event counters (bench.py / soak report them; names are the
+        # Event counters (tools/soak.py reports them; names are the
         # acceptance-criteria vocabulary).
         self.ejections = 0
         self.probes = 0

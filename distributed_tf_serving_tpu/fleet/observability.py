@@ -161,7 +161,7 @@ def hop_waterfall(top: dict) -> dict | None:
         comps["device"] = sum(int(n.get("duration_us") or 0) for n in device)
         comps["readback_wait"] = sum(
             int(n.get("duration_us") or 0)
-            for n in _find_all(server, ("readback.wait", "batch.readback"))
+            for n in _find_all(server, ("readback.wait",))
         )
     own_source = top.get("source")
     merges = [

@@ -13,7 +13,7 @@ jitted entries so measurement and serving share compiled executables:
 
 Gates (config, [kernels] section): measured speedup >= min_speedup AND
 max |Δscore| vs the f32 baseline <= max_abs_delta AND — when a labeled
-eval set is supplied (bench.py's trained-model block, the CI smoke) —
+eval set is supplied (the CI smoke, tools/check_kernel_smoke.py) —
 |AUC_f32 - AUC_variant| <= auc_margin. A variant that errors or fails a
 gate is recorded with its reason and left DISABLED — except a Pallas
 variant the accelerator's compiler refuses, which raises
@@ -507,10 +507,10 @@ class KernelManager:
     def _warm_enabled(self, batcher, servable, decisions: dict) -> None:
         """Compile the entry variants LIVE traffic hits for every enabled
         (bucket, decision): the harness only measured the score-only
-        non-donating entry, but live buckets serve the all-outputs entry
-        (unfiltered requests) and the donating combined variant — left
-        cold, the first live batch after enablement would pay a fresh
-        XLA/Pallas compile on the dispatch path under the wedge clock
+        entry, but live buckets also serve the all-outputs entry
+        (unfiltered requests) — left cold, the first live batch after
+        enablement would pay a fresh XLA/Pallas compile on the dispatch
+        path under the wedge clock
         (with [recovery] armed, a >15s compile trips a spurious
         quarantine). The warmup contract applies to variants too."""
         import jax
@@ -527,13 +527,6 @@ class KernelManager:
                         servable, dict(arrays), out_keys=out_keys,
                         _kernel_override=flags,
                     ))
-                _, _, combined = b.jit_entry(servable)
-                if combined and b._donation_ok():
-                    for out_keys in (None, score_only):
-                        jax.block_until_ready(b._execute(
-                            servable, dict(arrays), out_keys=out_keys,
-                            _force_donate=True, _kernel_override=flags,
-                        ))
             except Exception:  # noqa: BLE001 — a failed warm compiles at
                 # first use instead; never blocks enablement itself.
                 log.exception(

@@ -363,19 +363,6 @@ class DeviceInputCache:
         self._win_lookups = 0
         self._bypassed_lookups = 0
 
-    def rearm(self) -> None:
-        """Exit bypass immediately and restart the probe cycle — for
-        callers that KNOW a traffic-regime boundary just happened (a bench
-        phase change, a deployment cutover) and should not wait out the
-        automatic re-probe cadence. One locked reset of the full counter
-        set so external callers cannot drift from _note_bypassed's own
-        re-arm sequence."""
-        with self._lock:
-            self.bypassed = False
-            self._bypassed_lookups = 0
-            self._win_hits = 0
-            self._win_lookups = 0
-
     def _note_bypassed(self) -> None:
         """Count a pass-through lookup; periodically re-enter probing."""
         with self._lock:
@@ -484,7 +471,7 @@ class _HostBufferRing:
     per input; at depth-k pipelining that is k live multi-MB allocations
     per model churning through the allocator while the device works. The
     ring hands back the SAME buffers once their batch fully completes —
-    donation-safe by construction: a buffer is released only from the
+    safe to reuse by construction: a buffer is released only from the
     completer's finally (the batch's readback finished, so the H2D upload
     that read it is long done) or from a pre-device failure path, never
     while a transfer could still be reading it. The padding loops fully
@@ -828,9 +815,7 @@ class DynamicBatcher:
         buffer_ring: bool = False,
         output_wire_dtype: str = "float32",
         output_top_k: int = 0,
-        async_readback: bool = True,
         pipelined_dispatch: bool = True,
-        donate_buffers: bool = True,
         score_cache=None,
         row_cache=None,
         dedup: bool = False,
@@ -912,9 +897,6 @@ class DynamicBatcher:
         self.output_wire_dtype = output_wire_dtype
         self._wire_dt = _wire_dtype_of(output_wire_dtype)
         self.output_top_k = max(int(output_top_k or 0), 0)
-        self.async_readback = async_readback
-        self.donate_buffers = donate_buffers
-        self._donate_ok: bool | None = None  # resolved lazily (backend init)
         # Content-addressed device-resident inputs (only meaningful for the
         # default jit path; a custom run_fn manages its own placement).
         self.input_cache = (
@@ -966,7 +948,7 @@ class DynamicBatcher:
         # accumulate unbounded in-flight HBM. 0 = unbounded (the
         # historical behavior).
         self.inflight_window = max(int(inflight_window or 0), 0)
-        # Donation-safe padded-batch buffer reuse; None = allocate fresh
+        # Padded-batch host buffer reuse; None = allocate fresh
         # per batch (the historical behavior).
         self.buffer_ring = (
             _HostBufferRing(per_key=max(self.inflight_window, 4) + 4)
@@ -1470,16 +1452,13 @@ class DynamicBatcher:
         the reference client filters to its output_key), the top-k entry
         when configured (its queue-path gate skips warmup items, so ONLY
         this direct pass can precompile it — a live compile on the dispatch
-        path would stall the pipeline with the wedge clock armed), and the
-        donating variant of each where buffer donation is effective
-        (cache-bypass traffic compiles a distinct executable; its first
-        batch must not pay the compile). A client filtering to any OTHER
-        output subset still compiles its variant at first request — rare
-        enough (subsets of the signature's outputs) that warming the
-        combinatorial space is not worth the load-time."""
+        path would stall the pipeline with the wedge clock armed). A client
+        filtering to any OTHER output subset still compiles its variant at
+        first request — rare enough (subsets of the signature's outputs)
+        that warming the combinatorial space is not worth the load-time."""
         model = servable.model
         if self._run_fn is not None:
-            # Custom executors ignore donate/topk — but an executor that
+            # Custom executors ignore topk — but an executor that
             # honors output selection (the mesh path's supports_out_keys)
             # compiles a distinct executable per out_keys, so both
             # variants live traffic predictably hits (all-outputs +
@@ -1511,18 +1490,10 @@ class DynamicBatcher:
                         self._execute(servable, arrays, out_keys=out_keys)
             return
         score_only = (model.score_output,)
-        _, _, combined = self._jit_for(servable)
         for b in buckets or self.buckets:
             arrays = prepare_inputs(model, self.warmup_arrays(servable, b))
             for out_keys in (None, score_only):
                 self._execute(servable, arrays, out_keys=out_keys)
-                if combined and self._donation_ok():
-                    # Only combined entries HAVE a donating variant; the
-                    # per-key path ignores donate, and re-running it would
-                    # just double warmup time for the slowest (x64) models.
-                    self._execute(
-                        servable, arrays, out_keys=out_keys, _force_donate=True
-                    )
             if (
                 self.output_top_k
                 and self._run_fn is None
@@ -1558,12 +1529,12 @@ class DynamicBatcher:
 
     def jit_entry(self, servable: Servable) -> tuple[Callable, dict[str, str], bool]:
         """The (jitted fn, transfer spec, combined) this batcher serves
-        `servable` with — public so measurement harnesses (bench.py's
-        device-limited decomposition) can time the EXACT serving executable,
-        warm caches included, instead of compiling a lookalike. When
+        `servable` with — public so the kernel autotune (ops/autotune.py)
+        and tests time and inspect the EXACT serving executable, warm caches
+        included, instead of compiling a lookalike. When
         `combined` is True the fn signature is (params, uint32_buffer,
         layout) with layout static (ops/transfer.py combined_layout); both
-        shapes accept optional keywords (out_keys, donate, topk, n_valid)
+        shapes accept optional keywords (out_keys, topk, n_valid)
         selecting the output-compaction variant — defaults reproduce the
         all-outputs entry (see _build_entry)."""
         return self._jit_for(servable)
@@ -1814,17 +1785,6 @@ class DynamicBatcher:
 
     # ------------------------------------------------------------- internals
 
-    def _donation_ok(self) -> bool:
-        """Buffer donation is effective only off-CPU (the CPU backend
-        ignores it with a warning per call) and only when enabled.
-        Resolved lazily so constructing a batcher never forces backend
-        init."""
-        if self._donate_ok is None:
-            self._donate_ok = (
-                self.donate_buffers and jax.default_backend() != "cpu"
-            )
-        return self._donate_ok
-
     def _jit_for(self, servable: Servable) -> tuple[Callable, dict[str, str], bool]:
         with self._jit_lock:
             entry = self._jitted.get(servable)
@@ -1845,22 +1805,19 @@ class DynamicBatcher:
         - out_keys: hashable tuple restricting which model outputs the
           EXECUTABLE returns (None = all). Dead outputs are DCE'd by XLA
           and never materialize in HBM, let alone cross the D2H link.
-        - donate: donate the combined input buffer's HBM to the executable
-          (single-use buffers only — never cache-resident ones).
         - topk/n_valid: top-k output compaction — only the k best
           (score, index) pairs of the first n_valid rows come back.
           n_valid is traced, so executables key on (bucket, k) alone.
 
-        Each distinct (layout, out_keys, donate, topk) is a separate jit
-        closure, cached here exactly like the old per-layout cache; the
-        inner jax.jit trace cache still keys on buffer shape. The variant
-        count is bounded by the distinct output_filter subsets clients
-        actually send (the service validates filters against the signature,
-        so the space is subsets of the signature's outputs — a handful),
-        not by traffic volume. All float32 outputs are downcast to the
-        configured wire dtype on-device, and the full-fp32 row bytes are
-        recorded at trace time so the bytes_download_full_f32 counter
-        charges an honest baseline.
+        Each distinct (layout, out_keys, topk, k_apply, prune) is a
+        separate jit closure, cached here; the inner jax.jit trace cache
+        still keys on buffer shape. The variant count is bounded by the
+        distinct output_filter subsets clients actually send (the service
+        validates filters against the signature, so the space is subsets of
+        the signature's outputs — a handful), not by traffic volume. All
+        float32 outputs are downcast to the configured wire dtype on-device,
+        and the full-fp32 row bytes are recorded at trace time so the
+        bytes_download_full_f32 counter charges an honest baseline.
         """
         model = servable.model
         spec = transfer_spec(model) if self.compress_transfer else {}
@@ -1897,10 +1854,7 @@ class DynamicBatcher:
 
         def named(run, topk, prune):
             # The executable's name in a profiler trace (`jit_<name>`, the
-            # host plane's `PjitFunction(<name>)`): model and variant. The
-            # donating twin keeps its twin's name: the name is part of the
-            # persistent compile cache's key and donation is not, so the
-            # two share one compiled entry there, as they always have.
+            # host plane's `PjitFunction(<name>)`): model and variant.
             variant = "prune" if prune else "topk" if topk else "score"
             run.__name__ = re.sub(r"\W", "_", f"{servable.name}_{variant}")
             return run
@@ -1922,7 +1876,7 @@ class DynamicBatcher:
             # (round-4 microbench: 426 -> 251 us/call arg processing),
             # and each variant's jit compiles for its one buffer shape.
             def fn(
-                params, buf, layout, out_keys=None, donate=False,
+                params, buf, layout, out_keys=None,
                 topk=0, n_valid=None, k_apply=None, prune=False,
                 _cache=variants,
             ):
@@ -1932,12 +1886,11 @@ class DynamicBatcher:
                 # the variant key so the Pallas and XLA executables
                 # coexist; quantized params need no key (jax.jit retraces
                 # on the distinct param-tree structure).
-                key = (layout, out_keys, donate, topk, k_apply, prune)
+                key = (layout, out_keys, topk, k_apply, prune)
                 jfn = _cache.get(key)
                 if jfn is None:
                     if (fmt := describe_layout(layout)) not in formats:
                         formats.append(fmt)
-                    donargs = (1,) if donate else ()
                     ap = k_apply or apply
                     if topk:
                         select = cascade_prune_device if prune \
@@ -1950,13 +1903,11 @@ class DynamicBatcher:
                     else:
                         def run(p, b, _l=layout, _ok=out_keys, _ap=ap):
                             return finish(_ap(p, unpack_device_combined(b, _l)), _ok)
-                    jfn = _cache[key] = jax.jit(
-                        named(run, topk, prune), donate_argnums=donargs
-                    )
+                    jfn = _cache[key] = jax.jit(named(run, topk, prune))
                 return jfn(params, buf, n_valid) if topk else jfn(params, buf)
         else:
             def fn(
-                params, packed, out_keys=None, donate=False,
+                params, packed, out_keys=None,
                 topk=0, n_valid=None, k_apply=None, prune=False,
                 _cache=variants,
             ):
@@ -2100,11 +2051,6 @@ class DynamicBatcher:
                 cache._note_bypassed()
             with request_trace.span("batch.fusedpack"):
                 buf = build()
-        # Donate only single-use buffers: a cache-resident device array's
-        # HBM must survive this call for the next content hit. Cache-held
-        # buffers are jax.Arrays; only a bypass/no-cache build hands back
-        # the single-use host buffer.
-        donate = isinstance(buf, np.ndarray) and self._donation_ok()
         # np.int32, matching _execute and warmup(): a raw Python int has a
         # different jax aval (weak type) and would force a fresh trace on
         # the first live fused top-k batch despite warmup's precompile.
@@ -2115,7 +2061,7 @@ class DynamicBatcher:
         with request_trace.span("batch.jitcall"):
             return fn(
                 k_params, buf, layout,
-                out_keys=out_keys, donate=donate, topk=topk, n_valid=n_valid,
+                out_keys=out_keys, topk=topk, n_valid=n_valid,
                 k_apply=k_apply, prune=prune,
             )
 
@@ -2161,16 +2107,13 @@ class DynamicBatcher:
         topk: int = 0,
         n_valid: int | None = None,
         prune: bool = False,
-        _force_donate: bool = False,
         _kernel_override=None,
     ):
         """Device stage for one padded batch: fold, content cache, pack,
         upload, jit call. out_keys/topk/n_valid ride through to the jitted
         entry (output selection and top-k compaction are traced into the
-        executable); _force_donate is the warmup hook that precompiles the
-        donating variant without going through cache-bypass traffic;
-        _kernel_override pins the kernel plane's (quantized, pallas)
-        variant for the autotune harness."""
+        executable); _kernel_override pins the kernel plane's (quantized,
+        pallas) variant for the autotune harness."""
         arrays = self._fold_host(servable, arrays)
         if self._run_fn is not None:
             if getattr(self._run_fn, "supports_out_keys", False):
@@ -2200,7 +2143,7 @@ class DynamicBatcher:
         with ctx:
             if combined:
                 layout = combined_layout(arrays, spec)
-                cache = None if _force_donate else self.input_cache
+                cache = self.input_cache
                 if cache is not None:
                     # Digest the RAW arrays (a content hit skips pack AND
                     # concat AND upload); layout in the tag keeps distinct
@@ -2211,22 +2154,15 @@ class DynamicBatcher:
                             build=lambda: pack_host_combined(arrays, spec),
                             tag=str(layout),
                         )
-                    # A cache-resident device buffer must never be donated
-                    # (its HBM has to survive for the next content hit);
-                    # bypass-mode lookups hand back the single-use HOST
-                    # buffer, which is safe to donate.
-                    donate = isinstance(buf, np.ndarray) and self._donation_ok()
                 else:
                     buf = pack_host_combined(arrays, spec)
-                    donate = _force_donate or self._donation_ok()
                 with request_trace.span("batch.jitcall"):
                     return fn(
                         k_params, buf, layout,
-                        out_keys=out_keys, donate=donate,
-                        topk=topk, n_valid=n_valid, k_apply=k_apply,
-                        prune=prune,
+                        out_keys=out_keys, topk=topk, n_valid=n_valid,
+                        k_apply=k_apply, prune=prune,
                     )
-            if self.input_cache is not None and not _force_donate:
+            if self.input_cache is not None:
                 # Digest BEFORE packing: a content hit skips both the upload
                 # and the pack (u24/bf16) work.
                 with request_trace.span("batch.cache"):
@@ -2466,7 +2402,7 @@ class DynamicBatcher:
             [] if tracing.enabled() and any(it.span is not None for it in group)
             else None
         )
-        # Donation-safe buffer ring: padded-batch buffers acquired here are
+        # Buffer ring: padded-batch buffers acquired here are
         # released only after the batch fully completes (the completer's
         # finally) or on a pre-device failure path — never while the async
         # H2D upload could still be reading them.
@@ -3125,9 +3061,8 @@ class DynamicBatcher:
                 and integ.want_shadow()
             ):
                 # Shadow verification (ISSUE 20): re-execute the SAME
-                # jitted entry over the same inputs — donation-safe
-                # because the shadow arrays are host buffers device_put
-                # fresh per _execute call — and hand both device results
+                # jitted entry over the same inputs (host buffers, uploaded
+                # afresh by each _execute call) and hand both device results
                 # to the completer for a host-side bit-identity compare.
                 # Any divergence is hardware miscomputation (same
                 # program, same input, one device): OutputCorruptError
@@ -3190,21 +3125,20 @@ class DynamicBatcher:
                     if (shape := getattr(v, "shape", None)) is not None
                 )
             issue_t0 = time.perf_counter()
-            if self.async_readback:
-                # Start the device->host readback now; the completer thread
-                # then finds the bytes already (or sooner) on host.
-                with tracing.annotation("readback.issue"):
-                    for v in fetch.values():
+            # Start the device->host readback now; the completer thread
+            # then finds the bytes already (or sooner) on host.
+            with tracing.annotation("readback.issue"):
+                for v in fetch.values():
+                    if hasattr(v, "copy_to_host_async"):
+                        v.copy_to_host_async()
+                if shadow_fetch is not None:
+                    for v in shadow_fetch.values():
                         if hasattr(v, "copy_to_host_async"):
                             v.copy_to_host_async()
-                    if shadow_fetch is not None:
-                        for v in shadow_fetch.values():
-                            if hasattr(v, "copy_to_host_async"):
-                                v.copy_to_host_async()
-                with sink_ctx():
-                    request_trace.add(
-                        "readback.issue", time.perf_counter() - issue_t0
-                    )
+            with sink_ctx():
+                request_trace.add(
+                    "readback.issue", time.perf_counter() - issue_t0
+                )
 
             self.stats.batches += 1
             self.stats.requests += len(group)
@@ -3348,19 +3282,15 @@ class DynamicBatcher:
                 # aborted after dispatch; classified device-fatal).
                 faults.fire("readback")
                 faults.fire("executor_abort")
-                # The fetch: with async_readback the copy is already in
-                # flight (issued at dispatch), so this measures the residual
-                # WAIT, not a full synchronous transfer — the split the
-                # phase names carry.
-                fetch_phase = (
-                    "readback.wait" if self.async_readback else "batch.readback"
-                )
+                # The fetch: the copy is already in flight (issued at
+                # dispatch), so this measures the residual WAIT, not a full
+                # synchronous transfer.
                 wait_t0 = time.perf_counter()
-                with tracing.annotation(fetch_phase):
+                with tracing.annotation("readback.wait"):
                     host = {k: np.asarray(v) for k, v in outputs.items()}
                 done_t = time.perf_counter()
                 waited = done_t - wait_t0
-                request_trace.add(fetch_phase, waited)
+                request_trace.add("readback.wait", waited)
             # Everything from here to the last set_result is this batch's
             # delivery: one span, closed in the finally below.
             delivery.enter_context(request_trace.span("batch.deliver"))
@@ -3421,9 +3351,7 @@ class DynamicBatcher:
             with self._cv:  # counters race across completer threads otherwise
                 self.stats.bytes_downloaded += downloaded
                 self.stats.readback_window_s += window
-                self.stats.readback_blocked_s += (
-                    waited if self.async_readback else window
-                )
+                self.stats.readback_blocked_s += waited
             if meta is not None and "prune_n" in meta:
                 # Cascade stage-1 prune: widen the wire-dtype arrays to
                 # f32 and hand all three through — the orchestrator does

@@ -757,7 +757,7 @@ class RestGateway:
     async def profilez_start(self, request: web.Request) -> web.Response:
         """POST /profilez/start?seconds=N: one-shot deep capture —
         jax.profiler device trace + host-thread stack sampling over the
-        same window (tools/profile_host.py methodology). Returns the
+        same window (utilization.HostStackSampler). Returns the
         artifact paths immediately; the capture stops itself after N
         seconds. A concurrent capture is refused with 409 (the jax
         profiler is process-global)."""

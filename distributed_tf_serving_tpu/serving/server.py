@@ -1766,9 +1766,7 @@ def build_stack(
         completion_workers=cfg.completion_workers,
         output_wire_dtype=cfg.output_wire_dtype,
         output_top_k=cfg.output_top_k,
-        async_readback=cfg.async_readback,
         pipelined_dispatch=cfg.pipelined_dispatch,
-        donate_buffers=cfg.donate_buffers,
         score_cache=score_cache,
         row_cache=row_cache,
         # `enabled` is the MASTER switch for the whole cache plane: a

@@ -40,9 +40,8 @@ tracing/cache/overload precedent):
 - **On-demand deep capture**: `POST /profilez/start?seconds=N` runs a
   `jax.profiler.trace` capture (CPU-safe; artifact dir returned;
   concurrent captures refused with 409) and simultaneously samples every
-  host thread's Python stack (the tools/profile_host.py methodology,
-  shared here as HostStackSampler) so one call captures the device and
-  host sides of the same window together.
+  host thread's Python stack (HostStackSampler) so one call captures the
+  device and host sides of the same window together.
 
 The ledger is jax-free; only ProfilerCapture imports jax, lazily, when a
 capture actually starts.
@@ -529,8 +528,7 @@ class OccupancyLedger:
 
 
 class HostStackSampler:
-    """Periodic Python-stack sampler over every live thread — the
-    tools/profile_host.py host-side methodology packaged for on-demand
+    """Periodic Python-stack sampler over every live thread, for on-demand
     capture. Aggregates collapsed stacks (``func (file:line);...``) per
     thread name; the report is a plain dict the REST surface serializes.
     Pure stdlib; sampling cost is bounded by interval_s and stack depth."""

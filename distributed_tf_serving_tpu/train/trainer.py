@@ -89,10 +89,10 @@ class Trainer:
     ):
         self.model = model
         self.mesh = mesh
-        # learning_rate may be an optax schedule (bench.py passes
-        # warmup+cosine: the synthetic task is id memorization from noisy
-        # Bernoulli views, where a hot constant LR stops short of the
-        # information limit — the tail needs decay to average the noise).
+        # learning_rate may be an optax schedule (a cosine decay in
+        # tools/check_kernel_smoke.py: the synthetic task is id memorization
+        # from noisy Bernoulli views, where a hot constant LR stops short of
+        # the information limit — the tail needs decay to average the noise).
         self.optimizer = optax.adamw(learning_rate)
         params = jax.jit(model.init)(jax.random.PRNGKey(seed))
         if mesh is not None:
@@ -103,10 +103,10 @@ class Trainer:
         self._eval_apply = jax.jit(model.apply)  # compiled once, reused per eval
         # stream_config sets the data's difficulty: id catalog density
         # decides how many noisy Bernoulli views each embedding row gets per
-        # epoch-equivalent (short bench runs want a denser catalog — see
-        # bench.py train_on_chip). The default keeps the catalog within the
-        # vocab so folding is injective and every id's embedding can learn
-        # its teacher weight.
+        # epoch-equivalent (a short fit wants a denser catalog, as
+        # tools/soak.py's quality mode sets). The default keeps the catalog
+        # within the vocab so folding is injective and every id's embedding
+        # can learn its teacher weight.
         self.stream = SyntheticCTRStream(
             stream_config
             or SyntheticCTRConfig(
@@ -225,7 +225,7 @@ def main(argv=None) -> None:
     parser.add_argument("--id-space", type=int, default=0,
                         help="synthetic catalog size (0 = min(2^18, vocab)); "
                         "denser catalogs give each embedding row more views "
-                        "per step — see bench.py train_on_chip")
+                        "per step")
     args = parser.parse_args(argv)
 
     config = ModelConfig(

@@ -45,20 +45,11 @@ class ServerConfig:
     # full-length score vector with 0.0 off the head (sigmoid scores are
     # strictly positive, so ranking consumers see the same head). 0 = off.
     output_top_k: int = 0
-    # Issue copy_to_host_async() at dispatch so the completer's fetch waits
-    # on an in-flight transfer (readback.issue / readback.wait phases)
-    # instead of starting one (batch.readback). False = the synchronous
-    # fallback path.
-    async_readback: bool = True
     # Run the device stage (cache/pack/upload/jit-call) on a dedicated
     # dispatch thread so the batching thread's collect+pad of batch k+1
     # overlaps batch k's H2D upload and dispatch. False = the previous
     # single-threaded dispatch.
     pipelined_dispatch: bool = True
-    # Donate single-use combined input buffers to the jitted entry (XLA
-    # reuses their HBM for outputs). Only effective off-CPU and only for
-    # buffers the DeviceInputCache did not retain.
-    donate_buffers: bool = True
     warmup: bool = True
     # Coalescing keeps filling past max_wait while this many batches are in
     # flight (latency-free: the dispatch would queue behind device work
@@ -192,8 +183,8 @@ class ClientConfig:
 @dataclasses.dataclass(frozen=True)
 class BatchingConfig:
     """Continuous-batching pipeline knobs (serving/batcher.py, ISSUE 9):
-    the k-deep dispatch/in-flight window, donation-safe padded-batch
-    buffer reuse, and the server-side sub-batch split PredictStream uses.
+    the k-deep dispatch/in-flight window, padded-batch host buffer
+    reuse, and the server-side sub-batch split PredictStream uses.
     Every NEW behavior defaults off — pipeline_depth 0 inherits the
     [server] value (historically 2), inflight_window 0 keeps in-flight
     readbacks unbounded, buffer_ring false allocates per batch, and
@@ -208,8 +199,8 @@ class BatchingConfig:
     # readback): the dispatch thread keeps issuing batch k+2 while k
     # awaits readback until the window fills. 0 = unbounded (historical).
     inflight_window: int = 0
-    # Reuse padded-batch host buffers across batches (released only after
-    # the owning batch's readback completes — donation-safe).
+    # Reuse padded-batch host buffers across batches (a buffer is reused
+    # only after its batch's upload and readback are done).
     buffer_ring: bool = False
     # Default candidates per PredictStream sub-batch (the server-side
     # split; requests may override via x-dts-stream-chunk metadata).

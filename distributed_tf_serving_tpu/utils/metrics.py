@@ -1865,8 +1865,8 @@ def resilience_prometheus_text(resilience: dict) -> str:
     """Prometheus text exposition of the CLIENT resilience state — the
     dict client.ShardedPredictClient.resilience_counters() returns
     (ResilienceCounters fields + an optional BackendScoreboard snapshot).
-    The client has no scrape port of its own; bench.py/soak write this
-    next to their artifacts so fleet dashboards ingest client-side hedging
+    The client has no scrape port of its own; a caller writes this
+    next to its artifacts so fleet dashboards ingest client-side hedging
     /failover/ejection state in the same format as the server plane."""
     esc = escape_label_value
     lines = []

@@ -19,7 +19,7 @@ ate the budget. This module adds the per-request plane:
   slowest-N are always kept, everything else is sampled. `/tracez`
   (serving/rest.py) serves its contents as JSON; `chrome_trace()` exports
   Chrome-trace-event JSON that Perfetto / chrome://tracing load directly
-  (bench.py --trace-out and tools/soak.py write it to disk).
+  (tools/soak.py writes it to disk).
 - **collect_phases**: a thread-local sink that lets the batcher's existing
   PhaseTrace call sites double as per-request span producers — one pair of
   clock reads feeds both the aggregate and the span tree.

@@ -404,6 +404,33 @@ def test_warmup_arrays_signature_driven():
         batcher.stop()
 
 
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_warmup_runs_each_bucket_twice(servable, monkeypatch, backend):
+    """The ladder warm-up executes a bucket once an output selection live
+    traffic hits (all outputs, score-only) and leaves one jit variant each
+    in the entry's cache, whatever backend jax reports: there is one
+    executable a (layout, outputs, top-k), not a pair."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    batcher = DynamicBatcher(buckets=(16, 32), max_wait_us=0)
+    calls = []
+    execute = batcher._execute
+
+    def counting(sv, arrays, **kwargs):
+        calls.append((arrays["feat_ids"].shape[0], kwargs.get("out_keys")))
+        return execute(sv, arrays, **kwargs)
+
+    monkeypatch.setattr(batcher, "_execute", counting)
+    batcher.warmup(servable)
+    score_only = (servable.model.score_output,)
+    assert calls == [(16, None), (16, score_only), (32, None), (32, score_only)]
+    fn, _, combined = batcher.jit_entry(servable)
+    assert combined
+    variants = fn.__defaults__[-1]  # the entry's `_cache`: key -> jitted fn
+    assert len(variants) == 4
+    assert sorted(key[0][0] for key in variants) == [16, 16, 32, 32]  # rows
+    assert {key[1] for key in variants} == {None, score_only}
+
+
 # ------------------------------------------------- overload / wedge defense
 
 

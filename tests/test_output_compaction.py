@@ -5,13 +5,12 @@
   encoding (>=4x under the full-fp32 all-outputs baseline for score-only
   fetches at bf16);
 - score parity <=1e-2 relative at bf16, bit-exact at the float32 fallback;
-- the old batch.readback span is split into readback.issue (dispatch side)
-  and readback.wait (completer side), with the synchronous fallback keeping
-  the legacy span;
+- the readback is two spans, readback.issue (dispatch side) and
+  readback.wait (completer side), with and without the dispatch thread;
 - top-k compaction returns the exact score head with indices, reconstructed
   to the full-length response vector;
-- every knob is config-gated with the previous synchronous full-precision
-  path available (and exercised here) as a fallback.
+- the float32 wire is the full-precision fallback, bit-exact whichever
+  thread dispatches.
 """
 
 import jax
@@ -86,16 +85,15 @@ def test_bf16_wire_parity_and_byte_reduction(servable):
 
 
 def test_f32_wire_is_exact(servable):
-    """The float32 wire through the new pipeline must be bit-identical to
-    the synchronous full-precision fallback path (same executables — the
-    pipeline only changes which thread runs them and when the D2H copy is
-    issued, never the numerics)."""
+    """The float32 wire through the dispatch thread must be bit-identical to
+    inline dispatch (same executables — the pipeline only changes which
+    thread runs them, never the numerics)."""
     pipelined = DynamicBatcher(
         buckets=(32,), max_wait_us=0, output_wire_dtype="float32"
     ).start()
     legacy = DynamicBatcher(
         buckets=(32,), max_wait_us=0, output_wire_dtype="float32",
-        async_readback=False, pipelined_dispatch=False, donate_buffers=False,
+        pipelined_dispatch=False,
     ).start()
     try:
         arrays = make_arrays(19, seed=3)
@@ -113,9 +111,8 @@ def test_unknown_wire_dtype_rejected():
 
 
 def test_readback_span_split(servable):
-    """Async readback records readback.issue + readback.wait instead of
-    one synchronous batch.readback span, and the overlap counters track a
-    window at least as long as the blocked time."""
+    """The readback records readback.issue + readback.wait, and the overlap
+    counters track a window at least as long as the blocked time."""
     batcher = DynamicBatcher(buckets=(32,), max_wait_us=0).start()
     try:
         request_trace.reset()
@@ -123,7 +120,6 @@ def test_readback_span_split(servable):
         phases = request_trace.snapshot()
         assert "readback.issue" in phases
         assert "readback.wait" in phases
-        assert "batch.readback" not in phases
         stats = batcher.stats
         assert stats.readback_window_s >= stats.readback_blocked_s > 0
         assert 0.0 <= stats.readback_overlap_fraction <= 1.0
@@ -133,13 +129,13 @@ def test_readback_span_split(servable):
 
 
 def test_sync_fallback_path(servable):
-    """async_readback=False + pipelined_dispatch=False + float32 wire is
-    the previous synchronous full-precision path: legacy batch.readback
-    span, zero overlap, exact scores."""
+    """pipelined_dispatch=False + float32 wire is inline dispatch at full
+    precision: no dispatch thread, the device stage runs on the batching
+    thread, the fetch is still issued at dispatch and awaited by a
+    completer, exact scores."""
     batcher = DynamicBatcher(
         buckets=(32,), max_wait_us=0,
-        output_wire_dtype="float32", async_readback=False,
-        pipelined_dispatch=False, donate_buffers=False,
+        output_wire_dtype="float32", pipelined_dispatch=False,
     ).start()
     try:
         assert batcher._dispatcher is None
@@ -148,10 +144,10 @@ def test_sync_fallback_path(servable):
         got = batcher.submit(servable, arrays).result(timeout=30)["prediction_node"]
         np.testing.assert_allclose(got, golden(servable, arrays), rtol=1e-6)
         phases = request_trace.snapshot()
-        assert "batch.readback" in phases
-        assert "readback.issue" not in phases and "readback.wait" not in phases
-        assert batcher.stats.readback_overlap_fraction == 0.0
-        assert batcher.stats.bytes_downloaded > 0
+        assert "readback.issue" in phases and "readback.wait" in phases
+        stats = batcher.stats
+        assert stats.readback_window_s >= stats.readback_blocked_s > 0
+        assert stats.bytes_downloaded > 0
     finally:
         batcher.stop()
         request_trace.reset()

@@ -2,7 +2,7 @@
 the PredictStream RPC end to end (service generator, both transports,
 UDS), the client's incremental out-of-order merge, partial-failure
 degradation with the scoreboard, deadline expiry mid-stream, the k-deep
-in-flight window, the donation-safe buffer ring, and the [batching] /
+in-flight window, the buffer ring, and the [batching] /
 [transport] config sections."""
 
 import asyncio

@@ -1,6 +1,8 @@
 """Aux-subsystem tests: histogram percentiles, server metrics, phase traces,
 TOML config loading (SURVEY.md §5 obligations)."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -157,6 +159,18 @@ def test_toml_unknown_key_rejected(tmp_path):
     p.write_text("[srever]\n")
     with pytest.raises(ValueError, match="unknown config sections"):
         load_config(p)
+
+
+@pytest.mark.parametrize("key", ["donate_buffers", "async_readback"])
+def test_toml_removed_server_key_refused_by_name(tmp_path, key):
+    """A [server] switch that was taken out (it had one value in use, or
+    never took effect) fails a stale operator file loudly, naming the key,
+    instead of being read and ignored."""
+    p = tmp_path / "stale.toml"
+    p.write_text(f"[server]\nport = 9999\n{key} = true\n")
+    with pytest.raises(ValueError, match=f"unknown ServerConfig keys.*'{key}'"):
+        load_config(p)
+    assert key not in {f.name for f in dataclasses.fields(ServerConfig)}
 
 
 def test_model_section_in_toml(tmp_path):

@@ -1084,10 +1084,10 @@ def main() -> None:
         from distributed_tf_serving_tpu.train import Trainer
         from distributed_tf_serving_tpu.train.data import SyntheticCTRConfig
 
-        # Dense id catalog (the bench's CPU train_id_space): each id gets
+        # Dense id catalog: each id gets
         # enough noisy Bernoulli views inside a short fit that the model
         # actually generalizes — at the full vocab the same steps leave
-        # AUC at coin-flip (bench.py train_on_chip's finding).
+        # AUC at coin-flip.
         stream_cfg = SyntheticCTRConfig(
             num_fields=NUM_FIELDS,
             id_space=min(1 << 12, config.vocab_size),
