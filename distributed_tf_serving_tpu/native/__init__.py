@@ -14,8 +14,10 @@ pybind11 is not in this image; the C ABI keeps them trivial.
 from __future__ import annotations
 
 import ctypes
+import functools
 import hashlib
 import logging
+import math
 import os
 import pathlib
 import subprocess
@@ -142,12 +144,10 @@ def _load_locked(build: bool = True) -> ctypes.CDLL | None:
         ctypes.c_char_p, ctypes.c_int64,
         ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p,
     ]
-    lib.pack_batch_u24_bf16.argtypes = [
-        ctypes.POINTER(ctypes.c_void_p), ctypes.c_void_p,
-        ctypes.POINTER(ctypes.c_void_p), ctypes.c_void_p,
-        ctypes.c_void_p, ctypes.c_int64,
-        ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
-        ctypes.c_void_p, ctypes.c_void_p,
+    lib.assemble_batch.argtypes = [
+        ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+        ctypes.c_int64, ctypes.c_void_p,
     ]
     return lib
 
@@ -297,61 +297,111 @@ def f32_to_bf16(wts: np.ndarray) -> np.ndarray:
     return out
 
 
-def pack_batch_u24_bf16(
-    ids_parts: list[np.ndarray],
-    wts_parts: list[np.ndarray],
-    fields: int,
-    bucket: int,
-    vocab: int,
-) -> np.ndarray:
-    """Fused batch assembly (see hostops.cc): per-request [n_p, F] id/weight
-    arrays -> the final padded combined uint32 buffer
-    [four row planes of u24 ids in 3 words | two row planes of bf16 wts in 1]
-    (ops/transfer.py's word format) in one pass per input, zero padding
-    included. ids int64 are folded mod vocab; int32 (compact wire) pass
-    through; wts f32 are RNE-cast; bf16 copied. The per-part arrays must be
-    C-contiguous [n, fields] (the batcher's prepare_inputs guarantees it
-    for wire-decoded arrays; anything else is made contiguous here)."""
+# Planes a word group of the combined upload holds, by the packed width of a
+# value in bits (the word format: hostops.cc pack_words, ops/transfer.py).
+PLANES = {32: 1, 16: 2, 8: 4, 24: 4}
+
+# How assemble_batch reads one part (hostops.cc's enum).
+_RAW32, _FOLD64, _BF16_F32, _RAW16, _RAW8 = range(5)
+
+
+@functools.lru_cache(maxsize=None)
+def part_kinds(bits: int, dtype_str: str, folds: bool) -> dict[np.dtype, int]:
+    """The part dtypes assemble_batch can read for one entry of a combined
+    layout (packed width, dtype the device unpacks to), each with how it is
+    read: the entry's own dtype as it is; int32 ids for a u24 entry; float32
+    for a bf16 entry (cast here, RNE); int64 for an int32 entry whose ids
+    are folded (`folds`). A part of another dtype keeps the batch on the
+    generic pad+pack path. One shared table an entry kind: read, never
+    written, by its callers."""
     import ml_dtypes
 
+    target = np.dtype(ml_dtypes.bfloat16 if dtype_str == "bfloat16" else dtype_str)
+    if bits == 24:
+        kinds = {np.dtype(np.int32): _RAW32}
+    else:
+        kinds = {target: {32: _RAW32, 16: _RAW16, 8: _RAW8}[bits]}
+    if dtype_str == "bfloat16":
+        kinds[np.dtype(np.float32)] = _BF16_F32
+    if folds and dtype_str == "int32":
+        kinds[np.dtype(np.int64)] = _FOLD64
+    return kinds
+
+
+def assemble_batch(
+    layout: tuple,
+    parts: dict[str, list[np.ndarray]],
+    fold: dict[str, int] | None = None,
+) -> np.ndarray:
+    """One native pass from a batch's per-request arrays to its combined
+    upload buffer (see hostops.cc): `layout` is ops/transfer.py's
+    combined_layout of the PADDED batch, (bucket, entries); `parts[key]` the
+    requests' [n_p, *trailing] arrays of that input, in batch order; `fold`
+    names the inputs whose int64 parts are ids to fold, with their vocab.
+    The result is bit for bit pack_host_combined over the padded, folded
+    batch: uint32 words, zero padding included. A part's dtype must be one
+    part_kinds names for its entry; a part that is not C-contiguous is made
+    so here."""
     lib = _load()
     if lib is None:
         raise RuntimeError("native hostops library unavailable")
-    nparts = len(ids_parts)
-    if nparts == 0 or nparts != len(wts_parts):
-        raise ValueError(f"part-count mismatch: {nparts} ids vs {len(wts_parts)} wts")
-    ids_c = [np.ascontiguousarray(a) for a in ids_parts]
-    wts_c = [np.ascontiguousarray(a) for a in wts_parts]
-    # Real raises, not asserts: these are the ONLY guards between caller
-    # mistakes and an out-of-bounds write in C (review finding — asserts
-    # vanish under python -O, turning a shape bug into heap corruption).
-    for i, (a, w) in enumerate(zip(ids_c, wts_c)):
-        if a.dtype not in (np.int64, np.int32):
-            raise ValueError(f"ids part {i}: dtype {a.dtype} not int64/int32")
-        if w.dtype not in (np.float32, ml_dtypes.bfloat16):
-            raise ValueError(f"wts part {i}: dtype {w.dtype} not f32/bf16")
-        if a.ndim != 2 or a.shape[1] != fields or w.shape != a.shape:
-            raise ValueError(
-                f"part {i}: shapes ids {a.shape} / wts {w.shape} do not "
-                f"match [n, {fields}]"
-            )
-    ids_ptrs = (ctypes.c_void_p * nparts)(*(a.ctypes.data for a in ids_c))
-    wts_ptrs = (ctypes.c_void_p * nparts)(*(a.ctypes.data for a in wts_c))
-    ids_is64 = np.fromiter(
-        (a.dtype == np.int64 for a in ids_c), np.uint8, nparts
+    bucket, entries = layout
+    num_inputs = len(entries)
+    num_parts = len(parts[entries[0][0]]) if entries else 0
+    if not num_parts:
+        raise ValueError("assemble_batch needs at least one input and one part")
+    ns = np.fromiter(
+        (a.shape[0] for a in parts[entries[0][0]]), np.int64, num_parts
     )
-    wts_isf32 = np.fromiter(
-        (a.dtype == np.float32 for a in wts_c), np.uint8, nparts
-    )
-    ns = np.fromiter((a.shape[0] for a in ids_c), np.int64, nparts)
     if int(ns.sum()) > bucket:
         raise ValueError(f"{int(ns.sum())} rows exceed bucket {bucket}")
-    # 3 words a position of the ids' four planes, 1 of the weights' two.
-    words = (3 * -(-bucket // 4) + -(-bucket // 2)) * fields
+    bits = np.empty(num_inputs, np.int32)
+    inner = np.empty(num_inputs, np.int64)
+    vocab = np.zeros(num_inputs, np.int64)
+    ptrs = np.empty(num_inputs * num_parts, np.uint64)
+    kinds = np.empty(num_inputs * num_parts, np.uint8)
+    keep = []  # contiguous copies must outlive the call
+    words = 0
+    # Real raises, not asserts: these are the ONLY guards between caller
+    # mistakes and an out-of-bounds access in C (asserts vanish under
+    # python -O, turning a shape bug into heap corruption).
+    for k, (key, width, trailing, dtype_str) in enumerate(entries):
+        key_parts = parts[key]
+        if width not in PLANES or len(key_parts) != num_parts:
+            raise ValueError(
+                f"{key}: {width}-bit entry with {len(key_parts)} parts, "
+                f"the batch has {num_parts}"
+            )
+        folds = bool(fold) and key in fold
+        if folds:
+            vocab[k] = fold[key]
+            if vocab[k] <= 0:
+                raise ValueError(f"{key}: vocab {fold[key]} is not positive")
+        accepted = part_kinds(width, dtype_str, folds)
+        bits[k] = width
+        inner[k] = math.prod(trailing)
+        for p, a in enumerate(key_parts):
+            kind = accepted.get(a.dtype)
+            if kind is None:
+                raise ValueError(
+                    f"{key} part {p}: dtype {a.dtype} cannot travel as "
+                    f"{dtype_str}/{width}b"
+                )
+            if a.shape != (int(ns[p]),) + tuple(trailing):
+                raise ValueError(
+                    f"{key} part {p}: shape {a.shape} is not "
+                    f"{(int(ns[p]),) + tuple(trailing)}"
+                )
+            if not a.flags.c_contiguous:
+                a = np.ascontiguousarray(a)
+                keep.append(a)
+            ptrs[k * num_parts + p] = a.ctypes.data
+            kinds[k * num_parts + p] = kind
+        planes = PLANES[width]
+        words += -(-bucket // planes) * int(inner[k]) * planes * width // 32
     out = np.empty(words, np.uint32)
-    scratch = np.empty(4 * fields, np.uint32)
-    lib.pack_batch_u24_bf16(
-        ids_ptrs, _ptr(ids_is64), wts_ptrs, _ptr(wts_isf32),
-        _ptr(ns), nparts, fields, bucket, vocab, _ptr(scratch), _ptr(out),
+    lib.assemble_batch(
+        num_inputs, _ptr(bits), _ptr(inner), _ptr(vocab), _ptr(ptrs),
+        _ptr(kinds), _ptr(ns), num_parts, bucket, _ptr(out),
     )
     return out

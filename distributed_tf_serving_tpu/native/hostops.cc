@@ -27,7 +27,7 @@ inline uint64_t mix64(uint64_t a, uint64_t b) {
 }
 
 // THE fold: mathematical mod (result in [0, vocab)), pow2 fast path.
-// Shared by fold_i32 and the fused batch pack so the semantics cannot
+// Shared by fold_i32 and the batch assembler so the semantics cannot
 // drift between them.
 inline int64_t fold1(int64_t v, int64_t vocab, bool pow2, int64_t mask) {
   if (pow2) return v & mask;
@@ -36,7 +36,7 @@ inline int64_t fold1(int64_t v, int64_t vocab, bool pow2, int64_t mask) {
 }
 
 // f32 bits -> bf16 bits, round-to-nearest-even with NaN quieting (the one
-// rounding rule, shared by the exported f32_to_bf16 and the fused pack).
+// rounding rule, shared by the exported f32_to_bf16 and the batch assembler).
 inline uint16_t bf16_bits(uint32_t u) {
   if ((u & 0x7fffffffu) > 0x7f800000u) {   // NaN: keep quiet, drop payload
     return static_cast<uint16_t>((u >> 16) | 0x0040u);
@@ -212,6 +212,101 @@ void blake2b16_2seg(const uint8_t* s1, int64_t n1, const uint8_t* s2,
   std::memcpy(out16, h, 16);  // little-endian h[0..1] = the first 16 bytes
 }
 
+// The batch assembler's parts (assemble_batch below): how one part of one
+// input is read.
+enum : uint8_t { kRaw32 = 0, kFold64 = 1, kBf16F32 = 2, kRaw16 = 3, kRaw8 = 4 };
+
+// `count` values of one part, from its element `first`, widened to words.
+inline void load_values(const void* src, uint8_t kind, int64_t first,
+                        int64_t count, int64_t vocab, uint32_t* dst) {
+  switch (kind) {
+    case kRaw32:
+      std::memcpy(dst, static_cast<const uint32_t*>(src) + first,
+                  static_cast<size_t>(count) * 4);
+      break;
+    case kFold64: {
+      const int64_t* s = static_cast<const int64_t*>(src) + first;
+      const int64_t mask = vocab - 1;
+      const bool pow2 = (vocab & mask) == 0;
+      for (int64_t i = 0; i < count; ++i) {
+        dst[i] = static_cast<uint32_t>(fold1(s[i], vocab, pow2, mask));
+      }
+      break;
+    }
+    case kBf16F32: {
+      const uint32_t* s = static_cast<const uint32_t*>(src) + first;
+      for (int64_t i = 0; i < count; ++i) dst[i] = bf16_bits(s[i]);
+      break;
+    }
+    case kRaw16: {
+      const uint16_t* s = static_cast<const uint16_t*>(src) + first;
+      for (int64_t i = 0; i < count; ++i) dst[i] = s[i];
+      break;
+    }
+    default: {
+      const uint8_t* s = static_cast<const uint8_t*>(src) + first;
+      for (int64_t i = 0; i < count; ++i) dst[i] = s[i];
+    }
+  }
+}
+
+// Values a plane converts at a time: four planes' worth stay in L1.
+constexpr int64_t kChunk = 1024;
+
+// One sub-word input's segment; returns the segment's end.
+template <int BITS>
+uint32_t* assemble_planes(const void* const* ptrs, const uint8_t* kinds,
+                          const int64_t* ns, int64_t num_parts, int64_t inner,
+                          int64_t bucket, int64_t vocab, uint32_t* seg) {
+  constexpr int P = planes_of(BITS);
+  const int64_t stride = (bucket + P - 1) / P * inner;
+  // The part, and the element inside it, a plane reads next; part ==
+  // num_parts is the padding.
+  struct Cursor { int64_t part, off; } cur[P];
+  for (int p = 0; p < P; ++p) {
+    cur[p] = {0, p * stride};
+    while (cur[p].part < num_parts && cur[p].off >= ns[cur[p].part] * inner) {
+      cur[p].off -= ns[cur[p].part++] * inner;
+    }
+  }
+  uint32_t scratch[P * kChunk];
+  for (int64_t i = 0; i < stride;) {
+    if (cur[0].part >= num_parts) {  // and so is every later plane
+      for (int g = 0; g < P * BITS / 32; ++g) {
+        std::memset(seg + g * stride + i, 0,
+                    static_cast<size_t>(stride - i) * 4);
+      }
+      break;
+    }
+    int64_t run = stride - i < kChunk ? stride - i : kChunk;
+    for (int p = 0; p < P; ++p) {
+      if (cur[p].part < num_parts) {
+        const int64_t left = ns[cur[p].part] * inner - cur[p].off;
+        if (left < run) run = left;
+      }
+    }
+    for (int p = 0; p < P; ++p) {
+      Cursor& c = cur[p];
+      if (c.part >= num_parts) {
+        std::memset(scratch + p * kChunk, 0, static_cast<size_t>(run) * 4);
+        continue;
+      }
+      load_values(ptrs[c.part], kinds[c.part], c.off, run, vocab,
+                  scratch + p * kChunk);
+      c.off += run;
+      while (c.part < num_parts && c.off >= ns[c.part] * inner) {
+        c.off = 0;  // a run never crosses a part, so it ended exactly here
+        ++c.part;
+      }
+    }
+    pack_words<BITS>(
+        [&](int p, int64_t k) { return scratch[p * kChunk + k]; }, run,
+        seg + i, stride);
+    i += run;
+  }
+  return seg + (P * BITS / 32) * stride;
+}
+
 }  // namespace
 
 extern "C" {
@@ -331,98 +426,57 @@ void pack_planes_raw8(const uint8_t* in, int64_t n_rows, int64_t inner,
                  n_rows, inner, out);
 }
 
-// Fused batch assembly for the flagship combined layout
-// ({feat_ids: u24, feat_wts: bf16}, key-sorted so the ids segment precedes
-// the weights segment): reads each request's arrays ONCE and writes the
-// final padded device buffer directly, in the word format above —
-//   out = [3 * ceil(bucket/4) * F words: four row planes of u24(fold(ids))]
-//         [ceil(bucket/2) * F words: two row planes of bf16(wts)]
-// replacing the python path's pad copy + fold pass + pack pass (3 full
-// passes and 2 temporaries per batch, serving/batcher.py _dispatch +
-// ops/transfer.py). An output position takes one row of every plane, and
-// those rows lie in different requests, so each segment is written by
-// walking its planes' source rows side by side: row r of the padded batch
-// is row (r - start of its part) of that part, zero past the last part.
-// Per part p: ids_ptrs[p] is int64 (wide wire; folded here) or int32 when
-// ids_is64[p]==0 (compact wire, pre-folded by the client and range-checked
-// by the service; low 3 bytes taken either way, matching the numpy path's
-// truncation semantics: for OUT-of-contract ids in a MIXED group the python
-// path widens to int64 and folds while this path truncates — an
-// intentional, documented divergence reachable only by direct submit()
-// callers violating the compact contract). wts_ptrs[p] is f32 (cast here,
-// RNE) or bf16 bits when wts_isf32[p]==0 (compact; copied). `scratch` holds
-// 4 * fields words. Thread-safe; ctypes releases the GIL for the whole call.
-void pack_batch_u24_bf16(const void** ids_ptrs, const uint8_t* ids_is64,
-                         const void** wts_ptrs, const uint8_t* wts_isf32,
-                         const int64_t* ns, int64_t num_parts,
-                         int64_t fields, int64_t bucket, int64_t vocab,
-                         uint32_t* scratch, uint32_t* out) {
-  const bool pow2 = (vocab & (vocab - 1)) == 0;
-  const int64_t mask = vocab - 1;
-  // Row `row` of part `part` (part == num_parts: padding) as `fields`
-  // values in dst: folded ids, or bf16 bits.
-  auto ids_row = [&](int64_t part, int64_t row, uint32_t* dst) {
-    if (part >= num_parts) {
-      std::memset(dst, 0, static_cast<size_t>(fields) * 4);
-    } else if (ids_is64[part]) {
-      const int64_t* src =
-          static_cast<const int64_t*>(ids_ptrs[part]) + row * fields;
-      for (int64_t f = 0; f < fields; ++f) {
-        dst[f] = static_cast<uint32_t>(fold1(src[f], vocab, pow2, mask));
+// Batch assembly for ANY combined layout (ops/transfer.py combined_layout):
+// reads each request's arrays ONCE and writes the final padded upload buffer
+// directly, in the word format above, replacing the python path's pad copy +
+// fold pass + pack pass (3 full passes and 2 temporaries a batch,
+// serving/batcher.py _dispatch + ops/transfer.py). The buffer is, bit for
+// bit, pack_host_combined over the padded, folded batch. Input k (the
+// layout's entries, key-sorted) has bits[k] in {32, 24, 16, 8}, inner[k]
+// values a row and, where its int64 parts are ids to fold, vocab[k] > 0; its
+// segment follows input k-1's. Part p of input k is ptrs[k * num_parts + p],
+// ns[p] rows of inner[k] values, read as kinds[k * num_parts + p] says:
+//   kRaw32   4-byte values as they are (32 bits: any dtype; 24: int32 ids,
+//            pre-folded by a compact-wire client, low 3 bytes taken)
+//   kFold64  int64 ids, folded mod vocab[k] here (32 or 24 bits)
+//   kBf16F32 float32, cast to bf16 here, RNE (16 bits)
+//   kRaw16 / kRaw8  2- and 1-byte values as they are
+// (for OUT-of-contract int32 ids in a group MIXED with int64 ones the python
+// path widens to int64 and folds while this path truncates: an intentional,
+// documented divergence reachable only by direct submit() callers violating
+// the compact contract). A word of a sub-word segment takes one value of
+// every plane, and those lie in different requests, so each plane walks the
+// parts with a cursor of its own; the padded batch is flat here (rows are
+// the caller's notion): plane p is elements [p * stride, (p + 1) * stride),
+// zero past the last part. Thread-safe; ctypes releases the GIL for the call.
+void assemble_batch(int64_t num_inputs, const int32_t* bits,
+                    const int64_t* inner, const int64_t* vocab,
+                    const void* const* ptrs, const uint8_t* kinds,
+                    const int64_t* ns, int64_t num_parts, int64_t bucket,
+                    uint32_t* out) {
+  for (int64_t k = 0; k < num_inputs; ++k) {
+    const void* const* kp = ptrs + k * num_parts;
+    const uint8_t* kk = kinds + k * num_parts;
+    if (bits[k] == 32) {  // one plane: the padded array itself
+      int64_t off = 0;
+      for (int64_t p = 0; p < num_parts; ++p) {
+        load_values(kp[p], kk[p], 0, ns[p] * inner[k], vocab[k], out + off);
+        off += ns[p] * inner[k];
       }
+      std::memset(out + off, 0,
+                  static_cast<size_t>(bucket * inner[k] - off) * 4);
+      out += bucket * inner[k];
+    } else if (bits[k] == 24) {
+      out = assemble_planes<24>(kp, kk, ns, num_parts, inner[k], bucket,
+                                vocab[k], out);
+    } else if (bits[k] == 16) {
+      out = assemble_planes<16>(kp, kk, ns, num_parts, inner[k], bucket,
+                                vocab[k], out);
     } else {
-      std::memcpy(dst,
-                  static_cast<const int32_t*>(ids_ptrs[part]) + row * fields,
-                  static_cast<size_t>(fields) * 4);
+      out = assemble_planes<8>(kp, kk, ns, num_parts, inner[k], bucket,
+                               vocab[k], out);
     }
-  };
-  auto wts_row = [&](int64_t part, int64_t row, uint32_t* dst) {
-    if (part >= num_parts) {
-      std::memset(dst, 0, static_cast<size_t>(fields) * 4);
-    } else if (wts_isf32[part]) {
-      const uint32_t* src =
-          static_cast<const uint32_t*>(wts_ptrs[part]) + row * fields;
-      for (int64_t f = 0; f < fields; ++f) dst[f] = bf16_bits(src[f]);
-    } else {
-      const uint16_t* src =
-          static_cast<const uint16_t*>(wts_ptrs[part]) + row * fields;
-      for (int64_t f = 0; f < fields; ++f) dst[f] = src[f];
-    }
-  };
-  // One cursor a plane: the part and the row inside it of padded row
-  // plane * q + j, advanced with j.
-  struct Cursor { int64_t part, row; };
-  auto seek = [&](int64_t r) {
-    Cursor c{0, r};
-    while (c.part < num_parts && c.row >= ns[c.part]) c.row -= ns[c.part++];
-    return c;
-  };
-  auto step = [&](Cursor& c) {
-    if (c.part < num_parts && ++c.row >= ns[c.part]) {
-      c.row = 0;
-      do { ++c.part; } while (c.part < num_parts && ns[c.part] == 0);
-    }
-  };
-  auto segment = [&](auto bits_tag, auto row_fn, uint32_t* seg) {
-    constexpr int BITS = decltype(bits_tag)::value;
-    constexpr int P = planes_of(BITS);
-    const int64_t q = (bucket + P - 1) / P;
-    Cursor cur[P];
-    for (int p = 0; p < P; ++p) cur[p] = seek(p * q);
-    for (int64_t j = 0; j < q; ++j) {
-      for (int p = 0; p < P; ++p) {
-        row_fn(cur[p].part, cur[p].row, scratch + p * fields);
-        step(cur[p]);
-      }
-      pack_words<BITS>(
-          [&](int p, int64_t f) { return scratch[p * fields + f]; }, fields,
-          seg + j * fields, q * fields);
-    }
-    return seg + (P * BITS / 32) * q * fields;
-  };
-  uint32_t* wts_seg =
-      segment(std::integral_constant<int, 24>{}, ids_row, out);
-  segment(std::integral_constant<int, 16>{}, wts_row, wts_seg);
+  }
 }
 
 }  // extern "C"

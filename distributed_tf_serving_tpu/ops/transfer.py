@@ -36,6 +36,7 @@ import ml_dtypes
 import numpy as np
 
 from ..models.base import Model
+from ..native import PLANES as _PLANES
 
 U24_MAX = 1 << 24
 
@@ -322,8 +323,6 @@ def combined_supported(arrays: dict[str, np.ndarray]) -> bool:
     )
 
 
-# Planes a word group holds, by the packed width in bits.
-_PLANES = {32: 1, 16: 2, 8: 4, 24: 4}
 
 
 def _plane_shifts(bits: int):

@@ -305,6 +305,17 @@ def prepare_inputs(
     return out
 
 
+# Weight of the newest observation in the batcher's two load averages (the
+# gap between arrivals, a batch's time across the pipeline): sixteen or so
+# observations deep, so one odd gap of a Poisson stream does not flip
+# _holds_open.
+_LOAD_EWMA = 1.0 / 16.0
+
+
+def _smoothed(mean: float | None, seen: float) -> float:
+    return seen if mean is None else mean + (seen - mean) * _LOAD_EWMA
+
+
 class DeviceInputCache:
     """Content-addressed LRU of device-resident input arrays.
 
@@ -715,9 +726,9 @@ class BatcherStats:
     requests: int = 0
     candidates: int = 0
     padded_candidates: int = 0
-    # Batches assembled by the native fused pack (hostops.cc
-    # pack_batch_u24_bf16: fold+u24+bf16+pad+concat in one pass per input
-    # instead of 4 python/numpy passes + 3 temporaries).
+    # Batches assembled by the native pass (hostops.cc assemble_batch:
+    # fold + pack + pad + concat in one pass an input, for any combined
+    # layout, instead of 4 python/numpy passes + 3 temporaries).
     fused_batches: int = 0
     # Batches whose outputs rode the top-k compaction (only k (score, idx)
     # pairs crossed the D2H link instead of the full score vector).
@@ -988,6 +999,12 @@ class DynamicBatcher:
             else None
         )
         self._dispatch_pending = 0
+        # The load as _holds_open weighs it: smoothed seconds between two
+        # arrivals, and from a batch's stage start to its readback's end.
+        # None until measured: a batcher that has seen no load holds nothing.
+        self._last_arrival_t: float | None = None
+        self._arrival_gap_s: float | None = None
+        self._traversal_s: float | None = None
         self._staged_candidates = 0
         self._staged_groups: dict[int, tuple[list, int]] = {}
         self._staged_seq = 0
@@ -1327,6 +1344,12 @@ class DynamicBatcher:
         with self._cv:
             self._items.append(item)
             self.stats.max_queue_depth = max(self.stats.max_queue_depth, len(self._items))
+            if not _warmup:
+                if self._last_arrival_t is not None:
+                    self._arrival_gap_s = _smoothed(
+                        self._arrival_gap_s, now - self._last_arrival_t
+                    )
+                self._last_arrival_t = now
             self._cv.notify()
         if handle is not None and handle.leader:
             # Fill + waiter fan-out ride the future's completion (success,
@@ -1945,69 +1968,93 @@ class DynamicBatcher:
 
         return (fn, spec, combined)
 
-    _FUSED_SPEC = {"feat_ids": "u24", "feat_wts": "bf16"}
-
-    def _fused_ctx(self, group: list[_WorkItem], bucket: int) -> dict | None:
-        """Eligibility + host-side metadata for the native fused batch
-        assembler; None = the generic pad+pack path runs instead. Pure
-        bookkeeping (no device work), so it runs on the batcher thread —
-        the device stage itself (_execute_fused) rides the dispatch
-        pipeline.
-
-        hostops.cc pack_batch_u24_bf16 reads each request's arrays once and
-        writes the final padded [u24 ids | bf16 wts] word buffer directly
-        — the generic path makes 3 full passes (pad copy, fold, pack)
-        with 2 temporaries per batch. The buffer is bit-identical
-        to pack_host_combined over the padded batch (pinned by
-        tests/test_batcher.py), so it shares the same compiled executables
-        and the same content-cache semantics (keyed per-part here; distinct
-        tag keeps the two key schemes apart)."""
-        import os
-
-        import ml_dtypes
-
+    def _generic_reason(self, servable: Servable) -> str | None:
+        """Why every batch of `servable` takes the generic pad+pack path,
+        None when the native assembler can take them (a batch may still
+        fall back by what it holds: _fused_ctx)."""
         from .. import native
 
-        servable = group[0].servable
+        if self._run_fn is not None:
+            return "custom run_fn"
+        if not self.compress_transfer:
+            return "compress_transfer off"
+        if servable.model.needs_x64:
+            return "x64 model"
+        if not native.available():
+            return "no native library"
+        if not self._jit_for(servable)[2]:
+            return "per-key upload"
+        return None
+
+    def assemblers(self) -> dict[str, str]:
+        """"name:version" -> "native" or "generic: <why>", for every
+        servable an entry was built for: /monitoring's `startup.assembler`,
+        beside `upload_format`."""
+        with self._jit_lock:
+            servables = list(self._jitted)
+        out = {}
+        for sv in servables:
+            why = self._generic_reason(sv)
+            out[f"{sv.name}:{sv.version}"] = (
+                "native" if why is None else f"generic: {why}"
+            )
+        return out
+
+    def _fused_ctx(
+        self, servable: Servable, parts: dict[str, list[np.ndarray]],
+        bucket: int,
+    ) -> dict | None:
+        """Eligibility + host-side metadata for the native batch assembler;
+        None = the generic pad+pack path runs instead. Pure bookkeeping (no
+        device work), so it runs on the batcher thread — the device stage
+        itself (_execute_fused) rides the dispatch pipeline.
+
+        hostops.cc assemble_batch reads each request's arrays once and
+        writes the final padded word buffer directly, for any combined
+        layout — the generic path makes 3 full passes (pad copy, fold,
+        pack) with 2 temporaries per batch. The buffer is bit-identical to
+        pack_host_combined over the padded batch (pinned by
+        tests/test_fused_pack.py), so it shares the same compiled
+        executables and the same content-cache semantics (keyed per-part
+        here; distinct tag keeps the two key schemes apart). What the
+        layout is comes from what the batch holds, as on the generic path:
+        per input the spec's packed width, else the parts' own dtype
+        (int32 for int64 ids the model folds on the host)."""
+        from .. import native
+
+        if self._generic_reason(servable) is not None:
+            return None
         model = servable.model
-        if (
-            self._run_fn is not None
-            or not self.compress_transfer
-            or model.needs_x64
-            or not model.folds_ids_on_host
-            or os.environ.get("DTS_TPU_NO_FUSED") == "1"  # A/B isolation knob
-            or not native.available()
-        ):
-            return None
-        fn, spec, combined = self._jit_for(servable)
-        if not combined or spec != self._FUSED_SPEC:
-            return None
-        first = group[0].arrays
-        if set(first) != {"feat_ids", "feat_wts"}:
-            return None
-        fields = first["feat_ids"].shape[1] if first["feat_ids"].ndim == 2 else None
-        if not fields:
-            return None
-        for it in group:
-            ids, wts = it.arrays["feat_ids"], it.arrays["feat_wts"]
-            if (
-                ids.ndim != 2 or ids.shape[1] != fields
-                or wts.shape != ids.shape
-                or ids.dtype not in (np.int64, np.int32)
-                or wts.dtype not in (np.float32, ml_dtypes.bfloat16)
-            ):
-                return None
-        layout = combined_layout(
-            {k: first[k] for k in ("feat_ids", "feat_wts")}, spec, rows=bucket
+        fn, spec, _combined = self._jit_for(servable)
+        fold = (
+            {"feat_ids": model.config.vocab_size}
+            if model.folds_ids_on_host and "feat_ids" in parts else {}
         )
+        # The padded batch's arrays as combined_layout would see them, by
+        # zero-row stand-ins of the first part's trailing shape and dtype.
+        shown = {}
+        for key, key_parts in parts.items():
+            first = key_parts[0]
+            if first.ndim < 1 or 0 in first.shape[1:]:
+                return None
+            dtype = first.dtype
+            if key in fold and dtype == np.int64:
+                dtype = np.int32
+            shown[key] = np.empty((0,) + first.shape[1:], dtype)
+        if not combined_supported(shown):
+            return None
+        layout = combined_layout(shown, spec, rows=bucket)
+        for key, bits, trailing, dtype_str in layout[1]:
+            readable = native.part_kinds(bits, dtype_str, key in fold)
+            for part in parts[key]:
+                if part.dtype not in readable or part.shape[1:] != trailing:
+                    return None
         return {
             "servable": servable,
             "fn": fn,
             "layout": layout,
-            "vocab": model.config.vocab_size,
-            "fields": fields,
-            "ids_parts": [it.arrays["feat_ids"] for it in group],
-            "wts_parts": [it.arrays["feat_wts"] for it in group],
+            "fold": fold,
+            "parts": parts,
         }
 
     def _execute_fused(
@@ -2015,47 +2062,43 @@ class DynamicBatcher:
         out_keys: tuple[str, ...] | None, topk: int, n_valid,
         prune: bool = False,
     ):
-        """Device stage of the fused path: content cache / native pack /
-        upload / jit call (cache+pack+jitcall spans match the generic
-        path's, so fused/generic phase decompositions compare like for
-        like)."""
+        """Device stage of the native path: content cache / native assembly
+        / upload / jit call. `batch.cache` covers digest (while the cache
+        probes), assembly and upload in EVERY batch, as the generic path's
+        does, with `batch.fusedpack` inside it around the native call
+        alone; `batch.jitcall` follows."""
         from .. import native
 
         servable, fn, layout = ctx["servable"], ctx["fn"], ctx["layout"]
-        vocab, fields = ctx["vocab"], ctx["fields"]
-        ids_parts, wts_parts = ctx["ids_parts"], ctx["wts_parts"]
+        fold, parts = ctx["fold"], ctx["parts"]
 
         def build():
-            return native.pack_batch_u24_bf16(
-                ids_parts, wts_parts, fields, bucket, vocab
-            )
+            with request_trace.span("batch.fusedpack"):
+                return native.assemble_batch(layout, parts, fold)
 
         cache = self.input_cache
-        if cache is not None and not cache.bypassed:
-            with request_trace.span("batch.cache"):
+        with request_trace.span("batch.cache"):
+            if cache is not None and not cache.bypassed:
                 # Per-part content digests (same digest primitive, same
                 # total bytes as the group digest) + padded geometry.
-                # vocab is IN the tag: the digests are over RAW ids,
-                # and the stored buffer's fold depends on it — two
+                # The fold's vocab is IN the tag: the digests are over RAW
+                # ids, and the stored buffer's fold depends on it — two
                 # servables sharing a batcher but not a vocab must
                 # never share entries (review finding; the generic
                 # path's digests are post-fold so it gets this free).
-                key = (
-                    (f"fused:{layout}:{bucket}:{vocab}",)
-                    + tuple(cache._key("i", a) for a in ids_parts)
-                    + tuple(cache._key("w", a) for a in wts_parts)
+                key = (f"fused:{layout}:{sorted(fold.items())}",) + tuple(
+                    cache._key(k, a) for k in sorted(parts) for a in parts[k]
                 )
                 buf = cache._lookup(key, build)
-        else:
-            if cache is not None:
-                cache._note_bypassed()
-            with request_trace.span("batch.fusedpack"):
+            else:
+                if cache is not None:
+                    cache._note_bypassed()
                 buf = build()
         # np.int32, matching _execute and warmup(): a raw Python int has a
         # different jax aval (weak type) and would force a fresh trace on
         # the first live fused top-k batch despite warmup's precompile.
         n_valid = None if not topk else np.int32(n_valid)
-        # Kernel plane: the fused native assembler and the kernel variants
+        # Kernel plane: the native assembler and the kernel variants
         # compose — the packed buffer is variant-independent input bytes.
         k_params, k_apply = self._kernel_variant(servable, bucket)
         with request_trace.span("batch.jitcall"):
@@ -2284,18 +2327,48 @@ class DynamicBatcher:
             if token is not None:
                 util.wait_end(token)
 
-    def _coalesce_next(self, item: _WorkItem, total: int, deadline: float) -> _WorkItem | None:
+    def _holds_open(self, native: bool, busy: int) -> bool:
+        """Whether a batch past its deadline, the queue empty, stays open
+        for what arrives while the pipeline is occupied (`busy` batches
+        staged, in their stage or in flight; the caller holds `_cv`).
+
+        Always while the pipeline is saturated (>= pipeline_depth): the
+        batch would queue behind device work regardless. Where the native
+        assembler builds the batch on the dispatch thread (`native`), the
+        collector has no assembly of its own to overlap with the stage
+        before, so a batch closed early is only a smaller batch, and two
+        more cases hold it: a stage staged or running on the dispatch
+        thread (the batch would wait behind it, closed to later arrivals);
+        and a batch in flight while requests arrive at least as fast as
+        batches cross the pipeline (Little's law: on average one more
+        joins before the pipeline drains), which is when a batch's fixed
+        host cost is worth sharing. Below that rate a lone request
+        dispatches at once and overlaps the batch in flight."""
+        if busy >= self.pipeline_depth:
+            return True
+        if not native or not busy:
+            return False
+        if self._dispatch_pending:
+            return True
+        gap, crossing = self._arrival_gap_s, self._traversal_s
+        return gap is not None and crossing is not None and crossing >= gap
+
+    def _coalesce_next(
+        self, item: _WorkItem, total: int, deadline: float, native: bool = False,
+    ) -> _WorkItem | None:
         """Next same-target item within the (pipeline-extended) window, or
         None. The head item stays put when it doesn't match — deque order is
         preserved (the old SimpleQueue requeue pushed it to the BACK,
         reordering traffic).
 
-        Past `deadline` the wait continues only while the dispatch pipeline
-        is saturated (>= pipeline_depth batches in flight and none wedged):
-        the next dispatch would queue behind device work regardless, so the
-        extra fill time costs no latency. Completion of any in-flight batch
-        notifies this wait, ending the free-ride the moment dispatch could
-        actually start."""
+        Past `deadline` the wait continues only while _holds_open says the
+        pipeline is occupied (and none wedged): saturated, so the next
+        dispatch would queue behind device work regardless and the extra
+        fill time costs no latency; or, for a natively assembled batch
+        (`native`), the dispatch thread busy or a batch in flight under
+        load. Completion of any stage or in-flight batch notifies this
+        wait, ending the free-ride the moment dispatch could actually
+        start."""
         free_ride_counted = False
         with self._cv:
             while True:
@@ -2310,7 +2383,7 @@ class DynamicBatcher:
                         self._wait("coalesce", deadline - now)
                         continue
                     busy = len(self._inflight) + self._dispatch_pending
-                    if busy < self.pipeline_depth or self._wedged_for(now):
+                    if not self._holds_open(native, busy) or self._wedged_for(now):
                         return None
                     # Free-riding the busy pipeline; a completion notifies.
                     # Bounded wait: the wedge clock advances with wall time
@@ -2325,8 +2398,10 @@ class DynamicBatcher:
                         "pipeline", 0.005,
                         until=lambda: (
                             self._items or self._stopping
-                            or len(self._inflight) + self._dispatch_pending
-                            < self.pipeline_depth
+                            or not self._holds_open(
+                                native,
+                                len(self._inflight) + self._dispatch_pending,
+                            )
                             or self._wedged_for(time.perf_counter())
                         ),
                     )
@@ -2373,12 +2448,15 @@ class DynamicBatcher:
             group = [item]
             total = item.n
             deadline = item.enqueue_t + self.max_wait_s
+            # Whether the dispatch thread assembles this servable's batches
+            # (then the collector holds a batch open longer: _holds_open).
+            native = self._generic_reason(item.servable) is None
             # Coalesce same-servable work until the deadline or size cap.
             # Solo items (streamed sub-batches) dispatch alone: merging
             # them would undo the very split that lets their readbacks
             # complete (and flush) independently.
             while total < self.max_batch_candidates and not item.solo:
-                nxt = self._coalesce_next(item, total, deadline)
+                nxt = self._coalesce_next(item, total, deadline, native)
                 if nxt is None:
                     break
                 group.append(nxt)
@@ -2536,18 +2614,22 @@ class DynamicBatcher:
                     bucket = bucket_for(n_unique, self.buckets)
                     self.stats.dedup_batches += 1
                     self.stats.dedup_rows_collapsed += total - n_unique
-            # A collapsed batch skips the fused assembler: its native pack
-            # reads the ORIGINAL per-request parts, which would re-inflate
-            # the rows dedup just removed.
-            fused = None if scatter is not None else self._fused_ctx(group, bucket)
-            if fused is not None and dedup_cats is not None:
-                # All-unique screen with the fused path winning: hand the
-                # packer the screen's concatenated arrays as single parts
-                # (its output is row-sequential, so one pre-concatenated
-                # part packs bit-identically to the original part list) —
-                # the screen's concat is reused here too, never discarded.
-                fused["ids_parts"] = [dedup_cats["feat_ids"]]
-                fused["wts_parts"] = [dedup_cats["feat_wts"]]
+            # A collapsed batch skips the native assembler (the dedup plane
+            # pads its unique rows below). Otherwise it reads the requests'
+            # own arrays, or, where a screen already concatenated them
+            # (all-unique dedup, a row-cache plan's rows to execute), that
+            # concat as a single part: its output is row-sequential, so one
+            # pre-concatenated part packs bit-identically to the part list,
+            # and the screen's concat is never discarded.
+            fused = None
+            if scatter is None:
+                fused = self._fused_ctx(
+                    first.servable,
+                    {k: [v] for k, v in dedup_cats.items()}
+                    if dedup_cats is not None
+                    else {k: [it.arrays[k] for it in group] for k in first.arrays},
+                    bucket,
+                )
             batched = None
             if fused is None and (scatter is not None or dedup_cats is not None):
                 # Pad from the dedup screen's arrays: the unique rows when
@@ -3084,10 +3166,7 @@ class DynamicBatcher:
                     # Plain np.empty, not the buffer ring: this buffer
                     # dies with the dispatch frame.
                     shadow_in = {}
-                    for k, parts in (
-                        ("feat_ids", fused["ids_parts"]),
-                        ("feat_wts", fused["wts_parts"]),
-                    ):
+                    for k, parts in fused["parts"].items():
                         dt = parts[0].dtype
                         if any(p.dtype != dt for p in parts):
                             dt = np.result_type(*(p.dtype for p in parts))
@@ -3324,6 +3403,14 @@ class DynamicBatcher:
             downloaded = sum(v.nbytes for v in host.values())
             total_n = sum(it.n for it in group)
             ov = self.overload  # capture: detachable mid-flight (bench A/B)
+            if stage_t0 is not None and not any(it.warmup for it in group):
+                # What a batch takes to cross the pipeline, stage start to
+                # readback done: _holds_open weighs it against the gap
+                # between arrivals. Compiles are not crossings.
+                with self._cv:
+                    self._traversal_s = _smoothed(
+                        self._traversal_s, done_t - stage_t0
+                    )
             if (
                 ov is not None
                 and stage_t0 is not None

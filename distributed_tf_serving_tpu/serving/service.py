@@ -206,7 +206,8 @@ class PredictionServiceImpl:
         device_kind, device count, library versions — plus the load-time
         compile wall, the start-up's stamps (`startup`, with each loaded
         servable's embedding rows a candidate row, `lookups_per_row`, the
-        `bags` they pool to and the `upload_format` of its batches), the pack factor of
+        `bags` they pool to, the `upload_format` of its batches and the
+        `assembler` that builds them: "native" or "generic: <why>"), the pack factor of
         each loaded servable's embedding table (`embedding_pack`), persistent-cache
         traffic and whether the native host ops are loaded: the `runtime`
         block in /monitoring. jax falls back
@@ -218,12 +219,14 @@ class PredictionServiceImpl:
         block = describe_devices()
         block["warmup_s"] = self.warmup_s
         upload_formats = getattr(self.batcher, "upload_formats", None)
+        assemblers = getattr(self.batcher, "assemblers", None)
         block["startup"] = {
             **self.startup,
             "warmup_s": self.warmup_s,
             "lookups_per_row": self.registry.per_servable("lookups_per_row"),
             "bags": self.registry.per_servable("bags"),
             "upload_format": upload_formats() if callable(upload_formats) else {},
+            "assembler": assemblers() if callable(assemblers) else {},
         }
         block["embedding_pack"] = self.registry.per_servable("embedding_pack")
         block["compile_cache"] = (

@@ -746,6 +746,9 @@ class ServerMetrics:
         if batcher_stats is not None:
             out["batcher"] = {
                 "batches": batcher_stats.batches,
+                # Of them, those the native assembler built (one pass from
+                # the requests' arrays to the upload's words).
+                "fused_batches": getattr(batcher_stats, "fused_batches", 0),
                 "requests": batcher_stats.requests,
                 "mean_occupancy": round(batcher_stats.mean_occupancy, 3),
                 # The ratios' raw terms (mean_occupancy above,

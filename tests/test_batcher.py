@@ -600,3 +600,269 @@ def test_warmup_compile_does_not_trip_breaker(servable):
         assert not t.is_alive()
     finally:
         batcher.stop()
+
+
+# ------------------------------------------------- the native batch assembler
+#
+# One pass from the requests' arrays to the upload's words (hostops.cc
+# assemble_batch) for every combined layout: the benchmark's three
+# configurations at tiny tables, and the batches that must stay generic.
+
+_NATIVE_CONFIGS = {
+    # kind, fields, extra config, the ids' packed form
+    "dcn_v2_ref43": ("dcn_v2", 43, {}, "feat_ids int32/32b x1"),
+    "dlrm_mlperf": ("dlrm", 26, {"bottom_mlp_dims": (16, 4)}, "feat_ids int32/24b x4"),
+    "dlrm_dcnv2_mlperf": (
+        "dlrm_dcnv2", 214,
+        {"bottom_mlp_dims": (16, 4), "cross_low_rank": 4, "multi_hot_sizes": (
+            3, 2, 1, 2, 6, 1, 1, 1, 1, 7, 3, 8, 1, 6, 9, 5, 1, 1, 1, 12, 100, 27, 10, 3, 1, 1)},
+        "feat_ids int32/24b x4",
+    ),
+}
+
+
+def _native_servable(name, **model_overrides):
+    import dataclasses
+
+    kind, fields, extra, _ = _NATIVE_CONFIGS[name]
+    cfg = ModelConfig(
+        num_fields=fields, vocab_size=1009, embed_dim=4, mlp_dims=(16,),
+        num_cross_layers=1, compute_dtype="bfloat16", **extra,
+    )
+    model = build_model(kind, cfg)
+    if model_overrides:
+        model = dataclasses.replace(model, **model_overrides)
+    return Servable(
+        name=name, version=1, model=model,
+        params=jax.jit(model.init)(jax.random.PRNGKey(0)),
+        signatures=ctr_signatures(
+            fields, with_dense=cfg.num_dense_features if model.takes_dense else None
+        ),
+    )
+
+
+def _native_payload(sv, n, seed):
+    rng = np.random.RandomState(seed)
+    cfg = sv.model.config
+    out = {
+        "feat_ids": rng.randint(0, 1 << 40, size=(n, cfg.num_fields)).astype(np.int64),
+        "feat_wts": rng.rand(n, cfg.num_fields).astype(np.float32),
+    }
+    if sv.model.takes_dense:
+        out["dense_features"] = rng.randn(n, cfg.num_dense_features).astype(np.float32)
+    return out
+
+
+def _phase_counts(before):
+    from distributed_tf_serving_tpu.utils.tracing import request_trace
+
+    now = request_trace.snapshot()
+    return {
+        p: now.get(p, {}).get("count", 0) - before.get(p, {}).get("count", 0)
+        for p in ("batch.pad", "batch.cache", "batch.fusedpack", "batch.dispatch")
+    }
+
+
+def _score_burst(sv, payloads, **batcher_kwargs):
+    """The payloads submitted together (so they coalesce), then one alone:
+    scores in order, the batcher's stats, the spans the batches emitted."""
+    from distributed_tf_serving_tpu.utils.tracing import request_trace
+
+    batcher = DynamicBatcher(buckets=(16, 64), max_wait_us=20000, **batcher_kwargs).start()
+    try:
+        before = request_trace.snapshot()
+        futures = [batcher.submit(sv, p) for p in payloads]
+        scores = [f.result(timeout=120)["prediction_node"] for f in futures]
+        scores.append(batcher.submit(sv, payloads[0]).result(timeout=120)["prediction_node"])
+        return np.concatenate(scores), batcher.stats, _phase_counts(before), batcher
+    finally:
+        batcher.stop()
+
+
+@pytest.mark.parametrize("name", sorted(_NATIVE_CONFIGS))
+def test_every_batch_takes_the_native_assembler(name, monkeypatch):
+    """Each of the benchmark's configurations, at tiny tables: every batch is
+    assembled natively, `batch.cache` is emitted once a batch with
+    `batch.fusedpack` inside it and `batch.pad` never, /monitoring names the
+    assembler, and the scores are the generic path's to the last bit."""
+    from distributed_tf_serving_tpu import native
+    from distributed_tf_serving_tpu.ops import transfer
+
+    if not native.ensure():
+        pytest.skip("native hostops unavailable")
+    if name == "dcn_v2_ref43":
+        # The configuration's table has 1 << 27 rows, past what three bytes
+        # hold; a tiny table takes the same int32/32b id form this way.
+        monkeypatch.setattr(transfer, "U24_MAX", 1 << 8)
+    sv = _native_servable(name)
+    payloads = [_native_payload(sv, n, seed=n) for n in (5, 13, 1, 22)]
+    got, stats, spans, batcher = _score_burst(sv, payloads)
+    assert stats.batches >= 2 and stats.fused_batches == stats.batches
+    assert spans == {
+        "batch.pad": 0, "batch.cache": stats.batches,
+        "batch.fusedpack": stats.batches, "batch.dispatch": stats.batches,
+    }
+    assert batcher.assemblers() == {f"{name}:1": "native"}
+    assert _NATIVE_CONFIGS[name][3] in batcher.upload_formats()[f"{name}:1"]
+    monkeypatch.setattr(native, "available", lambda: False)
+    want, stats, spans, batcher = _score_burst(sv, payloads)
+    assert stats.fused_batches == 0 and spans["batch.fusedpack"] == 0
+    assert spans["batch.pad"] == spans["batch.cache"] == stats.batches
+    assert batcher.assemblers() == {f"{name}:1": "generic: no native library"}
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("case", ["dedup collapse", "x64 model", "compress_transfer off", "custom run_fn"])
+def test_batches_the_native_assembler_leaves_to_the_generic_path(case):
+    """What must stay generic does, by what the code observes, with the
+    same scores; a batch the dedup screen found all-unique rides native."""
+    from distributed_tf_serving_tpu import native
+
+    if not native.ensure():
+        pytest.skip("native hostops unavailable")
+    plain = _native_servable("dlrm_mlperf")
+    payloads = [_native_payload(plain, n, seed=n) for n in (5, 13)]
+    want, stats, _, _ = _score_burst(plain, payloads)
+    assert stats.fused_batches == stats.batches
+    sv, kwargs, why = plain, {}, None
+    if case == "dedup collapse":
+        kwargs = {"dedup": True}
+        payloads = payloads + [payloads[0]]  # a duplicate of every row of one
+        want = np.concatenate([want[:18], want[:5], want[18:]])
+    elif case == "x64 model":
+        sv, why = _native_servable("dlrm_mlperf", needs_x64=True), "x64 model"
+    elif case == "compress_transfer off":
+        kwargs, why = {"compress_transfer": False}, "compress_transfer off"
+    else:
+        def run_fn(servable, arrays):
+            return servable.model.apply(servable.params, arrays)
+
+        kwargs, why = {"run_fn": run_fn}, "custom run_fn"
+    got, stats, spans, batcher = _score_burst(sv, payloads, **kwargs)
+    if case == "dedup collapse":
+        # The burst collapses (generic, from the unique rows); the lone
+        # request after it has nothing to collapse and rides native.
+        assert stats.dedup_batches >= 1
+        assert 0 < stats.fused_batches < stats.batches
+        assert spans["batch.pad"] == stats.batches - stats.fused_batches
+        np.testing.assert_array_equal(got, want)
+    else:
+        assert stats.fused_batches == 0 and spans["batch.fusedpack"] == 0
+        assert spans["batch.pad"] == stats.batches
+        if why != "custom run_fn":
+            assert batcher.assemblers() == {"dlrm_mlperf:1": f"generic: {why}"}
+        if case == "compress_transfer off":
+            np.testing.assert_allclose(got, want, atol=1e-2)  # f32 weights, not bf16
+        else:
+            np.testing.assert_allclose(got, want, rtol=2e-2, atol=1e-3)
+
+
+def test_holds_open_weighs_the_pipeline_and_the_load():
+    """The rule alone: saturated always holds; an empty pipeline never; a
+    natively assembled batch also behind a busy dispatch thread, and behind
+    a batch in flight once requests arrive as fast as batches cross."""
+    batcher = DynamicBatcher(buckets=(16,), pipeline_depth=2)
+    assert not batcher._holds_open(True, 0) and not batcher._holds_open(False, 0)
+    assert batcher._holds_open(False, 2) and batcher._holds_open(True, 2)
+    assert not batcher._holds_open(False, 1)  # the collector pads beside the stage
+    assert not batcher._holds_open(True, 1)  # in flight, no load measured yet
+    batcher._arrival_gap_s, batcher._traversal_s = 0.005, 0.002
+    assert not batcher._holds_open(True, 1)  # a lone request overlaps the flight
+    batcher._arrival_gap_s = 0.002
+    assert batcher._holds_open(True, 1) and not batcher._holds_open(False, 1)
+    batcher._arrival_gap_s, batcher._dispatch_pending = 0.005, 1
+    assert batcher._holds_open(True, 1) and not batcher._holds_open(False, 1)
+
+
+@pytest.mark.parametrize("loaded", [True, False])
+def test_native_batches_fill_while_a_batch_is_in_flight_under_load(monkeypatch, loaded):
+    """One natively assembled batch parked in flight (its readback blocked),
+    then a trickle: under load (arrivals as fast as crossings) the trickle
+    waits for the flight and lands in ONE batch; below it each request
+    dispatches at once, beside the flight, as before."""
+    from distributed_tf_serving_tpu import native
+
+    if not native.ensure():
+        pytest.skip("native hostops unavailable")
+    sv = _native_servable("dlrm_mlperf")
+    release = threading.Event()
+    batcher = DynamicBatcher(buckets=(16, 64), max_wait_us=0, pipeline_depth=8)
+    batcher.warmup(sv)  # no compile inside a stage: a busy dispatch thread holds too
+    batcher.start()
+    real = batcher._execute_fused
+    parked = []
+
+    def execute(ctx, bucket, *args, **kwargs):
+        if not parked:  # the first batch alone: its readback blocks
+            parked.append(bucket)
+            return {"prediction_node": _LazyReadback(bucket, release)}
+        return real(ctx, bucket, *args, **kwargs)
+
+    monkeypatch.setattr(batcher, "_execute_fused", execute)
+    keys = ("prediction_node",)
+    try:
+        first = batcher.submit(sv, _native_payload(sv, 4, 0), output_keys=keys)
+        deadline = time.perf_counter() + 10
+        while not batcher._inflight and time.perf_counter() < deadline:
+            time.sleep(0.002)
+        assert len(batcher._inflight) == 1 and batcher.stats.batches == 1
+        trickled = []
+        for s in range(1, 5):
+            with batcher._cv:  # what the load averages would have settled at
+                batcher._traversal_s = 0.05
+                batcher._arrival_gap_s = 0.01 if loaded else 1.0
+                batcher._last_arrival_t = None
+            trickled.append(batcher.submit(sv, _native_payload(sv, 4, s), output_keys=keys))
+            if loaded:
+                time.sleep(0.03)
+            else:  # answered while the first is still parked in flight
+                assert trickled[-1].result(timeout=60)["prediction_node"].shape == (4,)
+        assert not first.done()
+        assert batcher.stats.batches == (1 if loaded else 5)
+        release.set()
+        for f in [first] + trickled:
+            assert f.result(timeout=60)["prediction_node"].shape == (4,)
+        assert batcher.stats.batches == (2 if loaded else 5)
+        assert batcher.stats.fused_batches == batcher.stats.batches
+        assert (batcher.stats.fill_waits > 0) == loaded
+    finally:
+        release.set()
+        batcher.stop()
+
+
+def test_native_batch_stays_open_while_the_dispatch_thread_is_in_a_stage(monkeypatch):
+    """With the assembly on the dispatch thread a batch closed behind a
+    running stage would only wait staged, closed to later arrivals: the
+    collector keeps it open until the stage ends, whatever the load."""
+    from distributed_tf_serving_tpu import native
+
+    if not native.ensure():
+        pytest.skip("native hostops unavailable")
+    sv = _native_servable("dlrm_mlperf")
+    batcher = DynamicBatcher(buckets=(16, 64), max_wait_us=0, pipeline_depth=8).start()
+    real = batcher._execute_fused
+    in_stage, leave = threading.Event(), threading.Event()
+
+    def execute(ctx, bucket, *args, **kwargs):
+        if not in_stage.is_set():  # the first batch's stage, held open
+            in_stage.set()
+            leave.wait(timeout=30)
+        return real(ctx, bucket, *args, **kwargs)
+
+    monkeypatch.setattr(batcher, "_execute_fused", execute)
+    keys = ("prediction_node",)
+    try:
+        futures = [batcher.submit(sv, _native_payload(sv, 4, 0), output_keys=keys)]
+        assert in_stage.wait(timeout=30)
+        for s in range(1, 4):
+            futures.append(batcher.submit(sv, _native_payload(sv, 4, s), output_keys=keys))
+            time.sleep(0.03)
+        with batcher._cv:
+            assert batcher._dispatch_pending == 1  # nothing staged behind the stage
+        leave.set()
+        for f in futures:
+            assert f.result(timeout=60)["prediction_node"].shape == (4,)
+        assert batcher.stats.batches == 2 and batcher.stats.fused_batches == 2
+    finally:
+        leave.set()
+        batcher.stop()

@@ -386,6 +386,8 @@ def test_cli_server_reports_startup_stamps_and_raw_counters(tmp_path):
         assert startup.pop("upload_format") == {
             "DCN:1": "uint32 words, row planes: feat_ids int32/24b x4, feat_wts bfloat16/16b x2"
         }
+        # And what builds them: one native pass from the requests' arrays.
+        assert startup.pop("assembler") == {"DCN:1": "native"}
         assert set(startup) == {
             "backend_init_s", "params_init_s", "native_build_s", "warmup_s", "to_serving_s",
         }
@@ -400,6 +402,7 @@ def test_cli_server_reports_startup_stamps_and_raw_counters(tmp_path):
             )
         block = _monitoring(rest_port, "metrics")["batcher"]
         assert block["candidates"] == 5 and block["padded_candidates"] == 16
+        assert block["fused_batches"] == block["batches"] == 1
         assert 0 < block["readback_blocked_s"] <= block["readback_window_s"]
         phases = _monitoring(rest_port, "phases")
         assert {"req.queue", "req.resume", "wait.queue_empty", "readback.window"} <= set(phases)

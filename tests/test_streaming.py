@@ -474,9 +474,12 @@ def test_inflight_window_bounds_issuance():
 def test_buffer_ring_reuses_and_stays_correct(servable):
     """Ring-recycled padded buffers must never change scores: sequential
     distinct payloads score identically to the reference while the ring
-    reports reuse."""
+    reports reuse. The ring serves the generic pad path (the native
+    assembler writes the upload's words and pads no array), taken here
+    with the one-buffer upload off."""
     batcher = DynamicBatcher(
         buckets=(32, 64), max_wait_us=0, buffer_ring=True,
+        compress_transfer=False,
     ).start()
     try:
         for s in range(6):
