@@ -58,6 +58,19 @@ class ModelConfig:
     # DLRM
     num_dense_features: int = 13
     bottom_mlp_dims: tuple[int, ...] = (64, 32, 16)
+    # phi4flash (models/phi4flash.py): a candidate row is a sequence of
+    # num_fields token ids; embed_dim is the hidden size and mlp_dims[0] the
+    # width of every layer's gated MLP. The other keys carry the names of the
+    # published config.json; the ssm_* sizes are the Mamba layer's (d_state,
+    # d_conv, expand; its dt_rank is ceil(embed_dim / 16), the published rule).
+    num_hidden_layers: int = 8
+    num_attention_heads: int = 4
+    num_key_value_heads: int = 2
+    sliding_window: int = 512
+    layer_norm_eps: float = 1e-5
+    ssm_state: int = 16
+    ssm_conv: int = 4
+    ssm_expand: int = 2
     # numerics
     compute_dtype: str = "bfloat16"  # "float32" for AUC-parity mode
     param_dtype: str = "float32"
@@ -115,6 +128,9 @@ class Model:
     # True when the signature carries `dense_features` [n, num_dense_features]
     # beside the id/weight pair (the DLRM families).
     takes_dense: bool = False
+    # The kind of every layer of a sequence family (phi4flash), whose rows
+    # are num_fields TOKENS; empty for the CTR families.
+    layer_plan: tuple[str, ...] = ()
 
 
 # ---------------------------------------------------------------------------
@@ -195,7 +211,7 @@ def register_model(kind: str):
 
 def build_model(kind: str, config: ModelConfig | None = None, **overrides) -> Model:
     """Instantiate a model family by kind: dcn, dcn_v2, wide_deep, deepfm,
-    two_tower, dlrm, dlrm_dcnv2."""
+    two_tower, dlrm, dlrm_dcnv2, phi4flash."""
     if kind not in _BUILDERS:
         raise KeyError(f"unknown model kind {kind!r}; have {sorted(_BUILDERS)}")
     if config is None:

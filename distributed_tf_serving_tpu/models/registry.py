@@ -10,10 +10,12 @@ stored SignatureDefs (get_model_metadata.proto:15-30).
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import threading
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -183,6 +185,16 @@ class Servable:
             return None
         return len(config.multi_hot_sizes) or config.num_fields
 
+    @property
+    def layer_plan(self) -> dict[str, int] | None:
+        """Layers of each kind in a sequence family's stack."""
+        return dict(collections.Counter(self.model.layer_plan)) or None
+
+    @property
+    def params_bytes(self) -> int:
+        """Bytes of the parameter tree as it is held."""
+        return sum(int(getattr(leaf, "nbytes", 0)) for leaf in jax.tree.leaves(self.params))
+
     def signature_def_map(self) -> dict:
         return {k: v.to_signature_def() for k, v in self.signatures.items()}
 
@@ -305,7 +317,8 @@ class ServableRegistry:
 
     def per_servable(self, attr: str) -> dict:
         """"name:version" -> that property of every loaded servable
-        (embedding_pack, lookups_per_row, bags)."""
+        (embedding_pack, lookups_per_row, bags, layer_plan,
+        params_bytes)."""
         with self._lock:
             loaded = [s for versions in self._servables.values() for s in versions.values()]
         return {f"{s.name}:{s.version}": getattr(s, attr) for s in loaded}

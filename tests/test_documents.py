@@ -6,6 +6,7 @@ run something the tree no longer holds. PERF.md and CHANGES.md are history
 and may name what was deleted, so they are not checked."""
 
 import glob
+import json
 import pathlib
 import re
 
@@ -53,3 +54,24 @@ def test_document_names_only_paths_that_exist(document):
     assert named, f"{document} names no path at all: the extraction is broken"
     missing = sorted(t for t in named if not _exists(t))
     assert not missing, f"{document} names paths the tree does not hold: {missing}"
+
+
+def _benchmark_lines(section: str) -> list[tuple[str, str]]:
+    """The fields of one section of BENCHMARK.json that the driver holds to
+    one line of 1 to 200 printable characters, as (where, text)."""
+    declared = json.loads((ROOT / "BENCHMARK.json").read_text())
+    if section == "command":
+        return [(f"command[{i}]", word) for i, word in enumerate(declared["command"])]
+    keys = {"configs": ("source", "why"), "workloads": ("why",), "per_layer": ("layer",)}[section]
+    return [(f"{section}.{entry['name']}.{key}", entry[key])
+            for entry in declared[section] for key in keys]
+
+
+@pytest.mark.parametrize("section", ["command", "configs", "workloads", "per_layer"])
+def test_benchmark_declaration_lines_fit_the_form(section):
+    # A `why` of 205 characters had the driver refuse PR 32 before any run.
+    lines = _benchmark_lines(section)
+    assert lines, section
+    bad = [(where, len(text)) for where, text in lines
+           if not (1 <= len(text) <= 200 and text.isprintable() and "\t" not in text)]
+    assert not bad, f"not one line of 1 to 200 printable characters: {bad}"

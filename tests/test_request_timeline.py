@@ -381,6 +381,9 @@ def test_cli_server_reports_startup_stamps_and_raw_counters(tmp_path):
         # Beside the stamps: what each loaded servable looks up a candidate row.
         assert startup.pop("lookups_per_row") == {"DCN:1": F}
         assert startup.pop("bags") == {"DCN:1": F}
+        # A CTR family has no layer plan; its tree's bytes are stamped (PR 32).
+        assert startup.pop("layer_plan") == {"DCN:1": None}
+        assert startup.pop("params_bytes")["DCN:1"] > 0
         # And how its batches cross to the device: the ladder's warm-up
         # traced the one-buffer entry (ops/transfer.py describe_layout).
         assert startup.pop("upload_format") == {
