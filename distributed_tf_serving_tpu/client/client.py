@@ -570,6 +570,13 @@ class ShardedPredictClient:
         # window throttles a half-MB-per-request load at high concurrency.
         self.channels_per_host = max(1, channels_per_host)
         opts = list(LARGE_MESSAGE_CHANNEL_OPTIONS)
+        if self.channels_per_host > 1:
+            # grpc shares one subchannel, so one TCP connection, between
+            # channels of one process with the same target and arguments:
+            # without a pool of its own each channel would stripe nothing,
+            # and the server's listeners (one poller thread a listener, a
+            # connection on one of them) would see one connection.
+            opts.append(("grpc.use_local_subchannel_pool", 1))
         if keepalive_time_ms > 0:
             # keepalive_time_ms=0 opts out entirely — for channels toward
             # stock gRPC backends whose default ping-abuse policy (5-minute

@@ -20,7 +20,9 @@ import dataclasses
 import functools
 import json
 import logging
+import os
 import signal
+import socket
 import threading
 import time
 from concurrent import futures
@@ -199,9 +201,23 @@ class _SyncServicerBase:
     status mapping + per-RPC metrics (+ the per-request server root span
     when tracing is on)."""
 
-    def __init__(self, impl: PredictionServiceImpl, metrics: ServerMetrics | None = None):
+    def __init__(
+        self,
+        impl: PredictionServiceImpl,
+        metrics: ServerMetrics | None = None,
+        listener: int = 0,
+    ):
         self.impl = impl
         self.metrics = metrics or ServerMetrics()
+        # One adapter a listener (create_server), all over the one impl and
+        # the one ServerMetrics: each RPC a listener's connections carried
+        # is counted, with its handler's time, under that listener's phase.
+        self._listener_phase = f"{LISTENER_PHASE}{listener}"
+
+    def _observe(self, name: str, t0: float, ok: bool, model) -> None:
+        seconds = time.perf_counter() - t0
+        self.metrics.observe(name, seconds, ok, model=model)
+        request_trace.add_many(((self._listener_phase, seconds, 1),))
 
     def _call(self, name: str, fn, request, context):
         t0 = time.perf_counter()
@@ -247,7 +263,7 @@ class _SyncServicerBase:
             log.exception("internal error serving %s", name)
             context.abort(grpc.StatusCode.INTERNAL, f"internal error: {e}")
         finally:
-            self.metrics.observe(name, time.perf_counter() - t0, ok, model=model)
+            self._observe(name, t0, ok, model)
 
     def _call_stream(self, name: str, fn, request, context):
         """_call for server-streaming RPCs: `fn(request)` returns a chunk
@@ -286,7 +302,7 @@ class _SyncServicerBase:
             log.exception("internal error serving %s", name)
             context.abort(grpc.StatusCode.INTERNAL, f"internal error: {e}")
         finally:
-            self.metrics.observe(name, time.perf_counter() - t0, ok, model=model)
+            self._observe(name, t0, ok, model)
 
 
 def _deadline_of(context) -> float | None:
@@ -546,6 +562,86 @@ def _add_uds_port(server, uds_path: str) -> None:
         raise RuntimeError(f"could not bind unix:{uds_path}")
 
 
+# The phase a listener's RPCs are counted under, its index appended:
+# `rpc.listener0`, `rpc.listener1`, ... on /monitoring?section=phases.
+LISTENER_PHASE = "rpc.listener"
+
+# How many listeners serve() opens on its one port: one for every
+# CORES_A_LISTENER cores the process may run on, at least one and at most
+# MAX_LISTENERS. On the 13-core host of one v5e chip that is four, which
+# read ahead of two and of one where the wire paces the server (5 MB
+# requests: +9 to +25% rows a second against +4 to +15% at two) and level
+# with one where it does not (PERF.md section 6, PR 34, has the readings).
+MAX_LISTENERS = 4
+CORES_A_LISTENER = 3
+
+
+def listener_count() -> tuple[int, int]:
+    """(listeners serve() opens, the cores they were derived from). A
+    Python `grpc.server` reads every connection's bytes on ONE thread (its
+    completion queue's poller); with several servers on one port the reads
+    spread over as many threads. Nothing configures this: a host too small
+    to give a second poller a core keeps the one listener."""
+    try:
+        cores = len(os.sched_getaffinity(0))
+    except AttributeError:  # not Linux
+        cores = os.cpu_count() or 1
+    return max(1, min(MAX_LISTENERS, cores // CORES_A_LISTENER)), cores
+
+
+def _time_left(deadline: float | None) -> float | None:
+    return None if deadline is None else max(deadline - time.monotonic(), 0.0)
+
+
+class _AllStopped:
+    """What `Listeners.stop` returns: waits like the `threading.Event` one
+    `grpc.Server.stop` returns, for every listener's."""
+
+    def __init__(self, events):
+        self._events = events
+
+    def is_set(self) -> bool:
+        return all(e.is_set() for e in self._events)
+
+    def wait(self, timeout: float | None = None) -> bool:
+        deadline = None if timeout is None else time.monotonic() + timeout
+        for e in self._events:
+            e.wait(_time_left(deadline))
+        return self.is_set()
+
+
+class Listeners:
+    """The `grpc.server` objects of one address, started, stopped and waited
+    for as one server (`start`, `stop`, `wait_for_termination` as
+    `grpc.Server` has them). Each has its own completion queue and poller
+    thread; all share the servicers' impl, the metrics and the handler pool."""
+
+    def __init__(self, servers: list[grpc.Server]):
+        self.servers = servers
+
+    def start(self) -> None:
+        for server in self.servers:
+            server.start()
+
+    def stop(self, grace: float | None) -> _AllStopped:
+        return _AllStopped([server.stop(grace) for server in self.servers])
+
+    def wait_for_termination(self, timeout: float | None = None) -> bool:
+        """True when `timeout` passed with a listener still serving."""
+        deadline = None if timeout is None else time.monotonic() + timeout
+        return any(server.wait_for_termination(_time_left(deadline)) for server in self.servers)
+
+
+def _bind(server: grpc.Server, address: str, credentials) -> int:
+    """The port `address` was bound to, 0 where it could not be."""
+    try:
+        if credentials is not None:
+            return server.add_secure_port(address, credentials)
+        return server.add_insecure_port(address)
+    except RuntimeError:  # grpc raises where older releases returned 0
+        return 0
+
+
 def create_server(
     impl: PredictionServiceImpl,
     address: str = "127.0.0.1:0",
@@ -553,42 +649,74 @@ def create_server(
     metrics: ServerMetrics | None = None,
     credentials: "grpc.ServerCredentials | None" = None,
     uds_path: str | None = None,
-) -> tuple[grpc.Server, int]:
+    listeners: int = 1,
+) -> tuple[Listeners, int]:
     """Build (not start) a server; returns (server, bound_port).
     `credentials` switches the port to TLS (ssl_server_credentials — the
     --ssl-config-file surface; see load_ssl_credentials). `uds_path`
     additionally binds a plaintext Unix-domain socket for co-located
-    clients ([transport] uds_path)."""
-    server = grpc.server(
-        futures.ThreadPoolExecutor(max_workers=max_workers, thread_name_prefix="rpc"),
-        options=list(LARGE_MESSAGE_CHANNEL_OPTIONS) + list(KEEPALIVE_SERVER_OPTIONS),
-    )
-    servicer = GrpcPredictionService(impl, metrics)
-    add_PredictionServiceServicer_to_server(servicer, server)
-    # Same port, second service — exactly tensorflow_model_server's layout.
-    add_ModelServiceServicer_to_server(GrpcModelService(impl, servicer.metrics), server)
-    # Third service: grpc.health.v1 (standard probes + client half-open
-    # probing) — NOT_SERVING until warmup completes, per-model afterward.
-    add_HealthServicer_to_server(GrpcHealthService(impl), server)
-    if credentials is not None:
-        if uds_path:
-            # The UDS listener is plaintext: binding it next to a TLS/mTLS
-            # TCP port would silently open an unauthenticated side door
-            # for any local process that can reach the socket file —
-            # refuse the combination instead of downgrading.
-            raise ValueError(
-                "[transport] uds_path cannot be combined with "
-                "--ssl-config-file: the unix socket is plaintext and "
-                "would bypass the TLS/mTLS the TCP port enforces"
-            )
-        port = server.add_secure_port(address, credentials)
-    else:
-        port = server.add_insecure_port(address)
+    clients ([transport] uds_path).
+
+    `listeners` > 1 binds that many `grpc.server` objects to the one port
+    through SO_REUSEPORT (the first binds `address`, port 0 included, the
+    others the port it got): the kernel spreads CONNECTIONS over them, and
+    each reads its share of the wire on its own thread. They serve the same
+    three services over the one `impl`, `metrics` and pool of `max_workers`
+    handler threads; the Unix-domain socket cannot be shared and stays on
+    the first. Where the host has no SO_REUSEPORT or a further listener
+    cannot bind the port, the first serves alone."""
+    if credentials is not None and uds_path:
+        # The UDS listener is plaintext: binding it next to a TLS/mTLS
+        # TCP port would silently open an unauthenticated side door
+        # for any local process that can reach the socket file —
+        # refuse the combination instead of downgrading.
+        raise ValueError(
+            "[transport] uds_path cannot be combined with "
+            "--ssl-config-file: the unix socket is plaintext and "
+            "would bypass the TLS/mTLS the TCP port enforces"
+        )
+    metrics = metrics or ServerMetrics()
+    pool = futures.ThreadPoolExecutor(max_workers=max_workers, thread_name_prefix="rpc")
+    options = list(LARGE_MESSAGE_CHANNEL_OPTIONS) + list(KEEPALIVE_SERVER_OPTIONS)
+
+    def build(index: int) -> grpc.Server:
+        server = grpc.server(pool, options=options)
+        add_PredictionServiceServicer_to_server(
+            GrpcPredictionService(impl, metrics, index), server)
+        # Same port, second service — exactly tensorflow_model_server's layout.
+        add_ModelServiceServicer_to_server(GrpcModelService(impl, metrics, index), server)
+        # Third service: grpc.health.v1 (standard probes + client half-open
+        # probing) — NOT_SERVING until warmup completes, per-model afterward.
+        add_HealthServicer_to_server(GrpcHealthService(impl), server)
+        return server
+
+    servers = [build(0)]
+    port = _bind(servers[0], address, credentials)
     if port == 0:
         raise RuntimeError(f"could not bind {address}")
     if uds_path:
-        _add_uds_port(server, uds_path)
-    return server, port
+        _add_uds_port(servers[0], uds_path)
+    if listeners > 1 and not hasattr(socket, "SO_REUSEPORT"):
+        log.warning("no SO_REUSEPORT on this host: one gRPC listener, not %d", listeners)
+        listeners = 1
+    shared = f"{address.rpartition(':')[0]}:{port}"
+    for index in range(1, listeners):
+        server = build(index)
+        if _bind(server, shared, credentials) != port:
+            log.warning(
+                "gRPC listener %d of %d could not bind %s: the first serves alone",
+                index + 1, listeners, shared,
+            )
+            # A bound listener that never accepts is still handed its share
+            # of the connections, and grpc closes a listening socket only
+            # on the way through a start and a stop.
+            for extra in servers[1:] + [server]:
+                extra.start()
+                extra.stop(0).wait()
+            del servers[1:]
+            break
+        servers.append(server)
+    return Listeners(servers), port
 
 
 def load_ssl_credentials(path) -> "grpc.ServerCredentials":
@@ -1217,8 +1345,9 @@ class GracefulShutdown:
     3. `batcher.drain(grace_s)`: queued + staged + in-flight batches run
        to completion, bounded by the grace period — work the server
        ACCEPTED is work it answers.
-    4. `server.stop(grace)` with the grace budget REMAINING after the
-       drain (plus a small floor so handler threads can encode the
+    4. `server.stop(grace)`, which stops every listener of the port and
+       waits for all (`Listeners`), with the grace budget REMAINING after
+       the drain (plus a small floor so handler threads can encode the
        responses the drain just completed), then batcher/request-log
        teardown.
 
@@ -2539,17 +2668,23 @@ def serve(argv=None) -> None:
     transport: dict = {}
 
     def listen(impl: PredictionServiceImpl) -> None:
-        # Listen before the parameters are made and the ladder is warmed
-        # (health NOT_SERVING, inference refused UNAVAILABLE until then): a
-        # client that has been dialing since before this process existed is
-        # deep in its reconnect back-off and notices a connection only when
-        # its channel is next polled, so the seconds of load and warm-up
-        # are the time it gets to connect in (PERF.md, PR 26).
+        # Listen, on every listener of the port, before the parameters are
+        # made and the ladder is warmed (health NOT_SERVING, inference
+        # refused UNAVAILABLE until then): a client that has been dialing
+        # since before this process existed is deep in its reconnect
+        # back-off and notices a connection only when its channel is next
+        # polled, so the seconds of load and warm-up are the time it gets
+        # to connect in (PERF.md, PR 26).
+        listeners, cores = listener_count()
         transport["server"], transport["port"] = create_server(
             impl, f"{cfg.host}:{cfg.port}", cfg.max_workers, metrics,
             credentials=credentials,
             uds_path=transport_config.uds_path or None,
+            listeners=listeners,
         )
+        impl.startup["listeners"] = {
+            "k": len(transport["server"].servers), "cores": cores,
+        }
         transport["server"].start()
 
     try:
@@ -2716,8 +2851,9 @@ def serve(argv=None) -> None:
             raise SystemExit(str(exc)) from exc
         log.info("REST gateway on %s:%d (/v1/models/...)", cfg.host, bound)
     log.info(
-        "PredictionService on %s:%d (model=%s kind=%s mesh=%s devices=%s)",
-        cfg.host, port, servable.name if servable else "<awaiting versions>",
+        "PredictionService on %s:%d (listeners=%d model=%s kind=%s mesh=%s devices=%s)",
+        cfg.host, port, len(server.servers),
+        servable.name if servable else "<awaiting versions>",
         cfg.model_kind, dict(mesh.shape) if mesh else None, jax.devices(),
     )
     try:

@@ -178,9 +178,11 @@ class PredictionServiceImpl:
         self.compile_cache = None
         # Start-up as stamps, in seconds, for the runtime block's `startup`
         # (which adds `warmup_s` above): build_stack writes `params_init_s`,
-        # serve() `backend_init_s`, `native_build_s` and `to_serving_s`. A
-        # stamp nobody took (an embedded stack has no serve()) is absent.
-        self.startup: dict[str, float] = {}
+        # serve() `backend_init_s`, `native_build_s` and `to_serving_s`, and
+        # `listeners`: {"k": the gRPC listeners on its port, "cores": the
+        # cores k was derived from}. A stamp nobody took (an embedded stack
+        # has no serve()) is absent.
+        self.startup: dict = {}
 
     def _arena(self):
         """The calling thread's EncodeArena, or None when the plane is

@@ -348,7 +348,8 @@ def _monitoring(port, section):
 def test_cli_server_reports_startup_stamps_and_raw_counters(tmp_path):
     """The CLI server's /monitoring: `runtime.startup` holds the five
     stamps serve() and build_stack take (and each servable's lookups a row,
-    bags and upload format), `metrics.batcher` the raw terms of the two ratios."""
+    bags and upload format, and the listeners on its port), `metrics.batcher`
+    the raw terms of the two ratios."""
     grpc = pytest.importorskip("grpc")
     from distributed_tf_serving_tpu.client import build_predict_request
     from distributed_tf_serving_tpu.proto import PredictionServiceStub
@@ -391,6 +392,11 @@ def test_cli_server_reports_startup_stamps_and_raw_counters(tmp_path):
         }
         # And what builds them: one native pass from the requests' arrays.
         assert startup.pop("assembler") == {"DCN:1": "native"}
+        # And how many gRPC listeners share its port, from how many cores (PR 34).
+        from distributed_tf_serving_tpu.serving.server import listener_count
+
+        k, cores = listener_count()
+        assert startup.pop("listeners") == {"k": k, "cores": cores}
         assert set(startup) == {
             "backend_init_s", "params_init_s", "native_build_s", "warmup_s", "to_serving_s",
         }
@@ -409,6 +415,9 @@ def test_cli_server_reports_startup_stamps_and_raw_counters(tmp_path):
         assert 0 < block["readback_blocked_s"] <= block["readback_window_s"]
         phases = _monitoring(rest_port, "phases")
         assert {"req.queue", "req.resume", "wait.queue_empty", "readback.window"} <= set(phases)
+        # The one Predict is counted under the listener whose connection carried it.
+        counted = {name: p["count"] for name, p in phases.items() if name.startswith("rpc.listener")}
+        assert sum(counted.values()) == 1 and set(counted) <= {f"rpc.listener{i}" for i in range(k)}
     finally:
         proc.send_signal(signal.SIGTERM)
         try:

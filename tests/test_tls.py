@@ -11,6 +11,7 @@ import pytest
 jax = pytest.importorskip("jax")
 import grpc
 
+from distributed_tf_serving_tpu import codec
 from distributed_tf_serving_tpu.client import ShardedPredictClient, build_predict_request
 from distributed_tf_serving_tpu.models import (
     ModelConfig,
@@ -127,6 +128,44 @@ def test_tls_serves_and_rejects_plaintext(pki, stack, tmp_path):
                 PredictionServiceStub(ch).Predict(
                     build_predict_request(arrays, "DCN"), timeout=10
                 )
+    finally:
+        server.stop(0)
+
+
+@pytest.mark.parametrize("listeners", [2, 4])
+def test_tls_on_every_listener(pki, stack, tmp_path, listeners):
+    """--ssl-config-file with several listeners on the port (PR 34): the
+    credentials go on every one, so whichever listener the kernel hands a
+    connection to completes the handshake, and none serves plaintext."""
+    from distributed_tf_serving_tpu.proto import PredictionServiceStub
+
+    impl, sv = stack
+    creds = load_ssl_credentials(_ssl_config(pki, tmp_path))
+    server, port = create_server(impl, "localhost:0", credentials=creds, listeners=listeners)
+    assert len(server.servers) == listeners
+    server.start()
+    try:
+        arrays = _arrays(seed=4)
+        want = np.asarray(sv.model.apply(sv.params, {
+            "feat_ids": arrays["feat_ids"] % CFG.vocab_size,
+            "feat_wts": arrays["feat_wts"],
+        })["prediction_node"])
+        chan_creds = grpc.ssl_channel_credentials(
+            root_certificates=(pki / "ca.crt").read_bytes()
+        )
+        local = [("grpc.use_local_subchannel_pool", 1)]
+        for _ in range(3 * listeners):
+            with grpc.secure_channel(f"localhost:{port}", chan_creds, options=local) as ch:
+                resp = PredictionServiceStub(ch).Predict(
+                    build_predict_request(arrays, "DCN"), timeout=10
+                )
+            np.testing.assert_allclose(
+                codec.to_ndarray(resp.outputs["prediction_node"]), want, rtol=1e-5)
+            with grpc.insecure_channel(f"localhost:{port}", options=local) as ch:
+                with pytest.raises(grpc.RpcError):
+                    PredictionServiceStub(ch).Predict(
+                        build_predict_request(arrays, "DCN"), timeout=5
+                    )
     finally:
         server.stop(0)
 
