@@ -71,6 +71,30 @@ class ModelConfig:
     ssm_state: int = 16
     ssm_conv: int = 4
     ssm_expand: int = 2
+    # pangu_moe (models/pangu_moe.py): a row is num_fields token ids as above;
+    # embed_dim the hidden size, intermediate_size the leading dense layers'
+    # gated MLP width (mlp_dims is not read), layer_norm_eps the RMSNorms'
+    # epsilon. The keys carry the published config.json's names, but for the
+    # two that say what THIS chip holds of a layer other chips share:
+    # num_attention_heads is the heads held (of num_attention_heads_published,
+    # which the start-up stamp alone reads), and the routed layer routes over
+    # all n_routed_experts and computes experts_held of them, from
+    # first_expert_held on.
+    first_k_dense_replace: int = 1
+    intermediate_size: int = 128
+    q_lora_rank: int = 64
+    kv_lora_rank: int = 32
+    qk_nope_head_dim: int = 16
+    qk_rope_head_dim: int = 8
+    v_head_dim: int = 16
+    rope_theta: float = 10000.0
+    num_attention_heads_published: int = 0  # 0 => the heads held are all there are
+    moe_intermediate_size: int = 64
+    n_routed_experts: int = 16
+    num_experts_per_tok: int = 2
+    routed_scaling_factor: float = 1.0
+    experts_held: int = 0  # 0 => all n_routed_experts
+    first_expert_held: int = 0
     # numerics
     compute_dtype: str = "bfloat16"  # "float32" for AUC-parity mode
     param_dtype: str = "float32"
@@ -128,9 +152,21 @@ class Model:
     # True when the signature carries `dense_features` [n, num_dense_features]
     # beside the id/weight pair (the DLRM families).
     takes_dense: bool = False
-    # The kind of every layer of a sequence family (phi4flash), whose rows
-    # are num_fields TOKENS; empty for the CTR families.
+    # The kind of every layer of a sequence family (phi4flash, pangu_moe),
+    # whose rows are num_fields TOKENS; empty for the CTR families.
     layer_plan: tuple[str, ...] = ()
+    # What a family with a routed layer holds of it, as (name, number) pairs:
+    # published, held, first, top_k, heads_published, heads_held,
+    # chips_sharing_layer (pangu_moe); empty for every other family.
+    expert_plan: tuple[tuple[str, int], ...] = ()
+    # For a family whose step counts what it did on the device (pangu_moe's
+    # routing): `apply_stats(params, batch) -> (apply's outputs, int32
+    # [len(step_stats)])`, the counters named by `step_stats` in order. The
+    # batcher decides on it when it BUILDS the servable's entry: the counters
+    # then ride back beside the scores and are recorded as phases by count.
+    # None, as for every other family, and nothing of that exists.
+    apply_stats: Callable[[Params, Batch], tuple[dict[str, jax.Array], jax.Array]] | None = None
+    step_stats: tuple[str, ...] = ()
 
 
 # ---------------------------------------------------------------------------
@@ -211,7 +247,7 @@ def register_model(kind: str):
 
 def build_model(kind: str, config: ModelConfig | None = None, **overrides) -> Model:
     """Instantiate a model family by kind: dcn, dcn_v2, wide_deep, deepfm,
-    two_tower, dlrm, dlrm_dcnv2, phi4flash."""
+    two_tower, dlrm, dlrm_dcnv2, phi4flash, pangu_moe."""
     if kind not in _BUILDERS:
         raise KeyError(f"unknown model kind {kind!r}; have {sorted(_BUILDERS)}")
     if config is None:

@@ -58,6 +58,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from . import sequence
 from .base import Model, ModelConfig, register_model
 from .embeddings import embedding_init, field_embed
 
@@ -68,11 +69,9 @@ RMS_EPS = 1e-5  # the per-head RMSNorm of the differential attention
 # position by position inside a chunk, unrolled, so the loop's overhead and
 # the state's round trip through memory are paid once a chunk.
 SCAN_CHUNK = 16
-# Queries a block of the attention at all positions: a [block, keys] score
-# tile per head instead of [L, L].
-ATTN_BLOCK = 512
-# Pieces of the compute dtype a wider activation enters a product as.
-OPERAND_PIECES = 2
+# Pieces of the compute dtype a wider activation enters a product as: read at
+# every call of `_product` (models/sequence.py has the product itself).
+OPERAND_PIECES = sequence.OPERAND_PIECES
 
 
 def layer_plan(num_layers: int) -> tuple[str, ...]:
@@ -197,33 +196,9 @@ def _layer_init(rng, kind: str, s: dict, dtype) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def _pieces(x: jax.Array, cd) -> list[jax.Array]:
-    """x as arrays of the compute dtype that sum to it: its rounding, then
-    the rounding of what that left, OPERAND_PIECES in all; x alone where the
-    compute dtype holds it whole. The rounding is `reduce_precision`, which
-    the compiler has to keep: a cast to the compute dtype and back it may
-    take for excess precision it is allowed to keep (the TPU's does), and
-    every piece after the first is then zero."""
-    info = jnp.finfo(cd)
-    if info.bits >= jnp.finfo(x.dtype).bits:
-        return [x.astype(cd)]
-    out = []
-    for _ in range(OPERAND_PIECES):
-        piece = jax.lax.reduce_precision(x, info.nexp, info.nmant)
-        out.append(piece.astype(cd))
-        x = x - piece
-    return out
-
-
 def _product(spec: str, x: jax.Array, y: jax.Array, cd) -> jax.Array:
-    """einsum(spec, x, y) with operands in the compute dtype and a float32
-    result: one pass a pair of pieces, but for the pairs whose product is
-    below the last piece's size."""
-    xs, ys = _pieces(x, cd), _pieces(y, cd)
-    return sum(
-        jnp.einsum(spec, a, b, preferred_element_type=jnp.float32)
-        for i, a in enumerate(xs) for j, b in enumerate(ys) if i + j < max(len(xs), len(ys))
-    )
+    """einsum(spec, x, y) as `sequence.product`, at this family's pieces."""
+    return sequence.product(spec, x, y, cd, OPERAND_PIECES)
 
 
 def _dot(x: jax.Array, w: jax.Array, cd) -> jax.Array:
@@ -319,12 +294,7 @@ def diff_attention(p, q, k, v, layer: int, q_start: int, window: int | None, s: 
     per_group = s["heads"] // 2 // groups
     q = q.reshape(n, lq, groups, per_group, 2, head)
     scores = _product("nqgjcd,nkgcd->ngjcqk", q, k, cd) * head ** -0.5
-    q_pos = q_start + jnp.arange(lq)[:, None]
-    k_pos = jnp.arange(k.shape[1])[None, :]
-    seen = k_pos <= q_pos
-    if window is not None:
-        seen &= q_pos - k_pos < window
-    probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+    probs = sequence.causal_softmax(scores, q_start, window)
     out = _product("ngjcqk,nkge->nqgjce", probs, v, cd)
     f32 = lambda name: p[name].astype(jnp.float32)  # noqa: E731
     base = lambda_init(layer)
@@ -340,16 +310,15 @@ def diff_attention(p, q, k, v, layer: int, q_start: int, window: int | None, s: 
 
 def _attend(p, q, k, v, layer: int, window: int | None, s: dict, cd) -> jax.Array:
     """diff_attention for queries at the LAST q.shape[1] positions of the
-    keys' range (all of them, or the last one alone), in blocks of ATTN_BLOCK
-    queries; a block reads only the keys its window can reach."""
+    keys' range (all of them, or the last one alone), in `sequence`'s blocks
+    of queries; a block reads only the keys its window can reach."""
     offset = k.shape[1] - q.shape[1]
-    out = []
-    for start in range(0, q.shape[1], ATTN_BLOCK):
-        stop = min(start + ATTN_BLOCK, q.shape[1])
-        first = 0 if window is None else max(0, offset + start - window + 1)
-        out.append(diff_attention(
-            p, q[:, start:stop], k[:, first:offset + stop], v[:, first:offset + stop], layer,
-            offset + start - first, window, s, cd))
+    out = [
+        diff_attention(
+            p, q[:, start:stop], k[:, first:last], v[:, first:last], layer,
+            offset + start - first, window, s, cd)
+        for start, stop, first, last in sequence.query_blocks(q.shape[1], k.shape[1], window)
+    ]
     return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
 
 
@@ -387,7 +356,7 @@ def forward(config: ModelConfig, params, batch) -> jax.Array:
                 p = layer["attn"]
                 keys, values = _split_kv(_dot(a, p["qkv"][:, hidden:], cd), s)
                 # From here on only the last position is computed.
-                x, a, memory = x[:, -1:], a[:, -1:], memory[:, -1:]
+                x, a, memory = sequence.last_position(x, a, memory)
                 q = _dot(a, p["qkv"][:, :hidden], cd)
                 mix = _dot(_attend(p, q, keys, values, i, None, s, cd), p["o"], cd)
         elif kind == "gmu":

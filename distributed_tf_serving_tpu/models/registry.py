@@ -191,6 +191,13 @@ class Servable:
         return dict(collections.Counter(self.model.layer_plan)) or None
 
     @property
+    def expert_plan(self) -> dict[str, int] | None:
+        """What a family with a routed layer holds of it here: experts
+        published, held and the first held, experts a token, heads published
+        and held, chips sharing a layer. None for every other family."""
+        return dict(self.model.expert_plan) or None
+
+    @property
     def params_bytes(self) -> int:
         """Bytes of the parameter tree as it is held."""
         return sum(int(getattr(leaf, "nbytes", 0)) for leaf in jax.tree.leaves(self.params))
@@ -317,7 +324,7 @@ class ServableRegistry:
 
     def per_servable(self, attr: str) -> dict:
         """"name:version" -> that property of every loaded servable
-        (embedding_pack, lookups_per_row, bags, layer_plan,
+        (embedding_pack, lookups_per_row, bags, layer_plan, expert_plan,
         params_bytes)."""
         with self._lock:
             loaded = [s for versions in self._servables.values() for s in versions.values()]

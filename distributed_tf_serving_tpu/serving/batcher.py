@@ -672,6 +672,36 @@ def _replay_group_phases(group: list["_WorkItem"], phases: list) -> None:
             tracing.replay_phases(it.span, phases)
 
 
+# The key a counting step's counters take among its executable's outputs
+# (Model.apply_stats). The dispatch stage takes it out of what the entry
+# returned before anything reads the outputs, so it is never in a fetch, a
+# shadow compare or a response.
+STEP_STATS_KEY = "::step_stats"
+
+
+def _with_step_stats(apply_stats, finish):
+    """For a model whose step counts what it did (Model.apply_stats): the
+    `apply` and the trace-time `finish` of _build_entry, such that the
+    executable returns the counters under STEP_STATS_KEY beside the outputs
+    that the selection and the wire downcast leave. A variant that returns
+    something else than `finish`'s (top-k, prune) or runs another apply (the
+    kernel plane's) carries none."""
+
+    def apply(params, batch):
+        out, stats = apply_stats(params, batch)
+        return {**out, STEP_STATS_KEY: stats}
+
+    def finish_beside(out, out_keys):
+        out = dict(out)
+        stats = out.pop(STEP_STATS_KEY, None)
+        done = finish(out, out_keys)
+        if stats is not None:
+            done[STEP_STATS_KEY] = stats
+        return done
+
+    return apply, finish_beside
+
+
 class _Timeline:
     """One batch's share of the request timeline `req.*` (request_trace).
 
@@ -1872,6 +1902,12 @@ class DynamicBatcher:
                 picked = {k: v for k, v in out.items() if k in out_keys}
                 out = picked or out  # never trace an empty output pytree
             return compact_outputs_device(out, wire)
+
+        # A step that counts (models/base.py Model.apply_stats): the counters
+        # leave the executable beside the outputs that were asked for, and
+        # the dispatch stage, which reads `model.step_stats`, takes them out.
+        if model.apply_stats is not None:
+            apply, finish = _with_step_stats(model.apply_stats, finish)
 
         variants: dict[tuple, Callable] = {}
 
@@ -3118,6 +3154,14 @@ class DynamicBatcher:
                 # and closes there (or in this frame's finally on a
                 # pre-handoff failure).
                 run_token = run_fn_cap.take_issue_token()
+            # A counting step's counters (names, device int32), or None: what
+            # the model was built with decides, and the completer records
+            # them once a batch. A shadow execution's are dropped with it.
+            step_stats = None
+            if servable.model.step_stats:
+                counts = outputs.pop(STEP_STATS_KEY, None)
+                if counts is not None:
+                    step_stats = (servable.model.step_stats, counts)
             if topk:
                 if prune:
                     self.stats.prune_batches += 1
@@ -3214,6 +3258,8 @@ class DynamicBatcher:
                     for v in shadow_fetch.values():
                         if hasattr(v, "copy_to_host_async"):
                             v.copy_to_host_async()
+                if step_stats is not None:
+                    step_stats[1].copy_to_host_async()
             with sink_ctx():
                 request_trace.add(
                     "readback.issue", time.perf_counter() - issue_t0
@@ -3279,7 +3325,7 @@ class DynamicBatcher:
                 stage_t0, util=util, bucket=bucket, ring_bufs=ring_bufs,
                 row_ctx=row_ctx, run_token=run_token,
                 run_fn=run_fn_cap if run_token is not None else None,
-                shadow=shadow_fetch,
+                shadow=shadow_fetch, step_stats=step_stats,
             ).add_done_callback(
                 lambda f, g=group: self._guard_worker_future(f, g, "completer")
             )
@@ -3342,6 +3388,7 @@ class DynamicBatcher:
         row_ctx: "_RowContext | None" = None,
         run_token=None, run_fn=None,
         shadow: dict | None = None,
+        step_stats: tuple | None = None,
     ) -> None:
         phases: list | None = (
             [] if tracing.enabled() and any(it.span is not None for it in group)
@@ -3370,6 +3417,14 @@ class DynamicBatcher:
                 done_t = time.perf_counter()
                 waited = done_t - wait_t0
                 request_trace.add("readback.wait", waited)
+                if step_stats is not None:
+                    # Phases BY COUNT (`/monitoring?section=phases`: `count`
+                    # is the sum, `total_ms` stays 0), copied beside the
+                    # scores by the readback the dispatch stage started.
+                    request_trace.add_many(tuple(
+                        (name, 0.0, int(n))
+                        for name, n in zip(step_stats[0], np.asarray(step_stats[1]))
+                    ))
             # Everything from here to the last set_result is this batch's
             # delivery: one span, closed in the finally below.
             delivery.enter_context(request_trace.span("batch.deliver"))

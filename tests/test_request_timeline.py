@@ -384,6 +384,8 @@ def test_cli_server_reports_startup_stamps_and_raw_counters(tmp_path):
         assert startup.pop("bags") == {"DCN:1": F}
         # A CTR family has no layer plan; its tree's bytes are stamped (PR 32).
         assert startup.pop("layer_plan") == {"DCN:1": None}
+        # Nor an expert plan: that is a routed family's share (PR 35).
+        assert startup.pop("expert_plan") == {"DCN:1": None}
         assert startup.pop("params_bytes")["DCN:1"] > 0
         # And how its batches cross to the device: the ladder's warm-up
         # traced the one-buffer entry (ops/transfer.py describe_layout).
