@@ -30,6 +30,9 @@ bag's weighted rows to one vector.
 
 from __future__ import annotations
 
+import contextlib
+import threading
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -125,12 +128,97 @@ def unpack_params(params, embed_dim: int):
     return _convert_table(params, unpack_table, embed_dim)
 
 
+_served = threading.local()  # .entry: (notes, interpret) while serving_gathers is entered
+
+
+@contextlib.contextmanager
+def serving_gathers(notes: list, interpret: bool = False):
+    """While the batcher traces a one-chip served entry in this thread
+    (serving/batcher.py _build_entry, and nowhere else): lookup_rows may take
+    the Pallas gather kernel, and appends to `notes` what it chose for each
+    gather (gather_choice's dict, once each), the servable's `startup.gather`
+    stamp. `interpret` is for tests on the CPU: choose as on a TPU and run
+    the kernel in interpret mode.
+
+    Outside it every gather is XLA's `jnp.take`, which GSPMD partitions, which
+    runs under shard_map and which differentiates: the mesh executors
+    (parallel/executor.py, parallel/multihost.py), the sharded lookup
+    (parallel/embedding_sharding.py) and the trainer (train/trainer.py) trace
+    `model.apply` themselves and keep it. A `tpu_custom_call` can do none of
+    the three."""
+    before = getattr(_served, "entry", None)
+    _served.entry = (notes, interpret)
+    try:
+        yield notes
+    finally:
+        _served.entry = before
+
+
+def gather_choice(table: jax.Array, rows: jax.Array) -> dict:
+    """Which gather serves table[rows], from what a trace can see:
+    `{"kernel": "pallas" | "xla", "row_bytes", "in_flight",
+    "picked_in_kernel"}`, a servable's `startup.gather` stamp.
+
+    The Pallas kernel (ops/gather_kernel.py) takes a table whose row is
+    exactly one float32 lane row, [V, 128], on a TPU, inside serving_gathers
+    (a one-chip served entry): the regime where XLA's gather costs 10-12 ns a
+    looked-up row whatever the row holds and the kernel under 4 (PERF.md
+    section 6, PR 39), at every lookup count of both benchmark ladders
+    (512 x 26 to 8192 x 214 rows), so there is no threshold. Everything else
+    keeps XLA's: a table of another width (the sequence families'
+    [200064, 2560] and [19200, 7680]: 8192 lookups of 5-15 KB,
+    bandwidth-bound; a logical [V, 16]), a bfloat16 [V, 128] table (two rows
+    share a 32-bit sublane and Mosaic refuses a one-row copy), every CPU run,
+    and every trace outside serving_gathers. Nothing picks a packed row's
+    lanes inside the kernel yet: XLA's mask and fold follow it (PERF.md
+    section 6, PR 39 has what was weighed)."""
+    choice = {
+        "kernel": "xla",
+        "row_bytes": table.shape[1] * table.dtype.itemsize,
+        "in_flight": 0,
+        "picked_in_kernel": False,
+    }
+    served = getattr(_served, "entry", None)
+    if (
+        served is not None
+        and (served[1] or jax.default_backend() == "tpu")
+        and table.shape[1] == LANES
+        and table.dtype == jnp.float32
+        and rows.size
+    ):
+        from ..ops.gather_kernel import rows_in_flight
+
+        choice.update(kernel="pallas", in_flight=rows_in_flight(rows.shape))
+    return choice
+
+
+def _take_rows(table: jax.Array, rows: jax.Array, dtype) -> jax.Array:
+    """table[rows] in `dtype` by the gather gather_choice names, noted for
+    the served entry being traced."""
+    choice = gather_choice(table, rows)
+    served = getattr(_served, "entry", None)
+    if served is not None and choice not in served[0]:
+        served[0].append(choice)
+    if choice["kernel"] == "xla":
+        return jnp.take(table, rows, axis=0).astype(dtype)
+    from ..ops.gather_kernel import gather_rows
+
+    return gather_rows(table, rows, dtype, interpret=served[1])
+
+
 def lookup_rows(table: jax.Array, rows: jax.Array, embed_dim: int, dtype) -> jax.Array:
     """table[rows] in `dtype`, for a logical or a packed table, bit for bit.
 
     table  [V/P, P*D]; P is read off the shape (1: a logical table)
     rows   [...] int32 in [0, V)
     returns [..., D]
+
+    The gather of the table's rows is XLA's `jnp.take` or, for a [V, 128]
+    float32 table in a one-chip served entry on a TPU, the Pallas kernel
+    that keeps row copies in flight (gather_choice has the rule and why;
+    both give the same bits). The sharded caller
+    (parallel/embedding_sharding.py, under shard_map) keeps XLA's gather, as
+    every trace outside serving_gathers does.
 
     Packed, row r is lanes (r % P) * D ... + D of packed row r // P: one
     gather of whole lane rows (cast on the way out, so the [..., 128]
@@ -141,9 +229,9 @@ def lookup_rows(table: jax.Array, rows: jax.Array, embed_dim: int, dtype) -> jax
     PERF.md (PR 25)."""
     p = table.shape[1] // embed_dim
     if p == 1:
-        return jnp.take(table, rows, axis=0).astype(dtype)
+        return _take_rows(table, rows, dtype)
     # P is a power of two (D divides 128): shift and mask, not divide.
-    wide = jnp.take(table, rows >> (p.bit_length() - 1), axis=0).astype(dtype)
+    wide = _take_rows(table, rows >> (p.bit_length() - 1), dtype)
     lane = jnp.arange(LANES, dtype=rows.dtype)
     keep = lane // embed_dim == (rows & (p - 1))[..., None]
     fold = (lane[:, None] % embed_dim == jnp.arange(embed_dim, dtype=rows.dtype)).astype(dtype)

@@ -64,6 +64,7 @@ from . import overload as overload_mod
 from ..cache import CoalescedLeaderCancelled, collapse_rows
 from ..cache.digest import canonical_rows
 from ..models.base import Model
+from ..models.embeddings import serving_gathers
 from ..models.registry import Servable
 from ..ops.transfer import (
     cascade_prune_device,
@@ -760,6 +761,9 @@ class BatcherStats:
     # fold + pack + pad + concat in one pass an input, for any combined
     # layout, instead of 4 python/numpy passes + 3 temporaries).
     fused_batches: int = 0
+    # Batches that ran an entry whose embedding gather is the Pallas kernel
+    # (models/embeddings.py gather_choice; `startup.gather` names it).
+    gather_kernel_batches: int = 0
     # Batches whose outputs rode the top-k compaction (only k (score, idx)
     # pairs crossed the D2H link instead of the full score vector).
     topk_batches: int = 0
@@ -1050,6 +1054,14 @@ class DynamicBatcher:
         self._upload_formats: weakref.WeakKeyDictionary[Servable, list] = (
             weakref.WeakKeyDictionary()
         )
+        # servable -> what lookup_rows chose for each gather its entry has
+        # been traced with (models/embeddings.py gather_choice):
+        # /monitoring's `startup.gather`; and the servables of them whose
+        # entry gathers with the Pallas kernel, whose batches are counted.
+        self._gathers: weakref.WeakKeyDictionary[Servable, list] = (
+            weakref.WeakKeyDictionary()
+        )
+        self._gather_kernel: weakref.WeakSet[Servable] = weakref.WeakSet()
         # _jit_for is reached from the batcher thread (fused-path
         # eligibility) AND the dispatch thread; one lock keeps the entry
         # build single-shot.
@@ -1614,6 +1626,20 @@ class DynamicBatcher:
                 for sv, formats in self._upload_formats.items()
             }
 
+    def gathers(self) -> dict[str, dict]:
+        """"name:version" -> the embedding gather of that servable's entry
+        as traced: `{"kernel": "pallas" | "xla", "row_bytes", "in_flight",
+        "picked_in_kernel"}` (the kernel's widest block where rungs differ),
+        for every servable whose step looked rows up. A custom run_fn traces
+        its own entries, outside serving_gathers: XLA's gather, no stamp."""
+        with self._jit_lock:
+            return {
+                f"{sv.name}:{sv.version}": max(
+                    notes, key=lambda n: (n["kernel"] == "pallas", n["in_flight"])
+                )
+                for sv, notes in self._gathers.items() if notes
+            }
+
     def pipeline_stats(self) -> dict:
         """Continuous-batching pipeline snapshot (ISSUE 9): configured
         depth/window, live in-flight occupancy (total and per bucket),
@@ -1883,6 +1909,23 @@ class DynamicBatcher:
         # A list, appended to at trace time and read by upload_formats()
         # from another thread: an append never breaks a reader's iteration.
         formats = self._upload_formats[servable] = []
+        # Likewise what the step's embedding gather is, noted while an
+        # executable is traced (every variant and every rung notes again).
+        # This entry runs on one chip (a mesh executor is a run_fn and never
+        # gets here), so it is the one trace in which lookup_rows may take
+        # the Pallas gather kernel (models/embeddings.py serving_gathers).
+        gathers = self._gathers[servable] = []
+        self._gather_kernel.discard(servable)
+
+        def noting(ap):
+            def traced(p, batch):
+                with serving_gathers(gathers):
+                    out = ap(p, batch)
+                if any(note["kernel"] == "pallas" for note in gathers):
+                    self._gather_kernel.add(servable)
+                return out
+            return traced
+
         if not combined:
             formats.append("per key: " + (", ".join(
                 f"{k} {v}" for k, v in sorted(spec.items())) or "as sent"))
@@ -1950,7 +1993,7 @@ class DynamicBatcher:
                 if jfn is None:
                     if (fmt := describe_layout(layout)) not in formats:
                         formats.append(fmt)
-                    ap = k_apply or apply
+                    ap = noting(k_apply or apply)
                     if topk:
                         select = cascade_prune_device if prune \
                             else topk_compact_device
@@ -1973,7 +2016,7 @@ class DynamicBatcher:
                 key = (out_keys, topk, k_apply, prune)
                 jfn = _cache.get(key)
                 if jfn is None:
-                    ap = k_apply or apply
+                    ap = noting(k_apply or apply)
                     if topk:
                         select = cascade_prune_device if prune \
                             else topk_compact_device
@@ -3148,6 +3191,10 @@ class DynamicBatcher:
                             out_keys=wanted_key, topk=topk, n_valid=n_valid,
                             prune=prune,
                         )
+                if servable in self._gather_kernel:
+                    # A phase by count, beside `batch.dispatch`'s.
+                    self.stats.gather_kernel_batches += 1
+                    request_trace.add_many((("batch.gather_kernel", 0.0, 1),))
             if run_fn_cap is not None and getattr(run_fn_cap, "elastic", False):
                 # Same thread, synchronous: the token names the split the
                 # dispatch above routed to. It travels to the completer

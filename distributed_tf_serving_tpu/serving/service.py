@@ -209,7 +209,8 @@ class PredictionServiceImpl:
         compile wall, the start-up's stamps (`startup`, with each loaded
         servable's embedding rows a candidate row, `lookups_per_row`, the
         `bags` they pool to, a sequence family's `layer_plan`, a routed family's `expert_plan`, its `params_bytes`, the `upload_format` of its batches and the
-        `assembler` that builds them: "native" or "generic: <why>"), the pack factor of
+        `assembler` that builds them: "native" or "generic: <why>", and the `gather` of its
+        embedding rows as traced: the Pallas kernel or XLA's, `models/embeddings.py`), the pack factor of
         each loaded servable's embedding table (`embedding_pack`), persistent-cache
         traffic and whether the native host ops are loaded: the `runtime`
         block in /monitoring. jax falls back
@@ -222,6 +223,7 @@ class PredictionServiceImpl:
         block["warmup_s"] = self.warmup_s
         upload_formats = getattr(self.batcher, "upload_formats", None)
         assemblers = getattr(self.batcher, "assemblers", None)
+        gathers = getattr(self.batcher, "gathers", None)
         block["startup"] = {
             **self.startup,
             "warmup_s": self.warmup_s,
@@ -232,6 +234,7 @@ class PredictionServiceImpl:
             "params_bytes": self.registry.per_servable("params_bytes"),
             "upload_format": upload_formats() if callable(upload_formats) else {},
             "assembler": assemblers() if callable(assemblers) else {},
+            "gather": gathers() if callable(gathers) else {},
         }
         block["embedding_pack"] = self.registry.per_servable("embedding_pack")
         block["compile_cache"] = (

@@ -749,6 +749,9 @@ class ServerMetrics:
                 # Of them, those the native assembler built (one pass from
                 # the requests' arrays to the upload's words).
                 "fused_batches": getattr(batcher_stats, "fused_batches", 0),
+                # And those whose entry gathers embedding rows with the
+                # Pallas kernel (`startup.gather`).
+                "gather_kernel_batches": getattr(batcher_stats, "gather_kernel_batches", 0),
                 "requests": batcher_stats.requests,
                 "mean_occupancy": round(batcher_stats.mean_occupancy, 3),
                 # The ratios' raw terms (mean_occupancy above,

@@ -394,6 +394,10 @@ def test_cli_server_reports_startup_stamps_and_raw_counters(tmp_path):
         }
         # And what builds them: one native pass from the requests' arrays.
         assert startup.pop("assembler") == {"DCN:1": "native"}
+        # And what gathers its embedding rows (PR 39): XLA's, on a CPU
+        # backend and over this table's 16-byte rows.
+        assert startup.pop("gather") == {"DCN:1": {
+            "kernel": "xla", "row_bytes": 16, "in_flight": 0, "picked_in_kernel": False}}
         # And how many gRPC listeners share its port, from how many cores (PR 34).
         from distributed_tf_serving_tpu.serving.server import listener_count
 
@@ -414,6 +418,7 @@ def test_cli_server_reports_startup_stamps_and_raw_counters(tmp_path):
         block = _monitoring(rest_port, "metrics")["batcher"]
         assert block["candidates"] == 5 and block["padded_candidates"] == 16
         assert block["fused_batches"] == block["batches"] == 1
+        assert block["gather_kernel_batches"] == 0
         assert 0 < block["readback_blocked_s"] <= block["readback_window_s"]
         phases = _monitoring(rest_port, "phases")
         assert {"req.queue", "req.resume", "wait.queue_empty", "readback.window"} <= set(phases)

@@ -117,3 +117,65 @@ def test_bags_pool_in_one_matmul_fused_with_their_weights(one_chip, no_compile_c
     text = compiled.as_text()
     assert re.search(rf"bf16\[{sum(bags) * bucket},128\]\S* fusion\(%p__embedding__", text)
     assert compiled.memory_analysis().temp_size_in_bytes < GIB // 2
+
+
+# ------------------------------------------------ the Pallas gather (PR 39)
+#
+# Interpret mode cannot see what Mosaic refuses (a slice off the tiling, too
+# much scalar or vector memory, a semaphore too many): the kernel at the three
+# CTR cells' top rungs, and the DCN-v2 step through it.
+
+
+@pytest.mark.parametrize("shape", [(32768, 43), (8192, 214), (16384, 26), (512 * 26,)], ids=str)
+def test_gather_kernel_compiles_at_the_cells_top_rungs(one_chip, no_compile_cache, shape):
+    from distributed_tf_serving_tpu.ops.gather_kernel import gather_rows
+
+    table = jax.ShapeDtypeStruct((1 << 24, 128), jnp.float32, sharding=one_chip)
+    rows = jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    compiled = (
+        jax.jit(functools.partial(gather_rows, dtype=jnp.bfloat16)).lower(table, rows).compile()
+    )
+    assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+    # The table is read where it lies: nothing table-sized beside it.
+    assert compiled.memory_analysis().temp_size_in_bytes < GIB // 2
+
+
+def test_a_bfloat16_lane_row_table_is_refused_by_mosaic(one_chip, no_compile_cache):
+    """Why gather_choice leaves a bfloat16 [V, 128] table to XLA: two of its
+    rows share a 32-bit sublane, and a one-row copy is off the tiling."""
+    from distributed_tf_serving_tpu.ops.gather_kernel import gather_rows
+
+    table = jax.ShapeDtypeStruct((1 << 20, 128), jnp.bfloat16, sharding=one_chip)
+    rows = jax.ShapeDtypeStruct((1024, 43), jnp.int32, sharding=one_chip)
+    with pytest.raises(Exception, match="aligned to tiling"):
+        jax.jit(functools.partial(gather_rows, dtype=jnp.bfloat16)).lower(table, rows).compile()
+
+
+def test_step_through_the_gather_kernel_has_no_xla_gather(one_chip, model, no_compile_cache, monkeypatch):
+    """The DCN-v2 step as a TPU traces it (here the backend is the CPU, so the
+    test says `tpu` where lookup_rows asks, inside serving_gathers as the
+    batcher's entry is): the rows come from the kernel as
+    [n, F, 128], so neither XLA's gather fusion nor the relayout after it is
+    in the program; the table is still read in place."""
+    from distributed_tf_serving_tpu.models import embeddings
+
+    monkeypatch.setattr(embeddings.jax, "default_backend", lambda: "tpu")
+    bucket = 1024
+    shapes = jax.eval_shape(functools.partial(model.init, packed=True), jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), shapes
+    )
+    batch = {
+        "feat_ids": jax.ShapeDtypeStruct((bucket, FIELDS), jnp.int32, sharding=one_chip),
+        "feat_wts": jax.ShapeDtypeStruct((bucket, FIELDS), jnp.bfloat16, sharding=one_chip),
+    }
+    with embeddings.serving_gathers([]) as notes:
+        compiled = (
+            jax.jit(lambda p, b: model.apply(p, b)["prediction_node"]).lower(params, batch).compile()
+        )
+    assert notes == [{
+        "kernel": "pallas", "row_bytes": 512, "in_flight": 32 * FIELDS, "picked_in_kernel": False}]
+    text = compiled.as_text()
+    assert re.search(rf"bf16\[{bucket},{FIELDS},128\]\S* custom-call\(", text)
+    assert not re.search(rf"bf16\[{bucket * FIELDS},128\]\S* fusion\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < GIB // 8
