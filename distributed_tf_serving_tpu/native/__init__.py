@@ -149,6 +149,7 @@ def _load_locked(build: bool = True) -> ctypes.CDLL | None:
         ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
         ctypes.c_int64, ctypes.c_void_p,
     ]
+    lib.assemble_batch.restype = ctypes.c_int64
     return lib
 
 
@@ -332,10 +333,12 @@ def assemble_batch(
     layout: tuple,
     parts: dict[str, list[np.ndarray]],
     fold: dict[str, int] | None = None,
-) -> np.ndarray:
+) -> tuple[np.ndarray, int]:
     """One native pass from a batch's per-request arrays to its combined
-    upload buffer (see hostops.cc): `layout` is ops/transfer.py's
-    combined_layout of the PADDED batch, (bucket, entries); `parts[key]` the
+    upload buffer, and the nanoseconds the pass itself took by its own clock
+    (the GIL released: a caller's clock around this call reads ctypes and the
+    wait to take the GIL back on top). See hostops.cc: `layout` is
+    ops/transfer.py's combined_layout of the PADDED batch, (bucket, entries); `parts[key]` the
     requests' [n_p, *trailing] arrays of that input, in batch order; `fold`
     names the inputs whose int64 parts are ids to fold, with their vocab.
     The result is bit for bit pack_host_combined over the padded, folded
@@ -400,8 +403,8 @@ def assemble_batch(
         planes = PLANES[width]
         words += -(-bucket // planes) * int(inner[k]) * planes * width // 32
     out = np.empty(words, np.uint32)
-    lib.assemble_batch(
+    native_ns = lib.assemble_batch(
         num_inputs, _ptr(bits), _ptr(inner), _ptr(vocab), _ptr(ptrs),
         _ptr(kinds), _ptr(ns), num_parts, bucket, _ptr(out),
     )
-    return out
+    return out, native_ns

@@ -12,6 +12,7 @@
 // functions are thread-safe (pure element-wise transforms on caller-owned
 // buffers).
 
+#include <chrono>
 #include <cstdint>
 #include <cstring>
 #include <type_traits>
@@ -449,11 +450,15 @@ void pack_planes_raw8(const uint8_t* in, int64_t n_rows, int64_t inner,
 // parts with a cursor of its own; the padded batch is flat here (rows are
 // the caller's notion): plane p is elements [p * stride, (p + 1) * stride),
 // zero past the last part. Thread-safe; ctypes releases the GIL for the call.
-void assemble_batch(int64_t num_inputs, const int32_t* bits,
-                    const int64_t* inner, const int64_t* vocab,
-                    const void* const* ptrs, const uint8_t* kinds,
-                    const int64_t* ns, int64_t num_parts, int64_t bucket,
-                    uint32_t* out) {
+// Returns the nanoseconds the pass took, first line to last, on a clock that
+// asks nothing of the interpreter: what the caller's own clock reads around
+// the call beyond this is ctypes and the wait to take the GIL back.
+int64_t assemble_batch(int64_t num_inputs, const int32_t* bits,
+                       const int64_t* inner, const int64_t* vocab,
+                       const void* const* ptrs, const uint8_t* kinds,
+                       const int64_t* ns, int64_t num_parts, int64_t bucket,
+                       uint32_t* out) {
+  const auto started = std::chrono::steady_clock::now();
   for (int64_t k = 0; k < num_inputs; ++k) {
     const void* const* kp = ptrs + k * num_parts;
     const uint8_t* kk = kinds + k * num_parts;
@@ -477,6 +482,9 @@ void assemble_batch(int64_t num_inputs, const int32_t* bits,
                                vocab[k], out);
     }
   }
+  return std::chrono::duration_cast<std::chrono::nanoseconds>(
+             std::chrono::steady_clock::now() - started)
+      .count();
 }
 
 }  // extern "C"

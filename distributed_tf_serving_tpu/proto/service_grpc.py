@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import grpc
 
+from ..utils.tracing import request_trace
 from . import serving_apis_pb2 as apis
 
 SERVICE_NAME = "tensorflow.serving.PredictionService"
@@ -135,6 +136,21 @@ class PredictionServiceServicer:
         context.abort(grpc.StatusCode.UNIMPLEMENTED, "PredictStream not implemented")
 
 
+# `rpc.parse` and `rpc.serialize`: protobuf's own two passes over a Predict,
+# which grpc runs OUTSIDE the handler: the parse of the request's bytes on
+# the listener's poller thread (sync server: `_server._receive_message`; one
+# thread for all of a listener's connections) and the serialization of the
+# response on the handler's thread after it returned. Each synchronous on
+# one thread, so both also show in an open profiler capture
+# (tracing._ON_PROFILER).
+def _spanned(phase: str, fn):
+    def timed(message):
+        with request_trace.span(phase):
+            return fn(message)
+
+    return timed
+
+
 def add_PredictionServiceServicer_to_server(servicer, server) -> None:
     handlers = {
         name: grpc.unary_unary_rpc_method_handler(
@@ -144,6 +160,11 @@ def add_PredictionServiceServicer_to_server(servicer, server) -> None:
         )
         for name, (req_cls, resp_cls) in _METHODS.items()
     }
+    handlers["Predict"] = grpc.unary_unary_rpc_method_handler(
+        servicer.Predict,
+        request_deserializer=_spanned("rpc.parse", apis.PredictRequest.FromString),
+        response_serializer=_spanned("rpc.serialize", apis.PredictResponse.SerializeToString),
+    )
     # The one non-unary method rides a unary_stream handler; both the
     # threaded server (a plain generator servicer method) and grpc.aio
     # (an async generator) accept this registration shape.

@@ -2145,7 +2145,8 @@ class DynamicBatcher:
         / upload / jit call. `batch.cache` covers digest (while the cache
         probes), assembly and upload in EVERY batch, as the generic path's
         does, with `batch.fusedpack` inside it around the native call
-        alone; `batch.jitcall` follows."""
+        alone and `batch.fusedpack_native` the pass's own time by its own
+        clock; `batch.jitcall` follows."""
         from .. import native
 
         servable, fn, layout = ctx["servable"], ctx["fn"], ctx["layout"]
@@ -2153,7 +2154,12 @@ class DynamicBatcher:
 
         def build():
             with request_trace.span("batch.fusedpack"):
-                return native.assemble_batch(layout, parts, fold)
+                buf, native_ns = native.assemble_batch(layout, parts, fold)
+            # The pass by its own clock inside the span above: the rest of
+            # the span is the wrapper, ctypes and this thread's wait to take
+            # the interpreter lock back, once a batch. Aggregate only.
+            request_trace.add_many((("batch.fusedpack_native", native_ns * 1e-9, 1),))
+            return buf
 
         cache = self.input_cache
         with request_trace.span("batch.cache"):

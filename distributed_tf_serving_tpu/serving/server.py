@@ -196,6 +196,68 @@ def _send_peer_role(context) -> None:
         pass
 
 
+class _RpcStamps:
+    """One RPC's clock reads (time.perf_counter), each taken where the work
+    happens, from the pool's `submit` to the call's termination:
+
+      t_submit   poller thread: `_server.py` hands the RPC to the pool
+      t_taken    pool thread: the pool's callable starts, before it asks the
+                 call for the request's message
+      t_handler  pool thread: `_call`'s first line; between t_taken and here
+                 the poller thread read and parsed the message (`rpc.parse`)
+                 and woke this thread
+      t_return   pool thread: `_call`'s `finally`; the handler's own time,
+                 t_return - t_handler, is what `rpc.listener<i>` holds
+      (t_done)   poller thread: `done`, once the status has gone out
+
+    `done` records the four phases that, with the handler's time, tile the
+    RPC as this process sees it: `rpc.server` = `rpc.pool_wait` +
+    `rpc.request_wait` + handler + `rpc.reply`, exactly. Differences of
+    stamps of two threads: aggregate only, not on the profiler's clock."""
+
+    __slots__ = ("t_submit", "t_taken", "t_handler", "t_return")
+
+    def done(self) -> None:
+        t_done = time.perf_counter()
+        request_trace.add_many((
+            ("rpc.pool_wait", self.t_taken - self.t_submit, 1),
+            ("rpc.request_wait", self.t_handler - self.t_taken, 1),
+            ("rpc.reply", t_done - self.t_return, 1),
+            ("rpc.server", t_done - self.t_submit, 1),
+        ))
+
+
+class _TakenRpc(threading.local):
+    """The stamps of the RPC this pool thread is running, for its handler."""
+
+    stamps: _RpcStamps | None = None
+
+
+_TAKEN = _TakenRpc()
+
+
+class _StampedPool(futures.ThreadPoolExecutor):
+    """The handler pool of create_server. grpc's `_server.py` calls nothing
+    of its pool but `submit`, once an RPC and on the listener's poller
+    thread: that is where an RPC's record starts. Every RPC passes through
+    (health checks and ModelService calls too); only a handler that asks
+    for the record (`GrpcPredictionService.Predict`) has it recorded."""
+
+    def submit(self, fn, /, *args, **kwargs):
+        stamps = _RpcStamps()
+        stamps.t_submit = time.perf_counter()
+
+        def taken():
+            stamps.t_taken = time.perf_counter()
+            _TAKEN.stamps = stamps
+            try:
+                return fn(*args, **kwargs)
+            finally:
+                _TAKEN.stamps = None
+
+        return super().submit(taken)
+
+
 class _SyncServicerBase:
     """Shared adapter plumbing for sync servicers: ServiceError -> grpc
     status mapping + per-RPC metrics (+ the per-request server root span
@@ -214,12 +276,15 @@ class _SyncServicerBase:
         # is counted, with its handler's time, under that listener's phase.
         self._listener_phase = f"{LISTENER_PHASE}{listener}"
 
-    def _observe(self, name: str, t0: float, ok: bool, model) -> None:
-        seconds = time.perf_counter() - t0
-        self.metrics.observe(name, seconds, ok, model=model)
-        request_trace.add_many(((self._listener_phase, seconds, 1),))
+    def _observe(self, name: str, t0: float, ok: bool, model) -> float:
+        """Count the RPC and its handler's time; returns the clock read that
+        ended it."""
+        t1 = time.perf_counter()
+        self.metrics.observe(name, t1 - t0, ok, model=model)
+        request_trace.add_many(((self._listener_phase, t1 - t0, 1),))
+        return t1
 
-    def _call(self, name: str, fn, request, context):
+    def _call(self, name: str, fn, request, context, stamps: _RpcStamps | None = None):
         t0 = time.perf_counter()
         ok = False
         model = _model_of(request)
@@ -263,7 +328,14 @@ class _SyncServicerBase:
             log.exception("internal error serving %s", name)
             context.abort(grpc.StatusCode.INTERNAL, f"internal error: {e}")
         finally:
-            self._observe(name, t0, ok, model)
+            t1 = self._observe(name, t0, ok, model)
+            if stamps is not None:
+                stamps.t_handler, stamps.t_return = t0, t1
+                # grpc runs the callback on the poller thread once the
+                # status has gone out. A call that has ended already (the
+                # client cancelled under the handler) takes none: it ends here.
+                if not context.add_callback(stamps.done):
+                    stamps.done()
 
     def _call_stream(self, name: str, fn, request, context):
         """_call for server-streaming RPCs: `fn(request)` returns a chunk
@@ -336,7 +408,7 @@ class GrpcPredictionService(_SyncServicerBase):
                 _stamp_response_crc(self.impl, context, resp)
             return resp
 
-        return self._call("Predict", handler, request, context)
+        return self._call("Predict", handler, request, context, _TAKEN.stamps)
 
     def Classify(self, request, context):
         deadline_s = _deadline_of(context)
@@ -676,7 +748,7 @@ def create_server(
             "would bypass the TLS/mTLS the TCP port enforces"
         )
     metrics = metrics or ServerMetrics()
-    pool = futures.ThreadPoolExecutor(max_workers=max_workers, thread_name_prefix="rpc")
+    pool = _StampedPool(max_workers=max_workers, thread_name_prefix="rpc")
     options = list(LARGE_MESSAGE_CHANNEL_OPTIONS) + list(KEEPALIVE_SERVER_OPTIONS)
 
     def build(index: int) -> grpc.Server:

@@ -19,7 +19,8 @@ ate the budget. This module adds the per-request plane:
   slowest-N are always kept, everything else is sampled. `/tracez`
   (serving/rest.py) serves its contents as JSON; `chrome_trace()` exports
   Chrome-trace-event JSON that Perfetto / chrome://tracing load directly
-  (tools/soak.py writes it to disk).
+  (`/tracez?format=chrome` and tools/soak.py's SOAK_TRACE_OUT fetch it over
+  HTTP and write the file themselves).
 - **collect_phases**: a thread-local sink that lets the batcher's existing
   PhaseTrace call sites double as per-request span producers — one pair of
   clock reads feeds both the aggregate and the span tree.
@@ -37,12 +38,17 @@ clock: once a server has bound jax's TraceAnnotation (`bind_annotation`,
 called by serving.server.build_stack; this module imports no jax, the
 jax-free client imports it), the phases of the batcher's own threads
 (`wait.*`, `batch.*`, `readback.*`, `cache.*`: all synchronous, none spans
-an `await`) are also written into an open jax.profiler capture under their
-own names, so a device idle gap can be read against what the host was doing.
-`predict.*`, `cascade.*` and `req.*` stay on perf_counter alone: the first
-two wrap an `await` in the coroutine servers, where an annotation would
-mis-nest on the event-loop thread, and `req.*` are differences of stamps
-taken on several threads.
+an `await`) and the transport's two protobuf passes (`rpc.parse` on a
+listener's poller thread, `rpc.serialize` on the handler's pool thread;
+proto/service_grpc.py) are also written into an open jax.profiler capture
+under their own names, so a device idle gap can be read against what the
+host was doing. `predict.*`, `cascade.*`, `req.*` and the other `rpc.*`
+phases stay on perf_counter alone: the first two wrap an `await` in the
+coroutine servers, where an annotation would mis-nest on the event-loop
+thread, and `req.*`, `rpc.pool_wait`, `rpc.request_wait`, `rpc.reply`,
+`rpc.server` and `rpc.listener<i>` are differences of stamps, most taken on
+two threads, that reach the trace through `add_many`, which annotates
+nothing.
 """
 
 from __future__ import annotations
@@ -51,7 +57,6 @@ import contextlib
 import contextvars
 import heapq
 import itertools
-import json
 import os
 import random
 import threading
@@ -70,7 +75,7 @@ _ENABLED = False  # per-request tracing; flipped by enable()/disable()
 # made only inside a capture: made for every span, capture or not, it
 # cost dcn_v2_ref43-rank 1.8% of its p50 (PERF.md section 6, PR 24).
 _ANNOTATION = None
-_ON_PROFILER = ("wait.", "batch.", "readback.", "cache.")
+_ON_PROFILER = ("wait.", "batch.", "readback.", "cache.", "rpc.")
 
 
 def bind_annotation(annotation_cls) -> None:
@@ -781,13 +786,6 @@ class TraceRecorder:
                 "producer": "distributed_tf_serving_tpu",
             },
         }
-
-    def write_chrome_trace(self, path: str) -> int:
-        """Serialize chrome_trace() to `path`; returns the event count."""
-        doc = self.chrome_trace()
-        with open(path, "w") as f:
-            json.dump(doc, f)
-        return len(doc["traceEvents"])
 
 
 # Process-global recorder (the /tracez surface); enable() swaps config.

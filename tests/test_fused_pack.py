@@ -68,7 +68,7 @@ def _reference(parts, bucket, vocab, spec):
 
 def _assemble(parts, bucket, vocab, spec):
     want, layout = _reference(parts, bucket, vocab, spec)
-    got = native.assemble_batch(
+    got, _ns = native.assemble_batch(
         layout, {k: [p[k] for p in parts] for k in parts[0]},
         {"feat_ids": vocab},
     )
@@ -166,7 +166,7 @@ def test_assembler_refuses_what_it_cannot_read():
         native.assemble_batch(
             (4, (("feat_ids", 32, (5,), "int32"),)), parts, {"feat_ids": 7}
         )
-    got = native.assemble_batch(layout, parts, {"feat_ids": 7})
+    got, _ns = native.assemble_batch(layout, parts, {"feat_ids": 7})
     np.testing.assert_array_equal(got, np.zeros(16, np.uint32))
 
 

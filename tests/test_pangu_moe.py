@@ -540,7 +540,7 @@ def test_u24_ids_of_the_sliced_vocabulary_assemble_bit_for_bit(published, sizes,
         at += n
     layout = combined_layout(padded, spec)
     assert "feat_ids int32/24b" in describe_layout(layout) and "feat_wts float32/32b" in describe_layout(layout)
-    got = native.assemble_batch(
+    got, _ns = native.assemble_batch(
         layout, {k: [p[k] for p in parts] for k in padded}, {"feat_ids": published.vocab_size})
     np.testing.assert_array_equal(got, pack_host_combined(padded, spec))
 

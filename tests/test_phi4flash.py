@@ -363,6 +363,6 @@ def test_u24_ids_and_float32_weights_of_1024_token_rows_assemble_bit_for_bit(siz
         at += n
     layout = combined_layout(padded, spec)
     assert "feat_ids int32/24b" in describe_layout(layout) and "feat_wts float32/32b" in describe_layout(layout)
-    got = native.assemble_batch(
+    got, _ns = native.assemble_batch(
         layout, {k: [p[k] for p in parts] for k in padded}, {"feat_ids": config.vocab_size})
     np.testing.assert_array_equal(got, pack_host_combined(padded, spec))

@@ -425,6 +425,18 @@ def test_cli_server_reports_startup_stamps_and_raw_counters(tmp_path):
         # The one Predict is counted under the listener whose connection carried it.
         counted = {name: p["count"] for name, p in phases.items() if name.startswith("rpc.listener")}
         assert sum(counted.values()) == 1 and set(counted) <= {f"rpc.listener{i}" for i in range(k)}
+        # And once in each phase of the transport around its handler (PR 40);
+        # the four of the call's termination come from the poller thread
+        # after the client has its answer.
+        transport = ("rpc.pool_wait", "rpc.request_wait", "rpc.parse",
+                     "rpc.serialize", "rpc.reply", "rpc.server")
+        deadline = time.monotonic() + 10
+        while "rpc.server" not in phases and time.monotonic() < deadline:
+            time.sleep(0.05)
+            phases = _monitoring(rest_port, "phases")
+        assert {name: phases[name]["count"] for name in transport} == dict.fromkeys(transport, 1)
+        assert phases["batch.fusedpack_native"]["count"] == phases["batch.fusedpack"]["count"] == 1
+        assert phases["batch.fusedpack_native"]["total_ms"] <= phases["batch.fusedpack"]["total_ms"]
     finally:
         proc.send_signal(signal.SIGTERM)
         try:
