@@ -13,7 +13,9 @@ TPU-first design:
   (servable, signature) are concatenated along the candidate axis into one
   device call, then split back — amortizing dispatch overhead exactly like
   TF-Serving's BatchingSession. At low load a request waits at most
-  `max_wait_us` before dispatch; under sustained load the window is
+  `max_wait_us` before dispatch (the longest a batch stays open for
+  company, not a wait every request pays: a request that would be alone
+  anyway crosses on its own handler thread, see below); under sustained load the window is
   *pipeline-aware*: while >= `pipeline_depth` batches are already in
   flight, dispatching another partial batch would only queue behind device
   work, so the batcher keeps filling past the deadline for free — latency
@@ -39,7 +41,12 @@ asyncio server (await wrap_future). Device work is serialized: in pipelined
 mode (default) the batching thread collects+pads while ONE dispatch thread
 runs the device stage (cache/pack/upload/jit-call) — batch k+1's H2D upload
 starts while batch k executes — and with pipelining off both stages share
-the batching thread exactly as before.
+the batching thread exactly as before. One more thread may run that same
+code, still one group at a time: a sync handler thread that submits a
+request onto an empty queue, below the load at which batches share
+anything, closes the batch and runs its stage inside submit()
+(`_crosses_direct_locked`, `_cross_direct`) instead of waking the batching
+thread to wait out a window nobody joins and the dispatch thread after it.
 """
 
 from __future__ import annotations
@@ -657,9 +664,14 @@ class _WorkItem:
     # score vector instead of full outputs. Prune submits are forced
     # solo — the survivor indices address the request's own rows.
     prune_k: int = 0
-    # When the collector closed the group this item rides (left its
-    # coalesce loop): the end of `req.queue`, the start of `req.assemble`.
+    # When the group this item rides was closed (the collector left its
+    # coalesce loop, or submit kept the item for a direct crossing): the end
+    # of `req.queue`, the start of `req.assemble`.
     closed_t: float | None = None
+    # A direct crossing's hold on `_dispatch_pending`: True from submit's
+    # decision until the count is given back (_run_stage, where a staged
+    # group's is; else _cross_direct on its way out). Under `_cv`.
+    direct: bool = False
 
 
 def _replay_group_phases(group: list["_WorkItem"], phases: list) -> None:
@@ -764,6 +776,10 @@ class BatcherStats:
     # Batches that ran an entry whose embedding gather is the Pallas kernel
     # (models/embeddings.py gather_choice; `startup.gather` names it).
     gather_kernel_batches: int = 0
+    # Batches of one request that its own handler thread closed and staged
+    # (submit's direct crossing): no collector, no coalesce window, no
+    # dispatch thread. The phase `batch.direct` counts the same.
+    direct_batches: int = 0
     # Batches whose outputs rode the top-k compaction (only k (score, idx)
     # pairs crossed the D2H link instead of the full score vector).
     topk_batches: int = 0
@@ -1039,6 +1055,13 @@ class DynamicBatcher:
         self._last_arrival_t: float | None = None
         self._arrival_gap_s: float | None = None
         self._traversal_s: float | None = None
+        # The direct crossing (_crosses_direct_locked): whether the collector
+        # is parked in `wait.queue_empty`, and the item a handler thread is
+        # crossing with right now (None: nobody is). The collector starts no
+        # _dispatch while one is, so _dispatch and _run_stage never run for
+        # two groups at once because of it.
+        self._collector_parked = False
+        self._direct_item: _WorkItem | None = None
         self._staged_candidates = 0
         self._staged_groups: dict[int, tuple[list, int]] = {}
         self._staged_seq = 0
@@ -1099,6 +1122,9 @@ class DynamicBatcher:
     def start(self) -> "DynamicBatcher":
         if not self._started:
             self._started = True
+            # On /monitoring from the start, at count 0: a server that
+            # crosses nothing direct reads 0, one that cannot reads nothing.
+            request_trace.add_many((("batch.direct", 0.0, 0),))
             self._thread.start()
             # Compile/load the native host ops off-thread so the first
             # request never pays the g++ latency (numpy fallback until ready).
@@ -1148,6 +1174,12 @@ class DynamicBatcher:
             with self._cv:
                 self._stopping = True
                 self._cv.notify_all()
+                # A direct crossing under way is a handler thread's: its
+                # stage ends as the dispatch thread's do below, before the
+                # completers go.
+                give_up = time.perf_counter() + 5
+                while self._direct_item is not None and time.perf_counter() < give_up:
+                    self._cv.wait(0.05)
             self._thread.join(timeout=5)
             if self._dispatcher is not None:
                 # Every staged group still executes (accepted work is
@@ -1197,6 +1229,7 @@ class DynamicBatcher:
         _warmup: bool = False,
         _solo: bool = False,
         _prune_k: int = 0,
+        _may_block: bool = False,
     ) -> Future:
         """Enqueue one request's arrays; returns a Future of output arrays
         (sliced back to the request's own candidate count). output_keys limits
@@ -1223,7 +1256,14 @@ class DynamicBatcher:
         (survivor indices address the request's own rows), and the score-
         cache key is salted with the mode+k so a prune result can never be
         served to a full-vector request for the same features (or vice
-        versa)."""
+        versa).
+
+        _may_block: the caller is a thread that is about to sleep on the
+        Future anyway (service._run's handler thread, and nobody else: never
+        an event loop). Such a caller crosses the batcher ITSELF when the
+        request would be alone in its batch (_crosses_direct_locked): it
+        closes the batch and runs its stage inside this call, and the Future
+        it gets back is resolved by a completer as ever."""
         if _prune_k:
             _solo = True
         if self._stopping:
@@ -1282,7 +1322,7 @@ class DynamicBatcher:
         try:
             return self._submit_miss(
                 servable, arrays, n, output_keys, deadline_s, span, _warmup,
-                handle, cache, criticality, _solo, _prune_k,
+                handle, cache, criticality, _solo, _prune_k, _may_block,
             )
         except BaseException as exc:
             if handle is not None and handle.leader:
@@ -1295,6 +1335,7 @@ class DynamicBatcher:
     def _submit_miss(
         self, servable, arrays, n, output_keys, deadline_s, span, _warmup,
         handle, cache=None, criticality=None, solo=False, prune_k=0,
+        may_block=False,
     ) -> Future:
         """The no-cache-hit tail of submit(): admission, prepare, enqueue
         (exactly the pre-cache-plane submit body). The cache handle, when
@@ -1384,15 +1425,32 @@ class DynamicBatcher:
                 self._queued_candidates -= n
             raise
         with self._cv:
-            self._items.append(item)
-            self.stats.max_queue_depth = max(self.stats.max_queue_depth, len(self._items))
             if not _warmup:
                 if self._last_arrival_t is not None:
                     self._arrival_gap_s = _smoothed(
                         self._arrival_gap_s, now - self._last_arrival_t
                     )
                 self._last_arrival_t = now
-            self._cv.notify()
+            direct = False
+            if may_block and self._crosses_direct_locked(item):
+                # Out of the queue as _take takes an item, and shed as _take
+                # sheds one (the Future then carries the deadline's error).
+                self._queued_candidates -= n
+                if not self._drop_stale_locked(item):
+                    # The count a staged group holds: a request that arrives
+                    # during this stage sees a stage running, queues and is
+                    # held for (_holds_open), and no second crossing starts
+                    # beside it.
+                    direct = item.direct = True
+                    self._direct_item = item
+                    self._dispatch_pending += 1
+                    item.closed_t = time.perf_counter()
+            else:
+                self._items.append(item)
+                self.stats.max_queue_depth = max(
+                    self.stats.max_queue_depth, len(self._items)
+                )
+                self._cv.notify()
         if handle is not None and handle.leader:
             # Fill + waiter fan-out ride the future's completion (success,
             # failure, or cancellation), on whichever thread resolves it.
@@ -1408,7 +1466,67 @@ class DynamicBatcher:
                 ok=output_keys, pk=prune_k:
                 self._cache_complete(c, h, f, sv, a, ok, pk)
             )
+        if direct:
+            self._cross_direct(item)
         return fut
+
+    def _crosses_direct_locked(self, item: _WorkItem) -> bool:
+        """Whether the thread that submits `item`, and may block, crosses
+        the batcher itself: closes a batch of this one request and runs its
+        stage, with no hand-over to the collector, no coalesce window and no
+        hand-over to the dispatch thread. The caller holds `_cv`.
+
+        When the request would be alone in its batch anyway, by what the
+        batcher already keeps: an ordinary item (not solo, not a warm-up,
+        no bisection half); nobody ahead of it and no batch open that it
+        could join (the queue empty, the collector parked in
+        `wait.queue_empty`); no stage staged or running and the pipeline
+        not full (a batch on the device is no obstacle, a stage on the
+        dispatch thread is: _holds_open holds a batch for what arrives
+        during one); and requests arriving slower than batches cross the
+        pipeline, by the two averages _holds_open weighs and on the other
+        side of its threshold (Little's law: on average nobody joins).
+        While either average is unknown everything queues."""
+        if item.solo or item.warmup or item.bisect_key is not None:
+            return False
+        if self._items or not self._collector_parked or self._stopping:
+            return False
+        if (
+            self._dispatch_pending
+            or self._direct_item is not None
+            or len(self._inflight) >= self.pipeline_depth
+        ):
+            return False
+        gap, crossing = self._arrival_gap_s, self._traversal_s
+        return gap is not None and crossing is not None and gap > crossing
+
+    def _cross_direct(self, item: _WorkItem) -> None:
+        """The direct crossing itself, on the submitting thread: the same
+        _dispatch the collector calls, its stage run inline. _run_stage
+        gives the pending count back where it does a staged group's; what
+        ends before a stage (a failed assembly, a batch answered from the
+        row cache) gives it back here, where the collector is let go too.
+
+        _dispatch and _run_stage fail their group on an Exception
+        themselves. What escapes them is what _guard_worker_future catches
+        on a pool's thread; this thread is the caller's, so no thread of the
+        batcher has died and there is no verdict: the request fails, alone."""
+        try:
+            self._dispatch([item], item.n)
+        except BaseException as exc:  # noqa: BLE001 — the waiter must resolve
+            if not item.future.done():
+                try:
+                    item.future.set_exception(exc)
+                except InvalidStateError:
+                    pass
+        finally:
+            with self._cv:
+                if item.direct:
+                    item.direct = False
+                    self._dispatch_pending = max(self._dispatch_pending - 1, 0)
+                if self._direct_item is item:
+                    self._direct_item = None
+                self._cv.notify_all()
 
     def _cache_complete(
         self, cache, handle, fut: Future, servable, arrays, output_keys,
@@ -1733,6 +1851,11 @@ class DynamicBatcher:
             self._inflight_buckets.clear()
             self._dispatching_since = None
             self._dispatch_pending = 0
+            if self._direct_item is not None:
+                # A crossing stranded in a wedged device call: its thread
+                # finds its count and its hold on the collector gone.
+                self._direct_item.direct = False
+                self._direct_item = None
             self._cv.notify_all()
         rc = self.row_cache
         if rc is not None:
@@ -2371,10 +2494,17 @@ class DynamicBatcher:
                     return it
                 if self._stopping:
                     return None
-                # No work arrived: the transport/client-bound share.
-                self._wait(
-                    "queue_empty", until=lambda: self._items or self._stopping
-                )
+                # No work arrived: the transport/client-bound share. Parked
+                # here, the collector has no batch open: what a direct
+                # crossing asks (_crosses_direct_locked), and sleeps through.
+                self._collector_parked = True
+                try:
+                    self._wait(
+                        "queue_empty",
+                        until=lambda: self._items or self._stopping,
+                    )
+                finally:
+                    self._collector_parked = False
 
     # The utilization ledger's gap cause for a wait (its names predate the
     # `wait.*` phases; /utilz keeps them). `window` is the dispatch
@@ -2428,7 +2558,9 @@ class DynamicBatcher:
         batches cross the pipeline (Little's law: on average one more
         joins before the pipeline drains), which is when a batch's fixed
         host cost is worth sharing. Below that rate a lone request
-        dispatches at once and overlaps the batch in flight."""
+        dispatches at once and overlaps the batch in flight (on its own
+        handler thread where that may block: _crosses_direct_locked reads
+        the same two averages from the other side)."""
         if busy >= self.pipeline_depth:
             return True
         if not native or not busy:
@@ -2549,13 +2681,31 @@ class DynamicBatcher:
             closed_t = time.perf_counter()
             for it in group:
                 it.closed_t = closed_t
+            with self._cv:
+                if self._direct_item is not None:
+                    # A handler thread is crossing direct. _holds_open held
+                    # this batch for its stage where a batch is held; a solo
+                    # item, a full batch and a generic-path one come here
+                    # early, and wait the rest of the crossing out: _dispatch
+                    # and _run_stage run for one group at a time.
+                    self._wait(
+                        "pipeline", 0.005,
+                        until=lambda: self._direct_item is None,
+                    )
             self._dispatch(group, total)
 
     def _dispatch(self, group: list[_WorkItem], total: int) -> None:
         """Host-side batch assembly (batcher thread), then the device stage
         — handed to the dispatch thread in pipelined mode so this thread
         returns to collecting+padding batch k+1 while batch k's
-        pack/upload/jit-call proceeds (and batch k-1 executes on device)."""
+        pack/upload/jit-call proceeds (and batch k-1 executes on device).
+
+        A direct crossing (_cross_direct) calls this on a handler thread
+        with its one item and runs the stage inline. It starts only while
+        the collector is parked and nothing is staged, and the collector
+        starts no _dispatch until it is over (_loop_inner), so this runs for
+        one group at a time, as it always has; beside it may run the tail
+        of the stage before, as beside the collector's ever."""
         # Per-request tracing: one phase sink per batch — request_trace's
         # existing call sites (batch.pad here; cache/pack/jitcall/readback
         # on the stage threads) land in it once and are replayed onto
@@ -2773,7 +2923,8 @@ class DynamicBatcher:
                 if not it.future.done():
                     it.future.set_exception(exc)
             return
-        if self._dispatcher is None:
+        if self._dispatcher is None or first.direct:
+            # No dispatch thread, or a direct crossing, which is its own.
             self._run_stage(
                 None, group, total, bucket, wanted, wanted_key,
                 topk, n_valid, fused, batched, phases, scatter, ring_bufs,
@@ -3050,11 +3201,17 @@ class DynamicBatcher:
         """Device stage for one assembled batch: execute, issue the async
         D2H readback, register in flight, hand off to a completer. Runs on
         the dispatch thread (pipelined mode) or inline on the batcher
-        thread (sid None from the fallback path). `phases` is the batch's
+        thread (sid None from the fallback path), or on a handler thread for
+        a direct crossing (sid None, the item `direct`: it holds one of
+        `_dispatch_pending`, given back here as a staged group's is).
+        `phases` is the batch's
         tracing sink (started in _dispatch with the pad phase); the device-
         stage phases and fault annotations land in it here and are
         replayed onto every member request's span."""
-        pending_closed = sid is None
+        pending_closed = sid is None and not group[0].direct
+        # Whether this batch is in flight: until then the wedge clock's
+        # `_dispatching_*` are this stage's to clear, after it the next's.
+        registered = False
         util = None  # assigned once the batch passes the early-out checks
         util_handed_off = False
         # Elastic run_fn completion protocol (parallel/elastic.py): the
@@ -3201,6 +3358,9 @@ class DynamicBatcher:
                     # A phase by count, beside `batch.dispatch`'s.
                     self.stats.gather_kernel_batches += 1
                     request_trace.add_many((("batch.gather_kernel", 0.0, 1),))
+                if group[0].direct:
+                    self.stats.direct_batches += 1
+                    request_trace.add_many((("batch.direct", 0.0, 1),))
             if run_fn_cap is not None and getattr(run_fn_cap, "elastic", False):
                 # Same thread, synchronous: the token names the split the
                 # dispatch above routed to. It travels to the completer
@@ -3362,12 +3522,14 @@ class DynamicBatcher:
                 # long-finished dispatch as a wedged device.
                 self._dispatching_since = None
                 self._dispatching_group = None
+                registered = True
                 if not pending_closed:
                     # Clamped at zero: a quarantine capture resets the
                     # pending count while abandoned stage calls may still
                     # be queued behind a wedged worker — their eventual
                     # decrements must not drive it negative.
                     self._dispatch_pending = max(self._dispatch_pending - 1, 0)
+                    group[0].direct = False
                     pending_closed = True
                 self._cv.notify_all()
             if phases is not None:
@@ -3425,10 +3587,16 @@ class DynamicBatcher:
                 except Exception:  # noqa: BLE001 — accounting, never fatal
                     pass
             with self._cv:
-                self._dispatching_since = None
-                self._dispatching_group = None
+                if not registered:
+                    # Once in flight, the pending count is back and the next
+                    # stage may have begun on another thread (a direct
+                    # crossing, or the dispatch thread after one): the clock
+                    # would be that stage's.
+                    self._dispatching_since = None
+                    self._dispatching_group = None
                 if not pending_closed:
                     self._dispatch_pending = max(self._dispatch_pending - 1, 0)
+                    group[0].direct = False
                 self._cv.notify_all()
 
     def _complete(

@@ -794,11 +794,14 @@ class PredictionServiceImpl:
         try:
             # The current span (the transport adapter's server root, when
             # tracing is on) rides into the batcher so its threads can
-            # attach queue/device/readback child spans per request.
+            # attach queue/device/readback child spans per request. This
+            # thread sleeps on the Future next, so it may as well cross the
+            # batcher itself where the request is alone (_may_block; never
+            # from _run_async, whose thread is every RPC's).
             fut = self.batcher.submit(
                 servable, arrays, output_keys=output_keys,
                 deadline_s=deadline_s, span=tracing.current_span(),
-                criticality=criticality, _prune_k=prune_k,
+                criticality=criticality, _prune_k=prune_k, _may_block=True,
             )
             out = fut.result(timeout=timeout)
             self._note_resumed(fut)

@@ -170,8 +170,9 @@ class PhaseTrace:
                 phase: {
                     "total_ms": round(self._totals[phase] * 1e3, 3),
                     "count": self._counts[phase],
+                    # A phase by count may stand at 0 (add_many).
                     "mean_us": round(
-                        self._totals[phase] / self._counts[phase] * 1e6, 1
+                        self._totals[phase] / max(self._counts[phase], 1) * 1e6, 1
                     ),
                 }
                 for phase in sorted(self._totals)

@@ -866,3 +866,330 @@ def test_native_batch_stays_open_while_the_dispatch_thread_is_in_a_stage(monkeyp
     finally:
         leave.set()
         batcher.stop()
+
+
+# ------------------------------------------------- the direct crossing (ISSUE 42)
+
+
+def _below_the_threshold(batcher, parked=True):
+    """A started batcher whose collector has parked, with the two load
+    averages as a trickle leaves them: arrivals a second apart, a crossing
+    of a millisecond."""
+    deadline = time.perf_counter() + 10
+    while parked and not batcher._collector_parked and time.perf_counter() < deadline:
+        time.sleep(0.002)
+    with batcher._cv:
+        assert batcher._collector_parked == parked
+        batcher._arrival_gap_s, batcher._traversal_s = 1.0, 0.001
+        batcher._last_arrival_t = None
+    return batcher
+
+
+def _stage_threads(batcher, monkeypatch):
+    """The names of the threads that ran a device stage, in order."""
+    names = []
+    real = batcher._run_stage
+
+    def run_stage(*args, **kwargs):
+        names.append(threading.current_thread().name)
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(batcher, "_run_stage", run_stage)
+    return names
+
+
+def _direct_servable(path):
+    from distributed_tf_serving_tpu import native
+
+    if not native.ensure():
+        pytest.skip("native hostops unavailable")
+    return _native_servable("dlrm_mlperf", **({"needs_x64": True} if path == "generic" else {}))
+
+
+@pytest.mark.parametrize("path", ["native", "generic"])
+def test_a_lone_blocking_request_crosses_direct(monkeypatch, path):
+    """Below the threshold, the pipeline empty: the submitting thread closes
+    the batch and runs its stage itself. The dispatch thread never runs, the
+    collector never wakes, and the scores are the queued path's to the bit."""
+    from distributed_tf_serving_tpu.utils.tracing import request_trace
+
+    sv = _direct_servable(path)
+    batcher = DynamicBatcher(buckets=(16, 64), max_wait_us=200_000)
+    batcher.warmup(sv)
+    batcher.start()
+    threads = _stage_threads(batcher, monkeypatch)
+    payloads = [_native_payload(sv, n, seed=n) for n in (5, 13, 16)]
+    keys = ("prediction_node",)
+    try:
+        want = [batcher.submit(sv, p, output_keys=keys).result(timeout=60) for p in payloads]
+        assert batcher.stats.direct_batches == 0 and batcher.stats.batches == 3
+        assert set(threads) == {"batch-dispatch_0"}
+        del threads[:]
+        _below_the_threshold(batcher)
+        before = request_trace.snapshot()
+        me = threading.current_thread().name
+        for p, w in zip(payloads, want):
+            t0 = time.perf_counter()
+            fut = batcher.submit(sv, p, output_keys=keys, _may_block=True)
+            assert time.perf_counter() - t0 < 0.1  # no coalesce window (0.2 s) was waited
+            np.testing.assert_array_equal(fut.result(timeout=60)["prediction_node"], w["prediction_node"])
+            with batcher._cv:
+                assert batcher._dispatch_pending == 0 and batcher._direct_item is None
+                batcher._last_arrival_t = None  # the trickle, not this loop's pace
+        assert threads == [me] * 3
+        assert batcher.stats.direct_batches == 3 and batcher.stats.batches == 6
+        assert (batcher.stats.fused_batches == 6) == (path == "native")
+        now = request_trace.snapshot()
+        assert now["batch.direct"]["count"] - before["batch.direct"]["count"] == 3
+        assert now["batch.dispatch"]["count"] - before["batch.dispatch"]["count"] == 3
+        # The collector slept through all three.
+        assert now["wait.queue_empty"]["count"] == before["wait.queue_empty"]["count"]
+    finally:
+        batcher.stop()
+
+
+def _bar_state(bar, batcher):
+    if bar == "a stage pending":
+        batcher._dispatch_pending = 1
+    elif bar == "another crossing under way":
+        batcher._direct_item = object()
+    elif bar == "the pipeline full":
+        batcher._inflight.update({-1: 0.0, -2: 0.0})
+    elif bar == "the queue holds an item":
+        batcher._items.append(object())
+    elif bar == "the collector has a batch open":
+        batcher._collector_parked = False
+    elif bar == "gap equals crossing":
+        batcher._arrival_gap_s = batcher._traversal_s
+    elif bar == "gap unknown":
+        batcher._arrival_gap_s = None
+    elif bar == "crossing unknown":
+        batcher._traversal_s = None
+    elif bar == "stopping":
+        batcher._stopping = True
+    else:
+        raise AssertionError(bar)
+
+
+@pytest.mark.parametrize("bar", [
+    "a stage pending", "another crossing under way", "the pipeline full",
+    "the queue holds an item", "the collector has a batch open",
+    "gap equals crossing", "gap unknown", "crossing unknown", "stopping",
+    "a solo item", "a warm-up item", "a bisection half",
+])
+def test_what_keeps_a_request_off_the_direct_crossing(servable, bar):
+    """The rule alone, on the state the batcher keeps: one batch in flight
+    is no obstacle; each of these is."""
+    import dataclasses
+    from concurrent.futures import Future
+
+    from distributed_tf_serving_tpu.serving.batcher import _WorkItem
+
+    batcher = DynamicBatcher(buckets=(16,), pipeline_depth=2)
+    item = _WorkItem(
+        servable=servable, arrays=make_arrays(4), n=4, future=Future(),
+        enqueue_t=time.perf_counter(), output_keys=None,
+    )
+    with batcher._cv:
+        batcher._collector_parked = True
+        batcher._arrival_gap_s, batcher._traversal_s = 0.006, 0.003
+        assert batcher._crosses_direct_locked(item)
+        batcher._inflight[-1] = 0.0  # on the device: the stage is free
+        assert batcher._crosses_direct_locked(item)
+        del batcher._inflight[-1]
+        kind = {"a solo item": {"solo": True}, "a warm-up item": {"warmup": True},
+                "a bisection half": {"bisect_key": 1}}.get(bar)
+        if kind is not None:
+            item = dataclasses.replace(item, **kind)
+        else:
+            _bar_state(bar, batcher)
+        assert not batcher._crosses_direct_locked(item)
+
+
+@pytest.mark.parametrize("case", ["may not block", "solo", "warm-up", "past deadline"])
+def test_submits_that_stay_off_the_direct_crossing(servable, monkeypatch, case):
+    """Through submit(): a caller that did not say it may block (the asyncio
+    transport, a direct user) queues, as a solo and a warm-up item do; a
+    request whose deadline has passed is shed as _take sheds one."""
+    from distributed_tf_serving_tpu.serving.batcher import RequestDeadlineError
+
+    batcher = _below_the_threshold(DynamicBatcher(buckets=(16, 64), max_wait_us=0).start())
+    threads = _stage_threads(batcher, monkeypatch)
+    kwargs = {
+        "may not block": {},
+        "solo": {"_may_block": True, "_solo": True},
+        "warm-up": {"_may_block": True, "_warmup": True},
+        "past deadline": {"_may_block": True, "deadline_s": -0.001},
+    }[case]
+    try:
+        fut = batcher.submit(servable, make_arrays(7), **kwargs)
+        if case == "past deadline":
+            with pytest.raises(RequestDeadlineError):
+                fut.result(timeout=30)
+            assert batcher.stats.deadline_sheds == 1 and batcher.stats.batches == 0
+            with batcher._cv:
+                assert batcher._queued_candidates == 0 and batcher._dispatch_pending == 0
+                assert batcher._direct_item is None
+        else:
+            assert fut.result(timeout=60)["prediction_node"].shape == (7,)
+            assert threads == ["batch-dispatch_0"]
+        assert batcher.stats.direct_batches == 0
+    finally:
+        batcher.stop()
+
+
+@pytest.mark.parametrize("second", ["native", "generic", "solo"])
+def test_what_arrives_during_a_direct_stage_queues_until_it_ends(monkeypatch, second):
+    """One request in its direct stage, held there: the next ones see a
+    stage running and queue. A natively assembled batch stays open for the
+    stage (_holds_open); a generic-path batch and a solo item close at once
+    and wait the crossing out before their _dispatch. Either way no stage
+    starts beside the direct one, and all of them are answered after it."""
+    sv = _direct_servable("generic" if second == "generic" else "native")
+    batcher = DynamicBatcher(buckets=(16, 64), max_wait_us=0, pipeline_depth=8)
+    batcher.warmup(sv)
+    batcher.start()
+    _below_the_threshold(batcher)
+    name = "_execute" if second == "generic" else "_execute_fused"
+    real = getattr(batcher, name)
+    in_stage, leave = threading.Event(), threading.Event()
+    running, beside = [0], []
+
+    def execute(*args, **kwargs):
+        running[0] += 1
+        beside.append(running[0])
+        try:
+            if not in_stage.is_set():  # the direct stage, held open
+                in_stage.set()
+                leave.wait(timeout=30)
+            return real(*args, **kwargs)
+        finally:
+            running[0] -= 1
+
+    monkeypatch.setattr(batcher, name, execute)
+    threads = _stage_threads(batcher, monkeypatch)
+    keys = ("prediction_node",)
+    futures = []
+
+    def first():
+        futures.append(batcher.submit(sv, _native_payload(sv, 4, 0), output_keys=keys, _may_block=True))
+
+    handler = threading.Thread(target=first, name="handler")
+    try:
+        handler.start()
+        assert in_stage.wait(timeout=30)
+        later = []
+        for s in range(1, 4):
+            with batcher._cv:
+                batcher._last_arrival_t = None  # the averages stay a trickle's
+            later.append(batcher.submit(
+                sv, _native_payload(sv, 4, s), output_keys=keys, _may_block=True,
+                _solo=second == "solo",
+            ))
+            time.sleep(0.03)
+        with batcher._cv:
+            assert batcher._dispatch_pending == 1  # the direct stage's, nothing staged
+            assert batcher._direct_item is not None and not batcher._staged_groups
+        assert threads == ["handler"] and batcher.stats.batches == 0
+        assert not any(f.done() for f in later)
+        leave.set()
+        handler.join(timeout=30)
+        for f in futures + later:
+            assert f.result(timeout=60)["prediction_node"].shape == (4,)
+        assert batcher.stats.direct_batches == 1
+        # Held open, the three ride one batch; closed early, the generic
+        # path's first goes alone and a solo item always does.
+        assert batcher.stats.batches == {"native": 2, "generic": 3, "solo": 4}[second]
+        assert set(threads[1:]) == {"batch-dispatch_0"}
+        assert max(beside) == 1
+    finally:
+        leave.set()
+        batcher.stop()
+
+
+class _Escape(BaseException):
+    """What no `except Exception` catches."""
+
+
+@pytest.mark.parametrize("where", ["the stage", "the assembly", "past every except"])
+def test_an_error_in_a_direct_crossing_fails_that_request_alone(servable, monkeypatch, where):
+    """The request gets the error the queued path would give it, the pending
+    count comes back, nothing is reported dead, and the next request, direct
+    again, is answered."""
+    batcher = _below_the_threshold(DynamicBatcher(buckets=(16, 64), max_wait_us=0).start())
+    error = _Escape("boom") if where == "past every except" else RuntimeError("boom")
+    failed = []
+
+    def failing_once(real):
+        def call(*args, **kwargs):
+            if not failed:
+                failed.append(threading.current_thread().name)
+                raise error
+            return real(*args, **kwargs)
+        return call
+
+    # Whichever assembler takes the batch, its stage goes through one of two.
+    for name in ("_fused_ctx",) if where == "the assembly" else ("_execute", "_execute_fused"):
+        monkeypatch.setattr(batcher, name, failing_once(getattr(batcher, name)))
+    try:
+        bad = batcher.submit(servable, make_arrays(7), _may_block=True)
+        with pytest.raises(type(error), match="boom"):
+            bad.result(timeout=30)
+        assert failed == [threading.current_thread().name]
+        with batcher._cv:
+            assert batcher._dispatch_pending == 0 and batcher._direct_item is None
+            assert batcher._dispatching_since is None and not batcher._inflight
+            assert batcher._dead is None
+            batcher._last_arrival_t = None
+        arrays = make_arrays(9, seed=1)
+        got = batcher.submit(servable, arrays, _may_block=True).result(timeout=60)
+        np.testing.assert_allclose(got["prediction_node"], reference_scores(servable, arrays), rtol=1e-6)
+        assert batcher.stats.direct_batches == 1 and batcher.stats.batches == 1
+    finally:
+        batcher.stop()
+
+
+@pytest.mark.parametrize("phases, want", [
+    ({"batch.dispatch": {"count": 200, "total_ms": 300.0},
+      "batch.direct": {"count": 150, "total_ms": 0.0}}, 75.0),
+    # A server that crossed nothing direct (a closed loop) reads 0 ...
+    ({"batch.dispatch": {"count": 200, "total_ms": 300.0},
+      "batch.direct": {"count": 0, "total_ms": 0.0}}, 0.0),
+    # ... the commit before ISSUE 42, which has no such phase, nothing ...
+    ({"batch.dispatch": {"count": 200, "total_ms": 300.0}}, None),
+    # ... and so does a window without a batch.
+    ({"batch.dispatch": {"count": 0, "total_ms": 0.0},
+      "batch.direct": {"count": 0, "total_ms": 0.0}}, None),
+])
+def test_direct_batch_pct_on_a_made_up_window(phases, want):
+    """benchmark/layers/direct_batch_pct.py over a window's phase deltas."""
+    import os
+    import sys
+
+    layers = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark", "layers")
+    sys.path.insert(0, layers)
+    try:
+        from benchmark.common import load_module
+
+        read = load_module(os.path.join(layers, "direct_batch_pct.py"), "bench_layer_direct").read
+    finally:
+        sys.path.remove(layers)
+    got = read({"phases": phases})
+    assert got is None if want is None else got == pytest.approx(want)
+
+
+def test_a_started_batcher_shows_the_direct_phase_at_zero():
+    """`batch.direct` is on /monitoring?section=phases from start(), so a
+    scraper tells a server that crosses nothing direct from one that
+    cannot; a phase that stands at count 0 has a mean of 0."""
+    from distributed_tf_serving_tpu.utils import tracing
+
+    trace = tracing.PhaseTrace()
+    trace.add_many((("batch.direct", 0.0, 0),))
+    assert trace.snapshot()["batch.direct"] == {"total_ms": 0.0, "count": 0, "mean_us": 0.0}
+    batcher = DynamicBatcher(buckets=(16,)).start()
+    try:
+        assert "batch.direct" in tracing.request_trace.snapshot()
+    finally:
+        batcher.stop()

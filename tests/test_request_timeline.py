@@ -137,6 +137,45 @@ def test_req_phases_tile_the_handler_interval(servable, mode):
     assert handler_ms - tiled_ms < 0.03 * handler_ms
 
 
+@pytest.mark.parametrize("stage", ["slow custom run_fn", "the jitted entry"])
+def test_req_phases_tile_a_direct_crossing(servable, stage):
+    """A request that crosses the batcher on its own handler thread (ISSUE
+    42) carries the same six stamps: equal counts, and the totals tile the
+    handler's interval, with `req.queue` and `req.assemble` now too short
+    for a thread to have been woken inside them."""
+    kwargs = {"run_fn": _slow_run(0.004)} if stage == "slow custom run_fn" else {}
+    impl, batcher = _impl(servable, max_wait_us=2000, **kwargs)
+    requests = 8
+    payloads = [_payload(seed=i) for i in range(requests)]
+    seen = []
+    try:
+        impl._run(servable, _payload(seed=99))  # compiles, and parks the collector again
+        deadline = time.monotonic() + 10
+        while not batcher._collector_parked and time.monotonic() < deadline:
+            time.sleep(0.002)
+        before = request_trace.snapshot()
+        for i in range(requests):
+            with batcher._cv:  # a trickle: arrivals far slower than crossings
+                batcher._arrival_gap_s, batcher._traversal_s = 1.0, 0.001
+                batcher._last_arrival_t = None
+            t0 = time.perf_counter()
+            impl._run(servable, payloads[i])
+            seen.append(time.perf_counter() - t0)
+        assert batcher.stats.direct_batches == requests
+    finally:
+        batcher.stop()
+    assert [_delta(before, p, "count") for p in REQ] == [requests] * 6
+    assert _delta(before, "batch.direct", "count") == requests
+    tiled_ms = sum(_delta(before, p, "total_ms") for p in REQ)
+    handler_ms = sum(seen) * 1e3
+    assert tiled_ms <= handler_ms
+    if stage == "slow custom run_fn":  # intervals of 4 ms and more, as above
+        assert handler_ms - tiled_ms < 0.03 * handler_ms
+    # No coalesce window (2 ms here) and no hand-over lie in the first two.
+    assert _delta(before, "req.queue", "total_ms") / requests < 1.0
+    assert _delta(before, "req.assemble", "total_ms") / requests < 1.0
+
+
 def test_warmup_items_and_cache_hits_add_no_req_phase(servable):
     from distributed_tf_serving_tpu.cache import ScoreCache
 
@@ -308,8 +347,14 @@ def test_profiler_capture_holds_the_programs_spans(tmp_path):
     try:
         jax.profiler.start_trace(str(tmp_path), profiler_options=options)
         try:
-            for i in range(4):
-                impl._run(sv, _payload(seed=i))
+            for i in range(6):
+                if i % 2:
+                    impl._run(sv, _payload(seed=i))
+                else:
+                    # Through the queue: a handler thread's direct crossing
+                    # lets the collector sleep on, and a `wait.queue_empty`
+                    # that never ends inside the capture is not in it.
+                    batcher.submit(sv, _payload(seed=i)).result(timeout=60)
                 time.sleep(0.01)
         finally:
             jax.profiler.stop_trace()

@@ -794,6 +794,62 @@ def test_aio_server_prepared_and_plain_paths_match_golden():
     batcher.stop()
 
 
+@pytest.mark.parametrize("transport", ["sync", "aio"])
+def test_only_a_handler_thread_crosses_the_batcher_direct(transport):
+    """Below the load at which batches share anything, a request over the
+    sync transport is staged by its own handler thread, which would sleep on
+    the Future anyway; the asyncio transport's one loop thread never is
+    (service._run_async does not say it may block)."""
+    from distributed_tf_serving_tpu.serving.server import create_server_async
+
+    registry = ServableRegistry()
+    servable = _servable(version=1, seed=0)
+    registry.load(servable)
+    batcher = DynamicBatcher(buckets=(32, 128), max_wait_us=0).start()
+    impl = PredictionServiceImpl(registry, batcher)
+    arrays = _arrays(n=10, seed=22)
+    want = _golden(servable, arrays)
+
+    def trickle():
+        with batcher._cv:  # arrivals far slower than crossings
+            batcher._arrival_gap_s, batcher._traversal_s = 1.0, 0.001
+            batcher._last_arrival_t = None
+
+    async def go():
+        server, port = create_server_async(impl, "127.0.0.1:0")
+        await server.start()
+        try:
+            async with ShardedPredictClient([f"127.0.0.1:{port}"], "DCN") as client:
+                out = []
+                for _ in range(3):
+                    trickle()
+                    out.append(await client.predict(arrays))
+                return out
+        finally:
+            await server.stop(0)
+
+    try:
+        if transport == "aio":
+            got = asyncio.run(go())
+        else:
+            server, port = create_server(impl, "127.0.0.1:0")
+            server.start()
+            try:
+                got = []
+                for _ in range(3):
+                    trickle()
+                    got.append(predict_sync(f"127.0.0.1:{port}", arrays, "DCN")["prediction_node"])
+            finally:
+                server.stop(0)
+        for scores in got:
+            np.testing.assert_allclose(scores, want, rtol=1e-6)
+        assert batcher.stats.batches == 3
+        # The first sync request may find the collector not parked yet.
+        assert batcher.stats.direct_batches == 0 if transport == "aio" else batcher.stats.direct_batches >= 2
+    finally:
+        batcher.stop()
+
+
 def test_aio_server_error_codes():
     """ServiceError mapping must survive the coroutine adapter: unknown model
     -> NOT_FOUND, malformed tensor -> INVALID_ARGUMENT."""
