@@ -95,6 +95,20 @@ class ModelConfig:
     routed_scaling_factor: float = 1.0
     experts_held: int = 0  # 0 => all n_routed_experts
     first_expert_held: int = 0
+    # exaone_moe (models/exaone_moe.py): a row is num_fields token ids as
+    # above, and the keys shared with pangu_moe mean what they mean there
+    # (embed_dim, intermediate_size, first_k_dense_replace, moe_intermediate_size,
+    # num_experts_per_tok, routed_scaling_factor, experts_held, first_expert_held,
+    # rope_theta, layer_norm_eps; sliding_window and num_key_value_heads as
+    # phi4flash's). Under the published config.json's names: the kind of
+    # every layer's attention ("sliding_attention" or "full_attention", one
+    # for each of num_hidden_layers; empty => the published period, three
+    # sliding layers then a full one, repeated), the width of a head (0 =>
+    # embed_dim / num_attention_heads, which the published 128 is not), and
+    # the ROUTER's width, of which experts_held are computed here.
+    layer_types: tuple[str, ...] = ()
+    head_dim: int = 0
+    num_experts: int = 16
     # numerics
     compute_dtype: str = "bfloat16"  # "float32" for AUC-parity mode
     param_dtype: str = "float32"
@@ -152,13 +166,17 @@ class Model:
     # True when the signature carries `dense_features` [n, num_dense_features]
     # beside the id/weight pair (the DLRM families).
     takes_dense: bool = False
-    # The kind of every layer of a sequence family (phi4flash, pangu_moe),
+    # The kind of every layer of a sequence family (phi4flash, pangu_moe, exaone_moe),
     # whose rows are num_fields TOKENS; empty for the CTR families.
     layer_plan: tuple[str, ...] = ()
     # What a family with a routed layer holds of it, as (name, number) pairs:
     # published, held, first, top_k, heads_published, heads_held,
-    # chips_sharing_layer (pangu_moe); empty for every other family.
+    # chips_sharing_layer (pangu_moe, exaone_moe); empty for every other family.
     expert_plan: tuple[tuple[str, int], ...] = ()
+    # For a family whose attention differs by layer (exaone_moe): a layer's
+    # kind, window, block of queries and keys a block, as (name, value) pairs
+    # a layer; empty for every other family.
+    attention_plan: tuple[tuple[tuple[str, object], ...], ...] = ()
     # For a family whose step counts what it did on the device (pangu_moe's
     # routing): `apply_stats(params, batch) -> (apply's outputs, int32
     # [len(step_stats)])`, the counters named by `step_stats` in order. The
@@ -247,7 +265,7 @@ def register_model(kind: str):
 
 def build_model(kind: str, config: ModelConfig | None = None, **overrides) -> Model:
     """Instantiate a model family by kind: dcn, dcn_v2, wide_deep, deepfm,
-    two_tower, dlrm, dlrm_dcnv2, phi4flash, pangu_moe."""
+    two_tower, dlrm, dlrm_dcnv2, phi4flash, pangu_moe, exaone_moe."""
     if kind not in _BUILDERS:
         raise KeyError(f"unknown model kind {kind!r}; have {sorted(_BUILDERS)}")
     if config is None:

@@ -41,12 +41,11 @@ out, and that partial result goes on to the next layer. Nothing stands in for
 the other chips or for their traffic. (`tests/test_pangu_moe.py`: the shares'
 parts, the shared expert counted once, add up to the uncut layer.)
 
-The held experts' part is a grouped product: for each held expert the tokens
-routed to it are gathered EXPERT_BLOCK at a time, as many blocks as its load
-takes (a loop whose length the routing decides), through the expert's gated
-MLP and added back into their rows times their gates. No token is dropped
-whatever the routing; a block is padded to its size, so the work follows the
-loads rounded up.
+The routed layer itself (the router, the held experts' grouped product, its
+counters) and the blocks beside it (the product of pieces with a weight, the
+RMSNorm, the gated MLP, the rotary turn) are `models/routed.py`'s, shared with
+`exaone_moe`; the names below that hand this family's `OPERAND_PIECES` on to
+them are what its tests and the benchmark's precision readings replace.
 
 What the served step skips (exact, as `phi4flash`'s): the score reads the
 last position, so the LAST layer's queries, attention output and FFN are
@@ -59,13 +58,9 @@ are left out of the grouped product and of the counters, exactly. (Its
 router scores are all a half, and `top_k` would hand all of them to experts
 0 .. top_k - 1.)
 
-The step counts its routing on the device (`STEP_STATS`, summed over the
-routed layers): (live token, routed layer) pairs, the (token, held expert)
-pairs that the blocks of the grouped product took through an expert, and the
-most that one held expert took. The last two are counted INSIDE the expert
-loops, from the rows a block gathered: a step that routed and then skipped or
-cut short a loop reads low. `Model.apply_stats` returns them beside the
-outputs; the batcher carries them back with the scores (serving/batcher.py
+The step counts its routing on the device (`routed.STEP_STATS`, summed over the
+routed layers). `Model.apply_stats` returns the counters beside the outputs;
+the batcher carries them back with the scores (serving/batcher.py
 `_build_entry`).
 
 Numerics: parameters and matmul operands in `compute_dtype` (bfloat16 as
@@ -84,25 +79,19 @@ section 6, PR 35). The router's product and its top-k are float32 at
 
 from __future__ import annotations
 
-import numpy as np
-
 import jax
 import jax.numpy as jnp
 
-from . import sequence
+from . import routed, sequence
 from .base import Model, ModelConfig, register_model
 from .embeddings import embedding_init, field_embed
+from .routed import EXPERT_BLOCK, INIT_STD, STEP_STATS, rope_table, rotate, route
+from .routed import gated_init as _gated_init, matrix as _matrix, rms_norm as _rms_norm
 
-INIT_STD = 0.02  # matrices, the embedding and the score vector
-# Tokens a block of the grouped product takes through a held expert: enough
-# rows to fill the MXU against the expert's weights, few enough that the
-# padding of an expert's last block stays under its mean load.
-EXPERT_BLOCK = 256
 # Pieces of the compute dtype a wider activation enters a product as: read at
 # every call of `_product` (models/sequence.py has the product itself). Three
 # bfloat16 pieces are the float32 value; the module's text says why not two.
 OPERAND_PIECES = 3
-STEP_STATS = ("moe.tokens", "moe.assignments_here", "moe.busiest_expert_tokens")
 
 
 def layer_plan(config: ModelConfig) -> tuple[str, ...]:
@@ -120,12 +109,7 @@ def _sizes(config: ModelConfig) -> dict[str, int]:
     heads = config.num_attention_heads
     if config.qk_rope_head_dim % 2:
         raise ValueError(f"qk_rope_head_dim {config.qk_rope_head_dim}: the rotary part turns pairs")
-    if not 0 < config.num_experts_per_tok <= experts:
-        raise ValueError(f"num_experts_per_tok {config.num_experts_per_tok} of {experts} routed experts")
-    if first < 0 or first + held > experts or experts % held:
-        raise ValueError(
-            f"experts_held {held} from first_expert_held {first} of n_routed_experts {experts}: "
-            "a contiguous range of the routed experts, of a size that divides them")
+    routed.check_share(experts, held, first, config.num_experts_per_tok)
     return {
         "hidden": config.embed_dim, "inter": config.intermediate_size, "heads": heads,
         "q_rank": config.q_lora_rank, "kv_rank": config.kv_lora_rank,
@@ -138,16 +122,6 @@ def _sizes(config: ModelConfig) -> dict[str, int]:
 # ---------------------------------------------------------------------------
 # Parameters
 # ---------------------------------------------------------------------------
-
-
-def _matrix(rng, shape, dtype):
-    return jax.random.normal(rng, shape, dtype) * jnp.asarray(INIT_STD, dtype)
-
-
-def _gated_init(rng, shape_in: tuple, shape_out: tuple, dtype) -> dict:
-    k_gate, k_up, k_down = jax.random.split(rng, 3)
-    return {"gate": _matrix(k_gate, shape_in, dtype), "up": _matrix(k_up, shape_in, dtype),
-            "down": _matrix(k_down, shape_out, dtype)}
 
 
 def _layer_init(rng, kind: str, s: dict, dtype) -> dict:
@@ -188,36 +162,12 @@ def _product(spec: str, x: jax.Array, y: jax.Array, cd) -> jax.Array:
 
 
 def _dot(x: jax.Array, w: jax.Array, cd) -> jax.Array:
-    """`x [..., k]` times the weight `w [k, n]`, float32: the pieces of `x`
-    stacked into ONE product, so that the weight is read once a product and
-    the executable holds one product where it held one a piece (a third of
-    its code: the ladder's executables have to fit the compile cache)."""
-    stacked = jnp.stack(sequence.pieces(x, cd, OPERAND_PIECES))
-    return jnp.sum(jnp.einsum("p...k,kn->p...n", stacked, w.astype(cd), preferred_element_type=jnp.float32), axis=0)
-
-
-def _rms_norm(w: jax.Array, x: jax.Array, eps: float) -> jax.Array:
-    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w.astype(jnp.float32)
+    """`routed.dot` at this family's pieces."""
+    return routed.dot(x, w, cd, OPERAND_PIECES)
 
 
 def _gated_mlp(p: dict, x: jax.Array, cd) -> jax.Array:
-    return _dot(jax.nn.silu(_dot(x, p["gate"], cd)) * _dot(x, p["up"], cd), p["down"], cd)
-
-
-def rope_table(length: int, width: int, theta: float) -> tuple[np.ndarray, np.ndarray]:
-    """cos and sin `[length, width / 2]` of the angles `t * theta ** (-2i / width)`,
-    made in float64 and held as float32 constants of the step."""
-    frequency = theta ** (-np.arange(0, width, 2, dtype=np.float64) / width)
-    angles = np.arange(length, dtype=np.float64)[:, None] * frequency[None, :]
-    return np.cos(angles).astype(np.float32), np.sin(angles).astype(np.float32)
-
-
-def rotate(x: jax.Array, cos, sin) -> jax.Array:
-    """The rotary turn of `x [..., d]` by `cos`, `sin` (broadcast against
-    `[..., d / 2]`): pairs (i, i + d/2)."""
-    half = x.shape[-1] // 2
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return routed.gated_mlp(p, x, cd, OPERAND_PIECES)
 
 
 def latent_attention(p: dict, a: jax.Array, s: dict, cd, eps: float, theta: float,
@@ -258,75 +208,17 @@ def latent_attention(p: dict, a: jax.Array, s: dict, cd, eps: float, theta: floa
     return _dot(o.reshape(n, queries, heads * v_dim), p["o"], cd)
 
 
-def route(router: jax.Array, x: jax.Array, top_k: int, scaling: float):
-    """(the chosen experts `[T, k]`, their gates `[T, k]`, every expert's
-    score `[T, E]`) for tokens `x [T, H]`: sigmoid scores over all the routed
-    experts, the k largest, normalised to sum 1 and scaled. float32 at
-    `highest` precision whatever the compute dtype."""
-    with jax.named_scope("router"):
-        scores = jax.nn.sigmoid(jnp.einsum(
-            "th,he->te", x, router.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32))
-        top, chosen = jax.lax.top_k(scores, top_k)
-        return chosen, top / jnp.sum(top, axis=-1, keepdims=True) * scaling, scores
-
-
 def held_experts(p: dict, x: jax.Array, chosen: jax.Array, gates: jax.Array, first: int, cd,
                  block: int = EXPERT_BLOCK, live: jax.Array | None = None):
-    """The held experts' part of the routed layer for tokens `x [T, H]`:
-    `sum over held e chosen by the token of g_e * expert_e(x)`, `[T, H]`
-    float32, and the tokens each held expert's blocks took through it,
-    `[held]` int32, counted where they were gathered. `p` holds the experts
-    `first .. first + held - 1` stacked; `chosen` and `gates` are the
-    router's `[T, k]`; `live [T]` is false for the tokens left out (a padded
-    row's: their part is zero). The caller's `experts` scope."""
-    tokens, held = x.shape[0], p["gate"].shape[0]
-    padded = -(-tokens // block) * block
-    with jax.named_scope("dispatch"):
-        mine = (chosen - first)[:, :, None] == jnp.arange(held)[None, None, :]  # [T, k, held]
-        gate_of = jnp.sum(jnp.where(mine, gates[:, :, None], 0.0), axis=1)  # [T, held]
-        routed_here = jnp.any(mine, axis=1)  # [T, held]
-        if live is not None:
-            routed_here &= live[:, None]
-        blocks = (jnp.sum(routed_here, axis=0, dtype=jnp.int32) + block - 1) // block
-        # A held expert's tokens first, in row order; then rows past the end,
-        # which a gather clips and a scatter drops.
-        orders = [
-            jnp.nonzero(routed_here[:, e], size=padded, fill_value=tokens)[0] for e in range(held)
-        ]
-    out, took = jnp.zeros(x.shape, jnp.float32), []
-    for e in range(held):
-        expert = {name: w[e] for name, w in p.items()}
-
-        def body(i, carry, e=e, expert=expert):
-            out, took = carry
-            rows = jax.lax.dynamic_slice(orders[e], (i * block,), (block,))
-            with jax.named_scope("grouped"):
-                y = _gated_mlp(expert, x.at[rows].get(mode="clip"), cd)
-            with jax.named_scope("combine"):
-                gate = gate_of[:, e].at[rows].get(mode="fill", fill_value=0.0)
-                return (out.at[rows].add(y * gate[:, None], mode="drop"),
-                        took + jnp.sum(rows < tokens, dtype=jnp.int32))
-
-        out, took_e = jax.lax.fori_loop(0, blocks[e], body, (out, jnp.int32(0)))
-        took.append(took_e)
-    return out, jnp.stack(took)
+    """`routed.held_experts` at this family's pieces."""
+    return routed.held_experts(p, x, chosen, gates, first, cd, block, live, count=OPERAND_PIECES)
 
 
 def routed_ffn(layer: dict, a: jax.Array, s: dict, scaling: float, cd, live: jax.Array | None = None):
-    """shared(a) + the held experts' part, `a`'s shape `[n, positions, H]`;
-    and this layer's counters, int32 `[len(STEP_STATS)]`. `live [n]` is false
-    for the rows that are zero throughout."""
-    x = a.reshape(-1, a.shape[-1])
-    if live is not None:
-        live = jnp.repeat(live, a.shape[1])
-    chosen, gates, _ = route(layer["router"], x, s["top_k"], scaling)
-    with jax.named_scope("shared_expert"):
-        shared = _gated_mlp(layer["shared"], x, cd)
-    with jax.named_scope("experts"):
-        routed, took = held_experts(layer["experts"], x, chosen, gates, s["first"], cd, live=live)
-    tokens = jnp.int32(x.shape[0]) if live is None else jnp.sum(live, dtype=jnp.int32)
-    return (shared + routed).reshape(a.shape), jnp.stack([tokens, jnp.sum(took), jnp.max(took)])
+    """`routed.routed_ffn` at this family's pieces, through this module's
+    `route` and `held_experts` (one replaced by name is the one that runs)."""
+    return routed.routed_ffn(
+        layer, a, s["top_k"], s["first"], scaling, cd, OPERAND_PIECES, live, router=route, experts=held_experts)
 
 
 def forward(config: ModelConfig, params, batch) -> tuple[jax.Array, jax.Array]:

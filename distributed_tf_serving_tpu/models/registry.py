@@ -198,6 +198,12 @@ class Servable:
         return dict(self.model.expert_plan) or None
 
     @property
+    def attention_plan(self) -> list[dict] | None:
+        """Each layer's attention where a family's differs by layer: kind,
+        window, block of queries, keys a block. None for every other family."""
+        return [dict(layer) for layer in self.model.attention_plan] or None
+
+    @property
     def params_bytes(self) -> int:
         """Bytes of the parameter tree as it is held."""
         return sum(int(getattr(leaf, "nbytes", 0)) for leaf in jax.tree.leaves(self.params))
@@ -325,7 +331,7 @@ class ServableRegistry:
     def per_servable(self, attr: str) -> dict:
         """"name:version" -> that property of every loaded servable
         (embedding_pack, lookups_per_row, bags, layer_plan, expert_plan,
-        params_bytes)."""
+        attention_plan, params_bytes)."""
         with self._lock:
             loaded = [s for versions in self._servables.values() for s in versions.values()]
         return {f"{s.name}:{s.version}": getattr(s, attr) for s in loaded}

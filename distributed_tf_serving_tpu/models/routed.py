@@ -1,0 +1,178 @@
+"""What the routed sequence-ranker families (pangu_moe, exaone_moe) share
+beside `sequence`'s products and blocks: the product of an activation's pieces
+with a weight, the RMSNorm, the gated MLP, the rotary turn, the sigmoid router
+and the held experts' grouped product with its counters. One implementation,
+so that a change to any of them is measured on both families' cells, whose
+hidden sizes (7680, 6144) and loads an expert (256 tokens a step, 512) differ.
+
+Every product takes the number of pieces (`count`) from its caller: a family
+keeps its own `OPERAND_PIECES` and hands it on at every call, so that its
+tests and the benchmark's precision readings plant the precision below the
+stated one by that one name.
+
+**The share.** The routed layer is told which experts it holds (`first` and
+the leading size of the experts' arrays): it routes over ALL the experts and
+computes `g_e * expert_e(x)` for the held `e` only. What the absent experts
+would have added is left out, and nothing stands in for the other chips.
+
+The held experts' part is a grouped product: for each held expert the tokens
+routed to it are gathered EXPERT_BLOCK at a time, as many blocks as its load
+takes (a loop whose length the routing decides), through the expert's gated
+MLP and added back into their rows times their gates. No token is dropped
+whatever the routing; a block is padded to its size, so the work follows the
+loads rounded up.
+
+The step counts its routing on the device (`STEP_STATS`, summed over the
+routed layers): (live token, routed layer) pairs, the (token, held expert)
+pairs that the blocks of the grouped product took through an expert, and the
+most that one held expert took. The last two are counted INSIDE the expert
+loops, from the rows a block gathered: a step that routed and then skipped or
+cut short a loop reads low.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+
+from . import sequence
+
+INIT_STD = 0.02  # matrices, the embedding and the score vector
+# Tokens a block of the grouped product takes through a held expert: enough
+# rows to fill the MXU against the expert's weights, few enough that the
+# padding of an expert's last block stays under its mean load.
+EXPERT_BLOCK = 256
+STEP_STATS = ("moe.tokens", "moe.assignments_here", "moe.busiest_expert_tokens")
+
+
+def matrix(rng, shape, dtype):
+    return jax.random.normal(rng, shape, dtype) * jnp.asarray(INIT_STD, dtype)
+
+
+def gated_init(rng, shape_in: tuple, shape_out: tuple, dtype) -> dict:
+    k_gate, k_up, k_down = jax.random.split(rng, 3)
+    return {"gate": matrix(k_gate, shape_in, dtype), "up": matrix(k_up, shape_in, dtype),
+            "down": matrix(k_down, shape_out, dtype)}
+
+
+def dot(x: jax.Array, w: jax.Array, cd, count: int) -> jax.Array:
+    """`x [..., k]` times the weight `w [k, n]`, float32: the pieces of `x`
+    stacked into ONE product, so that the weight is read once a product and
+    the executable holds one product where it held one a piece (a third of
+    its code: the ladder's executables have to fit the compile cache)."""
+    stacked = jnp.stack(sequence.pieces(x, cd, count))
+    return jnp.sum(jnp.einsum("p...k,kn->p...n", stacked, w.astype(cd), preferred_element_type=jnp.float32), axis=0)
+
+
+def rms_norm(w: jax.Array, x: jax.Array, eps: float) -> jax.Array:
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w.astype(jnp.float32)
+
+
+def gated_mlp(p: dict, x: jax.Array, cd, count: int) -> jax.Array:
+    return dot(jax.nn.silu(dot(x, p["gate"], cd, count)) * dot(x, p["up"], cd, count), p["down"], cd, count)
+
+
+def rope_table(length: int, width: int, theta: float) -> tuple[np.ndarray, np.ndarray]:
+    """cos and sin `[length, width / 2]` of the angles `t * theta ** (-2i / width)`,
+    made in float64 and held as float32 constants of the step."""
+    frequency = theta ** (-np.arange(0, width, 2, dtype=np.float64) / width)
+    angles = np.arange(length, dtype=np.float64)[:, None] * frequency[None, :]
+    return np.cos(angles).astype(np.float32), np.sin(angles).astype(np.float32)
+
+
+def rotate(x: jax.Array, cos, sin) -> jax.Array:
+    """The rotary turn of `x [..., d]` by `cos`, `sin` (broadcast against
+    `[..., d / 2]`): pairs (i, i + d/2)."""
+    half = x.shape[-1] // 2
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+
+def check_share(experts: int, held: int, first: int, top_k: int) -> None:
+    """Refuse a share the routed layer cannot be cut into."""
+    if not 0 < top_k <= experts:
+        raise ValueError(f"num_experts_per_tok {top_k} of {experts} routed experts")
+    if first < 0 or first + held > experts or experts % held:
+        raise ValueError(
+            f"experts_held {held} from first_expert_held {first} of {experts} routed experts: "
+            "a contiguous range of the routed experts, of a size that divides them")
+
+
+def route(router: jax.Array, x: jax.Array, top_k: int, scaling: float):
+    """(the chosen experts `[T, k]`, their gates `[T, k]`, every expert's
+    score `[T, E]`) for tokens `x [T, H]`: sigmoid scores over all the routed
+    experts, the k largest, normalised to sum 1 and scaled. float32 at
+    `highest` precision whatever the compute dtype."""
+    with jax.named_scope("router"):
+        scores = jax.nn.sigmoid(jnp.einsum(
+            "th,he->te", x, router.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32))
+        top, chosen = jax.lax.top_k(scores, top_k)
+        return chosen, top / jnp.sum(top, axis=-1, keepdims=True) * scaling, scores
+
+
+def held_experts(p: dict, x: jax.Array, chosen: jax.Array, gates: jax.Array, first: int, cd,
+                 block: int = EXPERT_BLOCK, live: jax.Array | None = None, *, count: int):
+    """The held experts' part of the routed layer for tokens `x [T, H]`:
+    `sum over held e chosen by the token of g_e * expert_e(x)`, `[T, H]`
+    float32, and the tokens each held expert's blocks took through it,
+    `[held]` int32, counted where they were gathered. `p` holds the experts
+    `first .. first + held - 1` stacked; `chosen` and `gates` are the
+    router's `[T, k]`; `live [T]` is false for the tokens left out (a padded
+    row's: their part is zero). The caller's `experts` scope."""
+    tokens, held = x.shape[0], p["gate"].shape[0]
+    padded = -(-tokens // block) * block
+    with jax.named_scope("dispatch"):
+        mine = (chosen - first)[:, :, None] == jnp.arange(held)[None, None, :]  # [T, k, held]
+        gate_of = jnp.sum(jnp.where(mine, gates[:, :, None], 0.0), axis=1)  # [T, held]
+        routed_here = jnp.any(mine, axis=1)  # [T, held]
+        if live is not None:
+            routed_here &= live[:, None]
+        blocks = (jnp.sum(routed_here, axis=0, dtype=jnp.int32) + block - 1) // block
+        # A held expert's tokens first, in row order; then rows past the end,
+        # which a gather clips and a scatter drops.
+        orders = [
+            jnp.nonzero(routed_here[:, e], size=padded, fill_value=tokens)[0] for e in range(held)
+        ]
+    out, took = jnp.zeros(x.shape, jnp.float32), []
+    for e in range(held):
+        expert = {name: w[e] for name, w in p.items()}
+
+        def body(i, carry, e=e, expert=expert):
+            out, took = carry
+            rows = jax.lax.dynamic_slice(orders[e], (i * block,), (block,))
+            with jax.named_scope("grouped"):
+                y = gated_mlp(expert, x.at[rows].get(mode="clip"), cd, count)
+            with jax.named_scope("combine"):
+                gate = gate_of[:, e].at[rows].get(mode="fill", fill_value=0.0)
+                return (out.at[rows].add(y * gate[:, None], mode="drop"),
+                        took + jnp.sum(rows < tokens, dtype=jnp.int32))
+
+        out, took_e = jax.lax.fori_loop(0, blocks[e], body, (out, jnp.int32(0)))
+        took.append(took_e)
+    return out, jnp.stack(took)
+
+
+def routed_ffn(layer: dict, a: jax.Array, top_k: int, first: int, scaling: float, cd, count: int,
+               live: jax.Array | None = None, router=None, experts=None):
+    """shared(a) + the held experts' part, `a`'s shape `[n, positions, H]`;
+    and this layer's counters, int32 `[len(STEP_STATS)]`. `live [n]` is false
+    for the rows that are zero throughout. `router` and `experts` stand for
+    this module's `route` and `held_experts` (at `count` pieces) where a family
+    hands in its own names for them (pangu_moe, whose tests plant faults under
+    those)."""
+    experts = experts or functools.partial(held_experts, count=count)
+    x = a.reshape(-1, a.shape[-1])
+    if live is not None:
+        live = jnp.repeat(live, a.shape[1])
+    chosen, gates, _ = (router or route)(layer["router"], x, top_k, scaling)
+    with jax.named_scope("shared_expert"):
+        shared = gated_mlp(layer["shared"], x, cd, count)
+    with jax.named_scope("experts"):
+        routed, took = experts(layer["experts"], x, chosen, gates, first, cd, live=live)
+    tokens = jnp.int32(x.shape[0]) if live is None else jnp.sum(live, dtype=jnp.int32)
+    return (shared + routed).reshape(a.shape), jnp.stack([tokens, jnp.sum(took), jnp.max(took)])

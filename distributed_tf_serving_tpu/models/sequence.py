@@ -1,8 +1,9 @@
-"""What the sequence-ranker families (phi4flash, pangu_moe) share: products
+"""What the sequence-ranker families (phi4flash, pangu_moe, exaone_moe) share: products
 whose float32 activations enter as pieces of the compute dtype, the causal
 softmax of a block of queries, the blocks themselves, and the cut to the last
 position. One implementation, so that a change to any of them is measured on
-both families' cells.
+every family's cell. (`models/routed.py` has what the two routed families
+share beside these.)
 
 A family keeps its own `OPERAND_PIECES` and a `_product` of four arguments
 that hands it on (its tests and the benchmark's precision readings replace

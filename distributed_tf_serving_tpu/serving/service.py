@@ -208,7 +208,7 @@ class PredictionServiceImpl:
         device_kind, device count, library versions — plus the load-time
         compile wall, the start-up's stamps (`startup`, with each loaded
         servable's embedding rows a candidate row, `lookups_per_row`, the
-        `bags` they pool to, a sequence family's `layer_plan`, a routed family's `expert_plan`, its `params_bytes`, the `upload_format` of its batches and the
+        `bags` they pool to, a sequence family's `layer_plan`, a routed family's `expert_plan`, the `attention_plan` of one whose attention differs by layer, its `params_bytes`, the `upload_format` of its batches and the
         `assembler` that builds them: "native" or "generic: <why>", and the `gather` of its
         embedding rows as traced: the Pallas kernel or XLA's, `models/embeddings.py`), the pack factor of
         each loaded servable's embedding table (`embedding_pack`), persistent-cache
@@ -231,6 +231,7 @@ class PredictionServiceImpl:
             "bags": self.registry.per_servable("bags"),
             "layer_plan": self.registry.per_servable("layer_plan"),
             "expert_plan": self.registry.per_servable("expert_plan"),
+            "attention_plan": self.registry.per_servable("attention_plan"),
             "params_bytes": self.registry.per_servable("params_bytes"),
             "upload_format": upload_formats() if callable(upload_formats) else {},
             "assembler": assemblers() if callable(assemblers) else {},

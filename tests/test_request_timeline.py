@@ -431,6 +431,8 @@ def test_cli_server_reports_startup_stamps_and_raw_counters(tmp_path):
         assert startup.pop("layer_plan") == {"DCN:1": None}
         # Nor an expert plan: that is a routed family's share (PR 35).
         assert startup.pop("expert_plan") == {"DCN:1": None}
+        # Nor an attention plan: that is a family's whose attention differs by layer (PR 43).
+        assert startup.pop("attention_plan") == {"DCN:1": None}
         assert startup.pop("params_bytes")["DCN:1"] > 0
         # And how its batches cross to the device: the ladder's warm-up
         # traced the one-buffer entry (ops/transfer.py describe_layout).

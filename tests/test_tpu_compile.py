@@ -179,3 +179,30 @@ def test_step_through_the_gather_kernel_has_no_xla_gather(one_chip, model, no_co
     assert re.search(rf"bf16\[{bucket},{FIELDS},128\]\S* custom-call\(", text)
     assert not re.search(rf"bf16\[{bucket * FIELDS},128\]\S* fusion\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes < GIB // 8
+
+
+def test_exaone_moes_four_row_step_compiles_at_the_published_cut(one_chip, no_compile_cache):
+    """K-EXAONE's share as `k_exaone_moe_rerank-bulk` serves it (2.386 B
+    parameters, rows of 2,048 tokens), the top bucket's step with its counters:
+    the chip's compiler takes the band's batched blocks, the 512-query blocks
+    of the full layer and the experts' loops, and what it holds beside the
+    4.77 GB of weights fits the chip's 16 GB."""
+    import json
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                           "benchmark", "configs", "k_exaone_moe_rerank", "config.json")) as f:
+        config = json.load(f)["toml"]
+    shape = {k: tuple(v) if isinstance(v, list) else v for k, v in config["model"].items()}
+    exaone = build_model("exaone_moe", ModelConfig(**shape))
+    shapes = jax.eval_shape(exaone.init, jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), shapes)
+    rows = max(config["server"]["buckets"])
+    batch = {
+        "feat_ids": jax.ShapeDtypeStruct((rows, shape["num_fields"]), jnp.int32, sharding=one_chip),
+        "feat_wts": jax.ShapeDtypeStruct((rows, shape["num_fields"]), jnp.float32, sharding=one_chip),
+    }
+    compiled = jax.jit(exaone.apply_stats).lower(params, batch).compile()
+    memory = compiled.memory_analysis()
+    assert 4.7e9 < memory.argument_size_in_bytes < 4.8e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12 * GIB
+    assert memory.generated_code_size_in_bytes < 64 << 20  # two of these beside the other cells' in the cache
