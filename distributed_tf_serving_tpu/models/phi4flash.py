@@ -38,10 +38,11 @@ norms, the softmax, the convolution and the scan's state. A float32
 activation enters a product as OPERAND_PIECES arrays of the compute dtype:
 its rounding to bfloat16 and the rounding of what that left (16 bits of
 mantissa: two passes of the MXU against a bfloat16 weight, three where both
-operands are activations). Rounded to one bfloat16 piece the activations
-alone put 0.02 rms on a logit of standard deviation 1 at the published
-widths, half of what computing wholly in bfloat16 costs, and no comparison
-of a few scores could tell the stated precision from the one below it.
+operands are activations; `sequence.product` makes those three one product).
+Rounded to one bfloat16 piece the activations alone put 0.02 rms on a logit of
+standard deviation 1 at the published widths, half of what computing wholly
+in bfloat16 costs, and no comparison of a few scores could tell the stated
+precision from the one below it.
 
 Head convention (a permutation of projection columns: under seeded random
 weights every convention is the same model): query heads `2h, 2h+1` are
@@ -202,7 +203,8 @@ def _product(spec: str, x: jax.Array, y: jax.Array, cd) -> jax.Array:
 
 
 def _dot(x: jax.Array, w: jax.Array, cd) -> jax.Array:
-    """x @ w, as `_product`."""
+    """x @ w, as `_product` (at this family's two pieces a product a piece:
+    `sequence.product` says why)."""
     return _product("...k,kn->...n", x, w, cd)
 
 

@@ -1,7 +1,8 @@
 """What the routed sequence-ranker families (pangu_moe, exaone_moe) share
-beside `sequence`'s products and blocks: the product of an activation's pieces
-with a weight, the RMSNorm, the gated MLP, the rotary turn, the sigmoid router
-and the held experts' grouped product with its counters. One implementation,
+beside `sequence`'s products and blocks: the product with a weight under its
+own name (`dot`: `sequence.product`'s stacked form), the RMSNorm, the gated
+MLP, the rotary turn, the sigmoid router and the held experts' grouped
+product with its counters. One implementation,
 so that a change to any of them is measured on both families' cells, whose
 hidden sizes (7680, 6144) and loads an expert (256 tokens a step, 512) differ.
 
@@ -60,12 +61,12 @@ def gated_init(rng, shape_in: tuple, shape_out: tuple, dtype) -> dict:
 
 
 def dot(x: jax.Array, w: jax.Array, cd, count: int) -> jax.Array:
-    """`x [..., k]` times the weight `w [k, n]`, float32: the pieces of `x`
-    stacked into ONE product, so that the weight is read once a product and
-    the executable holds one product where it held one a piece (a third of
-    its code: the ladder's executables have to fit the compile cache)."""
-    stacked = jnp.stack(sequence.pieces(x, cd, count))
-    return jnp.sum(jnp.einsum("p...k,kn->p...n", stacked, w.astype(cd), preferred_element_type=jnp.float32), axis=0)
+    """`x [..., k]` times the weight `w [k, n]`, float32: `sequence.product`
+    against the weight rounded to the compute dtype whole, which stacks the
+    pieces of `x` into ONE product, so that the weight is read once a product
+    and the executable holds one product where it held one a piece (a third
+    of its code: the ladder's executables have to fit the compile cache)."""
+    return sequence.product("...k,kn->...n", x, w.astype(cd), cd, count)
 
 
 def rms_norm(w: jax.Array, x: jax.Array, eps: float) -> jax.Array:

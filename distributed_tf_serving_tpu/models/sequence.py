@@ -1,5 +1,6 @@
 """What the sequence-ranker families (phi4flash, pangu_moe, exaone_moe) share: products
-whose float32 activations enter as pieces of the compute dtype, the causal
+whose float32 activations enter as pieces of the compute dtype (`product`: one
+product a call wherever a form exists that copies no large array), the causal
 softmax of a block of queries, the blocks themselves, and the cut to the last
 position. One implementation, so that a change to any of them is measured on
 every family's cell. (`models/routed.py` has what the two routed families
@@ -11,6 +12,9 @@ either by name to plant the precision below the stated one).
 """
 
 from __future__ import annotations
+
+import functools
+import string
 
 import jax
 import jax.numpy as jnp
@@ -40,15 +44,81 @@ def pieces(x: jax.Array, cd, count: int = OPERAND_PIECES) -> list[jax.Array]:
     return out
 
 
+def spec_terms(spec: str) -> tuple[str, str, str]:
+    """(first operand's labels, second's, the result's) of a two-operand spec."""
+    terms, _, out = spec.replace(" ", "").partition("->")
+    first, _, second = terms.partition(",")
+    return first, second, out
+
+
+def contraction_axes(spec: str) -> tuple[int, int] | None:
+    """The axis `spec` contracts, in the first operand and in the second:
+    that of the one label both operands carry and the result does not. None
+    where a spec has no such label, or more than one."""
+    first, second, out = spec_terms(spec)
+    found = [c for c in first if c.isalpha() and c in second and c not in out]
+    if len(found) != 1:
+        return None
+
+    def axis(term: str) -> int:  # counted from the end where an ellipsis stands before the label
+        head, dots, tail = term.partition("...")
+        return tail.index(found[0]) - len(tail) if dots and found[0] in tail else head.index(found[0])
+
+    return axis(first), axis(second)
+
+
 def product(spec: str, x: jax.Array, y: jax.Array, cd, count: int = OPERAND_PIECES) -> jax.Array:
     """einsum(spec, x, y) with operands in the compute dtype and a float32
-    result: one pass a pair of pieces, but for the pairs whose product is
-    below the last piece's size."""
+    result: one pass of the MXU a pair of pieces, but for the pairs whose
+    product is below the last piece's size. The pairs are added up in a
+    product's own accumulation wherever a form does that without copying a
+    large array (a product a pair writes its float32 result to memory and the
+    next reads it back to add its own). The form follows the operands:
+
+    - the second whole in the compute dtype (a weight) and the first in
+      three pieces or more: the pieces stacked on a new leading axis and
+      summed over it, which the compiler fuses into one product that reads
+      the weight once. Two pieces stay a product a piece: the compiler folds
+      the second product's add into its output already, and stacked they cost
+      a copy (2.4% of `phi4flash`'s step on the v5e: PERF.md section 6, PR 44);
+    - both in pieces and the result no smaller than either (`q k'`, whose
+      score tile is what the memory carries): the pairs side by side along
+      the contracted axis of both, ONE product. An operand is copied once a
+      pair, which is why the result has to be the larger;
+    - both in pieces and the first the largest array (`p v`, the
+      probabilities): a product a PIECE of it, against the second's pieces
+      that pair with that one side by side along an axis of the second that
+      the result keeps; the large operand is read once a piece and never
+      copied, and the parts of the small results are added up;
+    - anything else (the second the largest or the only one in pieces, an
+      ellipsis between pieces, a spec that does not contract exactly one
+      label): a product a pair, added up.
+    """
     xs, ys = pieces(x, cd, count), pieces(y, cd, count)
-    return sum(
-        jnp.einsum(spec, a, b, preferred_element_type=jnp.float32)
-        for i, a in enumerate(xs) for j, b in enumerate(ys) if i + j < max(len(xs), len(ys))
-    )
+    kept = max(len(xs), len(ys))
+    pairs = [(i, j) for i in range(len(xs)) for j in range(len(ys)) if i + j < kept]
+    einsum = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
+    first, second, out = spec_terms(spec)
+    if len(pairs) == 1:
+        return einsum(spec, xs[0], ys[0])
+    if len(ys) == 1 and len(xs) > 2:
+        extra = next(c for c in string.ascii_uppercase if c not in spec)
+        return jnp.sum(einsum(f"{extra}{first},{second}->{extra}{out}", jnp.stack(xs), ys[0]), axis=0)
+    axes = contraction_axes(spec)
+    if min(len(xs), len(ys)) > 1 and axes is not None:
+        result = jax.eval_shape(functools.partial(jnp.einsum, spec), x, y)
+        if result.size >= max(x.size, y.size):
+            return einsum(
+                spec, jnp.concatenate([xs[i] for i, _ in pairs], axis=axes[0]),
+                jnp.concatenate([ys[j] for _, j in pairs], axis=axes[1]))
+        beside = [c for c in second if c not in first and c in out]
+        if beside and "." not in spec and x.size >= y.size:
+            y_axis, out_axis = second.index(beside[0]), out.index(beside[0])
+            return sum(
+                part for i, piece in enumerate(xs)
+                for part in jnp.split(
+                    einsum(spec, piece, jnp.concatenate(ys[:kept - i], axis=y_axis)), kept - i, axis=out_axis))
+    return sum(einsum(spec, xs[i], ys[j]) for i, j in pairs)
 
 
 def causal_softmax(scores: jax.Array, q_start: int, window: int | None = None) -> jax.Array:

@@ -10,7 +10,6 @@ benchmark's tolerance catches, the step's counters and how they reach
 layer into `models/routed.py` left as it was."""
 
 import dataclasses
-import hashlib
 import importlib.util
 import json
 import os
@@ -598,31 +597,24 @@ def test_toml_reads_the_published_keys(tmp_path):
 # ------------------------------------- what the move into models/routed.py left
 
 
-# sha256 of the 4-row step's lowered program at the small TOML's sizes, taken
-# at the commit BEFORE the routed layer moved out of pangu_moe.py (PR 42's
-# tree): the same program is the same scores, to the bit, on any machine. A
-# new jax may print a program otherwise: take both again from that commit.
-PARENTS_PROGRAM = {
-    "pangu_moe_small": "81ddf56b631c03a95a8c72e7d145237f3ef3c3dc81fec7eecd41d570598a267a",
-    "phi4flash_small": "91d724d816a8e7dc11b1f67d694865c8e114fb5a6192cd5806f58026fa310dd9",
-}
-# and the logits it gave on this container's CPU for rows drawn at seed 5
+# The logits the 4-row step at the small TOML's sizes gave on this container's
+# CPU for rows drawn at seed 5, at the commit BEFORE the routed layer moved out
+# of pangu_moe.py (PR 42's tree). PR 43 held the lowered programs to that
+# tree's by sha256; since PR 44 a product of pieces is one product
+# (models/sequence.py::product), so the programs differ and the float32
+# partial sums meet in another order: the logits move in their last bits
+# (4.3e-6 relative at most, 9.8e-7 absolute), inside the limits below.
 PARENTS_LOGITS = {
     "pangu_moe_small": ["-0x1.3a46520000000p-2", "0x1.5d61040000000p-2", "-0x1.0209e20000000p-2", "0x1.377f260000000p-3"],
     "phi4flash_small": ["0x1.b9a12c0000000p-2", "0x1.5610340000000p-3", "0x1.7298ba0000000p-2", "0x1.d0b9ec0000000p-3"],
 }
 
 
-@pytest.mark.parametrize("name", sorted(PARENTS_PROGRAM))
-def test_the_other_sequence_families_steps_are_the_parents_letter_for_letter(name):
+@pytest.mark.parametrize("name", sorted(PARENTS_LOGITS))
+def test_the_other_sequence_families_steps_give_the_parents_logits(name):
     cfgs = load_config(os.path.join(ROOT, "configs", name + ".toml"))
     config = cfgs["model"]
     model = build_model(cfgs["server"].model_kind, config)
-    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    batch = {"feat_ids": jax.ShapeDtypeStruct((4, config.num_fields), jnp.int32),
-             "feat_wts": jax.ShapeDtypeStruct((4, config.num_fields), jnp.float32)}
-    text = jax.jit(model.apply).lower(shapes, batch).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == PARENTS_PROGRAM[name]
     rng = np.random.default_rng(5)
     drawn = {"feat_ids": rng.integers(0, config.vocab_size, (4, config.num_fields)).astype(np.int32),
              "feat_wts": rng.random((4, config.num_fields), dtype=np.float32)}

@@ -7,6 +7,7 @@ it row-major, one whole lane row a lookup. All such compiles live in this one
 file: the process that describes the topology holds the TPU library."""
 
 import functools
+import json
 import os
 import re
 
@@ -181,28 +182,68 @@ def test_step_through_the_gather_kernel_has_no_xla_gather(one_chip, model, no_co
     assert compiled.memory_analysis().temp_size_in_bytes < GIB // 8
 
 
-def test_exaone_moes_four_row_step_compiles_at_the_published_cut(one_chip, no_compile_cache):
-    """K-EXAONE's share as `k_exaone_moe_rerank-bulk` serves it (2.386 B
-    parameters, rows of 2,048 tokens), the top bucket's step with its counters:
-    the chip's compiler takes the band's batched blocks, the 512-query blocks
-    of the full layer and the experts' loops, and what it holds beside the
-    4.77 GB of weights fits the chip's 16 GB."""
-    import json
+# ----------------------- the sequence cells' top-bucket steps (PR 43, PR 44)
+#
+# `bytes accessed` is the compiler's own count of what the step's operations
+# read and write. It says, without a chip, in which PR a change puts a score
+# tile back through memory: one product a PAIR of pieces read 145.1 / 203.9 /
+# 189.3 GB in the three steps (PR 43's tree); with the pairs added up inside
+# a product they read 115.9 / 138.7 / 139.3 (models/sequence.py::product,
+# PR 44).
 
+
+def sequence_cells_step(name: str, kind: str, one_chip):
+    """(the compiled top-bucket step of the configuration `name` as its cell
+    serves it, with its counters where it has them; its `bytes accessed`)."""
     with open(os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                           "benchmark", "configs", "k_exaone_moe_rerank", "config.json")) as f:
+                           "benchmark", "configs", name, "config.json")) as f:
         config = json.load(f)["toml"]
     shape = {k: tuple(v) if isinstance(v, list) else v for k, v in config["model"].items()}
-    exaone = build_model("exaone_moe", ModelConfig(**shape))
-    shapes = jax.eval_shape(exaone.init, jax.random.PRNGKey(0))
+    model = build_model(kind, ModelConfig(**shape))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), shapes)
     rows = max(config["server"]["buckets"])
     batch = {
         "feat_ids": jax.ShapeDtypeStruct((rows, shape["num_fields"]), jnp.int32, sharding=one_chip),
         "feat_wts": jax.ShapeDtypeStruct((rows, shape["num_fields"]), jnp.float32, sharding=one_chip),
     }
-    compiled = jax.jit(exaone.apply_stats).lower(params, batch).compile()
+    compiled = jax.jit(model.apply_stats if model.step_stats else model.apply).lower(params, batch).compile()
+    cost = compiled.cost_analysis()
+    return compiled, (cost[0] if isinstance(cost, (list, tuple)) else cost)["bytes accessed"]
+
+
+def test_exaone_moes_four_row_step_compiles_at_the_published_cut(one_chip, no_compile_cache):
+    """K-EXAONE's share as `k_exaone_moe_rerank-bulk` serves it (2.386 B
+    parameters, rows of 2,048 tokens), the top bucket's step with its counters:
+    the chip's compiler takes the band's batched blocks, the 512-query blocks
+    of the full layer and the experts' loops, and what it holds beside the
+    4.77 GB of weights fits the chip's 16 GB."""
+    compiled, accessed = sequence_cells_step("k_exaone_moe_rerank", "exaone_moe", one_chip)
     memory = compiled.memory_analysis()
     assert 4.7e9 < memory.argument_size_in_bytes < 4.8e9
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12 * GIB
     assert memory.generated_code_size_in_bytes < 64 << 20  # two of these beside the other cells' in the cache
+    assert accessed < 160e9
+
+
+def test_phi4flashs_eight_row_step_writes_a_score_tile_once(one_chip, no_compile_cache):
+    """Phi-4-mini-flash at the 16 layers `phi4_mini_flash_rerank-bulk` serves
+    (2.19 B parameters, 8 rows of 1,024 tokens): the weights, what the step
+    holds beside them, the ladder's largest executable, and the bytes."""
+    compiled, accessed = sequence_cells_step("phi4_mini_flash_rerank", "phi4flash", one_chip)
+    memory = compiled.memory_analysis()
+    assert 4.3e9 < memory.argument_size_in_bytes < 4.5e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12 * GIB
+    assert memory.generated_code_size_in_bytes < 122 << 20  # 116.0 MB in PR 43's tree
+    assert accessed < 122e9
+
+
+def test_pangu_moes_eight_row_step_writes_a_score_tile_once(one_chip, no_compile_cache):
+    """openPangu-Ultra-MoE's share as `pangu_ultra_moe_rerank-bulk` serves it
+    (2.585 B parameters, 8 rows of 1,024 tokens), with its counters."""
+    compiled, accessed = sequence_cells_step("pangu_ultra_moe_rerank", "pangu_moe", one_chip)
+    memory = compiled.memory_analysis()
+    assert 5.1e9 < memory.argument_size_in_bytes < 5.3e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12 * GIB
+    assert memory.generated_code_size_in_bytes < 71 << 20  # 67.0 MB in PR 43's tree
+    assert accessed < 156e9
