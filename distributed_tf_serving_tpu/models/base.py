@@ -32,7 +32,10 @@ Batch = dict[str, jax.Array]
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
-    """Knob set shared by the CTR model zoo.
+    """Knob set shared by the CTR model zoo and the four sequence families
+    (phi4flash, pangu_moe, exaone_moe, olmo_hybrid), whose keys carry the
+    names of their published config.json and whose defaults build a small
+    valid model.
 
     Matches the reference workload point where applicable: num_fields=43
     (FIELD_NUM, DCNClient.java:25).
@@ -109,6 +112,22 @@ class ModelConfig:
     layer_types: tuple[str, ...] = ()
     head_dim: int = 0
     num_experts: int = 16
+    # olmo_hybrid (models/olmo_hybrid.py): a row is num_fields token ids as
+    # above; embed_dim the hidden size, intermediate_size every layer's gated
+    # MLP width (mlp_dims is not read), layer_norm_eps the RMSNorms' epsilon,
+    # num_attention_heads / num_key_value_heads / head_dim the full layers'.
+    # layer_types takes "linear_attention" beside "full_attention" (empty =>
+    # the published period, three linear layers then a full one, repeated).
+    # Under the published config.json's names, the linear layers' gated delta
+    # rule: its key and value heads (one key head a value head), their widths
+    # (the state is [key dim, value dim] a head), the taps of the causal
+    # convolution before it, and whether b reaches (0, 2) and not (0, 1).
+    linear_num_key_heads: int = 2
+    linear_num_value_heads: int = 2
+    linear_key_head_dim: int = 8
+    linear_value_head_dim: int = 16
+    linear_conv_kernel_dim: int = 4
+    linear_allow_neg_eigval: bool = True
     # numerics
     compute_dtype: str = "bfloat16"  # "float32" for AUC-parity mode
     param_dtype: str = "float32"
@@ -166,19 +185,22 @@ class Model:
     # True when the signature carries `dense_features` [n, num_dense_features]
     # beside the id/weight pair (the DLRM families).
     takes_dense: bool = False
-    # The kind of every layer of a sequence family (phi4flash, pangu_moe, exaone_moe),
-    # whose rows are num_fields TOKENS; empty for the CTR families.
+    # The kind of every layer of a sequence family (phi4flash, pangu_moe, exaone_moe,
+    # olmo_hybrid), whose rows are num_fields TOKENS; empty for the CTR families.
     layer_plan: tuple[str, ...] = ()
     # What a family with a routed layer holds of it, as (name, number) pairs:
     # published, held, first, top_k, heads_published, heads_held,
     # chips_sharing_layer (pangu_moe, exaone_moe); empty for every other family.
     expert_plan: tuple[tuple[str, int], ...] = ()
-    # For a family whose attention differs by layer (exaone_moe): a layer's
-    # kind, window, block of queries and keys a block, as (name, value) pairs
-    # a layer; empty for every other family.
+    # For a family whose mixer differs by layer, as (name, value) pairs a
+    # layer: an attention layer's kind, window, block of queries and keys a
+    # block (exaone_moe, olmo_hybrid); a linear layer's kind, chunk, state
+    # hand-overs a row and bytes of a row's state (olmo_hybrid); empty for
+    # every other family.
     attention_plan: tuple[tuple[tuple[str, object], ...], ...] = ()
     # For a family whose step counts what it did on the device (pangu_moe's
-    # routing): `apply_stats(params, batch) -> (apply's outputs, int32
+    # and exaone_moe's routing, exaone_moe's and olmo_hybrid's score tiles,
+    # olmo_hybrid's state hand-overs): `apply_stats(params, batch) -> (apply's outputs, int32
     # [len(step_stats)])`, the counters named by `step_stats` in order. The
     # batcher decides on it when it BUILDS the servable's entry: the counters
     # then ride back beside the scores and are recorded as phases by count.
@@ -265,7 +287,7 @@ def register_model(kind: str):
 
 def build_model(kind: str, config: ModelConfig | None = None, **overrides) -> Model:
     """Instantiate a model family by kind: dcn, dcn_v2, wide_deep, deepfm,
-    two_tower, dlrm, dlrm_dcnv2, phi4flash, pangu_moe, exaone_moe."""
+    two_tower, dlrm, dlrm_dcnv2, phi4flash, pangu_moe, exaone_moe, olmo_hybrid."""
     if kind not in _BUILDERS:
         raise KeyError(f"unknown model kind {kind!r}; have {sorted(_BUILDERS)}")
     if config is None:

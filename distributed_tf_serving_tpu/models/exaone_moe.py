@@ -162,9 +162,8 @@ def step_pairs(kinds: tuple[str, ...], length: int, window: int) -> tuple[int, i
             keys = length if reach is None else min(length, reach)
             computed, seen = computed + keys, seen + keys
         elif reach is None:
-            computed += sum((stop - start) * (last - first)
-                            for start, stop, first, last in sequence.query_blocks(length, length))
-            seen += length * (length + 1) // 2
+            blocks = sequence.blocked_pairs(length, length)
+            computed, seen = computed + blocks[0], seen + blocks[1]
         else:
             blocks, back = band_blocks(length, window, window)
             computed += blocks * window * (back + 1) * window
@@ -203,15 +202,9 @@ def band_attention(q: jax.Array, k: jax.Array, v: jax.Array, window: int, cd, bl
 
 
 def blocked_attention(q: jax.Array, k: jax.Array, v: jax.Array, window: int | None, cd) -> jax.Array:
-    """Causal attention of the queries at the LAST `q.shape[1]` positions of
-    the keys' range in `sequence`'s blocks of queries: a full layer at all
-    positions, either kind at the last position alone. Shapes as `band_attention`."""
-    queries, keys, out = q.shape[1], k.shape[1], []
-    for start, stop, first, last in sequence.query_blocks(queries, keys, window):
-        scores = _product("nqgjd,nkgd->ngjqk", q[:, start:stop], k[:, first:last], cd) * q.shape[-1] ** -0.5
-        probs = sequence.causal_softmax(scores, keys - queries + start - first, window)
-        out.append(_product("ngjqk,nkgd->nqgjd", probs, v[:, first:last], cd))
-    return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
+    """`sequence.blocked_attention` at this family's pieces: a full layer at
+    all positions, either kind at the last position alone."""
+    return sequence.blocked_attention(q, k, v, window, cd, OPERAND_PIECES)
 
 
 def attention(p: dict, x: jax.Array, s: dict, kind: str, cd, eps: float, theta: float,

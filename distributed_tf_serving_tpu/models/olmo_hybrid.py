@@ -1,0 +1,390 @@
+"""olmo_hybrid: Olmo-Hybrid-7B (`model_type: olmo_hybrid`) as a pointwise
+sequence ranker, through the same Predict path and wire contract as
+`phi4flash`, `pangu_moe` and `exaone_moe`: a candidate row is `num_fields`
+token ids (`feat_ids [n, L]`, folded by `% vocab_size`), `feat_wts [n, L]`
+multiplies the token's embedding (`x0_t = w_t * E[id_t]`, float32 on the link
+and in the product), and `prediction_node [n]` is the sigmoid of one logit read
+at the last position, `s = w_score . RMS_final(h_L)`.
+
+The mixer differs BY LAYER, from a plan in the configuration (`layer_types`:
+`linear_attention` or `full_attention`, three to one as published). Every
+layer normalises each sub-layer's OUTPUT before the residual add and has no
+norm before it (OLMo 2, arXiv:2501.00656):
+
+  h = x + RMS_post_attn(mix(x));   y = h + RMS_post_ffn((silu(h W_g) * (h W_u)) W_d)
+
+full_attention: `q = x W_q, k = x W_k, v = x W_v`, no biases; `q <- RMS_q(q)`,
+`k <- RMS_k(k)` over the WHOLE projection width (one learned weight a column);
+no rotary; `scores = q k' / sqrt(d)`, position t sees every u <= t; query head
+h reads key-value head `h // (heads / kv)`; `mix = concat_h(softmax(scores) v) W_o`.
+`sequence.blocked_attention` computes it, as `exaone_moe`'s full layers.
+
+linear_attention, the gated delta rule (Gated DeltaNet, arXiv:2412.06464), H
+heads whose keys are dk wide and whose values dv:
+
+  q = x W_q [H x dk], k = x W_k [H x dk], v = x W_v [H x dv]
+  each <- silu(causal depthwise convolution, `linear_conv_kernel_dim` taps, no bias)
+  q <- q / sqrt(sum q^2 + 1e-6) / sqrt(dk),  k <- k / sqrt(sum k^2 + 1e-6)     per head
+  b_t = 2 sigmoid(x W_b) [H]      (the 2 is `linear_allow_neg_eigval`; without it b in (0, 1))
+  g_t = -exp(A_log) * softplus(x W_a + dt_bias) [H],   a_t = exp(g_t) in (0, 1)
+  S_0 = 0 [dk x dv] a head;  S_t = a_t (I - b_t k_t k_t') S_{t-1} + b_t k_t v_t';  o_t = S_t' q_t
+  o <- RMS_o(o) (one learned [dv] weight a layer) * silu(x W_gate);   mix = concat_h(o) W_o
+
+The state is a MATRIX a head, and position by position the rule is L dependent
+rank-one updates a row and layer. `gated_delta_rule` computes it a chunk of
+DELTA_CHUNK positions at a time, exactly (in real arithmetic): with `G_i` the
+running sum of g inside the chunk and `D_ij = exp(G_i - G_j)` for i >= j,
+
+  A = strict_lower(diag(b) (K K') * D);  T = (I + A)^-1 diag(b)      a unit lower triangular solve
+  W = T (K * exp(G));  U = T V                                        every chunk at once
+  for each chunk in order, S the state handed in:   V' = U - W S
+      O = (Q * exp(G)) S + lower((Q K') * D) V';   S <- exp(G_C) S + (K * exp(G_C - G))' V'
+
+so a row's dependent chain is one state hand-over a chunk (`delta.handovers`),
+not one a position. Every exponent is a difference G_i - G_j <= 0 under its
+mask: nothing overflows however fast a head decays. The rule takes the state
+it starts from and returns the one it ends in; the served step starts from
+none and drops the last (nothing keeps a state between requests).
+
+What the served step skips (exact): the score reads the last position, so the
+LAST layer's queries (a full layer) or its output gate and projection (a linear
+one), and its MLP, are computed there alone; its keys and values, or its rule,
+at all positions, and every layer before it at all positions. A row whose
+weights are all zero (a padded row) is zero at every position of every layer
+and is left out of every counter.
+
+Numerics as `phi4flash`: parameters and matmul operands in `compute_dtype`,
+float32 accumulation, residual, norms, convolution, gates, decays, softmax, the
+rule's state and its triangular solve; a float32 activation enters a product
+as OPERAND_PIECES = 2 pieces of the compute dtype, the products between
+activations inside the rule included.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+from . import routed, sequence
+from .base import Model, ModelConfig, register_model
+from .embeddings import embedding_init, field_embed
+from .routed import INIT_STD, gated_init, matrix, rms_norm
+
+# Pieces of the compute dtype a wider activation enters a product as: read at
+# every call (tests and the benchmark's readings replace it by name).
+OPERAND_PIECES = sequence.OPERAND_PIECES
+# What the rule's state is carried in from chunk to chunk; replaced by name as
+# OPERAND_PIECES is, to plant a state of the precision below.
+STATE_DTYPE = jnp.float32
+# Positions a step of the rule's chunk loop advances: one triangular solve and
+# one state hand-over a chunk.
+DELTA_CHUNK = 64
+L2_EPS = 1e-6  # under the root of the per-head L2 norm of q and k
+STEP_STATS = ("attn.scores_computed", "attn.scores_seen", "delta.rows", "delta.handovers", "delta.positions")
+KINDS = {"linear_attention": "linear", "full_attention": "full"}
+PERIOD = ("linear_attention",) * 3 + ("full_attention",)
+
+
+def layer_plan(config: ModelConfig) -> tuple[str, ...]:
+    """The mixer of every layer: `linear` or `full`."""
+    layers = config.num_hidden_layers
+    kinds = config.layer_types or (PERIOD * layers)[:layers]
+    if len(kinds) != layers or set(kinds) - set(KINDS):
+        raise ValueError(
+            f"layer_types {kinds}: one of {sorted(KINDS)} for each of num_hidden_layers {layers}")
+    return tuple(KINDS[kind] for kind in kinds)
+
+
+def _sizes(config: ModelConfig) -> dict:
+    heads, kv = config.num_attention_heads, config.num_key_value_heads
+    if kv <= 0 or heads % kv:
+        raise ValueError(f"num_key_value_heads {kv} of num_attention_heads {heads}: whole groups of query heads")
+    head = config.head_dim or config.embed_dim // heads
+    if head <= 0:
+        raise ValueError(f"head_dim {head}")
+    lin = config.linear_num_value_heads
+    if lin <= 0 or config.linear_num_key_heads != lin:
+        raise ValueError(
+            f"linear_num_key_heads {config.linear_num_key_heads}, linear_num_value_heads {lin}: "
+            "one key head a value head (no head is repeated)")
+    if min(config.linear_key_head_dim, config.linear_value_head_dim, config.linear_conv_kernel_dim) <= 0:
+        raise ValueError("linear_key_head_dim, linear_value_head_dim, linear_conv_kernel_dim: positive")
+    return {
+        "hidden": config.embed_dim, "inter": config.intermediate_size, "heads": heads, "kv": kv, "head": head,
+        "lin": lin, "dk": config.linear_key_head_dim, "dv": config.linear_value_head_dim,
+        "conv": config.linear_conv_kernel_dim, "neg": bool(config.linear_allow_neg_eigval),
+    }
+
+
+def _linear_init(rng, s: dict, dtype) -> dict:
+    """flash-linear-attention's `GatedDeltaNet`: `A` uniform in (0, 16), `dt`
+    log-uniform in (1e-3, 1e-1) and `dt_bias` its inverse softplus, so that a
+    step's decay spreads over about (0.2, 1); the depthwise convolutions as
+    torch's Conv1d draws them (uniform, bound 1 / sqrt(taps))."""
+    k_q, k_k, k_v, k_cq, k_ck, k_cv, k_b, k_a, k_A, k_dt, k_gate, k_o = jax.random.split(rng, 12)
+    hidden, keys, values, taps = s["hidden"], s["lin"] * s["dk"], s["lin"] * s["dv"], s["conv"]
+    bound = taps ** -0.5
+    conv = lambda k, width: jax.random.uniform(k, (width, taps), dtype, -bound, bound)  # noqa: E731
+    dt = jnp.exp(jax.random.uniform(k_dt, (s["lin"],)) * (math.log(0.1) - math.log(0.001)) + math.log(0.001))
+    return {
+        "q": matrix(k_q, (hidden, keys), dtype), "k": matrix(k_k, (hidden, keys), dtype),
+        "v": matrix(k_v, (hidden, values), dtype),
+        "conv_q": conv(k_cq, keys), "conv_k": conv(k_ck, keys), "conv_v": conv(k_cv, values),
+        "b": matrix(k_b, (hidden, s["lin"]), dtype), "a": matrix(k_a, (hidden, s["lin"]), dtype),
+        "A_log": jnp.log(jax.random.uniform(k_A, (s["lin"],), minval=1e-3, maxval=16.0)).astype(dtype),
+        "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
+        "gate": matrix(k_gate, (hidden, values), dtype), "o_norm": jnp.ones((s["dv"],), dtype),
+        "o": matrix(k_o, (values, hidden), dtype),
+    }
+
+
+def _layer_init(rng, kind: str, s: dict, dtype) -> dict:
+    k_mix, k_q, k_k, k_v, k_o, k_mlp = jax.random.split(rng, 6)
+    hidden, head = s["hidden"], s["head"]
+    ones = lambda width: jnp.ones((width,), dtype)  # noqa: E731
+    layer = {
+        "post_attn_norm": ones(hidden), "post_ffn_norm": ones(hidden),
+        "mlp": gated_init(k_mlp, (hidden, s["inter"]), (s["inter"], hidden), dtype),
+    }
+    if kind == "linear":
+        layer["linear"] = _linear_init(k_mix, s, dtype)
+    else:
+        layer["attn"] = {
+            "q": matrix(k_q, (hidden, s["heads"] * head), dtype), "q_norm": ones(s["heads"] * head),
+            "k": matrix(k_k, (hidden, s["kv"] * head), dtype), "k_norm": ones(s["kv"] * head),
+            "v": matrix(k_v, (hidden, s["kv"] * head), dtype),
+            "o": matrix(k_o, (s["heads"] * head, hidden), dtype),
+        }
+    return layer
+
+
+# ---------------------------------------------------------------------------
+# Building blocks
+# ---------------------------------------------------------------------------
+
+
+def _product(spec: str, x: jax.Array, y: jax.Array, cd) -> jax.Array:
+    """einsum(spec, x, y) as `sequence.product`, at this family's pieces."""
+    return sequence.product(spec, x, y, cd, OPERAND_PIECES)
+
+
+def _dot(x: jax.Array, w: jax.Array, cd) -> jax.Array:
+    """`routed.dot` at this family's pieces."""
+    return routed.dot(x, w, cd, OPERAND_PIECES)
+
+
+def l2_norm(x: jax.Array) -> jax.Array:
+    """x over its last axis' length, `x / sqrt(sum x^2 + 1e-6)`."""
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
+
+
+def delta_chunks(length: int, chunk: int = DELTA_CHUNK) -> tuple[int, int]:
+    """(positions a step of the rule's loop advances over rows of `length`,
+    the steps it takes a row: its state hand-overs)."""
+    chunk = max(1, min(chunk, length))
+    return chunk, -(-length // chunk)
+
+
+def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, b: jax.Array,
+                     initial_state: jax.Array | None = None, *, chunk: int = DELTA_CHUNK,
+                     cd=jnp.float32) -> tuple[jax.Array, jax.Array]:
+    """The gated delta rule over rows of L positions, chunked (the module's
+    docstring has the algebra):
+
+      S_t = exp(g_t) (I - b_t k_t k_t') S_{t-1} + b_t k_t v_t';   o_t = S_t' q_t
+
+    `q`, `k [n, L, H, dk]` (normalised and scaled by the caller), `v [n, L, H, dv]`,
+    `g`, `b [n, L, H]` (g <= 0), all float32; `initial_state [n, H, dk, dv]` is
+    S before the first position (zero where None). Returns `o [n, L, H, dv]`
+    and the state after the last position, float32. A length that is no
+    multiple of the chunk is padded with k = v = 0, b = 0, g = 0, which leave
+    the state as it is. The caller's `delta_rule` scope."""
+    n, length, heads, dk = q.shape
+    dv = v.shape[-1]
+    chunk, steps = delta_chunks(length, chunk)
+    pad = steps * chunk - length
+
+    def chunks(x):  # [n, L, H, ...] -> [n, steps, H, chunk, ...]: a head's chunk is one matrix
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        return jnp.moveaxis(x.reshape((n, steps, chunk) + x.shape[2:]), 3, 2)
+
+    q, k, v, g, b = (chunks(x) for x in (q, k, v, g, b))
+    with jax.named_scope("solve"):
+        total = jnp.cumsum(g, axis=-1)  # G, the running sum inside a chunk [n, Z, H, C]
+        i, j = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None, :]
+        # D_ij = exp(G_i - G_j) where i >= j, else 0: from the difference under the mask
+        decay = jnp.exp(jnp.where(j <= i, total[..., :, None] - total[..., None, :], -jnp.inf))
+        a = jnp.where(j < i, b[..., :, None] * _product("nzhid,nzhjd->nzhij", k, k, cd) * decay, 0.0)
+        # T = (I + A)^-1 diag(b): the unit diagonal is taken as read, not read
+        t = jax.lax.linalg.triangular_solve(
+            a, jnp.eye(chunk, dtype=jnp.float32) * b[..., None, :], left_side=True, lower=True,
+            unit_diagonal=True)
+        grown = jnp.exp(total)[..., None]  # exp(G_i) [n, Z, H, C, 1]
+        w = _product("nzhij,nzhjd->nzhid", t, k * grown, cd)
+        u = _product("nzhij,nzhje->nzhie", t, v, cd)
+        within = _product("nzhid,nzhjd->nzhij", q, k, cd) * decay  # lower((Q K') * D)
+        left = total[..., -1:]  # G_C [n, Z, H, 1]
+        xs = (w, u, q * grown, within, k * jnp.exp(left - total)[..., None], jnp.exp(left)[..., None])
+
+    def body(state, x):
+        w_z, u_z, q_z, within_z, k_z, decay_z = x
+        state = state.astype(jnp.float32)
+        fresh = u_z - _product("nhid,nhde->nhie", w_z, state, cd)  # V'
+        o = _product("nhid,nhde->nhie", q_z, state, cd) + _product("nhij,nhje->nhie", within_z, fresh, cd)
+        state = decay_z * state + _product("nhid,nhie->nhde", k_z, fresh, cd)
+        return state.astype(STATE_DTYPE), o
+
+    with jax.named_scope("chunks"):
+        state = jnp.zeros((n, heads, dk, dv), jnp.float32) if initial_state is None else initial_state
+        state, o = jax.lax.scan(body, state.astype(STATE_DTYPE), tuple(jnp.moveaxis(x, 1, 0) for x in xs))
+    o = jnp.moveaxis(o, (0, 2), (1, 3)).reshape(n, steps * chunk, heads, dv)  # [Z, n, H, C, dv] -> [n, Z, C, H, dv]
+    return o[:, :length], state.astype(jnp.float32)
+
+
+def out_gate(p: dict, o: jax.Array, x: jax.Array, cd, eps: float) -> jax.Array:
+    """The rule's output `o [n, L, H, dv]`, normalised a head and gated by the
+    layer's input `x [n, L, hidden]`: `RMS_o(o) * silu(x W_gate)`."""
+    return rms_norm(p["o_norm"], o, eps) * jax.nn.silu(_dot(x, p["gate"], cd)).reshape(o.shape)
+
+
+def qk_norm(p: dict, q: jax.Array, k: jax.Array, eps: float) -> tuple[jax.Array, jax.Array]:
+    """A full layer's queries and keys `[n, L, heads x d]`, each normalised
+    over its WHOLE width, not a head's."""
+    return rms_norm(p["q_norm"], q, eps), rms_norm(p["k_norm"], k, eps)
+
+
+def linear_attention(p: dict, x: jax.Array, s: dict, cd, eps: float, last_only: bool = False) -> jax.Array:
+    """One layer's gated delta rule of `x [n, L, H]`: `[n, L, H]`, or
+    `[n, 1, H]` where the last position's output alone is asked for (the rule
+    still runs over every position). The caller's `linear_attn` scope."""
+    n, length, _ = x.shape
+    heads, dk, dv = s["lin"], s["dk"], s["dv"]
+    with jax.named_scope("qkv"):
+        q, k, v = _dot(x, p["q"], cd), _dot(x, p["k"], cd), _dot(x, p["v"], cd)
+    with jax.named_scope("conv"):
+        q, k, v = (sequence.causal_conv(y, p[name]) for y, name in ((q, "conv_q"), (k, "conv_k"), (v, "conv_v")))
+        q = l2_norm(q.reshape(n, length, heads, dk)) * dk ** -0.5
+        k = l2_norm(k.reshape(n, length, heads, dk))
+        v = v.reshape(n, length, heads, dv)
+    with jax.named_scope("gates"):
+        b = jax.nn.sigmoid(_dot(x, p["b"], cd)) * (2.0 if s["neg"] else 1.0)
+        g = -jnp.exp(p["A_log"].astype(jnp.float32)) * jax.nn.softplus(
+            _dot(x, p["a"], cd) + p["dt_bias"].astype(jnp.float32))
+    with jax.named_scope("delta_rule"):
+        o, _ = gated_delta_rule(q, k, v, g, b, cd=cd)
+    if last_only:
+        x, o = sequence.last_position(x, o)
+    with jax.named_scope("out_gate"):
+        o = out_gate(p, o, x, cd, eps)
+    return _dot(o.reshape(n, -1, heads * dv), p["o"], cd)
+
+
+def full_attention(p: dict, x: jax.Array, s: dict, cd, eps: float, last_only: bool = False) -> jax.Array:
+    """One layer's full causal attention of `x [n, L, H]`: `[n, L, H]`, or
+    `[n, 1, H]` for the last position's query alone against the keys and
+    values of every position. The caller's `attn_full` scope."""
+    n, length, _ = x.shape
+    heads, kv, head = s["heads"], s["kv"], s["head"]
+    at = sequence.last_position(x) if last_only else x
+    with jax.named_scope("qkv"):
+        q, k, v = _dot(at, p["q"], cd), _dot(x, p["k"], cd), _dot(x, p["v"], cd)
+    with jax.named_scope("qk_norm"):
+        q, k = qk_norm(p, q, k, eps)
+    with jax.named_scope("softmax"):
+        o = sequence.blocked_attention(
+            q.reshape(n, -1, kv, heads // kv, head), k.reshape(n, length, kv, head), v.reshape(n, length, kv, head),
+            None, cd, OPERAND_PIECES)
+    return _dot(o.reshape(n, -1, heads * head), p["o"], cd)
+
+
+def step_counts(plan: tuple[str, ...], length: int) -> tuple[int, ...]:
+    """STEP_STATS a live row, from the shapes: the (query, key) pairs the full
+    layers' tiles compute and those their masks keep (the last layer's one
+    query where it is a full one; every head computes the same pairs), 1, and
+    the state hand-overs and the positions of the linear layers' chunk loops."""
+    computed = seen = 0
+    for i, kind in enumerate(plan):
+        if kind == "full":
+            pairs = sequence.blocked_pairs(1 if i == len(plan) - 1 else length, length)
+            computed, seen = computed + pairs[0], seen + pairs[1]
+    linear = plan.count("linear")
+    return computed, seen, 1, linear * delta_chunks(length)[1], linear * length
+
+
+def forward(config: ModelConfig, params, batch) -> tuple[jax.Array, jax.Array]:
+    """(the logit of every row, the step's counters): of the last layer, what
+    follows its mixing along the positions at the last position alone."""
+    s, cd, eps = _sizes(config), config.cdtype, config.layer_norm_eps
+    plan = layer_plan(config)
+    with jax.named_scope("embed"):
+        # The weighted embedding in float32, where a bfloat16 row times a
+        # float32 weight is exact.
+        x = field_embed(params["embedding"], batch["feat_ids"], batch["feat_wts"], jnp.float32, s["hidden"])
+        live = jnp.any(batch["feat_wts"] != 0, axis=1)  # a padded row is zero throughout
+    for i, (kind, layer) in enumerate(zip(plan, params["layers"])):
+        last = i == len(plan) - 1
+        if kind == "linear":
+            with jax.named_scope("linear_attn"):
+                mix = linear_attention(layer["linear"], x, s, cd, eps, last)
+        else:
+            with jax.named_scope("attn_full"):
+                mix = full_attention(layer["attn"], x, s, cd, eps, last)
+        if last:
+            x = sequence.last_position(x)
+        h = x + rms_norm(layer["post_attn_norm"], mix, eps)
+        with jax.named_scope("mlp"):
+            x = h + rms_norm(layer["post_ffn_norm"], routed.gated_mlp(layer["mlp"], h, cd, OPERAND_PIECES), eps)
+    with jax.named_scope("score"):
+        final = rms_norm(params["final_norm"], x[:, -1], eps)
+        counts = jnp.asarray(step_counts(plan, batch["feat_ids"].shape[1]), jnp.int32)
+        return (jnp.sum(final * params["score"].astype(jnp.float32), axis=-1),
+                jnp.sum(live, dtype=jnp.int32) * counts)
+
+
+def attention_plan(config: ModelConfig) -> tuple[tuple[tuple[str, object], ...], ...]:
+    """Each layer's mixer as (name, value) pairs: a full layer's kind, window,
+    block of queries and keys a block, as `exaone_moe`'s; a linear layer's
+    chunk, the state hand-overs a row and the bytes of a row's state."""
+    s, length, out = _sizes(config), config.num_fields, []
+    chunk, steps = delta_chunks(length)
+    for kind in layer_plan(config):
+        if kind == "linear":
+            out.append((("kind", kind), ("chunk", chunk), ("handovers_a_row", steps),
+                        ("state_bytes_a_row", s["lin"] * s["dk"] * s["dv"] * 4)))
+        else:
+            out.append((("kind", kind), ("window", 0), ("block", min(sequence.ATTN_BLOCK, length)),
+                        ("keys_a_block", length)))
+    return tuple(out)
+
+
+@register_model("olmo_hybrid")
+def build_olmo_hybrid(config: ModelConfig) -> Model:
+    s = _sizes(config)
+    plan = layer_plan(config)
+
+    def init(rng, packed: bool = False):
+        k_emb, k_score, *k_layers = jax.random.split(rng, 2 + len(plan))
+        dtype = config.pdtype
+        # embedding_init scales by 1/sqrt(dim); INIT_STD is wanted.
+        table = embedding_init(k_emb, config.vocab_size, s["hidden"], dtype, packed)
+        return {
+            "embedding": table * jnp.asarray(INIT_STD * s["hidden"] ** 0.5, dtype),
+            "layers": [_layer_init(k, kind, s, dtype) for k, kind in zip(k_layers, plan)],
+            "final_norm": jnp.ones((s["hidden"],), dtype),
+            "score": matrix(k_score, (s["hidden"],), dtype),
+        }
+
+    def apply_stats(params, batch):
+        logits, stats = forward(config, params, batch)
+        return {"prediction_node": jax.nn.sigmoid(logits), "logits": logits}, stats
+
+    def apply(params, batch):
+        return apply_stats(params, batch)[0]
+
+    # The weights cross as float32, as phi4flash's and for its reason: a
+    # token's weight scales its embedding in the residual stream.
+    return Model(
+        config=config, init=init, apply=apply, wts_in_compute_dtype=False, layer_plan=plan,
+        attention_plan=attention_plan(config), apply_stats=apply_stats, step_stats=STEP_STATS)

@@ -255,14 +255,10 @@ def selective_scan(u, delta, a, b, c, chunk: int = SCAN_CHUNK):
 def _mamba(p: dict, x: jax.Array, s: dict, cd) -> tuple[jax.Array, jax.Array]:
     """(mix [n, L, H], the scan's output before the gate [n, L, Di])."""
     with jax.named_scope("ssm"):
-        inner, state, rank, width = s["inner"], s["state"], s["dt_rank"], s["conv"]
+        inner, state, rank = s["inner"], s["state"], s["dt_rank"]
         uz = _dot(x, p["in_proj"], cd)
         u, z = uz[..., :inner], uz[..., inner:]
-        # Causal depthwise convolution: tap k reads position t - (width-1) + k.
-        w = p["conv_w"].astype(jnp.float32)
-        padded = jnp.pad(u, ((0, 0), (width - 1, 0), (0, 0)))
-        u = sum(padded[:, k:k + u.shape[1]] * w[:, k] for k in range(width))
-        u = jax.nn.silu(u + p["conv_b"].astype(jnp.float32))
+        u = sequence.causal_conv(u, p["conv_w"], p["conv_b"])
         proj = _dot(u, p["x_proj"], cd)
         dt, b, c = proj[..., :rank], proj[..., rank:rank + state], proj[..., rank + state:]
         delta = jax.nn.softplus(_dot(dt, p["dt_proj"], cd) + p["dt_bias"].astype(jnp.float32))

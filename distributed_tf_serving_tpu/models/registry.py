@@ -199,8 +199,10 @@ class Servable:
 
     @property
     def attention_plan(self) -> list[dict] | None:
-        """Each layer's attention where a family's differs by layer: kind,
-        window, block of queries, keys a block. None for every other family."""
+        """Each layer's mixer where a family's differs by layer: an attention
+        layer's kind, window, block of queries, keys a block; a linear
+        layer's kind, chunk, hand-overs and state bytes a row. None for every
+        other family."""
         return [dict(layer) for layer in self.model.attention_plan] or None
 
     @property

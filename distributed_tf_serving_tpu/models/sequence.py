@@ -1,10 +1,13 @@
-"""What the sequence-ranker families (phi4flash, pangu_moe, exaone_moe) share: products
-whose float32 activations enter as pieces of the compute dtype (`product`: one
-product a call wherever a form exists that copies no large array), the causal
-softmax of a block of queries, the blocks themselves, and the cut to the last
-position. One implementation, so that a change to any of them is measured on
-every family's cell. (`models/routed.py` has what the two routed families
-share beside these.)
+"""What the sequence-ranker families (phi4flash, pangu_moe, exaone_moe,
+olmo_hybrid) share: products whose float32 activations enter as pieces of the
+compute dtype (`product`: one product a call wherever a form exists that
+copies no large array), the causal softmax of a block of queries, the blocks
+themselves, full causal attention in those blocks (`blocked_attention`:
+exaone_moe's and olmo_hybrid's full layers), the causal depthwise convolution
+(`causal_conv`: phi4flash's Mamba layers and olmo_hybrid's linear ones), and
+the cut to the last position. One implementation, so that a change to any of
+them is measured on every family's cell. (`models/routed.py` has what the
+routed families share beside these.)
 
 A family keeps its own `OPERAND_PIECES` and a `_product` of four arguments
 that hands it on (its tests and the benchmark's precision readings replace
@@ -146,6 +149,43 @@ def query_blocks(queries: int, keys: int, window: int | None = None, block: int 
         stop = min(start + block, queries)
         first = 0 if window is None else max(0, offset + start - window + 1)
         yield start, stop, first, offset + stop
+
+
+def blocked_pairs(queries: int, keys: int, window: int | None = None) -> tuple[int, int]:
+    """((query, key) pairs the tiles of `blocked_attention` compute over a row,
+    those its masks keep) for the last `queries` positions of `keys`."""
+    computed = sum((stop - start) * (last - first) for start, stop, first, last in query_blocks(queries, keys, window))
+    offset = keys - queries
+    seen = sum(min(offset + t + 1, window or keys) for t in range(queries))
+    return computed, seen
+
+
+def blocked_attention(q: jax.Array, k: jax.Array, v: jax.Array, window: int | None, cd,
+                      count: int = OPERAND_PIECES) -> jax.Array:
+    """Causal attention of the queries at the LAST `q.shape[1]` positions of
+    the keys' range in blocks of ATTN_BLOCK queries, each against the keys its
+    causal reach (and its window's, where one is given) holds: a full layer at
+    all positions, any layer at the last position alone. `q [n, Lq, G, J, d]`
+    (J query heads a key-value head), `k`, `v [n, Lk, G, d]`; returns
+    `[n, Lq, G, J, d]` float32. Activations enter as `count` pieces."""
+    queries, keys, out = q.shape[1], k.shape[1], []
+    for start, stop, first, last in query_blocks(queries, keys, window):
+        scores = product("nqgjd,nkgd->ngjqk", q[:, start:stop], k[:, first:last], cd, count) * q.shape[-1] ** -0.5
+        probs = causal_softmax(scores, keys - queries + start - first, window)
+        out.append(product("ngjqk,nkgd->nqgjd", probs, v[:, first:last], cd, count))
+    return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
+
+
+def causal_conv(x: jax.Array, w: jax.Array, b: jax.Array | None = None) -> jax.Array:
+    """silu of the causal depthwise convolution of `x [n, L, channels]` along
+    its positions, float32: `w [channels, taps]`, tap k reads position
+    t - (taps - 1) + k, so position t reads t - taps + 1 .. t and nothing
+    ahead of it; `b [channels]` is added before the silu where given."""
+    taps = w.shape[1]
+    w = w.astype(jnp.float32)
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    y = sum(padded[:, k:k + x.shape[1]] * w[:, k] for k in range(taps))
+    return jax.nn.silu(y if b is None else y + b.astype(jnp.float32))
 
 
 def last_position(*arrays: jax.Array):

@@ -1,0 +1,566 @@
+"""The olmo_hybrid family (Olmo-Hybrid-7B as a pointwise sequence ranker: three
+gated-delta-rule layers, whose state is a matrix a head, to one full-attention
+layer with whole-width query and key norms) at tiny widths on the CPU: against
+the benchmark's plain reference through `model.apply` and down the served
+path, the chunked rule against the position-by-position loop, a row split with
+its state handed over, the extremes of decay and b, the convolution's
+causality, the full layer's blocks, the last-position cut, a padded row, what
+the benchmark's tolerance catches, the step's counters and how they reach
+`/monitoring`, the plan stamps, the shapes at the published cut, and the plans
+that are refused."""
+
+import dataclasses
+import importlib.util
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_tf_serving_tpu import native
+from distributed_tf_serving_tpu.models import ModelConfig, build_model, olmo_hybrid, routed, sequence
+from distributed_tf_serving_tpu.utils.config import load_config
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CONFIG_DIR = os.path.join(ROOT, "benchmark", "configs", "olmo_hybrid_rerank")
+LIN, FULL = "linear_attention", "full_attention"
+LENGTH, HEAD = 75, 16  # no multiple of any chunk tried below
+HEADS, DK, DV = 3, 8, 12  # the linear layers': dk != dv
+
+
+def tiny_config(**overrides) -> ModelConfig:
+    return ModelConfig(**{
+        "name": "M", "num_fields": LENGTH, "vocab_size": 1000, "embed_dim": 64, "intermediate_size": 96,
+        "num_hidden_layers": 4, "layer_types": (LIN, LIN, LIN, FULL), "num_attention_heads": 4,
+        "num_key_value_heads": 2, "head_dim": HEAD, "layer_norm_eps": 1e-6, "linear_num_key_heads": HEADS,
+        "linear_num_value_heads": HEADS, "linear_key_head_dim": DK, "linear_value_head_dim": DV,
+        "compute_dtype": "float32", **overrides,
+    })
+
+
+def rows(n: int, config: ModelConfig, seed: int = 3, folded: bool = True) -> dict:
+    rng = np.random.default_rng(seed)
+    ids = rng.integers(0, 1 << 40, size=(n, config.num_fields), dtype=np.int64)
+    return {
+        "feat_ids": (ids % config.vocab_size).astype(np.int32) if folded else ids,
+        "feat_wts": rng.random((n, config.num_fields), dtype=np.float32),
+    }
+
+
+def unit_gain(params, config: ModelConfig, seed: int = 0):
+    """The tree with its matrices scaled so that a product keeps a unit input
+    at the size it has at the published width of 3840 (gates, b and a score
+    logit that spread, not ones that sit at their middle), and every norm
+    weight drawn around 1, so that a norm left out or misplaced shows."""
+    gain = (3840 / config.embed_dim) ** 0.5
+    rng = np.random.default_rng(seed)
+
+    def scale(path, leaf):
+        name = path[-1].key if hasattr(path[-1], "key") else ""
+        if name == "embedding" or name.startswith("conv") or name in ("A_log", "dt_bias"):
+            return leaf
+        if name.endswith("norm"):
+            return (leaf * (1.0 + 0.2 * rng.standard_normal(leaf.shape))).astype(leaf.dtype)
+        return leaf * gain
+
+    return jax.tree_util.tree_map_with_path(scale, params)
+
+
+def load(name: str):
+    spec = importlib.util.spec_from_file_location(f"olmo_{name}", os.path.join(CONFIG_DIR, name + ".py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.fixture(scope="module")
+def reference():
+    return load("reference")
+
+
+@pytest.fixture(scope="module")
+def tolerance():
+    with open(os.path.join(CONFIG_DIR, "config.json")) as f:
+        return float(json.load(f)["tolerance"])
+
+
+def reference_scores(reference, params, batch, config, what="forward"):
+    sizes = {"layer_types": config.layer_types, "head": config.head_dim, "eps": config.layer_norm_eps,
+             "neg_eigval": config.linear_allow_neg_eigval}
+    with jax.default_matmul_precision("highest"):
+        return np.asarray(jax.jit(lambda p, b: getattr(reference, what)(p, b, **sizes))(params, batch))
+
+
+def rule_inputs(n, length, seed=0):
+    """q, k (unit length), v, g <= 0 and b in (0, 2) of the rule, float32."""
+    rng = np.random.default_rng(seed)
+    draw = lambda *shape: rng.standard_normal(shape).astype(np.float32)  # noqa: E731
+    k = draw(n, length, HEADS, DK)
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    return (draw(n, length, HEADS, DK) / DK ** 0.5, k, draw(n, length, HEADS, DV),
+            -np.abs(draw(n, length, HEADS)), (2 * rng.random((n, length, HEADS))).astype(np.float32))
+
+
+def rule_by_position(q, k, v, g, b, state=None):
+    """S_t = a_t (I - b_t k_t k_t') S_{t-1} + b_t k_t v_t', o_t = S_t' q_t, in
+    float64, a position at a time."""
+    q, k, v, g, b = (np.asarray(x, np.float64) for x in (q, k, v, g, b))
+    n, length, heads, dk = q.shape
+    state = np.zeros((n, heads, dk, v.shape[-1])) if state is None else np.asarray(state, np.float64)
+    out = []
+    for t in range(length):
+        a_t, b_t, k_t = np.exp(g[:, t])[..., None, None], b[:, t][..., None, None], k[:, t]
+        read = np.einsum("nhd,nhde->nhe", k_t, state)
+        state = a_t * (state - b_t * k_t[..., :, None] * read[..., None, :])
+        state = state + b_t * k_t[..., :, None] * v[:, t][..., None, :]
+        out.append(np.einsum("nhde,nhd->nhe", state, q[:, t]))
+    return np.stack(out, axis=1), state
+
+
+# ------------------------------------------------- the family and the reference
+
+
+@pytest.mark.parametrize("kinds,length,limit", [
+    ((LIN, LIN, LIN, FULL), 75, 2e-5), ((LIN, LIN, LIN, FULL) * 2, 130, 3e-4), ((LIN, FULL, LIN), 20, 2e-5),
+    ((FULL, LIN), 9, 2e-5), ((LIN,), 5, 2e-5), ((FULL, FULL), 33, 2e-5)])
+def test_float32_logits_match_the_plain_reference(reference, kinds, length, limit):
+    """Through `model.apply`; the reference computes every layer at every
+    position and the rule a position at a time, the family the rule in chunks
+    and what follows the last layer's mixing at the last position alone: exact
+    under either kind of last layer, at a length that is no multiple of the
+    chunk, over several chunks and where the row is shorter than one. (Two
+    periods under these gains read 1e-4: float32 itself. Each of the two stands
+    1.8e-5 from the same stack in float64, and eight layers carry that on.)"""
+    config = tiny_config(num_hidden_layers=len(kinds), layer_types=kinds, num_fields=length)
+    model = build_model("olmo_hybrid", config)
+    params = unit_gain(jax.jit(model.init)(jax.random.PRNGKey(7)), config)
+    batch = rows(5, config)
+    want = reference_scores(reference, params, batch, config, "logits")
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(jax.jit(model.apply)(params, batch)["logits"])
+    assert want.shape == got.shape == (5,) and want.std() > 0.3
+    assert np.max(np.abs(want - got)) < limit
+
+
+@pytest.mark.parametrize("kind", ["linear", "full"])
+def test_the_last_layers_cut_is_the_whole_layers_last_position(reference, kind):
+    config = tiny_config()
+    s = olmo_hybrid._sizes(config)
+    layer = unit_gain(jax.jit(build_model("olmo_hybrid", config).init)(jax.random.PRNGKey(2)), config)["layers"]
+    x = jnp.asarray(np.random.default_rng(0).standard_normal((3, LENGTH, 64)), jnp.float32)
+    mix, p = ((olmo_hybrid.linear_attention, layer[1]["linear"]) if kind == "linear"
+              else (olmo_hybrid.full_attention, layer[3]["attn"]))
+    with jax.default_matmul_precision("highest"):
+        whole = mix(p, x, s, jnp.float32, 1e-6)
+        last = mix(p, x, s, jnp.float32, 1e-6, last_only=True)
+        want = reference.linear_attention(p, x) if kind == "linear" else reference.full_attention(p, x, HEAD)
+    assert last.shape == (3, 1, 64)
+    np.testing.assert_allclose(np.asarray(last), np.asarray(whole[:, -1:]), rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(whole), np.asarray(want), rtol=1e-4, atol=1e-5)
+
+
+# --------------------------------------------------------- the gated delta rule
+
+
+@pytest.mark.parametrize("chunk", [1, 16, 64, LENGTH, 200])
+def test_the_chunked_rule_is_the_position_by_position_loop(chunk):
+    """At chunks of 1 (the loop itself), 16, 64 (the served one; the row is
+    one chunk and a part) and the whole row: outputs and the last state."""
+    q, k, v, g, b = rule_inputs(2, LENGTH)
+    want, state = rule_by_position(q, k, v, g, b)
+    with jax.default_matmul_precision("highest"):
+        got, last = olmo_hybrid.gated_delta_rule(*map(jnp.asarray, (q, k, v, g, b)), chunk=chunk)
+    assert got.shape == (2, LENGTH, HEADS, DV) and last.shape == (2, HEADS, DK, DV)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(last), state, rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("cut", [1, 16, 37, 64, 74])
+def test_a_row_split_anywhere_with_its_state_handed_over_is_the_whole_row(cut):
+    arrays = [jnp.asarray(x) for x in rule_inputs(2, LENGTH, seed=cut)]
+    with jax.default_matmul_precision("highest"):
+        whole, state = olmo_hybrid.gated_delta_rule(*arrays, chunk=16)
+        head, handed = olmo_hybrid.gated_delta_rule(*(x[:, :cut] for x in arrays), chunk=16)
+        tail, last = olmo_hybrid.gated_delta_rule(*(x[:, cut:] for x in arrays), handed, chunk=16)
+    np.testing.assert_allclose(np.asarray(jnp.concatenate([head, tail], axis=1)), np.asarray(whole), rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(last), np.asarray(state), rtol=1e-4, atol=1e-5)
+    assert float(jnp.max(jnp.abs(handed))) > 0.1  # a state worth handing over
+
+
+@pytest.mark.parametrize("g,b", [(-80.0, 2.0), (-1e4, 1.0), (0.0, 2.0), (-1e-6, 1.9999), (-30.0, 0.0)])
+def test_decays_near_zero_and_b_near_two_stay_finite(g, b):
+    """exp(-G_j) alone overflows float32 past a running sum of 88: every
+    exponent the rule takes is a difference under its mask. b = 2 on one key
+    repeated is the reflection (I - 2 k k'), whose powers neither grow nor
+    die; the loop in float64 agrees."""
+    q, k, v, _, _ = rule_inputs(1, 130, seed=5)
+    k[:, 64:] = k[:, 64:65]  # one key repeated over a chunk and more
+    gs, bs = np.full((1, 130, HEADS), g, np.float32), np.full((1, 130, HEADS), b, np.float32)
+    want, state = rule_by_position(q, k, v, gs, bs)
+    with jax.default_matmul_precision("highest"):
+        got, last = olmo_hybrid.gated_delta_rule(*map(jnp.asarray, (q, k, v, gs, bs)))
+    assert np.isfinite(np.asarray(got)).all() and np.isfinite(np.asarray(last)).all()
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-2, atol=2e-3)
+    np.testing.assert_allclose(np.asarray(last), state, rtol=1e-2, atol=2e-3)
+
+
+def test_the_convolution_sees_nothing_ahead_of_its_position():
+    """Another input from position 40 on moves no output before it; tap j
+    reads position t - 3 + j; a bias is added before the silu."""
+    rng = np.random.default_rng(0)
+    x = rng.standard_normal((2, 60, 10)).astype(np.float32)
+    w, bias = rng.standard_normal((10, 4)).astype(np.float32), rng.standard_normal(10).astype(np.float32)
+    moved = x.copy()
+    moved[:, 40:] += 1.0
+    a, b = np.asarray(sequence.causal_conv(x, w)), np.asarray(sequence.causal_conv(moved, w))
+    np.testing.assert_array_equal(a[:, :40], b[:, :40])
+    assert np.all(np.abs(a[:, 40:44] - b[:, 40:44]).max(axis=(0, 2)) > 1e-3)
+    padded = np.pad(x, ((0, 0), (3, 0), (0, 0)))
+    want = sum(padded[:, j:j + 60] * w[:, j] for j in range(4))
+    np.testing.assert_allclose(a, np.asarray(jax.nn.silu(want)), rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(sequence.causal_conv(x, w, bias)), np.asarray(jax.nn.silu(want + bias)),
+                               rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("queries", [LENGTH, 1])
+def test_the_full_layer_through_sequences_blocks_is_the_dense_masked_softmax(queries, monkeypatch):
+    monkeypatch.setattr(sequence.query_blocks, "__defaults__", (None, 16))  # five blocks, the last one short
+    rng = np.random.default_rng(4)
+    draw = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)  # noqa: E731
+    q, k, v = draw(2, LENGTH, 2, 2, HEAD), draw(2, LENGTH, 2, HEAD), draw(2, LENGTH, 2, HEAD)
+    with jax.default_matmul_precision("highest"):
+        got = sequence.blocked_attention(q[:, LENGTH - queries:], k, v, None, jnp.float32, 2)
+    t = np.arange(LENGTH)
+    scores = np.einsum("nqgjd,nkgd->ngjqk", np.asarray(q, np.float64), np.asarray(k, np.float64)) / np.sqrt(HEAD)
+    e = np.where(t[None, :] <= t[:, None], np.exp(scores - scores.max(-1, keepdims=True)), 0.0)
+    want = np.einsum("ngjqk,nkgd->nqgjd", e / e.sum(-1, keepdims=True), np.asarray(v, np.float64))
+    np.testing.assert_allclose(np.asarray(got), want[:, LENGTH - queries:], rtol=1e-4, atol=1e-5)
+    assert sequence.blocked_pairs(queries, LENGTH) == (
+        (sum(min(s + 16, LENGTH) * (min(s + 16, LENGTH) - s) for s in range(0, LENGTH, 16)), LENGTH * (LENGTH + 1) // 2)
+        if queries == LENGTH else (LENGTH, LENGTH))
+
+
+def test_a_row_of_zero_weights_is_zero_throughout_and_in_no_counter():
+    config = tiny_config()
+    model = build_model("olmo_hybrid", config)
+    params = unit_gain(jax.jit(model.init)(jax.random.PRNGKey(4)), config)
+    batch = rows(3, config)
+    padded = {k: np.concatenate([v, np.zeros_like(v[:1])]) for k, v in batch.items()}
+    step = jax.jit(model.apply_stats)
+    (out, stats), (out_padded, stats_padded) = step(params, batch), step(params, padded)
+    np.testing.assert_array_equal(np.asarray(out_padded["logits"][:3]), np.asarray(out["logits"]))
+    assert stats_padded.tolist() == stats.tolist() and float(out_padded["logits"][3]) == 0.0
+
+
+# ------------------------------------------------------------------ counters
+
+
+def test_the_steps_counters_are_a_numpy_count():
+    """Three linear layers and a full last layer over 75 positions, 4 rows:
+    the full layer's one query reads 75 keys; each linear layer hands its
+    state over twice a row (chunks of 64) and advances 75 positions."""
+    config = tiny_config()
+    model = build_model("olmo_hybrid", config)
+    params = jax.jit(model.init)(jax.random.PRNGKey(9))
+    _, stats = jax.jit(model.apply_stats)(params, rows(4, config))
+    assert model.step_stats == (
+        "attn.scores_computed", "attn.scores_seen", "delta.rows", "delta.handovers", "delta.positions")
+    assert stats.tolist() == [4 * 75, 4 * 75, 4, 4 * 3 * 2, 4 * 3 * 75]
+    # a full layer that is not the last: every block's tile, and the causal half kept
+    plan = ("linear", "full", "linear", "full")
+    t = np.arange(150)
+    computed = sum(int((t < min(start + 64, 150)).sum()) * (min(start + 64, 150) - start) for start in range(0, 150, 64))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(sequence.query_blocks, "__defaults__", (None, 64))
+        assert olmo_hybrid.step_counts(plan, 150) == (computed + 150, 150 * 151 // 2 + 150, 1, 2 * 3, 2 * 150)
+
+
+def test_the_published_rows_counts_are_what_the_readers_will_divide():
+    """2,048 positions, two periods: 192 hand-overs a row; the first full
+    layer in blocks of 512 computes 2,621,440 pairs and keeps 2,098,176, the
+    last one's query 2,048: 20.0% masked."""
+    plan = ("linear", "linear", "linear", "full") * 2
+    assert olmo_hybrid.step_counts(plan, 2048) == (2_621_440 + 2048, 2048 * 2049 // 2 + 2048, 1, 192, 6 * 2048)
+    assert olmo_hybrid.delta_chunks(2048) == (64, 32) and olmo_hybrid.delta_chunks(150) == (64, 3)
+    assert olmo_hybrid.delta_chunks(40) == (40, 1)
+
+
+# ---------------------------------------------------------------- precision
+
+
+@pytest.fixture(scope="module")
+def served_precision(reference):
+    """bfloat16 weights and compute as served, rows of three chunks and a
+    part, and the float32 reference's scores. Wider than the other tests'
+    stack: this one carries a rounding on the less the wider it is (two pieces
+    read 3e-4 at a hidden size of 64 with keys of 8, 3e-5 to 1.3e-4 at 256
+    with keys of 32, 3e-5 here; one piece 0.14, 0.04, 0.02; on the chip at
+    3840 with keys of 96 two pieces read 1.9e-5 at the most against float32
+    there and one piece 3e-3 at the least: my chip run, PR 46), and the limit
+    is the published width's."""
+    config = tiny_config(num_fields=200, compute_dtype="bfloat16", param_dtype="bfloat16", num_hidden_layers=8,
+                         layer_types=(LIN, LIN, LIN, FULL) * 2, embed_dim=512, intermediate_size=1024,
+                         num_attention_heads=16, num_key_value_heads=16, head_dim=32, linear_num_key_heads=8,
+                         linear_num_value_heads=8, linear_key_head_dim=48, linear_value_head_dim=96)
+    model = build_model("olmo_hybrid", config)
+    params = unit_gain(jax.jit(model.init)(jax.random.PRNGKey(5)), config)
+    batch = rows(8, config, seed=11)
+    return model, params, batch, reference_scores(reference, params, batch, config)
+
+
+def _worst(model, params, batch, want) -> float:
+    got = np.asarray(jax.jit(lambda p, b: model.apply(p, b))(params, batch)["prediction_node"])
+    worst = float(np.max(np.abs(got.astype(np.float64) - want)))
+    return worst if np.isfinite(worst) else np.inf  # a score that is no number misses by any limit
+
+
+def test_two_piece_scores_within_the_benchmark_tolerance(served_precision, tolerance):
+    model, params, batch, want = served_precision
+    assert want.std() > 0.1  # scores that spread, or the comparison compares nothing
+    assert olmo_hybrid.OPERAND_PIECES == 2 and olmo_hybrid.STATE_DTYPE == jnp.float32
+    assert _worst(model, params, batch, want) < tolerance / 3
+
+
+def _one_piece(monkeypatch, model):
+    """The nearest precision below the stated one: every activation rounded
+    to bfloat16 where it enters a product."""
+    monkeypatch.setattr(olmo_hybrid, "OPERAND_PIECES", 1)
+
+
+def _a_bfloat16_state(monkeypatch, model):
+    monkeypatch.setattr(olmo_hybrid, "STATE_DTYPE", jnp.bfloat16)
+
+
+def _b_not_doubled(monkeypatch, model):
+    """b in (0, 1): `linear_allow_neg_eigval` taken for false."""
+    return build_model("olmo_hybrid", dataclasses.replace(model.config, linear_allow_neg_eigval=False))
+
+
+def _the_decay_left_out(monkeypatch, model):
+    rule = olmo_hybrid.gated_delta_rule
+    monkeypatch.setattr(olmo_hybrid, "gated_delta_rule",
+                        lambda q, k, v, g, b, *rest, **kw: rule(q, k, v, jnp.zeros_like(g), b, *rest, **kw))
+
+
+def _the_l2_norm_left_out(monkeypatch, model):
+    monkeypatch.setattr(olmo_hybrid, "l2_norm", lambda x: x)
+
+
+def _the_convolution_left_out(monkeypatch, model):
+    monkeypatch.setattr(sequence, "causal_conv", lambda x, w, b=None: jax.nn.silu(x))
+
+
+def _the_output_gate_left_out(monkeypatch, model):
+    monkeypatch.setattr(olmo_hybrid, "out_gate", lambda p, o, x, cd, eps: routed.rms_norm(p["o_norm"], o, eps))
+
+
+def _the_query_norm_made_per_head(monkeypatch, model):
+    """RMS over a head's width (with the same learned weights) where the
+    family's is over the whole projection."""
+    def per_head(p, q, k, eps):
+        norm = lambda w, x: routed.rms_norm(  # noqa: E731
+            w.reshape(-1, 32), x.reshape(x.shape[:-1] + (-1, 32)), eps).reshape(x.shape)
+        return norm(p["q_norm"], q), norm(p["k_norm"], k)
+
+    monkeypatch.setattr(olmo_hybrid, "qk_norm", per_head)
+
+
+def _rotary_put_on(monkeypatch, model):
+    """The full layers' queries and keys turned by their positions."""
+    blocked = sequence.blocked_attention
+
+    def turned(q, k, v, window, cd, count):
+        cos, sin = routed.rope_table(k.shape[1], q.shape[-1], 10000.0)
+        q = routed.rotate(q, cos[k.shape[1] - q.shape[1]:, None, None, :], sin[k.shape[1] - q.shape[1]:, None, None, :])
+        return blocked(q, routed.rotate(k, cos[:, None, :], sin[:, None, :]), v, window, cd, count)
+
+    monkeypatch.setattr(sequence, "blocked_attention", turned)
+
+
+@pytest.mark.parametrize("plant,factor", [
+    (_one_piece, 3), (_a_bfloat16_state, 1), (_b_not_doubled, 10), (_the_decay_left_out, 10),
+    (_the_l2_norm_left_out, 10), (_the_convolution_left_out, 10), (_the_output_gate_left_out, 10),
+    (_the_query_norm_made_per_head, 3), (_rotary_put_on, 10)],
+    ids=["one-piece operands", "a bfloat16 state", "b not doubled", "no decay", "no l2 norm", "no convolution",
+         "no output gate", "query norm per head", "rotary on"])
+def test_what_the_tolerance_refuses(served_precision, tolerance, monkeypatch, plant, factor):
+    """Each fault is planted here, not in the program, and misses the
+    float32 reference by more than `factor` times the benchmark's limit."""
+    model, params, batch, want = served_precision
+    model = plant(monkeypatch, model) or model
+    assert _worst(model, params, batch, want) > factor * tolerance
+
+
+# ------------------------------------------------------------ the served path
+
+
+@pytest.fixture(scope="module")
+def served():
+    from distributed_tf_serving_tpu.serving.server import build_stack
+
+    cfgs = load_config(os.path.join(ROOT, "configs", "olmo_hybrid_small.toml"))
+    config = dataclasses.replace(cfgs["model"], name="M")
+    cfg = dataclasses.replace(cfgs["server"], model_name="M", warmup=False)
+    _registry, batcher, impl, servable, _mesh, _watcher = build_stack(cfg, model_config=config)
+    yield batcher, impl, servable
+    batcher.stop()
+
+
+def _step_phases() -> dict:
+    from distributed_tf_serving_tpu.utils.tracing import request_trace
+
+    return {k: v["count"] for k, v in request_trace.snapshot().items() if k.startswith(("delta.", "attn."))}
+
+
+def test_a_request_through_the_batchers_entry_scores_like_the_reference(served, reference, tolerance):
+    """configs/olmo_hybrid_small.toml down the served path: 3 rows pad to the
+    bucket of 4; ids travel as u24 and weights as float32; the step's five
+    counters come back with the scores and are recorded by count, the padded
+    row in none of them."""
+    batcher, _impl, servable = served
+    config = servable.model.config
+    arrays = rows(3, config, folded=False)
+    before = _step_phases()
+    got = batcher.submit(servable, arrays).result(timeout=300)
+    assert set(got) == {"prediction_node", "logits"} and type(got["prediction_node"]) is np.ndarray
+    batch = dict(arrays, feat_ids=(arrays["feat_ids"] % config.vocab_size).astype(np.int32))
+    want = reference_scores(reference, servable.params, batch, config)
+    assert got["prediction_node"].shape == (3,) and batcher.compress_transfer
+    assert np.max(np.abs(got["prediction_node"] - want)) < tolerance
+    after = _step_phases()
+    delta = {name: after[name] - before.get(name, 0) for name in servable.model.step_stats}
+    # 150 positions: 3 hand-overs a row and linear layer, 6 linear layers; the first full layer's tile and the last's query
+    assert delta == {"attn.scores_computed": 3 * (150 * 150 + 150), "attn.scores_seen": 3 * (150 * 151 // 2 + 150),
+                     "delta.rows": 3, "delta.handovers": 3 * 6 * 3, "delta.positions": 3 * 6 * 150}
+
+
+def test_predict_answers_a_row_of_tokens_and_nothing_else(served):
+    from distributed_tf_serving_tpu import codec
+    from distributed_tf_serving_tpu.client import build_predict_request
+
+    batcher, impl, servable = served
+    arrays = rows(2, servable.model.config, seed=9, folded=False)
+    response = impl.predict(build_predict_request(arrays, "M"))
+    scores = codec.to_ndarray(response.outputs["prediction_node"])
+    direct = batcher.submit(servable, arrays).result(timeout=300)["prediction_node"]
+    assert sorted(response.outputs) == ["logits", "prediction_node"]  # the counters are no output
+    assert scores.shape == (2,) and np.all((scores > 0) & (scores < 1))
+    np.testing.assert_array_equal(scores, direct)
+
+
+def test_runtime_block_reports_the_plans(served):
+    batcher, impl, servable = served
+    batcher.submit(servable, rows(2, servable.model.config, folded=False)).result(timeout=300)
+    startup = impl.runtime_stats()["startup"]
+    assert startup["layer_plan"] == {"M:1": {"linear": 6, "full": 2}}
+    linear = {"kind": "linear", "chunk": 64, "handovers_a_row": 3, "state_bytes_a_row": 3 * 16 * 24 * 4}
+    full = {"kind": "full", "window": 0, "block": 150, "keys_a_block": 150}
+    assert startup["attention_plan"] == {"M:1": [linear, linear, linear, full] * 2}
+    assert startup["expert_plan"] == {"M:1": None}
+    assert startup["assembler"] == {"M:1": "native"} or not native.available()
+    assert "feat_ids int32/24b" in startup["upload_format"]["M:1"]
+
+
+def test_shadow_verification_counts_a_batch_once(served):
+    """With the integrity plane's shadow execution on, the step runs twice
+    over a batch and its counters are recorded once."""
+    from distributed_tf_serving_tpu.utils.config import IntegrityConfig
+
+    batcher, _impl, servable = served
+    arrays = rows(2, servable.model.config, seed=6, folded=False)
+    batcher.submit(servable, arrays).result(timeout=300)
+    once = _step_phases()
+    plain = batcher.submit(servable, arrays).result(timeout=300)
+    twice = _step_phases()
+    plane = IntegrityConfig(enabled=True, shadow_fraction=1.0).build()
+    batcher.integrity = plane
+    try:
+        shadowed = batcher.submit(servable, arrays).result(timeout=300)
+    finally:
+        batcher.integrity = None
+    thrice = _step_phases()
+    shadow = plane.snapshot()["shadow"]
+    assert shadow["batches"] == 1 and shadow["mismatches"] == 0
+    np.testing.assert_array_equal(shadowed["prediction_node"], plain["prediction_node"])
+    assert all(twice[k] - once[k] == thrice[k] - twice[k] > 0 for k in servable.model.step_stats)
+
+
+# ------------------------------------------------------- the published shapes
+
+
+@pytest.fixture(scope="module")
+def published():
+    with open(os.path.join(CONFIG_DIR, "config.json")) as f:
+        shape = json.load(f)["toml"]["model"]
+    return ModelConfig(**{k: tuple(v) if isinstance(v, list) else v for k, v in shape.items()})
+
+
+def test_plan_and_parameter_count_at_the_published_cut(published):
+    """By `jax.eval_shape`: nothing of the 2.050 B parameters is made."""
+    model = build_model("olmo_hybrid", published)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    size = lambda tree: sum(int(np.prod(x.shape)) for x in jax.tree.leaves(tree))  # noqa: E731
+    assert model.layer_plan == ("linear", "linear", "linear", "full") * 2 and model.expert_plan == ()
+    assert [dict(layer) for layer in model.attention_plan][2:4] == [
+        {"kind": "linear", "chunk": 64, "handovers_a_row": 32, "state_bytes_a_row": 2_211_840},
+        {"kind": "full", "window": 0, "block": 512, "keys_a_block": 2048}]
+    linear, attn = shapes["layers"][0]["linear"], shapes["layers"][3]["attn"]
+    assert (linear["q"].shape, linear["k"].shape, linear["v"].shape, linear["gate"].shape, linear["o"].shape) == (
+        (3840, 2880), (3840, 2880), (3840, 5760), (3840, 5760), (5760, 3840))
+    assert (linear["conv_q"].shape, linear["conv_k"].shape, linear["conv_v"].shape) == ((2880, 4), (2880, 4), (5760, 4))
+    assert linear["b"].shape == linear["a"].shape == (3840, 30) and linear["o_norm"].shape == (192,)
+    assert linear["A_log"].shape == linear["dt_bias"].shape == (30,)
+    assert size(linear) == 2 * 3840 * 2880 + 3 * 3840 * 5760 + 2 * 3840 * 30 + 11520 * 4 + 252 == 88_750_332
+    assert {k: v.shape for k, v in attn.items()} == {
+        "q": (3840, 3840), "k": (3840, 3840), "v": (3840, 3840), "o": (3840, 3840), "q_norm": (3840,), "k_norm": (3840,)}
+    assert size(shapes["layers"][0]["mlp"]) == 3 * 3840 * 11008 == 126_812_160
+    assert shapes["embedding"].shape == (100352, 3840) and shapes["score"].shape == (3840,)
+    assert round(size(shapes) / 1e5) == 20504 and {x.dtype for x in jax.tree.leaves(shapes)} == {jnp.dtype("bfloat16")}
+
+
+@pytest.mark.parametrize("overrides,match", [
+    ({"layer_types": (LIN, LIN, FULL)}, "layer_types"),
+    ({"layer_types": (LIN, LIN, LIN, "sliding_attention")}, "layer_types"),
+    ({"num_key_value_heads": 3}, "num_key_value_heads"),
+    ({"head_dim": 0, "embed_dim": 3}, "head_dim"),
+    ({"linear_num_key_heads": 1}, "linear_num_key_heads"),
+    ({"linear_num_value_heads": 0, "linear_num_key_heads": 0}, "linear_num_key_heads"),
+    ({"linear_value_head_dim": 0}, "linear_value_head_dim"),
+    ({"linear_conv_kernel_dim": 0}, "linear_conv_kernel_dim"),
+])
+def test_a_plan_the_stack_cannot_be_built_from_is_refused(overrides, match):
+    with pytest.raises(ValueError, match=match):
+        build_model("olmo_hybrid", tiny_config(**overrides))
+
+
+def test_keys_left_out_take_the_published_period_and_the_usual_head():
+    model = build_model("olmo_hybrid", tiny_config(layer_types=(), head_dim=0, num_hidden_layers=6))
+    assert model.layer_plan == ("linear",) * 3 + ("full",) + ("linear",) * 2
+    assert jax.eval_shape(model.init, jax.random.PRNGKey(0))["layers"][3]["attn"]["q"].shape == (64, 64)
+    # ModelConfig's own defaults build a valid small model: two periods
+    assert build_model("olmo_hybrid", ModelConfig()).layer_plan == ("linear", "linear", "linear", "full") * 2
+
+
+def test_the_initial_decays_spread_and_do_not_sit_at_an_end():
+    """`A` uniform in (0, 16) and `dt` log-uniform in (1e-3, 1e-1): a step's
+    decay exp(-A softplus(dt_bias)) = exp(-A dt) lies in (0.2, 1), where a
+    missing gate can be told."""
+    config = tiny_config(linear_num_key_heads=64, linear_num_value_heads=64)
+    p = jax.jit(build_model("olmo_hybrid", config).init)(jax.random.PRNGKey(3))["layers"][0]["linear"]
+    decay = np.exp(-np.exp(np.asarray(p["A_log"], np.float64)) * np.log1p(np.exp(np.asarray(p["dt_bias"], np.float64))))
+    assert 0.19 < decay.min() < 0.9 and 0.97 < decay.max() <= 1.0 and decay.std() > 0.05
+
+
+def test_toml_reads_the_published_keys(tmp_path):
+    cfgs = load_config(os.path.join(ROOT, "configs", "olmo_hybrid_small.toml"))
+    model = build_model(cfgs["server"].model_kind, cfgs["model"])
+    assert model.kind == "olmo_hybrid" and not model.takes_dense and not model.wts_in_compute_dtype
+    assert cfgs["server"].num_fields == cfgs["model"].num_fields == 150
+    assert len(model.layer_plan) == cfgs["model"].num_hidden_layers == len(cfgs["model"].layer_types)
+    assert cfgs["model"].linear_key_head_dim != cfgs["model"].linear_value_head_dim
+    assert cfgs["model"].num_fields % olmo_hybrid.DELTA_CHUNK
+    (tmp_path / "s.toml").write_text('[model]\nlinear_key_head_dims = 8\n')
+    with pytest.raises(ValueError, match="unknown ModelConfig keys"):
+        load_config(str(tmp_path / "s.toml"))

@@ -247,3 +247,19 @@ def test_pangu_moes_eight_row_step_writes_a_score_tile_once(one_chip, no_compile
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12 * GIB
     assert memory.generated_code_size_in_bytes < 71 << 20  # 67.0 MB in PR 43's tree
     assert accessed < 156e9
+
+
+def test_olmo_hybrids_four_row_step_compiles_at_the_published_cut(one_chip, no_compile_cache):
+    """Olmo-Hybrid-7B's first pipeline stage as `olmo_hybrid_rerank-bulk`
+    serves it (2.050 B parameters, rows of 2,048 tokens), the top bucket's
+    step with its counters: the chip's compiler takes the rule's triangular
+    solve a chunk, its chunk loop (a `while`, one a linear layer: the code
+    stays small) and the full layers' 512-query blocks, and what the step
+    holds beside the 4.10 GB of weights fits the chip's 16 GB."""
+    compiled, accessed = sequence_cells_step("olmo_hybrid_rerank", "olmo_hybrid", one_chip)
+    memory = compiled.memory_analysis()
+    assert 4.0e9 < memory.argument_size_in_bytes < 4.2e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12 * GIB
+    assert memory.generated_code_size_in_bytes < 64 << 20  # 36.6 MB: two of these beside the other cells' in the cache
+    assert accessed < 215e9  # 191.8 GB as PR 46 left it
+    assert len(re.findall(r"\) while\(", compiled.as_text())) == 6  # the six linear layers' chunk loops, and no other
