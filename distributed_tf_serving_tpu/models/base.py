@@ -207,6 +207,20 @@ class Model:
     # None, as for every other family, and nothing of that exists.
     apply_stats: Callable[[Params, Batch], tuple[dict[str, jax.Array], jax.Array]] | None = None
     step_stats: tuple[str, ...] = ()
+    # XLA options the family's served step compiles with where the backend is
+    # a TPU, as (name, value) pairs (`step_jit`); empty for every family but
+    # olmo_hybrid, whose executables are then the ones `jax.jit` alone makes.
+    tpu_compiler_options: tuple[tuple[str, object], ...] = ()
+
+
+def step_jit(model: Model, run: Callable, platform: str | None = None) -> Callable:
+    """`jax.jit(run)` for a served step of `model`: with the family's
+    `tpu_compiler_options` where the backend (`platform`, else the process's
+    default) is a TPU. No other backend knows those options by name, and a
+    family without any compiles as `jax.jit(run)` does, to the same cache key."""
+    if model.tpu_compiler_options and (platform or jax.default_backend()) == "tpu":
+        return jax.jit(run, compiler_options=dict(model.tpu_compiler_options))
+    return jax.jit(run)
 
 
 # ---------------------------------------------------------------------------

@@ -70,7 +70,7 @@ from .. import faults
 from . import overload as overload_mod
 from ..cache import CoalescedLeaderCancelled, collapse_rows
 from ..cache.digest import canonical_rows
-from ..models.base import Model
+from ..models.base import Model, step_jit
 from ..models.embeddings import serving_gathers
 from ..models.registry import Servable
 from ..ops.transfer import (
@@ -2128,7 +2128,7 @@ class DynamicBatcher:
                     else:
                         def run(p, b, _l=layout, _ok=out_keys, _ap=ap):
                             return finish(_ap(p, unpack_device_combined(b, _l)), _ok)
-                    jfn = _cache[key] = jax.jit(named(run, topk, prune))
+                    jfn = _cache[key] = step_jit(model, named(run, topk, prune))
                 return jfn(params, buf, n_valid) if topk else jfn(params, buf)
         else:
             def fn(
@@ -2154,7 +2154,7 @@ class DynamicBatcher:
                             # executable (its `unpack` scope).
                             batch = unpack_device(b, spec) if spec else b
                             return finish(_ap(p, batch), _ok)
-                    jfn = _cache[key] = jax.jit(named(run, topk, prune))
+                    jfn = _cache[key] = step_jit(model, named(run, topk, prune))
                 return jfn(params, packed, n_valid) if topk else jfn(params, packed)
 
         if model.needs_x64:

@@ -17,6 +17,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from distributed_tf_serving_tpu.models import ModelConfig, build_model
+from distributed_tf_serving_tpu.models.base import step_jit
 
 GIB = 1 << 30
 VOCAB, DIM, FIELDS = 1 << 27, 16, 43
@@ -207,7 +208,7 @@ def sequence_cells_step(name: str, kind: str, one_chip):
         "feat_ids": jax.ShapeDtypeStruct((rows, shape["num_fields"]), jnp.int32, sharding=one_chip),
         "feat_wts": jax.ShapeDtypeStruct((rows, shape["num_fields"]), jnp.float32, sharding=one_chip),
     }
-    compiled = jax.jit(model.apply_stats if model.step_stats else model.apply).lower(params, batch).compile()
+    compiled = step_jit(model, model.apply_stats if model.step_stats else model.apply, "tpu").lower(params, batch).compile()
     cost = compiled.cost_analysis()
     return compiled, (cost[0] if isinstance(cost, (list, tuple)) else cost)["bytes accessed"]
 
@@ -252,14 +253,19 @@ def test_pangu_moes_eight_row_step_writes_a_score_tile_once(one_chip, no_compile
 def test_olmo_hybrids_four_row_step_compiles_at_the_published_cut(one_chip, no_compile_cache):
     """Olmo-Hybrid-7B's first pipeline stage as `olmo_hybrid_rerank-bulk`
     serves it (2.050 B parameters, rows of 2,048 tokens), the top bucket's
-    step with its counters: the chip's compiler takes the rule's triangular
-    solve a chunk, its chunk loop (a `while`, one a linear layer: the code
-    stays small) and the full layers' 512-query blocks, and what the step
-    holds beside the 4.10 GB of weights fits the chip's 16 GB."""
+    step with its counters, compiled as the batcher compiles it
+    (`base.step_jit`): the rule's solve is the block form (16 unrolled
+    substitution steps over all diagonal blocks and the merges' fused
+    reductions a layer: no triangular-solve custom call and no loop of its
+    own), its chunk loop a `while`, one a linear layer, what the step holds
+    beside the 4.10 GB of weights fits the chip's 16 GB, and the layers share
+    one copy of a fusion (the family's compiler option; without it the same
+    step is 164.4 MB of code: PERF.md section 6, PR 47)."""
     compiled, accessed = sequence_cells_step("olmo_hybrid_rerank", "olmo_hybrid", one_chip)
-    memory = compiled.memory_analysis()
+    memory, text = compiled.memory_analysis(), compiled.as_text()
     assert 4.0e9 < memory.argument_size_in_bytes < 4.2e9
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12 * GIB
-    assert memory.generated_code_size_in_bytes < 64 << 20  # 36.6 MB: two of these beside the other cells' in the cache
-    assert accessed < 215e9  # 191.8 GB as PR 46 left it
-    assert len(re.findall(r"\) while\(", compiled.as_text())) == 6  # the six linear layers' chunk loops, and no other
+    assert memory.generated_code_size_in_bytes < 64 << 20  # 42.6 MB: two of these beside the other cells' in the cache
+    assert accessed < 178e9  # 169.2 GB; 191.8 with the backend's triangular solve (PR 46)
+    assert "Triangular" not in text  # InvertDiagBlocksLowerTriangular, what triangular_solve lowers to
+    assert len(re.findall(r"\) while\(", text)) == 6  # the six linear layers' chunk loops, and no other
