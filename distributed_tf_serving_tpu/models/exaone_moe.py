@@ -30,7 +30,11 @@ sigmoid scores over ALL `num_experts`, the top `num_experts_per_tok`
 `experts_held` from `first_expert_held` on and leaves the others' part out.
 The attention is whole on every chip of the stated deployment.
 
-**Blocks follow the layer's kind.** A full layer takes `sequence`'s blocks of
+**Blocks follow the layer's kind.** In a one-chip served entry on a TPU every
+layer but the last runs `sequence.attention`, ONE Pallas kernel a layer whose
+tiles follow the kind too (ops/attention_kernel.py: 512 x 512, 128 x 128 for a
+window of 128, and no key block a mask throws away whole). Everywhere else,
+the XLA path: a full layer takes `sequence`'s blocks of
 ATTN_BLOCK queries against every key up to the block's end. A sliding layer
 takes blocks of `sliding_window` queries, ALL of them in one batched product,
 each against the key block before its own and its own: 2 x window keys a
@@ -161,8 +165,8 @@ def step_pairs(kinds: tuple[str, ...], length: int, window: int) -> tuple[int, i
         if i == len(kinds) - 1:
             keys = length if reach is None else min(length, reach)
             computed, seen = computed + keys, seen + keys
-        elif reach is None:
-            blocks = sequence.blocked_pairs(length, length)
+        elif reach is None or sequence.kernel_serves(length):
+            blocks = sequence.blocked_pairs(length, length, reach)
             computed, seen = computed + blocks[0], seen + blocks[1]
         else:
             blocks, back = band_blocks(length, window, window)
@@ -232,9 +236,9 @@ def attention(p: dict, x: jax.Array, s: dict, kind: str, cd, eps: float, theta: 
             q = rotate(q, cos[length - queries:, None, None, :], sin[length - queries:, None, None, :])
             k = rotate(k, cos[length - keys:, None, :], sin[length - keys:, None, :])
     with jax.named_scope("softmax"):
-        if window is not None and queries > 1:
+        if window is not None and queries > 1 and not sequence.kernel_serves(queries):
             o = band_attention(q, k, v, window, cd)
-        else:
+        else:  # the kernel where it serves, whose tiles follow the window themselves
             o = blocked_attention(q, k, v, window, cd)
     return _dot(o.reshape(n, queries, heads * head), p["o"], cd)
 

@@ -196,15 +196,21 @@ def latent_attention(p: dict, a: jax.Array, s: dict, cd, eps: float, theta: floa
         k_rope = rotate(k_rope, cos, sin)
     with jax.named_scope("softmax"):
         scale = (nope + rope) ** -0.5
-        out = []
-        for start, stop, first, last in sequence.query_blocks(queries, length):
-            scores = (
-                _product("nqhd,nkhd->nhqk", q_nope[:, start:stop], k_nope[:, first:last], cd)
-                + _product("nqhd,nkd->nhqk", q_rope[:, start:stop], k_rope[:, first:last], cd)
-            ) * scale
-            probs = sequence.causal_softmax(scores, length - queries + start - first)
-            out.append(_product("nhqk,nkhd->nqhd", probs, values[:, first:last], cd))
-        o = out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
+        if sequence.takes_kernel(queries, length, None, OPERAND_PIECES):
+            # Both score products accumulate in the kernel's one tile; the
+            # rotary keys are one head that every query head reads.
+            o = sequence.attention(
+                (q_nope, q_rope), (k_nope, k_rope[:, :, None]), values, None, cd, OPERAND_PIECES, scale)
+        else:
+            out = []
+            for start, stop, first, last in sequence.query_blocks(queries, length):
+                scores = (
+                    _product("nqhd,nkhd->nhqk", q_nope[:, start:stop], k_nope[:, first:last], cd)
+                    + _product("nqhd,nkd->nhqk", q_rope[:, start:stop], k_rope[:, first:last], cd)
+                ) * scale
+                probs = sequence.causal_softmax(scores, length - queries + start - first)
+                out.append(_product("nhqk,nkhd->nqhd", probs, values[:, first:last], cd))
+            o = out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
     return _dot(o.reshape(n, queries, heads * v_dim), p["o"], cd)
 
 

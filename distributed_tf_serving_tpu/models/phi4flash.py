@@ -293,7 +293,12 @@ def diff_attention(p, q, k, v, layer: int, q_start: int, window: int | None, s: 
     q = q.reshape(n, lq, groups, per_group, 2, head)
     scores = _product("nqgjcd,nkgcd->ngjcqk", q, k, cd) * head ** -0.5
     probs = sequence.causal_softmax(scores, q_start, window)
-    out = _product("ngjcqk,nkge->nqgjce", probs, v, cd)
+    return _difference(p, _product("ngjcqk,nkge->nqgjce", probs, v, cd), layer)
+
+
+def _difference(p, out: jax.Array, layer: int) -> jax.Array:
+    """`out [n, Lq, G, J, 2, 2d]`, the two softmaxes' `p v` of every head
+    pair: their difference under lambda, normed; `[n, Lq, H]`."""
     f32 = lambda name: p[name].astype(jnp.float32)  # noqa: E731
     base = lambda_init(layer)
     lam = (
@@ -303,7 +308,7 @@ def diff_attention(p, q, k, v, layer: int, q_start: int, window: int | None, s: 
     o = out[..., 0, :] - lam * out[..., 1, :]
     o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + RMS_EPS)
     o = o * f32("subln") * (1.0 - base)
-    return o.reshape(n, lq, -1)
+    return o.reshape(out.shape[0], out.shape[1], -1)
 
 
 def _attend(p, q, k, v, layer: int, window: int | None, s: dict, cd) -> jax.Array:
@@ -311,6 +316,15 @@ def _attend(p, q, k, v, layer: int, window: int | None, s: dict, cd) -> jax.Arra
     keys' range (all of them, or the last one alone), in `sequence`'s blocks
     of queries; a block reads only the keys its window can reach."""
     offset = k.shape[1] - q.shape[1]
+    if sequence.takes_kernel(q.shape[1], k.shape[1], window, OPERAND_PIECES):
+        # The halves of a head pair are two key heads that share one value
+        # head of width 2d: heads in the order (group, half, query head).
+        n, lq, groups, head = q.shape[0], q.shape[1], s["kv"] // 2, s["head"]
+        halves = jnp.swapaxes(q.reshape(n, lq, groups, -1, 2, head), 3, 4)
+        out = sequence.attention(
+            (halves.reshape(n, lq, -1, head),), (k.reshape(n, -1, 2 * groups, head),), v, window, cd,
+            OPERAND_PIECES, head ** -0.5)
+        return _difference(p, jnp.swapaxes(out.reshape(halves.shape[:5] + (2 * head,)), 3, 4), layer)
     out = [
         diff_attention(
             p, q[:, start:stop], k[:, first:last], v[:, first:last], layer,

@@ -3,7 +3,9 @@ olmo_hybrid) share: products whose float32 activations enter as pieces of the
 compute dtype (`product`: one product a call wherever a form exists that
 copies no large array), the causal softmax of a block of queries, the blocks
 themselves, full causal attention in those blocks (`blocked_attention`:
-exaone_moe's and olmo_hybrid's full layers), the causal depthwise convolution
+exaone_moe's and olmo_hybrid's full layers), the attention at all positions
+as one Pallas kernel a layer where a one-chip served entry runs on a TPU
+(`attention`, `takes_kernel`: all four families), the causal depthwise convolution
 (`causal_conv`: phi4flash's Mamba layers and olmo_hybrid's linear ones), and
 the cut to the last position. One implementation, so that a change to any of
 them is measured on every family's cell. (`models/routed.py` has what the
@@ -16,8 +18,10 @@ either by name to plant the precision below the stated one).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import string
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -151,10 +155,96 @@ def query_blocks(queries: int, keys: int, window: int | None = None, block: int 
         yield start, stop, first, offset + stop
 
 
+_served = threading.local()  # .entry: (notes, interpret) while serving_attention is entered
+
+
+@contextlib.contextmanager
+def serving_attention(notes: list, interpret: bool = False):
+    """While the batcher traces a one-chip served entry in this thread
+    (serving/batcher.py _build_entry, and nowhere else): an attention at all
+    positions may take the Pallas kernel (ops/attention_kernel.py), and
+    `takes_kernel` appends to `notes` what it chose (attention_choice's dict,
+    once each), the servable's `startup.attention` stamp. `interpret` is for
+    tests on the CPU: choose as on a TPU and run the kernel interpreted.
+
+    Outside it every attention is the XLA path that stood before the kernel,
+    as `embeddings.serving_gathers` keeps XLA's gather and for its reasons:
+    the GSPMD executors, `shard_map` and the trainer trace `model.apply`
+    themselves, and a `tpu_custom_call` neither partitions nor has a
+    gradient rule."""
+    before = getattr(_served, "entry", None)
+    _served.entry = (notes, interpret)
+    try:
+        yield notes
+    finally:
+        _served.entry = before
+
+
+def kernel_serves(queries: int) -> bool:
+    """Whether an attention of `queries` queries a row runs the kernel, from
+    what a trace can see: inside serving_attention, on a TPU, and more than
+    one query (the last layer's one query has a `[1, keys]` tile: nothing to
+    keep out of memory)."""
+    served = getattr(_served, "entry", None)
+    return served is not None and (served[1] or jax.default_backend() == "tpu") and queries > 1
+
+
+def attention_choice(queries: int, keys: int, window: int | None, count: int) -> dict:
+    """`{"kernel": "pallas" | "xla", "block", "pieces"}`: which path serves
+    an attention, the side of the kernel's score tile (0 where XLA's blocks
+    run: `Model.attention_plan` states those) and the pieces an activation
+    enters its products as. A servable's `startup.attention` stamp."""
+    if not kernel_serves(queries):
+        return {"kernel": "xla", "block": 0, "pieces": count}
+    from ..ops.attention_kernel import tile
+
+    return {"kernel": "pallas", "block": tile(keys, window), "pieces": count}
+
+
+def takes_kernel(queries: int, keys: int, window: int | None, count: int) -> bool:
+    """Whether `attention` serves this one (attention_choice has the rule),
+    noted for the served entry being traced."""
+    choice = attention_choice(queries, keys, window, count)
+    served = getattr(_served, "entry", None)
+    if served is not None and choice not in served[0]:
+        served[0].append(choice)
+    return choice["kernel"] == "pallas"
+
+
+def attention(qs, ks, v: jax.Array, window: int | None, cd, count: int, scale: float) -> jax.Array:
+    """Causal attention of the queries at the LAST positions of the keys'
+    range as ONE Pallas kernel (ops/attention_kernel.py) that keeps the score
+    tile in VMEM: for the callers `takes_kernel` said yes to. The score is
+    the sum over the parts of `q k'`, times `scale`; position t sees
+    `t - window + 1 .. t` (all up to t without a window).
+
+    qs  a tuple of `[n, Lq, H, d_p]` float32, a part each
+    ks  a tuple of `[n, Lk, H_p, d_p]`: query head h reads head `h // (H / H_p)`
+    v   `[n, Lk, H_v, d_v]`, read the same way
+    returns `[n, Lq, H, d_v]` float32
+
+    The kernel's arrays are head-major; the transposes are XLA's, fused into
+    what makes the operands and reads the result."""
+    from ..ops.attention_kernel import attention as kernel
+
+    heads_first = functools.partial(jnp.transpose, axes=(0, 2, 1, 3))
+    out = kernel(
+        tuple(map(heads_first, qs)), tuple(map(heads_first, ks)), heads_first(v),
+        scale=float(scale), window=window, cd=jnp.dtype(cd), count=count, interpret=_served.entry[1])
+    return heads_first(out)
+
+
 def blocked_pairs(queries: int, keys: int, window: int | None = None) -> tuple[int, int]:
     """((query, key) pairs the tiles of `blocked_attention` compute over a row,
-    those its masks keep) for the last `queries` positions of `keys`."""
-    computed = sum((stop - start) * (last - first) for start, stop, first, last in query_blocks(queries, keys, window))
+    those its masks keep) for the last `queries` positions of `keys`: the
+    kernel's tiles where it serves."""
+    if kernel_serves(queries):
+        from ..ops.attention_kernel import tile_pairs
+
+        computed = tile_pairs(queries, keys, window)
+    else:
+        computed = sum(
+            (stop - start) * (last - first) for start, stop, first, last in query_blocks(queries, keys, window))
     offset = keys - queries
     seen = sum(min(offset + t + 1, window or keys) for t in range(queries))
     return computed, seen
@@ -169,6 +259,10 @@ def blocked_attention(q: jax.Array, k: jax.Array, v: jax.Array, window: int | No
     (J query heads a key-value head), `k`, `v [n, Lk, G, d]`; returns
     `[n, Lq, G, J, d]` float32. Activations enter as `count` pieces."""
     queries, keys, out = q.shape[1], k.shape[1], []
+    if takes_kernel(queries, keys, window, count):
+        n, _, groups, per_group, head = q.shape
+        o = attention((q.reshape(n, queries, groups * per_group, head),), (k,), v, window, cd, count, head ** -0.5)
+        return o.reshape(q.shape)
     for start, stop, first, last in query_blocks(queries, keys, window):
         scores = product("nqgjd,nkgd->ngjqk", q[:, start:stop], k[:, first:last], cd, count) * q.shape[-1] ** -0.5
         probs = causal_softmax(scores, keys - queries + start - first, window)

@@ -72,6 +72,7 @@ from ..cache import CoalescedLeaderCancelled, collapse_rows
 from ..cache.digest import canonical_rows
 from ..models.base import Model, step_jit
 from ..models.embeddings import serving_gathers
+from ..models.sequence import serving_attention
 from ..models.registry import Servable
 from ..ops.transfer import (
     cascade_prune_device,
@@ -776,6 +777,9 @@ class BatcherStats:
     # Batches that ran an entry whose embedding gather is the Pallas kernel
     # (models/embeddings.py gather_choice; `startup.gather` names it).
     gather_kernel_batches: int = 0
+    # And those whose attention is the Pallas kernel (models/sequence.py
+    # attention_choice; `startup.attention` names it).
+    attention_kernel_batches: int = 0
     # Batches of one request that its own handler thread closed and staged
     # (submit's direct crossing): no collector, no coalesce window, no
     # dispatch thread. The phase `batch.direct` counts the same.
@@ -1085,6 +1089,14 @@ class DynamicBatcher:
             weakref.WeakKeyDictionary()
         )
         self._gather_kernel: weakref.WeakSet[Servable] = weakref.WeakSet()
+        # Likewise what each attention of its entry chose
+        # (models/sequence.py attention_choice): /monitoring's
+        # `startup.attention`; and the servables whose entry runs the Pallas
+        # attention kernel, whose batches are counted.
+        self._attentions: weakref.WeakKeyDictionary[Servable, list] = (
+            weakref.WeakKeyDictionary()
+        )
+        self._attention_kernel: weakref.WeakSet[Servable] = weakref.WeakSet()
         # _jit_for is reached from the batcher thread (fused-path
         # eligibility) AND the dispatch thread; one lock keeps the entry
         # build single-shot.
@@ -1758,6 +1770,20 @@ class DynamicBatcher:
                 for sv, notes in self._gathers.items() if notes
             }
 
+    def attentions(self) -> dict[str, dict]:
+        """"name:version" -> the attention of that servable's entry as
+        traced: `{"kernel": "pallas" | "xla", "block", "pieces"}` (the
+        kernel's, and its widest tile, where layers or rungs differ), for
+        every servable whose step attends. A custom run_fn traces its own
+        entries, outside serving_attention: the XLA path, no stamp."""
+        with self._jit_lock:
+            return {
+                f"{sv.name}:{sv.version}": max(
+                    notes, key=lambda n: (n["kernel"] == "pallas", n["block"])
+                )
+                for sv, notes in self._attentions.items() if notes
+            }
+
     def pipeline_stats(self) -> dict:
         """Continuous-batching pipeline snapshot (ISSUE 9): configured
         depth/window, live in-flight occupancy (total and per bucket),
@@ -2039,13 +2065,19 @@ class DynamicBatcher:
         # the Pallas gather kernel (models/embeddings.py serving_gathers).
         gathers = self._gathers[servable] = []
         self._gather_kernel.discard(servable)
+        # And the one in which an attention at all positions may take the
+        # Pallas attention kernel (models/sequence.py serving_attention).
+        attentions = self._attentions[servable] = []
+        self._attention_kernel.discard(servable)
 
         def noting(ap):
             def traced(p, batch):
-                with serving_gathers(gathers):
+                with serving_gathers(gathers), serving_attention(attentions):
                     out = ap(p, batch)
                 if any(note["kernel"] == "pallas" for note in gathers):
                     self._gather_kernel.add(servable)
+                if any(note["kernel"] == "pallas" for note in attentions):
+                    self._attention_kernel.add(servable)
                 return out
             return traced
 
@@ -3358,6 +3390,9 @@ class DynamicBatcher:
                     # A phase by count, beside `batch.dispatch`'s.
                     self.stats.gather_kernel_batches += 1
                     request_trace.add_many((("batch.gather_kernel", 0.0, 1),))
+                if servable in self._attention_kernel:
+                    self.stats.attention_kernel_batches += 1
+                    request_trace.add_many((("batch.attention_kernel", 0.0, 1),))
                 if group[0].direct:
                     self.stats.direct_batches += 1
                     request_trace.add_many((("batch.direct", 0.0, 1),))

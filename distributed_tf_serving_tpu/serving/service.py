@@ -224,6 +224,7 @@ class PredictionServiceImpl:
         upload_formats = getattr(self.batcher, "upload_formats", None)
         assemblers = getattr(self.batcher, "assemblers", None)
         gathers = getattr(self.batcher, "gathers", None)
+        attentions = getattr(self.batcher, "attentions", None)
         block["startup"] = {
             **self.startup,
             "warmup_s": self.warmup_s,
@@ -236,6 +237,7 @@ class PredictionServiceImpl:
             "upload_format": upload_formats() if callable(upload_formats) else {},
             "assembler": assemblers() if callable(assemblers) else {},
             "gather": gathers() if callable(gathers) else {},
+            "attention": attentions() if callable(attentions) else {},
         }
         block["embedding_pack"] = self.registry.per_servable("embedding_pack")
         block["compile_cache"] = (

@@ -752,6 +752,9 @@ class ServerMetrics:
                 # And those whose entry gathers embedding rows with the
                 # Pallas kernel (`startup.gather`).
                 "gather_kernel_batches": getattr(batcher_stats, "gather_kernel_batches", 0),
+                # And those whose entry attends with the Pallas kernel
+                # (`startup.attention`).
+                "attention_kernel_batches": getattr(batcher_stats, "attention_kernel_batches", 0),
                 # And those of one request that its own handler thread
                 # closed and staged (the batcher's direct crossing).
                 "direct_batches": getattr(batcher_stats, "direct_batches", 0),

@@ -445,6 +445,8 @@ def test_cli_server_reports_startup_stamps_and_raw_counters(tmp_path):
         # backend and over this table's 16-byte rows.
         assert startup.pop("gather") == {"DCN:1": {
             "kernel": "xla", "row_bytes": 16, "in_flight": 0, "picked_in_kernel": False}}
+        # And no attention stamp (PR 48): a CTR step attends to nothing.
+        assert startup.pop("attention") == {}
         # And how many gRPC listeners share its port, from how many cores (PR 34).
         from distributed_tf_serving_tpu.serving.server import listener_count
 
