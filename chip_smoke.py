@@ -42,6 +42,7 @@ import subprocess
 import sys
 import tempfile
 import time
+import urllib.error
 import urllib.request
 
 REPO = os.path.dirname(os.path.abspath(__file__))
@@ -136,7 +137,11 @@ def monitoring(rest_port: int, section: str):
         return json.load(r)[section]
 
 
-def wait_serving(proc: subprocess.Popen, port: int) -> None:
+def wait_serving(proc: subprocess.Popen, port: int, rest_port: int) -> dict:
+    """Returns once health answers SERVING and the REST gateway answers too,
+    with its /monitoring `runtime` block. serve() answers SERVING from the
+    end of the warm-up and starts the gateway after that (its first import
+    of aiohttp among the rest): for that moment the REST port refuses."""
     import grpc
 
     from distributed_tf_serving_tpu.proto import health
@@ -154,7 +159,10 @@ def wait_serving(proc: subprocess.Popen, port: int) -> None:
             except grpc.RpcError:
                 status = None  # not listening yet: still loading/compiling
             if status == health.SERVING:
-                return
+                try:
+                    return monitoring(rest_port, "runtime")
+                except urllib.error.URLError:
+                    pass  # SERVING, the gateway not listening yet
             time.sleep(1.0)
     raise RuntimeError(f"server not SERVING after {SERVING_TIMEOUT_S}s")
 
@@ -301,9 +309,8 @@ def main() -> None:
             )
         try:
             t0 = time.perf_counter()
-            wait_serving(proc, port)
+            at_start = wait_serving(proc, port, rest_port)
             say(f"SERVING after {time.perf_counter() - t0:.1f}s")
-            at_start = monitoring(rest_port, "runtime")
             say(f"runtime: {json.dumps(at_start)}")
 
             payloads = make_payloads(num_fields, max(buckets))

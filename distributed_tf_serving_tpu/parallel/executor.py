@@ -22,8 +22,8 @@ First-class serving mode (ISSUE 13): the executor is hardened for the
   model zoo is row-independent and the pad rows never change WHICH rows
   are served, and the output-FILTERED path (what every production client
   sends — the reference client filters to its output_key) is bit-identical
-  to single-chip (CI-gated, TIER1_MESH_SMOKE); an UNFILTERED all-outputs
-  request at a padded shape may differ from single-chip by ~1 ULP — the
+  to single-chip (tools/check_mesh_smoke.py, in tier-1); an UNFILTERED
+  all-outputs request at a padded shape may differ from single-chip by ~1 ULP — the
   padded shape is a different executable and XLA may fuse the
   multi-output graph differently (measured 6e-8 on CPU at one shape) —
   which is float-exact for ranking but not bitwise.

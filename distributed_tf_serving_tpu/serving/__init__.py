@@ -14,7 +14,6 @@ from .server import (
     GrpcModelService,
     GrpcPredictionService,
     create_server,
-    create_server_async,
     load_demo_servable,
     load_ssl_credentials,
     serve,
@@ -47,7 +46,6 @@ __all__ = [
     "GrpcPredictionService",
     "GrpcModelService",
     "create_server",
-    "create_server_async",
     "load_demo_servable",
     "load_ssl_credentials",
     "serve",

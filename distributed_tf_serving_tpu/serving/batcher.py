@@ -36,8 +36,8 @@ TPU-first design:
   serializing behind it.
 
 The core is a dedicated batching thread with a thread-safe queue, so it
-serves both the sync grpc server (handler threads block on a Future) and the
-asyncio server (await wrap_future). Device work is serialized: in pipelined
+serves both the grpc server (handler threads block on a Future) and the REST
+gateway's event loop (await wrap_future). Device work is serialized: in pipelined
 mode (default) the batching thread collects+pads while ONE dispatch thread
 runs the device stage (cache/pack/upload/jit-call) — batch k+1's H2D upload
 starts while batch k executes — and with pipelining off both stages share
@@ -1272,10 +1272,11 @@ class DynamicBatcher:
 
         _may_block: the caller is a thread that is about to sleep on the
         Future anyway (service._run's handler thread, and nobody else: never
-        an event loop). Such a caller crosses the batcher ITSELF when the
-        request would be alone in its batch (_crosses_direct_locked): it
-        closes the batch and runs its stage inside this call, and the Future
-        it gets back is resolved by a completer as ever."""
+        the REST gateway's event loop). Such a caller crosses the batcher
+        ITSELF when the request would be alone in its batch
+        (_crosses_direct_locked): it closes the batch and runs its stage
+        inside this call, and the Future it gets back is resolved by a
+        completer as ever."""
         if _prune_k:
             _solo = True
         if self._stopping:

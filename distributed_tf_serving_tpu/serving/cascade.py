@@ -261,8 +261,8 @@ class CascadeOrchestrator:
 
     async def run_async(self, impl, servable, arrays, fetch_keys,
                         deadline_t, criticality) -> dict:
-        """run() for coroutine servers: identical semantics, stage waits
-        are awaited instead of blocking the event-loop thread."""
+        """run() for the REST gateway's event loop: identical semantics,
+        stage waits are awaited instead of blocking the loop's thread."""
         score_key = servable.model.score_output
         n = next(iter(arrays.values())).shape[0]
         k = self.plan_k(n)

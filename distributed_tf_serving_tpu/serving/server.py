@@ -66,9 +66,8 @@ def _model_of(request) -> str | None:
 
 
 def _traceparent_of(context) -> str | None:
-    """The W3C traceparent from the RPC's invocation metadata (both sync
-    and aio contexts expose it as (key, value) pairs); None when absent.
-    Only called when tracing is enabled."""
+    """The W3C traceparent from the RPC's invocation metadata ((key, value)
+    pairs); None when absent. Only called when tracing is enabled."""
     try:
         for key, value in context.invocation_metadata() or ():
             if key == "traceparent":
@@ -143,11 +142,11 @@ def _input_crc_of(context, impl) -> str | None:
 
 def _stamp_response_crc(impl, context, resp) -> None:
     """x-dts-score-crc trailing-metadata stamp over the encoded response
-    tensors (ISSUE 20), shared by both transports. Advisory: a stamping
-    failure must never fail a good response, and an armed overload
-    plane's degraded/pushback trailing metadata (set later on the same
-    context) wins the slot — the client treats an absent stamp as
-    "server didn't verify", exactly like a plane-less server."""
+    tensors (ISSUE 20). Advisory: a stamping failure must never fail a
+    good response, and an armed overload plane's degraded/pushback
+    trailing metadata (set later on the same context) wins the slot — the
+    client treats an absent stamp as "server didn't verify", exactly like
+    a plane-less server."""
     try:
         sidecar = impl.response_crc_sidecar(resp)
         if sidecar:
@@ -157,11 +156,10 @@ def _stamp_response_crc(impl, context, resp) -> None:
 
 
 def _push_overload_metadata(context, exc: ServiceError | None) -> None:
-    """Overload-plane trailing metadata, shared by both transports: the
-    retry-after-ms pushback hint on refusals, and the degraded marker on
-    brownout stale-served successes (exc None). set_trailing_metadata
-    exists on both sync and aio contexts and is a no-op cost when the
-    plane is off (callers gate on overload.active())."""
+    """Overload-plane trailing metadata: the retry-after-ms pushback hint
+    on refusals, and the degraded marker on brownout stale-served
+    successes (exc None). Callers gate on overload.active(), so the plane
+    costs nothing when it is off."""
     try:
         if exc is not None:
             ra = getattr(exc, "retry_after_ms", None)
@@ -189,7 +187,6 @@ _PEER_ROLE_KEY = "x-dts-peer-role"
 
 
 def _send_peer_role(context) -> None:
-    """Sync-transport stamp (aio contexts need `await` — inlined there)."""
     try:
         context.send_initial_metadata(((_PEER_ROLE_KEY, "replica"),))
     except Exception:  # noqa: BLE001 — advisory only
@@ -495,7 +492,7 @@ class GrpcHealthService:
       unknown-service answer).
     """
 
-    # How often Watch re-evaluates serving state. Each sync watcher holds
+    # How often Watch re-evaluates serving state. Each watcher holds
     # a thread-pool worker for the stream's lifetime, so this is a
     # router-tier surface (a handful of subscribers), not an edge one.
     watch_poll_s = 0.2
@@ -547,10 +544,13 @@ class GrpcHealthService:
             return "quarantined"
         return "starting"
 
-    def _check_response(self, request, context):
+    def Check(self, request, context):
         st = self._status(request.service)
         if st is None:
-            return None
+            context.abort(
+                grpc.StatusCode.NOT_FOUND,
+                f"unknown service {request.service!r}",
+            )
         if st == health_proto.NOT_SERVING:
             reason = self._reason(request.service)
             if reason:
@@ -558,15 +558,6 @@ class GrpcHealthService:
                     ((HEALTH_REASON_METADATA_KEY, reason),)
                 )
         return health_proto.HealthCheckResponse(status=st)
-
-    def Check(self, request, context):
-        resp = self._check_response(request, context)
-        if resp is None:
-            context.abort(
-                grpc.StatusCode.NOT_FOUND,
-                f"unknown service {request.service!r}",
-            )
-        return resp
 
     def Watch(self, request, context):
         """grpc.health.v1 streaming Watch: current status immediately,
@@ -588,32 +579,6 @@ class GrpcHealthService:
         """Test seam: one Watch evaluation without the stream loop."""
         st = self._status(request.service)
         return health_proto.SERVICE_UNKNOWN if st is None else st
-
-
-class AioGrpcHealthService(GrpcHealthService):
-    """Same status logic on the coroutine server (context.abort awaits)."""
-
-    async def Check(self, request, context):
-        resp = self._check_response(request, context)
-        if resp is None:
-            await context.abort(
-                grpc.StatusCode.NOT_FOUND,
-                f"unknown service {request.service!r}",
-            )
-        return resp
-
-    async def Watch(self, request, context):
-        import asyncio
-
-        last = None
-        while True:
-            st = self._status(request.service)
-            if st is None:
-                st = health_proto.SERVICE_UNKNOWN
-            if st != last:
-                last = st
-                yield health_proto.HealthCheckResponse(status=st)
-            await asyncio.sleep(self.watch_poll_s)
 
 
 def _add_uds_port(server, uds_path: str) -> None:
@@ -821,283 +786,6 @@ def load_ssl_credentials(path) -> "grpc.ServerCredentials":
         root_certificates=cfg.custom_ca.encode() if cfg.custom_ca else None,
         require_client_auth=cfg.client_verify,
     )
-
-
-class _AioServicerBase:
-    """Shared adapter plumbing for grpc.aio servicers: ServiceError ->
-    status mapping (coroutine- and plain-callable-aware) + per-RPC
-    metrics. Mirrors _SyncServicerBase."""
-
-    def __init__(self, impl: PredictionServiceImpl, metrics: ServerMetrics | None = None):
-        self.impl = impl
-        self.metrics = metrics or ServerMetrics()
-
-    async def _call(self, name: str, fn, request, context):
-        t0 = time.perf_counter()
-        ok = False
-        model = _model_of(request)
-        overload_on = overload_mod.active()
-        if overload_on:
-            overload_mod.consume_degraded()  # clear a failed predecessor's marker
-        if tracing.enabled():
-            span_ctx = tracing.start_root(
-                f"server.{name}",
-                traceparent=_traceparent_of(context),
-                attrs={"entrypoint": name, **({"model": model} if model else {})},
-            )
-            try:
-                await context.send_initial_metadata(
-                    ((_PEER_ROLE_KEY, "replica"),)
-                )
-            except Exception:  # noqa: BLE001 — advisory only
-                pass
-        else:
-            span_ctx = None
-        try:
-            if span_ctx is not None:
-                # Sync `with` is correct across awaits here: contextvars
-                # are coroutine-scoped, so the span stays current through
-                # the await and resets on exit.
-                with span_ctx:
-                    resp = fn(request)
-                    if hasattr(resp, "__await__"):
-                        resp = await resp
-            else:
-                resp = fn(request)
-                if hasattr(resp, "__await__"):
-                    resp = await resp
-            ok = True
-            if overload_on:
-                _push_overload_metadata(context, None)
-            return resp
-        except ServiceError as e:
-            if overload_on:
-                _push_overload_metadata(context, e)
-            await context.abort(_status(e.code), str(e))
-        except grpc.aio.AbortError:
-            raise
-        except Exception as e:  # internal bug: surface as INTERNAL, keep serving
-            log.exception("internal error serving %s", name)
-            await context.abort(grpc.StatusCode.INTERNAL, f"internal error: {e}")
-        finally:
-            self.metrics.observe(name, time.perf_counter() - t0, ok, model=model)
-
-
-class AioGrpcPredictionService(_AioServicerBase):
-    """grpc.aio servicer adapter: one event-loop thread carries every
-    in-flight RPC instead of a handler thread each.
-
-    On a single-core serving host the thread-per-RPC model's GIL hand-offs
-    and context switches are a first-order cost (round-3 load experiment:
-    ~15% of achievable QPS at 64-way concurrency); the coroutine model keeps
-    the hot paths on one thread and awaits the batcher future:
-    Predict/Classify/Regress all ride their _async impl variants.
-    GetModelMetadata runs its (cheap, synchronous) body inline;
-    MultiInference — whose sub-calls block on batcher futures for a
-    client-controlled deadline — dispatches to a worker thread so it can
-    never stall the loop that carries every other in-flight RPC.
-    """
-
-    async def Predict(self, request, context):
-        deadline_s = _deadline_of(context)
-        crit = _criticality_of(context)
-        int8_wire = _score_wire_of(context)
-        input_crc = _input_crc_of(context, self.impl)
-
-        async def handler(req):
-            resp = await self.impl.predict_async(
-                req, deadline_s=deadline_s, criticality=crit,
-                int8_wire=int8_wire, input_crc=input_crc,
-            )
-            if self.impl.integrity is not None:
-                _stamp_response_crc(self.impl, context, resp)
-            return resp
-
-        return await self._call("Predict", handler, request, context)
-
-    async def Classify(self, request, context):
-        deadline_s = _deadline_of(context)
-        crit = _criticality_of(context)
-        return await self._call(
-            "Classify",
-            lambda req: self.impl.classify_async(
-                req, deadline_s=deadline_s, criticality=crit
-            ),
-            request, context,
-        )
-
-    async def Regress(self, request, context):
-        deadline_s = _deadline_of(context)
-        crit = _criticality_of(context)
-        return await self._call(
-            "Regress",
-            lambda req: self.impl.regress_async(
-                req, deadline_s=deadline_s, criticality=crit
-            ),
-            request, context,
-        )
-
-    async def MultiInference(self, request, context):
-        import asyncio
-
-        # Off the event loop: multi_inference's sequential sub-calls BLOCK
-        # on batcher futures (there is no *_async variant), and with
-        # deadline propagation that stall window is client-controlled — one
-        # MultiInference with a long deadline against a saturated batcher
-        # must not freeze every other in-flight RPC.
-        deadline_s = _deadline_of(context)
-        crit = _criticality_of(context)
-        entry_t = time.perf_counter()
-        loop = asyncio.get_running_loop()
-
-        def run(req, _fn=self.impl.multi_inference):
-            overload_on = overload_mod.active()
-            if overload_on:
-                # Pool threads keep their contextvar context across uses:
-                # drop any marker a FAILED earlier request left behind.
-                overload_mod.consume_degraded()
-            # Re-derive the REMAINING budget at executor start: time spent
-            # queued behind other executor work belongs to the client's
-            # budget, not on top of it.
-            left = (
-                None if deadline_s is None
-                else deadline_s - (time.perf_counter() - entry_t)
-            )
-            resp = _fn(req, deadline_s=left, criticality=crit)
-            # run_in_executor does NOT propagate contextvars back, so a
-            # brownout stale-serve marker set in THIS thread must ride the
-            # return value or the aio transport would mark stale results
-            # fresh.
-            return resp, (
-                overload_mod.consume_degraded() if overload_on else None
-            )
-
-        async def dispatch(req):
-            resp, degraded = await loop.run_in_executor(None, run, req)
-            if degraded:
-                overload_mod.mark_degraded(degraded)
-            return resp
-
-        return await self._call("MultiInference", dispatch, request, context)
-
-    async def GetModelMetadata(self, request, context):
-        return await self._call("GetModelMetadata", self.impl.get_model_metadata, request, context)
-
-    async def PredictStream(self, request, context):
-        """Server-streaming Predict on the coroutine server: an async
-        generator awaiting each sub-batch completion on the event loop —
-        same error mapping / metrics / tracing shape as _call, inlined
-        because the stream must YIELD through the adapter."""
-        t0 = time.perf_counter()
-        ok = False
-        model = _model_of(request)
-        overload_on = overload_mod.active()
-        if overload_on:
-            overload_mod.consume_degraded()
-        deadline_s = _deadline_of(context)
-        crit = _criticality_of(context)
-        chunk = _stream_chunk_of(context)
-        if tracing.enabled():
-            span_ctx = tracing.start_root(
-                "server.PredictStream",
-                traceparent=_traceparent_of(context),
-                attrs={"entrypoint": "PredictStream",
-                       **({"model": model} if model else {})},
-            )
-            try:
-                await context.send_initial_metadata(
-                    ((_PEER_ROLE_KEY, "replica"),)
-                )
-            except Exception:  # noqa: BLE001 — advisory only
-                pass
-        else:
-            span_ctx = None
-        try:
-            agen = self.impl.predict_stream_async(
-                request, deadline_s=deadline_s, criticality=crit, chunk=chunk
-            )
-            if span_ctx is not None:
-                # Sync `with` across awaits: contextvars are coroutine-
-                # scoped (the _call precedent).
-                with span_ctx:
-                    async for item in agen:
-                        yield item
-            else:
-                async for item in agen:
-                    yield item
-            ok = True
-            if overload_on:
-                _push_overload_metadata(context, None)
-        except ServiceError as e:
-            if overload_on:
-                _push_overload_metadata(context, e)
-            await context.abort(_status(e.code), str(e))
-        except grpc.aio.AbortError:
-            raise
-        except Exception as e:  # internal bug: surface as INTERNAL, keep serving
-            log.exception("internal error serving PredictStream")
-            await context.abort(grpc.StatusCode.INTERNAL, f"internal error: {e}")
-        finally:
-            self.metrics.observe(
-                "PredictStream", time.perf_counter() - t0, ok, model=model
-            )
-
-
-class AioGrpcModelService(_AioServicerBase):
-    """ModelService on the coroutine server: GetModelStatus is a cheap
-    registry read and runs inline on the loop through the shared _call
-    error mapping. Reload is inline ONLY for the label-flip mode; a
-    multi-model lifecycle reload loads/warms whole models, which would
-    stall every in-flight RPC on the single event-loop thread — it rides
-    a worker thread instead (the lifecycle lock already serializes
-    concurrent reloads, so off-loop dispatch adds no new interleaving)."""
-
-    async def GetModelStatus(self, request, context):
-        return await self._call("GetModelStatus", self.impl.get_model_status, request, context)
-
-    async def HandleReloadConfigRequest(self, request, context):
-        import asyncio
-
-        fn = self.impl.handle_reload_config
-        if self.impl.model_lifecycle is not None:
-            loop = asyncio.get_running_loop()
-
-            def dispatch(req, _fn=fn):
-                # run_in_executor returns an awaitable future; _call awaits
-                # it, keeping the loop free while the reload loads models.
-                return loop.run_in_executor(None, _fn, req)
-
-            fn = dispatch
-        return await self._call("HandleReloadConfigRequest", fn, request, context)
-
-
-def create_server_async(
-    impl: PredictionServiceImpl,
-    address: str = "127.0.0.1:0",
-    metrics: ServerMetrics | None = None,
-    uds_path: str | None = None,
-) -> tuple["grpc.aio.Server", int]:
-    """Build (not start) a grpc.aio server; returns (server, bound_port).
-    Must be called from (or started on) the event loop that will own it.
-    `uds_path` additionally binds a Unix-domain socket ([transport]
-    uds_path) for co-located clients."""
-    server = grpc.aio.server(
-        options=list(LARGE_MESSAGE_CHANNEL_OPTIONS) + list(KEEPALIVE_SERVER_OPTIONS),
-    )
-    servicer = AioGrpcPredictionService(impl, metrics)
-    add_PredictionServiceServicer_to_server(servicer, server)
-    # Same port, second service — exactly tensorflow_model_server's layout.
-    add_ModelServiceServicer_to_server(
-        AioGrpcModelService(impl, servicer.metrics), server
-    )
-    # grpc.health.v1 on the coroutine server too (same status logic).
-    add_HealthServicer_to_server(AioGrpcHealthService(impl), server)
-    port = server.add_insecure_port(address)
-    if port == 0:
-        raise RuntimeError(f"could not bind {address}")
-    if uds_path:
-        _add_uds_port(server, uds_path)
-    return server, port
 
 
 def load_demo_servable(
@@ -1463,7 +1151,7 @@ class GracefulShutdown:
         # gossip before their next health probe — then stopped with the
         # transport.
         self.fleet = None
-        self.server = None  # attached once created (create_server[_async])
+        self.server = None  # attached once created (create_server)
         self.drained: bool | None = None
         self._lock = threading.Lock()
         self._started = False
@@ -2067,7 +1755,7 @@ def build_stack(
         # Data-integrity plane (serving/integrity.py, ISSUE 20): ONE
         # plane object shared by every hook site — the batcher (shadow
         # sampling + readback screens + escalation), the impl (input CRC
-        # verify, response stamping, /integrityz), and the transports
+        # verify, response stamping, /integrityz), and the gRPC adapters
         # (metadata read/write) all reach the same counters.
         integrity_plane = integrity_config.build()
         batcher.integrity = integrity_plane

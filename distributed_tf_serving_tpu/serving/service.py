@@ -693,8 +693,9 @@ class PredictionServiceImpl:
         return arrays
 
     # Bounded wait: a wedged batcher must not permanently consume an RPC
-    # handler thread / event-loop slot (first compile of a large bucket
-    # through a remote-compile path can legitimately take tens of seconds).
+    # handler thread or a REST request's task (first compile of a large
+    # bucket through a remote-compile path can legitimately take tens of
+    # seconds).
     _BATCH_DEADLINE_S = 120.0
 
     @staticmethod
@@ -800,7 +801,8 @@ class PredictionServiceImpl:
             # attach queue/device/readback child spans per request. This
             # thread sleeps on the Future next, so it may as well cross the
             # batcher itself where the request is alone (_may_block; never
-            # from _run_async, whose thread is every RPC's).
+            # from _run_async, whose thread is the REST gateway's event
+            # loop and carries every HTTP request).
             fut = self.batcher.submit(
                 servable, arrays, output_keys=output_keys,
                 deadline_s=deadline_s, span=tracing.current_span(),
@@ -822,12 +824,10 @@ class PredictionServiceImpl:
         criticality: str | None = None,
         prune_k: int = 0,
     ) -> dict[str, np.ndarray]:
-        """_run for coroutine servers (server.create_server_async): the
-        batcher Future is awaited instead of blocked on, so one event-loop
-        thread carries every in-flight RPC — on a single-core host the
-        handler-thread-per-RPC model spends a measurable slice of the whole
-        CPU budget on GIL hand-offs and context switches (round-3 load
-        experiment: 72 threads cost ~15% of achievable QPS)."""
+        """_run for the REST gateway's event loop (serving/rest.py, the only
+        coroutine caller): the batcher Future is awaited instead of blocked
+        on, so the gateway's one thread carries every in-flight HTTP
+        request."""
         import asyncio
 
         timeout = self._effective_timeout(deadline_s)
@@ -981,8 +981,8 @@ class PredictionServiceImpl:
         criticality: str | None = None, int8_wire: bool = False,
         input_crc: str | None = None,
     ) -> apis.PredictResponse:
-        """Predict for coroutine servers: identical semantics, awaits the
-        batch instead of blocking a handler thread on it."""
+        """Predict for the REST gateway's event loop: identical semantics,
+        awaits the batch instead of blocking a handler thread on it."""
         self._refuse_if_draining()
         deadline_t = self._clock_deadline(deadline_s)
         servable, arrays, out_names, fetch_keys = self._predict_prepare(
@@ -1150,8 +1150,8 @@ class PredictionServiceImpl:
     def _stream_submit(
         self, request, deadline_t, criticality, chunk
     ):
-        """Shared front half of both predict_stream flavors: resolve,
-        decode, split, and submit EVERY sub-batch up front — the
+        """Front half of predict_stream: resolve, decode, split, and
+        submit EVERY sub-batch up front — the
         sub-batches ride the batcher's k-deep pipeline independently, so
         sub-batch k+1 uploads while k executes and k-1 reads back. Returns
         (servable, out_names, mirror_content, total, {future: (off, n)}).
@@ -1282,66 +1282,6 @@ class PredictionServiceImpl:
             raise
         self._log_request("predict", request)
 
-    async def predict_stream_async(
-        self, request: apis.PredictRequest, deadline_s: float | None = None,
-        criticality: str | None = None, chunk: int | None = None,
-    ):
-        """predict_stream for coroutine servers: an async generator that
-        awaits sub-batch completions instead of blocking an RPC handler
-        thread between chunks."""
-        import asyncio
-
-        self._refuse_if_draining()
-        deadline_t = self._clock_deadline(deadline_s)
-        timeout = self._effective_timeout(deadline_s)
-        give_up_t = time.perf_counter() + timeout
-        servable, out_names, mirror_content, total, futs = (
-            self._stream_submit(request, deadline_t, criticality, chunk)
-        )
-        reuse = apis.PredictStreamChunk() if self.response_arena else None
-        wrapped = {asyncio.wrap_future(f): f for f in futs}
-        pending = set(wrapped)
-        emitted = 0
-        try:
-            while pending:
-                left = give_up_t - time.perf_counter()
-                if left <= 0:
-                    raise ServiceError(
-                        "DEADLINE_EXCEEDED",
-                        "deadline expired mid-stream "
-                        f"({emitted}/{len(futs)} sub-batches delivered)",
-                    )
-                done, pending = await asyncio.wait(
-                    pending, timeout=left,
-                    return_when=asyncio.FIRST_COMPLETED,
-                )
-                if not done:
-                    continue  # loop re-checks the give-up clock
-                for task in done:
-                    try:
-                        outputs = task.result()
-                    except Exception as e:  # noqa: BLE001 — translator re-raises
-                        raise self._translate_batcher_error(
-                            e, wrapped[task]
-                        ) from e
-                    # Stale-row marker forwarding, as in the sync stream.
-                    self._consume_future_degraded(wrapped[task])
-                    off, cnt = futs[wrapped[task]]
-                    emitted += 1
-                    yield self._encode_stream_chunk(
-                        request, servable, out_names, outputs,
-                        off, cnt, total, final=emitted == len(futs),
-                        mirror_content=mirror_content, msg=reuse,
-                    )
-        except BaseException:
-            for task in pending:
-                task.cancel()
-            for f in wrapped.values():
-                if not f.done():
-                    f.cancel()
-            raise
-        self._log_request("predict", request)
-
     # ----------------------------------------------------- Classify / Regress
 
     def _examples_prepare(self, request, criticality: str | None = None):
@@ -1374,8 +1314,8 @@ class PredictionServiceImpl:
         self, request, deadline_s: float | None = None,
         criticality: str | None = None,
     ):
-        """_run_examples for coroutine servers (the REST gateway's
-        :classify/:regress routes ride the same event loop as :predict)."""
+        """_run_examples for the REST gateway: its :classify/:regress
+        routes ride the same event loop as :predict."""
         deadline_t = self._clock_deadline(deadline_s)
         servable, arrays = self._examples_prepare(request, criticality)
         outputs = await self._run_async(

@@ -455,43 +455,6 @@ def test_health_watch_sync_unknown_service_streams_service_unknown(
             call.cancel()
 
 
-def test_health_watch_aio_streams_changes():
-    from distributed_tf_serving_tpu.serving.server import (
-        AioGrpcHealthService,
-        create_server_async,
-    )
-
-    registry = ServableRegistry()
-    registry.load(_servable())
-    batcher = DynamicBatcher(buckets=(32,), max_wait_us=0).start()
-    impl = PredictionServiceImpl(registry, batcher)
-
-    async def go():
-        import grpc.aio
-
-        old = AioGrpcHealthService.watch_poll_s
-        AioGrpcHealthService.watch_poll_s = 0.05
-        server, port = create_server_async(impl, "127.0.0.1:0")
-        await server.start()
-        try:
-            async with grpc.aio.insecure_channel(f"127.0.0.1:{port}") as ch:
-                stub = health_proto.HealthStub(ch)
-                call = stub.Watch(health_proto.HealthCheckRequest(""))
-                first = (await call.read()).status
-                impl.draining = True
-                second = (await call.read()).status
-                call.cancel()
-                return first, second
-        finally:
-            AioGrpcHealthService.watch_poll_s = old
-            await server.stop(0)
-
-    first, second = asyncio.run(go())
-    assert first == health_proto.SERVING
-    assert second == health_proto.NOT_SERVING
-    batcher.stop()
-
-
 def test_check_not_serving_carries_draining_reason(two_backends):
     """The drain trailer: NOT_SERVING answers carry x-dts-health-reason
     so the client's health probe can distinguish draining (steer away,

@@ -2,13 +2,12 @@
 
 - GetModelStatus reports START (not NOT_FOUND) for a configured-but-not-
   ready model, so TF-Serving-style readiness probes survive a rollout;
-- the aio ModelService dispatches lifecycle reloads off the event loop
-  (a model load must not stall every in-flight RPC);
+- a lifecycle reload held open on the server stalls no other RPC (a model
+  load holds its own handler thread alone);
 - the CRC32C table is built eagerly at import (the lazy appender raced
   concurrent first callers, ADVICE round 5).
 """
 
-import asyncio
 import threading
 import time
 
@@ -40,6 +39,17 @@ def _impl():
     registry = ServableRegistry()
     batcher = DynamicBatcher(buckets=(32,), max_wait_us=0)
     return registry, PredictionServiceImpl(registry, batcher)
+
+
+def _load_dcn(registry):
+    model = build_model("dcn", CFG)
+    registry.load(
+        Servable(
+            name="DCN", version=1, model=model,
+            params=model.init(jax.random.PRNGKey(0)),
+            signatures=ctr_signatures(CFG.num_fields),
+        )
+    )
 
 
 def _status_request(name):
@@ -88,68 +98,73 @@ def test_get_model_status_unknown_model_stays_not_found():
 
 def test_get_model_status_loaded_still_available():
     registry, impl = _impl()
-    model = build_model("dcn", CFG)
-    registry.load(
-        Servable(
-            name="DCN", version=1, model=model,
-            params=model.init(jax.random.PRNGKey(0)),
-            signatures=ctr_signatures(CFG.num_fields),
-        )
-    )
+    _load_dcn(registry)
     impl.served_sources["DCN"] = ("/models/dcn", "dcn_v2")  # configured AND ready
     resp = impl.get_model_status(_status_request("DCN"))
     assert resp.model_version_status[0].state == apis.ModelVersionStatus.AVAILABLE
 
 
-# --------------------------------------------- aio reload off the event loop
+# ------------------------------------- a slow reload holds one handler alone
 
 
-def test_aio_lifecycle_reload_does_not_stall_event_loop():
-    """With model_lifecycle set, HandleReloadConfigRequest runs on a worker
-    thread: other coroutines keep making progress while the reload loads
-    models. Without a lifecycle, the cheap label flip stays inline."""
-    from distributed_tf_serving_tpu.serving.server import AioGrpcModelService
+def test_lifecycle_reload_held_open_does_not_stall_predict():
+    """While HandleReloadConfigRequest with a slow lifecycle reload is held
+    open on the server (a real reload loads and warms a model there), a
+    Predict on another connection is answered: the reload holds its own
+    handler thread and nothing the other RPCs need."""
+    import grpc
 
-    release = threading.Event()
+    from distributed_tf_serving_tpu.client import build_predict_request
+    from distributed_tf_serving_tpu.proto import ModelServiceStub, PredictionServiceStub
+    from distributed_tf_serving_tpu.serving import create_server
+
+    entered, release = threading.Event(), threading.Event()
     applied = []
 
     class SlowLifecycle:
         def apply(self, entries):
-            # A real reload loads+warms a model here; a stalled loop would
-            # freeze the heartbeat coroutine below for the duration.
+            entered.set()
             release.wait(timeout=30)
             applied.append([mc.name for mc in entries])
 
         def configured_models(self):
             return {"DCN"}
 
-    _registry, impl = _impl()
+    registry, impl = _impl()
+    _load_dcn(registry)
     impl.model_lifecycle = SlowLifecycle()
-    servicer = AioGrpcModelService(impl)
+    impl.batcher.start()
+    server, port = create_server(impl, "127.0.0.1:0")
+    server.start()
 
     req = apis.ReloadConfigRequest()
     mc = req.config.model_config_list.config.add()
     mc.name = "DCN"
     mc.base_path = "/models/dcn"
-
-    async def go():
-        beats = 0
-        reload_task = asyncio.ensure_future(
-            servicer.HandleReloadConfigRequest(req, context=None)
-        )
-        # The loop must keep beating while the reload blocks on `release`.
-        for _ in range(5):
-            await asyncio.sleep(0.01)
-            beats += 1
-        assert not reload_task.done()  # reload is parked on the worker thread
+    rng = np.random.RandomState(5)
+    arrays = {
+        "feat_ids": rng.randint(0, 1 << 40, size=(4, CFG.num_fields)).astype(np.int64),
+        "feat_wts": rng.rand(4, CFG.num_fields).astype(np.float32),
+    }
+    try:
+        with grpc.insecure_channel(f"127.0.0.1:{port}") as reload_ch, \
+                grpc.insecure_channel(f"127.0.0.1:{port}") as predict_ch:
+            reload_call = ModelServiceStub(reload_ch).HandleReloadConfigRequest.future(
+                req, timeout=60
+            )
+            assert entered.wait(timeout=30)
+            resp = PredictionServiceStub(predict_ch).Predict(
+                build_predict_request(arrays, "DCN"), timeout=30
+            )
+            assert resp.outputs["prediction_node"].tensor_shape.dim[0].size == 4
+            assert not reload_call.done()  # the reload is still parked
+            release.set()
+            assert reload_call.result(timeout=30).status.error_code == 0
+        assert applied == [["DCN"]]
+    finally:
         release.set()
-        resp = await asyncio.wait_for(reload_task, timeout=30)
-        return beats, resp
-
-    beats, resp = asyncio.run(go())
-    assert beats == 5
-    assert resp.status.error_code == 0
-    assert applied == [["DCN"]]
+        server.stop(0)
+        impl.batcher.stop()
 
 
 # --------------------------------------------------------- CRC table safety

@@ -591,46 +591,6 @@ def test_server_started_from_build_stacks_hook_refuses_until_warm():
         batcher.stop()
 
 
-def test_health_service_aio_server():
-    from distributed_tf_serving_tpu.serving.server import create_server_async
-
-    registry = ServableRegistry()
-    registry.load(_servable())
-    batcher = DynamicBatcher(buckets=(32,), max_wait_us=0).start()
-    impl = PredictionServiceImpl(registry, batcher)
-
-    async def go():
-        import grpc.aio
-
-        server, port = create_server_async(impl, "127.0.0.1:0")
-        await server.start()
-        try:
-            async with grpc.aio.insecure_channel(f"127.0.0.1:{port}") as ch:
-                stub = health_proto.HealthStub(ch)
-                overall = await stub.Check(
-                    health_proto.HealthCheckRequest(""), timeout=5
-                )
-                model = await stub.Check(
-                    health_proto.HealthCheckRequest("DCN"), timeout=5
-                )
-                try:
-                    await stub.Check(
-                        health_proto.HealthCheckRequest("NOPE"), timeout=5
-                    )
-                    unknown = None
-                except grpc.aio.AioRpcError as e:
-                    unknown = e.code()
-                return overall.status, model.status, unknown
-        finally:
-            await server.stop(0)
-
-    overall, model, unknown = asyncio.run(go())
-    assert overall == health_proto.SERVING
-    assert model == health_proto.SERVING
-    assert unknown == grpc.StatusCode.NOT_FOUND
-    batcher.stop()
-
-
 def test_client_half_open_health_probe(three_backends):
     """health_probe=True: a half-open backend is probed with a
     grpc.health.v1 Check (cheap) before any real shard lands on it."""

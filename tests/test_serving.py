@@ -178,54 +178,50 @@ def test_version_label_errors(stack):
         registry.set_label("DCN", "broken", 99)
 
 
-def test_aio_server_classify_regress_async_path(stack):
-    """Classify/Regress on the COROUTINE server ride their _async impl
-    variants (the event loop must not block on the batch): same scores as
-    the sync server, over a real aio socket."""
-    import asyncio
+def _example_request(cls, seed):
+    rng = np.random.RandomState(seed)
+    req = cls()
+    req.model_spec.name = "DCN"
+    for _ in range(3):
+        req.input.example_list.examples.append(make_example(
+            rng.randint(0, 1 << 40, size=CFG.num_fields).astype(np.int64),
+            rng.rand(CFG.num_fields).astype(np.float32),
+        ))
+    return req
 
-    from distributed_tf_serving_tpu.proto import PredictionServiceStub
-    from distributed_tf_serving_tpu.serving.example_codec import make_example
-    from distributed_tf_serving_tpu.serving.server import create_server_async
 
-    registry, impl, _port = stack
-    rng = np.random.RandomState(31)
-    ids = rng.randint(0, 1 << 40, size=(3, CFG.num_fields)).astype(np.int64)
-    wts = rng.rand(3, CFG.num_fields).astype(np.float32)
+_COROUTINE_VARIANTS = {
+    "predict": (
+        lambda seed: build_predict_request(_arrays(seed=seed), "DCN"),
+        lambda resp: codec.to_ndarray(resp.outputs["prediction_node"]),
+    ),
+    "classify": (
+        lambda seed: _example_request(apis.ClassificationRequest, seed),
+        lambda resp: [c.classes[1].score for c in resp.result.classifications],
+    ),
+    "regress": (
+        lambda seed: _example_request(apis.RegressionRequest, seed),
+        lambda resp: [r.value for r in resp.result.regressions],
+    ),
+}
 
-    creq = apis.ClassificationRequest()
-    creq.model_spec.name = "DCN"
-    for i in range(3):
-        creq.input.example_list.examples.append(make_example(ids[i], wts[i]))
-    rreq = apis.RegressionRequest()
-    rreq.model_spec.name = "DCN"
-    rreq.input.CopyFrom(creq.input)
-    sync_scores = [
-        c.classes[1].score for c in impl.classify(creq).result.classifications
-    ]
-    sync_reg = [r.value for r in impl.regress(rreq).result.regressions]
+
+@pytest.mark.parametrize("method", sorted(_COROUTINE_VARIANTS))
+def test_coroutine_variant_matches_sync_method(stack, method):
+    """The `_async` variant of each method (what the REST gateway's event
+    loop rides): several calls awaiting the batcher on ONE loop thread give
+    the sync method's scores."""
+    _registry, impl, _port = stack
+    make, scores = _COROUTINE_VARIANTS[method]
+    requests = [make(seed) for seed in (31, 32, 33)]
+    want = [scores(getattr(impl, method)(req)) for req in requests]
 
     async def go():
-        server, port = create_server_async(impl, "127.0.0.1:0")
-        await server.start()
-        try:
-            async with grpc.aio.insecure_channel(f"127.0.0.1:{port}") as ch:
-                stub = PredictionServiceStub(ch)
-                # Concurrent: both await the batcher on ONE loop thread.
-                cresp, rresp = await asyncio.gather(
-                    stub.Classify(creq, timeout=60),
-                    stub.Regress(rreq, timeout=60),
-                )
-                return (
-                    [c.classes[1].score for c in cresp.result.classifications],
-                    [r.value for r in rresp.result.regressions],
-                )
-        finally:
-            await server.stop(0)
+        variant = getattr(impl, f"{method}_async")
+        return await asyncio.gather(*(variant(req) for req in requests))
 
-    aio_scores, aio_reg = asyncio.run(go())
-    np.testing.assert_allclose(aio_scores, sync_scores, rtol=1e-6)
-    np.testing.assert_allclose(aio_reg, sync_reg, rtol=1e-6)
+    for resp, expected in zip(asyncio.run(go()), want):
+        np.testing.assert_allclose(scores(resp), expected, rtol=1e-6)
 
 
 def test_model_service_get_model_status(stack):
@@ -756,52 +752,16 @@ def test_fanout_failover_exhaustion_raises_last_host():
     assert getattr(ei.value.code, "name", "") == "UNAVAILABLE"
 
 
-# ------------------------------------- aio server + prepared-request client
+# ----------------------------------------- who crosses the batcher direct
 
 
-def test_aio_server_prepared_and_plain_paths_match_golden():
-    """The coroutine server (create_server_async) + the prepared-bytes client
-    path must produce byte-identical scores to the threaded server + per-call
-    build path — same wire protocol, different machinery on both ends."""
-    from distributed_tf_serving_tpu.serving.server import create_server_async
-
-    registry = ServableRegistry()
-    servable = _servable(version=1, seed=0)
-    registry.load(servable)
-    batcher = DynamicBatcher(buckets=(32, 128), max_wait_us=0).start()
-    impl = PredictionServiceImpl(registry, batcher)
-    arrays = _arrays(n=10, seed=21)
-    want = _golden(servable, arrays)
-
-    async def go():
-        server, port = create_server_async(impl, "127.0.0.1:0")
-        await server.start()
-        try:
-            async with ShardedPredictClient([f"127.0.0.1:{port}"], "DCN") as client:
-                plain = await client.predict(arrays)
-                prep = client.prepare(arrays)
-                prepared = await client.predict_prepared(prep)
-                prepared_sorted = await client.predict_prepared(prep, sort_scores=True)
-                return plain, prepared, prepared_sorted
-        finally:
-            await server.stop(0)
-
-    plain, prepared, prepared_sorted = asyncio.run(go())
-    np.testing.assert_allclose(plain, want, rtol=1e-6)
-    # Identical wire bytes through the identical server path: bitwise equal.
-    np.testing.assert_array_equal(prepared, plain)
-    np.testing.assert_array_equal(prepared_sorted, np.sort(plain))
-    batcher.stop()
-
-
-@pytest.mark.parametrize("transport", ["sync", "aio"])
-def test_only_a_handler_thread_crosses_the_batcher_direct(transport):
+@pytest.mark.parametrize("caller", ["sync", "coroutine"])
+def test_only_a_handler_thread_crosses_the_batcher_direct(caller):
     """Below the load at which batches share anything, a request over the
-    sync transport is staged by its own handler thread, which would sleep on
-    the Future anyway; the asyncio transport's one loop thread never is
+    gRPC transport is staged by its own handler thread, which would sleep on
+    the Future anyway; a coroutine caller (the REST gateway's event loop,
+    here `impl.predict_async` on a loop of the test's) never is
     (service._run_async does not say it may block)."""
-    from distributed_tf_serving_tpu.serving.server import create_server_async
-
     registry = ServableRegistry()
     servable = _servable(version=1, seed=0)
     registry.load(servable)
@@ -816,20 +776,15 @@ def test_only_a_handler_thread_crosses_the_batcher_direct(transport):
             batcher._last_arrival_t = None
 
     async def go():
-        server, port = create_server_async(impl, "127.0.0.1:0")
-        await server.start()
-        try:
-            async with ShardedPredictClient([f"127.0.0.1:{port}"], "DCN") as client:
-                out = []
-                for _ in range(3):
-                    trickle()
-                    out.append(await client.predict(arrays))
-                return out
-        finally:
-            await server.stop(0)
+        out = []
+        for _ in range(3):
+            trickle()
+            resp = await impl.predict_async(build_predict_request(arrays, "DCN"))
+            out.append(codec.to_ndarray(resp.outputs["prediction_node"]))
+        return out
 
     try:
-        if transport == "aio":
+        if caller == "coroutine":
             got = asyncio.run(go())
         else:
             server, port = create_server(impl, "127.0.0.1:0")
@@ -845,53 +800,9 @@ def test_only_a_handler_thread_crosses_the_batcher_direct(transport):
             np.testing.assert_allclose(scores, want, rtol=1e-6)
         assert batcher.stats.batches == 3
         # The first sync request may find the collector not parked yet.
-        assert batcher.stats.direct_batches == 0 if transport == "aio" else batcher.stats.direct_batches >= 2
+        assert batcher.stats.direct_batches == 0 if caller == "coroutine" else batcher.stats.direct_batches >= 2
     finally:
         batcher.stop()
-
-
-def test_aio_server_error_codes():
-    """ServiceError mapping must survive the coroutine adapter: unknown model
-    -> NOT_FOUND, malformed tensor -> INVALID_ARGUMENT."""
-    from distributed_tf_serving_tpu.serving.server import create_server_async
-
-    registry = ServableRegistry()
-    registry.load(_servable(version=1, seed=0))
-    batcher = DynamicBatcher(buckets=(32, 128), max_wait_us=0).start()
-    impl = PredictionServiceImpl(registry, batcher)
-
-    async def go():
-        import grpc.aio
-
-        server, port = create_server_async(impl, "127.0.0.1:0")
-        await server.start()
-        codes = []
-        try:
-            async with grpc.aio.insecure_channel(f"127.0.0.1:{port}") as ch:
-                from distributed_tf_serving_tpu.proto import PredictionServiceStub
-
-                stub = PredictionServiceStub(ch)
-                for req in (
-                    build_predict_request(_arrays(), "NOPE"),
-                    _bad_count_request(),
-                ):
-                    try:
-                        await stub.Predict(req, timeout=10)
-                        codes.append(None)
-                    except grpc.aio.AioRpcError as e:
-                        codes.append(e.code())
-        finally:
-            await server.stop(0)
-        return codes
-
-    def _bad_count_request():
-        bad = build_predict_request(_arrays(), "DCN", use_tensor_content=False)
-        bad.inputs["feat_ids"].int64_val.append(0)
-        return bad
-
-    codes = asyncio.run(go())
-    assert codes == [grpc.StatusCode.NOT_FOUND, grpc.StatusCode.INVALID_ARGUMENT]
-    batcher.stop()
 
 
 def test_prepared_request_against_threaded_server(three_backends):

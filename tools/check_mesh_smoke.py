@@ -14,8 +14,8 @@ asserting the [mesh] serving mode's contract end to end over REAL gRPC on
   series answer over HTTP, with per-device occupancy attribution when the
   utilization ledger rides along.
 
-Prints one JSON line; exit 0 = gate passed. Run by tools/ci_tier1.sh under
-TIER1_MESH_SMOKE=1.
+Prints one JSON line; exit 0 = gate passed. Run in tier-1 by
+tests/test_tool_smokes.py.
 """
 
 import asyncio
@@ -46,7 +46,7 @@ from distributed_tf_serving_tpu.models import (  # noqa: E402
 )
 from distributed_tf_serving_tpu.serving.server import (  # noqa: E402
     build_stack,
-    create_server_async,
+    create_server,
     start_rest_in_thread,
 )
 from distributed_tf_serving_tpu.train import Trainer  # noqa: E402
@@ -77,15 +77,15 @@ def _server_cfg() -> ServerConfig:
 
 
 async def _score_over_grpc(impl, payloads, deadline_s=5.0):
-    server, port = create_server_async(impl, "127.0.0.1:0")
-    await server.start()
+    server, port = create_server(impl, "127.0.0.1:0")
+    server.start()
     try:
         async with ShardedPredictClient(
             [f"127.0.0.1:{port}"], "DCN", timeout_s=deadline_s,
         ) as client:
             return [np.asarray(await client.predict(p)) for p in payloads]
     finally:
-        await server.stop(0)
+        server.stop(0).wait()
 
 
 async def _probe_http(port: int, out: dict) -> None:

@@ -8,12 +8,12 @@ gate asserting the PR's correctness contract end to end over REAL gRPC —
   of order;
 - the client's incremental merge survives the out-of-order arrival and
   records first-scores latency;
-- the k-deep pipeline (depth 4, in-flight window 4, buffer ring) serves
+- the k-deep pipeline (depth 4, in-flight window 4) serves
   the same scores as the defaults would;
 - a mid-stream deadline aborts DEADLINE_EXCEEDED instead of hanging.
 
-Prints one JSON line; exit 0 = gate passed. Run by tools/ci_tier1.sh under
-TIER1_STREAMING_SMOKE=1.
+Prints one JSON line; exit 0 = gate passed. Run in tier-1 by
+tests/test_tool_smokes.py.
 """
 
 import asyncio
@@ -36,7 +36,7 @@ from distributed_tf_serving_tpu.client import (  # noqa: E402
 from distributed_tf_serving_tpu.models import ServableRegistry  # noqa: E402
 from distributed_tf_serving_tpu.serving.batcher import DynamicBatcher  # noqa: E402
 from distributed_tf_serving_tpu.serving.server import (  # noqa: E402
-    create_server_async,
+    create_server,
     load_demo_servable,
 )
 from distributed_tf_serving_tpu.serving.service import (  # noqa: E402
@@ -56,7 +56,6 @@ def build_stack():
         max_wait_us=200,
         pipeline_depth=4,
         inflight_window=4,
-        buffer_ring=True,
     ).start()
     servable = load_demo_servable(
         registry, kind="dcn_v2", name="DCN",
@@ -72,8 +71,8 @@ def build_stack():
 async def main() -> dict:
     _registry, batcher, impl = build_stack()
     uds = os.path.join(tempfile.gettempdir(), f"dts_smoke_{os.getpid()}.sock")
-    server, port = create_server_async(impl, "127.0.0.1:0", uds_path=uds)
-    await server.start()
+    server, port = create_server(impl, "127.0.0.1:0", uds_path=uds)
+    server.start()
     out = {
         "bit_identical": {},
         "out_of_order_seen": False,
@@ -153,14 +152,11 @@ async def main() -> dict:
                 "inflight_peak < 2: sub-batches never overlapped "
                 f"({out['pipeline']})"
             )
-        ring = out["pipeline"].get("buffer_ring") or {}
-        if not ring.get("reuses"):
-            out["errors"].append(f"buffer ring never reused: {ring}")
         if not all(out["bit_identical"].values()) or len(out["bit_identical"]) != 2:
             out["errors"].append("bit-identity did not hold on both transports")
     finally:
         faults.reset()
-        await server.stop(0)
+        server.stop(0).wait()
         batcher.stop()
         try:
             os.unlink(uds)

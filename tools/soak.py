@@ -937,7 +937,7 @@ def main() -> None:
     )
     from distributed_tf_serving_tpu.serving import DynamicBatcher, PredictionServiceImpl
     from distributed_tf_serving_tpu.serving.rest import start_rest_gateway
-    from distributed_tf_serving_tpu.serving.server import create_server_async
+    from distributed_tf_serving_tpu.serving.server import create_server
 
     platform = jax.devices()[0].platform
     tpu = platform != "cpu"
@@ -2262,8 +2262,8 @@ def main() -> None:
     client_counters: list[dict] = []
 
     async def drive():
-        server, gport = create_server_async(impl, "127.0.0.1:0")
-        await server.start()
+        server, gport = create_server(impl, "127.0.0.1:0")
+        server.start()
         runner, rport = await start_rest_gateway(impl, port=0)
         try:
             client_kwargs = dict(
@@ -2422,7 +2422,7 @@ def main() -> None:
                             trace_block["error"] = f"{type(e).__name__}: {e}"
         finally:
             await runner.cleanup()
-            await server.stop(0)
+            server.stop(0).wait()
 
     t0 = time.perf_counter()
     try:
