@@ -32,8 +32,8 @@ Batch = dict[str, jax.Array]
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
-    """Knob set shared by the CTR model zoo and the four sequence families
-    (phi4flash, pangu_moe, exaone_moe, olmo_hybrid), whose keys carry the
+    """Knob set shared by the CTR model zoo and the five sequence families
+    (phi4flash, pangu_moe, exaone_moe, olmo_hybrid, mimo_v2), whose keys carry the
     names of their published config.json and whose defaults build a small
     valid model.
 
@@ -128,6 +128,28 @@ class ModelConfig:
     linear_value_head_dim: int = 16
     linear_conv_kernel_dim: int = 4
     linear_allow_neg_eigval: bool = True
+    # mimo_v2 (models/mimo_v2.py): a row is num_fields token ids as above, and
+    # the keys shared with pangu_moe and exaone_moe mean what they mean there
+    # (embed_dim, intermediate_size, moe_intermediate_size, n_routed_experts the
+    # ROUTER's width, num_experts_per_tok, routed_scaling_factor, experts_held,
+    # first_expert_held, layer_norm_eps, sliding_window, num_attention_heads;
+    # head_dim the keys' and queries' width, v_head_dim the values';
+    # num_key_value_heads and rope_theta are the FULL layers'). Under the
+    # published config.json's names: every layer's attention kind (0 full, 1
+    # window; empty => the published pattern, layer 0 and every sixth from
+    # layer 5 on full), every layer's FFN (0 dense, 1 routed; empty => layer 0
+    # dense), the WINDOW layers' key-value heads (0 => num_key_value_heads)
+    # and rotary base, the share of a head's dims the rotary turns (the first
+    # int(head_dim * factor)), what the values are multiplied by, and which
+    # kind of layer's softmax holds a learned sink logit a head.
+    hybrid_layer_pattern: tuple[int, ...] = ()
+    moe_layer_freq: tuple[int, ...] = ()
+    swa_num_key_value_heads: int = 0
+    swa_rope_theta: float = 10000.0
+    partial_rotary_factor: float = 1.0
+    attention_value_scale: float = 1.0
+    add_swa_attention_sink_bias: bool = True
+    add_full_attention_sink_bias: bool = False
     # numerics
     compute_dtype: str = "bfloat16"  # "float32" for AUC-parity mode
     param_dtype: str = "float32"
@@ -186,21 +208,23 @@ class Model:
     # beside the id/weight pair (the DLRM families).
     takes_dense: bool = False
     # The kind of every layer of a sequence family (phi4flash, pangu_moe, exaone_moe,
-    # olmo_hybrid), whose rows are num_fields TOKENS; empty for the CTR families.
+    # olmo_hybrid, mimo_v2), whose rows are num_fields TOKENS; empty for the CTR families.
     layer_plan: tuple[str, ...] = ()
     # What a family with a routed layer holds of it, as (name, number) pairs:
     # published, held, first, top_k, heads_published, heads_held,
-    # chips_sharing_layer (pangu_moe, exaone_moe); empty for every other family.
+    # chips_sharing_layer (pangu_moe, exaone_moe, mimo_v2); empty for every other family.
     expert_plan: tuple[tuple[str, int], ...] = ()
     # For a family whose mixer differs by layer, as (name, value) pairs a
     # layer: an attention layer's kind, window, block of queries and keys a
-    # block (exaone_moe, olmo_hybrid); a linear layer's kind, chunk, state
-    # hand-overs a row and bytes of a row's state (olmo_hybrid); empty for
-    # every other family.
+    # block (exaone_moe, olmo_hybrid, mimo_v2, which adds what else differs by
+    # kind: kv_heads, rotary_dims, theta, sink); a linear layer's kind, chunk,
+    # state hand-overs a row and bytes of a row's state (olmo_hybrid); empty
+    # for every other family.
     attention_plan: tuple[tuple[tuple[str, object], ...], ...] = ()
-    # For a family whose step counts what it did on the device (pangu_moe's
-    # and exaone_moe's routing, exaone_moe's and olmo_hybrid's score tiles,
-    # olmo_hybrid's state hand-overs): `apply_stats(params, batch) -> (apply's outputs, int32
+    # For a family whose step counts what it did on the device (pangu_moe's,
+    # exaone_moe's and mimo_v2's routing, exaone_moe's, olmo_hybrid's and
+    # mimo_v2's score tiles, olmo_hybrid's state hand-overs, mimo_v2's
+    # sinks): `apply_stats(params, batch) -> (apply's outputs, int32
     # [len(step_stats)])`, the counters named by `step_stats` in order. The
     # batcher decides on it when it BUILDS the servable's entry: the counters
     # then ride back beside the scores and are recorded as phases by count.
@@ -301,7 +325,7 @@ def register_model(kind: str):
 
 def build_model(kind: str, config: ModelConfig | None = None, **overrides) -> Model:
     """Instantiate a model family by kind: dcn, dcn_v2, wide_deep, deepfm,
-    two_tower, dlrm, dlrm_dcnv2, phi4flash, pangu_moe, exaone_moe, olmo_hybrid."""
+    two_tower, dlrm, dlrm_dcnv2, phi4flash, pangu_moe, exaone_moe, olmo_hybrid, mimo_v2."""
     if kind not in _BUILDERS:
         raise KeyError(f"unknown model kind {kind!r}; have {sorted(_BUILDERS)}")
     if config is None:

@@ -1,4 +1,4 @@
-"""What the routed sequence-ranker families (pangu_moe, exaone_moe) share
+"""What the routed sequence-ranker families (pangu_moe, exaone_moe, mimo_v2) share
 beside `sequence`'s products and blocks: the product with a weight under its
 own name (`dot`: `sequence.product`'s stacked form), the RMSNorm, the gated
 MLP, the rotary turn, the sigmoid router and the held experts' grouped
@@ -85,9 +85,13 @@ def rope_table(length: int, width: int, theta: float) -> tuple[np.ndarray, np.nd
     return np.cos(angles).astype(np.float32), np.sin(angles).astype(np.float32)
 
 
-def rotate(x: jax.Array, cos, sin) -> jax.Array:
+def rotate(x: jax.Array, cos, sin, width: int | None = None) -> jax.Array:
     """The rotary turn of `x [..., d]` by `cos`, `sin` (broadcast against
-    `[..., d / 2]`): pairs (i, i + d/2)."""
+    `[..., d / 2]`): pairs (i, i + d/2). With `width`, of the FIRST `width`
+    dims alone (pairs (i, i + width/2), `cos` and `sin` against
+    `[..., width / 2]`); the others pass unturned."""
+    if width is not None and width < x.shape[-1]:
+        return jnp.concatenate([rotate(x[..., :width], cos, sin), x[..., width:]], axis=-1)
     half = x.shape[-1] // 2
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
@@ -160,8 +164,9 @@ def held_experts(p: dict, x: jax.Array, chosen: jax.Array, gates: jax.Array, fir
 
 def routed_ffn(layer: dict, a: jax.Array, top_k: int, first: int, scaling: float, cd, count: int,
                live: jax.Array | None = None, router=None, experts=None):
-    """shared(a) + the held experts' part, `a`'s shape `[n, positions, H]`;
-    and this layer's counters, int32 `[len(STEP_STATS)]`. `live [n]` is false
+    """shared(a) + the held experts' part, `a`'s shape `[n, positions, H]`
+    (the held experts' part alone where the layer has no `shared` expert:
+    mimo_v2); and this layer's counters, int32 `[len(STEP_STATS)]`. `live [n]` is false
     for the rows that are zero throughout. `router` and `experts` stand for
     this module's `route` and `held_experts` (at `count` pieces) where a family
     hands in its own names for them (pangu_moe, whose tests plant faults under
@@ -172,8 +177,9 @@ def routed_ffn(layer: dict, a: jax.Array, top_k: int, first: int, scaling: float
         live = jnp.repeat(live, a.shape[1])
     chosen, gates, _ = (router or route)(layer["router"], x, top_k, scaling)
     with jax.named_scope("shared_expert"):
-        shared = gated_mlp(layer["shared"], x, cd, count)
+        shared = gated_mlp(layer["shared"], x, cd, count) if "shared" in layer else None
     with jax.named_scope("experts"):
         routed, took = experts(layer["experts"], x, chosen, gates, first, cd, live=live)
     tokens = jnp.int32(x.shape[0]) if live is None else jnp.sum(live, dtype=jnp.int32)
-    return (shared + routed).reshape(a.shape), jnp.stack([tokens, jnp.sum(took), jnp.max(took)])
+    out = routed if shared is None else shared + routed
+    return out.reshape(a.shape), jnp.stack([tokens, jnp.sum(took), jnp.max(took)])

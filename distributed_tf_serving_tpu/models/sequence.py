@@ -1,11 +1,12 @@
 """What the sequence-ranker families (phi4flash, pangu_moe, exaone_moe,
-olmo_hybrid) share: products whose float32 activations enter as pieces of the
+olmo_hybrid, mimo_v2) share: products whose float32 activations enter as pieces of the
 compute dtype (`product`: one product a call wherever a form exists that
 copies no large array), the causal softmax of a block of queries, the blocks
-themselves, full causal attention in those blocks (`blocked_attention`:
-exaone_moe's and olmo_hybrid's full layers), the attention at all positions
+themselves, causal attention in those blocks (`blocked_attention`:
+exaone_moe's and olmo_hybrid's full layers, both kinds of mimo_v2's, whose
+window layers' softmax holds a learned sink), the attention at all positions
 as one Pallas kernel a layer where a one-chip served entry runs on a TPU
-(`attention`, `takes_kernel`: all four families), the causal depthwise convolution
+(`attention`, `takes_kernel`: all five families), the causal depthwise convolution
 (`causal_conv`: phi4flash's Mamba layers and olmo_hybrid's linear ones), and
 the cut to the last position. One implementation, so that a change to any of
 them is measured on every family's cell. (`models/routed.py` has what the
@@ -128,17 +129,28 @@ def product(spec: str, x: jax.Array, y: jax.Array, cd, count: int = OPERAND_PIEC
     return sum(einsum(spec, xs[i], ys[j]) for i, j in pairs)
 
 
-def causal_softmax(scores: jax.Array, q_start: int, window: int | None = None) -> jax.Array:
+def causal_softmax(scores: jax.Array, q_start: int, window: int | None = None, sink: jax.Array | None = None):
     """softmax over the keys of `scores [..., queries, keys]`, the queries at
     positions q_start .. against the keys at positions 0 ..: a query sees the
     keys up to its own position, and within `window` positions where one is
-    given (position t sees t - window + 1 .. t). float32 in, float32 out."""
+    given (position t sees t - window + 1 .. t). float32 in, float32 out.
+
+    `sink` (broadcast against `scores[..., :1]`) is a learned logit that no
+    key carries: it joins the maximum and the denominator and gives no value,
+    so a query's probabilities sum to less than one. With it the result is
+    (the probabilities, the sink's own share `[..., queries, 1]`)."""
     q_pos = q_start + jnp.arange(scores.shape[-2])[:, None]
     k_pos = jnp.arange(scores.shape[-1])[None, :]
     seen = k_pos <= q_pos
     if window is not None:
         seen &= q_pos - k_pos < window
-    return jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+    if sink is None:
+        return jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+    scores = jnp.where(seen, scores, -jnp.inf)
+    top = jnp.maximum(jnp.max(scores, axis=-1, keepdims=True), sink)
+    kept, aside = jnp.exp(scores - top), jnp.exp(sink - top)
+    total = jnp.sum(kept, axis=-1, keepdims=True) + aside
+    return kept / total, aside / total
 
 
 def query_blocks(queries: int, keys: int, window: int | None = None, block: int = ATTN_BLOCK):
@@ -211,17 +223,21 @@ def takes_kernel(queries: int, keys: int, window: int | None, count: int) -> boo
     return choice["kernel"] == "pallas"
 
 
-def attention(qs, ks, v: jax.Array, window: int | None, cd, count: int, scale: float) -> jax.Array:
+def attention(qs, ks, v: jax.Array, window: int | None, cd, count: int, scale: float,
+              sink: jax.Array | None = None):
     """Causal attention of the queries at the LAST positions of the keys'
     range as ONE Pallas kernel (ops/attention_kernel.py) that keeps the score
     tile in VMEM: for the callers `takes_kernel` said yes to. The score is
     the sum over the parts of `q k'`, times `scale`; position t sees
     `t - window + 1 .. t` (all up to t without a window).
 
-    qs  a tuple of `[n, Lq, H, d_p]` float32, a part each
-    ks  a tuple of `[n, Lk, H_p, d_p]`: query head h reads head `h // (H / H_p)`
-    v   `[n, Lk, H_v, d_v]`, read the same way
-    returns `[n, Lq, H, d_v]` float32
+    qs    a tuple of `[n, Lq, H, d_p]` float32, a part each
+    ks    a tuple of `[n, Lk, H_p, d_p]`: query head h reads head `h // (H / H_p)`
+    v     `[n, Lk, H_v, d_v]`, read the same way
+    sink  `[H]` float32 where every head's softmax holds one more term, a
+          logit that no key carries (`causal_softmax` has the form)
+    returns `[n, Lq, H, d_v]` float32; with a sink, that and the sink's share
+    of every query's softmax, `[n, Lq, H]`
 
     The kernel's arrays are head-major; the transposes are XLA's, fused into
     what makes the operands and reads the result."""
@@ -230,8 +246,10 @@ def attention(qs, ks, v: jax.Array, window: int | None, cd, count: int, scale: f
     heads_first = functools.partial(jnp.transpose, axes=(0, 2, 1, 3))
     out = kernel(
         tuple(map(heads_first, qs)), tuple(map(heads_first, ks)), heads_first(v),
-        scale=float(scale), window=window, cd=jnp.dtype(cd), count=count, interpret=_served.entry[1])
-    return heads_first(out)
+        scale=float(scale), window=window, cd=jnp.dtype(cd), count=count, interpret=_served.entry[1], sink=sink)
+    if sink is None:
+        return heads_first(out)
+    return heads_first(out[0]), jnp.transpose(out[1][..., 0], (0, 2, 1))
 
 
 def blocked_pairs(queries: int, keys: int, window: int | None = None) -> tuple[int, int]:
@@ -251,23 +269,36 @@ def blocked_pairs(queries: int, keys: int, window: int | None = None) -> tuple[i
 
 
 def blocked_attention(q: jax.Array, k: jax.Array, v: jax.Array, window: int | None, cd,
-                      count: int = OPERAND_PIECES) -> jax.Array:
+                      count: int = OPERAND_PIECES, sink: jax.Array | None = None):
     """Causal attention of the queries at the LAST `q.shape[1]` positions of
     the keys' range in blocks of ATTN_BLOCK queries, each against the keys its
-    causal reach (and its window's, where one is given) holds: a full layer at
+    causal reach (and its window's, where one is given) holds: a layer at
     all positions, any layer at the last position alone. `q [n, Lq, G, J, d]`
-    (J query heads a key-value head), `k`, `v [n, Lk, G, d]`; returns
-    `[n, Lq, G, J, d]` float32. Activations enter as `count` pieces."""
-    queries, keys, out = q.shape[1], k.shape[1], []
+    (J query heads a key-value head), `k [n, Lk, G, d]`, `v [n, Lk, G, d_v]`;
+    returns `[n, Lq, G, J, d_v]` float32. Activations enter as `count` pieces.
+    With `sink [G * J]` (a logit a query head beside its keys':
+    `causal_softmax`), returns that and the sink's share of every query's
+    softmax, `[n, Lq, G, J]`."""
+    queries, keys, out, shares = q.shape[1], k.shape[1], [], []
+    n, _, groups, per_group, head = q.shape
     if takes_kernel(queries, keys, window, count):
-        n, _, groups, per_group, head = q.shape
-        o = attention((q.reshape(n, queries, groups * per_group, head),), (k,), v, window, cd, count, head ** -0.5)
-        return o.reshape(q.shape)
+        flat = q.reshape(n, queries, groups * per_group, head)
+        o = attention((flat,), (k,), v, window, cd, count, head ** -0.5, sink)
+        if sink is None:
+            return o.reshape(q.shape[:-1] + v.shape[-1:])
+        return o[0].reshape(q.shape[:-1] + v.shape[-1:]), o[1].reshape(q.shape[:-1])
+    aside = None if sink is None else sink.astype(jnp.float32).reshape(groups, per_group, 1, 1)
     for start, stop, first, last in query_blocks(queries, keys, window):
         scores = product("nqgjd,nkgd->ngjqk", q[:, start:stop], k[:, first:last], cd, count) * q.shape[-1] ** -0.5
-        probs = causal_softmax(scores, keys - queries + start - first, window)
+        probs = causal_softmax(scores, keys - queries + start - first, window, aside)
+        if sink is not None:
+            probs, share = probs
+            shares.append(jnp.transpose(share[..., 0], (0, 3, 1, 2)))
         out.append(product("ngjqk,nkgd->nqgjd", probs, v[:, first:last], cd, count))
-    return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
+    o = out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
+    if sink is None:
+        return o
+    return o, shares[0] if len(shares) == 1 else jnp.concatenate(shares, axis=1)
 
 
 def causal_conv(x: jax.Array, w: jax.Array, b: jax.Array | None = None) -> jax.Array:

@@ -35,6 +35,14 @@ A score may be a SUM of products over parts (`pangu_moe`: the heads' own
 stand side by side in the same contracted axis. Each part of the keys, and
 the values, may have fewer heads than the queries: query head h reads head
 `h // (heads / theirs)` of each.
+
+A softmax may hold one more term a head, a learned logit that no key carries
+(`sink [heads]`, `mimo_v2`'s window layers): it is where a row's running state
+STARTS (maximum the logit, sum 1, accumulator 0, where without one it starts
+at MASKED, 0, 0), so it joins the maximum and the denominator and gives no
+value. The heads a grid step stacks each take their own logit, read from
+SMEM. With a sink the kernel also writes the sink's share of every query's
+softmax, from its own running maximum and sum (`attn.sink_mass_ppm`).
 """
 
 from __future__ import annotations
@@ -120,12 +128,15 @@ def heads_a_step(shared: int, block: int) -> int:
     return max(h for h in range(1, shared + 1) if shared % h == 0 and h * block <= max(ROWS, block))
 
 
-def _kernel(*refs, widths, reps, rep_v, dv, held, cd, scale, window, offset, block, keys, stacked):
+def _kernel(*refs, widths, reps, rep_v, dv, held, cd, scale, window, offset, block, keys, stacked, sunk=False):
     parts = len(widths)
     tall = stacked * block
     pairs = [(i, j) for i in range(held) for j in range(held) if i + j < held]
-    q_refs, k_refs, v_ref, o_ref = refs[:parts], refs[parts:2 * parts], refs[2 * parts], refs[2 * parts + 1]
-    qcat, kcat, vcat, m_ref, l_ref, acc_ref = refs[2 * parts + 2:]
+    q_refs, k_refs, v_ref = refs[:parts], refs[parts:2 * parts], refs[2 * parts]
+    # With a sink: its logits [heads] in SMEM after the values, and the
+    # share's block after the result's.
+    sink_ref, o_ref, share_ref = (refs[2 * parts + 1:2 * parts + 4] if sunk else (None, refs[2 * parts + 1], None))
+    qcat, kcat, vcat, m_ref, l_ref, acc_ref = refs[-6:]
     head, qi = pl.program_id(1), pl.program_id(2)
     used = len(pairs) * sum(widths)
 
@@ -168,8 +179,20 @@ def _kernel(*refs, widths, reps, rep_v, dv, held, cd, scale, window, offset, blo
 
         cut(v_ref, rows, store)
 
-    m_ref[...] = jnp.full(m_ref.shape, MASKED, jnp.float32)
-    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    def sink_logits():
+        # Row r is of the step's head r // block: each head's own logit.
+        of_head = jax.lax.broadcasted_iota(jnp.int32, (stacked, block, 1), 0).reshape(tall, 1)
+        logit = jnp.full((tall, 1), sink_ref[head * stacked], jnp.float32)
+        for j in range(1, stacked):
+            logit = jnp.where(of_head == j, sink_ref[head * stacked + j], logit)
+        return logit
+
+    if sunk:
+        m_ref[...] = sink_logits()
+        l_ref[...] = jnp.ones(l_ref.shape, jnp.float32)
+    else:
+        m_ref[...] = jnp.full(m_ref.shape, MASKED, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
     acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
     q_first = offset + qi * block
     # A head's block under the last one's: row r is query r % block.
@@ -206,17 +229,23 @@ def _kernel(*refs, widths, reps, rep_v, dv, held, cd, scale, window, offset, blo
     last = jnp.minimum((q_first + block - 1) // block + 1, keys // block)
     jax.lax.fori_loop(first, last, key_block, None)
     o_ref[...] = (acc_ref[...] / l_ref[...]).reshape(stacked, block, dv)
+    if sunk:
+        share_ref[...] = (jnp.exp(sink_logits() - m_ref[...]) / l_ref[...]).reshape(stacked, block, 1)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "window", "cd", "count", "interpret"))
-def attention(qs, ks, v, *, scale: float, window: int | None, cd, count: int, interpret: bool = False):
+def attention(qs, ks, v, *, scale: float, window: int | None, cd, count: int, interpret: bool = False,
+              sink: jax.Array | None = None):
     """softmax(sum over the parts of `q k'` * scale | causal, window) v.
 
-    qs  a tuple of `[n, H, Lq, d_p]` float32, a part each: the queries stand
-        at the LAST Lq positions of the keys' range
-    ks  a tuple of `[n, H_p, Lk, d_p]`, H_p dividing H
-    v   `[n, H_v, Lk, d_v]`, H_v dividing H
-    returns `[n, H, Lq, d_v]` float32
+    qs    a tuple of `[n, H, Lq, d_p]` float32, a part each: the queries stand
+          at the LAST Lq positions of the keys' range
+    ks    a tuple of `[n, H_p, Lk, d_p]`, H_p dividing H
+    v     `[n, H_v, Lk, d_v]`, H_v dividing H
+    sink  `[H]` float32, a logit a head that joins its softmax's maximum and
+          denominator and gives no value; None for a softmax over the keys alone
+    returns `[n, H, Lq, d_v]` float32; with a sink, that and the sink's share
+    of every query's softmax, `[n, H, Lq, 1]`
 
     Activations enter the products as `count` pieces of `cd`, in the pairs
     `i + j < count`; position t sees `t - window + 1 .. t` (all up to t
@@ -248,15 +277,26 @@ def attention(qs, ks, v, *, scale: float, window: int | None, cd, count: int, in
         rep = heads // x.shape[1] // stacked
         return pl.BlockSpec((None, None, k_len, x.shape[-1]), lambda b, g, i: (b, g // rep, 0, 0))
 
+    def result(d):
+        return jax.ShapeDtypeStruct((n, heads // stacked, stacked, q_len, d), jnp.float32)
+
+    body = functools.partial(
+        _kernel, widths=widths, reps=tuple(heads // k.shape[1] // stacked for k in ks),
+        rep_v=heads // v.shape[1] // stacked, dv=dv, held=held, cd=cd, scale=scale, window=window,
+        offset=keys - queries, block=block, keys=k_len, stacked=stacked)
+    in_specs = [stacked_heads(d) for d in widths] + [a_head(k) for k in ks] + [a_head(v)]
+    out_shape, out_specs, operands = result(dv), stacked_heads(dv), (*qs, *ks, v)
+    if sink is not None:
+        body = functools.partial(body, sunk=True)
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        out_shape, out_specs = (out_shape, result(1)), (out_specs, stacked_heads(1))
+        operands += (sink.astype(jnp.float32).reshape(heads),)
     out = pl.pallas_call(
-        functools.partial(
-            _kernel, widths=widths, reps=tuple(heads // k.shape[1] // stacked for k in ks),
-            rep_v=heads // v.shape[1] // stacked, dv=dv, held=held, cd=cd, scale=scale, window=window,
-            offset=keys - queries, block=block, keys=k_len, stacked=stacked),
-        out_shape=jax.ShapeDtypeStruct((n, heads // stacked, stacked, q_len, dv), jnp.float32),
+        body,
+        out_shape=out_shape,
         grid=(n, heads // stacked, q_len // block),
-        in_specs=[stacked_heads(d) for d in widths] + [a_head(k) for k in ks] + [a_head(v)],
-        out_specs=stacked_heads(dv),
+        in_specs=in_specs,
+        out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((stacked * block, width), cd),
             pltpu.VMEM((k_len, width), cd),
@@ -274,5 +314,7 @@ def attention(qs, ks, v, *, scale: float, window: int | None, cd, count: int, in
             bytes_accessed=4 * (sum(q.size for q in qs) + sum(k.size for k in ks) + v.size + n * heads * q_len * dv)),
         interpret=interpret,
         name="attention",
-    )(*qs, *ks, v)
-    return out.reshape(n, heads, q_len, dv)[:, :, :queries]
+    )(*operands)
+    if sink is None:
+        return out.reshape(n, heads, q_len, dv)[:, :, :queries]
+    return tuple(x.reshape(n, heads, q_len, -1)[:, :, :queries] for x in out)

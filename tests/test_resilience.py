@@ -96,6 +96,11 @@ def three_backends():
         servers.append(server)
         batchers.append(batcher)
         hosts.append(f"127.0.0.1:{port}")
+        # The first request to a backend compiles its step: with five other
+        # workers on the machine's cores that has outlasted the 1 s client
+        # timeout of the tests below (a healthy shard then read
+        # DEADLINE_EXCEEDED). Compile here, where nothing waits on a clock.
+        batcher.submit(registry.resolve("DCN"), _arrays(n=3)).result(timeout=300)
     yield hosts
     for s in servers:
         s.stop(0)

@@ -299,12 +299,31 @@ def test_olmo_hybrids_four_row_step_compiles_at_the_published_cut(one_chip, no_c
     assert len(re.findall(r"\) while\(", text)) == 6  # the six linear layers' chunk loops, and no other
 
 
+def test_mimo_v2s_four_row_step_compiles_at_the_published_cut(one_chip, no_compile_cache, served_on_a_tpu):
+    """MiMo-V2.5's share as `mimo_v2_5_rerank-bulk` serves it (2.144 B
+    parameters, rows of 2,048 tokens), the top bucket's step with its seven
+    counters: a kernel a layer but the last, four of them with a sink (its
+    logits in SMEM, the share a second result), 192-wide keys over 128-wide
+    values, and what the step holds beside the 4.29 GB of weights fits the
+    chip's 16 GB."""
+    compiled, accessed = sequence_cells_step("mimo_v2_5_rerank", "mimo_v2", one_chip)
+    memory = compiled.memory_analysis()
+    assert 4.2e9 < memory.argument_size_in_bytes < 4.4e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12 * GIB  # 4.29 GB + 3.8 GiB
+    assert memory.generated_code_size_in_bytes < 64 << 20  # 37.5 MB
+    assert accessed < 106e9  # 99.1 GB
+    assert not SCORE_TILE.search(compiled.as_text())
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 6  # a kernel a layer but the last
+
+
 # ------------------------------------------- the Pallas attention (PR 48)
 #
 # What interpret mode cannot see: Mosaic's verdict on the kernel's slices,
 # scratch and products at the four cells' top rungs (head-major operands, as
 # `sequence.attention` hands them over).
 
+# (the queries' parts, the keys' parts, the values, the window, the pieces,
+# whether a sink logit a head joins the softmax)
 ATTENTION_SHAPES = {
     "exaone_moe_full": (((4, 64, 2048, 128),), ((4, 8, 2048, 128),), (4, 8, 2048, 128), None, 3),
     "exaone_moe_window": (((4, 64, 2048, 128),), ((4, 8, 2048, 128),), (4, 8, 2048, 128), 128, 3),
@@ -312,6 +331,11 @@ ATTENTION_SHAPES = {
         ((8, 32, 1024, 128), (8, 32, 1024, 64)), ((8, 32, 1024, 128), (8, 1, 1024, 64)), (8, 32, 1024, 128), None, 3),
     "phi4flash": (((8, 40, 1024, 64),), ((8, 20, 1024, 64),), (8, 10, 1024, 128), 512, 2),
     "olmo_hybrid": (((4, 30, 2048, 128),), ((4, 30, 2048, 128),), (4, 30, 2048, 128), None, 2),
+    # mimo_v2_5_rerank: 192-wide keys over 128-wide values, 4 key-value heads on a full layer, 8 and a sink on a
+    # window layer: the keys' pieces of a head are 2048 x 1152 bfloat16, the largest scratch of any cell
+    "mimo_v2_full": (((4, 64, 2048, 192),), ((4, 4, 2048, 192),), (4, 4, 2048, 128), None, 3),
+    "mimo_v2_window_sink": (((4, 64, 2048, 192),), ((4, 8, 2048, 192),), (4, 8, 2048, 128), 128, 3, True),
+    "mimo_v2_full_sink": (((4, 64, 2048, 192),), ((4, 4, 2048, 192),), (4, 4, 2048, 128), None, 3, True),
 }
 
 
@@ -319,10 +343,11 @@ ATTENTION_SHAPES = {
 def test_attention_kernel_compiles_at_the_cells_top_rungs(one_chip, no_compile_cache, form):
     from distributed_tf_serving_tpu.ops.attention_kernel import attention
 
-    qs, ks, v, window, count = ATTENTION_SHAPES[form]
+    qs, ks, v, window, count, *sunk = ATTENTION_SHAPES[form]
     shaped = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)  # noqa: E731
     run = functools.partial(
         attention, scale=sum(q[-1] for q in qs) ** -0.5, window=window, cd=jnp.dtype(jnp.bfloat16), count=count)
-    compiled = jax.jit(run).lower(tuple(map(shaped, qs)), tuple(map(shaped, ks)), shaped(v)).compile()
+    sink = {"sink": shaped((qs[0][1],))} if sunk else {}  # within the default VMEM: the kernel asks for no more
+    compiled = jax.jit(run).lower(tuple(map(shaped, qs)), tuple(map(shaped, ks)), shaped(v), **sink).compile()
     assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
     assert compiled.memory_analysis().generated_code_size_in_bytes < 2 << 20  # one kernel a layer
