@@ -167,38 +167,54 @@ def query_blocks(queries: int, keys: int, window: int | None = None, block: int 
         yield start, stop, first, offset + stop
 
 
-_served = threading.local()  # .entry: (notes, interpret) while serving_attention is entered
+_served = threading.local()  # .entry: (notes, interpret, grouped) while serving_attention is entered
 
 
 @contextlib.contextmanager
-def serving_attention(notes: list, interpret: bool = False):
+def serving_attention(notes: list, interpret: bool = False, grouped: list | None = None):
     """While the batcher traces a one-chip served entry in this thread
     (serving/batcher.py _build_entry, and nowhere else): an attention at all
     positions may take the Pallas kernel (ops/attention_kernel.py), and
     `takes_kernel` appends to `notes` what it chose (attention_choice's dict,
-    once each), the servable's `startup.attention` stamp. `interpret` is for
-    tests on the CPU: choose as on a TPU and run the kernel interpreted.
+    once each), the servable's `startup.attention` stamp; and a routed
+    layer's held experts may take theirs (ops/grouped_kernel.py, chosen by
+    `routed.takes_kernel`, which appends `routed.grouped_choice`'s dict to
+    `grouped` where one is given: the `startup.grouped` stamp). `interpret`
+    is for tests on the CPU: choose as on a TPU and run the kernels
+    interpreted.
 
-    Outside it every attention is the XLA path that stood before the kernel,
-    as `embeddings.serving_gathers` keeps XLA's gather and for its reasons:
-    the GSPMD executors, `shard_map` and the trainer trace `model.apply`
-    themselves, and a `tpu_custom_call` neither partitions nor has a
-    gradient rule."""
+    Outside it every attention and every routed layer is the XLA path that
+    stood before its kernel, as `embeddings.serving_gathers` keeps XLA's
+    gather and for its reasons: the GSPMD executors, `shard_map` and the
+    trainer trace `model.apply` themselves, and a `tpu_custom_call` neither
+    partitions nor has a gradient rule."""
     before = getattr(_served, "entry", None)
-    _served.entry = (notes, interpret)
+    _served.entry = (notes, interpret, grouped)
     try:
         yield notes
     finally:
         _served.entry = before
 
 
+def served_entry() -> tuple | None:
+    """`(notes, interpret, grouped)` of the served entry this thread is
+    tracing (serving_attention), else None."""
+    return getattr(_served, "entry", None)
+
+
+def kernels_run() -> bool:
+    """Whether a served entry's kernels run, from what a trace can see:
+    inside serving_attention, on a TPU (or interpreted)."""
+    served = served_entry()
+    return served is not None and (served[1] or jax.default_backend() == "tpu")
+
+
 def kernel_serves(queries: int) -> bool:
-    """Whether an attention of `queries` queries a row runs the kernel, from
-    what a trace can see: inside serving_attention, on a TPU, and more than
-    one query (the last layer's one query has a `[1, keys]` tile: nothing to
-    keep out of memory)."""
-    served = getattr(_served, "entry", None)
-    return served is not None and (served[1] or jax.default_backend() == "tpu") and queries > 1
+    """Whether an attention of `queries` queries a row runs the kernel: where
+    a served entry's kernels run (`kernels_run`), and more than one query (the
+    last layer's one query has a `[1, keys]` tile: nothing to keep out of
+    memory)."""
+    return kernels_run() and queries > 1
 
 
 def attention_choice(queries: int, keys: int, window: int | None, count: int) -> dict:
@@ -217,7 +233,7 @@ def takes_kernel(queries: int, keys: int, window: int | None, count: int) -> boo
     """Whether `attention` serves this one (attention_choice has the rule),
     noted for the served entry being traced."""
     choice = attention_choice(queries, keys, window, count)
-    served = getattr(_served, "entry", None)
+    served = served_entry()
     if served is not None and choice not in served[0]:
         served[0].append(choice)
     return choice["kernel"] == "pallas"
@@ -246,7 +262,7 @@ def attention(qs, ks, v: jax.Array, window: int | None, cd, count: int, scale: f
     heads_first = functools.partial(jnp.transpose, axes=(0, 2, 1, 3))
     out = kernel(
         tuple(map(heads_first, qs)), tuple(map(heads_first, ks)), heads_first(v),
-        scale=float(scale), window=window, cd=jnp.dtype(cd), count=count, interpret=_served.entry[1], sink=sink)
+        scale=float(scale), window=window, cd=jnp.dtype(cd), count=count, interpret=served_entry()[1], sink=sink)
     if sink is None:
         return heads_first(out)
     return heads_first(out[0]), jnp.transpose(out[1][..., 0], (0, 2, 1))

@@ -780,6 +780,10 @@ class BatcherStats:
     # And those whose attention is the Pallas kernel (models/sequence.py
     # attention_choice; `startup.attention` names it).
     attention_kernel_batches: int = 0
+    # Batches whose entry runs the routed layers' held experts through the
+    # Pallas grouped kernels (models/routed.py grouped_choice;
+    # `startup.grouped` names them).
+    grouped_kernel_batches: int = 0
     # Batches of one request that its own handler thread closed and staged
     # (submit's direct crossing): no collector, no coalesce window, no
     # dispatch thread. The phase `batch.direct` counts the same.
@@ -1097,6 +1101,13 @@ class DynamicBatcher:
             weakref.WeakKeyDictionary()
         )
         self._attention_kernel: weakref.WeakSet[Servable] = weakref.WeakSet()
+        # And what each routed layer's held experts chose (models/routed.py
+        # grouped_choice): `startup.grouped`; and the servables whose entry
+        # runs the Pallas grouped kernels, whose batches are counted.
+        self._groupeds: weakref.WeakKeyDictionary[Servable, list] = (
+            weakref.WeakKeyDictionary()
+        )
+        self._grouped_kernel: weakref.WeakSet[Servable] = weakref.WeakSet()
         # _jit_for is reached from the batcher thread (fused-path
         # eligibility) AND the dispatch thread; one lock keeps the entry
         # build single-shot.
@@ -1785,6 +1796,18 @@ class DynamicBatcher:
                 for sv, notes in self._attentions.items() if notes
             }
 
+    def groupeds(self) -> dict[str, dict]:
+        """"name:version" -> the held experts' product of that servable's
+        entry as traced: `{"kernel": "pallas" | "xla", "tile", "pieces"}`
+        (the kernels' where layers or rungs differ), for every servable
+        whose step has a routed layer. A custom run_fn traces its own
+        entries, outside serving_attention: XLA's loops, no stamp."""
+        with self._jit_lock:
+            return {
+                f"{sv.name}:{sv.version}": max(notes, key=lambda n: n["kernel"] == "pallas")
+                for sv, notes in self._groupeds.items() if notes
+            }
+
     def pipeline_stats(self) -> dict:
         """Continuous-batching pipeline snapshot (ISSUE 9): configured
         depth/window, live in-flight occupancy (total and per bucket),
@@ -2067,18 +2090,23 @@ class DynamicBatcher:
         gathers = self._gathers[servable] = []
         self._gather_kernel.discard(servable)
         # And the one in which an attention at all positions may take the
-        # Pallas attention kernel (models/sequence.py serving_attention).
+        # Pallas attention kernel, and a routed layer's held experts the
+        # grouped kernels (models/sequence.py serving_attention).
         attentions = self._attentions[servable] = []
         self._attention_kernel.discard(servable)
+        groupeds = self._groupeds[servable] = []
+        self._grouped_kernel.discard(servable)
 
         def noting(ap):
             def traced(p, batch):
-                with serving_gathers(gathers), serving_attention(attentions):
+                with serving_gathers(gathers), serving_attention(attentions, grouped=groupeds):
                     out = ap(p, batch)
                 if any(note["kernel"] == "pallas" for note in gathers):
                     self._gather_kernel.add(servable)
                 if any(note["kernel"] == "pallas" for note in attentions):
                     self._attention_kernel.add(servable)
+                if any(note["kernel"] == "pallas" for note in groupeds):
+                    self._grouped_kernel.add(servable)
                 return out
             return traced
 
@@ -3394,6 +3422,9 @@ class DynamicBatcher:
                 if servable in self._attention_kernel:
                     self.stats.attention_kernel_batches += 1
                     request_trace.add_many((("batch.attention_kernel", 0.0, 1),))
+                if servable in self._grouped_kernel:
+                    self.stats.grouped_kernel_batches += 1
+                    request_trace.add_many((("batch.grouped_kernel", 0.0, 1),))
                 if group[0].direct:
                     self.stats.direct_batches += 1
                     request_trace.add_many((("batch.direct", 0.0, 1),))

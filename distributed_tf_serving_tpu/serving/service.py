@@ -225,6 +225,7 @@ class PredictionServiceImpl:
         assemblers = getattr(self.batcher, "assemblers", None)
         gathers = getattr(self.batcher, "gathers", None)
         attentions = getattr(self.batcher, "attentions", None)
+        groupeds = getattr(self.batcher, "groupeds", None)
         block["startup"] = {
             **self.startup,
             "warmup_s": self.warmup_s,
@@ -238,6 +239,7 @@ class PredictionServiceImpl:
             "assembler": assemblers() if callable(assemblers) else {},
             "gather": gathers() if callable(gathers) else {},
             "attention": attentions() if callable(attentions) else {},
+            "grouped": groupeds() if callable(groupeds) else {},
         }
         block["embedding_pack"] = self.registry.per_servable("embedding_pack")
         block["compile_cache"] = (

@@ -167,7 +167,7 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_layer(reference):
         ffn, given = pangu_moe._gated_mlp(layer["shared"], tokens, f32), 0
         for share in range(32):  # experts 2 * share, 2 * share + 1
             held = {k: w[2 * share:2 * share + 2] for k, w in layer["experts"].items()}
-            part, loads = pangu_moe.held_experts(held, tokens, chosen, gates, 2 * share, f32, block=16)
+            part, loads, _ = pangu_moe.held_experts(held, tokens, chosen, gates, 2 * share, f32, block=16)
             ffn, given = ffn + part, given + int(loads.sum())
         got = h + pangu_moe._rms_norm(layer["post_mlp_norm"], ffn.reshape(x.shape), eps)
     assert given == tokens.shape[0] * 8  # every choice of every token fell on exactly one share
@@ -196,7 +196,7 @@ def test_grouped_product_is_the_masked_sum_under_any_routing(tokens, block, rout
         chosen = np.tile([0, 9], (tokens, 1))
     gates = rng.random((tokens, k)).astype(np.float32)
     with jax.default_matmul_precision("highest"):
-        got, loads = jax.jit(lambda *a: pangu_moe.held_experts(p, *a, first, jnp.float32, block=block))(
+        got, loads, ran = jax.jit(lambda *a: pangu_moe.held_experts(p, *a, first, jnp.float32, block=block))(
             jnp.asarray(x), jnp.asarray(chosen.astype(np.int32)), jnp.asarray(gates))
     want = np.zeros((tokens, hidden))
     for e in range(held):
@@ -205,6 +205,7 @@ def test_grouped_product_is_the_masked_sum_under_any_routing(tokens, block, rout
         y = (g / (1 + np.exp(-g)) * (x @ w["up"])) @ w["down"]
         want += ((chosen == first + e) * gates).sum(1)[:, None] * y
     assert loads.tolist() == [(chosen == first + e).sum() for e in range(held)]
+    assert int(ran) == sum(-(-int(load) // block) * block for load in loads)  # the blocks the loops ran, padding and all
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=2e-4)
 
 
@@ -233,11 +234,11 @@ def test_the_counters_follow_the_blocks_the_loops_ran(monkeypatch):
     x = jnp.asarray(rng.standard_normal((40, 16)), jnp.float32)
     chosen = jnp.asarray(np.tile([1, 0], (40, 1)).astype(np.int32))  # every token to both held experts
     gates = jnp.ones((40, 2), jnp.float32)
-    run = lambda: pangu_moe.held_experts(p, x, chosen, gates, 0, jnp.float32, block=16)[1].tolist()  # noqa: E731
-    assert run() == [40, 40]
+    run = lambda: [np.asarray(c).tolist() for c in pangu_moe.held_experts(p, x, chosen, gates, 0, jnp.float32, block=16)[1:]]  # noqa: E731
+    assert run() == [[40, 40], 96]
     loop = jax.lax.fori_loop
     monkeypatch.setattr(jax.lax, "fori_loop", lambda lo, hi, body, init: loop(lo, jnp.minimum(hi, 1), body, init))
-    assert run() == [16, 16]
+    assert run() == [[16, 16], 32]
 
 
 # ------------------------------------------------------------------ counters
@@ -254,7 +255,7 @@ def test_the_steps_counters_are_a_numpy_count_on_the_same_router_scores():
     _, _, scores = pangu_moe.route(layer["router"], a.reshape(-1, 64), 4, 2.5)
     top = np.argsort(-np.asarray(scores), axis=1)[:, :4]
     loads = [(top == e).sum() for e in range(4, 8)]
-    assert counts.tolist() == [4 * LENGTH, sum(loads), max(loads)] and sum(loads) > 0
+    assert counts.tolist() == [4 * LENGTH, sum(loads), max(loads), sum(-(-n // 256) * 256 for n in loads)] and sum(loads) > 0
     # the whole step: a routed layer before the last at all positions, the last at one
     _, stats = jax.jit(model.apply_stats)(params, rows(4, config))
     assert model.step_stats == pangu_moe.STEP_STATS and int(stats[0]) == 4 * LENGTH + 4

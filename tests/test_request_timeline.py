@@ -447,6 +447,8 @@ def test_cli_server_reports_startup_stamps_and_raw_counters(tmp_path):
             "kernel": "xla", "row_bytes": 16, "in_flight": 0, "picked_in_kernel": False}}
         # And no attention stamp (PR 48): a CTR step attends to nothing.
         assert startup.pop("attention") == {}
+        # Nor a grouped stamp (PR 51): it routes to no expert.
+        assert startup.pop("grouped") == {}
         # And how many gRPC listeners share its port, from how many cores (PR 34).
         from distributed_tf_serving_tpu.serving.server import listener_count
 

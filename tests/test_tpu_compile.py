@@ -7,6 +7,7 @@ it row-major, one whole lane row a lookup. All such compiles live in this one
 file: the process that describes the topology holds the TPU library."""
 
 import functools
+import hashlib
 import json
 import os
 import re
@@ -38,7 +39,7 @@ def one_chip():
 @pytest.fixture(scope="module")
 def model():
     return build_model(
-        "dcn_v2", ModelConfig(name="DCN", num_fields=FIELDS, vocab_size=VOCAB, embed_dim=DIM)
+        "dcn_v2", ModelConfig(name="c4b1ba715cf54e70", num_fields=FIELDS, vocab_size=VOCAB, embed_dim=DIM)
     )
 
 
@@ -101,7 +102,7 @@ def test_bags_pool_in_one_matmul_fused_with_their_weights(one_chip, no_compile_c
     bags = (3, 2, 1, 2, 6, 1, 1, 1, 1, 7, 3, 8, 1, 6, 9, 5, 1, 1, 1, 12, 100, 27, 10, 3, 1, 1)
     bucket = 4096
     bag_model = build_model("dlrm_dcnv2", ModelConfig(
-        name="DCN", num_fields=sum(bags), multi_hot_sizes=bags, vocab_size=1 << 24, embed_dim=128,
+        name="c4b1ba715cf54e70", num_fields=sum(bags), multi_hot_sizes=bags, vocab_size=1 << 24, embed_dim=128,
         bottom_mlp_dims=(512, 256, 128), mlp_dims=(1024, 1024, 512, 256), cross_low_rank=512,
     ))
     shapes = jax.eval_shape(functools.partial(bag_model.init, packed=True), jax.random.PRNGKey(0))
@@ -210,22 +211,35 @@ def served_on_a_tpu(monkeypatch):
     monkeypatch.setattr(sequence.jax, "default_backend", lambda: "tpu")
 
 
-def sequence_cells_step(name: str, kind: str, one_chip):
-    """(the compiled top-bucket step of the configuration `name` as its cell
-    serves it, with its counters where it has them; its `bytes accessed`)."""
-    from distributed_tf_serving_tpu.models import sequence
-
+def cells_model(name: str, kind: str):
+    """(the model of the benchmark configuration `name`, its top bucket's rows, its fields)."""
     with open(os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                            "benchmark", "configs", name, "config.json")) as f:
         config = json.load(f)["toml"]
     shape = {k: tuple(v) if isinstance(v, list) else v for k, v in config["model"].items()}
-    model = build_model(kind, ModelConfig(**shape))
+    return build_model(kind, ModelConfig(**shape)), max(config["server"]["buckets"]), shape["num_fields"]
+
+
+STEPS: dict = {}  # (name, kind) -> sequence_cells_step's result: a step is compiled once a session
+
+
+def sequence_cells_step(name: str, kind: str, one_chip):
+    """(the compiled top-bucket step of the configuration `name` as its cell
+    serves it, with its counters where it has them; its `bytes accessed`)."""
+    if (name, kind) not in STEPS:
+        STEPS[name, kind] = _sequence_cells_step(name, kind, one_chip)
+    return STEPS[name, kind]
+
+
+def _sequence_cells_step(name: str, kind: str, one_chip):
+    from distributed_tf_serving_tpu.models import sequence
+
+    model, rows, fields = cells_model(name, kind)
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), shapes)
-    rows = max(config["server"]["buckets"])
     batch = {
-        "feat_ids": jax.ShapeDtypeStruct((rows, shape["num_fields"]), jnp.int32, sharding=one_chip),
-        "feat_wts": jax.ShapeDtypeStruct((rows, shape["num_fields"]), jnp.float32, sharding=one_chip),
+        "feat_ids": jax.ShapeDtypeStruct((rows, fields), jnp.int32, sharding=one_chip),
+        "feat_wts": jax.ShapeDtypeStruct((rows, fields), jnp.float32, sharding=one_chip),
     }
     run = model.apply_stats if model.step_stats else model.apply
 
@@ -251,7 +265,8 @@ def test_exaone_moes_four_row_step_compiles_at_the_published_cut(one_chip, no_co
     assert memory.generated_code_size_in_bytes < 64 << 20  # 37.7 MB; 49.9 on the XLA path
     assert accessed < 93e9  # 86.1 GB; 139.3 on the XLA path
     assert not SCORE_TILE.search(compiled.as_text())
-    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 4  # a kernel a layer but the last
+    # the attention's kernel a layer but the last, and since PR 51 the grouped kernels' two a routed layer
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 4 + 2 * 4
 
 
 def test_phi4flashs_eight_row_step_writes_a_score_tile_once(one_chip, no_compile_cache, served_on_a_tpu):
@@ -313,7 +328,8 @@ def test_mimo_v2s_four_row_step_compiles_at_the_published_cut(one_chip, no_compi
     assert memory.generated_code_size_in_bytes < 64 << 20  # 37.5 MB
     assert accessed < 106e9  # 99.1 GB
     assert not SCORE_TILE.search(compiled.as_text())
-    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 6  # a kernel a layer but the last
+    # the attention's kernel a layer but the last, and since PR 51 the grouped kernels' two a routed layer
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 6 + 2 * 6
 
 
 # ------------------------------------------- the Pallas attention (PR 48)
@@ -351,3 +367,119 @@ def test_attention_kernel_compiles_at_the_cells_top_rungs(one_chip, no_compile_c
     compiled = jax.jit(run).lower(tuple(map(shaped, qs)), tuple(map(shaped, ks)), shaped(v), **sink).compile()
     assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
     assert compiled.memory_analysis().generated_code_size_in_bytes < 2 << 20  # one kernel a layer
+
+
+# ------------------------------------------- the grouped kernels (PR 51)
+#
+# The held experts of a routed layer as one pass of two Pallas kernels over
+# row tiles (ops/grouped_kernel.py): Mosaic's verdict on the strided row
+# copies, the pieces one under the other and the scratch at the three routed
+# cells' top rungs and at the last layer's few tokens, within the 16 MiB of
+# VMEM a kernel has by default; and what the served steps hold since.
+
+# (hidden, an expert's width, tokens a routed layer at all positions[, the compute dtype where not bfloat16])
+GROUPED_SHAPES = {
+    "exaone_moe": (6144, 2048, 8192), "pangu_moe": (7680, 2048, 8192), "mimo_v2": (4096, 2048, 8192),
+    "exaone_moe_last_layer": (6144, 2048, 4), "pangu_moe_last_layer": (7680, 2048, 8),
+    # a float32 compute dtype (the precision readings' stand-in for the reference, a float32 TOML): the weights'
+    # blocks hold half the columns, the same bytes. As first written they held as many, and inside a whole step the
+    # chip refused `grouped_down` at run time (PERF.md section 6, PR 51, call 7) though this compile of the kernels
+    # alone passed: necessary, not sufficient, as PR 50 found of the attention's.
+    "pangu_moe_float32": (7680, 2048, 8192, jnp.float32), "mimo_v2_float32": (4096, 2048, 8192, jnp.float32),
+    # configs/*_moe_small.toml and mimo_v2_small.toml (what `chip_smoke.py --config` serves): an expert half a lane row wide
+    "small_tomls": (128, 64, 160),
+}
+
+
+@pytest.mark.parametrize("form", sorted(GROUPED_SHAPES))
+def test_grouped_kernels_compile_at_the_cells_top_rungs(one_chip, no_compile_cache, form):
+    from distributed_tf_serving_tpu.ops.grouped_kernel import TILE, grouped_experts
+
+    hidden, width, tokens, *dtype = GROUPED_SHAPES[form]
+    cd = jnp.dtype(dtype[0] if dtype else jnp.bfloat16)
+    held, padded = 8, -(-tokens // TILE) * TILE
+    shaped = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    run = functools.partial(grouped_experts, cd=cd, count=3)
+    compiled = jax.jit(run).lower(
+        shaped((held, hidden, width), cd), shaped((held, hidden, width), cd),
+        shaped((held, width, hidden), cd), shaped((tokens, hidden), jnp.float32),
+        shaped((tokens, held), jnp.float32), shaped((held, padded), jnp.int32), shaped((held,), jnp.int32)).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 and "vmem_limit" not in text
+    assert not re.findall(r"\) while\(", text)  # the routing decides the grids' length, and no loop of XLA's
+    # Worst-case buffers: `[held, T, F]` float32 between the kernels and nothing else the size of the tokens (the
+    # sorted tokens and the sorted result are never made; the tokens' and the result's own bytes are read as they lie).
+    assert memory.temp_size_in_bytes < held * padded * width * 4 + (8 << 20)
+    assert memory.generated_code_size_in_bytes < 2 << 20
+
+
+# The parent's (PR 50's) code bytes of the three routed cells' top rungs; each routed layer held eight `while` loops.
+ROUTED_CELLS = {
+    "k_exaone_moe_rerank": ("exaone_moe", 37.7e6), "pangu_ultra_moe_rerank": ("pangu_moe", 60.9e6),
+    "mimo_v2_5_rerank": ("mimo_v2", 37.5e6),
+}
+
+
+@pytest.mark.parametrize("name", sorted(ROUTED_CELLS))
+def test_a_routed_cells_step_holds_no_expert_loop(name, one_chip, no_compile_cache, served_on_a_tpu):
+    """The top rung as the cell serves it: no `while` at all (the experts'
+    loops were the step's only ones: 32, 32 and 48), the grouped kernels at
+    the VMEM a kernel has by default, and no more code than the parent's."""
+    kind, parents_code = ROUTED_CELLS[name]
+    compiled, _ = sequence_cells_step(name, kind, one_chip)
+    text = compiled.as_text()
+    assert not re.findall(r"\) while\(", text)
+    assert text.count("grouped_gate_up") and text.count("grouped_down") and "vmem_limit" not in text
+    assert compiled.memory_analysis().generated_code_size_in_bytes < parents_code  # 23.1 / 23.1 / 25.4 MB
+
+
+# sha256 (its first 16 digits) of the lowered text of a top-rung step at the
+# parent (PR 50's tree, on this container's jax). Inside the served entry on a
+# backend that answers `tpu` for the families without a routed layer, whose
+# served steps PR 51 leaves as they were; OUTSIDE the entry (every CPU run, the
+# GSPMD executors, `shard_map_score`, the trainer) for the three routed
+# families, which keep XLA's loops there: their text is the parent's but for
+# the one counter PR 51 adds to the loops (`moe.rows_computed`), so these three
+# are PR 51's own, held here for the next change to be seen against.
+LOWERED_TEXT = {
+    "phi4_mini_flash_rerank/phi4flash/served": "023772657653519e",
+    "olmo_hybrid_rerank/olmo_hybrid/served": "64186f915a41165c",
+    "dcn_v2_ref43/dcn_v2/served": "c4b1ba715cf54e70",
+    "dlrm_dcnv2_mlperf/dlrm_dcnv2/served": "9bbd2eb81f11ee6f",
+    "k_exaone_moe_rerank/exaone_moe/outside": "e21defa4c02679fe",
+    "pangu_ultra_moe_rerank/pangu_moe/outside": "e9d6693e5443e71c",
+    "mimo_v2_5_rerank/mimo_v2/outside": "cf958181426246d5",
+}
+
+
+def lowered_text_digest(name: str, kind: str, where: str, one_chip) -> str:
+    from distributed_tf_serving_tpu.models import embeddings, sequence
+
+    model, rows, fields = cells_model(name, kind)
+    on_chip = lambda tree: jax.tree.map(  # noqa: E731
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), tree)
+    batch = {"feat_ids": jax.ShapeDtypeStruct((rows, fields), jnp.int32),
+             "feat_wts": jax.ShapeDtypeStruct((rows, fields), jnp.bfloat16)}
+    if model.takes_dense:
+        batch["dense_features"] = jax.ShapeDtypeStruct((rows, model.config.num_dense_features), jnp.float32)
+    run = model.apply_stats if model.step_stats else model.apply
+
+    def served(p, b):
+        with embeddings.serving_gathers([]), sequence.serving_attention([]):
+            return run(p, b)
+
+    params = jax.eval_shape(functools.partial(model.init, packed=True), jax.random.PRNGKey(0))
+    text = jax.jit(served if where == "served" else run).lower(on_chip(params), on_chip(batch)).as_text()
+    # A Pallas kernel's serialized body names the files and the callers it was traced from (this test's own among
+    # them): left out. Its operands, results, grid and name stay in.
+    text = re.sub(r'\\22body\\22: \\22[A-Za-z0-9+/=]*\\22', "", text)
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("key", sorted(LOWERED_TEXT))
+def test_a_step_outside_the_grouped_kernels_lowers_to_the_text_it_had(key, one_chip, served_on_a_tpu, monkeypatch):
+    from distributed_tf_serving_tpu.models import embeddings
+
+    monkeypatch.setattr(embeddings.jax, "default_backend", lambda: "tpu")
+    name, kind, where = key.split("/")
+    assert lowered_text_digest(name, kind, where, one_chip) == LOWERED_TEXT[key]
