@@ -66,6 +66,21 @@ every step fills whole lane rows. A chunk that is no whole number of blocks (a
 short row's, a test's) is padded out with zeros to the next one: the inverse
 of [[M, 0], [0, I]] is [[inv(M), 0], [0, I]], and the corner is cut off again.
 
+Which path computes what, where. `T` (the running sum of g, `K K'`, `A`, the
+block inverse, `diag(b)`) is XLA's everywhere. What follows it is XLA's too
+wherever `model.apply` is traced outside the batcher's one-chip served entry
+(`shard_map`, the GSPMD executors, the trainer) and on a CPU: `W`, `U` and
+`lower((Q K') * D)` for every chunk at once, then a `scan` a chunk whose carry
+is the state. Inside that entry on a TPU (`delta_choice`, by
+`sequence.kernels_run`; the servable's `startup.delta_rule` stamp says which)
+it is ONE Pallas kernel a layer (ops/delta_kernel.py): a grid over (row, group
+of heads, chunk), the chunks in order, the state in VMEM from a row's first
+chunk to its last and written out once; `W`, `U`, `within` and `V'` exist in
+VMEM alone, `W S` and `(Q e^G) S` are one product, and q, k, v and o cross as
+they lie. The same pieces in the same pairs, the same float32 state rounded
+to STATE_DTYPE a chunk: the two paths agree to float32 rounding in another
+order of additions (tests/test_delta_kernel.py).
+
 What the served step skips (exact): the score reads the last position, so the
 LAST layer's queries (a full layer) or its output gate and projection (a linear
 one), and its MLP, are computed there alone; its keys and values, or its rule,
@@ -264,6 +279,41 @@ def unit_lower_inverse(a: jax.Array) -> jax.Array:
     return jnp.moveaxis(inverse(0, blocks), -1, 0)[:, :c, :c].reshape(lead + (c, c))
 
 
+def delta_choice(length: int, count: int, chunk: int = DELTA_CHUNK) -> dict:
+    """`{"kernel": "pallas" | "xla", "chunk", "pieces"}`: which path walks the
+    rule's chunks over rows of `length` positions, the positions a chunk and
+    the pieces an activation enters its products as. A servable's
+    `startup.delta_rule` stamp. The kernel (ops/delta_kernel.py) runs where a
+    served entry's kernels do (`sequence.kernels_run`): inside the batcher's
+    one-chip entry on a TPU, at every length."""
+    kernel = "pallas" if sequence.kernels_run() else "xla"
+    return {"kernel": kernel, "chunk": delta_chunks(length, chunk)[0], "pieces": count}
+
+
+def takes_kernel(length: int, count: int, chunk: int = DELTA_CHUNK) -> bool:
+    """Whether the kernel walks this rule's chunks (delta_choice has the
+    rule), noted for the served entry being traced."""
+    choice = delta_choice(length, count, chunk)
+    served = sequence.served_entry()
+    if served is not None and served.delta is not None and choice not in served.delta:
+        served.delta.append(choice)
+    return choice["kernel"] == "pallas"
+
+
+def _chunk_inverse(k: jax.Array, g: jax.Array, b: jax.Array, cd) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """(G, D, T) of chunks `k [x, y, z, C, dk]`, `g`, `b [x, y, z, C]`: g's
+    running sum inside a chunk, `D_ij = exp(G_i - G_j)` where i >= j, else 0,
+    and `T = (I + A)^-1 diag(b)`. The caller's `solve` scope."""
+    chunk = k.shape[-2]
+    total = jnp.cumsum(g, axis=-1)  # G, the running sum inside a chunk
+    i, j = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None, :]
+    # D from the difference under the mask
+    decay = jnp.exp(jnp.where(j <= i, total[..., :, None] - total[..., None, :], -jnp.inf))
+    a = jnp.where(j < i, b[..., :, None] * _product("nzhid,nzhjd->nzhij", k, k, cd) * decay, 0.0)
+    # the unit diagonal is taken as read, not read
+    return total, decay, unit_lower_inverse(a) * b[..., None, :]
+
+
 def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, b: jax.Array,
                      initial_state: jax.Array | None = None, *, chunk: int = DELTA_CHUNK,
                      cd=jnp.float32) -> tuple[jax.Array, jax.Array]:
@@ -277,25 +327,40 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, b: 
     S before the first position (zero where None). Returns `o [n, L, H, dv]`
     and the state after the last position, float32. A length that is no
     multiple of the chunk is padded with k = v = 0, b = 0, g = 0, which leave
-    the state as it is. The caller's `delta_rule` scope."""
+    the state as it is. The caller's `delta_rule` scope.
+
+    Where a one-chip served entry's kernels run (`takes_kernel`) what follows
+    `T` is one Pallas kernel a layer that keeps the state in VMEM
+    (ops/delta_kernel.py); everywhere else (`shard_map`, the GSPMD executors,
+    the trainer, a CPU) it is XLA's, below: the plain form the kernel is
+    tested against."""
     n, length, heads, dk = q.shape
     dv = v.shape[-1]
+    kernel = takes_kernel(length, OPERAND_PIECES, chunk)
     chunk, steps = delta_chunks(length, chunk)
     pad = steps * chunk - length
 
-    def chunks(x):  # [n, L, H, ...] -> [n, steps, H, chunk, ...]: a head's chunk is one matrix
-        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-        return jnp.moveaxis(x.reshape((n, steps, chunk) + x.shape[2:]), 3, 2)
+    def padded(x):
+        return jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
 
+    def chunks(x):  # [n, L, H, ...] -> [n, steps, H, chunk, ...]: a head's chunk is one matrix
+        return jnp.moveaxis(padded(x).reshape((n, steps, chunk) + x.shape[2:]), 3, 2)
+
+    if kernel:  # q, k, v and o cross as they lie, `[n, L, H x d]`: no turn on either side
+        from ..ops import delta_kernel
+
+        with jax.named_scope("solve"):
+            total, _, t = _chunk_inverse(chunks(k), chunks(g), chunks(b), cd)
+        with jax.named_scope("chunks"):
+            state = jnp.zeros((n, heads, dk, dv), jnp.float32) if initial_state is None else initial_state
+            o, state = delta_kernel.chunk_pass(
+                jnp.moveaxis(total, 1, 2), *(padded(x).reshape(n, steps * chunk, -1) for x in (q, k, v)), t,
+                state.astype(STATE_DTYPE).astype(jnp.float32), heads=heads, cd=jnp.dtype(cd), count=OPERAND_PIECES,
+                state_dtype=jnp.dtype(STATE_DTYPE), interpret=sequence.served_entry().interpret)
+        return o.reshape(n, steps * chunk, heads, dv)[:, :length], state
     q, k, v, g, b = (chunks(x) for x in (q, k, v, g, b))
     with jax.named_scope("solve"):
-        total = jnp.cumsum(g, axis=-1)  # G, the running sum inside a chunk [n, Z, H, C]
-        i, j = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None, :]
-        # D_ij = exp(G_i - G_j) where i >= j, else 0: from the difference under the mask
-        decay = jnp.exp(jnp.where(j <= i, total[..., :, None] - total[..., None, :], -jnp.inf))
-        a = jnp.where(j < i, b[..., :, None] * _product("nzhid,nzhjd->nzhij", k, k, cd) * decay, 0.0)
-        # T = (I + A)^-1 diag(b): the unit diagonal is taken as read, not read
-        t = unit_lower_inverse(a) * b[..., None, :]
+        total, decay, t = _chunk_inverse(k, g, b, cd)
         grown = jnp.exp(total)[..., None]  # exp(G_i) [n, Z, H, C, 1]
         w = _product("nzhij,nzhjd->nzhid", t, k * grown, cd)
         u = _product("nzhij,nzhje->nzhie", t, v, cd)
@@ -414,8 +479,14 @@ def forward(config: ModelConfig, params, batch) -> tuple[jax.Array, jax.Array]:
     with jax.named_scope("score"):
         final = rms_norm(params["final_norm"], x[:, -1], eps)
         counts = jnp.asarray(step_counts(plan, batch["feat_ids"].shape[1]), jnp.int32)
-        return (jnp.sum(final * params["score"].astype(jnp.float32), axis=-1),
-                jnp.sum(live, dtype=jnp.int32) * counts)
+        # The counters leave WITH the logits. Without the barrier, where the
+        # counters stand among an executable's results decides the order XLA
+        # walks the step in, and with it which weights it prefetches: the
+        # batcher's entry, whose counters come first, lost the prefetch of
+        # seven MLP weights and 11 ms a step that the same step with them
+        # last kept (PERF.md section 6, PR 52).
+        return jax.lax.optimization_barrier((
+            jnp.sum(final * params["score"].astype(jnp.float32), axis=-1), jnp.sum(live, dtype=jnp.int32) * counts))
 
 
 def attention_plan(config: ModelConfig) -> tuple[tuple[tuple[str, object], ...], ...]:

@@ -149,8 +149,8 @@ def takes_kernel(count: int) -> bool:
     rule), noted for the served entry being traced."""
     choice = grouped_choice(count)
     served = sequence.served_entry()
-    if served is not None and served[2] is not None and choice not in served[2]:
-        served[2].append(choice)
+    if served is not None and served.grouped is not None and choice not in served.grouped:
+        served.grouped.append(choice)
     return choice["kernel"] == "pallas"
 
 
@@ -191,7 +191,7 @@ def held_experts(p: dict, x: jax.Array, chosen: jax.Array, gates: jax.Array, fir
         return grouped_kernel.grouped_experts(
             *(p[name].astype(cd) for name in ("gate", "up", "down")), x, gate_of,
             jnp.stack(orders).astype(jnp.int32), loads, cd=jnp.dtype(cd), count=count, tile=block,
-            interpret=sequence.served_entry()[1])
+            interpret=sequence.served_entry().interpret)
     blocks = (loads + block - 1) // block
     out, took, ran = jnp.zeros(x.shape, jnp.float32), [], jnp.int32(0)
     for e in range(held):

@@ -23,6 +23,7 @@ import contextlib
 import functools
 import string
 import threading
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -167,11 +168,22 @@ def query_blocks(queries: int, keys: int, window: int | None = None, block: int 
         yield start, stop, first, offset + stop
 
 
-_served = threading.local()  # .entry: (notes, interpret, grouped) while serving_attention is entered
+class Served(NamedTuple):
+    """What `serving_attention` was entered with: the notes lists of the
+    attention, the routed layers and the delta rule, and whether the kernels
+    run interpreted."""
+    notes: list
+    interpret: bool
+    grouped: list | None
+    delta: list | None
+
+
+_served = threading.local()  # .entry: a Served while serving_attention is entered
 
 
 @contextlib.contextmanager
-def serving_attention(notes: list, interpret: bool = False, grouped: list | None = None):
+def serving_attention(notes: list, interpret: bool = False, grouped: list | None = None,
+                      delta: list | None = None):
     """While the batcher traces a one-chip served entry in this thread
     (serving/batcher.py _build_entry, and nowhere else): an attention at all
     positions may take the Pallas kernel (ops/attention_kernel.py), and
@@ -179,26 +191,28 @@ def serving_attention(notes: list, interpret: bool = False, grouped: list | None
     once each), the servable's `startup.attention` stamp; and a routed
     layer's held experts may take theirs (ops/grouped_kernel.py, chosen by
     `routed.takes_kernel`, which appends `routed.grouped_choice`'s dict to
-    `grouped` where one is given: the `startup.grouped` stamp). `interpret`
-    is for tests on the CPU: choose as on a TPU and run the kernels
-    interpreted.
+    `grouped` where one is given: the `startup.grouped` stamp); and a gated
+    delta rule's chunk pass may take its own (ops/delta_kernel.py, chosen by
+    `olmo_hybrid.takes_kernel`, which appends `olmo_hybrid.delta_choice`'s
+    dict to `delta`: the `startup.delta_rule` stamp). `interpret` is for
+    tests on the CPU: choose as on a TPU and run the kernels interpreted.
 
-    Outside it every attention and every routed layer is the XLA path that
-    stood before its kernel, as `embeddings.serving_gathers` keeps XLA's
+    Outside it every attention, routed layer and delta rule is the XLA path
+    that stood before its kernel, as `embeddings.serving_gathers` keeps XLA's
     gather and for its reasons: the GSPMD executors, `shard_map` and the
     trainer trace `model.apply` themselves, and a `tpu_custom_call` neither
     partitions nor has a gradient rule."""
     before = getattr(_served, "entry", None)
-    _served.entry = (notes, interpret, grouped)
+    _served.entry = Served(notes, interpret, grouped, delta)
     try:
         yield notes
     finally:
         _served.entry = before
 
 
-def served_entry() -> tuple | None:
-    """`(notes, interpret, grouped)` of the served entry this thread is
-    tracing (serving_attention), else None."""
+def served_entry() -> Served | None:
+    """The `Served` of the served entry this thread is tracing
+    (serving_attention), else None."""
     return getattr(_served, "entry", None)
 
 
@@ -206,7 +220,7 @@ def kernels_run() -> bool:
     """Whether a served entry's kernels run, from what a trace can see:
     inside serving_attention, on a TPU (or interpreted)."""
     served = served_entry()
-    return served is not None and (served[1] or jax.default_backend() == "tpu")
+    return served is not None and (served.interpret or jax.default_backend() == "tpu")
 
 
 def kernel_serves(queries: int) -> bool:
@@ -234,8 +248,8 @@ def takes_kernel(queries: int, keys: int, window: int | None, count: int) -> boo
     noted for the served entry being traced."""
     choice = attention_choice(queries, keys, window, count)
     served = served_entry()
-    if served is not None and choice not in served[0]:
-        served[0].append(choice)
+    if served is not None and choice not in served.notes:
+        served.notes.append(choice)
     return choice["kernel"] == "pallas"
 
 
@@ -262,7 +276,7 @@ def attention(qs, ks, v: jax.Array, window: int | None, cd, count: int, scale: f
     heads_first = functools.partial(jnp.transpose, axes=(0, 2, 1, 3))
     out = kernel(
         tuple(map(heads_first, qs)), tuple(map(heads_first, ks)), heads_first(v),
-        scale=float(scale), window=window, cd=jnp.dtype(cd), count=count, interpret=served_entry()[1], sink=sink)
+        scale=float(scale), window=window, cd=jnp.dtype(cd), count=count, interpret=served_entry().interpret, sink=sink)
     if sink is None:
         return heads_first(out)
     return heads_first(out[0]), jnp.transpose(out[1][..., 0], (0, 2, 1))

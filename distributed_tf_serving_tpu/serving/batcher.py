@@ -784,6 +784,10 @@ class BatcherStats:
     # Pallas grouped kernels (models/routed.py grouped_choice;
     # `startup.grouped` names them).
     grouped_kernel_batches: int = 0
+    # Batches whose entry walks the gated delta rule's chunks in the Pallas
+    # kernel (models/olmo_hybrid.py delta_choice; `startup.delta_rule` names
+    # it).
+    delta_kernel_batches: int = 0
     # Batches of one request that its own handler thread closed and staged
     # (submit's direct crossing): no collector, no coalesce window, no
     # dispatch thread. The phase `batch.direct` counts the same.
@@ -1108,6 +1112,14 @@ class DynamicBatcher:
             weakref.WeakKeyDictionary()
         )
         self._grouped_kernel: weakref.WeakSet[Servable] = weakref.WeakSet()
+        # And what each gated delta rule's chunk pass chose
+        # (models/olmo_hybrid.py delta_choice): `startup.delta_rule`; and the
+        # servables whose entry runs the Pallas kernel, whose batches are
+        # counted.
+        self._deltas: weakref.WeakKeyDictionary[Servable, list] = (
+            weakref.WeakKeyDictionary()
+        )
+        self._delta_kernel: weakref.WeakSet[Servable] = weakref.WeakSet()
         # _jit_for is reached from the batcher thread (fused-path
         # eligibility) AND the dispatch thread; one lock keeps the entry
         # build single-shot.
@@ -1808,6 +1820,18 @@ class DynamicBatcher:
                 for sv, notes in self._groupeds.items() if notes
             }
 
+    def delta_rules(self) -> dict[str, dict]:
+        """"name:version" -> the gated delta rule's chunk pass of that
+        servable's entry as traced: `{"kernel": "pallas" | "xla", "chunk",
+        "pieces"}` (the kernel's where rungs differ), for every servable
+        whose step has a linear-attention layer. A custom run_fn traces its
+        own entries, outside serving_attention: XLA's scan, no stamp."""
+        with self._jit_lock:
+            return {
+                f"{sv.name}:{sv.version}": max(notes, key=lambda n: n["kernel"] == "pallas")
+                for sv, notes in self._deltas.items() if notes
+            }
+
     def pipeline_stats(self) -> dict:
         """Continuous-batching pipeline snapshot (ISSUE 9): configured
         depth/window, live in-flight occupancy (total and per bucket),
@@ -2090,16 +2114,19 @@ class DynamicBatcher:
         gathers = self._gathers[servable] = []
         self._gather_kernel.discard(servable)
         # And the one in which an attention at all positions may take the
-        # Pallas attention kernel, and a routed layer's held experts the
-        # grouped kernels (models/sequence.py serving_attention).
+        # Pallas attention kernel, a routed layer's held experts the grouped
+        # kernels and a gated delta rule's chunk pass its own
+        # (models/sequence.py serving_attention).
         attentions = self._attentions[servable] = []
         self._attention_kernel.discard(servable)
         groupeds = self._groupeds[servable] = []
         self._grouped_kernel.discard(servable)
+        deltas = self._deltas[servable] = []
+        self._delta_kernel.discard(servable)
 
         def noting(ap):
             def traced(p, batch):
-                with serving_gathers(gathers), serving_attention(attentions, grouped=groupeds):
+                with serving_gathers(gathers), serving_attention(attentions, grouped=groupeds, delta=deltas):
                     out = ap(p, batch)
                 if any(note["kernel"] == "pallas" for note in gathers):
                     self._gather_kernel.add(servable)
@@ -2107,6 +2134,8 @@ class DynamicBatcher:
                     self._attention_kernel.add(servable)
                 if any(note["kernel"] == "pallas" for note in groupeds):
                     self._grouped_kernel.add(servable)
+                if any(note["kernel"] == "pallas" for note in deltas):
+                    self._delta_kernel.add(servable)
                 return out
             return traced
 
@@ -3425,6 +3454,9 @@ class DynamicBatcher:
                 if servable in self._grouped_kernel:
                     self.stats.grouped_kernel_batches += 1
                     request_trace.add_many((("batch.grouped_kernel", 0.0, 1),))
+                if servable in self._delta_kernel:
+                    self.stats.delta_kernel_batches += 1
+                    request_trace.add_many((("batch.delta_kernel", 0.0, 1),))
                 if group[0].direct:
                     self.stats.direct_batches += 1
                     request_trace.add_many((("batch.direct", 0.0, 1),))

@@ -226,6 +226,7 @@ class PredictionServiceImpl:
         gathers = getattr(self.batcher, "gathers", None)
         attentions = getattr(self.batcher, "attentions", None)
         groupeds = getattr(self.batcher, "groupeds", None)
+        delta_rules = getattr(self.batcher, "delta_rules", None)
         block["startup"] = {
             **self.startup,
             "warmup_s": self.warmup_s,
@@ -240,6 +241,7 @@ class PredictionServiceImpl:
             "gather": gathers() if callable(gathers) else {},
             "attention": attentions() if callable(attentions) else {},
             "grouped": groupeds() if callable(groupeds) else {},
+            "delta_rule": delta_rules() if callable(delta_rules) else {},
         }
         block["embedding_pack"] = self.registry.per_servable("embedding_pack")
         block["compile_cache"] = (

@@ -758,6 +758,9 @@ class ServerMetrics:
                 # And those whose entry runs the routed layers' held experts
                 # through the Pallas grouped kernels (`startup.grouped`).
                 "grouped_kernel_batches": getattr(batcher_stats, "grouped_kernel_batches", 0),
+                # And those whose entry walks the gated delta rule's chunks in
+                # the Pallas kernel (`startup.delta_rule`).
+                "delta_kernel_batches": getattr(batcher_stats, "delta_kernel_batches", 0),
                 # And those of one request that its own handler thread
                 # closed and staged (the batcher's direct crossing).
                 "direct_batches": getattr(batcher_stats, "direct_batches", 0),

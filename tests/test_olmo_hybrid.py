@@ -475,6 +475,39 @@ def test_a_request_through_the_batchers_entry_scores_like_the_reference(served, 
                      "delta.rows": 3, "delta.handovers": 3 * 6 * 3, "delta.positions": 3 * 6 * 150}
 
 
+def test_a_request_through_the_interpreted_kernels_scores_like_the_reference(reference, tolerance, monkeypatch):
+    """The same request through an entry traced as on a TPU, the kernels
+    interpreted: the rule's chunk pass is ops/delta_kernel.py (the stamp says
+    so and every batch is counted), the scores are within the configuration's
+    tolerance of the plain reference, and the rule's counters are what the
+    XLA entry counts: 3 hand-overs a row and linear layer, from the shapes."""
+    import functools
+
+    from distributed_tf_serving_tpu.serving import batcher as batcher_mod
+    from distributed_tf_serving_tpu.serving.server import build_stack
+
+    monkeypatch.setattr(batcher_mod, "serving_attention", functools.partial(sequence.serving_attention, interpret=True))
+    cfgs = load_config(os.path.join(ROOT, "configs", "olmo_hybrid_small.toml"))
+    config = dataclasses.replace(cfgs["model"], name="M")
+    cfg = dataclasses.replace(cfgs["server"], model_name="M", warmup=False)
+    _registry, batcher, impl, servable, _mesh, _watcher = build_stack(cfg, model_config=config)
+    try:
+        arrays = rows(3, config, folded=False)
+        before = _step_phases()
+        got = batcher.submit(servable, arrays).result(timeout=600)
+        after = _step_phases()
+        startup = impl.runtime_stats()["startup"]
+    finally:
+        batcher.stop()
+    batch = dict(arrays, feat_ids=(arrays["feat_ids"] % config.vocab_size).astype(np.int32))
+    want = reference_scores(reference, servable.params, batch, config)
+    assert np.max(np.abs(got["prediction_node"] - want)) < tolerance
+    assert startup["delta_rule"] == {"M:1": {"kernel": "pallas", "chunk": 64, "pieces": 2}}
+    assert startup["attention"]["M:1"]["kernel"] == "pallas" and batcher.stats.delta_kernel_batches == 1
+    delta = {name: after[name] - before.get(name, 0) for name in servable.model.step_stats if name.startswith("delta.")}
+    assert delta == {"delta.rows": 3, "delta.handovers": 3 * 6 * 3, "delta.positions": 3 * 6 * 150}
+
+
 def test_predict_answers_a_row_of_tokens_and_nothing_else(served):
     from distributed_tf_serving_tpu import codec
     from distributed_tf_serving_tpu.client import build_predict_request

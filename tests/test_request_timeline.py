@@ -449,6 +449,8 @@ def test_cli_server_reports_startup_stamps_and_raw_counters(tmp_path):
         assert startup.pop("attention") == {}
         # Nor a grouped stamp (PR 51): it routes to no expert.
         assert startup.pop("grouped") == {}
+        # Nor a delta-rule stamp (PR 52): it carries no matrix state.
+        assert startup.pop("delta_rule") == {}
         # And how many gRPC listeners share its port, from how many cores (PR 34).
         from distributed_tf_serving_tpu.serving.server import listener_count
 

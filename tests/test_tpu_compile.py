@@ -223,18 +223,20 @@ def cells_model(name: str, kind: str):
 STEPS: dict = {}  # (name, kind) -> sequence_cells_step's result: a step is compiled once a session
 
 
-def sequence_cells_step(name: str, kind: str, one_chip):
+def sequence_cells_step(name: str, kind: str, one_chip, rows: int | None = None):
     """(the compiled top-bucket step of the configuration `name` as its cell
-    serves it, with its counters where it has them; its `bytes accessed`)."""
-    if (name, kind) not in STEPS:
-        STEPS[name, kind] = _sequence_cells_step(name, kind, one_chip)
-    return STEPS[name, kind]
+    serves it, with its counters where it has them; its `bytes accessed`);
+    another rung's where `rows` says which."""
+    if (name, kind, rows) not in STEPS:
+        STEPS[name, kind, rows] = _sequence_cells_step(name, kind, one_chip, rows)
+    return STEPS[name, kind, rows]
 
 
-def _sequence_cells_step(name: str, kind: str, one_chip):
+def _sequence_cells_step(name: str, kind: str, one_chip, rung: int | None = None):
     from distributed_tf_serving_tpu.models import sequence
 
     model, rows, fields = cells_model(name, kind)
+    rows = rung or rows
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), shapes)
     batch = {
@@ -293,25 +295,89 @@ def test_pangu_moes_eight_row_step_writes_a_score_tile_once(one_chip, no_compile
     assert not SCORE_TILE.search(compiled.as_text())
 
 
-def test_olmo_hybrids_four_row_step_compiles_at_the_published_cut(one_chip, no_compile_cache, served_on_a_tpu):
+DEFAULT_VMEM = 16 << 20  # what a kernel has where it asks for no more
+
+
+def kernels_vmem(text: str, name: str) -> list[int]:
+    """The bytes of VMEM each compiled `tpu_custom_call` named `name` was given."""
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line and f"%{name}" in line]
+    return [int(re.search(r'used_scoped_memory_configs":\[\{"memory_space":"1","offset":"0","size":"(\d+)"', line).group(1))
+            for line in calls]
+
+
+@pytest.mark.parametrize("rows", [4, 2])
+def test_olmo_hybrids_steps_compile_at_the_published_cut(one_chip, no_compile_cache, served_on_a_tpu, rows):
     """Olmo-Hybrid-7B's first pipeline stage as `olmo_hybrid_rerank-bulk`
-    serves it (2.050 B parameters, rows of 2,048 tokens), the top bucket's
-    step with its counters, compiled as the batcher compiles it
-    (`base.step_jit`): the rule's solve is the block form (16 unrolled
-    substitution steps over all diagonal blocks and the merges' fused
-    reductions a layer: no triangular-solve custom call and no loop of its
-    own), its chunk loop a `while`, one a linear layer, what the step holds
-    beside the 4.10 GB of weights fits the chip's 16 GB, and the layers share
-    one copy of a fusion (the family's compiler option; without it the same
-    step is 164.4 MB of code: PERF.md section 6, PR 47)."""
-    compiled, accessed = sequence_cells_step("olmo_hybrid_rerank", "olmo_hybrid", one_chip)
+    serves it (2.050 B parameters, rows of 2,048 tokens), both rungs of its
+    ladder with their counters, compiled as the batcher compiles them
+    (`base.step_jit`): the rule's solve is the block form (no triangular-solve
+    custom call and no loop of its own), its chunk pass ONE Pallas kernel a
+    linear layer and no `while` (PR 52; six chunk loops before it), the
+    kernel's VMEM inside the 16 MiB a kernel has by default and no more asked
+    for, what the step holds beside the 4.10 GB of weights fits the chip's
+    16 GB, and the layers share one copy of a fusion (the family's compiler
+    option; without it the 4-row step was 164.4 MB of code: PERF.md section 6,
+    PR 47)."""
+    compiled, accessed = sequence_cells_step("olmo_hybrid_rerank", "olmo_hybrid", one_chip, rows)
     memory, text = compiled.memory_analysis(), compiled.as_text()
     assert 4.0e9 < memory.argument_size_in_bytes < 4.2e9
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12 * GIB
-    assert memory.generated_code_size_in_bytes < 64 << 20  # 39.1 MB; 42.6 on the XLA path
-    assert accessed < 166e9  # 160.7 GB; 169.2 on the XLA path, 191.8 with the backend's triangular solve (PR 46)
+    assert memory.generated_code_size_in_bytes < 64 << 20  # 36.1 MB at 4 rows; 39.1 with the chunk loops
+    # 143.1 GB at 4 rows (160.7 with the chunk loops, 169.2 with XLA's attention too) and 84.2 at 2
+    assert accessed < {4: 150e9, 2: 88e9}[rows]
     assert "Triangular" not in text  # InvertDiagBlocksLowerTriangular, what triangular_solve lowers to
-    assert len(re.findall(r"\) while\(", text)) == 6  # the six linear layers' chunk loops, and no other
+    assert not re.findall(r"\) while\(", text)
+    # the first full layer's attention and the six linear layers' rules
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 + 6 and "vmem_limit" not in text
+    vmem = kernels_vmem(text, "delta_rule")
+    assert len(vmem) == 6 and all(0 < size < DEFAULT_VMEM // 2 for size in vmem)  # 6.7 MB
+
+
+def test_olmo_hybrids_entry_as_the_batcher_builds_it_keeps_the_mlp_weights_prefetched(
+        one_chip, no_compile_cache, served_on_a_tpu, monkeypatch):
+    """The 4-row entry `batcher._build_entry` traces (the one-buffer upload's
+    unpack, the counters beside the outputs under a key that sorts FIRST),
+    compiled for a described v5e from shapes alone: XLA still prefetches the
+    seven MLP `up` weights into VMEM ahead of the fusions that read them
+    (`copy-done` operands). Where the counters stand among an executable's
+    results decided that until `forward` tied them to the logits with one
+    barrier: this entry lost all seven and 11 ms a step on the chip while the
+    same step traced with the counters last kept them (PERF.md section 6,
+    PR 52). A guard on a compiler's heuristic, so a failure here says "read
+    the served step again on the chip", not "the program is wrong"."""
+    import numpy as np
+
+    from distributed_tf_serving_tpu.ops.transfer import combined_layout, combined_words, transfer_spec
+    from distributed_tf_serving_tpu.serving import batcher as batcher_mod
+
+    model, rows, fields = cells_model("olmo_hybrid_rerank", "olmo_hybrid")
+    on_chip = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)  # noqa: E731
+    params = jax.tree.map(on_chip, jax.eval_shape(functools.partial(model.init, packed=True), jax.random.PRNGKey(0)))
+    layout = combined_layout(
+        {"feat_ids": np.empty((0, fields), np.int32), "feat_wts": np.empty((0, fields), np.float32)},
+        transfer_spec(model), rows=rows)
+    compiled = []
+
+    def compile_only(model, run, platform=None):
+        def call(p, b):
+            compiled.append(step_jit(model, run, "tpu").lower(p, b).compile())
+            raise StopIteration
+        return call
+
+    class Servable:  # what _build_entry reads of one
+        name, version = "olmo_hybrid", 1
+
+    Servable.model = model
+    monkeypatch.setattr(batcher_mod, "step_jit", compile_only)
+    fn, _spec, combined = batcher_mod.DynamicBatcher(buckets=(2, 4))._build_entry(Servable(), True)
+    with pytest.raises(StopIteration):
+        fn(params, jax.ShapeDtypeStruct((combined_words(layout),), jnp.uint32, sharding=one_chip), layout)
+    text = compiled[0].as_text()
+    assert combined and text.count('custom_call_target="tpu_custom_call"') == 1 + 6
+    fusions = [re.sub(r", (sharding|metadata|backend_config)=.*", "", line)
+               for line in re.findall(r"^\s*%fusion\.\d+ = f32\[4,2048,11008\][^\n]*", text, re.M)
+               if "convolution_add_fusion" in line]  # the second piece's product with `up`, times silu(gate)
+    assert len(fusions) == 7 and all("%copy-done" in line for line in fusions)
 
 
 def test_mimo_v2s_four_row_step_compiles_at_the_published_cut(one_chip, no_compile_cache, served_on_a_tpu):
@@ -366,6 +432,31 @@ def test_attention_kernel_compiles_at_the_cells_top_rungs(one_chip, no_compile_c
     sink = {"sink": shaped((qs[0][1],))} if sunk else {}  # within the default VMEM: the kernel asks for no more
     compiled = jax.jit(run).lower(tuple(map(shaped, qs)), tuple(map(shaped, ks)), shaped(v), **sink).compile()
     assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+    assert compiled.memory_analysis().generated_code_size_in_bytes < 2 << 20  # one kernel a layer
+
+
+# ------------------------------------------- the gated delta rule's chunk pass (PR 52)
+#
+# Mosaic's verdict on the kernel alone at the cell's two rungs: a head's tile
+# a static slice of lanes that are no whole vregs (96 and 192 columns a head),
+# the transposed product of the state's update, a last group of heads that
+# hangs over the array's edge (30 heads in groups of 8).
+
+@pytest.mark.parametrize("rows", [4, 2])
+@pytest.mark.parametrize("count", [2, 1])
+def test_delta_kernel_compiles_at_the_cells_rungs(one_chip, no_compile_cache, rows, count):
+    from distributed_tf_serving_tpu.ops.delta_kernel import chunk_pass
+
+    heads, length, dk, dv, chunk = 30, 2048, 96, 192, 64
+    shaped = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)  # noqa: E731
+    run = functools.partial(chunk_pass, heads=heads, cd=jnp.dtype(jnp.bfloat16), count=count)
+    compiled = jax.jit(run).lower(
+        shaped(rows, heads, length // chunk, chunk), shaped(rows, length, heads * dk), shaped(rows, length, heads * dk),
+        shaped(rows, length, heads * dv), shaped(rows, length // chunk, heads, chunk, chunk),
+        shaped(rows, heads, dk, dv)).compile()
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text and "vmem_limit" not in text
+    assert all(0 < size < DEFAULT_VMEM // 2 for size in kernels_vmem(text, "delta_rule"))  # 6.7 MB
     assert compiled.memory_analysis().generated_code_size_in_bytes < 2 << 20  # one kernel a layer
 
 
@@ -443,7 +534,10 @@ def test_a_routed_cells_step_holds_no_expert_loop(name, one_chip, no_compile_cac
 # are PR 51's own, held here for the next change to be seen against.
 LOWERED_TEXT = {
     "phi4_mini_flash_rerank/phi4flash/served": "023772657653519e",
-    "olmo_hybrid_rerank/olmo_hybrid/served": "64186f915a41165c",
+    # PR 52: the rule's chunk pass is the kernel and the counters leave with the logits (one barrier) ...
+    "olmo_hybrid_rerank/olmo_hybrid/served": "d0ad9408ce772c58",
+    # ... and XLA's path is the parent's but for that barrier (970016a6f6fb2d72 on both trees before it)
+    "olmo_hybrid_rerank/olmo_hybrid/outside": "a9d9621dead11f8d",
     "dcn_v2_ref43/dcn_v2/served": "c4b1ba715cf54e70",
     "dlrm_dcnv2_mlperf/dlrm_dcnv2/served": "9bbd2eb81f11ee6f",
     "k_exaone_moe_rerank/exaone_moe/outside": "e21defa4c02679fe",
