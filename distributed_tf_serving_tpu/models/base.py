@@ -32,10 +32,10 @@ Batch = dict[str, jax.Array]
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
-    """Knob set shared by the CTR model zoo and the five sequence families
-    (phi4flash, pangu_moe, exaone_moe, olmo_hybrid, mimo_v2), whose keys carry the
-    names of their published config.json and whose defaults build a small
-    valid model.
+    """Knob set shared by the CTR model zoo and the six sequence families
+    (phi4flash, pangu_moe, exaone_moe, olmo_hybrid, mimo_v2, falcon_h1), whose keys
+    carry the names of their published config.json and whose defaults build a
+    small valid model.
 
     Matches the reference workload point where applicable: num_fields=43
     (FIELD_NUM, DCNClient.java:25).
@@ -150,6 +150,36 @@ class ModelConfig:
     attention_value_scale: float = 1.0
     add_swa_attention_sink_bias: bool = True
     add_full_attention_sink_bias: bool = False
+    # falcon_h1 (models/falcon_h1.py): a row is num_fields token ids as above;
+    # embed_dim the hidden size, intermediate_size every layer's gated MLP
+    # width (mlp_dims is not read), layer_norm_eps the RMSNorms' epsilon,
+    # num_attention_heads / num_key_value_heads / head_dim / rope_theta the
+    # attention's (rotary on all of a head's dims). EVERY layer holds a
+    # Mamba-2 mixer beside its attention, both on one normed input. Under the
+    # published config.json's names, the mixer's sizes: its inner width
+    # (mamba_n_heads heads of mamba_d_head), the state's width a head (the
+    # state is [mamba_d_head, mamba_d_state] a head), the groups that share B
+    # and C, the taps of the causal convolution and the positions a chunk of
+    # the SSD's matrix form; and the model's multipliers, constants on the
+    # path of every product: the embedding's, the attention's input, output
+    # and keys, the mixer's input and output, one a slice of the mixer's
+    # input projection (z, x, B, C, dt in that order) and the MLP's gate and
+    # output.
+    mamba_d_ssm: int = 64
+    mamba_n_heads: int = 4
+    mamba_d_head: int = 16
+    mamba_d_state: int = 32
+    mamba_n_groups: int = 2
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 128
+    embedding_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    mlp_multipliers: tuple[float, ...] = (1.0, 1.0)
     # numerics
     compute_dtype: str = "bfloat16"  # "float32" for AUC-parity mode
     param_dtype: str = "float32"
@@ -208,7 +238,8 @@ class Model:
     # beside the id/weight pair (the DLRM families).
     takes_dense: bool = False
     # The kind of every layer of a sequence family (phi4flash, pangu_moe, exaone_moe,
-    # olmo_hybrid, mimo_v2), whose rows are num_fields TOKENS; empty for the CTR families.
+    # olmo_hybrid, mimo_v2, falcon_h1), whose rows are num_fields TOKENS; empty for the
+    # CTR families.
     layer_plan: tuple[str, ...] = ()
     # What a family with a routed layer holds of it, as (name, number) pairs:
     # published, held, first, top_k, heads_published, heads_held,
@@ -218,13 +249,15 @@ class Model:
     # layer: an attention layer's kind, window, block of queries and keys a
     # block (exaone_moe, olmo_hybrid, mimo_v2, which adds what else differs by
     # kind: kv_heads, rotary_dims, theta, sink); a linear layer's kind, chunk,
-    # state hand-overs a row and bytes of a row's state (olmo_hybrid); empty
-    # for every other family.
+    # state hand-overs a row and bytes of a row's state (olmo_hybrid);
+    # falcon_h1's layers, which hold both, state the attention's entry and
+    # beside it `ssd`, the SSM's (kind, chunk, hand-overs and state bytes a
+    # row); empty for every other family.
     attention_plan: tuple[tuple[tuple[str, object], ...], ...] = ()
     # For a family whose step counts what it did on the device (pangu_moe's,
     # exaone_moe's and mimo_v2's routing, exaone_moe's, olmo_hybrid's and
-    # mimo_v2's score tiles, olmo_hybrid's state hand-overs, mimo_v2's
-    # sinks): `apply_stats(params, batch) -> (apply's outputs, int32
+    # mimo_v2's score tiles, olmo_hybrid's and falcon_h1's state hand-overs,
+    # falcon_h1's score tiles, mimo_v2's sinks): `apply_stats(params, batch) -> (apply's outputs, int32
     # [len(step_stats)])`, the counters named by `step_stats` in order. The
     # batcher decides on it when it BUILDS the servable's entry: the counters
     # then ride back beside the scores and are recorded as phases by count.
@@ -325,7 +358,8 @@ def register_model(kind: str):
 
 def build_model(kind: str, config: ModelConfig | None = None, **overrides) -> Model:
     """Instantiate a model family by kind: dcn, dcn_v2, wide_deep, deepfm,
-    two_tower, dlrm, dlrm_dcnv2, phi4flash, pangu_moe, exaone_moe, olmo_hybrid, mimo_v2."""
+    two_tower, dlrm, dlrm_dcnv2, phi4flash, pangu_moe, exaone_moe, olmo_hybrid, mimo_v2,
+    falcon_h1."""
     if kind not in _BUILDERS:
         raise KeyError(f"unknown model kind {kind!r}; have {sorted(_BUILDERS)}")
     if config is None:

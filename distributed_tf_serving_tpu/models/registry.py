@@ -201,9 +201,11 @@ class Servable:
     def attention_plan(self) -> list[dict] | None:
         """Each layer's mixer where a family's differs by layer: an attention
         layer's kind, window, block of queries, keys a block; a linear
-        layer's kind, chunk, hand-overs and state bytes a row. None for every
-        other family."""
-        return [dict(layer) for layer in self.model.attention_plan] or None
+        layer's kind, chunk, hand-overs and state bytes a row; under `ssd`
+        the same of the Mamba-2 mixer a falcon_h1 layer holds beside its
+        attention. None for every other family."""
+        return [{name: dict(value) if isinstance(value, tuple) else value for name, value in layer}
+                for layer in self.model.attention_plan] or None
 
     @property
     def params_bytes(self) -> int:

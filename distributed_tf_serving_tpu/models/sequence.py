@@ -1,13 +1,14 @@
 """What the sequence-ranker families (phi4flash, pangu_moe, exaone_moe,
-olmo_hybrid, mimo_v2) share: products whose float32 activations enter as pieces of the
+olmo_hybrid, mimo_v2, falcon_h1) share: products whose float32 activations enter as pieces of the
 compute dtype (`product`: one product a call wherever a form exists that
 copies no large array), the causal softmax of a block of queries, the blocks
 themselves, causal attention in those blocks (`blocked_attention`:
-exaone_moe's and olmo_hybrid's full layers, both kinds of mimo_v2's, whose
-window layers' softmax holds a learned sink), the attention at all positions
-as one Pallas kernel a layer where a one-chip served entry runs on a TPU
-(`attention`, `takes_kernel`: all five families), the causal depthwise convolution
-(`causal_conv`: phi4flash's Mamba layers and olmo_hybrid's linear ones), and
+exaone_moe's and olmo_hybrid's full layers, falcon_h1's, both kinds of
+mimo_v2's, whose window layers' softmax holds a learned sink), the attention
+at all positions as one Pallas kernel a layer where a one-chip served entry
+runs on a TPU (`attention`, `takes_kernel`: all six families), the causal
+depthwise convolution (`causal_conv`: phi4flash's Mamba layers, olmo_hybrid's
+linear ones and falcon_h1's Mamba-2 mixers), and
 the cut to the last position. One implementation, so that a change to any of
 them is measured on every family's cell. (`models/routed.py` has what the
 routed families share beside these.)
@@ -170,12 +171,13 @@ def query_blocks(queries: int, keys: int, window: int | None = None, block: int 
 
 class Served(NamedTuple):
     """What `serving_attention` was entered with: the notes lists of the
-    attention, the routed layers and the delta rule, and whether the kernels
-    run interpreted."""
+    attention, the routed layers, the delta rule and the SSD, and whether the
+    kernels run interpreted."""
     notes: list
     interpret: bool
     grouped: list | None
     delta: list | None
+    ssd: list | None
 
 
 _served = threading.local()  # .entry: a Served while serving_attention is entered
@@ -183,7 +185,7 @@ _served = threading.local()  # .entry: a Served while serving_attention is enter
 
 @contextlib.contextmanager
 def serving_attention(notes: list, interpret: bool = False, grouped: list | None = None,
-                      delta: list | None = None):
+                      delta: list | None = None, ssd: list | None = None):
     """While the batcher traces a one-chip served entry in this thread
     (serving/batcher.py _build_entry, and nowhere else): an attention at all
     positions may take the Pallas kernel (ops/attention_kernel.py), and
@@ -194,8 +196,11 @@ def serving_attention(notes: list, interpret: bool = False, grouped: list | None
     `grouped` where one is given: the `startup.grouped` stamp); and a gated
     delta rule's chunk pass may take its own (ops/delta_kernel.py, chosen by
     `olmo_hybrid.takes_kernel`, which appends `olmo_hybrid.delta_choice`'s
-    dict to `delta`: the `startup.delta_rule` stamp). `interpret` is for
-    tests on the CPU: choose as on a TPU and run the kernels interpreted.
+    dict to `delta`: the `startup.delta_rule` stamp); and a Mamba-2 mixer's
+    SSD says how it walks a row (`falcon_h1.ssd_choice`'s dict, appended to
+    `ssd`: the `startup.ssd` stamp; XLA's path everywhere, no kernel yet).
+    `interpret` is for tests on the CPU: choose as on a TPU and run the
+    kernels interpreted.
 
     Outside it every attention, routed layer and delta rule is the XLA path
     that stood before its kernel, as `embeddings.serving_gathers` keeps XLA's
@@ -203,7 +208,7 @@ def serving_attention(notes: list, interpret: bool = False, grouped: list | None
     trainer trace `model.apply` themselves, and a `tpu_custom_call` neither
     partitions nor has a gradient rule."""
     before = getattr(_served, "entry", None)
-    _served.entry = Served(notes, interpret, grouped, delta)
+    _served.entry = Served(notes, interpret, grouped, delta, ssd)
     try:
         yield notes
     finally:

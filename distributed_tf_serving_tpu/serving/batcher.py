@@ -1120,6 +1120,12 @@ class DynamicBatcher:
             weakref.WeakKeyDictionary()
         )
         self._delta_kernel: weakref.WeakSet[Servable] = weakref.WeakSet()
+        # And how each Mamba-2 mixer's SSD walks a row (models/falcon_h1.py
+        # ssd_choice): `startup.ssd`. XLA's path everywhere: no kernel, so no
+        # batches to count.
+        self._ssds: weakref.WeakKeyDictionary[Servable, list] = (
+            weakref.WeakKeyDictionary()
+        )
         # _jit_for is reached from the batcher thread (fused-path
         # eligibility) AND the dispatch thread; one lock keeps the entry
         # build single-shot.
@@ -1832,6 +1838,18 @@ class DynamicBatcher:
                 for sv, notes in self._deltas.items() if notes
             }
 
+    def ssds(self) -> dict[str, dict]:
+        """"name:version" -> the SSD of that servable's entry as traced:
+        `{"path": "xla", "chunk", "state_bytes_a_row"}` (the longest
+        chunk's where rungs differ), for every servable whose layers
+        hold a Mamba-2 mixer. A custom run_fn traces its own entries, outside
+        serving_attention: no stamp."""
+        with self._jit_lock:
+            return {
+                f"{sv.name}:{sv.version}": max(notes, key=lambda n: n["chunk"])
+                for sv, notes in self._ssds.items() if notes
+            }
+
     def pipeline_stats(self) -> dict:
         """Continuous-batching pipeline snapshot (ISSUE 9): configured
         depth/window, live in-flight occupancy (total and per bucket),
@@ -2115,18 +2133,21 @@ class DynamicBatcher:
         self._gather_kernel.discard(servable)
         # And the one in which an attention at all positions may take the
         # Pallas attention kernel, a routed layer's held experts the grouped
-        # kernels and a gated delta rule's chunk pass its own
-        # (models/sequence.py serving_attention).
+        # kernels and a gated delta rule's chunk pass its own, and a Mamba-2
+        # mixer's SSD says how it walks a row (models/sequence.py
+        # serving_attention).
         attentions = self._attentions[servable] = []
         self._attention_kernel.discard(servable)
         groupeds = self._groupeds[servable] = []
         self._grouped_kernel.discard(servable)
         deltas = self._deltas[servable] = []
         self._delta_kernel.discard(servable)
+        ssds = self._ssds[servable] = []
 
         def noting(ap):
             def traced(p, batch):
-                with serving_gathers(gathers), serving_attention(attentions, grouped=groupeds, delta=deltas):
+                with serving_gathers(gathers), serving_attention(
+                        attentions, grouped=groupeds, delta=deltas, ssd=ssds):
                     out = ap(p, batch)
                 if any(note["kernel"] == "pallas" for note in gathers):
                     self._gather_kernel.add(servable)

@@ -227,6 +227,7 @@ class PredictionServiceImpl:
         attentions = getattr(self.batcher, "attentions", None)
         groupeds = getattr(self.batcher, "groupeds", None)
         delta_rules = getattr(self.batcher, "delta_rules", None)
+        ssds = getattr(self.batcher, "ssds", None)
         block["startup"] = {
             **self.startup,
             "warmup_s": self.warmup_s,
@@ -242,6 +243,7 @@ class PredictionServiceImpl:
             "attention": attentions() if callable(attentions) else {},
             "grouped": groupeds() if callable(groupeds) else {},
             "delta_rule": delta_rules() if callable(delta_rules) else {},
+            "ssd": ssds() if callable(ssds) else {},
         }
         block["embedding_pack"] = self.registry.per_servable("embedding_pack")
         block["compile_cache"] = (

@@ -451,6 +451,8 @@ def test_cli_server_reports_startup_stamps_and_raw_counters(tmp_path):
         assert startup.pop("grouped") == {}
         # Nor a delta-rule stamp (PR 52): it carries no matrix state.
         assert startup.pop("delta_rule") == {}
+        # Nor an SSD stamp (PR 54): no layer of it holds a Mamba-2 mixer.
+        assert startup.pop("ssd") == {}
         # And how many gRPC listeners share its port, from how many cores (PR 34).
         from distributed_tf_serving_tpu.serving.server import listener_count
 

@@ -398,6 +398,27 @@ def test_mimo_v2s_four_row_step_compiles_at_the_published_cut(one_chip, no_compi
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 6 + 2 * 6
 
 
+@pytest.mark.parametrize("rows", [4, 2])
+def test_falcon_h1s_steps_compile_at_the_published_cut(one_chip, no_compile_cache, served_on_a_tpu, rows):
+    """Falcon-H1-34B's first pipeline stage as `falcon_h1_34b_rerank-bulk`
+    serves it (3.49 B parameters at five layers, rows of 2,048 tokens), both
+    rungs of its ladder with their five counters: the attention's kernel a
+    layer but the last at 20 query heads over 4 key-value heads (5 a group, a
+    grouping no other cell has), the SSD plain XLA whose only loop is the
+    state's hand-over (ONE `while` a layer, 16 steps of one multiply-add of
+    the state), and what the step holds beside the 6.98 GB of weights fits
+    the chip's 16 GB."""
+    compiled, accessed = sequence_cells_step("falcon_h1_34b_rerank", "falcon_h1", one_chip, rows)
+    memory, text = compiled.memory_analysis(), compiled.as_text()
+    layers = len(cells_model("falcon_h1_34b_rerank", "falcon_h1")[0].layer_plan)
+    assert layers in (4, 5) and {5: 6.9e9, 4: 6.0e9}[layers] < memory.argument_size_in_bytes < {5: 7.1e9, 4: 6.2e9}[layers]
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14 * GIB
+    assert memory.generated_code_size_in_bytes < 64 << 20
+    assert text.count('custom_call_target="tpu_custom_call"') == layers - 1 and "vmem_limit" not in text
+    assert len(re.findall(r"\) while\(", text)) == layers  # the hand-over scan, and no other loop
+    assert not SCORE_TILE.search(text)
+
+
 # ------------------------------------------- the Pallas attention (PR 48)
 #
 # What interpret mode cannot see: Mosaic's verdict on the kernel's slices,
@@ -418,6 +439,8 @@ ATTENTION_SHAPES = {
     "mimo_v2_full": (((4, 64, 2048, 192),), ((4, 4, 2048, 192),), (4, 4, 2048, 128), None, 3),
     "mimo_v2_window_sink": (((4, 64, 2048, 192),), ((4, 8, 2048, 192),), (4, 8, 2048, 128), 128, 3, True),
     "mimo_v2_full_sink": (((4, 64, 2048, 192),), ((4, 4, 2048, 192),), (4, 4, 2048, 128), None, 3, True),
+    # falcon_h1_34b_rerank: 20 query heads over 4 key-value heads, 5 a group (the others' groups are 1, 2, 8 and 16)
+    "falcon_h1": (((4, 20, 2048, 128),), ((4, 4, 2048, 128),), (4, 4, 2048, 128), None, 2),
 }
 
 
