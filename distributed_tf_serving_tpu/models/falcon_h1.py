@@ -42,13 +42,19 @@ nothing overflows however fast a head decays) and `S_in` the state handed in,
   Y = (M o (C B')) (dt x) + exp(cum) o (C S_in),     M_ij = exp(cum_i - cum_j) where j <= i, else 0
   S_out = exp(cum_last) S_in + (exp(cum_last - cum) dt x)' B
 
-`C B'` is made once a GROUP and read by its heads. Every product is made for
-all chunks of a row at once but the hand-over itself, a `lax.scan` over the
-chunks whose carry is the float32 state and whose step is one multiply-add of
-it (`ssd.handovers`: one a chunk, row and layer). A length that is no multiple
-of the chunk is padded with `dt = 0`, which leaves the state as it is. Plain
-`jax.numpy` through `sequence.product` everywhere: no kernel walks the chunks
-yet, and the servable's `startup.ssd` stamp says so (`ssd_choice`).
+`C B'` is made once a GROUP and read by its heads. XLA's path makes every
+product for all chunks of a row at once but the hand-over itself, a `lax.scan`
+over the chunks whose carry is the float32 state and whose step is one
+multiply-add of it (`ssd.handovers`: one a chunk, row and layer): the chunks'
+own states and the states handed in pass through memory. Inside a one-chip
+served entry on a TPU (`takes_kernel`, by `sequence.kernels_run`; the servable's
+`startup.ssd` stamp says which) the walk at all positions is ONE Pallas kernel
+a layer (ops/ssd_kernel.py): a grid over (row, group of heads, chunk) whose
+states stay in VMEM from a row's first chunk to its last, x, B, C and y
+crossing as the convolution leaves them; the same pieces in the same pairs,
+float32 sums in another order (tests/test_ssd_kernel.py). A length that is no
+multiple of the chunk is padded with `dt = 0`, which leaves the state as it
+is.
 
 The attention is `sequence.blocked_attention`: in a one-chip served entry on a
 TPU every layer but the last runs ONE Pallas kernel (ops/attention_kernel.py),
@@ -58,7 +64,8 @@ What the served step skips (exact): the score reads the last position, so of
 the LAST layer the keys and values, the SSM's input projection, convolution
 and state hand-overs are computed at all positions, and the query, the
 attention's output, the SSD's read of the state (the last `y` alone: the
-chunks' products inside themselves are not made at all), the gate, the gated
+chunks' products inside themselves are not made at all, and the hand-overs
+are XLA's scan on every backend), the gate, the gated
 norm, both output products and the MLP at the last position only; every layer
 before it at all positions. A row whose weights are all zero (a padded row) is
 left out of every counter.
@@ -221,21 +228,30 @@ def ssd_chunks(length: int, chunk: int) -> tuple[int, int]:
     return chunk, -(-length // chunk)
 
 
-def ssd_choice(length: int, s: dict) -> dict:
-    """`{"path": "xla", "chunk", "state_bytes_a_row"}`: how the SSD walks rows
-    of `length` positions: the positions a chunk and the float32 bytes of a
-    row's state (every head's `[P, N]`) that a hand-over carries. A servable's
-    `startup.ssd` stamp. XLA's scan hands the state over through HBM; there is
-    no kernel to choose yet, so the path does not depend on where the step is
-    traced."""
-    return {"path": "xla", "chunk": ssd_chunks(length, s["chunk"])[0],
+def takes_kernel(last_only: bool = False) -> bool:
+    """Whether the Pallas kernel walks an SSD's chunks (ops/ssd_kernel.py: the
+    states stay in VMEM along a row): where a served entry's kernels run
+    (`sequence.kernels_run`: inside the batcher's one-chip entry on a TPU), at
+    every length, for `y` at all positions. The last layer's hand-overs, whose
+    `y` is read at one position, stay XLA's scan, as its one query stays XLA's
+    attention."""
+    return sequence.kernels_run() and not last_only
+
+
+def ssd_choice(length: int, s: dict, last_only: bool = False) -> dict:
+    """`{"path": "pallas" | "xla", "chunk", "state_bytes_a_row"}`: how the SSD
+    walks rows of `length` positions: which path (`takes_kernel`; XLA's scan
+    hands the state over through HBM), the positions a chunk and the float32
+    bytes of a row's state (every head's `[P, N]`) that a hand-over carries. A
+    servable's `startup.ssd` stamp."""
+    return {"path": "pallas" if takes_kernel(last_only) else "xla", "chunk": ssd_chunks(length, s["chunk"])[0],
             "state_bytes_a_row": s["ssm_heads"] * s["ssm_head"] * s["state"] * 4}
 
 
-def note_ssd(length: int, s: dict) -> None:
+def note_ssd(length: int, s: dict, last_only: bool = False) -> None:
     """`ssd_choice`, noted for the served entry being traced."""
     served = sequence.served_entry()
-    choice = ssd_choice(length, s)
+    choice = ssd_choice(length, s, last_only)
     if served is not None and served.ssd is not None and choice not in served.ssd:
         served.ssd.append(choice)
 
@@ -253,7 +269,13 @@ def ssd(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array,
     is S before the first position (zero where None). Returns `y [n, L, H, P]`
     and the state after the last position, float32. With `last_only`, `y` is
     `[n, 1, H, P]`, the last position's alone: the state's hand-overs are made
-    and the chunks' own products are not. The caller's `ssd` scope."""
+    and the chunks' own products are not. The caller's `ssd` scope.
+
+    Where a one-chip served entry's kernels run (`takes_kernel`) the walk at
+    all positions is one Pallas kernel a layer that keeps the states in VMEM
+    (ops/ssd_kernel.py); everywhere else (`shard_map`, the GSPMD executors,
+    the trainer, a CPU) and for the last position alone it is XLA's, below:
+    the plain form the kernel is tested against."""
     n, length, heads, width = x.shape
     groups, state_width = b.shape[2], b.shape[3]
     per = heads // groups
@@ -261,10 +283,24 @@ def ssd(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array,
     chunk, steps = ssd_chunks(length, chunk)
     pad = steps * chunk - length
 
-    def chunks(v):  # [n, L, ...] -> [n, steps, chunk, ...], the row's end padded with zeros (dt = 0)
-        v = jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
-        return v.reshape((n, steps, chunk) + v.shape[2:])
+    def padded(v):  # the row's end padded with zeros (dt = 0)
+        return jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
 
+    def chunks(v):  # [n, L, ...] -> [n, steps, chunk, ...]
+        return padded(v).reshape((n, steps, chunk) + v.shape[2:])
+
+    if takes_kernel(last_only):  # x, B, C and y cross as they lie: no turn on either side
+        from ..ops import ssd_kernel
+
+        with jax.named_scope("chunks"):
+            dt = jnp.moveaxis(chunks(dt), 3, 1)  # [n, H, Z, C]: a chunk's positions in the lanes
+            state = jnp.zeros((n, heads, width, state_width), jnp.float32) if initial_state is None else initial_state
+            y, state = ssd_kernel.chunk_walk(
+                dt, jnp.cumsum(dt * a.astype(jnp.float32)[:, None, None], axis=3),
+                *(padded(v).reshape(n, steps * chunk, -1) for v in (x, b, c)),
+                state.astype(STATE_DTYPE).astype(jnp.float32), heads=heads, groups=groups, cd=jnp.dtype(cd),
+                count=OPERAND_PIECES, state_dtype=jnp.dtype(STATE_DTYPE), interpret=sequence.served_entry().interpret)
+        return y.reshape(n, steps * chunk, heads, width)[:, :length], state
     x = chunks(x).reshape(n, steps, chunk, groups, per, width)
     dt = chunks(dt).reshape(n, steps, chunk, groups, per)
     b, c = chunks(b), chunks(c)
@@ -306,7 +342,7 @@ def ssm(p: dict, a: jax.Array, s: dict, cd, eps: float, last_only: bool = False)
     (the state still walks every position). The caller's `ssm` scope."""
     n, length, _ = a.shape
     heads, width, groups, state = s["ssm_heads"], s["ssm_head"], s["groups"], s["state"]
-    note_ssd(length, s)
+    note_ssd(length, s, last_only)
     with jax.named_scope("in_proj"):
         projected = _dot(a * s["ssm_in"], p["in"], cd) * slice_multipliers(s)
         z, mixed, dt = jnp.split(projected, (s["d_ssm"], s["d_ssm"] + s["channels"]), axis=-1)

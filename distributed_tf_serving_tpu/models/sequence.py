@@ -197,12 +197,13 @@ def serving_attention(notes: list, interpret: bool = False, grouped: list | None
     delta rule's chunk pass may take its own (ops/delta_kernel.py, chosen by
     `olmo_hybrid.takes_kernel`, which appends `olmo_hybrid.delta_choice`'s
     dict to `delta`: the `startup.delta_rule` stamp); and a Mamba-2 mixer's
-    SSD says how it walks a row (`falcon_h1.ssd_choice`'s dict, appended to
-    `ssd`: the `startup.ssd` stamp; XLA's path everywhere, no kernel yet).
+    SSD at all positions may take its own (ops/ssd_kernel.py, chosen by
+    `falcon_h1.takes_kernel`; `falcon_h1.note_ssd` appends
+    `falcon_h1.ssd_choice`'s dict to `ssd`: the `startup.ssd` stamp).
     `interpret` is for tests on the CPU: choose as on a TPU and run the
     kernels interpreted.
 
-    Outside it every attention, routed layer and delta rule is the XLA path
+    Outside it every attention, routed layer, delta rule and SSD is the XLA path
     that stood before its kernel, as `embeddings.serving_gathers` keeps XLA's
     gather and for its reasons: the GSPMD executors, `shard_map` and the
     trainer trace `model.apply` themselves, and a `tpu_custom_call` neither

@@ -788,6 +788,9 @@ class BatcherStats:
     # kernel (models/olmo_hybrid.py delta_choice; `startup.delta_rule` names
     # it).
     delta_kernel_batches: int = 0
+    # Batches whose entry walks a Mamba-2 mixer's SSD chunks in the Pallas
+    # kernel (models/falcon_h1.py ssd_choice; `startup.ssd` names the path).
+    ssd_kernel_batches: int = 0
     # Batches of one request that its own handler thread closed and staged
     # (submit's direct crossing): no collector, no coalesce window, no
     # dispatch thread. The phase `batch.direct` counts the same.
@@ -1121,11 +1124,12 @@ class DynamicBatcher:
         )
         self._delta_kernel: weakref.WeakSet[Servable] = weakref.WeakSet()
         # And how each Mamba-2 mixer's SSD walks a row (models/falcon_h1.py
-        # ssd_choice): `startup.ssd`. XLA's path everywhere: no kernel, so no
-        # batches to count.
+        # ssd_choice): `startup.ssd`; and the servables whose entry runs the
+        # Pallas kernel, whose batches are counted.
         self._ssds: weakref.WeakKeyDictionary[Servable, list] = (
             weakref.WeakKeyDictionary()
         )
+        self._ssd_kernel: weakref.WeakSet[Servable] = weakref.WeakSet()
         # _jit_for is reached from the batcher thread (fused-path
         # eligibility) AND the dispatch thread; one lock keeps the entry
         # build single-shot.
@@ -1840,13 +1844,13 @@ class DynamicBatcher:
 
     def ssds(self) -> dict[str, dict]:
         """"name:version" -> the SSD of that servable's entry as traced:
-        `{"path": "xla", "chunk", "state_bytes_a_row"}` (the longest
-        chunk's where rungs differ), for every servable whose layers
-        hold a Mamba-2 mixer. A custom run_fn traces its own entries, outside
-        serving_attention: no stamp."""
+        `{"path": "pallas" | "xla", "chunk", "state_bytes_a_row"}` (the
+        kernel's, then the longest chunk's, where rungs differ), for every
+        servable whose layers hold a Mamba-2 mixer. A custom run_fn traces its
+        own entries, outside serving_attention: XLA's scan, no stamp."""
         with self._jit_lock:
             return {
-                f"{sv.name}:{sv.version}": max(notes, key=lambda n: n["chunk"])
+                f"{sv.name}:{sv.version}": max(notes, key=lambda n: (n["path"] == "pallas", n["chunk"]))
                 for sv, notes in self._ssds.items() if notes
             }
 
@@ -2133,9 +2137,8 @@ class DynamicBatcher:
         self._gather_kernel.discard(servable)
         # And the one in which an attention at all positions may take the
         # Pallas attention kernel, a routed layer's held experts the grouped
-        # kernels and a gated delta rule's chunk pass its own, and a Mamba-2
-        # mixer's SSD says how it walks a row (models/sequence.py
-        # serving_attention).
+        # kernels, a gated delta rule's chunk pass its own and a Mamba-2
+        # mixer's SSD its own (models/sequence.py serving_attention).
         attentions = self._attentions[servable] = []
         self._attention_kernel.discard(servable)
         groupeds = self._groupeds[servable] = []
@@ -2143,6 +2146,7 @@ class DynamicBatcher:
         deltas = self._deltas[servable] = []
         self._delta_kernel.discard(servable)
         ssds = self._ssds[servable] = []
+        self._ssd_kernel.discard(servable)
 
         def noting(ap):
             def traced(p, batch):
@@ -2157,6 +2161,8 @@ class DynamicBatcher:
                     self._grouped_kernel.add(servable)
                 if any(note["kernel"] == "pallas" for note in deltas):
                     self._delta_kernel.add(servable)
+                if any(note["path"] == "pallas" for note in ssds):
+                    self._ssd_kernel.add(servable)
                 return out
             return traced
 
@@ -3478,6 +3484,9 @@ class DynamicBatcher:
                 if servable in self._delta_kernel:
                     self.stats.delta_kernel_batches += 1
                     request_trace.add_many((("batch.delta_kernel", 0.0, 1),))
+                if servable in self._ssd_kernel:
+                    self.stats.ssd_kernel_batches += 1
+                    request_trace.add_many((("batch.ssd_kernel", 0.0, 1),))
                 if group[0].direct:
                     self.stats.direct_batches += 1
                     request_trace.add_many((("batch.direct", 0.0, 1),))

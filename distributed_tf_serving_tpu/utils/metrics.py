@@ -761,6 +761,9 @@ class ServerMetrics:
                 # And those whose entry walks the gated delta rule's chunks in
                 # the Pallas kernel (`startup.delta_rule`).
                 "delta_kernel_batches": getattr(batcher_stats, "delta_kernel_batches", 0),
+                # And those whose entry walks a Mamba-2 mixer's SSD chunks in
+                # the Pallas kernel (`startup.ssd`).
+                "ssd_kernel_batches": getattr(batcher_stats, "ssd_kernel_batches", 0),
                 # And those of one request that its own handler thread
                 # closed and staged (the batcher's direct crossing).
                 "direct_batches": getattr(batcher_stats, "direct_batches", 0),

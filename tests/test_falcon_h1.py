@@ -456,22 +456,27 @@ def test_a_request_through_the_batchers_entry_scores_like_the_reference(served, 
                      "ssd.rows": 3, "ssd.handovers": 3 * 4 * 3, "ssd.positions": 3 * 4 * 150}
 
 
-def test_a_request_through_the_interpreted_kernel_scores_like_the_reference(reference, tolerance, monkeypatch):
-    """The same request through an entry traced as on a TPU, the attention's
-    kernel interpreted: 6 query heads over 2 key-value heads, 3 a group, a
-    grouping no other family has; the SSD stays XLA's and its stamp says so."""
+def test_a_request_through_the_interpreted_kernels_scores_like_the_reference(reference, tolerance, monkeypatch):
+    """The same request through an entry traced as on a TPU, its kernels
+    interpreted: the attention's at 6 query heads over 2 key-value heads, 3 a
+    group, a grouping no other family has, and the SSD's chunk walk
+    (ops/ssd_kernel.py) in every layer but the last, whose hand-overs stay
+    XLA's scan; the `startup.ssd` stamp names the kernel's path of the two the
+    entry noted, and the batch is counted once under `batch.ssd_kernel`."""
     import functools
 
     from distributed_tf_serving_tpu.serving import batcher as batcher_mod
     from distributed_tf_serving_tpu.serving.server import build_stack
+    from distributed_tf_serving_tpu.utils.tracing import request_trace
 
     monkeypatch.setattr(batcher_mod, "serving_attention", functools.partial(sequence.serving_attention, interpret=True))
     cfgs = load_config(os.path.join(ROOT, "configs", "falcon_h1_small.toml"))
     config = dataclasses.replace(cfgs["model"], name="M")
     cfg = dataclasses.replace(cfgs["server"], model_name="M", warmup=False)
     _registry, batcher, impl, servable, _mesh, _watcher = build_stack(cfg, model_config=config)
+    counted = lambda: request_trace.snapshot().get("batch.ssd_kernel", {}).get("count", 0)  # noqa: E731
     try:
-        arrays = rows(3, config, folded=False)
+        arrays, before = rows(3, config, folded=False), counted()
         got = batcher.submit(servable, arrays).result(timeout=600)
         startup = impl.runtime_stats()["startup"]
     finally:
@@ -480,7 +485,41 @@ def test_a_request_through_the_interpreted_kernel_scores_like_the_reference(refe
     want = reference_scores(reference, servable.params, batch, config)
     assert np.max(np.abs(got["prediction_node"] - want)) < tolerance
     assert startup["attention"]["M:1"]["kernel"] == "pallas" and batcher.stats.attention_kernel_batches == 1
-    assert startup["ssd"] == {"M:1": {"path": "xla", "chunk": 64, "state_bytes_a_row": 8 * 16 * 32 * 4}}
+    assert startup["ssd"] == {"M:1": {"path": "pallas", "chunk": 64, "state_bytes_a_row": 8 * 16 * 32 * 4}}
+    assert batcher.stats.batches == 1 and batcher.stats.ssd_kernel_batches == 1 and counted() - before == 1
+
+
+def test_the_ssd_takes_the_kernel_inside_a_served_entry_on_a_tpu_and_nowhere_else(monkeypatch):
+    """`ssd_choice` from what a trace can see: `pallas` inside the batcher's
+    one-chip entry on a backend that answers `tpu` (or interpreted), `xla` on
+    a CPU, outside the entry whatever the backend (the mesh executors,
+    `shard_map`, the trainer) and for the last position alone; `note_ssd`
+    notes each choice once."""
+    s = falcon_h1._sizes(load_config(os.path.join(ROOT, "configs", "falcon_h1_small.toml"))["model"])
+    xla = {"path": "xla", "chunk": 64, "state_bytes_a_row": 8 * 16 * 32 * 4}
+    assert falcon_h1.ssd_choice(150, s) == xla
+    with sequence.serving_attention([], ssd=(notes := [])):
+        falcon_h1.note_ssd(150, s)
+    assert notes == [xla]
+    with sequence.serving_attention([], interpret=True):
+        assert falcon_h1.ssd_choice(150, s) == dict(xla, path="pallas")
+    monkeypatch.setattr(sequence.jax, "default_backend", lambda: "tpu")
+    assert falcon_h1.ssd_choice(150, s) == xla
+    with sequence.serving_attention([], ssd=(notes := [])):
+        falcon_h1.note_ssd(150, s)
+        falcon_h1.note_ssd(150, s)
+        falcon_h1.note_ssd(40, s)
+        falcon_h1.note_ssd(150, s, last_only=True)  # the last layer's hand-overs stay XLA's scan
+    assert notes == [dict(xla, path="pallas"), dict(xla, path="pallas", chunk=40), xla]
+
+
+def test_metrics_block_counts_the_ssd_kernels_batches():
+    from distributed_tf_serving_tpu.serving.batcher import BatcherStats
+    from distributed_tf_serving_tpu.utils.metrics import ServerMetrics
+
+    stats = BatcherStats(batches=3, fused_batches=3, ssd_kernel_batches=2)
+    block = ServerMetrics().snapshot(batcher_stats=stats)["batcher"]
+    assert block["batches"] == 3 and block["ssd_kernel_batches"] == 2
 
 
 def test_predict_answers_a_row_of_tokens_and_nothing_else(served):

@@ -404,18 +404,23 @@ def test_falcon_h1s_steps_compile_at_the_published_cut(one_chip, no_compile_cach
     serves it (3.49 B parameters at five layers, rows of 2,048 tokens), both
     rungs of its ladder with their five counters: the attention's kernel a
     layer but the last at 20 query heads over 4 key-value heads (5 a group, a
-    grouping no other cell has), the SSD plain XLA whose only loop is the
-    state's hand-over (ONE `while` a layer, 16 steps of one multiply-add of
-    the state), and what the step holds beside the 6.98 GB of weights fits
-    the chip's 16 GB."""
+    grouping no other cell has), the SSD's chunk walk ONE Pallas kernel a
+    layer but the last too (PR 55; a `while` a layer before it, the state's
+    hand-over through HBM), whose VMEM is inside the 16 MiB a kernel has by
+    default and no more asked for; the one loop left is the last layer's
+    hand-overs, whose `y` is read at the last position alone; and what the
+    step holds beside the 6.98 GB of weights fits the chip's 16 GB."""
     compiled, accessed = sequence_cells_step("falcon_h1_34b_rerank", "falcon_h1", one_chip, rows)
     memory, text = compiled.memory_analysis(), compiled.as_text()
     layers = len(cells_model("falcon_h1_34b_rerank", "falcon_h1")[0].layer_plan)
     assert layers in (4, 5) and {5: 6.9e9, 4: 6.0e9}[layers] < memory.argument_size_in_bytes < {5: 7.1e9, 4: 6.2e9}[layers]
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14 * GIB
     assert memory.generated_code_size_in_bytes < 64 << 20
-    assert text.count('custom_call_target="tpu_custom_call"') == layers - 1 and "vmem_limit" not in text
-    assert len(re.findall(r"\) while\(", text)) == layers  # the hand-over scan, and no other loop
+    # the attention's kernel and the SSD's, a layer but the last
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 * (layers - 1) and "vmem_limit" not in text
+    vmem = kernels_vmem(text, "ssd_chunks")
+    assert len(vmem) == layers - 1 and all(0 < size < DEFAULT_VMEM * 3 // 4 for size in vmem)  # 8.1 MB
+    assert len(re.findall(r"\) while\(", text)) == 1  # the last layer's hand-over scan, and no other loop
     assert not SCORE_TILE.search(text)
 
 
@@ -480,6 +485,32 @@ def test_delta_kernel_compiles_at_the_cells_rungs(one_chip, no_compile_cache, ro
     text = compiled.as_text()
     assert 'custom_call_target="tpu_custom_call"' in text and "vmem_limit" not in text
     assert all(0 < size < DEFAULT_VMEM // 2 for size in kernels_vmem(text, "delta_rule"))  # 6.7 MB
+    assert compiled.memory_analysis().generated_code_size_in_bytes < 2 << 20  # one kernel a layer
+
+
+# ------------------------------------------- Mamba-2's SSD chunk walk (PR 55)
+#
+# Mosaic's verdict on the kernel alone at the cell's two rungs: a head's tile
+# a static 128-lane slice of the step's 1,024, B turned once a step and the
+# states turned in and out at a row's ends (`[128, 256]` float32), eight
+# states in scratch beside the pipeline's blocks.
+
+@pytest.mark.parametrize("rows", [4, 2])
+@pytest.mark.parametrize("count", [2, 1])
+def test_ssd_kernel_compiles_at_the_cells_rungs(one_chip, no_compile_cache, rows, count):
+    from distributed_tf_serving_tpu.ops.ssd_kernel import chunk_walk
+
+    heads, groups, length, width, wide, chunk = 32, 2, 2048, 128, 256, 128
+    shaped = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)  # noqa: E731
+    run = functools.partial(chunk_walk, heads=heads, groups=groups, cd=jnp.dtype(jnp.bfloat16), count=count)
+    compiled = jax.jit(run).lower(
+        shaped(rows, heads, length // chunk, chunk), shaped(rows, heads, length // chunk, chunk),
+        shaped(rows, length, heads * width), shaped(rows, length, groups * wide), shaped(rows, length, groups * wide),
+        shaped(rows, heads, width, wide)).compile()
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text and "vmem_limit" not in text
+    # 9.9 MiB alone, 8.1 MB in the step
+    assert all(0 < size < DEFAULT_VMEM * 3 // 4 for size in kernels_vmem(text, "ssd_chunks"))
     assert compiled.memory_analysis().generated_code_size_in_bytes < 2 << 20  # one kernel a layer
 
 
