@@ -3471,25 +3471,29 @@ class DynamicBatcher:
                             out_keys=wanted_key, topk=topk, n_valid=n_valid,
                             prune=prune,
                         )
+                # Phases by count, beside `batch.dispatch`'s: one take of
+                # the trace's lock a batch for all that apply.
+                counted = []
                 if servable in self._gather_kernel:
-                    # A phase by count, beside `batch.dispatch`'s.
                     self.stats.gather_kernel_batches += 1
-                    request_trace.add_many((("batch.gather_kernel", 0.0, 1),))
+                    counted.append(("batch.gather_kernel", 0.0, 1))
                 if servable in self._attention_kernel:
                     self.stats.attention_kernel_batches += 1
-                    request_trace.add_many((("batch.attention_kernel", 0.0, 1),))
+                    counted.append(("batch.attention_kernel", 0.0, 1))
                 if servable in self._grouped_kernel:
                     self.stats.grouped_kernel_batches += 1
-                    request_trace.add_many((("batch.grouped_kernel", 0.0, 1),))
+                    counted.append(("batch.grouped_kernel", 0.0, 1))
                 if servable in self._delta_kernel:
                     self.stats.delta_kernel_batches += 1
-                    request_trace.add_many((("batch.delta_kernel", 0.0, 1),))
+                    counted.append(("batch.delta_kernel", 0.0, 1))
                 if servable in self._ssd_kernel:
                     self.stats.ssd_kernel_batches += 1
-                    request_trace.add_many((("batch.ssd_kernel", 0.0, 1),))
+                    counted.append(("batch.ssd_kernel", 0.0, 1))
                 if group[0].direct:
                     self.stats.direct_batches += 1
-                    request_trace.add_many((("batch.direct", 0.0, 1),))
+                    counted.append(("batch.direct", 0.0, 1))
+                if counted:
+                    request_trace.add_many(counted)
             if run_fn_cap is not None and getattr(run_fn_cap, "elastic", False):
                 # Same thread, synchronous: the token names the split the
                 # dispatch above routed to. It travels to the completer

@@ -622,6 +622,9 @@ class RestGateway:
                 getattr(self.impl.batcher, "stats", None)
             ),
             "phases": request_trace.snapshot,
+            # Who is on the CPU, a row a thread and a row a role: one pass
+            # over /proc/self/task on this thread, per scrape.
+            "threads": tracing.thread_cpu.threads,
             "tracing": lambda: {
                 "enabled": tracing.enabled(),
                 "recorded": tracing.recorder().recorded,
@@ -675,7 +678,7 @@ class RestGateway:
         for name in ("cache", "row_cache", "overload", "utilization",
                      "quality", "lifecycle", "recovery", "kernels", "mesh",
                      "elastic", "fleet", "cascade", "integrity", "versions",
-                     "pipeline", "runtime"):
+                     "pipeline", "runtime", "threads"):
             if name == "mesh":
                 block = self.impl.mesh_stats(
                     utilization=snap.get("utilization")

@@ -210,18 +210,28 @@ class _RpcStamps:
     `done` records the four phases that, with the handler's time, tile the
     RPC as this process sees it: `rpc.server` = `rpc.pool_wait` +
     `rpc.request_wait` + handler + `rpc.reply`, exactly. Differences of
-    stamps of two threads: aggregate only, not on the profiler's clock."""
+    stamps of two threads: aggregate only, not on the profiler's clock.
 
-    __slots__ = ("t_submit", "t_taken", "t_handler", "t_return")
+    While a profiler capture is open (`tracing.capture_open`, asked once an
+    RPC at t_taken) the pool thread's CPU clock (time.thread_time) is read
+    beside t_taken and t_return, and `done` adds `cpu.rpc_handler`: what
+    one Predict cost its handler thread in CPU, of the wall time between
+    the same two stamps. `cpu_taken` is None outside a capture."""
+
+    __slots__ = ("t_submit", "t_taken", "t_handler", "t_return",
+                 "cpu_taken", "cpu_return")
 
     def done(self) -> None:
         t_done = time.perf_counter()
-        request_trace.add_many((
+        phases = (
             ("rpc.pool_wait", self.t_taken - self.t_submit, 1),
             ("rpc.request_wait", self.t_handler - self.t_taken, 1),
             ("rpc.reply", t_done - self.t_return, 1),
             ("rpc.server", t_done - self.t_submit, 1),
-        ))
+        )
+        if self.cpu_taken is not None:
+            phases += (("cpu.rpc_handler", self.cpu_return - self.cpu_taken, 1),)
+        request_trace.add_many(phases)
 
 
 class _TakenRpc(threading.local):
@@ -246,6 +256,7 @@ class _StampedPool(futures.ThreadPoolExecutor):
 
         def taken():
             stamps.t_taken = time.perf_counter()
+            stamps.cpu_taken = time.thread_time() if tracing.capture_open() else None
             _TAKEN.stamps = stamps
             try:
                 return fn(*args, **kwargs)
@@ -328,6 +339,8 @@ class _SyncServicerBase:
             t1 = self._observe(name, t0, ok, model)
             if stamps is not None:
                 stamps.t_handler, stamps.t_return = t0, t1
+                if stamps.cpu_taken is not None:
+                    stamps.cpu_return = time.thread_time()
                 # grpc runs the callback on the poller thread once the
                 # status has gone out. A call that has ended already (the
                 # client cancelled under the handler) takes none: it ends here.

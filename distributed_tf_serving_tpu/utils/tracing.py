@@ -49,6 +49,15 @@ thread, and `req.*`, `rpc.pool_wait`, `rpc.request_wait`, `rpc.reply`,
 `rpc.server` and `rpc.listener<i>` are differences of stamps, most taken on
 two threads, that reach the trace through `add_many`, which annotates
 nothing.
+
+Who is on the CPU (ISSUE 56): a wall span under ONE interpreter lock cannot
+say whether its thread computed, waited for the lock or was denied a core.
+`ThreadSampler` reads what the kernel keeps a thread (its CPU-time clock;
+where there is a `schedstat`, also the ns it was runnable and waiting for a
+core), on a scrape and at no other time, and publishes it by thread ROLE as
+the phases `cpu.*` and `sched.*` of `request_trace`; inside an open capture
+the spans that only compute (`_CPU_SPLIT`) also read their thread's CPU
+clock and add `offcpu.<phase>`, wall less CPU: lock wait plus preemption.
 """
 
 from __future__ import annotations
@@ -62,7 +71,7 @@ import random
 import threading
 import time
 import weakref
-from collections import defaultdict, deque
+from collections import Counter, defaultdict, deque
 
 # --------------------------------------------------------------------------
 # Aggregate phase timing (the original plane).
@@ -76,6 +85,19 @@ _ENABLED = False  # per-request tracing; flipped by enable()/disable()
 # cost dcn_v2_ref43-rank 1.8% of its p50 (PERF.md section 6, PR 24).
 _ANNOTATION = None
 _ON_PROFILER = ("wait.", "batch.", "readback.", "cache.", "rpc.")
+# The spans that open and close on one thread and wait for nothing by
+# design: inside a capture they also read that thread's CPU clock, and what
+# their wall time holds beyond it (the interpreter lock taken by another
+# thread, a core given to another process) is the phase `offcpu.<phase>`.
+# `predict.execute` is not one: its handler blocks on the batcher on purpose.
+_CPU_SPLIT = frozenset((
+    "predict.decode", "predict.encode", "batch.dispatch", "batch.cache",
+    "batch.jitcall", "batch.deliver",
+))
+# What a span asks the gate about: the two of `_CPU_SPLIT` that are not on
+# the profiler's clock beside those that are. Every other phase stays one
+# `startswith` short of it.
+_GATED = _ON_PROFILER + ("predict.decode", "predict.encode")
 
 
 def bind_annotation(annotation_cls) -> None:
@@ -85,6 +107,13 @@ def bind_annotation(annotation_cls) -> None:
     None unbinds."""
     global _ANNOTATION
     _ANNOTATION = annotation_cls
+
+
+def capture_open() -> bool:
+    """The gate itself: a bound annotation class says a capture is open.
+    For a stamp that rides no span (the transport's `_RpcStamps`)."""
+    cls = _ANNOTATION
+    return cls is not None and cls.is_enabled()
 
 
 def annotation(phase: str):
@@ -103,10 +132,10 @@ class _PhaseSpan:
 
     __slots__ = ("_trace", "_phase", "_annotation", "_t0")
 
-    def __init__(self, trace: "PhaseTrace", phase: str):
+    def __init__(self, trace: "PhaseTrace", phase: str, annotation):
         self._trace = trace
         self._phase = phase
-        self._annotation = annotation(phase)
+        self._annotation = annotation
 
     def __enter__(self):
         self._annotation.__enter__()
@@ -120,6 +149,30 @@ class _PhaseSpan:
         return False
 
 
+class _SplitPhaseSpan(_PhaseSpan):
+    """The span of a `_CPU_SPLIT` phase while a capture is open: the thread's
+    CPU clock beside each wall read, INSIDE the wall reads (so that a span
+    that only computes reads no negative rest), and `offcpu.<phase>` beside
+    the phase."""
+
+    __slots__ = ("_cpu0",)
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        self._cpu0 = time.thread_time()
+        return None
+
+    def __exit__(self, *exc):
+        on_cpu = time.thread_time() - self._cpu0
+        seconds = time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc)
+        # Not cut off at zero a span: where the CPU clock ticks coarsely
+        # (10 ms under gVisor) only the SUM of many spans means anything.
+        self._trace.add(self._phase, seconds, offcpu=seconds - on_cpu)
+        return False
+
+
 class PhaseTrace:
     """Accumulates wall time per named phase, aggregated across requests."""
 
@@ -127,19 +180,36 @@ class PhaseTrace:
         self._totals: dict[str, float] = defaultdict(float)
         self._counts: dict[str, int] = defaultdict(int)
         self._lock = threading.Lock()
+        self._sources: list = []
+
+    def add_source(self, source) -> None:
+        """Merge `source.phases()` (cumulative blocks a phase, as snapshot's
+        own) into every snapshot; `reset()` calls `source.rebase()`. For
+        counters read on a scrape and at no other time (`ThreadSampler`)."""
+        self._sources.append(source)
 
     def span(self, phase: str) -> _PhaseSpan:
-        return _PhaseSpan(self, phase)
+        cls = _ANNOTATION
+        if cls is None or not phase.startswith(_GATED) or not cls.is_enabled():
+            return _PhaseSpan(self, phase, _NOOP)
+        note = cls(phase) if phase.startswith(_ON_PROFILER) else _NOOP
+        kind = _SplitPhaseSpan if phase in _CPU_SPLIT else _PhaseSpan
+        return kind(self, phase, note)
 
-    def add(self, phase: str, seconds: float) -> None:
+    def add(self, phase: str, seconds: float, offcpu: float | None = None) -> None:
         """Record an externally timed duration under `phase`. For callers
         that already hold the wall time for their own accounting (the
         batcher's readback-overlap bookkeeping times the fetch once and
         feeds both this trace and the overlap counters) — a nested span
-        would pay a second pair of clock reads for the same interval."""
+        would pay a second pair of clock reads for the same interval.
+        `offcpu`, from a span that split itself, lands under
+        `offcpu.<phase>` in the same take of the lock."""
         with self._lock:
             self._totals[phase] += seconds
             self._counts[phase] += 1
+            if offcpu is not None:
+                self._totals["offcpu." + phase] += offcpu
+                self._counts["offcpu." + phase] += 1
         if _ENABLED:
             # Per-request plane: the same interval becomes a child span of
             # whatever request context is active on this thread — the
@@ -166,26 +236,245 @@ class PhaseTrace:
 
     def snapshot(self) -> dict[str, dict]:
         with self._lock:
-            return {
-                phase: {
-                    "total_ms": round(self._totals[phase] * 1e3, 3),
-                    "count": self._counts[phase],
-                    # A phase by count may stand at 0 (add_many).
-                    "mean_us": round(
-                        self._totals[phase] / max(self._counts[phase], 1) * 1e6, 1
-                    ),
-                }
-                for phase in sorted(self._totals)
+            out = {
+                phase: _phase_block(self._totals[phase], self._counts[phase])
+                for phase in self._totals
             }
+        for source in self._sources:
+            out.update(source.phases())
+        return dict(sorted(out.items()))
 
     def reset(self) -> None:
         with self._lock:
             self._totals.clear()
             self._counts.clear()
+        for source in self._sources:
+            source.rebase()
 
 
-# Process-wide default trace used by the serving path.
+def _phase_block(seconds: float, count: int) -> dict:
+    return {
+        "total_ms": round(seconds * 1e3, 3),
+        "count": count,
+        # A phase by count may stand at 0 (add_many).
+        "mean_us": round(seconds / max(count, 1) * 1e6, 1),
+    }
+
+
+# --------------------------------------------------------------------------
+# Who is on the CPU: the kernel's per-thread clocks, by the thread's role.
+
+# A Python thread's role, from the name its owner gave it: a listener's
+# `_serve` thread (grpc names none, so `threading` calls it
+# `Thread-N (_serve)`), `create_server`'s pool, the batcher's three kinds
+# and the REST gateway's loop, which is also where a scrape runs: its cost
+# shows there, outside the request path's roles.
+_ROLE_OF_PREFIX = (
+    ("rpc_", "handler"), ("batch-dispatch", "dispatch"),
+    ("batch-complete", "completer"),
+)
+_ROLE_OF_NAME = {"batcher": "collector", "rest": "rest"}
+_NATIVE_NAMES_KEPT = 8
+
+
+def thread_role(name: str) -> str:
+    """The role of a thread Python knows, from `Thread.name`."""
+    if name.endswith("(_serve)"):
+        return "poller"
+    role = _ROLE_OF_NAME.get(name)
+    if role is not None:
+        return role
+    for prefix, role in _ROLE_OF_PREFIX:
+        if name.startswith(prefix):
+            return role
+    return "python_other"
+
+
+class ThreadSampler:
+    """CPU time and run-queue wait of this process's threads, by role.
+
+    One pass over the threads a call of `phases()` or `threads()`, and
+    nothing between calls: no thread, no stamp on the request path. The
+    threads are `/proc/self/task`'s; a thread's CPU is its CPU-time clock,
+    which the kernel names by the thread's id (the id glibc's
+    `pthread_getcpuclockid` builds), read without a file and without giving
+    the interpreter lock away; where the kernel keeps `schedstat` (ns on a
+    core, ns runnable and waiting for one, timeslices: Linux with
+    SCHED_INFO; gVisor has none) that file is read instead and the wait is
+    kept too. A thread Python knows (`threading.enumerate()`'s `native_id`)
+    takes its role from its name; any other is `native.<comm, trailing
+    digits cut>`: the TPU runtime's and grpc core's own. A comm's place is
+    fixed when it is first seen (those first seen in one pass ranked by
+    CPU): one of `_NATIVE_NAMES_KEPT` names of its own, or `native.other`.
+    Each pass adds a thread's growth since the pass before to its role's
+    total, so a thread that exits leaves what it had and no total ever
+    falls. Where there is no `/proc/self/task` every reading is absent."""
+
+    def __init__(self, task_dir: str = "/proc/self/task"):
+        self._task_dir = task_dir
+        self._lock = threading.Lock()
+        self._t0 = time.perf_counter()
+        self._schedstat: bool | None = None  # asked of the kernel at the first pass
+        # tid -> (run ns, wait ns, role, comm) at the pass before
+        self._last: dict[int, tuple[int, int, str, str]] = {}
+        self._run_ns: dict[str, int] = defaultdict(int)  # by role, cumulative
+        self._wait_ns: dict[str, int] = defaultdict(int)
+        self._native_role: dict[str, str] = {}  # comm -> native.<name>
+        self._base: dict[str, float] = {}  # phase -> seconds at reset()
+        self._scraped: tuple | None = None  # threads()'s last (t, run, wait)
+        self._pass_s, self._passes = 0.0, 0  # what the passes themselves took
+
+    def _clocks(self, tid: int) -> tuple[int, int, int | None] | None:
+        """(ns on a core, ns waiting for one, timeslices) of one thread;
+        without `schedstat` the wait is 0 and the timeslices None. None for
+        a thread that ended since the listing."""
+        try:
+            if self._schedstat:
+                with open(f"{self._task_dir}/{tid}/schedstat") as f:
+                    run, wait, slices = (int(x) for x in f.read().split())
+                return run, wait, slices
+            # MAKE_THREAD_CPUCLOCK(tid, CPUCLOCK_SCHED) of the kernel's ABI.
+            return time.clock_gettime_ns((~tid << 3) | 6), 0, None
+        except (OSError, ValueError):
+            return None
+
+    def _comm(self, tid: int) -> str:
+        try:
+            with open(f"{self._task_dir}/{tid}/comm") as f:
+                return f.read().strip()
+        except OSError:
+            return ""
+
+    def _pass(self) -> list[dict] | None:
+        """Read every thread once and add its growth to its role; the live
+        threads' rows, or None where the kernel shows none. Lock held."""
+        t_pass = time.perf_counter()
+        try:
+            tids = [int(tid) for tid in os.listdir(self._task_dir)]
+        except OSError:
+            return None
+        if self._schedstat is None:
+            self._schedstat = bool(tids) and os.path.exists(
+                f"{self._task_dir}/{tids[0]}/schedstat")
+        names = {t.native_id: t.name for t in threading.enumerate()}
+        rows, grown, fresh = [], [], defaultdict(int)
+        for tid in tids:
+            clocks = self._clocks(tid)
+            if clocks is None:
+                continue
+            run, wait, slices = clocks
+            run0, wait0, role, comm = self._last.get(tid, (0, 0, None, None))
+            if run < run0:  # the kernel gave a dead thread's id to a new one
+                run0, wait0, role, comm = 0, 0, None, None
+            comm = comm or self._comm(tid)  # once a thread: a file, unlike the clock
+            name, group = names.get(tid), None
+            if name is not None:
+                role = thread_role(name)
+            elif role is None or role.startswith("native."):
+                # Not a Python thread on its way out (which keeps its role
+                # when `threading` has forgotten it): native, by its comm.
+                group = comm.rstrip("0123456789") or comm or "unnamed"
+                role = self._native_role.get(group)
+                if role is None:
+                    fresh[group] += run - run0
+            rows.append({
+                "role": role, "name": name, "comm": comm, "native_id": tid,
+                "cpu_s": run / 1e9,
+                "runq_wait_s": wait / 1e9 if self._schedstat else None,
+                "timeslices": slices,
+            })
+            grown.append((run, wait, run - run0, max(wait - wait0, 0), group))
+        # Native comms not seen before take their places, largest first.
+        for group in sorted(fresh, key=fresh.get, reverse=True):
+            kept = sum(r != "native.other" for r in self._native_role.values())
+            self._native_role[group] = (
+                f"native.{group}" if kept < _NATIVE_NAMES_KEPT else "native.other")
+        self._last = {}
+        for row, (run, wait, run_grew, wait_grew, group) in zip(rows, grown):
+            role = row["role"] = row["role"] or self._native_role[group]
+            self._run_ns[role] += run_grew
+            self._wait_ns[role] += wait_grew
+            self._last[row["native_id"]] = (run, wait, role, row["comm"])
+        self._pass_s += time.perf_counter() - t_pass
+        self._passes += 1
+        return rows
+
+    def _cumulative(self, rows: list[dict]) -> dict[str, tuple[float, int]]:
+        """{phase: (seconds, count)}: `cpu.<role>` and, where the kernel
+        keeps the wait, `sched.<role>`, with the role's live threads as the
+        count; `cpu.process`, `cpu.wall`, and `cpu.scrape`: the WALL time of
+        the passes so far, this one too, by their number (what a scrape
+        costs, on the thread that asked)."""
+        live = Counter(row["role"] for row in rows)
+        out = {}
+        for role, run_ns in self._run_ns.items():
+            out["cpu." + role] = (run_ns / 1e9, live[role])
+            if self._schedstat:
+                out["sched." + role] = (self._wait_ns[role] / 1e9, live[role])
+        out["cpu.process"] = (time.process_time(), len(rows))
+        out["cpu.wall"] = (time.perf_counter() - self._t0, 1)
+        out["cpu.scrape"] = (self._pass_s, self._passes)
+        return out
+
+    def phases(self) -> dict[str, dict]:
+        """The blocks `PhaseTrace.snapshot` merges: cumulative since the
+        process started (since `rebase()`, after one), never falling. A
+        window's delta of a `count` is 0: absent is told by the key."""
+        with self._lock:
+            rows = self._pass()
+            if rows is None:
+                return {}
+            return {
+                phase: _phase_block(seconds - self._base.get(phase, 0.0), count)
+                for phase, (seconds, count) in self._cumulative(rows).items()
+            }
+
+    def rebase(self) -> None:
+        """Count from now (`PhaseTrace.reset`)."""
+        with self._lock:
+            rows = self._pass()
+            self._base = {} if rows is None else {
+                phase: seconds for phase, (seconds, _n) in self._cumulative(rows).items()}
+
+    def threads(self) -> dict | None:
+        """`/monitoring?section=threads`: a row a live thread, and a row a
+        role with its share of ONE core and of the run queue since the last
+        call of this method (since the process started, at the first);
+        `runq_*` and `timeslices` are null where the kernel keeps none."""
+        with self._lock:
+            rows = self._pass()
+            if rows is None:
+                return None
+            now = time.perf_counter()
+            t0, run0, wait0 = self._scraped or (self._t0, {}, {})
+            self._scraped = (now, dict(self._run_ns), dict(self._wait_ns))
+            span_ns = max(now - t0, 1e-9) * 1e9
+            live = Counter(row["role"] for row in rows)
+
+            def pct(total, before, role):
+                return round(100.0 * (total[role] - before.get(role, 0)) / span_ns, 2)
+
+            return {
+                "interval_s": round(now - t0, 6),
+                "roles": [
+                    {
+                        "role": role, "threads": live[role], "cpu_s": run_ns / 1e9,
+                        "runq_wait_s": self._wait_ns[role] / 1e9 if self._schedstat else None,
+                        "cpu_pct_of_core": pct(self._run_ns, run0, role),
+                        "runq_pct_of_core": (
+                            pct(self._wait_ns, wait0, role) if self._schedstat else None),
+                    }
+                    for role, run_ns in sorted(self._run_ns.items())
+                ],
+                "threads": sorted(rows, key=lambda r: (r["role"], r["native_id"])),
+            }
+
+
+# Process-wide default trace used by the serving path, and the sampler whose
+# `cpu.*` and `sched.*` phases every snapshot of it holds.
 request_trace = PhaseTrace()
+thread_cpu = ThreadSampler()
+request_trace.add_source(thread_cpu)
 
 
 # --------------------------------------------------------------------------

@@ -377,6 +377,205 @@ def test_profiler_capture_holds_the_programs_spans(tmp_path):
     assert startup["params_init_s"] >= 0 and startup["warmup_s"] == impl.warmup_s
 
 
+class _Capture:
+    """Stands in for jax's TraceAnnotation, bound: `open` is what its
+    `is_enabled()` says, `asked` how often it was asked."""
+
+    open = False
+    asked = 0
+
+    def __init__(self, name):
+        pass
+
+    @classmethod
+    def is_enabled(cls):
+        cls.asked += 1
+        return cls.open
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+@pytest.fixture
+def capture():
+    _Capture.open, _Capture.asked = False, 0
+    tracing.bind_annotation(_Capture)
+    yield _Capture
+    _Capture.open = False
+
+
+SPLIT = ("predict.decode", "predict.encode", "batch.dispatch", "batch.cache",
+         "batch.jitcall", "batch.deliver")
+
+
+@pytest.mark.parametrize("phase", SPLIT)
+def test_offcpu_rides_the_span_only_inside_a_capture(capture, phase):
+    """wall less the thread's CPU, one entry a span so split, never above
+    the span's own wall time; nothing of it with the gate closed."""
+    trace = tracing.PhaseTrace()
+    for _ in range(3):
+        with trace.span(phase):
+            pass
+    assert set(trace.snapshot()) == {phase}
+    capture.open = True
+    for _ in range(4):
+        with trace.span(phase):
+            time.sleep(0.002)  # off the core: all of it is `offcpu`
+    with trace.span(phase):
+        t0 = time.thread_time()
+        while time.thread_time() - t0 < 0.002:  # on it: none of it is
+            pass
+    capture.open = False
+    with trace.span(phase):
+        pass
+    snap = trace.snapshot()
+    assert snap[phase]["count"] == 9 and snap["offcpu." + phase]["count"] == 5
+    assert 4 * 2.0 <= snap["offcpu." + phase]["total_ms"] <= snap[phase]["total_ms"]
+    assert snap[phase]["total_ms"] - snap["offcpu." + phase]["total_ms"] >= 1.9
+
+
+@pytest.mark.parametrize("phase", ["predict.execute", "wait.queue_empty", "batch.pad", "cascade.stage1"])
+def test_a_span_that_waits_or_nests_is_never_split(capture, phase):
+    capture.open = True
+    trace = tracing.PhaseTrace()
+    with trace.span(phase):
+        pass
+    assert set(trace.snapshot()) == {phase}
+
+
+class _Clocks:
+    """`time`, with the reads of each clock counted."""
+
+    def __init__(self):
+        self.reads = {"perf_counter": 0, "thread_time": 0}
+
+    def __getattr__(self, name):
+        if name in self.reads:
+            self.reads[name] += 1
+        return getattr(time, name)
+
+
+@pytest.mark.parametrize("bound", [False, True])
+@pytest.mark.parametrize("phase", ["predict.decode", "batch.dispatch", "predict.execute"])
+def test_a_span_outside_a_capture_reads_two_clocks_and_asks_the_gate_once(
+        capture, monkeypatch, phase, bound):
+    if not bound:
+        tracing.bind_annotation(None)
+    clocks = _Clocks()
+    monkeypatch.setattr(tracing, "time", clocks)
+    trace = tracing.PhaseTrace()
+    with trace.span(phase):
+        pass
+    assert clocks.reads == {"perf_counter": 2, "thread_time": 0}
+    assert capture.asked == (1 if bound and phase != "predict.execute" else 0)
+    capture.open = True
+    with trace.span(phase):
+        pass
+    split = bound and phase != "predict.execute"
+    assert clocks.reads == {"perf_counter": 4, "thread_time": 2 if split else 0}
+
+
+def _grpc_predict(port, n=1):
+    import grpc
+
+    from distributed_tf_serving_tpu.client import build_predict_request
+    from distributed_tf_serving_tpu.proto import PredictionServiceStub
+
+    with grpc.insecure_channel(f"127.0.0.1:{port}") as channel:
+        for i in range(n):
+            PredictionServiceStub(channel).Predict(
+                build_predict_request(_payload(seed=i), "DCN"), timeout=60)
+
+
+def test_handler_cpu_is_stamped_only_inside_a_capture(servable, capture):
+    """`cpu.rpc_handler`: the pool thread's CPU from `t_taken` to `t_return`,
+    one entry an RPC stamped while the gate was open, asked once an RPC; it
+    cannot pass the wall time between the same two stamps."""
+    from distributed_tf_serving_tpu.serving.server import create_server
+
+    impl, batcher = _impl(servable, max_wait_us=0)
+    impl.warmup_complete = True
+    server, port = create_server(impl, "127.0.0.1:0")
+    server.start()
+
+    def settled(want):
+        deadline = time.monotonic() + 10  # `done` runs after the client has its answer
+        while request_trace.snapshot().get("rpc.server", {}).get("count", 0) < want and \
+                time.monotonic() < deadline:
+            time.sleep(0.005)
+        return request_trace.snapshot()
+
+    try:
+        _grpc_predict(port)  # compiles
+        base = settled(1)
+        _grpc_predict(port, 3)
+        closed = settled(base["rpc.server"]["count"] + 3)
+        capture.open = True
+        _grpc_predict(port, 5)
+        opened = settled(closed["rpc.server"]["count"] + 5)
+        capture.open = False
+    finally:
+        server.stop(0).wait()
+        batcher.stop()
+
+    def rose(after, before, phase, field):
+        return after.get(phase, {}).get(field, 0) - before.get(phase, {}).get(field, 0)
+
+    assert rose(closed, base, "cpu.rpc_handler", "count") == 0
+    assert not any(rose(closed, base, "offcpu." + p, "count") for p in SPLIT)
+    assert rose(opened, closed, "cpu.rpc_handler", "count") == 5
+    # The same counts as their spans: one decode and one encode a request,
+    # one dispatch and one delivery a batch.
+    for phase in ("predict.decode", "predict.encode", "batch.dispatch", "batch.deliver"):
+        assert rose(opened, closed, "offcpu." + phase, "count") == \
+            rose(opened, closed, phase, "count") >= 5, phase
+        assert rose(opened, closed, "offcpu." + phase, "total_ms") <= \
+            rose(opened, closed, phase, "total_ms") + 1e-3
+    cpu_ms = rose(opened, closed, "cpu.rpc_handler", "total_ms")
+    wall_ms = rose(opened, closed, "rpc.request_wait", "total_ms") + sum(
+        rose(opened, closed, name, "total_ms") for name in opened if name.startswith("rpc.listener"))
+    assert 0.0 < cpu_ms <= wall_ms + 1e-2
+
+
+def test_the_kernels_counts_of_a_batch_are_one_add_many(servable, monkeypatch):
+    """ISSUE 56: a batch's phases by count (`batch.<x>_kernel`, `batch.direct`)
+    reach the trace in ONE `add_many`, every name and counter as before."""
+    impl, batcher = _impl(servable, max_wait_us=0)
+    calls = []
+    real = request_trace.add_many
+
+    def add_many(entries):
+        entries = tuple(entries)
+        calls.append([name for name, _s, _c in entries])
+        real(entries)
+
+    try:
+        impl._run(servable, _payload(seed=0))  # compiles
+        for kernel in ("_gather_kernel", "_attention_kernel", "_grouped_kernel",
+                       "_delta_kernel", "_ssd_kernel"):
+            getattr(batcher, kernel).add(servable)
+        before = request_trace.snapshot()
+        stats0 = {k: getattr(batcher.stats, k) for k in (
+            "gather_kernel_batches", "attention_kernel_batches", "grouped_kernel_batches",
+            "delta_kernel_batches", "ssd_kernel_batches", "direct_batches")}
+        monkeypatch.setattr(request_trace, "add_many", add_many)
+        with batcher._cv:  # a trickle, so the batch crosses direct
+            batcher._arrival_gap_s, batcher._traversal_s = 1.0, 0.001
+            batcher._last_arrival_t = None
+        impl._run(servable, _payload(seed=1))
+    finally:
+        monkeypatch.undo()
+        batcher.stop()
+    counted = ["batch.gather_kernel", "batch.attention_kernel", "batch.grouped_kernel",
+               "batch.delta_kernel", "batch.ssd_kernel", "batch.direct"]
+    assert [c for c in calls if any(n in counted for n in c)] == [counted]
+    assert [_delta(before, p, "count") for p in counted] == [1] * 6
+    assert all(getattr(batcher.stats, k) - v == 1 for k, v in stats0.items())
+
+
 def _free_port():
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
