@@ -38,7 +38,7 @@ norms, the softmax, the convolution and the scan's state. A float32
 activation enters a product as OPERAND_PIECES arrays of the compute dtype:
 its rounding to bfloat16 and the rounding of what that left (16 bits of
 mantissa: two passes of the MXU against a bfloat16 weight, three where both
-operands are activations; `sequence.product` makes those three one product).
+operands are activations; `sequence.product` makes either ONE product).
 Rounded to one bfloat16 piece the activations alone put 0.02 rms on a logit of
 standard deviation 1 at the published widths, half of what computing wholly
 in bfloat16 costs, and no comparison of a few scores could tell the stated
@@ -203,8 +203,8 @@ def _product(spec: str, x: jax.Array, y: jax.Array, cd) -> jax.Array:
 
 
 def _dot(x: jax.Array, w: jax.Array, cd) -> jax.Array:
-    """x @ w, as `_product` (at this family's two pieces a product a piece:
-    `sequence.product` says why)."""
+    """x @ w, as `_product` (at this family's two pieces ONE product, the
+    pieces along a second contracted axis: `sequence.product` has the form)."""
     return _product("...k,kn->...n", x, w, cd)
 
 

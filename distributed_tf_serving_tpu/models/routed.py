@@ -1,6 +1,7 @@
 """What the routed sequence-ranker families (pangu_moe, exaone_moe, mimo_v2) share
 beside `sequence`'s products and blocks: the product with a weight under its
-own name (`dot`: `sequence.product`'s stacked form), the RMSNorm, the gated
+own name (`dot`: `sequence.product` against a weight, the three families'
+three pieces its stacked form), the RMSNorm, the gated
 MLP, the rotary turn, the sigmoid router and the held experts' grouped
 product with its counters. One implementation, so that a change to any of
 them is measured on all three families' cells, whose hidden sizes (7680, 6144,
@@ -71,10 +72,12 @@ def gated_init(rng, shape_in: tuple, shape_out: tuple, dtype) -> dict:
 
 def dot(x: jax.Array, w: jax.Array, cd, count: int) -> jax.Array:
     """`x [..., k]` times the weight `w [k, n]`, float32: `sequence.product`
-    against the weight rounded to the compute dtype whole, which stacks the
-    pieces of `x` into ONE product, so that the weight is read once a product
-    and the executable holds one product where it held one a piece (a third
-    of its code: the ladder's executables have to fit the compile cache)."""
+    against the weight rounded to the compute dtype whole, which makes the
+    pieces of `x` meet in ONE product (two along a second contracted axis,
+    three or more stacked and summed), so that the float32 result is written
+    once, the weight is read once a product and the executable holds one
+    product where it held one a piece (a third of its code: the ladder's
+    executables have to fit the compile cache)."""
     return sequence.product("...k,kn->...n", x, w.astype(cd), cd, count)
 
 

@@ -1,7 +1,9 @@
 """What the sequence-ranker families (phi4flash, pangu_moe, exaone_moe,
 olmo_hybrid, mimo_v2, falcon_h1) share: products whose float32 activations enter as pieces of the
 compute dtype (`product`: one product a call wherever a form exists that
-copies no large array), the causal softmax of a block of queries, the blocks
+copies no large array; against a weight always, two pieces along a second
+contracted axis and three or more stacked, noted for the `startup.products`
+stamp), the causal softmax of a block of queries, the blocks
 themselves, causal attention in those blocks (`blocked_attention`:
 exaone_moe's and olmo_hybrid's full layers, falcon_h1's, both kinds of
 mimo_v2's, whose window layers' softmax holds a learned sink), the attention
@@ -20,6 +22,7 @@ either by name to plant the precision below the stated one).
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
 import string
@@ -77,6 +80,24 @@ def contraction_axes(spec: str) -> tuple[int, int] | None:
     return axis(first), axis(second)
 
 
+# The forms a product of an activation in pieces against a weight takes, as
+# `startup.products` names them; in both the pieces meet in ONE product's own
+# accumulation.
+FUSED_FORMS = ("stacked", "contracted")
+
+
+def product_summary(notes: list) -> dict:
+    """`{"ops", "fused_ops", "forms"}` of the products `product` noted as
+    `(M, k, n, pieces, form)`: their operations (2 M k n a piece), those of
+    the products whose pieces meet in one product (FUSED_FORMS), and the
+    calls by form. A servable's `startup.products` stamp."""
+    ops = [(2 * m * k * n * count, form) for m, k, n, count, form in notes]
+    return {
+        "ops": sum(o for o, _ in ops), "fused_ops": sum(o for o, form in ops if form in FUSED_FORMS),
+        "forms": dict(sorted(collections.Counter(form for _, form in ops).items())),
+    }
+
+
 def product(spec: str, x: jax.Array, y: jax.Array, cd, count: int = OPERAND_PIECES) -> jax.Array:
     """einsum(spec, x, y) with operands in the compute dtype and a float32
     result: one pass of the MXU a pair of pieces, but for the pairs whose
@@ -85,12 +106,27 @@ def product(spec: str, x: jax.Array, y: jax.Array, cd, count: int = OPERAND_PIEC
     large array (a product a pair writes its float32 result to memory and the
     next reads it back to add its own). The form follows the operands:
 
-    - the second whole in the compute dtype (a weight) and the first in
-      three pieces or more: the pieces stacked on a new leading axis and
+    - the second whole in the compute dtype (a weight): ONE `dot_general`
+      whatever the shapes. Exactly two pieces meet along a second contracted
+      axis, the weight repeated over it by a `broadcast_to` (`contracted`:
+      what the next case does for `q k'`, with nothing concatenated): the
+      float32 result is written once, where a product a piece wrote it, read
+      it back and wrote it again. The v5e's compiler keeps that broadcast
+      inside the product for an MLP's and a mixer's weights, and COPIES the
+      repeated weight (`bf16[2, k, n]`, a temporary of the step) for the
+      attention's q, k and v at all positions, whose products it turns round
+      to write head-major for the attention kernel: ten, two and four such
+      arrays in the three cells' steps, 2-3 ms of the 58.6 / 9.0 / 3.9 the
+      form gains (PERF.md section 7, PR 57 (b);
+      tests/test_tpu_compile.py::assert_no_large_weight_is_copied counts
+      them). Three pieces or more are stacked on a new leading axis and
       summed over it, which the compiler fuses into one product that reads
-      the weight once. Two pieces stay a product a piece: the compiler folds
-      the second product's add into its output already, and stacked they cost
-      a copy (2.4% of `phi4flash`'s step on the v5e: PERF.md section 6, PR 44);
+      the weight once (`stacked`: at two pieces it loses to a product a piece
+      in two cells of three). Read on the v5e at every weight product of the
+      three two-piece cells' top-bucket steps, 2,560 to 21,504 deep, 4 rows x
+      1 position to 8,192 positions (PERF.md section 6, PR 57): the
+      contracted form is the fastest step of the three in each. Noted for the
+      served entry being traced (`serving_attention(products=)`);
     - both in pieces and the result no smaller than either (`q k'`, whose
       score tile is what the memory carries): the pairs side by side along
       the contracted axis of both, ONE product. An operand is copied once a
@@ -111,10 +147,18 @@ def product(spec: str, x: jax.Array, y: jax.Array, cd, count: int = OPERAND_PIEC
     first, second, out = spec_terms(spec)
     if len(pairs) == 1:
         return einsum(spec, xs[0], ys[0])
-    if len(ys) == 1 and len(xs) > 2:
-        extra = next(c for c in string.ascii_uppercase if c not in spec)
-        return jnp.sum(einsum(f"{extra}{first},{second}->{extra}{out}", jnp.stack(xs), ys[0]), axis=0)
     axes = contraction_axes(spec)
+    if len(ys) == 1:
+        form = "contracted" if len(xs) == 2 else "stacked"
+        served = served_entry()
+        if served is not None and served.products is not None and axes is not None:
+            k = x.shape[axes[0]]
+            served.products.append((x.size // k, k, y.size // k, len(xs), form))
+        extra = next(c for c in string.ascii_uppercase if c not in spec)
+        if form == "stacked":
+            return jnp.sum(einsum(f"{extra}{first},{second}->{extra}{out}", jnp.stack(xs), ys[0]), axis=0)
+        return einsum(f"{extra}{first},{extra}{second}->{out}", jnp.stack(xs),
+                      jnp.broadcast_to(ys[0], (len(xs),) + ys[0].shape))
     if min(len(xs), len(ys)) > 1 and axes is not None:
         result = jax.eval_shape(functools.partial(jnp.einsum, spec), x, y)
         if result.size >= max(x.size, y.size):
@@ -171,13 +215,14 @@ def query_blocks(queries: int, keys: int, window: int | None = None, block: int 
 
 class Served(NamedTuple):
     """What `serving_attention` was entered with: the notes lists of the
-    attention, the routed layers, the delta rule and the SSD, and whether the
-    kernels run interpreted."""
+    attention, the routed layers, the delta rule, the SSD and the weight
+    products, and whether the kernels run interpreted."""
     notes: list
     interpret: bool
     grouped: list | None
     delta: list | None
     ssd: list | None
+    products: list | None
 
 
 _served = threading.local()  # .entry: a Served while serving_attention is entered
@@ -185,7 +230,7 @@ _served = threading.local()  # .entry: a Served while serving_attention is enter
 
 @contextlib.contextmanager
 def serving_attention(notes: list, interpret: bool = False, grouped: list | None = None,
-                      delta: list | None = None, ssd: list | None = None):
+                      delta: list | None = None, ssd: list | None = None, products: list | None = None):
     """While the batcher traces a one-chip served entry in this thread
     (serving/batcher.py _build_entry, and nowhere else): an attention at all
     positions may take the Pallas kernel (ops/attention_kernel.py), and
@@ -199,7 +244,11 @@ def serving_attention(notes: list, interpret: bool = False, grouped: list | None
     dict to `delta`: the `startup.delta_rule` stamp); and a Mamba-2 mixer's
     SSD at all positions may take its own (ops/ssd_kernel.py, chosen by
     `falcon_h1.takes_kernel`; `falcon_h1.note_ssd` appends
-    `falcon_h1.ssd_choice`'s dict to `ssd`: the `startup.ssd` stamp).
+    `falcon_h1.ssd_choice`'s dict to `ssd`: the `startup.ssd` stamp); and
+    `product` appends to `products`, where one is given, every product of an
+    activation in pieces against a weight that it traces, as `(M, k, n,
+    pieces, form)` (the `startup.products` stamp: the form is chosen the same
+    inside and outside).
     `interpret` is for tests on the CPU: choose as on a TPU and run the
     kernels interpreted.
 
@@ -209,7 +258,7 @@ def serving_attention(notes: list, interpret: bool = False, grouped: list | None
     trainer trace `model.apply` themselves, and a `tpu_custom_call` neither
     partitions nor has a gradient rule."""
     before = getattr(_served, "entry", None)
-    _served.entry = Served(notes, interpret, grouped, delta, ssd)
+    _served.entry = Served(notes, interpret, grouped, delta, ssd, products)
     try:
         yield notes
     finally:

@@ -72,7 +72,7 @@ from ..cache import CoalescedLeaderCancelled, collapse_rows
 from ..cache.digest import canonical_rows
 from ..models.base import Model, step_jit
 from ..models.embeddings import serving_gathers
-from ..models.sequence import serving_attention
+from ..models.sequence import product_summary, serving_attention
 from ..models.registry import Servable
 from ..ops.transfer import (
     cascade_prune_device,
@@ -1130,6 +1130,13 @@ class DynamicBatcher:
             weakref.WeakKeyDictionary()
         )
         self._ssd_kernel: weakref.WeakSet[Servable] = weakref.WeakSet()
+        # And every product of an activation in pieces against a weight that
+        # its entry was traced with, `(M, k, n, pieces, form)`
+        # (models/sequence.py product): `startup.products`. Read at a
+        # scrape, never by a batch.
+        self._products: weakref.WeakKeyDictionary[Servable, list] = (
+            weakref.WeakKeyDictionary()
+        )
         # _jit_for is reached from the batcher thread (fused-path
         # eligibility) AND the dispatch thread; one lock keeps the entry
         # build single-shot.
@@ -1854,6 +1861,17 @@ class DynamicBatcher:
                 for sv, notes in self._ssds.items() if notes
             }
 
+    def products(self) -> dict[str, dict]:
+        """"name:version" -> the products of an activation in pieces against
+        a weight in that servable's entries as traced, every rung and variant
+        added up: `{"ops", "fused_ops", "forms"}`, the operations (2 M k n a
+        piece), those of them whose pieces meet in ONE product's accumulation
+        (`sequence.FUSED_FORMS`), and the calls by form. For every servable
+        whose step makes one (the sequence families); a custom run_fn traces
+        its own entries, outside serving_attention: no stamp."""
+        with self._jit_lock:
+            return {f"{sv.name}:{sv.version}": product_summary(notes) for sv, notes in self._products.items() if notes}
+
     def pipeline_stats(self) -> dict:
         """Continuous-batching pipeline snapshot (ISSUE 9): configured
         depth/window, live in-flight occupancy (total and per bucket),
@@ -2147,11 +2165,12 @@ class DynamicBatcher:
         self._delta_kernel.discard(servable)
         ssds = self._ssds[servable] = []
         self._ssd_kernel.discard(servable)
+        products = self._products[servable] = []
 
         def noting(ap):
             def traced(p, batch):
                 with serving_gathers(gathers), serving_attention(
-                        attentions, grouped=groupeds, delta=deltas, ssd=ssds):
+                        attentions, grouped=groupeds, delta=deltas, ssd=ssds, products=products):
                     out = ap(p, batch)
                 if any(note["kernel"] == "pallas" for note in gathers):
                     self._gather_kernel.add(servable)

@@ -210,7 +210,7 @@ class PredictionServiceImpl:
         servable's embedding rows a candidate row, `lookups_per_row`, the
         `bags` they pool to, a sequence family's `layer_plan`, a routed family's `expert_plan`, the `attention_plan` of one whose attention differs by layer, its `params_bytes`, the `upload_format` of its batches and the
         `assembler` that builds them: "native" or "generic: <why>", and the `gather` of its
-        embedding rows as traced: the Pallas kernel or XLA's, `models/embeddings.py`), the pack factor of
+        embedding rows as traced: the Pallas kernel or XLA's, `models/embeddings.py`, and the `products` of a sequence family's entries: the operations in pieces against a weight and those whose pieces meet in one product, `models/sequence.py`), the pack factor of
         each loaded servable's embedding table (`embedding_pack`), persistent-cache
         traffic and whether the native host ops are loaded: the `runtime`
         block in /monitoring. jax falls back
@@ -228,6 +228,7 @@ class PredictionServiceImpl:
         groupeds = getattr(self.batcher, "groupeds", None)
         delta_rules = getattr(self.batcher, "delta_rules", None)
         ssds = getattr(self.batcher, "ssds", None)
+        products = getattr(self.batcher, "products", None)
         block["startup"] = {
             **self.startup,
             "warmup_s": self.warmup_s,
@@ -244,6 +245,7 @@ class PredictionServiceImpl:
             "grouped": groupeds() if callable(groupeds) else {},
             "delta_rule": delta_rules() if callable(delta_rules) else {},
             "ssd": ssds() if callable(ssds) else {},
+            "products": products() if callable(products) else {},
         }
         block["embedding_pack"] = self.registry.per_servable("embedding_pack")
         block["compile_cache"] = (

@@ -244,7 +244,13 @@ def test_a_served_entry_on_a_tpu_takes_the_kernel(kind, monkeypatch):
 
     got = jax.jit(served)(params, batch)
     logits = lambda out: (out[0] if model.step_stats else out)["logits"]  # noqa: E731
-    np.testing.assert_allclose(logits(got), logits(want), atol=2e-5)
+    # olmo_hybrid's small model turns the ORDER of float32 sums alone into 3e-5 on a logit, and 2e-5 held at this seed
+    # by the draw: over sixteen seeds of weights and ids the two paths stand a median 3.0e-5 apart (6.7e-5 at the most,
+    # eleven seeds over 2e-5) at PR 56's tree and 3.2e-5 (6.8e-5, twelve) at PR 57's, which reorders the sums of every
+    # weight product on both paths; this seed read 1.6e-5 before and reads 2.7e-5. Each path's distance from the same
+    # step with its products in float64 did not move (medians 9.0e-5 -> 1.0e-4 XLA's, 8.6e-5 -> 6.9e-5 the kernels':
+    # the two pieces' rounding). PERF.md section 6, PR 57 has the readings; the other families read 6e-6 at the most.
+    np.testing.assert_allclose(logits(got), logits(want), atol=5e-5 if kind == "olmo_hybrid" else 2e-5)
     if "attn.scores_computed" in model.step_stats:
         at = model.step_stats.index("attn.scores_computed")
         assert int(got[1][at]) > int(want[1][at]) and int(got[1][at + 1]) == int(want[1][at + 1])

@@ -666,18 +666,21 @@ def test_toml_reads_the_published_keys(tmp_path):
 # PR 52 at PR 52's tree: its counters leave with the logits through one
 # `optimization_barrier` on either path, before which the XLA path lowered
 # to PR 49's text still, and inside the entry the rule's chunk pass is the
-# delta kernel, interpreted.)
+# delta kernel, interpreted. `phi4flash_small` and `olmo_hybrid_small` since
+# PR 57 at PR 57's tree: their two pieces meet a weight in ONE product, along
+# a second contracted axis (`sequence.product`), on either path and at every
+# rung; the three-piece families' ten digests passed that change untouched.)
 PARENTS_TEXT = {
-    "phi4flash_small/2/xla": "416e6232410eada1", "phi4flash_small/2/kernel": "93cf2c875849e436",
-    "phi4flash_small/4/xla": "1de2619cf59e44ed", "phi4flash_small/4/kernel": "cc505c2bf7f42d6b",
-    "phi4flash_small/8/xla": "a11f29bd5e3f242c", "phi4flash_small/8/kernel": "8a0189f4ca09df28",
+    "phi4flash_small/2/xla": "4301e004f3aa5b02", "phi4flash_small/2/kernel": "f1d7617a55d2f678",
+    "phi4flash_small/4/xla": "9c36b633ec949e4c", "phi4flash_small/4/kernel": "567ce8c611a949ed",
+    "phi4flash_small/8/xla": "f1b0268aae7189c9", "phi4flash_small/8/kernel": "6b8f1bda33313ff8",
     "pangu_moe_small/2/xla": "37e0da30df3df885", "pangu_moe_small/2/kernel": "8e91f3619862ddb1",
     "pangu_moe_small/4/xla": "9a7ed5847f1a38b1", "pangu_moe_small/4/kernel": "490f131f5930da45",
     "pangu_moe_small/8/xla": "44d4aca134f03335", "pangu_moe_small/8/kernel": "ef3eed34ccb4da88",
     "exaone_moe_small/2/xla": "a766e567a1c79cc9", "exaone_moe_small/2/kernel": "8e525b84dd5524e8",
     "exaone_moe_small/4/xla": "80bcc80aef27e6c8", "exaone_moe_small/4/kernel": "dd88cc09cfcbcd97",
-    "olmo_hybrid_small/2/xla": "6eb4800667d1a6fc", "olmo_hybrid_small/2/kernel": "4f42c4d2ad0245d0",
-    "olmo_hybrid_small/4/xla": "d0e6cd9b96d5c3d5", "olmo_hybrid_small/4/kernel": "ec7128e667ef1487",
+    "olmo_hybrid_small/2/xla": "ecdd9541dea18350", "olmo_hybrid_small/2/kernel": "bdf706717c727039",
+    "olmo_hybrid_small/4/xla": "503592a291cb1507", "olmo_hybrid_small/4/kernel": "e05f07731113e13b",
 }
 
 

@@ -652,6 +652,8 @@ def test_cli_server_reports_startup_stamps_and_raw_counters(tmp_path):
         assert startup.pop("delta_rule") == {}
         # Nor an SSD stamp (PR 54): no layer of it holds a Mamba-2 mixer.
         assert startup.pop("ssd") == {}
+        # Nor a products stamp (PR 57): its step makes no product of an activation in pieces.
+        assert startup.pop("products") == {}
         # And how many gRPC listeners share its port, from how many cores (PR 34).
         from distributed_tf_serving_tpu.serving.server import listener_count
 

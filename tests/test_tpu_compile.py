@@ -197,9 +197,39 @@ def test_step_through_the_gather_kernel_has_no_xla_gather(one_chip, model, no_co
 # counts its operands and results alone (its `cost_estimate`), and the steps
 # read 100.4 / 94.9 / 86.1 GB and olmo_hybrid's 160.7 where it read 169.2, and no
 # score tile is a fusion's result any more (six and twelve were, in the two
-# routed steps: SCORE_TILE).
+# routed steps: SCORE_TILE). Since PR 57 two pieces meet a weight in ONE
+# product (`sequence.product`'s contracted form), whose float32 result is
+# written once: the three two-piece steps read 74.9 / 105.5 / 61.7 GB where
+# they read 100.4 / 143.1 / 87.4 (`phi4flash`, `olmo_hybrid`, `falcon_h1`).
 
 SCORE_TILE = re.compile(r"f32\[[\d,]*,512,(?:1024|2048)\]\S* fusion\(")
+REPEATED_WEIGHT = re.compile(r"^\s*%\S+ = bf16\[2,(\d+),(\d+)\]\S* (?!bitcast\()(\w[\w\-]*)\(.*?op_name=\"([^\"]*)\"", re.M)
+
+
+def repeated_weights(text: str, name: str, kind: str) -> list[tuple[int, str]]:
+    """(bytes, the scope that made it) of every array the ENTRY computation
+    holds of one of the configuration's weights `[k, n]` repeated over the second
+    contracted axis of `sequence.product`'s contracted form, `bf16[2, k, n]`:
+    the broadcast where the compiler did not keep it inside the product."""
+    shapes = jax.eval_shape(cells_model(name, kind)[0].init, jax.random.PRNGKey(0))
+    weights = {leaf.shape for leaf in jax.tree.leaves(shapes) if len(leaf.shape) == 2}
+    entry = text[text.index("\nENTRY "):]
+    return [(2 * int(k) * int(n) * 2, scope) for k, n, _op, scope in REPEATED_WEIGHT.findall(entry[:entry.index("\n}")])
+            if (int(k), int(n)) in weights]
+
+
+def assert_no_large_weight_is_copied(text: str, name: str, kind: str, count: int) -> None:
+    """No weight of the MLP or of a mixer's projections (42 to 220 MB) stands
+    repeated among the entry's instructions: there the broadcast stays inside
+    the product. The ones that do are the attention's q, k and v at all
+    positions (26 to 29 MB a weight, 52 to 59 repeated, and 5 MB ones), whose
+    products the compiler turns round to write their result head-major for
+    the attention kernel, the weight as the streamed operand: exactly `count`
+    of them as read at PR 57's tree, so that one more is seen (and one fewer:
+    lower the count), temporaries of the step and no more. ISSUE 57 asked for
+    none; PERF.md section 7, PR 57 (b) has what they cost and the repair."""
+    repeated = repeated_weights(text, name, kind)
+    assert len(repeated) == count and all(size <= 64 << 20 and "attn" in scope for size, scope in repeated), repeated
 
 
 @pytest.fixture()
@@ -279,8 +309,10 @@ def test_phi4flashs_eight_row_step_writes_a_score_tile_once(one_chip, no_compile
     memory = compiled.memory_analysis()
     assert 4.3e9 < memory.argument_size_in_bytes < 4.5e9
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12 * GIB
-    assert memory.generated_code_size_in_bytes < 122 << 20  # 106.9 MB; 116.2 on the XLA path
-    assert accessed < 107e9  # 100.4 GB; 115.9 on the XLA path
+    # 74.8 MB (106.9 while two pieces met a weight in a product a piece: PR 48 to PR 56; 116.2 on the XLA path)
+    assert memory.generated_code_size_in_bytes < 86 << 20
+    assert accessed < 80e9  # 74.9 GB (100.4 with a product a piece; 115.9 on the XLA path)
+    assert_no_large_weight_is_copied(compiled.as_text(), "phi4_mini_flash_rerank", "phi4flash", count=4)  # the full and the three cross layers' q
 
 
 def test_pangu_moes_eight_row_step_writes_a_score_tile_once(one_chip, no_compile_cache, served_on_a_tpu):
@@ -322,9 +354,11 @@ def test_olmo_hybrids_steps_compile_at_the_published_cut(one_chip, no_compile_ca
     memory, text = compiled.memory_analysis(), compiled.as_text()
     assert 4.0e9 < memory.argument_size_in_bytes < 4.2e9
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12 * GIB
-    assert memory.generated_code_size_in_bytes < 64 << 20  # 36.1 MB at 4 rows; 39.1 with the chunk loops
-    # 143.1 GB at 4 rows (160.7 with the chunk loops, 169.2 with XLA's attention too) and 84.2 at 2
-    assert accessed < {4: 150e9, 2: 88e9}[rows]
+    assert memory.generated_code_size_in_bytes < 64 << 20  # 25.9 MB at 4 rows; 36.1 a product a piece, 39.1 with the chunk loops
+    # 105.5 GB at 4 rows and 63.5 at 2, two pieces against a weight ONE product (PR 57); a product a piece read 143.1
+    # and 84.2 (160.7 with the chunk loops, 169.2 with XLA's attention too)
+    assert accessed < {4: 112e9, 2: 68e9}[rows]
+    assert_no_large_weight_is_copied(text, "olmo_hybrid_rerank", "olmo_hybrid", count={4: 2, 2: 2}[rows])  # the first full layer's q and k
     assert "Triangular" not in text  # InvertDiagBlocksLowerTriangular, what triangular_solve lowers to
     assert not re.findall(r"\) while\(", text)
     # the first full layer's attention and the six linear layers' rules
@@ -333,18 +367,24 @@ def test_olmo_hybrids_steps_compile_at_the_published_cut(one_chip, no_compile_ca
     assert len(vmem) == 6 and all(0 < size < DEFAULT_VMEM // 2 for size in vmem)  # 6.7 MB
 
 
-def test_olmo_hybrids_entry_as_the_batcher_builds_it_keeps_the_mlp_weights_prefetched(
+def test_olmo_hybrids_entry_as_the_batcher_builds_it_is_scheduled_as_the_bare_step_is(
         one_chip, no_compile_cache, served_on_a_tpu, monkeypatch):
     """The 4-row entry `batcher._build_entry` traces (the one-buffer upload's
     unpack, the counters beside the outputs under a key that sorts FIRST),
-    compiled for a described v5e from shapes alone: XLA still prefetches the
-    seven MLP `up` weights into VMEM ahead of the fusions that read them
-    (`copy-done` operands). Where the counters stand among an executable's
+    compiled for a described v5e from shapes alone, beside the same step
+    traced bare with its counters last (`sequence_cells_step`): the compiler
+    schedules both alike. Where the counters stand among an executable's
     results decided that until `forward` tied them to the logits with one
-    barrier: this entry lost all seven and 11 ms a step on the chip while the
-    same step traced with the counters last kept them (PERF.md section 6,
-    PR 52). A guard on a compiler's heuristic, so a failure here says "read
-    the served step again on the chip", not "the program is wrong"."""
+    barrier: the entry lost the prefetch of seven MLP `up` weights and 11 ms a
+    step on the chip while the bare step kept them (PERF.md section 6,
+    PR 52). Since PR 57 the MLP's `gate` and `up` are ONE product each a layer
+    (fourteen fusions with an 11,008-wide result where a product a piece made
+    twenty-eight), which read their weight as it lies (one of the fourteen
+    through a prefetch, in the bare step and in the entry alike), so what is
+    held is that the two agree: in those fusions' prefetched operands and in
+    the prefetches they start overall. A guard on a compiler's heuristic, so
+    a failure here says "read the served step again on the chip", not "the
+    program is wrong"."""
     import numpy as np
 
     from distributed_tf_serving_tpu.ops.transfer import combined_layout, combined_words, transfer_spec
@@ -374,10 +414,17 @@ def test_olmo_hybrids_entry_as_the_batcher_builds_it_keeps_the_mlp_weights_prefe
         fn(params, jax.ShapeDtypeStruct((combined_words(layout),), jnp.uint32, sharding=one_chip), layout)
     text = compiled[0].as_text()
     assert combined and text.count('custom_call_target="tpu_custom_call"') == 1 + 6
-    fusions = [re.sub(r", (sharding|metadata|backend_config)=.*", "", line)
-               for line in re.findall(r"^\s*%fusion\.\d+ = f32\[4,2048,11008\][^\n]*", text, re.M)
-               if "convolution_add_fusion" in line]  # the second piece's product with `up`, times silu(gate)
-    assert len(fusions) == 7 and all("%copy-done" in line for line in fusions)
+    bare = sequence_cells_step("olmo_hybrid_rerank", "olmo_hybrid", one_chip, 4)[0].as_text()
+
+    def wide(hlo: str) -> list[bool]:
+        """Whether each fusion with an 11,008-wide result reads a prefetched operand."""
+        return ["%copy-done" in operands for operands in re.findall(
+            r"^\s*%fusion\.\d+ = \(?(?:f32|bf16)\[(?:1,)?4,2048,11008[^\n]*? fusion\(([^)]*)\)", hlo, re.M)]
+
+    # the recorded counts: 1 of 14 prefetched on both sides at PR 57's tree; PR 52 to PR 56 held 7 of 7 here (the second
+    # piece's product with `up`, a `convolution_add_fusion` each), which the entry had lost before PR 52's barrier
+    assert len(wide(text)) == len(wide(bare)) == 14 and sum(wide(text)) == sum(wide(bare)) == 1
+    assert abs(text.count(" copy-start(") - bare.count(" copy-start(")) <= 4 < bare.count(" copy-start(")  # 244, 242
 
 
 def test_mimo_v2s_four_row_step_compiles_at_the_published_cut(one_chip, no_compile_cache, served_on_a_tpu):
@@ -422,6 +469,9 @@ def test_falcon_h1s_steps_compile_at_the_published_cut(one_chip, no_compile_cach
     assert len(vmem) == layers - 1 and all(0 < size < DEFAULT_VMEM * 3 // 4 for size in vmem)  # 8.1 MB
     assert len(re.findall(r"\) while\(", text)) == 1  # the last layer's hand-over scan, and no other loop
     assert not SCORE_TILE.search(text)
+    # 61.7 GB at 4 rows and 39.1 at 2, two pieces against a weight ONE product (PR 57): 87.4 a product a piece
+    assert accessed < {4: 66e9, 2: 42e9}[rows]
+    assert_no_large_weight_is_copied(text, "falcon_h1_34b_rerank", "falcon_h1", count={4: 10, 2: 10}[rows])  # q, k and v of the layers at all positions
 
 
 # ------------------------------------------- the Pallas attention (PR 48)
@@ -585,13 +635,17 @@ def test_a_routed_cells_step_holds_no_expert_loop(name, one_chip, no_compile_cac
 # GSPMD executors, `shard_map_score`, the trainer) for the three routed
 # families, which keep XLA's loops there: their text is the parent's but for
 # the one counter PR 51 adds to the loops (`moe.rows_computed`), so these three
-# are PR 51's own, held here for the next change to be seen against.
+# are PR 51's own, held here for the next change to be seen against. PR 57
+# re-pins the two two-piece families here (`phi4flash` served, `olmo_hybrid`
+# on both paths): their pieces meet a weight in ONE product, where
+# `sequence.product` left them a product a piece; the three routed families'
+# digests, three pieces a product, passed that change untouched.
 LOWERED_TEXT = {
-    "phi4_mini_flash_rerank/phi4flash/served": "023772657653519e",
-    # PR 52: the rule's chunk pass is the kernel and the counters leave with the logits (one barrier) ...
-    "olmo_hybrid_rerank/olmo_hybrid/served": "d0ad9408ce772c58",
-    # ... and XLA's path is the parent's but for that barrier (970016a6f6fb2d72 on both trees before it)
-    "olmo_hybrid_rerank/olmo_hybrid/outside": "a9d9621dead11f8d",
+    "phi4_mini_flash_rerank/phi4flash/served": "8e0e9b40cf785a63",
+    # PR 52: the rule's chunk pass is the kernel and the counters leave with the logits (one barrier) ...; PR 57's text
+    "olmo_hybrid_rerank/olmo_hybrid/served": "013d7048fcf30596",
+    # ... and XLA's path is the parent's but for that barrier (970016a6f6fb2d72 on both trees before it); PR 57's text
+    "olmo_hybrid_rerank/olmo_hybrid/outside": "2796b2ffd7c03eba",
     "dcn_v2_ref43/dcn_v2/served": "c4b1ba715cf54e70",
     "dlrm_dcnv2_mlperf/dlrm_dcnv2/served": "9bbd2eb81f11ee6f",
     "k_exaone_moe_rerank/exaone_moe/outside": "e21defa4c02679fe",
