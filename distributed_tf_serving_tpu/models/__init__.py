@@ -2,7 +2,8 @@
 
 Model families cover every BASELINE.json config: dcn / dcn_v2 (the
 reference's served model, DCNClient.java:33), wide_deep, deepfm, two_tower,
-dlrm, dlrm_dcnv2; and six sequence rankers, phi4flash, pangu_moe, exaone_moe, olmo_hybrid, mimo_v2 and falcon_h1,
+dlrm, dlrm_dcnv2; and seven sequence rankers, phi4flash, pangu_moe, exaone_moe, olmo_hybrid, mimo_v2, falcon_h1
+and qwen3_next,
 whose row is F token ids.
 All share the reference serving contract feat_ids/feat_wts [n, F] -> prediction_node [n].
 """
@@ -21,7 +22,7 @@ from .registry import (
 )
 
 # Import model modules for their registration side effects.
-from . import dcn, deepfm, dlrm, exaone_moe, falcon_h1, generic, mimo_v2, olmo_hybrid, pangu_moe, phi4flash, two_tower, wide_deep  # noqa: E402,F401
+from . import dcn, deepfm, dlrm, exaone_moe, falcon_h1, generic, mimo_v2, olmo_hybrid, pangu_moe, phi4flash, qwen3_next, two_tower, wide_deep  # noqa: E402,F401
 
 __all__ = [
     "Batch",

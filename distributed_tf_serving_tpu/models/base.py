@@ -32,8 +32,8 @@ Batch = dict[str, jax.Array]
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
-    """Knob set shared by the CTR model zoo and the six sequence families
-    (phi4flash, pangu_moe, exaone_moe, olmo_hybrid, mimo_v2, falcon_h1), whose keys
+    """Knob set shared by the CTR model zoo and the seven sequence families
+    (phi4flash, pangu_moe, exaone_moe, olmo_hybrid, mimo_v2, falcon_h1, qwen3_next), whose keys
     carry the names of their published config.json and whose defaults build a
     small valid model.
 
@@ -180,6 +180,25 @@ class ModelConfig:
     ssm_out_multiplier: float = 1.0
     ssm_multipliers: tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
     mlp_multipliers: tuple[float, ...] = (1.0, 1.0)
+    # qwen3_next (models/qwen3_next.py): a row is num_fields token ids as
+    # above, and the shared keys mean what they mean elsewhere: embed_dim the
+    # hidden size, num_experts the ROUTER's width (as exaone_moe reads it),
+    # num_experts_per_tok, moe_intermediate_size, experts_held,
+    # first_expert_held, the linear_* keys the gated delta rule's (here with
+    # fewer key heads than value heads, whole groups of value heads a key
+    # head, and linear_allow_neg_eigval false), partial_rotary_factor as
+    # mimo_v2 reads it, head_dim, rope_theta, num_attention_heads /
+    # num_key_value_heads the full layers', layer_norm_eps. Every layer holds
+    # the routed block; the router is a softmax over all num_experts (the
+    # family's own: the published config has no key for it). Under the
+    # published config.json's names: every how-manieth layer is a full
+    # (gated) attention layer, the others gated-delta-rule layers
+    # (layer_types, where given, says it layer by layer), the width of the
+    # one shared expert, whose output a gate a token scales, and whether the
+    # chosen experts' probabilities are normalised to sum 1.
+    full_attention_interval: int = 4
+    shared_expert_intermediate_size: int = 64
+    norm_topk_prob: bool = True
     # numerics
     compute_dtype: str = "bfloat16"  # "float32" for AUC-parity mode
     param_dtype: str = "float32"
@@ -238,25 +257,28 @@ class Model:
     # beside the id/weight pair (the DLRM families).
     takes_dense: bool = False
     # The kind of every layer of a sequence family (phi4flash, pangu_moe, exaone_moe,
-    # olmo_hybrid, mimo_v2, falcon_h1), whose rows are num_fields TOKENS; empty for the
-    # CTR families.
+    # olmo_hybrid, mimo_v2, falcon_h1, qwen3_next), whose rows are num_fields TOKENS;
+    # empty for the CTR families.
     layer_plan: tuple[str, ...] = ()
     # What a family with a routed layer holds of it, as (name, number) pairs:
     # published, held, first, top_k, heads_published, heads_held,
-    # chips_sharing_layer (pangu_moe, exaone_moe, mimo_v2); empty for every other family.
+    # chips_sharing_layer (pangu_moe, exaone_moe, mimo_v2, qwen3_next); empty for every
+    # other family.
     expert_plan: tuple[tuple[str, int], ...] = ()
     # For a family whose mixer differs by layer, as (name, value) pairs a
     # layer: an attention layer's kind, window, block of queries and keys a
     # block (exaone_moe, olmo_hybrid, mimo_v2, which adds what else differs by
     # kind: kv_heads, rotary_dims, theta, sink); a linear layer's kind, chunk,
-    # state hand-overs a row and bytes of a row's state (olmo_hybrid);
+    # state hand-overs a row and bytes of a row's state (olmo_hybrid; qwen3_next,
+    # which adds the rule's key and value heads, and to a full layer its
+    # kv_heads, rotary_dims, theta and output gate);
     # falcon_h1's layers, which hold both, state the attention's entry and
     # beside it `ssd`, the SSM's (kind, chunk, hand-overs and state bytes a
     # row); empty for every other family.
     attention_plan: tuple[tuple[tuple[str, object], ...], ...] = ()
     # For a family whose step counts what it did on the device (pangu_moe's,
-    # exaone_moe's and mimo_v2's routing, exaone_moe's, olmo_hybrid's and
-    # mimo_v2's score tiles, olmo_hybrid's and falcon_h1's state hand-overs,
+    # exaone_moe's, mimo_v2's and qwen3_next's routing, exaone_moe's, olmo_hybrid's,
+    # mimo_v2's and qwen3_next's score tiles, olmo_hybrid's, qwen3_next's and falcon_h1's state hand-overs,
     # falcon_h1's score tiles, mimo_v2's sinks): `apply_stats(params, batch) -> (apply's outputs, int32
     # [len(step_stats)])`, the counters named by `step_stats` in order. The
     # batcher decides on it when it BUILDS the servable's entry: the counters
@@ -359,7 +381,7 @@ def register_model(kind: str):
 def build_model(kind: str, config: ModelConfig | None = None, **overrides) -> Model:
     """Instantiate a model family by kind: dcn, dcn_v2, wide_deep, deepfm,
     two_tower, dlrm, dlrm_dcnv2, phi4flash, pangu_moe, exaone_moe, olmo_hybrid, mimo_v2,
-    falcon_h1."""
+    falcon_h1, qwen3_next."""
     if kind not in _BUILDERS:
         raise KeyError(f"unknown model kind {kind!r}; have {sorted(_BUILDERS)}")
     if config is None:

@@ -98,6 +98,7 @@ rule (`K K'`, `W`, `U`, `Q K'`, the chunk loop's) included.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -156,7 +157,8 @@ def _sizes(config: ModelConfig) -> dict:
     if lin <= 0 or config.linear_num_key_heads != lin:
         raise ValueError(
             f"linear_num_key_heads {config.linear_num_key_heads}, linear_num_value_heads {lin}: "
-            "one key head a value head (no head is repeated)")
+            "this family's tree holds one key head a value head (`gated_delta_rule` itself takes "
+            "whole groups of value heads a key head: qwen3_next)")
     if min(config.linear_key_head_dim, config.linear_value_head_dim, config.linear_conv_kernel_dim) <= 0:
         raise ValueError("linear_key_head_dim, linear_value_head_dim, linear_conv_kernel_dim: positive")
     return {
@@ -166,23 +168,35 @@ def _sizes(config: ModelConfig) -> dict:
     }
 
 
+def conv_init(rng, width: int, taps: int, dtype) -> jax.Array:
+    """A causal depthwise convolution `[width, taps]` as torch's Conv1d draws
+    it: uniform, bound 1 / sqrt(taps)."""
+    bound = taps ** -0.5
+    return jax.random.uniform(rng, (width, taps), dtype, -bound, bound)
+
+
+def decay_init(k_A, k_dt, heads: int, dtype) -> tuple[jax.Array, jax.Array]:
+    """(`A_log`, `dt_bias`) `[heads]` as flash-linear-attention's
+    `GatedDeltaNet` draws them: `A` uniform in (0, 16), `dt` log-uniform in
+    (1e-3, 1e-1) and `dt_bias` its inverse softplus, so that a step's decay
+    spreads over about (0.2, 1)."""
+    dt = jnp.exp(jax.random.uniform(k_dt, (heads,)) * (math.log(0.1) - math.log(0.001)) + math.log(0.001))
+    return (jnp.log(jax.random.uniform(k_A, (heads,), minval=1e-3, maxval=16.0)).astype(dtype),
+            (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype))
+
+
 def _linear_init(rng, s: dict, dtype) -> dict:
-    """flash-linear-attention's `GatedDeltaNet`: `A` uniform in (0, 16), `dt`
-    log-uniform in (1e-3, 1e-1) and `dt_bias` its inverse softplus, so that a
-    step's decay spreads over about (0.2, 1); the depthwise convolutions as
-    torch's Conv1d draws them (uniform, bound 1 / sqrt(taps))."""
+    """flash-linear-attention's `GatedDeltaNet` (`decay_init`, `conv_init`)."""
     k_q, k_k, k_v, k_cq, k_ck, k_cv, k_b, k_a, k_A, k_dt, k_gate, k_o = jax.random.split(rng, 12)
     hidden, keys, values, taps = s["hidden"], s["lin"] * s["dk"], s["lin"] * s["dv"], s["conv"]
-    bound = taps ** -0.5
-    conv = lambda k, width: jax.random.uniform(k, (width, taps), dtype, -bound, bound)  # noqa: E731
-    dt = jnp.exp(jax.random.uniform(k_dt, (s["lin"],)) * (math.log(0.1) - math.log(0.001)) + math.log(0.001))
+    A_log, dt_bias = decay_init(k_A, k_dt, s["lin"], dtype)
     return {
         "q": matrix(k_q, (hidden, keys), dtype), "k": matrix(k_k, (hidden, keys), dtype),
         "v": matrix(k_v, (hidden, values), dtype),
-        "conv_q": conv(k_cq, keys), "conv_k": conv(k_ck, keys), "conv_v": conv(k_cv, values),
+        "conv_q": conv_init(k_cq, keys, taps, dtype), "conv_k": conv_init(k_ck, keys, taps, dtype),
+        "conv_v": conv_init(k_cv, values, taps, dtype),
         "b": matrix(k_b, (hidden, s["lin"]), dtype), "a": matrix(k_a, (hidden, s["lin"]), dtype),
-        "A_log": jnp.log(jax.random.uniform(k_A, (s["lin"],), minval=1e-3, maxval=16.0)).astype(dtype),
-        "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
+        "A_log": A_log, "dt_bias": dt_bias,
         "gate": matrix(k_gate, (hidden, values), dtype), "o_norm": jnp.ones((s["dv"],), dtype),
         "o": matrix(k_o, (values, hidden), dtype),
     }
@@ -211,11 +225,6 @@ def _layer_init(rng, kind: str, s: dict, dtype) -> dict:
 # ---------------------------------------------------------------------------
 # Building blocks
 # ---------------------------------------------------------------------------
-
-
-def _product(spec: str, x: jax.Array, y: jax.Array, cd) -> jax.Array:
-    """einsum(spec, x, y) as `sequence.product`, at this family's pieces."""
-    return sequence.product(spec, x, y, cd, OPERAND_PIECES)
 
 
 def _dot(x: jax.Array, w: jax.Array, cd) -> jax.Array:
@@ -279,28 +288,32 @@ def unit_lower_inverse(a: jax.Array) -> jax.Array:
     return jnp.moveaxis(inverse(0, blocks), -1, 0)[:, :c, :c].reshape(lead + (c, c))
 
 
-def delta_choice(length: int, count: int, chunk: int = DELTA_CHUNK) -> dict:
-    """`{"kernel": "pallas" | "xla", "chunk", "pieces"}`: which path walks the
-    rule's chunks over rows of `length` positions, the positions a chunk and
-    the pieces an activation enters its products as. A servable's
+def delta_choice(length: int, count: int, chunk: int = DELTA_CHUNK, heads: tuple[int, int] | None = None) -> dict:
+    """`{"kernel": "pallas" | "xla", "chunk", "pieces", "key_heads",
+    "value_heads"}`: which path walks the rule's chunks over rows of `length`
+    positions, the positions a chunk, the pieces an activation enters its
+    products as and the rule's (key, value) `heads` where given. A servable's
     `startup.delta_rule` stamp. The kernel (ops/delta_kernel.py) runs where a
     served entry's kernels do (`sequence.kernels_run`): inside the batcher's
     one-chip entry on a TPU, at every length."""
     kernel = "pallas" if sequence.kernels_run() else "xla"
-    return {"kernel": kernel, "chunk": delta_chunks(length, chunk)[0], "pieces": count}
+    choice = {"kernel": kernel, "chunk": delta_chunks(length, chunk)[0], "pieces": count}
+    if heads is not None:
+        choice.update(key_heads=heads[0], value_heads=heads[1])
+    return choice
 
 
-def takes_kernel(length: int, count: int, chunk: int = DELTA_CHUNK) -> bool:
+def takes_kernel(length: int, count: int, chunk: int = DELTA_CHUNK, heads: tuple[int, int] | None = None) -> bool:
     """Whether the kernel walks this rule's chunks (delta_choice has the
     rule), noted for the served entry being traced."""
-    choice = delta_choice(length, count, chunk)
+    choice = delta_choice(length, count, chunk, heads)
     served = sequence.served_entry()
     if served is not None and served.delta is not None and choice not in served.delta:
         served.delta.append(choice)
     return choice["kernel"] == "pallas"
 
 
-def _chunk_inverse(k: jax.Array, g: jax.Array, b: jax.Array, cd) -> tuple[jax.Array, jax.Array, jax.Array]:
+def _chunk_inverse(k: jax.Array, g: jax.Array, b: jax.Array, cd, count: int) -> tuple[jax.Array, jax.Array, jax.Array]:
     """(G, D, T) of chunks `k [x, y, z, C, dk]`, `g`, `b [x, y, z, C]`: g's
     running sum inside a chunk, `D_ij = exp(G_i - G_j)` where i >= j, else 0,
     and `T = (I + A)^-1 diag(b)`. The caller's `solve` scope."""
@@ -309,34 +322,48 @@ def _chunk_inverse(k: jax.Array, g: jax.Array, b: jax.Array, cd) -> tuple[jax.Ar
     i, j = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None, :]
     # D from the difference under the mask
     decay = jnp.exp(jnp.where(j <= i, total[..., :, None] - total[..., None, :], -jnp.inf))
-    a = jnp.where(j < i, b[..., :, None] * _product("nzhid,nzhjd->nzhij", k, k, cd) * decay, 0.0)
+    a = jnp.where(j < i, b[..., :, None] * sequence.product("nzhid,nzhjd->nzhij", k, k, cd, count) * decay, 0.0)
     # the unit diagonal is taken as read, not read
     return total, decay, unit_lower_inverse(a) * b[..., None, :]
 
 
 def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, b: jax.Array,
                      initial_state: jax.Array | None = None, *, chunk: int = DELTA_CHUNK,
-                     cd=jnp.float32) -> tuple[jax.Array, jax.Array]:
+                     cd=jnp.float32, count: int | None = None) -> tuple[jax.Array, jax.Array]:
     """The gated delta rule over rows of L positions, chunked (the module's
     docstring has the algebra):
 
       S_t = exp(g_t) (I - b_t k_t k_t') S_{t-1} + b_t k_t v_t';   o_t = S_t' q_t
 
-    `q`, `k [n, L, H, dk]` (normalised and scaled by the caller), `v [n, L, H, dv]`,
+    `q`, `k [n, L, Hk, dk]` (normalised and scaled by the caller), `v [n, L, H, dv]`,
     `g`, `b [n, L, H]` (g <= 0), all float32; `initial_state [n, H, dk, dv]` is
     S before the first position (zero where None). Returns `o [n, L, H, dv]`
     and the state after the last position, float32. A length that is no
     multiple of the chunk is padded with k = v = 0, b = 0, g = 0, which leave
     the state as it is. The caller's `delta_rule` scope.
 
+    The H value heads are whole groups of `H / Hk` a key head: value head h
+    reads the q and k of key head `h // (H / Hk)` (qwen3_next: 32 over 16),
+    which are repeated for its group before the chunk algebra, as the
+    published code does: `b`, `g` and so `T` are a VALUE head's, and only
+    `K K'` and `Q K'` would be shared. One to one (olmo_hybrid) nothing is
+    repeated. Activations enter the products as `count` pieces of `cd`
+    (this module's OPERAND_PIECES where None).
+
     Where a one-chip served entry's kernels run (`takes_kernel`) what follows
     `T` is one Pallas kernel a layer that keeps the state in VMEM
     (ops/delta_kernel.py); everywhere else (`shard_map`, the GSPMD executors,
     the trainer, a CPU) it is XLA's, below: the plain form the kernel is
     tested against."""
-    n, length, heads, dk = q.shape
-    dv = v.shape[-1]
-    kernel = takes_kernel(length, OPERAND_PIECES, chunk)
+    n, length, key_heads, dk = q.shape
+    heads, dv = v.shape[-2:]
+    count = OPERAND_PIECES if count is None else count
+    if heads % key_heads:
+        raise ValueError(f"{heads} value heads over {key_heads} key heads: whole groups of value heads a key head")
+    if heads != key_heads:
+        q, k = (jnp.repeat(x, heads // key_heads, axis=2) for x in (q, k))
+    product = functools.partial(sequence.product, cd=cd, count=count)
+    kernel = takes_kernel(length, count, chunk, (key_heads, heads))
     chunk, steps = delta_chunks(length, chunk)
     pad = steps * chunk - length
 
@@ -350,30 +377,30 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, b: 
         from ..ops import delta_kernel
 
         with jax.named_scope("solve"):
-            total, _, t = _chunk_inverse(chunks(k), chunks(g), chunks(b), cd)
+            total, _, t = _chunk_inverse(chunks(k), chunks(g), chunks(b), cd, count)
         with jax.named_scope("chunks"):
             state = jnp.zeros((n, heads, dk, dv), jnp.float32) if initial_state is None else initial_state
             o, state = delta_kernel.chunk_pass(
                 jnp.moveaxis(total, 1, 2), *(padded(x).reshape(n, steps * chunk, -1) for x in (q, k, v)), t,
-                state.astype(STATE_DTYPE).astype(jnp.float32), heads=heads, cd=jnp.dtype(cd), count=OPERAND_PIECES,
+                state.astype(STATE_DTYPE).astype(jnp.float32), heads=heads, cd=jnp.dtype(cd), count=count,
                 state_dtype=jnp.dtype(STATE_DTYPE), interpret=sequence.served_entry().interpret)
         return o.reshape(n, steps * chunk, heads, dv)[:, :length], state
     q, k, v, g, b = (chunks(x) for x in (q, k, v, g, b))
     with jax.named_scope("solve"):
-        total, decay, t = _chunk_inverse(k, g, b, cd)
+        total, decay, t = _chunk_inverse(k, g, b, cd, count)
         grown = jnp.exp(total)[..., None]  # exp(G_i) [n, Z, H, C, 1]
-        w = _product("nzhij,nzhjd->nzhid", t, k * grown, cd)
-        u = _product("nzhij,nzhje->nzhie", t, v, cd)
-        within = _product("nzhid,nzhjd->nzhij", q, k, cd) * decay  # lower((Q K') * D)
+        w = product("nzhij,nzhjd->nzhid", t, k * grown)
+        u = product("nzhij,nzhje->nzhie", t, v)
+        within = product("nzhid,nzhjd->nzhij", q, k) * decay  # lower((Q K') * D)
         left = total[..., -1:]  # G_C [n, Z, H, 1]
         xs = (w, u, q * grown, within, k * jnp.exp(left - total)[..., None], jnp.exp(left)[..., None])
 
     def body(state, x):
         w_z, u_z, q_z, within_z, k_z, decay_z = x
         state = state.astype(jnp.float32)
-        fresh = u_z - _product("nhid,nhde->nhie", w_z, state, cd)  # V'
-        o = _product("nhid,nhde->nhie", q_z, state, cd) + _product("nhij,nhje->nhie", within_z, fresh, cd)
-        state = decay_z * state + _product("nhid,nhie->nhde", k_z, fresh, cd)
+        fresh = u_z - product("nhid,nhde->nhie", w_z, state)  # V'
+        o = product("nhid,nhde->nhie", q_z, state) + product("nhij,nhje->nhie", within_z, fresh)
+        state = decay_z * state + product("nhid,nhie->nhde", k_z, fresh)
         return state.astype(STATE_DTYPE), o
 
     with jax.named_scope("chunks"):

@@ -196,7 +196,7 @@ def latent_attention(p: dict, a: jax.Array, s: dict, cd, eps: float, theta: floa
         k_rope = rotate(k_rope, cos, sin)
     with jax.named_scope("softmax"):
         scale = (nope + rope) ** -0.5
-        if sequence.takes_kernel(queries, length, None, OPERAND_PIECES):
+        if sequence.takes_kernel(queries, length, None, OPERAND_PIECES, sequence.Heads((nope, rope), v_dim, 1, cd)):
             # Both score products accumulate in the kernel's one tile; the
             # rotary keys are one head that every query head reads.
             o = sequence.attention(

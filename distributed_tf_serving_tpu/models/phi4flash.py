@@ -316,7 +316,8 @@ def _attend(p, q, k, v, layer: int, window: int | None, s: dict, cd) -> jax.Arra
     keys' range (all of them, or the last one alone), in `sequence`'s blocks
     of queries; a block reads only the keys its window can reach."""
     offset = k.shape[1] - q.shape[1]
-    if sequence.takes_kernel(q.shape[1], k.shape[1], window, OPERAND_PIECES):
+    shapes = sequence.Heads((s["head"],), 2 * s["head"], s["heads"] // s["kv"], cd)
+    if sequence.takes_kernel(q.shape[1], k.shape[1], window, OPERAND_PIECES, shapes):
         # The halves of a head pair are two key heads that share one value
         # head of width 2d: heads in the order (group, half, query head).
         n, lq, groups, head = q.shape[0], q.shape[1], s["kv"] // 2, s["head"]

@@ -1,16 +1,18 @@
 """What the sequence-ranker families (phi4flash, pangu_moe, exaone_moe,
-olmo_hybrid, mimo_v2, falcon_h1) share: products whose float32 activations enter as pieces of the
+olmo_hybrid, mimo_v2, falcon_h1, qwen3_next) share: products whose float32 activations enter as pieces of the
 compute dtype (`product`: one product a call wherever a form exists that
 copies no large array; against a weight always, two pieces along a second
 contracted axis and three or more stacked, noted for the `startup.products`
 stamp), the causal softmax of a block of queries, the blocks
 themselves, causal attention in those blocks (`blocked_attention`:
-exaone_moe's and olmo_hybrid's full layers, falcon_h1's, both kinds of
-mimo_v2's, whose window layers' softmax holds a learned sink), the attention
-at all positions as one Pallas kernel a layer where a one-chip served entry
-runs on a TPU (`attention`, `takes_kernel`: all six families), the causal
-depthwise convolution (`causal_conv`: phi4flash's Mamba layers, olmo_hybrid's
-linear ones and falcon_h1's Mamba-2 mixers), and
+exaone_moe's, olmo_hybrid's and qwen3_next's full layers, falcon_h1's, both
+kinds of mimo_v2's, whose window layers' softmax holds a learned sink), the
+attention at all positions as one Pallas kernel a layer where a one-chip
+served entry runs on a TPU and the kernel's scratch fits its VMEM at the
+layer's shapes (`attention`, `takes_kernel`, `attention_choice`: all seven
+families), the causal depthwise convolution (`causal_conv`: phi4flash's Mamba
+layers, olmo_hybrid's and qwen3_next's linear ones and falcon_h1's Mamba-2
+mixers), and
 the cut to the last position. One implementation, so that a change to any of
 them is measured on every family's cell. (`models/routed.py` has what the
 routed families share beside these.)
@@ -279,29 +281,47 @@ def kernels_run() -> bool:
 
 
 def kernel_serves(queries: int) -> bool:
-    """Whether an attention of `queries` queries a row runs the kernel: where
-    a served entry's kernels run (`kernels_run`), and more than one query (the
-    last layer's one query has a `[1, keys]` tile: nothing to keep out of
-    memory)."""
+    """Whether an attention of `queries` queries a row may run the kernel:
+    where a served entry's kernels run (`kernels_run`), and more than one
+    query (the last layer's one query has a `[1, keys]` tile: nothing to keep
+    out of memory). `attention_choice` asks besides whether its shapes fit."""
     return kernels_run() and queries > 1
 
 
-def attention_choice(queries: int, keys: int, window: int | None, count: int) -> dict:
+class Heads(NamedTuple):
+    """The shapes of an attention that decide what its kernel keeps in VMEM
+    (ops/attention_kernel.py `vmem_bytes`): the widths of the parts of a
+    query and key head, the width of a value head, the query heads that read
+    one key-value head (the fewest over the parts and the values) and the
+    compute dtype."""
+    widths: tuple[int, ...]
+    dv: int
+    shared: int
+    cd: object
+
+
+def attention_choice(queries: int, keys: int, window: int | None, count: int, heads: Heads | None = None) -> dict:
     """`{"kernel": "pallas" | "xla", "block", "pieces"}`: which path serves
     an attention, the side of the kernel's score tile (0 where XLA's blocks
     run: `Model.attention_plan` states those) and the pieces an activation
-    enters its products as. A servable's `startup.attention` stamp."""
+    enters its products as. A servable's `startup.attention` stamp. Where the
+    kernel would serve but its scratch and blocks at the `heads`' shapes are
+    past the VMEM a kernel has (`attention_kernel.vmem_bytes`: a shape that does
+    not fit is refused by the chip at warm-up, not by the compiler), XLA's blocks
+    serve, and the stamp says `"why": "vmem"`."""
     if not kernel_serves(queries):
         return {"kernel": "xla", "block": 0, "pieces": count}
-    from ..ops.attention_kernel import tile
+    from ..ops.attention_kernel import VMEM_LIMIT, tile, vmem_bytes
 
+    if heads is not None and vmem_bytes(keys, window, *heads, count) > VMEM_LIMIT:
+        return {"kernel": "xla", "block": 0, "pieces": count, "why": "vmem"}
     return {"kernel": "pallas", "block": tile(keys, window), "pieces": count}
 
 
-def takes_kernel(queries: int, keys: int, window: int | None, count: int) -> bool:
+def takes_kernel(queries: int, keys: int, window: int | None, count: int, heads: Heads | None = None) -> bool:
     """Whether `attention` serves this one (attention_choice has the rule),
     noted for the served entry being traced."""
-    choice = attention_choice(queries, keys, window, count)
+    choice = attention_choice(queries, keys, window, count, heads)
     served = served_entry()
     if served is not None and choice not in served.notes:
         served.notes.append(choice)
@@ -337,11 +357,13 @@ def attention(qs, ks, v: jax.Array, window: int | None, cd, count: int, scale: f
     return heads_first(out[0]), jnp.transpose(out[1][..., 0], (0, 2, 1))
 
 
-def blocked_pairs(queries: int, keys: int, window: int | None = None) -> tuple[int, int]:
+def blocked_pairs(queries: int, keys: int, window: int | None = None, count: int = OPERAND_PIECES,
+                  heads: Heads | None = None) -> tuple[int, int]:
     """((query, key) pairs the tiles of `blocked_attention` compute over a row,
     those its masks keep) for the last `queries` positions of `keys`: the
-    kernel's tiles where it serves."""
-    if kernel_serves(queries):
+    kernel's tiles where it serves (`attention_choice`, at the `heads`' shapes
+    and `count` pieces where given)."""
+    if attention_choice(queries, keys, window, count, heads)["kernel"] == "pallas":
         from ..ops.attention_kernel import tile_pairs
 
         computed = tile_pairs(queries, keys, window)
@@ -366,7 +388,7 @@ def blocked_attention(q: jax.Array, k: jax.Array, v: jax.Array, window: int | No
     softmax, `[n, Lq, G, J]`."""
     queries, keys, out, shares = q.shape[1], k.shape[1], [], []
     n, _, groups, per_group, head = q.shape
-    if takes_kernel(queries, keys, window, count):
+    if takes_kernel(queries, keys, window, count, Heads((head,), v.shape[-1], per_group, cd)):
         flat = q.reshape(n, queries, groups * per_group, head)
         o = attention((flat,), (k,), v, window, cd, count, head ** -0.5, sink)
         if sink is None:

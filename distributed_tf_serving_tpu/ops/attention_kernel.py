@@ -128,6 +128,39 @@ def heads_a_step(shared: int, block: int) -> int:
     return max(h for h in range(1, shared + 1) if shared % h == 0 and h * block <= max(ROWS, block))
 
 
+def vmem_bytes(keys: int, window: int | None, widths: tuple[int, ...], dv: int, shared: int, cd, count: int) -> int:
+    """The VMEM bytes a call of `attention` asks for, from its shapes alone:
+    the scratch (the queries' and the keys' pieces side by side a pair, the
+    values' a piece, the running maximum and sum, a lane row each, and the
+    float32 accumulator), the pipeline's double-buffered float32 blocks
+    (the step's queries a part, a head's keys a part, its values, the result)
+    and one float32 score tile.
+    `keys` positions a row, the parts' `widths`, the values' `dv`, `shared`
+    query heads a key-value head (the fewest over the parts and the values),
+    `count` pieces of `cd`. Mosaic's own count is not this one (it keeps a
+    stack of temporaries beside them and some blocks once: 12.5 MiB where this
+    counts 15.1, 13.9 where this counts 12.0); this one is read against
+    VMEM_LIMIT and agrees with the chip's verdict on every shape a cell or a
+    float32 stand-in of one has run (PERF.md section 6, PR 58)."""
+    held, block = pieces_held(cd, count), tile(keys, window)
+    tall, k_len = heads_a_step(shared, block) * block, _round_up(keys, block)
+    width = _round_up(held * (held + 1) // 2 * sum(widths), LANES)
+    item = jnp.dtype(cd).itemsize
+    scratch = (tall + k_len) * width * item + k_len * held * dv * item + tall * (2 * LANES + dv) * 4
+    blocks = 2 * 4 * ((tall + k_len) * sum(widths) + k_len * dv + tall * dv)
+    return scratch + blocks + tall * block * 4
+
+
+# What a kernel has by default on a v5e, which this one never raises (ROWS has
+# why), and what `sequence.attention_choice` reads `vmem_bytes` against before
+# it says `pallas`. The widest head a cell runs, `mimo_v2`'s 192-wide keys over
+# 128-wide values at three pieces, counts 15.1 MiB and is taken by the chip;
+# 256 wide both ways counts 22.5 MiB at three pieces and 16.5 at one float32
+# piece (the readings' stand-in), and the chip refuses both at run time (16.99
+# and 16.20 MiB by its own count): PERF.md section 6, PR 50 (a) and PR 58.
+VMEM_LIMIT = 16 << 20
+
+
 def _kernel(*refs, widths, reps, rep_v, dv, held, cd, scale, window, offset, block, keys, stacked, sunk=False):
     parts = len(widths)
     tall = stacked * block

@@ -1,15 +1,16 @@
 """The held experts' part of a routed layer as ONE pass of Pallas TPU kernels
 over row tiles, each of which finds its expert's weights.
 
-XLA's path (`models/routed.py::held_experts`' loops) walks each held expert in
-a loop of its own, a padded block of 256 rows a step: eight loops a layer that
-start and end, a fifth to a third of the rows computed padding, a 256-row
-scatter-add into a carried `[T, H]` a block (PERF.md section 6, PR 51). Here
-the (token, held expert) pairs of a layer are laid out once, expert by expert,
-each expert's run rounded up to whole tiles of TILE rows, and two kernels walk
-the tiles that hold a token (a grid whose length the routing decides: a
-dynamic grid bound) with the tile's expert read from scalar memory by the
-weights' index maps, so that no weight is sliced or copied in HBM:
+XLA's path (`models/routed.py::held_experts`' loop) walks the tiles in a loop,
+a padded tile of 256 rows a step: a fifth to a third of the rows computed
+padding, a 256-row scatter-add into a carried `[T, H]` a tile (PERF.md section
+6, PR 51). Here the (token, held expert) pairs of a layer, laid out once,
+expert by expert, each expert's run rounded up to whole tiles of TILE rows
+(`models/routed.py::lay_out`: one sort, whatever the number of experts held),
+are walked by two kernels over the tiles that hold a token (a grid whose
+length the routing decides: a dynamic grid bound) with the tile's expert read
+from scalar memory by the weights' index maps, so that no weight is sliced or
+copied in HBM:
 
 - `grouped_gate_up`: a tile's rows are gathered from the tokens by row copies
   HBM -> VMEM (no sorted copy of the tokens exists in HBM), cut into `count`
@@ -39,8 +40,10 @@ its index.
 
 Every buffer whose size the routing decides is either never made (the sorted
 tokens, the sorted result) or sized for the worst case the shapes allow, every
-token on every held expert: `[held, T, F]` float32 between the kernels, of
-which only the tiles that hold a token are written or read. No token is
+token on `min(k, held)` held experts and a tile of padding an expert
+(`routed.layout_tiles`): `[tiles x TILE, F]` float32 between the kernels (at
+128 held of 512 and top-10 a twelfth of `[held, T, F]`), of which only the
+tiles that hold a token are written or read, and a tile table as long. No token is
 dropped whatever the routing and nothing is approximated: `count` pieces of
 every activation, the weights rounded once, float32 accumulation and gating:
 `routed.gated_mlp`'s arithmetic to float32 rounding in another order of
@@ -89,22 +92,6 @@ def _block(size: int, most: int) -> int:
     return size
 
 
-def tile_table(loads: jax.Array, tiles_an_expert: int, tile: int = TILE):
-    """(expert, tile within the expert's run, rows that hold a token) of every
-    tile a pass may walk, `[held * tiles_an_expert]` int32 each, the tiles that
-    hold a token first and in the experts' order; and how many those are.
-    `loads [held]` are the tokens of each held expert."""
-    held = loads.shape[0]
-    tiles = (loads + tile - 1) // tile
-    ends = jnp.cumsum(tiles)
-    at = jnp.arange(held * tiles_an_expert, dtype=jnp.int32)
-    # The runs that end at or before the tile: its expert (no search: a loop)
-    expert = jnp.minimum(jnp.sum(at[:, None] >= ends[None, :], axis=1, dtype=jnp.int32), held - 1)
-    within = at - (ends - tiles)[expert]
-    rows = jnp.clip(loads[expert] - within * tile, 0, tile)
-    return expert, within, jnp.where(at < ends[-1], rows, 0), ends[-1]
-
-
 def _row(ref, r):
     """Row r of a `[rows, 128]` array, or of a `[rows / 8, chunks, 8, 128]`
     one (a `[rows, chunks * 128]` array in tiles of (8, 128), tile by tile)."""
@@ -140,9 +127,9 @@ def _stacked_product(stacked, w_ref, held: int, tile: int):
     return out
 
 
-def _gate_up_kernel(expert, within, rows, tokens, x_hbm, gate_ref, up_ref, h_ref, ring, sem, acc_g, acc_u,
+def _gate_up_kernel(expert, rows, tokens, x_hbm, gate_ref, up_ref, h_ref, ring, sem, acc_g, acc_u,
                     *, held, cd, tile, chunks):
-    del expert, within
+    del expert
     i, n, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when((n == 0) & (k == 0))
@@ -173,9 +160,9 @@ def _gate_up_kernel(expert, within, rows, tokens, x_hbm, gate_ref, up_ref, h_ref
         h_ref[...] = g * jax.nn.sigmoid(g) * acc_u[...]
 
 
-def _down_kernel(expert, within, rows, tokens, h_ref, down_ref, gates_hbm, zeros_hbm, out_hbm, counts, cut, ring,
+def _down_kernel(expert, rows, tokens, h_ref, down_ref, gates_hbm, zeros_hbm, out_hbm, counts, cut, ring,
                  lanes, sem, gate_sem, *, held, experts, cd, tile, chunks):
-    del within, zeros_hbm  # the result's own buffer, zero where no row is added
+    del zeros_hbm  # the result's own buffer, zero where no row is added
     i, n = pl.program_id(0), pl.program_id(1)
     taken = rows[i]
 
@@ -218,7 +205,7 @@ def _down_kernel(expert, within, rows, tokens, h_ref, down_ref, gates_hbm, zeros
 
 
 @functools.partial(jax.jit, static_argnames=("cd", "count", "tile", "interpret"))
-def grouped_experts(gate, up, down, x, gate_of, orders, loads, *, cd, count: int, tile: int = TILE,
+def grouped_experts(gate, up, down, x, gate_of, orders, expert, rows, live, *, cd, count: int, tile: int = TILE,
                     interpret: bool = False):
     """`sum over the held experts e that chose token t of gate_of[t, e] *
     expert_e(x[t])`, `[T, H]` float32; the rows that were added back a held
@@ -227,19 +214,21 @@ def grouped_experts(gate, up, down, x, gate_of, orders, loads, *, cd, count: int
     gate, up  `[held, H, F]` in the compute dtype `cd`; down `[held, F, H]`
     x         `[T, H]` float32, the tokens
     gate_of   `[T, held]` float32, a token's gate for each held expert
-    orders    `[held, P]` int32, P a whole number of tiles no less than T: an
-              expert's tokens first and in order (what stands after them is
-              not read)
-    loads     `[held]` int32, the tokens of each held expert
+    orders    `[tiles, tile]` int32, the token of every row of every tile a
+              pass may walk: an expert's tokens in order, its run in whole
+              tiles (what stands after a tile's `rows` is not read)
+    expert    `[tiles]` int32, the held expert of each tile
+    rows      `[tiles]` int32, the rows of each tile that hold a token: the
+              tiles that hold one first (`routed.lay_out` makes the three)
+    live      how many tiles hold a token
     """
     held, hidden, width = gate.shape
-    tokens, per_expert = x.shape[0], orders.shape[1] // tile
+    tokens, tiles = x.shape[0], orders.shape[0]
     pieces = pieces_held(cd, count)
-    expert, within, rows, live = tile_table(loads, per_expert, tile)
     # One tile at least, of no rows where no token came here: its step zeroes
     # the counters.
     walked = jnp.maximum(live, 1)
-    order_tiles = orders.reshape(held * per_expert, 1, tile)
+    order_tiles = orders.reshape(tiles, 1, tile)
     # A row as chunks of whole lanes (one chunk, a test's narrow row), eight
     # rows a tile: the bytes of `[T, H]` as they lie, where T is whole eights.
     lanes = LANES if hidden % LANES == 0 else hidden
@@ -249,27 +238,23 @@ def grouped_experts(gate, up, down, x, gate_of, orders, loads, *, cd, count: int
     ring = pltpu.VMEM((tile // 8, hidden // lanes, 8, lanes), jnp.float32)
     params = functools.partial(pltpu.CompilerParams, disable_bounds_checks=True)
 
-    def tile_of(i, expert, within):
-        return expert[i] * per_expert + within[i]
-
     def a_tiles_tokens():
-        return pl.BlockSpec((None, 1, tile), lambda i, *rest: (tile_of(i, rest[-3], rest[-2]), 0, 0),
-                            memory_space=pltpu.SMEM)
+        return pl.BlockSpec((None, 1, tile), lambda i, *rest: (i, 0, 0), memory_space=pltpu.SMEM)
 
     with jax.named_scope("grouped"):
         h = pl.pallas_call(
             functools.partial(_gate_up_kernel, held=pieces, cd=cd, tile=tile, chunks=k_block // lanes),
-            out_shape=jax.ShapeDtypeStruct((held * per_expert * tile, width), jnp.float32),
+            out_shape=jax.ShapeDtypeStruct((tiles * tile, width), jnp.float32),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=3,
+                num_scalar_prefetch=2,
                 grid=(walked, width // n_block, hidden // k_block),
                 in_specs=[
                     a_tiles_tokens(),
                     pl.BlockSpec(memory_space=pl.ANY),
-                    pl.BlockSpec((None, k_block, n_block), lambda i, n, k, e, w, r: (e[i], k, n)),
-                    pl.BlockSpec((None, k_block, n_block), lambda i, n, k, e, w, r: (e[i], k, n)),
+                    pl.BlockSpec((None, k_block, n_block), lambda i, n, k, e, r: (e[i], k, n)),
+                    pl.BlockSpec((None, k_block, n_block), lambda i, n, k, e, r: (e[i], k, n)),
                 ],
-                out_specs=pl.BlockSpec((tile, n_block), lambda i, n, k, e, w, r: (tile_of(i, e, w), n)),
+                out_specs=pl.BlockSpec((tile, n_block), lambda i, n, k, e, r: (i, n)),
                 scratch_shapes=[
                     ring,
                     pltpu.SemaphoreType.DMA(()),
@@ -279,7 +264,7 @@ def grouped_experts(gate, up, down, x, gate_of, orders, loads, *, cd, count: int
             compiler_params=params(dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
             interpret=interpret,
             name="grouped_gate_up",
-        )(expert, within, rows, order_tiles, x, gate, up)
+        )(expert, rows, order_tiles, x, gate, up)
     # A token's gates, a row of whole lanes: what a row copy can bring.
     gate_lanes = jnp.pad(gate_of, ((0, 0), (0, _round_up(held, LANES) - held)))
     with jax.named_scope("combine"):
@@ -287,12 +272,12 @@ def grouped_experts(gate, up, down, x, gate_of, orders, loads, *, cd, count: int
             functools.partial(_down_kernel, held=pieces, experts=held, cd=cd, tile=tile, chunks=k_block // lanes),
             out_shape=(jax.ShapeDtypeStruct(x.shape, jnp.float32), jax.ShapeDtypeStruct((held + 1,), jnp.int32)),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=3,
+                num_scalar_prefetch=2,
                 grid=(walked, hidden // k_block),
                 in_specs=[
                     a_tiles_tokens(),
-                    pl.BlockSpec((tile, width), lambda i, n, e, w, r: (tile_of(i, e, w), 0)),
-                    pl.BlockSpec((None, width, k_block), lambda i, n, e, w, r: (e[i], 0, n)),
+                    pl.BlockSpec((tile, width), lambda i, n, e, r: (i, 0)),
+                    pl.BlockSpec((None, width, k_block), lambda i, n, e, r: (e[i], 0, n)),
                     pl.BlockSpec(memory_space=pl.ANY),
                     pl.BlockSpec(memory_space=pl.ANY),
                 ],
@@ -304,10 +289,10 @@ def grouped_experts(gate, up, down, x, gate_of, orders, loads, *, cd, count: int
                     pltpu.SemaphoreType.DMA(()),
                     pltpu.SemaphoreType.DMA(()),
                 ]),
-            # operand 7 (after the three tables): the zeros the result starts as
-            input_output_aliases={7: 0},
+            # operand 6 (after the two tables): the zeros the result starts as
+            input_output_aliases={6: 0},
             compiler_params=params(dimension_semantics=("arbitrary", "arbitrary")),
             interpret=interpret,
             name="grouped_down",
-        )(expert, within, rows, order_tiles, h, down, gate_lanes, jnp.zeros(x.shape, jnp.float32))
+        )(expert, rows, order_tiles, h, down, gate_lanes, jnp.zeros(x.shape, jnp.float32))
     return out.transpose(0, 2, 1, 3).reshape(eights * 8, hidden)[:tokens], counts[:held], counts[held]

@@ -1814,33 +1814,36 @@ class DynamicBatcher:
     def attentions(self) -> dict[str, dict]:
         """"name:version" -> the attention of that servable's entry as
         traced: `{"kernel": "pallas" | "xla", "block", "pieces"}` (the
-        kernel's, and its widest tile, where layers or rungs differ), for
+        kernel's, and its widest tile, where layers or rungs differ; with
+        `"why": "vmem"` where XLA's blocks serve because the kernel's scratch
+        does not fit at the layer's shapes), for
         every servable whose step attends. A custom run_fn traces its own
         entries, outside serving_attention: the XLA path, no stamp."""
         with self._jit_lock:
             return {
                 f"{sv.name}:{sv.version}": max(
-                    notes, key=lambda n: (n["kernel"] == "pallas", n["block"])
+                    notes, key=lambda n: (n["kernel"] == "pallas", n["block"], "why" in n)
                 )
                 for sv, notes in self._attentions.items() if notes
             }
 
     def groupeds(self) -> dict[str, dict]:
         """"name:version" -> the held experts' product of that servable's
-        entry as traced: `{"kernel": "pallas" | "xla", "tile", "pieces"}`
-        (the kernels' where layers or rungs differ), for every servable
+        entry as traced: `{"kernel": "pallas" | "xla", "tile", "pieces",
+        "held", "rows"}` (the kernels', then the layout of the most rows,
+        where layers or rungs differ), for every servable
         whose step has a routed layer. A custom run_fn traces its own
-        entries, outside serving_attention: XLA's loops, no stamp."""
+        entries, outside serving_attention: XLA's loop, no stamp."""
         with self._jit_lock:
             return {
-                f"{sv.name}:{sv.version}": max(notes, key=lambda n: n["kernel"] == "pallas")
+                f"{sv.name}:{sv.version}": max(notes, key=lambda n: (n["kernel"] == "pallas", n.get("rows", 0)))
                 for sv, notes in self._groupeds.items() if notes
             }
 
     def delta_rules(self) -> dict[str, dict]:
         """"name:version" -> the gated delta rule's chunk pass of that
         servable's entry as traced: `{"kernel": "pallas" | "xla", "chunk",
-        "pieces"}` (the kernel's where rungs differ), for every servable
+        "pieces", "key_heads", "value_heads"}` (the kernel's where rungs differ), for every servable
         whose step has a linear-attention layer. A custom run_fn traces its
         own entries, outside serving_attention: XLA's scan, no stamp."""
         with self._jit_lock:

@@ -86,7 +86,7 @@ def test_the_kernel_is_xlas_rule_to_float32_rounding(name):
     notes = []
     got, last = through_the_kernel(arrays, chunk, cd, notes)
     want, want_last = through_xla(arrays, chunk, cd)
-    assert notes == [{"kernel": "pallas", "chunk": min(chunk, length), "pieces": 2}]
+    assert notes == [{"kernel": "pallas", "chunk": min(chunk, length), "pieces": 2, "key_heads": heads, "value_heads": heads}]
     assert got.shape == (n, length, heads, dv) and last.shape == (n, heads, dk, dv)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=2e-6 * np.abs(want).max())
     np.testing.assert_allclose(last, want_last, rtol=1e-5, atol=2e-6 * np.abs(want_last).max())
@@ -189,7 +189,8 @@ def test_outside_a_served_entry_the_rule_is_xlas(monkeypatch):
     assert "delta_rule" not in lowered()
     with sequence.serving_attention([], delta=(notes := [])):
         assert not olmo_hybrid.takes_kernel(2048, 2) and "delta_rule" not in lowered()
-    assert notes == [{"kernel": "xla", "chunk": 64, "pieces": 2}, {"kernel": "xla", "chunk": 16, "pieces": 2}]
+    assert notes == [{"kernel": "xla", "chunk": 64, "pieces": 2},
+                     {"kernel": "xla", "chunk": 16, "pieces": 2, "key_heads": 3, "value_heads": 3}]  # the rule says its heads
     monkeypatch.setattr(sequence.jax, "default_backend", lambda: "tpu")
     assert not olmo_hybrid.takes_kernel(2048, 2)  # outside it, on a TPU: the trainer's, an executor's
     with sequence.serving_attention([], delta=(notes := [])):
@@ -233,11 +234,11 @@ def test_batcher_stamps_the_delta_rule_and_counts_its_batches(monkeypatch):
     } for n in (1, 3)]
     want, stats, counted, stamp = _serve(payloads)
     assert stats.batches == 2 and stats.delta_kernel_batches == 0 and counted == 0
-    assert stamp == {"M:1": {"kernel": "xla", "chunk": 64, "pieces": 2}}
+    assert stamp == {"M:1": {"kernel": "xla", "chunk": 64, "pieces": 2, "key_heads": 3, "value_heads": 3}}
     monkeypatch.setattr(batcher_mod, "serving_attention", interpreted)
     got, stats, counted, stamp = _serve(payloads)
     assert stats.batches == 2 and stats.delta_kernel_batches == 2 and counted == 2
-    assert stamp == {"M:1": {"kernel": "pallas", "chunk": 64, "pieces": 2}}
+    assert stamp == {"M:1": {"kernel": "pallas", "chunk": 64, "pieces": 2, "key_heads": 3, "value_heads": 3}}
     np.testing.assert_allclose(got, want, atol=1e-4)
 
 

@@ -299,7 +299,8 @@ def test_the_steps_counters_are_a_numpy_count():
     _, _, scores = routed.route(layer["router"], a.reshape(-1, 64), 4, 2.5)
     top = np.argsort(-np.asarray(scores), axis=1)[:, :4]
     loads = [(top == e).sum() for e in range(4, 8)]
-    assert counts.tolist() == [4 * LENGTH, sum(loads), max(loads), sum(-(-n // 256) * 256 for n in loads)] and sum(loads) > 0
+    assert counts.tolist() == [4 * LENGTH, sum(loads), max(loads), sum(-(-n // 256) * 256 for n in loads),
+                               sum(n > 0 for n in loads)] and sum(loads) > 0
 
 
 def test_the_published_rows_pairs_are_what_the_reader_will_divide():

@@ -106,12 +106,24 @@ def test_the_planted_precision_is_told_apart_through_the_kernels():
     assert float(jnp.max(jnp.abs(three - loops))) * 300 < float(jnp.max(jnp.abs(one - loops)))
 
 
-def test_the_tile_table_walks_the_tiles_that_hold_a_token_in_the_experts_order():
-    expert, within, rows, live = grouped_kernel.tile_table(jnp.asarray([130, 0, 5, 256], jnp.int32), 3, 128)
-    assert int(live) == 5
-    assert expert.tolist()[:5] == [0, 0, 2, 3, 3] and within.tolist()[:5] == [0, 1, 0, 0, 1]
-    assert rows.tolist() == [128, 2, 5, 128, 128] + [0] * 7
-    assert int(grouped_kernel.tile_table(jnp.zeros((4,), jnp.int32), 3, 128)[3]) == 0
+def test_the_layout_walks_the_tiles_that_hold_a_token_in_the_experts_order():
+    """One sort lays the pairs out: loads 130, 0, 5, 256 of four held experts
+    (first = 2) are five tiles of 128, each expert's tokens in row order."""
+    loads, first, tokens = [130, 0, 5, 256], 2, 300
+    chosen = np.full((tokens, 4), 9, np.int32)  # expert 9 is not held
+    for e, n in enumerate(loads):
+        chosen[np.random.default_rng(e).permutation(tokens)[:n], e] = first + e
+    orders, expert, rows, live = routed.lay_out(jnp.asarray(chosen), first, 4, 128)
+    assert orders.shape == (routed.layout_tiles(tokens, 4, 4, 128), 128)
+    assert [int(rows[np.asarray(expert) == e].sum()) for e in range(4)] == loads
+    assert int(live) == 5 and expert.tolist()[:5] == [0, 0, 2, 3, 3]
+    assert rows.tolist() == [128, 2, 5, 128, 128] + [0] * (orders.shape[0] - 5)
+    for i, e in enumerate(expert.tolist()[:5]):
+        took = orders[i, :int(rows[i])].tolist()
+        assert took == sorted(took) and all(first + e in chosen[t] for t in took)
+        assert (np.asarray(orders[i, int(rows[i]):]) == tokens).all()
+    nobody = routed.lay_out(jnp.full((64, 2), 9, jnp.int32), first, 4, 128)
+    assert int(nobody[3]) == 0 and not nobody[2].any() and (np.asarray(nobody[0]) == 64).all()
 
 
 # ------------------------------------------------ the three families' steps
@@ -141,7 +153,10 @@ def _served_step(model):
     def served(p, b):
         with interpreted([], grouped=(notes := [])):
             out = model.apply_stats(p, b)
-        assert notes == [{"kernel": "pallas", "tile": TILE, "pieces": 3}]
+        # a note a token count: the layers at all positions, the last layer's one position a row
+        assert notes and all(
+            dict(n, held=0, rows=0) == {"kernel": "pallas", "tile": TILE, "pieces": 3, "held": 0, "rows": 0}
+            and 0 < n["held"] <= model.config.experts_held and n["rows"] % TILE == 0 for n in notes)  # a planted fault drops one
         return out
 
     return jax.jit(served)
@@ -237,7 +252,9 @@ def test_everything_but_the_served_entry_on_a_tpu_keeps_the_loops(caller, noted,
         monkeypatch.setattr(sequence.jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(grouped_kernel, "grouped_experts", _refused)
     notes = caller(*_family("mimo_v2"))
-    assert bool(notes) == noted and all(n == {"kernel": "xla", "tile": routed.EXPERT_BLOCK, "pieces": 3} for n in notes)
+    assert bool(notes) == noted and all(
+        {k: n[k] for k in ("kernel", "tile", "pieces")} == {"kernel": "xla", "tile": routed.EXPERT_BLOCK, "pieces": 3}
+        for n in notes)
 
 
 def test_a_served_entry_on_a_tpu_takes_the_kernels(monkeypatch):
@@ -247,7 +264,10 @@ def test_a_served_entry_on_a_tpu_takes_the_kernels(monkeypatch):
     monkeypatch.setattr(sequence.jax, "default_backend", lambda: "tpu")
     with sequence.serving_attention([], grouped=(notes := [])):
         assert routed.takes_kernel(3) and routed.takes_kernel(3)
-    assert notes == [{"kernel": "pallas", "tile": TILE, "pieces": 3}]
+        assert routed.takes_kernel(3, 16384, 10, 128)
+    assert notes == [{"kernel": "pallas", "tile": TILE, "pieces": 3},
+                     # 128 of 512 experts at top-10 over 16,384 tokens: 1,408 tiles where `[held, T]` is 16,384
+                     {"kernel": "pallas", "tile": TILE, "pieces": 3, "held": 128, "rows": 1408 * TILE}]
     assert not routed.takes_kernel(3)  # outside it
 
 
@@ -285,11 +305,15 @@ def test_batcher_stamps_the_grouped_product_and_counts_its_batches(monkeypatch):
     } for n in (1, 2)]
     want, stats, counted, stamp = _serve(payloads)
     assert stats.batches == 2 and stats.grouped_kernel_batches == 0 and counted == 0
-    assert stamp == {"M:1": {"kernel": "xla", "tile": routed.EXPERT_BLOCK, "pieces": 3}}
+    held = load_config(os.path.join(CONFIGS, "mimo_v2_small.toml"))["model"].experts_held
+    rows = stamp["M:1"].pop("rows")  # the widest layout traced: the top rung's layers at all positions
+    assert stamp == {"M:1": {"kernel": "xla", "tile": routed.EXPERT_BLOCK, "pieces": 3, "held": held}}
+    assert rows >= held * routed.EXPERT_BLOCK and rows % routed.EXPERT_BLOCK == 0
     monkeypatch.setattr(batcher_mod, "serving_attention", interpreted)
     got, stats, counted, stamp = _serve(payloads)
     assert stats.batches == 2 and stats.grouped_kernel_batches == 2 and counted == 2
-    assert stamp == {"M:1": {"kernel": "pallas", "tile": TILE, "pieces": 3}}
+    assert stamp["M:1"].pop("rows") % TILE == 0
+    assert stamp == {"M:1": {"kernel": "pallas", "tile": TILE, "pieces": 3, "held": held}}
     np.testing.assert_allclose(got, want, atol=1e-6)
 
 

@@ -669,16 +669,20 @@ def test_toml_reads_the_published_keys(tmp_path):
 # delta kernel, interpreted. `phi4flash_small` and `olmo_hybrid_small` since
 # PR 57 at PR 57's tree: their two pieces meet a weight in ONE product, along
 # a second contracted axis (`sequence.product`), on either path and at every
-# rung; the three-piece families' ten digests passed that change untouched.)
+# rung; the three-piece families' ten digests passed that change untouched.
+# `pangu_moe_small` and `exaone_moe_small` since PR 58 at PR 58's tree: a
+# routed layer's pairs are laid out by ONE sort and walked by one loop (the
+# kernels' tile table as long as `T x k` rows and a tile an expert), and the
+# layers count a fifth thing, `moe.experts_hit`.)
 PARENTS_TEXT = {
     "phi4flash_small/2/xla": "4301e004f3aa5b02", "phi4flash_small/2/kernel": "f1d7617a55d2f678",
     "phi4flash_small/4/xla": "9c36b633ec949e4c", "phi4flash_small/4/kernel": "567ce8c611a949ed",
     "phi4flash_small/8/xla": "f1b0268aae7189c9", "phi4flash_small/8/kernel": "6b8f1bda33313ff8",
-    "pangu_moe_small/2/xla": "37e0da30df3df885", "pangu_moe_small/2/kernel": "8e91f3619862ddb1",
-    "pangu_moe_small/4/xla": "9a7ed5847f1a38b1", "pangu_moe_small/4/kernel": "490f131f5930da45",
-    "pangu_moe_small/8/xla": "44d4aca134f03335", "pangu_moe_small/8/kernel": "ef3eed34ccb4da88",
-    "exaone_moe_small/2/xla": "a766e567a1c79cc9", "exaone_moe_small/2/kernel": "8e525b84dd5524e8",
-    "exaone_moe_small/4/xla": "80bcc80aef27e6c8", "exaone_moe_small/4/kernel": "dd88cc09cfcbcd97",
+    "pangu_moe_small/2/xla": "89d28ae9c63851d4", "pangu_moe_small/2/kernel": "0a044d0f1395fab2",
+    "pangu_moe_small/4/xla": "4411e78ec9ba45f8", "pangu_moe_small/4/kernel": "d98e08eb410da32d",
+    "pangu_moe_small/8/xla": "5c9b63c0a52c3ea8", "pangu_moe_small/8/kernel": "ffa8e034d2c70bfe",
+    "exaone_moe_small/2/xla": "fd0a9c4f6522c23a", "exaone_moe_small/2/kernel": "6e608894b7a2580f",
+    "exaone_moe_small/4/xla": "ab4f227eb45d603c", "exaone_moe_small/4/kernel": "9256b6fa33fdb4d8",
     "olmo_hybrid_small/2/xla": "ecdd9541dea18350", "olmo_hybrid_small/2/kernel": "bdf706717c727039",
     "olmo_hybrid_small/4/xla": "503592a291cb1507", "olmo_hybrid_small/4/kernel": "e05f07731113e13b",
 }

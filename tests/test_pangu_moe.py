@@ -225,9 +225,10 @@ def test_a_row_of_zero_weights_is_left_out_of_the_experts_and_the_counters_exact
 
 
 def test_the_counters_follow_the_blocks_the_loops_ran(monkeypatch):
-    """`assignments_here` and the busiest load are counted where a block
-    gathers its rows: an expert loop cut to its first block reads what that
-    block took, not what the router sent."""
+    """`assignments_here` and the busiest load are counted where a tile
+    gathers its rows: the loop over the tiles cut to its first tile reads
+    what that tile took (16 tokens of the first held expert), not what the
+    router sent."""
     rng = np.random.default_rng(1)
     p = {name: jnp.asarray(rng.standard_normal(shape), jnp.float32)
          for name, shape in (("gate", (2, 16, 8)), ("up", (2, 16, 8)), ("down", (2, 8, 16)))}
@@ -238,7 +239,7 @@ def test_the_counters_follow_the_blocks_the_loops_ran(monkeypatch):
     assert run() == [[40, 40], 96]
     loop = jax.lax.fori_loop
     monkeypatch.setattr(jax.lax, "fori_loop", lambda lo, hi, body, init: loop(lo, jnp.minimum(hi, 1), body, init))
-    assert run() == [[16, 16], 32]
+    assert run() == [[16, 0], 16]
 
 
 # ------------------------------------------------------------------ counters
@@ -255,7 +256,8 @@ def test_the_steps_counters_are_a_numpy_count_on_the_same_router_scores():
     _, _, scores = pangu_moe.route(layer["router"], a.reshape(-1, 64), 4, 2.5)
     top = np.argsort(-np.asarray(scores), axis=1)[:, :4]
     loads = [(top == e).sum() for e in range(4, 8)]
-    assert counts.tolist() == [4 * LENGTH, sum(loads), max(loads), sum(-(-n // 256) * 256 for n in loads)] and sum(loads) > 0
+    assert counts.tolist() == [4 * LENGTH, sum(loads), max(loads), sum(-(-n // 256) * 256 for n in loads),
+                               sum(n > 0 for n in loads)] and sum(loads) > 0
     # the whole step: a routed layer before the last at all positions, the last at one
     _, stats = jax.jit(model.apply_stats)(params, rows(4, config))
     assert model.step_stats == pangu_moe.STEP_STATS and int(stats[0]) == 4 * LENGTH + 4

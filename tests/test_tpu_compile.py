@@ -474,6 +474,29 @@ def test_falcon_h1s_steps_compile_at_the_published_cut(one_chip, no_compile_cach
     assert_no_large_weight_is_copied(text, "falcon_h1_34b_rerank", "falcon_h1", count={4: 10, 2: 10}[rows])  # q, k and v of the layers at all positions
 
 
+def test_qwen3_nexts_eight_row_step_compiles_at_the_published_cut(one_chip, no_compile_cache, served_on_a_tpu):
+    """Qwen3-Next's share as `qwen3_next_80b_rerank-bulk` serves it (2.508 B
+    parameters, 128 of 512 experts a layer, 8 rows of 2,048 tokens), the top
+    bucket's step with its counters: the four linear layers' rules ONE Pallas
+    kernel each at 32 value heads of 128, the five routed layers' held experts
+    the two grouped kernels each over a layout of `T x k` rows and a tile an
+    expert, no loop of XLA's anywhere (the dispatch is one sort a layer), and
+    the full layer's attention XLA's blocks: heads 256 wide at three pieces do
+    not fit the kernel's VMEM (`sequence.attention_choice`). Necessary, not
+    sufficient: the chip decides (PERF.md section 6, PR 58)."""
+    compiled, accessed = sequence_cells_step("qwen3_next_80b_rerank", "qwen3_next", one_chip)
+    memory, text = compiled.memory_analysis(), compiled.as_text()
+    assert 5.0e9 < memory.argument_size_in_bytes < 5.1e9  # 2,508 M parameters in bfloat16
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12 * GIB  # 3.6 GB of temporaries
+    assert memory.generated_code_size_in_bytes < 64 << 20  # 46 MB
+    assert not re.findall(r"\) while\(", text)
+    assert text.count('custom_call_target="tpu_custom_call"') == 4 + 2 * 5 and "vmem_limit" not in text
+    assert len(kernels_vmem(text, "delta_rule")) == 4 and not kernels_vmem(text, "attention")
+    assert all(0 < size < DEFAULT_VMEM // 2 for name in ("delta_rule", "grouped_gate_up", "grouped_down")
+               for size in kernels_vmem(text, name))
+    assert accessed < 160e9  # 145 GB
+
+
 # ------------------------------------------- the Pallas attention (PR 48)
 #
 # What interpret mode cannot see: Mosaic's verdict on the kernel's slices,
@@ -520,12 +543,19 @@ def test_attention_kernel_compiles_at_the_cells_top_rungs(one_chip, no_compile_c
 # the transposed product of the state's update, a last group of heads that
 # hangs over the array's edge (30 heads in groups of 8).
 
-@pytest.mark.parametrize("rows", [4, 2])
-@pytest.mark.parametrize("count", [2, 1])
-def test_delta_kernel_compiles_at_the_cells_rungs(one_chip, no_compile_cache, rows, count):
+# (heads, key and value width a head): olmo_hybrid_rerank's 30 heads of 96 / 192, a group of 8 that hangs over the
+# edge; qwen3_next_80b_rerank's 32 value heads of 128 / 128 (PR 58: every head a lane block), at its three pieces
+DELTA_SHAPES = {"olmo_hybrid": (30, 96, 192, (4, 2), (2, 1)), "qwen3_next": (32, 128, 128, (8, 2), (3,))}
+
+
+@pytest.mark.parametrize("form,rows,count", [
+    (form, rows, count) for form, (_, _, _, rungs, counts) in sorted(DELTA_SHAPES.items())
+    for rows in rungs for count in counts])
+def test_delta_kernel_compiles_at_the_cells_rungs(one_chip, no_compile_cache, form, rows, count):
     from distributed_tf_serving_tpu.ops.delta_kernel import chunk_pass
 
-    heads, length, dk, dv, chunk = 30, 2048, 96, 192, 64
+    heads, dk, dv = DELTA_SHAPES[form][:3]
+    length, chunk = 2048, 64
     shaped = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)  # noqa: E731
     run = functools.partial(chunk_pass, heads=heads, cd=jnp.dtype(jnp.bfloat16), count=count)
     compiled = jax.jit(run).lower(
@@ -534,7 +564,7 @@ def test_delta_kernel_compiles_at_the_cells_rungs(one_chip, no_compile_cache, ro
         shaped(rows, heads, dk, dv)).compile()
     text = compiled.as_text()
     assert 'custom_call_target="tpu_custom_call"' in text and "vmem_limit" not in text
-    assert all(0 < size < DEFAULT_VMEM // 2 for size in kernels_vmem(text, "delta_rule"))  # 6.7 MB
+    assert all(0 < size < DEFAULT_VMEM // 2 for size in kernels_vmem(text, "delta_rule"))  # 6.7 MB; 4.7 MB at 128 / 128
     assert compiled.memory_analysis().generated_code_size_in_bytes < 2 << 20  # one kernel a layer
 
 
@@ -572,7 +602,8 @@ def test_ssd_kernel_compiles_at_the_cells_rungs(one_chip, no_compile_cache, rows
 # cells' top rungs and at the last layer's few tokens, within the 16 MiB of
 # VMEM a kernel has by default; and what the served steps hold since.
 
-# (hidden, an expert's width, tokens a routed layer at all positions[, the compute dtype where not bfloat16])
+# (hidden, an expert's width, tokens a routed layer at all positions[, the compute dtype where not bfloat16
+# [, the experts held and the experts a token where not 8 and 8]])
 GROUPED_SHAPES = {
     "exaone_moe": (6144, 2048, 8192), "pangu_moe": (7680, 2048, 8192), "mimo_v2": (4096, 2048, 8192),
     "exaone_moe_last_layer": (6144, 2048, 4), "pangu_moe_last_layer": (7680, 2048, 8),
@@ -583,28 +614,35 @@ GROUPED_SHAPES = {
     "pangu_moe_float32": (7680, 2048, 8192, jnp.float32), "mimo_v2_float32": (4096, 2048, 8192, jnp.float32),
     # configs/*_moe_small.toml and mimo_v2_small.toml (what `chip_smoke.py --config` serves): an expert half a lane row wide
     "small_tomls": (128, 64, 160),
+    # qwen3_next_80b_rerank (PR 58): 128 of 512 experts held at top-10, the gates' lane row exactly full; the layout's
+    # 1,408 tiles where `[held, T]` rows would be 16,384 tiles (4.3 GB between the kernels)
+    "qwen3_next": (2048, 512, 16384, jnp.bfloat16, 128, 10), "qwen3_next_last_layer": (2048, 512, 8, jnp.bfloat16, 128, 10),
 }
 
 
 @pytest.mark.parametrize("form", sorted(GROUPED_SHAPES))
 def test_grouped_kernels_compile_at_the_cells_top_rungs(one_chip, no_compile_cache, form):
+    from distributed_tf_serving_tpu.models.routed import layout_tiles
     from distributed_tf_serving_tpu.ops.grouped_kernel import TILE, grouped_experts
 
-    hidden, width, tokens, *dtype = GROUPED_SHAPES[form]
-    cd = jnp.dtype(dtype[0] if dtype else jnp.bfloat16)
-    held, padded = 8, -(-tokens // TILE) * TILE
+    hidden, width, tokens, *rest = GROUPED_SHAPES[form]
+    cd = jnp.dtype(rest[0] if rest else jnp.bfloat16)
+    held, top_k = rest[1:] or (8, 8)
+    tiles = layout_tiles(tokens, top_k, held, TILE)
     shaped = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
     run = functools.partial(grouped_experts, cd=cd, count=3)
     compiled = jax.jit(run).lower(
         shaped((held, hidden, width), cd), shaped((held, hidden, width), cd),
         shaped((held, width, hidden), cd), shaped((tokens, hidden), jnp.float32),
-        shaped((tokens, held), jnp.float32), shaped((held, padded), jnp.int32), shaped((held,), jnp.int32)).compile()
+        shaped((tokens, held), jnp.float32), shaped((tiles, TILE), jnp.int32), shaped((tiles,), jnp.int32),
+        shaped((tiles,), jnp.int32), shaped((), jnp.int32)).compile()
     text, memory = compiled.as_text(), compiled.memory_analysis()
     assert text.count('custom_call_target="tpu_custom_call"') == 2 and "vmem_limit" not in text
     assert not re.findall(r"\) while\(", text)  # the routing decides the grids' length, and no loop of XLA's
-    # Worst-case buffers: `[held, T, F]` float32 between the kernels and nothing else the size of the tokens (the
-    # sorted tokens and the sorted result are never made; the tokens' and the result's own bytes are read as they lie).
-    assert memory.temp_size_in_bytes < held * padded * width * 4 + (8 << 20)
+    # Worst-case buffers: `[T x min(k, held) + held x TILE, F]` float32 between the kernels and nothing else the size
+    # of the tokens (the sorted tokens and the sorted result are never made; the tokens' and the result's own bytes
+    # are read as they lie).
+    assert memory.temp_size_in_bytes < tiles * TILE * width * 4 + (8 << 20)
     assert memory.generated_code_size_in_bytes < 2 << 20
 
 
@@ -648,9 +686,12 @@ LOWERED_TEXT = {
     "olmo_hybrid_rerank/olmo_hybrid/outside": "2796b2ffd7c03eba",
     "dcn_v2_ref43/dcn_v2/served": "c4b1ba715cf54e70",
     "dlrm_dcnv2_mlperf/dlrm_dcnv2/served": "9bbd2eb81f11ee6f",
-    "k_exaone_moe_rerank/exaone_moe/outside": "e21defa4c02679fe",
-    "pangu_ultra_moe_rerank/pangu_moe/outside": "e9d6693e5443e71c",
-    "mimo_v2_5_rerank/mimo_v2/outside": "cf958181426246d5",
+    # PR 58: the pairs of a routed layer laid out by ONE sort and walked by ONE loop over their tiles, whatever the
+    # number of experts held, and a fifth counter (`moe.experts_hit`): the three routed families' XLA path is PR 58's
+    # own (e21defa4c02679fe, e9d6693e5443e71c, cf958181426246d5 before it), held here for the next change
+    "k_exaone_moe_rerank/exaone_moe/outside": "64088851d872d0ce",
+    "pangu_ultra_moe_rerank/pangu_moe/outside": "501f02b4922b3960",
+    "mimo_v2_5_rerank/mimo_v2/outside": "08288534fdd2baf4",
 }
 
 
