@@ -66,6 +66,13 @@ every step fills whole lane rows. A chunk that is no whole number of blocks (a
 short row's, a test's) is padded out with zeros to the next one: the inverse
 of [[M, 0], [0, I]] is [[inv(M), 0], [0, I]], and the corner is cut off again.
 
+Value heads over key heads. The rule takes `Hk` key heads under `H` value
+heads, whole groups of `r = H / Hk` (this family's tree holds r = 1;
+qwen3_next's 2). q and k are never repeated: what depends on them alone,
+`K K'` and `Q K'`, is made once a KEY head and read by its r value heads,
+and what stands under `b` or `g` (`D`, `A`, `T`, `W`, `U`, `V'`, the state) is
+a VALUE head's.
+
 Which path computes what, where. `T` (the running sum of g, `K K'`, `A`, the
 block inverse, `diag(b)`) is XLA's everywhere. What follows it is XLA's too
 wherever `model.apply` is traced outside the batcher's one-chip served entry
@@ -290,16 +297,18 @@ def unit_lower_inverse(a: jax.Array) -> jax.Array:
 
 def delta_choice(length: int, count: int, chunk: int = DELTA_CHUNK, heads: tuple[int, int] | None = None) -> dict:
     """`{"kernel": "pallas" | "xla", "chunk", "pieces", "key_heads",
-    "value_heads"}`: which path walks the rule's chunks over rows of `length`
-    positions, the positions a chunk, the pieces an activation enters its
-    products as and the rule's (key, value) `heads` where given. A servable's
-    `startup.delta_rule` stamp. The kernel (ops/delta_kernel.py) runs where a
-    served entry's kernels do (`sequence.kernels_run`): inside the batcher's
-    one-chip entry on a TPU, at every length."""
+    "value_heads", "shared"}`: which path walks the rule's chunks over rows of
+    `length` positions, the positions a chunk, the pieces an activation enters
+    its products as and, where the rule's (key, value) `heads` are given,
+    those and the value heads that read ONE key head's `K K'` and `Q K'`
+    (`shared`: 1 one to one). A servable's `startup.delta_rule` stamp. The
+    kernel (ops/delta_kernel.py) runs where a served entry's kernels do
+    (`sequence.kernels_run`): inside the batcher's one-chip entry on a TPU, at
+    every length."""
     kernel = "pallas" if sequence.kernels_run() else "xla"
     choice = {"kernel": kernel, "chunk": delta_chunks(length, chunk)[0], "pieces": count}
     if heads is not None:
-        choice.update(key_heads=heads[0], value_heads=heads[1])
+        choice.update(key_heads=heads[0], value_heads=heads[1], shared=heads[1] // heads[0])
     return choice
 
 
@@ -313,18 +322,48 @@ def takes_kernel(length: int, count: int, chunk: int = DELTA_CHUNK, heads: tuple
     return choice["kernel"] == "pallas"
 
 
+def shared(x: jax.Array, group: int) -> jax.Array:
+    """A key head's `x [n, Z, Hk, ...]` as each of its `group` value heads
+    reads it, `[n, Z, Hk x group, ...]`; one to one it is x. XLA's path alone
+    calls it, behind `K K'` and `Q K'`, where a value head's own decays meet
+    its key head's q and k."""
+    return x if group == 1 else jnp.repeat(x, group, axis=2)
+
+
 def _chunk_inverse(k: jax.Array, g: jax.Array, b: jax.Array, cd, count: int) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """(G, D, T) of chunks `k [x, y, z, C, dk]`, `g`, `b [x, y, z, C]`: g's
+    """(G, D, T) of chunks `k [n, Z, Hk, C, dk]`, `g`, `b [n, Z, H, C]`: g's
     running sum inside a chunk, `D_ij = exp(G_i - G_j)` where i >= j, else 0,
-    and `T = (I + A)^-1 diag(b)`. The caller's `solve` scope."""
-    chunk = k.shape[-2]
+    and `T = (I + A)^-1 diag(b)`, a VALUE head's as `b` and `g` are. `K K'` is
+    made once a KEY head. Where a key head has a group of `r = H / Hk` value
+    heads, the group is the leading axis of everything a value head's here,
+    `[r, n Z Hk, ..]` beside `K K' [n Z Hk, C, C]`: the fusion that makes `A`
+    reads the one `K K'` for each of the r, and the block inverse is mapped
+    over the axis, which so stays apart from the chunks it batches in its
+    lanes (merged with them, `K K'` is copied out r times first, into a
+    layout padded eightfold: PERF.md section 6, PR 59). The caller's `solve`
+    scope."""
+    n, steps, keys, chunk = k.shape[:4]
+    group = g.shape[2] // keys
+
+    def by_group(x):  # a value head's `[n, Z, H, C]` -> `[r, n Z Hk, C]`
+        return x if group == 1 else jnp.moveaxis(x.reshape(-1, group, chunk), 1, 0)
+
+    def by_head(x):  # `[r, n Z Hk, ...]` -> `[n, Z, H, ...]`
+        return x if group == 1 else jnp.moveaxis(x, 0, 1).reshape((n, steps, keys * group) + x.shape[2:])
+
+    def a_key_heads(x):  # `K K' [n, Z, Hk, C, C]` as it lies beside its value heads
+        return x if group == 1 else x.reshape(-1, chunk, chunk)
+
+    g, b = by_group(g), by_group(b)
     total = jnp.cumsum(g, axis=-1)  # G, the running sum inside a chunk
     i, j = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None, :]
     # D from the difference under the mask
     decay = jnp.exp(jnp.where(j <= i, total[..., :, None] - total[..., None, :], -jnp.inf))
-    a = jnp.where(j < i, b[..., :, None] * sequence.product("nzhid,nzhjd->nzhij", k, k, cd, count) * decay, 0.0)
+    a = jnp.where(
+        j < i, b[..., :, None] * a_key_heads(sequence.product("nzhid,nzhjd->nzhij", k, k, cd, count)) * decay, 0.0)
     # the unit diagonal is taken as read, not read
-    return total, decay, unit_lower_inverse(a) * b[..., None, :]
+    inverse = unit_lower_inverse if group == 1 else jax.vmap(unit_lower_inverse)
+    return by_head(total), by_head(decay), by_head(inverse(a) * b[..., None, :])
 
 
 def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, b: jax.Array,
@@ -342,13 +381,15 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, b: 
     multiple of the chunk is padded with k = v = 0, b = 0, g = 0, which leave
     the state as it is. The caller's `delta_rule` scope.
 
-    The H value heads are whole groups of `H / Hk` a key head: value head h
-    reads the q and k of key head `h // (H / Hk)` (qwen3_next: 32 over 16),
-    which are repeated for its group before the chunk algebra, as the
-    published code does: `b`, `g` and so `T` are a VALUE head's, and only
-    `K K'` and `Q K'` would be shared. One to one (olmo_hybrid) nothing is
-    repeated. Activations enter the products as `count` pieces of `cd`
-    (this module's OPERAND_PIECES where None).
+    The H value heads are whole groups of `r = H / Hk` a key head: value head
+    h reads the q and k of key head `h // r` (qwen3_next: 32 over 16). q and k
+    are never repeated (the published code repeats them for the group before
+    the chunk algebra): what depends on the keys and queries alone, `K K'` and
+    `Q K'`, is made once a KEY head and read by its r value heads (`shared`),
+    and `b`, `g` and so `D`, `T`, `W`, `U` and the state are a VALUE head's.
+    One to one (olmo_hybrid) a key head's group is itself. Activations enter
+    the products as `count` pieces of `cd` (this module's OPERAND_PIECES where
+    None).
 
     Where a one-chip served entry's kernels run (`takes_kernel`) what follows
     `T` is one Pallas kernel a layer that keeps the state in VMEM
@@ -360,8 +401,7 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, b: 
     count = OPERAND_PIECES if count is None else count
     if heads % key_heads:
         raise ValueError(f"{heads} value heads over {key_heads} key heads: whole groups of value heads a key head")
-    if heads != key_heads:
-        q, k = (jnp.repeat(x, heads // key_heads, axis=2) for x in (q, k))
+    group = heads // key_heads
     product = functools.partial(sequence.product, cd=cd, count=count)
     kernel = takes_kernel(length, count, chunk, (key_heads, heads))
     chunk, steps = delta_chunks(length, chunk)
@@ -373,7 +413,7 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, b: 
     def chunks(x):  # [n, L, H, ...] -> [n, steps, H, chunk, ...]: a head's chunk is one matrix
         return jnp.moveaxis(padded(x).reshape((n, steps, chunk) + x.shape[2:]), 3, 2)
 
-    if kernel:  # q, k, v and o cross as they lie, `[n, L, H x d]`: no turn on either side
+    if kernel:  # q, k, v and o cross as they lie, `[n, L, heads x d]`: no turn on either side
         from ..ops import delta_kernel
 
         with jax.named_scope("solve"):
@@ -389,11 +429,12 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, b: 
     with jax.named_scope("solve"):
         total, decay, t = _chunk_inverse(k, g, b, cd, count)
         grown = jnp.exp(total)[..., None]  # exp(G_i) [n, Z, H, C, 1]
-        w = product("nzhij,nzhjd->nzhid", t, k * grown)
+        w = product("nzhij,nzhjd->nzhid", t, shared(k, group) * grown)  # a value head's decays on its key head's k
         u = product("nzhij,nzhje->nzhie", t, v)
-        within = product("nzhid,nzhjd->nzhij", q, k) * decay  # lower((Q K') * D)
+        within = shared(product("nzhid,nzhjd->nzhij", q, k), group) * decay  # lower((Q K') * D), Q K' once a key head
         left = total[..., -1:]  # G_C [n, Z, H, 1]
-        xs = (w, u, q * grown, within, k * jnp.exp(left - total)[..., None], jnp.exp(left)[..., None])
+        xs = (w, u, shared(q, group) * grown, within, shared(k, group) * jnp.exp(left - total)[..., None],
+              jnp.exp(left)[..., None])
 
     def body(state, x):
         w_z, u_z, q_z, within_z, k_z, decay_z = x

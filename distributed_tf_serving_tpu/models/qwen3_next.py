@@ -29,8 +29,9 @@ r x Hk value heads dv wide (16 and 32 at 128 as published):
 
 `olmo_hybrid.gated_delta_rule` computes the rule (its docstring has the chunk
 algebra, the block solve and the Pallas kernel that walks the chunks in a
-one-chip served entry on a TPU), here with a key head's q and k repeated for
-its r value heads and at this family's three pieces.
+one-chip served entry on a TPU), here at this family's three pieces and with
+r = 2 value heads a key head: q and k stay the 16 key heads they are, `K K'`
+and `Q K'` are made once a key head, and its two value heads read them.
 
 full layer (gated attention), `heads` query heads over `kv` key-value heads, d wide:
 

@@ -22,9 +22,17 @@ exponent is a difference under its mask. The state `S [dk, dv]` of the heads
 in flight is float32 VMEM scratch: the start state is copied in at a row's
 chunk 0, the state after the last chunk is written out once, and `W`, `U`,
 `within` and `V'` never exist in HBM. What stays XLA's: the running sum of g,
-`K K'`, `A` and the block inverse that makes `T` (lane-batched over every
-chunk of a layer side by side, which a kernel that walks chunks in order has
-not).
+`K K'` (once a KEY head), `A` and the block inverse that makes `T`
+(lane-batched over every chunk of a layer side by side, which a kernel that
+walks chunks in order has not).
+
+The H value heads are whole groups of `r = H / Hk` a key head (one to one in
+olmo_hybrid, two to one in qwen3_next). q and k cross as the `Hk` key heads
+they are, never repeated: a step's q and k blocks are the `group / r` key
+heads of its `group` value heads, `Q K'` is one product a key head that each
+of its value heads masks with its own `D`, and everything under a value
+head's decays (`K e^G`, `K e^(G_C - G)`, `W`, `U`, `V'`, the state) is a value
+head's.
 
 Operands enter the MXU as `count` pieces of the compute dtype in the pairs
 `i + j < count`, accumulation and everything else is float32, and the state
@@ -33,11 +41,12 @@ path's carry is: the result is that path's to float32 rounding in another
 order of additions (tests/test_delta_kernel.py, interpreted on the CPU;
 tests/test_tpu_compile.py compiles it for a v5e).
 
-q, k, v and o cross the kernel as they lie, `[n, L, H x d]`, with no turn on
-either side: a block is a chunk's rows and the columns of a GROUP of heads,
-the fewest whose keys and whose values are whole lanes side by side (4 at dk
-96, dv 192; a block of one head's 96 columns is no legal block of that array),
-twice that here. A head's tile is a static slice of the block's lanes. Where
+q, k, v and o cross the kernel as they lie, `[n, L, heads x d]`, with no turn
+on either side: a block is a chunk's rows and the columns of a GROUP of value
+heads (of its key heads, for q and k), the fewest whole key heads' groups
+whose keys and whose values are whole lanes side by side (4 at dk 96, dv 192;
+a block of one head's 96 columns is no legal block of that array), twice that
+here. A head's tile is a static slice of the block's lanes. Where
 the heads are no whole number of groups (30 of 8) the last group's block hangs
 over the array's edge: what it reads there no head's result reads, and what it
 writes there is dropped. (Head-major `[n, H, L, d]` blocks of one head are
@@ -88,11 +97,13 @@ def _product(xs: list, ys: list, dims=NN) -> jax.Array:
     return out
 
 
-def _chunk(across, q, k, v, t, s, *, cut, diagonal, lower, state_dtype):
-    """One head's chunk: (`O [C, dv]`, the state after it) from the chunk's
-    running sum `across [1, C]`, `q`, `k [C, dk]`, `v [C, dv]`, `t [C, C]`
-    and the state before it `s [dk, dv]`; `diagonal` and `lower` are the
-    `[C, C]` masks i == j and i >= j."""
+def _chunk(across, q, k, v, t, s, scores, *, cut, diagonal, lower, state_dtype):
+    """One value head's chunk: (`O [C, dv]`, the state after it, `Q K'`) from
+    the chunk's running sum `across [1, C]`, its key head's `q`, `k [C, dk]`,
+    `v [C, dv]`, `t [C, C]`, the state before it `s [dk, dv]` and `scores`,
+    the key head's `Q K' [C, C]` where a value head before this one has made
+    it, else None: made here then, and handed back for the next;
+    `diagonal` and `lower` are the `[C, C]` masks i == j and i >= j."""
     chunk = q.shape[0]
     down = jnp.sum(jnp.where(diagonal, across, 0.0), axis=1, keepdims=True)  # G_i [C, 1] of G_j [1, C]
     left = down[chunk - 1:chunk, :]  # G_C, [1, 1]
@@ -102,16 +113,18 @@ def _chunk(across, q, k, v, t, s, *, cut, diagonal, lower, state_dtype):
     t = cut(t)
     w = _product(t, cut(k * grown))
     u = _product(t, cut(v))
-    within = _product(cut(q), cut(k), NT) * decay
+    if scores is None:
+        scores = _product(cut(q), cut(k), NT)
+    within = scores * decay  # each value head masks its key head's Q K' with its own D
     both = _product(cut(jnp.concatenate([w, q * grown], axis=0)), cut(s))  # [W; Q e^G] S
     fresh = cut(u - both[:chunk])  # V'
     o = both[chunk:] + _product(cut(within), fresh)
     s = jnp.exp(left) * s + _product(cut(k * jnp.exp(left - down)), fresh, TN)
-    return o, s.astype(state_dtype).astype(jnp.float32)
+    return o, s.astype(state_dtype).astype(jnp.float32), scores
 
 
 def _kernel(total_ref, q_ref, k_ref, v_ref, t_ref, start_ref, o_ref, end_ref, state, *,
-            held, cd, state_dtype, chunk, heads, dk, dv):
+            held, cd, state_dtype, chunk, heads, shared, dk, dv):
     z = pl.program_id(2)
     i = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     j = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
@@ -123,22 +136,28 @@ def _kernel(total_ref, q_ref, k_ref, v_ref, t_ref, start_ref, o_ref, end_ref, st
     def _start():
         state[...] = start_ref[...]
 
-    for h in range(heads):  # a head's columns of the step's lanes
-        keys, values = slice(h * dk, (h + 1) * dk), slice(h * dv, (h + 1) * dv)
-        o_ref[:, values], state[h] = chunk_of(
-            total_ref[h, pl.ds(z, 1), :], q_ref[:, keys], k_ref[:, keys], v_ref[:, values], t_ref[h], state[h])
+    for h in range(heads):  # a value head's columns of the step's lanes, and its key head's
+        key = h // shared
+        keys, values = slice(key * dk, (key + 1) * dk), slice(h * dv, (h + 1) * dv)
+        if h % shared == 0:  # Q K' is one product a key head: its first value head makes it
+            scores = None
+        o_ref[:, values], state[h], scores = chunk_of(
+            total_ref[h, pl.ds(z, 1), :], q_ref[:, keys], k_ref[:, keys], v_ref[:, values], t_ref[h], state[h], scores)
 
     @pl.when(z == pl.num_programs(2) - 1)
     def _end():
         end_ref[...] = state[...]
 
 
-def heads_a_step(heads: int, dk: int, dv: int) -> int:
-    """Heads a grid step takes: a multiple of the fewest whose keys and whose
-    values are whole lanes side by side (4 at dk 96, dv 192), HEADS or the
-    next one up; every head where that is no fewer than all of them (a test's
-    narrow heads: the whole axis is a legal block whatever its width)."""
-    aligned = next(g for g in range(1, LANES + 1) if g * dk % LANES == 0 and g * dv % LANES == 0)
+def heads_a_step(heads: int, dk: int, dv: int, shared: int = 1) -> int:
+    """Value heads a grid step takes, `shared` of them reading one key head: a
+    multiple of the fewest whole key heads' groups whose keys and whose values
+    are whole lanes side by side (4 at dk 96, dv 192 one to one; 2 over 1 at
+    128 / 128 two to one), HEADS or the next one up; every head where that is
+    no fewer than all of them (a test's narrow heads: the whole axis is a
+    legal block whatever its width)."""
+    aligned = next(g for g in range(shared, shared * LANES + 1, shared)
+                   if g // shared * dk % LANES == 0 and g * dv % LANES == 0)
     group = aligned * -(-HEADS // aligned)
     return group if group < heads else heads
 
@@ -150,39 +169,44 @@ def chunk_pass(total, q, k, v, t, start, *, heads: int, cd, count: int, state_dt
     state after the last chunk `[n, H, dk, dv]`, float32.
 
     total  `[n, H, Z, C]` float32, g's running sum inside each of a row's Z chunks of C positions
-    q, k   `[n, L, H x dk]` float32 as the projections lie, L = Z x C (the caller pads: k = v = 0, g = 0
-           and a zero column of `t` leave the state as it is); v `[n, L, H x dv]`
+    q, k   `[n, L, Hk x dk]` float32 as the projections lie, L = Z x C (the caller pads: k = v = 0, g = 0
+           and a zero column of `t` leave the state as it is), a key head for each `H / Hk` value heads in
+           a row; v `[n, L, H x dv]`
     t      `[n, Z, H, C, C]` float32, a chunk's `T = (I + A)^-1 diag(b)`
     start  `[n, H, dk, dv]` float32, the state before the first position
 
     Activations enter the products as `count` pieces of `cd` in the pairs
     `i + j < count`; the state is rounded to `state_dtype` after every chunk."""
     n, _, steps, chunk = total.shape
-    dk, dv = q.shape[-1] // heads, v.shape[-1] // heads
-    held, group = pieces_held(cd, count), heads_a_step(heads, dk, dv)
+    dk, dv = start.shape[2], v.shape[-1] // heads
+    shared = heads * dk // q.shape[-1]  # the value heads that read one key head
+    held, group = pieces_held(cd, count), heads_a_step(heads, dk, dv, shared)
     pairs = held * (held + 1) // 2
 
-    def lanes(d):  # a chunk's rows, the group's columns
-        return pl.BlockSpec((None, chunk, group * d), lambda b, g, z: (b, z, g))
+    def lanes(columns):  # a chunk's rows, the columns of the group's heads side by side
+        return pl.BlockSpec((None, chunk, columns), lambda b, g, z: (b, z, g))
 
     def a_row(*shape):  # fetched once a (row, group of heads): the index does not move with the chunk
         return pl.BlockSpec((None, group) + shape, lambda b, g, z: (b, g, 0, 0))
 
-    products = chunk * chunk * (2 * dk + 2 * dv) + 3 * chunk * dk * dv
+    # value heads [g group, (g + 1) group) read key heads [g group / r, (g + 1) group / r): the same index map
+    keys, values = lanes(group // shared * dk), lanes(group * dv)
+    products = heads * (chunk * chunk * (dk + 2 * dv) + 3 * chunk * dk * dv) + heads // shared * chunk * chunk * dk
     return pl.pallas_call(
-        functools.partial(_kernel, held=held, cd=cd, state_dtype=state_dtype, chunk=chunk, heads=group, dk=dk, dv=dv),
+        functools.partial(_kernel, held=held, cd=cd, state_dtype=state_dtype, chunk=chunk, heads=group, shared=shared,
+                          dk=dk, dv=dv),
         out_shape=(jax.ShapeDtypeStruct(v.shape, jnp.float32), jax.ShapeDtypeStruct(start.shape, jnp.float32)),
         # The last group may hold heads past the last: what it reads for them is
         # no number anyone reads, and what it writes for them is dropped.
         grid=(n, -(-heads // group), steps),
-        in_specs=[a_row(steps, chunk), lanes(dk), lanes(dk), lanes(dv),
+        in_specs=[a_row(steps, chunk), keys, keys, values,
                   pl.BlockSpec((None, None, group, chunk, chunk), lambda b, g, z: (b, z, g, 0, 0)), a_row(dk, dv)],
-        out_specs=(lanes(dv), a_row(dk, dv)),
+        out_specs=(values, a_row(dk, dv)),
         scratch_shapes=[pltpu.VMEM((group, dk, dv), jnp.float32)],
         # A row's chunks in order: the state in scratch is the last chunk's.
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
-            flops=2 * pairs * n * heads * steps * products,
+            flops=2 * pairs * n * steps * products,
             transcendentals=n * heads * steps * chunk * (chunk + 3),
             bytes_accessed=4 * (total.size + q.size + k.size + 2 * v.size + t.size + 2 * start.size)),
         interpret=interpret,

@@ -502,7 +502,8 @@ def test_a_request_through_the_interpreted_kernels_scores_like_the_reference(ref
     batch = dict(arrays, feat_ids=(arrays["feat_ids"] % config.vocab_size).astype(np.int32))
     want = reference_scores(reference, servable.params, batch, config)
     assert np.max(np.abs(got["prediction_node"] - want)) < tolerance
-    assert startup["delta_rule"] == {"M:1": {"kernel": "pallas", "chunk": 64, "pieces": 2, "key_heads": 3, "value_heads": 3}}
+    assert startup["delta_rule"] == {
+        "M:1": {"kernel": "pallas", "chunk": 64, "pieces": 2, "key_heads": 3, "value_heads": 3, "shared": 1}}
     assert startup["attention"]["M:1"]["kernel"] == "pallas" and batcher.stats.delta_kernel_batches == 1
     delta = {name: after[name] - before.get(name, 0) for name in servable.model.step_stats if name.startswith("delta.")}
     assert delta == {"delta.rows": 3, "delta.handovers": 3 * 6 * 3, "delta.positions": 3 * 6 * 150}

@@ -159,7 +159,7 @@ def test_the_chunked_rule_at_two_value_heads_a_key_head_is_the_recurrence(refere
         def served():
             with interpreted([], delta=(notes := [])):
                 out = rule()
-            assert notes == [{"kernel": "pallas", "chunk": 64, "pieces": 3, "key_heads": 2, "value_heads": 4}]
+            assert notes == [{"kernel": "pallas", "chunk": 64, "pieces": 3, "key_heads": 2, "value_heads": 4, "shared": 2}]
             return out
 
         through_the_kernel = np.asarray(jax.jit(served)())
@@ -290,9 +290,9 @@ def test_held_experts_is_the_sum_an_expert(held, kernel):
 
 def test_the_delta_kernel_at_128_wide_heads_and_two_value_heads_a_key_head():
     """ops/delta_kernel.py at dk = dv = 128 (every head a lane block: a group
-    of 8 by its own rule) against XLA's chunk walk, the key heads' q and k
-    repeated for their value heads."""
-    assert delta_kernel.heads_a_step(32, 128, 128) == 8 and delta_kernel.heads_a_step(4, 128, 128) == 4
+    of 8 value heads over 4 key heads by its own rule) against XLA's chunk
+    walk, a key head's q and k read by its two value heads as they lie."""
+    assert delta_kernel.heads_a_step(32, 128, 128, 2) == 8 and delta_kernel.heads_a_step(4, 128, 128, 2) == 4
     rng = np.random.default_rng(7)
     n, length, keys, values, d = 1, 128, 2, 4, 128
     q = olmo_hybrid.l2_norm(jnp.asarray(rng.standard_normal((n, length, keys, d)), jnp.float32)) * d ** -0.5
@@ -481,7 +481,8 @@ def test_the_batcher_stamps_the_three_choices_and_counts_the_new_counter(monkeyp
         batcher.stop()
     assert scores.shape == (2,) and np.isfinite(scores).all()
     assert startup["attention"]["Q:1"] == {"kernel": "pallas", "block": 256, "pieces": 3}
-    assert startup["delta_rule"]["Q:1"] == {"kernel": "pallas", "chunk": 64, "pieces": 3, "key_heads": 2, "value_heads": 4}
+    assert startup["delta_rule"]["Q:1"] == {
+        "kernel": "pallas", "chunk": 64, "pieces": 3, "key_heads": 2, "value_heads": 4, "shared": 2}
     grouped = startup["grouped"]["Q:1"]
     assert grouped == {"kernel": "pallas", "tile": 128, "pieces": 3, "held": 4, "rows": grouped["rows"]}
     assert grouped["rows"] == routed.layout_tiles(2 * config.num_fields, 4, 4, 128) * 128

@@ -543,23 +543,25 @@ def test_attention_kernel_compiles_at_the_cells_top_rungs(one_chip, no_compile_c
 # the transposed product of the state's update, a last group of heads that
 # hangs over the array's edge (30 heads in groups of 8).
 
-# (heads, key and value width a head): olmo_hybrid_rerank's 30 heads of 96 / 192, a group of 8 that hangs over the
-# edge; qwen3_next_80b_rerank's 32 value heads of 128 / 128 (PR 58: every head a lane block), at its three pieces
-DELTA_SHAPES = {"olmo_hybrid": (30, 96, 192, (4, 2), (2, 1)), "qwen3_next": (32, 128, 128, (8, 2), (3,))}
+# (key heads, value heads, key and value width a head): olmo_hybrid_rerank's 30 heads of 96 / 192 one to one, a group
+# of 8 that hangs over the edge; qwen3_next_80b_rerank's 32 value heads over 16 key heads of 128 / 128 (PR 58: every
+# head a lane block; PR 59: a step's 8 value heads read the 4 key heads' 512 lanes of q and k as they lie), at its
+# three pieces
+DELTA_SHAPES = {"olmo_hybrid": (30, 30, 96, 192, (4, 2), (2, 1)), "qwen3_next": (16, 32, 128, 128, (8, 2), (3,))}
 
 
 @pytest.mark.parametrize("form,rows,count", [
-    (form, rows, count) for form, (_, _, _, rungs, counts) in sorted(DELTA_SHAPES.items())
+    (form, rows, count) for form, (*_, rungs, counts) in sorted(DELTA_SHAPES.items())
     for rows in rungs for count in counts])
 def test_delta_kernel_compiles_at_the_cells_rungs(one_chip, no_compile_cache, form, rows, count):
     from distributed_tf_serving_tpu.ops.delta_kernel import chunk_pass
 
-    heads, dk, dv = DELTA_SHAPES[form][:3]
+    keys, heads, dk, dv = DELTA_SHAPES[form][:4]
     length, chunk = 2048, 64
     shaped = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)  # noqa: E731
     run = functools.partial(chunk_pass, heads=heads, cd=jnp.dtype(jnp.bfloat16), count=count)
     compiled = jax.jit(run).lower(
-        shaped(rows, heads, length // chunk, chunk), shaped(rows, length, heads * dk), shaped(rows, length, heads * dk),
+        shaped(rows, heads, length // chunk, chunk), shaped(rows, length, keys * dk), shaped(rows, length, keys * dk),
         shaped(rows, length, heads * dv), shaped(rows, length // chunk, heads, chunk, chunk),
         shaped(rows, heads, dk, dv)).compile()
     text = compiled.as_text()
@@ -692,6 +694,10 @@ LOWERED_TEXT = {
     "k_exaone_moe_rerank/exaone_moe/outside": "64088851d872d0ce",
     "pangu_ultra_moe_rerank/pangu_moe/outside": "501f02b4922b3960",
     "mimo_v2_5_rerank/mimo_v2/outside": "08288534fdd2baf4",
+    # PR 59: the rule's key-side work once a KEY head (q and k the 16 key heads they are into the kernel, `K K'` and
+    # `Q K'` made for a key head and read by its two value heads); olmo_hybrid's two digests above, one key head a
+    # value head, passed that change untouched. PR 59's own, held here for the next change to be seen against
+    "qwen3_next_80b_rerank/qwen3_next/served": "7b7b2b0f57ed4461",
 }
 
 
