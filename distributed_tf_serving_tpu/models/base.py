@@ -32,8 +32,8 @@ Batch = dict[str, jax.Array]
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
-    """Knob set shared by the CTR model zoo and the seven sequence families
-    (phi4flash, pangu_moe, exaone_moe, olmo_hybrid, mimo_v2, falcon_h1, qwen3_next), whose keys
+    """Knob set shared by the CTR model zoo and the eight sequence families
+    (phi4flash, pangu_moe, exaone_moe, olmo_hybrid, mimo_v2, falcon_h1, qwen3_next, nemotron_h), whose keys
     carry the names of their published config.json and whose defaults build a
     small valid model.
 
@@ -199,6 +199,24 @@ class ModelConfig:
     full_attention_interval: int = 4
     shared_expert_intermediate_size: int = 64
     norm_topk_prob: bool = True
+    # nemotron_h (models/nemotron_h.py): a row is num_fields token ids as
+    # above, and the shared keys mean what they mean elsewhere: embed_dim the
+    # hidden size, num_attention_heads / num_key_value_heads / head_dim the
+    # attention layers' (no rotary turn: rope_theta is not read), the mamba_*
+    # keys the Mamba-2 layers' as falcon_h1 reads them, n_routed_experts the
+    # ROUTER's width (as pangu_moe reads it), num_experts_per_tok,
+    # routed_scaling_factor, norm_topk_prob, experts_held, first_expert_held,
+    # moe_intermediate_size an expert's width, layer_norm_eps. Every layer is
+    # ONE mixer. Under the published config.json's names: the kind of every
+    # layer, a letter each (`M` Mamba-2, `*` attention, `E` the routed block;
+    # the layers run are the first num_hidden_layers), the width of the latent
+    # the routed experts live in, between two projections every token meets,
+    # and the width of the one shared expert, which reads the full width.
+    # Both kinds of expert are ungated, relu(x U)^2 D: the family's
+    # (mlp_hidden_act relu2 as published).
+    hybrid_override_pattern: str = "MEMEMEM*EMEM"  # the published pattern's first twelve
+    moe_latent_size: int = 32
+    moe_shared_expert_intermediate_size: int = 64
     # numerics
     compute_dtype: str = "bfloat16"  # "float32" for AUC-parity mode
     param_dtype: str = "float32"
@@ -257,13 +275,13 @@ class Model:
     # beside the id/weight pair (the DLRM families).
     takes_dense: bool = False
     # The kind of every layer of a sequence family (phi4flash, pangu_moe, exaone_moe,
-    # olmo_hybrid, mimo_v2, falcon_h1, qwen3_next), whose rows are num_fields TOKENS;
+    # olmo_hybrid, mimo_v2, falcon_h1, qwen3_next, nemotron_h), whose rows are num_fields TOKENS;
     # empty for the CTR families.
     layer_plan: tuple[str, ...] = ()
     # What a family with a routed layer holds of it, as (name, number) pairs:
     # published, held, first, top_k, heads_published, heads_held,
-    # chips_sharing_layer (pangu_moe, exaone_moe, mimo_v2, qwen3_next); empty for every
-    # other family.
+    # chips_sharing_layer (pangu_moe, exaone_moe, mimo_v2, qwen3_next, nemotron_h); empty
+    # for every other family.
     expert_plan: tuple[tuple[str, int], ...] = ()
     # For a family whose mixer differs by layer, as (name, value) pairs a
     # layer: an attention layer's kind, window, block of queries and keys a
@@ -274,12 +292,15 @@ class Model:
     # kv_heads, rotary_dims, theta and output gate);
     # falcon_h1's layers, which hold both, state the attention's entry and
     # beside it `ssd`, the SSM's (kind, chunk, hand-overs and state bytes a
-    # row); empty for every other family.
+    # row); nemotron_h's layers, one mixer each, state a Mamba-2 layer's entry
+    # as falcon_h1's `ssd`, an attention layer's as a full one's (rotary_dims
+    # 0) and a routed layer's kind, latent width and experts' form; empty for
+    # every other family.
     attention_plan: tuple[tuple[tuple[str, object], ...], ...] = ()
     # For a family whose step counts what it did on the device (pangu_moe's,
-    # exaone_moe's, mimo_v2's and qwen3_next's routing, exaone_moe's, olmo_hybrid's,
+    # exaone_moe's, mimo_v2's, qwen3_next's and nemotron_h's routing, exaone_moe's, olmo_hybrid's,
     # mimo_v2's and qwen3_next's score tiles, olmo_hybrid's, qwen3_next's and falcon_h1's state hand-overs,
-    # falcon_h1's score tiles, mimo_v2's sinks): `apply_stats(params, batch) -> (apply's outputs, int32
+    # falcon_h1's and nemotron_h's score tiles and state hand-overs, mimo_v2's sinks): `apply_stats(params, batch) -> (apply's outputs, int32
     # [len(step_stats)])`, the counters named by `step_stats` in order. The
     # batcher decides on it when it BUILDS the servable's entry: the counters
     # then ride back beside the scores and are recorded as phases by count.
@@ -381,7 +402,7 @@ def register_model(kind: str):
 def build_model(kind: str, config: ModelConfig | None = None, **overrides) -> Model:
     """Instantiate a model family by kind: dcn, dcn_v2, wide_deep, deepfm,
     two_tower, dlrm, dlrm_dcnv2, phi4flash, pangu_moe, exaone_moe, olmo_hybrid, mimo_v2,
-    falcon_h1, qwen3_next."""
+    falcon_h1, qwen3_next, nemotron_h."""
     if kind not in _BUILDERS:
         raise KeyError(f"unknown model kind {kind!r}; have {sorted(_BUILDERS)}")
     if config is None:

@@ -31,8 +31,14 @@ one into a bfloat16 weight is another rounding and is not done):
   h = x + att + ssm
   y = h + ((silu((b W_gate) * mlp_multipliers[0]) * (b W_up)) W_down) * mlp_multipliers[1],  b = RMS_ff(h)
 
+`ssm`, `ssd` and the blocks under them also serve nemotron_h's Mamba-2 layers
+(models/nemotron_h.py: the same mixer alone in a layer, every multiplier 1,
+128 heads of 64 over 8 groups of 16 with a `[64, 128]` state, 10,240 convolved
+channels, three pieces), which hands in its own sizes and piece count.
+
 The state is a `[P, N]` matrix a head whose decay is a scalar a head and
-position (`[128, 256]` over 32 heads, 4.19 MB a row, at the published widths):
+position (`[128, 256]` over 32 heads, 4.19 MB a row, at the published widths;
+nemotron_h's `[64, 128]` over 128 heads are the same bytes):
 position by position a row would carry it through memory L times a layer. `ssd`
 computes the recurrence a chunk of `mamba_chunk_size` positions at a time,
 exactly (in real arithmetic). With `cum_i` the running sum of `dt A` inside a
@@ -49,7 +55,8 @@ multiply-add of it (`ssd.handovers`: one a chunk, row and layer): the chunks'
 own states and the states handed in pass through memory. Inside a one-chip
 served entry on a TPU (`takes_kernel`, by `sequence.kernels_run`; the servable's
 `startup.ssd` stamp says which) the walk at all positions is ONE Pallas kernel
-a layer (ops/ssd_kernel.py): a grid over (row, group of heads, chunk) whose
+a layer (ops/ssd_kernel.py): a grid over (row, group of heads, chunk: 8 heads
+a step here, a whole group of 16 of nemotron_h's narrower ones) whose
 states stay in VMEM from a row's first chunk to its last, x, B, C and y
 crossing as the convolution leaves them; the same pieces in the same pairs,
 float32 sums in another order (tests/test_ssd_kernel.py). A length that is no
@@ -80,6 +87,7 @@ read of the state handed in) included.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import numpy as np
@@ -184,14 +192,15 @@ def _layer_init(rng, s: dict, dtype) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def _product(spec: str, x: jax.Array, y: jax.Array, cd) -> jax.Array:
-    """einsum(spec, x, y) as `sequence.product`, at this family's pieces."""
-    return sequence.product(spec, x, y, cd, OPERAND_PIECES)
+def _product(spec: str, x: jax.Array, y: jax.Array, cd, count: int | None = None) -> jax.Array:
+    """einsum(spec, x, y) as `sequence.product`, at the caller's pieces (this
+    family's where it names none)."""
+    return sequence.product(spec, x, y, cd, OPERAND_PIECES if count is None else count)
 
 
-def _dot(x: jax.Array, w: jax.Array, cd) -> jax.Array:
-    """`routed.dot` at this family's pieces."""
-    return routed.dot(x, w, cd, OPERAND_PIECES)
+def _dot(x: jax.Array, w: jax.Array, cd, count: int | None = None) -> jax.Array:
+    """`routed.dot` at the caller's pieces (this family's where it names none)."""
+    return routed.dot(x, w, cd, OPERAND_PIECES if count is None else count)
 
 
 def slice_multipliers(s: dict) -> np.ndarray:
@@ -239,13 +248,15 @@ def takes_kernel(last_only: bool = False) -> bool:
 
 
 def ssd_choice(length: int, s: dict, last_only: bool = False) -> dict:
-    """`{"path": "pallas" | "xla", "chunk", "state_bytes_a_row"}`: how the SSD
-    walks rows of `length` positions: which path (`takes_kernel`; XLA's scan
-    hands the state over through HBM), the positions a chunk and the float32
-    bytes of a row's state (every head's `[P, N]`) that a hand-over carries. A
-    servable's `startup.ssd` stamp."""
+    """`{"path": "pallas" | "xla", "chunk", "state_bytes_a_row", "heads"}`: how
+    the SSD walks rows of `length` positions: which path (`takes_kernel`;
+    XLA's scan hands the state over through HBM), the positions a chunk, the
+    float32 bytes of a row's state that a hand-over carries and the heads'
+    shape, `[H, P, N]`: H heads, each a `[P, N]` state. A servable's
+    `startup.ssd` stamp."""
     return {"path": "pallas" if takes_kernel(last_only) else "xla", "chunk": ssd_chunks(length, s["chunk"])[0],
-            "state_bytes_a_row": s["ssm_heads"] * s["ssm_head"] * s["state"] * 4}
+            "state_bytes_a_row": s["ssm_heads"] * s["ssm_head"] * s["state"] * 4,
+            "heads": [s["ssm_heads"], s["ssm_head"], s["state"]]}
 
 
 def note_ssd(length: int, s: dict, last_only: bool = False) -> None:
@@ -258,7 +269,7 @@ def note_ssd(length: int, s: dict, last_only: bool = False) -> None:
 
 def ssd(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array,
         initial_state: jax.Array | None = None, *, chunk: int, cd=jnp.float32,
-        last_only: bool = False) -> tuple[jax.Array, jax.Array]:
+        last_only: bool = False, count: int | None = None) -> tuple[jax.Array, jax.Array]:
     """Mamba-2's recurrence over rows of L positions, chunked (the module's
     docstring has the algebra):
 
@@ -269,7 +280,9 @@ def ssd(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array,
     is S before the first position (zero where None). Returns `y [n, L, H, P]`
     and the state after the last position, float32. With `last_only`, `y` is
     `[n, 1, H, P]`, the last position's alone: the state's hand-overs are made
-    and the chunks' own products are not. The caller's `ssd` scope.
+    and the chunks' own products are not. Activations enter the products as
+    `count` pieces (this family's OPERAND_PIECES where None). The caller's
+    `ssd` scope.
 
     Where a one-chip served entry's kernels run (`takes_kernel`) the walk at
     all positions is one Pallas kernel a layer that keeps the states in VMEM
@@ -279,6 +292,8 @@ def ssd(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array,
     n, length, heads, width = x.shape
     groups, state_width = b.shape[2], b.shape[3]
     per = heads // groups
+    count = OPERAND_PIECES if count is None else count
+    product = functools.partial(_product, count=count)
     c_last = c[:, -1]
     chunk, steps = ssd_chunks(length, chunk)
     pad = steps * chunk - length
@@ -299,7 +314,7 @@ def ssd(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array,
                 dt, jnp.cumsum(dt * a.astype(jnp.float32)[:, None, None], axis=3),
                 *(padded(v).reshape(n, steps * chunk, -1) for v in (x, b, c)),
                 state.astype(STATE_DTYPE).astype(jnp.float32), heads=heads, groups=groups, cd=jnp.dtype(cd),
-                count=OPERAND_PIECES, state_dtype=jnp.dtype(STATE_DTYPE), interpret=sequence.served_entry().interpret)
+                count=count, state_dtype=jnp.dtype(STATE_DTYPE), interpret=sequence.served_entry().interpret)
         return y.reshape(n, steps * chunk, heads, width)[:, :length], state
     x = chunks(x).reshape(n, steps, chunk, groups, per, width)
     dt = chunks(dt).reshape(n, steps, chunk, groups, per)
@@ -308,7 +323,7 @@ def ssd(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array,
     left = total[:, :, -1:]  # cum_last
     fed = x * dt[..., None]  # dt x
     with jax.named_scope("states"):  # what each chunk adds to the state it is handed, all chunks at once
-        local = _product("nzcgjp,nzcgs->nzgjps", fed * jnp.exp(left - total)[..., None], b, cd)
+        local = product("nzcgjp,nzcgs->nzgjps", fed * jnp.exp(left - total)[..., None], b, cd)
     with jax.named_scope("handover"):
         def body(state, step):
             local_z, decay_z = step
@@ -322,29 +337,33 @@ def ssd(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array,
         state = state.astype(jnp.float32)
     if last_only:
         with jax.named_scope("read"):
-            y = _product("ngjps,ngs->ngjp", state, c_last, cd)
+            y = product("ngjps,ngs->ngjp", state, c_last, cd)
         return y.reshape(n, 1, heads, width), state.reshape(n, heads, width, state_width)
     with jax.named_scope("within"):  # the chunk's own positions: (M o (C B')) (dt x)
         i, j = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None, :]
         falling = jnp.moveaxis(total, 2, -1)  # [n, Z, G, J, C]
         decay = jnp.exp(jnp.where(j <= i, falling[..., :, None] - falling[..., None, :], -jnp.inf))
-        scores = _product("nzigs,nzcgs->nzgic", c, b, cd)  # C B', once a group
-        y = _product("nzgjic,nzcgjp->nzigjp", decay * scores[:, :, :, None], fed, cd)
+        scores = product("nzigs,nzcgs->nzgic", c, b, cd)  # C B', once a group
+        y = product("nzgjic,nzcgjp->nzigjp", decay * scores[:, :, :, None], fed, cd)
     with jax.named_scope("read"):  # the state handed in, read by every position of the chunk
         entered = jnp.moveaxis(entered, 0, 1).astype(jnp.float32)  # [n, Z, G, J, P, N]
-        y = y + _product("nzgjps,nzcgs->nzcgjp", entered, c, cd) * jnp.exp(total)[..., None]
+        y = y + product("nzgjps,nzcgs->nzcgjp", entered, c, cd) * jnp.exp(total)[..., None]
     return y.reshape(n, steps * chunk, heads, width)[:, :length], state.reshape(n, heads, width, state_width)
 
 
-def ssm(p: dict, a: jax.Array, s: dict, cd, eps: float, last_only: bool = False) -> jax.Array:
+def ssm(p: dict, a: jax.Array, s: dict, cd, eps: float, last_only: bool = False,
+        count: int | None = None) -> jax.Array:
     """One layer's Mamba-2 mixer of the normed `a [n, L, hidden]`: `[n, L, hidden]`,
     or `[n, 1, hidden]` where the last position's output alone is asked for
-    (the state still walks every position). The caller's `ssm` scope."""
+    (the state still walks every position). `s` holds the mixer's sizes and
+    multipliers (`_sizes`' keys `hidden` to `ssm_mults`; nemotron_h hands in
+    its own, every multiplier 1) and `count` the pieces an activation enters
+    a product as (this family's where None). The caller's `ssm` scope."""
     n, length, _ = a.shape
     heads, width, groups, state = s["ssm_heads"], s["ssm_head"], s["groups"], s["state"]
     note_ssd(length, s, last_only)
     with jax.named_scope("in_proj"):
-        projected = _dot(a * s["ssm_in"], p["in"], cd) * slice_multipliers(s)
+        projected = _dot(a * s["ssm_in"], p["in"], cd, count) * slice_multipliers(s)
         z, mixed, dt = jnp.split(projected, (s["d_ssm"], s["d_ssm"] + s["channels"]), axis=-1)
     with jax.named_scope("conv"):
         mixed = sequence.causal_conv(mixed, p["conv_w"], p["conv_b"])
@@ -352,14 +371,14 @@ def ssm(p: dict, a: jax.Array, s: dict, cd, eps: float, last_only: bool = False)
         x = x.reshape(n, length, heads, width)
     with jax.named_scope("ssd"):
         y, _ = ssd(x, time_steps(p, dt), -jnp.exp(p["A_log"].astype(jnp.float32)), b.reshape(n, length, groups, state),
-                   c.reshape(n, length, groups, state), chunk=s["chunk"], cd=cd, last_only=last_only)
+                   c.reshape(n, length, groups, state), chunk=s["chunk"], cd=cd, last_only=last_only, count=count)
         if last_only:
             x, z = sequence.last_position(x, z)
         y = skip(p, y, x)
     with jax.named_scope("gate_norm"):
         y = gated_norm(p, y.reshape(n, -1, s["d_ssm"]), z, s, eps)
     with jax.named_scope("out_proj"):
-        return _dot(y, p["out"], cd) * s["ssm_out"]
+        return _dot(y, p["out"], cd, count) * s["ssm_out"]
 
 
 def attention(p: dict, a: jax.Array, s: dict, cd, last_only: bool = False) -> jax.Array:

@@ -1,12 +1,17 @@
 """What the routed sequence-ranker families (pangu_moe, exaone_moe, mimo_v2,
-qwen3_next) share beside `sequence`'s products and blocks: the product with a
-weight under its own name (`dot`: `sequence.product` against a weight, the
-families' three pieces its stacked form), the RMSNorm, the gated MLP, the
-rotary turn, the router (sigmoid scores or a softmax, as the family says) and
-the held experts' grouped product with its counters. One implementation, so
-that a change to any of them is measured on all four families' cells, whose
-hidden sizes (7680, 6144, 4096, 2048), held experts (8, 8, 8, 128) and loads
-an expert (256 tokens a step, 512, 256, 320) differ.
+qwen3_next, nemotron_h) share beside `sequence`'s products and blocks: the
+product with a weight under its own name (`dot`: `sequence.product` against a
+weight, the families' three pieces its stacked form), the RMSNorm, an expert
+of either form (the gated MLP, `silu(x G) * (x U)` through `D`: four
+families'; the ungated one, `relu(x U)^2 D`: nemotron_h's; `expert_form`
+reads which from the expert's own tree, a `gate` leaf or none), the rotary
+turn, the router (sigmoid scores or a softmax, as the family says; with a
+selection bias where the family has one) and the held experts' grouped
+product with its counters. One implementation, so that a change to any of
+them is measured on all five families' cells, whose rows into the experts
+(the residual's 7680, 6144, 4096, 2048; nemotron_h's a LATENT's 1024 under a
+residual of 4096), held experts (8, 8, 8, 128, 64), experts a token (8, 8, 8,
+10, 22) and loads an expert (256 tokens a step, 512, 256, 320, 704) differ.
 
 Every product takes the number of pieces (`count`) from its caller: a family
 keeps its own `OPERAND_PIECES` and hands it on at every call, so that its
@@ -30,10 +35,10 @@ place. Everywhere else (every CPU run, the GSPMD executors, `shard_map_score`,
 the trainer: a `tpu_custom_call` neither partitions nor has a gradient) it is
 the plain form the kernels are tested against: ONE loop over the tiles of
 EXPERT_BLOCK rows that hold a token (its length the routing decides), a tile's
-tokens gathered, through its expert's gated MLP and added back into their
-rows times their gates. No token is dropped whatever the routing, on either
-path: there is no capacity; a tile is padded to its size, so the work follows
-the loads rounded up.
+tokens gathered, through its expert (`expert_mlp`, of the tree's form) and
+added back into their rows times their gates. No token is dropped whatever the
+routing, on either path: there is no capacity; a tile is padded to its size,
+so the work follows the loads rounded up.
 
 The step counts its routing on the device (`STEP_STATS`, summed over the
 routed layers): (live token, routed layer) pairs, the (token, held expert)
@@ -96,6 +101,22 @@ def gated_mlp(p: dict, x: jax.Array, cd, count: int) -> jax.Array:
     return dot(jax.nn.silu(dot(x, p["gate"], cd, count)) * dot(x, p["up"], cd, count), p["down"], cd, count)
 
 
+def relu2_mlp(p: dict, x: jax.Array, cd, count: int) -> jax.Array:
+    """The ungated form, `relu(x U)^2 D`: two matrices an expert."""
+    return dot(jnp.square(jax.nn.relu(dot(x, p["up"], cd, count))), p["down"], cd, count)
+
+
+def expert_form(p: dict) -> str:
+    """`gated_silu` or `relu2`: the form of an expert (or of the held experts
+    stacked), read from its own tree: a `gate` leaf or none."""
+    return "gated_silu" if "gate" in p else "relu2"
+
+
+def expert_mlp(p: dict, x: jax.Array, cd, count: int) -> jax.Array:
+    """An expert of either form over `x` (`expert_form`)."""
+    return (gated_mlp if "gate" in p else relu2_mlp)(p, x, cd, count)
+
+
 def rope_table(length: int, width: int, theta: float) -> tuple[np.ndarray, np.ndarray]:
     """cos and sin `[length, width / 2]` of the angles `t * theta ** (-2i / width)`,
     made in float64 and held as float32 constants of the step."""
@@ -127,13 +148,16 @@ def check_share(experts: int, held: int, first: int, top_k: int) -> None:
 
 
 def route(router: jax.Array, x: jax.Array, top_k: int, scaling: float, scoring: str = "sigmoid",
-          normalise: bool = True):
+          normalise: bool = True, bias: jax.Array | None = None):
     """(the chosen experts `[T, k]`, their gates `[T, k]`, every expert's
     score `[T, E]`) for tokens `x [T, H]`: scores over ALL the routed experts
     (`scoring`: a sigmoid an expert, or one softmax over them; a family's
     router is part of its equations, so the family says which), the k
-    largest, normalised to sum 1 (where `normalise`) and scaled. float32 at
-    `highest` precision whatever the compute dtype."""
+    largest, normalised to sum 1 (where `normalise`) and scaled. With a
+    selection `bias [E]` (nemotron_h) the k largest of `scores + bias` are
+    chosen and their gates are made from the UNBIASED scores: the bias
+    chooses and never weighs. float32 at `highest` precision whatever the
+    compute dtype."""
     if scoring not in SCORINGS:
         raise ValueError(f"scoring {scoring!r}: one of {SCORINGS}")
     with jax.named_scope("router"):
@@ -141,7 +165,9 @@ def route(router: jax.Array, x: jax.Array, top_k: int, scaling: float, scoring: 
             "th,he->te", x, router.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32)
         scores = jax.nn.sigmoid(logits) if scoring == "sigmoid" else jax.nn.softmax(logits, axis=-1)
-        top, chosen = jax.lax.top_k(scores, top_k)
+        top, chosen = jax.lax.top_k(scores if bias is None else scores + bias.astype(jnp.float32), top_k)
+        if bias is not None:
+            top = jnp.take_along_axis(scores, chosen, axis=-1)
         if normalise:
             top = top / jnp.sum(top, axis=-1, keepdims=True)
         return chosen, top * scaling, scores
@@ -154,13 +180,16 @@ def layout_tiles(tokens: int, top_k: int, held: int, tile: int) -> int:
     return tokens * min(top_k, held) // tile + held
 
 
-def grouped_choice(count: int, tokens: int | None = None, top_k: int = 0, held: int = 0) -> dict:
-    """`{"kernel": "pallas" | "xla", "tile", "pieces", "held", "rows"}`: which
-    path serves the held experts of a routed layer, the rows of a tile, the
-    pieces an activation enters its products as, the experts held and the
-    layout's bound in rows (`layout_tiles`: what the buffer between the
-    kernels and the tile table are sized by; the last two where `tokens`, the
-    layer's, are given). A servable's `startup.grouped` stamp. The kernels
+def grouped_choice(count: int, tokens: int | None = None, top_k: int = 0, held: int = 0,
+                   form: str = "", width: int = 0) -> dict:
+    """`{"kernel": "pallas" | "xla", "tile", "pieces", "held", "rows", "form",
+    "width"}`: which path serves the held experts of a routed layer, the rows
+    of a tile, the pieces an activation enters its products as, the experts
+    held, the layout's bound in rows (`layout_tiles`: what the buffer between
+    the kernels and the tile table are sized by), the experts' form
+    (`expert_form`) and the width of the rows they take and give (the
+    residual's, or a latent's); the last four where `tokens`, the layer's,
+    are given. A servable's `startup.grouped` stamp. The kernels
     (ops/grouped_kernel.py) run where a served entry's kernels do
     (`sequence.kernels_run`): inside the batcher's one-chip entry on a TPU,
     at every token count (the last layer's 4-8 tokens too, a third of the
@@ -170,14 +199,15 @@ def grouped_choice(count: int, tokens: int | None = None, top_k: int = 0, held: 
         from ..ops.grouped_kernel import TILE as tile
     choice = {"kernel": "pallas" if kernel else "xla", "tile": tile, "pieces": count}
     if tokens is not None:
-        choice.update(held=held, rows=layout_tiles(tokens, top_k, held, tile) * tile)
+        choice.update(held=held, rows=layout_tiles(tokens, top_k, held, tile) * tile, form=form, width=width)
     return choice
 
 
-def takes_kernel(count: int, tokens: int | None = None, top_k: int = 0, held: int = 0) -> bool:
+def takes_kernel(count: int, tokens: int | None = None, top_k: int = 0, held: int = 0,
+                 form: str = "", width: int = 0) -> bool:
     """Whether the kernels serve this routed layer (grouped_choice has the
     rule), noted for the served entry being traced."""
-    choice = grouped_choice(count, tokens, top_k, held)
+    choice = grouped_choice(count, tokens, top_k, held, form, width)
     served = sequence.served_entry()
     if served is not None and served.grouped is not None and choice not in served.grouped:
         served.grouped.append(choice)
@@ -228,12 +258,15 @@ def lay_out(chosen: jax.Array, first: int, held: int, tile: int, live: jax.Array
 
 def held_experts(p: dict, x: jax.Array, chosen: jax.Array, gates: jax.Array, first: int, cd,
                  block: int = EXPERT_BLOCK, live: jax.Array | None = None, *, count: int):
-    """The held experts' part of the routed layer for tokens `x [T, H]`:
-    `sum over held e chosen by the token of g_e * expert_e(x)`, `[T, H]`
-    float32; the tokens each held expert's rows took through it, `[held]`
-    int32, counted where they were gathered; and the rows that were computed
-    for them, padding and all. `p` holds the experts `first .. first + held - 1`
-    stacked; `chosen` and `gates` are the router's `[T, k]`; `live [T]` is
+    """The held experts' part of the routed layer for tokens `x [T, H]`: `sum
+    over held e chosen by the token of g_e * expert_e(x)`, `[T, H]` float32;
+    the tokens each held expert's rows took through it, `[held]` int32,
+    counted where they were gathered; and the rows that were computed for
+    them, padding and all. `p` holds the experts `first .. first + held - 1`
+    stacked, of either form (`expert_form`: `gate`, `up`, `down`, or `up` and
+    `down` alone), and H is whatever width they take and give: the residual's,
+    or a latent's (nemotron_h: 1,024 of whole lanes under a residual of
+    4,096); `chosen` and `gates` are the router's `[T, k]`; `live [T]` is
     false for the tokens left out (a padded row's: their part is zero). The
     caller's `experts` scope.
 
@@ -242,8 +275,8 @@ def held_experts(p: dict, x: jax.Array, chosen: jax.Array, gates: jax.Array, fir
     is then the kernel's own tile); else ONE loop over the tiles of `block`
     rows that hold a token, whose expert's weights a tile reads by its
     index."""
-    tokens, held = x.shape[0], p["gate"].shape[0]
-    kernel = takes_kernel(count, tokens, chosen.shape[1], held)
+    tokens, held = x.shape[0], p["down"].shape[0]
+    kernel = takes_kernel(count, tokens, chosen.shape[1], held, expert_form(p), x.shape[1])
     if kernel:
         from ..ops import grouped_kernel
 
@@ -254,14 +287,15 @@ def held_experts(p: dict, x: jax.Array, chosen: jax.Array, gates: jax.Array, fir
         orders, expert, rows, walked = lay_out(chosen, first, held, block, live)
     if kernel:
         return grouped_kernel.grouped_experts(
-            *(p[name].astype(cd) for name in ("gate", "up", "down")), x, gate_of, orders, expert, rows, walked,
+            *(p[name].astype(cd) if name in p else None for name in ("gate", "up", "down")), x, gate_of, orders, expert,
+            rows, walked,
             cd=jnp.dtype(cd), count=count, tile=block, interpret=sequence.served_entry().interpret)
 
     def body(i, carry):
         out, took, ran = carry
         e, at = expert[i], orders[i]
         with jax.named_scope("grouped"):
-            y = gated_mlp({name: w[e] for name, w in p.items()}, x.at[at].get(mode="clip"), cd, count)
+            y = expert_mlp({name: w[e] for name, w in p.items()}, x.at[at].get(mode="clip"), cd, count)
         with jax.named_scope("combine"):
             gate = gate_of.at[at, e].get(mode="fill", fill_value=0.0)
             return (out.at[at].add(y * gate[:, None], mode="drop"),

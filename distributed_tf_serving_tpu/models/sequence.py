@@ -1,18 +1,19 @@
 """What the sequence-ranker families (phi4flash, pangu_moe, exaone_moe,
-olmo_hybrid, mimo_v2, falcon_h1, qwen3_next) share: products whose float32 activations enter as pieces of the
+olmo_hybrid, mimo_v2, falcon_h1, qwen3_next, nemotron_h) share: products whose float32 activations enter as pieces of the
 compute dtype (`product`: one product a call wherever a form exists that
 copies no large array; against a weight always, two pieces along a second
 contracted axis and three or more stacked, noted for the `startup.products`
 stamp), the causal softmax of a block of queries, the blocks
 themselves, causal attention in those blocks (`blocked_attention`:
-exaone_moe's, olmo_hybrid's and qwen3_next's full layers, falcon_h1's, both
+exaone_moe's, olmo_hybrid's and qwen3_next's full layers, falcon_h1's,
+nemotron_h's (no rotary turn before it), both
 kinds of mimo_v2's, whose window layers' softmax holds a learned sink), the
 attention at all positions as one Pallas kernel a layer where a one-chip
 served entry runs on a TPU and the kernel's scratch fits its VMEM at the
-layer's shapes (`attention`, `takes_kernel`, `attention_choice`: all seven
+layer's shapes (`attention`, `takes_kernel`, `attention_choice`: all eight
 families), the causal depthwise convolution (`causal_conv`: phi4flash's Mamba
-layers, olmo_hybrid's and qwen3_next's linear ones and falcon_h1's Mamba-2
-mixers), and
+layers, olmo_hybrid's and qwen3_next's linear ones and falcon_h1's and
+nemotron_h's Mamba-2 mixers), and
 the cut to the last position. One implementation, so that a change to any of
 them is measured on every family's cell. (`models/routed.py` has what the
 routed families share beside these.)
@@ -245,7 +246,7 @@ def serving_attention(notes: list, interpret: bool = False, grouped: list | None
     `olmo_hybrid.takes_kernel`, which appends `olmo_hybrid.delta_choice`'s
     dict to `delta`: the `startup.delta_rule` stamp); and a Mamba-2 mixer's
     SSD at all positions may take its own (ops/ssd_kernel.py, chosen by
-    `falcon_h1.takes_kernel`; `falcon_h1.note_ssd` appends
+    `falcon_h1.takes_kernel`, for falcon_h1's and nemotron_h's; `falcon_h1.note_ssd` appends
     `falcon_h1.ssd_choice`'s dict to `ssd`: the `startup.ssd` stamp); and
     `product` appends to `products`, where one is given, every product of an
     activation in pieces against a weight that it traces, as `(M, k, n,

@@ -17,7 +17,9 @@ copied in HBM:
   pieces of the compute dtype there, and the pieces, one under the other, meet
   each `[K_BLOCK, N_BLOCK]` block of `gate` and of `up` in one product a
   weight; float32 accumulators over the hidden axis; `silu(g) * u` in float32
-  is what goes back to HBM, `[rows, F]`.
+  is what goes back to HBM, `[rows, F]`. Experts of the UNGATED form (two
+  matrices, no `gate`: nemotron_h's) take the same kernel against `up` alone,
+  under the name `grouped_up`, and `relu(u)^2` goes back.
 - `grouped_down`: the tile's `[TILE, F]` float32 rows are cut into pieces once
   a tile and meet `down` a `[F, columns]` block a step; meanwhile the rows of
   the `[T, H]` result that the tile's tokens own have been copied in, each
@@ -29,6 +31,8 @@ copied in HBM:
   the same way, a `[1, 128]` row a token that holds its gate for every held
   expert; the tile's expert picks the lane.
 
+H is the width of the rows the experts take and give, any whole number of
+lanes: the residual's in four families, a latent's 1,024 in nemotron_h.
 A row of a `[T, H]` float32 array is one sublane of each of H / 128 tiles of
 (8, 128), and a copy may not slice a tiled axis of a wider array off its
 tiling: the tokens and the result cross the kernels' boundary as
@@ -127,9 +131,13 @@ def _stacked_product(stacked, w_ref, held: int, tile: int):
     return out
 
 
-def _gate_up_kernel(expert, rows, tokens, x_hbm, gate_ref, up_ref, h_ref, ring, sem, acc_g, acc_u,
-                    *, held, cd, tile, chunks):
+def _first_kernel(expert, rows, tokens, x_hbm, *refs, held, cd, tile, chunks):
+    """`refs`: the blocks of the first pass's weights (`gate` and `up` of the
+    gated form, `up` alone of the ungated one), the result's block, the ring
+    of gathered rows, its semaphore, and a float32 accumulator a weight."""
     del expert
+    count = (len(refs) - 3) // 2
+    weights, (h_ref, ring, sem), accs = refs[:count], refs[count:count + 3], refs[count + 3:]
     i, n, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when((n == 0) & (k == 0))
@@ -141,23 +149,26 @@ def _gate_up_kernel(expert, rows, tokens, x_hbm, gate_ref, up_ref, h_ref, ring, 
 
     x = jnp.concatenate([ring[:, k * chunks + c].reshape(tile, ring.shape[3]) for c in range(chunks)], axis=1)
     stacked = jnp.concatenate(_pieces(x, cd, held), axis=0)
-    g = _stacked_product(stacked, gate_ref, held, tile)
-    u = _stacked_product(stacked, up_ref, held, tile)
+    products = [_stacked_product(stacked, w_ref, held, tile) for w_ref in weights]
 
     @pl.when(k == 0)
     def _first():
-        acc_g[...] = g
-        acc_u[...] = u
+        for acc, y in zip(accs, products):
+            acc[...] = y
 
     @pl.when(k > 0)
     def _add():
-        acc_g[...] += g
-        acc_u[...] += u
+        for acc, y in zip(accs, products):
+            acc[...] += y
 
     @pl.when(k == pl.num_programs(2) - 1)
-    def _gated():
-        g = acc_g[...]
-        h_ref[...] = g * jax.nn.sigmoid(g) * acc_u[...]
+    def _activated():
+        if count == 2:  # silu(g) * u
+            g = accs[0][...]
+            h_ref[...] = g * jax.nn.sigmoid(g) * accs[1][...]
+        else:  # relu(u)^2
+            u = jnp.maximum(accs[0][...], 0.0)
+            h_ref[...] = u * u
 
 
 def _down_kernel(expert, rows, tokens, h_ref, down_ref, gates_hbm, zeros_hbm, out_hbm, counts, cut, ring,
@@ -211,7 +222,11 @@ def grouped_experts(gate, up, down, x, gate_of, orders, expert, rows, live, *, c
     expert_e(x[t])`, `[T, H]` float32; the rows that were added back a held
     expert, `[held]` int32; and the rows of the tiles the pass walked.
 
-    gate, up  `[held, H, F]` in the compute dtype `cd`; down `[held, F, H]`
+    gate, up  `[held, H, F]` in the compute dtype `cd`; down `[held, F, H]`;
+              `gate` None for experts of the ungated form, `relu(x up)^2 down`
+              (the first kernel then meets one weight and is named
+              `grouped_up`). H is the width of the rows the experts take and
+              give, whole lanes: the residual's, or a latent's
     x         `[T, H]` float32, the tokens
     gate_of   `[T, held]` float32, a token's gate for each held expert
     orders    `[tiles, tile]` int32, the token of every row of every tile a
@@ -222,7 +237,8 @@ def grouped_experts(gate, up, down, x, gate_of, orders, expert, rows, live, *, c
               tiles that hold one first (`routed.lay_out` makes the three)
     live      how many tiles hold a token
     """
-    held, hidden, width = gate.shape
+    held, hidden, width = up.shape
+    first = [up] if gate is None else [gate, up]
     tokens, tiles = x.shape[0], orders.shape[0]
     pieces = pieces_held(cd, count)
     # One tile at least, of no rows where no token came here: its step zeroes
@@ -243,28 +259,20 @@ def grouped_experts(gate, up, down, x, gate_of, orders, expert, rows, live, *, c
 
     with jax.named_scope("grouped"):
         h = pl.pallas_call(
-            functools.partial(_gate_up_kernel, held=pieces, cd=cd, tile=tile, chunks=k_block // lanes),
+            functools.partial(_first_kernel, held=pieces, cd=cd, tile=tile, chunks=k_block // lanes),
             out_shape=jax.ShapeDtypeStruct((tiles * tile, width), jnp.float32),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(walked, width // n_block, hidden // k_block),
-                in_specs=[
-                    a_tiles_tokens(),
-                    pl.BlockSpec(memory_space=pl.ANY),
-                    pl.BlockSpec((None, k_block, n_block), lambda i, n, k, e, r: (e[i], k, n)),
-                    pl.BlockSpec((None, k_block, n_block), lambda i, n, k, e, r: (e[i], k, n)),
-                ],
+                in_specs=[a_tiles_tokens(), pl.BlockSpec(memory_space=pl.ANY)] + [
+                    pl.BlockSpec((None, k_block, n_block), lambda i, n, k, e, r: (e[i], k, n)) for _ in first],
                 out_specs=pl.BlockSpec((tile, n_block), lambda i, n, k, e, r: (i, n)),
-                scratch_shapes=[
-                    ring,
-                    pltpu.SemaphoreType.DMA(()),
-                    pltpu.VMEM((tile, n_block), jnp.float32),
-                    pltpu.VMEM((tile, n_block), jnp.float32),
-                ]),
+                scratch_shapes=[ring, pltpu.SemaphoreType.DMA(())] + [
+                    pltpu.VMEM((tile, n_block), jnp.float32) for _ in first]),
             compiler_params=params(dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
             interpret=interpret,
-            name="grouped_gate_up",
-        )(expert, rows, order_tiles, x, gate, up)
+            name="grouped_up" if gate is None else "grouped_gate_up",
+        )(expert, rows, order_tiles, x, *first)
     # A token's gates, a row of whole lanes: what a row copy can bring.
     gate_lanes = jnp.pad(gate_of, ((0, 0), (0, _round_up(held, LANES) - held)))
     with jax.named_scope("combine"):

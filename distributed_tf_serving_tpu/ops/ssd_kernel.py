@@ -32,7 +32,9 @@ tests/test_tpu_compile.py compiles it for a v5e).
 x and y cross the kernel as they lie, `[n, L, H x P]`, B and C as
 `[n, L, G x N]`, with no turn on either side: a block is a chunk's rows and
 the columns of a step's heads (a head's tile a static slice of the block's
-lanes) or of their group. The heads a step divide a group's, so a step never
+lanes: whole lane tiles at Falcon-H1's 128-wide heads, HALF a lane tile at
+Nemotron-H's 64, which Mosaic takes as it takes the delta rule's 96) or of
+their group. The heads a step divide a group's, so a step never
 straddles two groups and none hangs over the array's edge; where no such
 count has whole lanes (a test's narrow heads) one step takes every head and
 every group, and the whole axis is a legal block whatever its width.
@@ -49,15 +51,21 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .attention_kernel import LANES, _pieces, pieces_held
 
-# Heads a grid step takes at most. A head's state is 128 KB at `[128, 256]`
-# and its x and y tiles 64 KB each a chunk of 128: at 8 heads the states, the
-# start and end blocks and the step's blocks twice for the pipeline are 8.1 MB
-# in the compiled step, inside the 16 MiB a kernel has by default (what a
-# kernel claims beyond it is taken from XLA's prefetch of the step's weights:
-# PERF.md section 6, PR 48); 16 heads, a whole group, ask for 18.4 MB. On the
-# v5e the SSD of a layer read 2.85 ms at 8 heads a step and 3.06 at 4 (by the
-# host's clock, a call alone: PERF.md section 6, PR 55).
-HEADS = 8
+# The float32 bytes of state a grid step holds in scratch at most, which sets
+# the heads a step (`heads_a_step`): 8 of Falcon-H1's `[128, 256]` states (128
+# KB each, x and y tiles 64 KB each a chunk of 128: the states, the start and
+# end blocks and the step's blocks twice for the pipeline are 8.1 MB in the
+# compiled step, inside the 16 MiB a kernel has by default; what a kernel
+# claims beyond it is taken from XLA's prefetch of the step's weights, PERF.md
+# section 6, PR 48; 16 heads, a whole group, ask for 18.4 MB; on the v5e the
+# SSD of a layer read 2.85 ms at 8 heads a step and 3.06 at 4, by the host's
+# clock, a call alone: PERF.md section 6, PR 55), and a whole group of 16 of
+# Nemotron-H's `[64, 128]` states (32 KB each, half a lane tile wide: the
+# step's x and y blocks are the same 1,024 lanes as Falcon-H1's 8 heads of
+# 128, its B and C one group's 128; PERF.md section 6, PR 60 has the chip's
+# reading).
+STATE_BYTES = 1 << 20
+
 
 def _stacked(xs: list) -> list:
     """For each piece j of the other side, x's pieces that pair with it
@@ -118,13 +126,15 @@ def _kernel(dt_ref, total_ref, x_ref, b_ref, c_ref, start_ref, y_ref, end_ref, s
             end_ref[h] = state[h].T
 
 
-def heads_a_step(heads: int, groups: int, width: int) -> int:
-    """Heads a grid step takes: the most, up to HEADS, that divide a group's
-    heads and whose columns are whole lanes; every head (and every group)
-    where no count does (a test's narrow heads: the whole axis is a legal
-    block whatever its width)."""
+def heads_a_step(heads: int, groups: int, width: int, wide: int) -> int:
+    """Heads a grid step takes: the most whose `[width, wide]` float32 states
+    fit STATE_BYTES, that divide a group's heads and whose columns are whole
+    lanes (an even count of 64-wide heads); every head (and every group) where
+    no count does (a test's narrow heads: the whole axis is a legal block
+    whatever its width)."""
     per = heads // groups
-    fit = [h for h in range(1, min(per, HEADS) + 1) if per % h == 0 and h * width % LANES == 0]
+    most = min(per, max(1, STATE_BYTES // (4 * width * wide)))
+    fit = [h for h in range(1, most + 1) if per % h == 0 and h * width % LANES == 0]
     return max(fit) if fit else heads
 
 
@@ -145,7 +155,7 @@ def chunk_walk(dt, total, x, b, c, start, *, heads: int, groups: int, cd, count:
     `i + j < count`; the state is rounded to `state_dtype` after every chunk."""
     n, _, steps, chunk = total.shape
     width, wide = x.shape[-1] // heads, b.shape[-1] // groups
-    held, group = pieces_held(cd, count), heads_a_step(heads, groups, width)
+    held, group = pieces_held(cd, count), heads_a_step(heads, groups, width, wide)
     per = min(group, heads // groups)  # heads of a step that share a group; the step spans `group // per` groups
     pairs = held * (held + 1) // 2
 

@@ -485,7 +485,8 @@ def test_a_request_through_the_interpreted_kernels_scores_like_the_reference(ref
     want = reference_scores(reference, servable.params, batch, config)
     assert np.max(np.abs(got["prediction_node"] - want)) < tolerance
     assert startup["attention"]["M:1"]["kernel"] == "pallas" and batcher.stats.attention_kernel_batches == 1
-    assert startup["ssd"] == {"M:1": {"path": "pallas", "chunk": 64, "state_bytes_a_row": 8 * 16 * 32 * 4}}
+    assert startup["ssd"] == {"M:1": {"path": "pallas", "chunk": 64, "state_bytes_a_row": 8 * 16 * 32 * 4,
+                                     "heads": [8, 16, 32]}}
     assert batcher.stats.batches == 1 and batcher.stats.ssd_kernel_batches == 1 and counted() - before == 1
 
 
@@ -496,7 +497,7 @@ def test_the_ssd_takes_the_kernel_inside_a_served_entry_on_a_tpu_and_nowhere_els
     `shard_map`, the trainer) and for the last position alone; `note_ssd`
     notes each choice once."""
     s = falcon_h1._sizes(load_config(os.path.join(ROOT, "configs", "falcon_h1_small.toml"))["model"])
-    xla = {"path": "xla", "chunk": 64, "state_bytes_a_row": 8 * 16 * 32 * 4}
+    xla = {"path": "xla", "chunk": 64, "state_bytes_a_row": 8 * 16 * 32 * 4, "heads": [8, 16, 32]}
     assert falcon_h1.ssd_choice(150, s) == xla
     with sequence.serving_attention([], ssd=(notes := [])):
         falcon_h1.note_ssd(150, s)
@@ -544,7 +545,7 @@ def test_runtime_block_reports_the_plan_and_the_ssd_stamp(served):
     layer = {"kind": "parallel", "window": 0, "block": 150, "keys_a_block": 150, "kv_heads": 2, "theta": 1e11,
              "ssd": {"kind": "ssd", "chunk": 64, "handovers_a_row": 3, "state_bytes_a_row": 8 * 16 * 32 * 4}}
     assert startup["attention_plan"] == {"M:1": [layer] * 4}
-    assert startup["ssd"] == {"M:1": {"path": "xla", "chunk": 64, "state_bytes_a_row": 16384}}
+    assert startup["ssd"] == {"M:1": {"path": "xla", "chunk": 64, "state_bytes_a_row": 16384, "heads": [8, 16, 32]}}
     assert startup["attention"] == {"M:1": {"kernel": "xla", "block": 0, "pieces": 2}}
     assert startup["expert_plan"] == {"M:1": None} and startup["delta_rule"] == {} and startup["grouped"] == {}
     assert startup["assembler"] == {"M:1": "native"} or not native.available()

@@ -25,7 +25,8 @@ SMALL = {"exaone_moe": "exaone_moe_small", "pangu_moe": "pangu_moe_small", "mimo
 TILE = grouped_kernel.TILE
 interpreted = functools.partial(sequence.serving_attention, interpret=True)
 
-# name -> (tokens, held, first, k, the experts routed over, routing, live rows left out, compute dtype, pieces)
+# name -> (tokens, held, first, k, the experts routed over, routing, live rows left out, compute dtype, pieces
+#          [, the experts' form where not the gated one [, the width of a row where not 256]])
 ROUTINGS = {
     "uniform": (300, 3, 0, 2, 12, "uniform", False, jnp.bfloat16, 3),
     "an expert no token chose": (300, 3, 0, 2, 12, "one_empty", False, jnp.bfloat16, 3),
@@ -40,15 +41,23 @@ ROUTINGS = {
     "one piece": (200, 3, 5, 2, 12, "uniform", False, jnp.bfloat16, 1),
     "two pieces": (200, 3, 5, 2, 12, "uniform", False, jnp.bfloat16, 2),
     "float32 compute dtype": (200, 3, 5, 2, 12, "uniform", True, jnp.float32, 3),
+    # PR 60 (nemotron_h): experts of two matrices and a squared relu, the first kernel against ONE weight; rows of a
+    # latent's width (1,024: eight lane chunks, two steps of the contraction), not the residual's; k over twice 8
+    "ungated": (300, 3, 0, 2, 12, "uniform", False, jnp.bfloat16, 3, "relu2"),
+    "ungated, an expert no token chose, live rows left out": (300, 3, 0, 2, 12, "one_empty", True, jnp.bfloat16, 3, "relu2"),
+    "ungated, every token on all min(k, held) held experts": (260, 3, 0, 3, 12, "all_held", False, jnp.bfloat16, 3, "relu2"),
+    "ungated, float32 compute dtype": (200, 3, 5, 2, 12, "uniform", True, jnp.float32, 3, "relu2"),
+    "ungated, 1,024-wide rows, 16 held at top-22 of 128": (90, 16, 0, 22, 128, "uniform", False, jnp.bfloat16, 3, "relu2", 1024),
+    "gated, 1,024-wide rows": (90, 4, 4, 3, 16, "uniform", False, jnp.bfloat16, 3, "gated_silu", 1024),
 }
 
 
-def _operands(tokens, held, first, k, experts, routing, dead, cd, seed=0):
+def _operands(tokens, held, first, k, experts, routing, dead, cd, form="gated_silu", hidden=256, seed=0):
     rng = np.random.default_rng(seed)
-    hidden, width = 256, 128
+    width = 128
     p = {"gate": rng.standard_normal((held, hidden, width)) * 0.1, "up": rng.standard_normal((held, hidden, width)) * 0.1,
          "down": rng.standard_normal((held, width, hidden)) * 0.1}
-    p = {name: jnp.asarray(w, cd) for name, w in p.items()}
+    p = {name: jnp.asarray(w, cd) for name, w in p.items() if form == "gated_silu" or name != "gate"}
     x = jnp.asarray(rng.standard_normal((tokens, hidden)), jnp.float32)
     if routing == "uniform":
         chosen = np.stack([rng.permutation(experts)[:k] for _ in range(tokens)])
@@ -71,8 +80,9 @@ def test_the_pass_is_the_loops_on_the_same_operands(case):
     """Values to float32 rounding (the same pieces against the same weights,
     added in another order), the tokens each held expert took exactly, and the
     rows computed: the tiles that hold a token, padding and all."""
-    tokens, held, first, k, experts, routing, dead, cd, count = ROUTINGS[case]
-    p, x, chosen, gates, live = _operands(tokens, held, first, k, experts, routing, dead, cd)
+    tokens, held, first, k, experts, routing, dead, cd, count, *form = ROUTINGS[case]
+    p, x, chosen, gates, live = _operands(tokens, held, first, k, experts, routing, dead, cd, *form)
+    assert routed.expert_form(p) == (form[0] if form else "gated_silu")
     run = lambda: routed.held_experts(p, x, chosen, gates, first, cd, live=live, count=count)  # noqa: E731
     want, took, _ = jax.jit(run)()
 
@@ -155,7 +165,8 @@ def _served_step(model):
             out = model.apply_stats(p, b)
         # a note a token count: the layers at all positions, the last layer's one position a row
         assert notes and all(
-            dict(n, held=0, rows=0) == {"kernel": "pallas", "tile": TILE, "pieces": 3, "held": 0, "rows": 0}
+            dict(n, held=0, rows=0, width=0) == {"kernel": "pallas", "tile": TILE, "pieces": 3, "held": 0, "rows": 0,
+                                                 "form": "gated_silu", "width": 0}
             and 0 < n["held"] <= model.config.experts_held and n["rows"] % TILE == 0 for n in notes)  # a planted fault drops one
         return out
 
@@ -264,10 +275,15 @@ def test_a_served_entry_on_a_tpu_takes_the_kernels(monkeypatch):
     monkeypatch.setattr(sequence.jax, "default_backend", lambda: "tpu")
     with sequence.serving_attention([], grouped=(notes := [])):
         assert routed.takes_kernel(3) and routed.takes_kernel(3)
-        assert routed.takes_kernel(3, 16384, 10, 128)
+        assert routed.takes_kernel(3, 16384, 10, 128, "gated_silu", 2048)
+        assert routed.takes_kernel(3, 16384, 22, 64, "relu2", 1024)
     assert notes == [{"kernel": "pallas", "tile": TILE, "pieces": 3},
                      # 128 of 512 experts at top-10 over 16,384 tokens: 1,408 tiles where `[held, T]` is 16,384
-                     {"kernel": "pallas", "tile": TILE, "pieces": 3, "held": 128, "rows": 1408 * TILE}]
+                     {"kernel": "pallas", "tile": TILE, "pieces": 3, "held": 128, "rows": 1408 * TILE,
+                      "form": "gated_silu", "width": 2048},
+                     # 64 of 512 at top-22 (PR 60): 2,880 tiles, of which the even share fills an eighth
+                     {"kernel": "pallas", "tile": TILE, "pieces": 3, "held": 64, "rows": 2880 * TILE,
+                      "form": "relu2", "width": 1024}]
     assert not routed.takes_kernel(3)  # outside it
 
 
@@ -307,13 +323,16 @@ def test_batcher_stamps_the_grouped_product_and_counts_its_batches(monkeypatch):
     assert stats.batches == 2 and stats.grouped_kernel_batches == 0 and counted == 0
     held = load_config(os.path.join(CONFIGS, "mimo_v2_small.toml"))["model"].experts_held
     rows = stamp["M:1"].pop("rows")  # the widest layout traced: the top rung's layers at all positions
-    assert stamp == {"M:1": {"kernel": "xla", "tile": routed.EXPERT_BLOCK, "pieces": 3, "held": held}}
+    width = load_config(os.path.join(CONFIGS, "mimo_v2_small.toml"))["model"].embed_dim
+    assert stamp == {"M:1": {"kernel": "xla", "tile": routed.EXPERT_BLOCK, "pieces": 3, "held": held,
+                             "form": "gated_silu", "width": width}}
     assert rows >= held * routed.EXPERT_BLOCK and rows % routed.EXPERT_BLOCK == 0
     monkeypatch.setattr(batcher_mod, "serving_attention", interpreted)
     got, stats, counted, stamp = _serve(payloads)
     assert stats.batches == 2 and stats.grouped_kernel_batches == 2 and counted == 2
     assert stamp["M:1"].pop("rows") % TILE == 0
-    assert stamp == {"M:1": {"kernel": "pallas", "tile": TILE, "pieces": 3, "held": held}}
+    assert stamp == {"M:1": {"kernel": "pallas", "tile": TILE, "pieces": 3, "held": held,
+                             "form": "gated_silu", "width": width}}
     np.testing.assert_allclose(got, want, atol=1e-6)
 
 

@@ -272,7 +272,8 @@ def test_held_experts_is_the_sum_an_expert(held, kernel):
         with interpreted([], grouped=(notes := [])):
             out = routed.held_experts(p, x, chosen, gates, 0, jnp.float32, count=3)
         assert notes == [{"kernel": "pallas", "tile": 128, "pieces": 3, "held": 128,
-                          "rows": (300 * 10 // 128 + 128) * 128}]  # not 128 x 384
+                          "rows": (300 * 10 // 128 + 128) * 128,  # not 128 x 384
+                          "form": "gated_silu", "width": 128}]
         return out
 
     with jax.default_matmul_precision("highest"):
@@ -484,7 +485,8 @@ def test_the_batcher_stamps_the_three_choices_and_counts_the_new_counter(monkeyp
     assert startup["delta_rule"]["Q:1"] == {
         "kernel": "pallas", "chunk": 64, "pieces": 3, "key_heads": 2, "value_heads": 4, "shared": 2}
     grouped = startup["grouped"]["Q:1"]
-    assert grouped == {"kernel": "pallas", "tile": 128, "pieces": 3, "held": 4, "rows": grouped["rows"]}
+    assert grouped == {"kernel": "pallas", "tile": 128, "pieces": 3, "held": 4, "rows": grouped["rows"],
+                       "form": "gated_silu", "width": 128}
     assert grouped["rows"] == routed.layout_tiles(2 * config.num_fields, 4, 4, 128) * 128
     assert startup["layer_plan"]["Q:1"] == {"linear": 4, "full": 1}
     assert counted["moe.tokens"] == 2 * (4 * config.num_fields + 1) and 16 <= counted["moe.experts_hit"] <= 20

@@ -3,7 +3,7 @@ in interpret mode: against XLA's `falcon_h1.ssd` on the same operands to
 float32 rounding and against the recurrence position by position in float64,
 at the published head and state (128 by 256) and at small ones; a length that
 is no multiple of the chunk, a state handed in and the one handed back, one
-group and two, a group whose heads are no multiple of HEADS, the fastest heads
+group and two, a group whose heads are no multiple of a step's, heads of 64 (half a lane tile) sixteen a step, the fastest heads
 of Mamba-2's own init; the pieces and the planted state told apart THROUGH the
 kernel; who takes it. What the batcher stamps and counts is in
 test_falcon_h1.py. Times come from the chip (PERF.md section 6, PR 55); the
@@ -27,6 +27,8 @@ SHAPES = {
     "the published head and state, two groups, a step a group": (1, 300, 16, 128, 2, 256, 128, jnp.bfloat16, False),
     "the published head, a length that is no multiple of the chunk": (2, 150, 8, 128, 1, 256, 64, jnp.bfloat16, True),
     "a group of 12 heads, 6 a step": (1, 130, 12, 128, 1, 64, 64, jnp.bfloat16, True),
+    # Nemotron-H's (PR 60): a head half a lane tile wide, a state `[64, 128]`, 16 heads a group and a step
+    "heads of 64, a whole group of 16 a step": (1, 200, 32, 64, 2, 128, 128, jnp.bfloat16, True),
     "narrow heads, every head and both groups in one step": (2, 75, 4, 16, 2, 32, 16, jnp.bfloat16, False),
     "narrow heads, a state handed in": (2, 75, 4, 16, 2, 32, 16, jnp.bfloat16, True),
     "one group": (2, 75, 4, 16, 1, 32, 16, jnp.bfloat16, True),
@@ -182,15 +184,18 @@ def test_a_planted_bfloat16_state_reads_what_xlas_planted_state_reads(monkeypatc
     assert np.abs(planted_state - xla_state).max() < 0.02 * np.abs(xla_state).max()
 
 
-@pytest.mark.parametrize("heads,groups,width,want", [
-    (32, 2, 128, 8), (32, 1, 128, 8), (24, 1, 64, 8), (12, 1, 128, 6), (14, 2, 128, 7), (6, 2, 128, 3),
-    (16, 2, 16, 8), (4, 2, 16, 4), (8, 2, 16, 8)])
-def test_a_steps_heads_divide_a_groups_and_are_whole_lanes(heads, groups, width, want):
-    """8 of a group's 16 at the published widths; the most under HEADS that
-    divide a group's heads, so a step never straddles two groups nor hangs
-    over the array's edge; every head, and every group with them, where no
+@pytest.mark.parametrize("heads,groups,width,wide,want", [
+    (32, 2, 128, 256, 8), (32, 1, 128, 256, 8), (24, 1, 64, 512, 8), (12, 1, 128, 256, 6), (14, 2, 128, 256, 7),
+    (6, 2, 128, 256, 3), (16, 2, 16, 32, 8), (4, 2, 16, 32, 4), (8, 2, 16, 32, 8),
+    (128, 8, 64, 128, 16), (24, 1, 64, 256, 12), (128, 8, 64, 2048, 2)])
+def test_a_steps_heads_divide_a_groups_and_are_whole_lanes(heads, groups, width, wide, want):
+    """8 of a group's 16 at Falcon-H1's published widths and a whole group of
+    16 at Nemotron-H's, whose states are a quarter the bytes; the most whose
+    states fit STATE_BYTES that divide a group's heads, so a step never
+    straddles two groups nor hangs over the array's edge (two heads of 64 at
+    the least: whole lanes); every head, and every group with them, where no
     such count's columns are whole lanes."""
-    got = ssd_kernel.heads_a_step(heads, groups, width)
+    got = ssd_kernel.heads_a_step(heads, groups, width, wide)
     assert got == want and (got == heads or (heads // groups % got == 0 and got * width % 128 == 0))
 
 
@@ -204,7 +209,7 @@ def test_outside_a_served_entry_the_ssd_is_xlas(monkeypatch):
     last layer's hand-overs (`last_only`) stay XLA's scan there too."""
     arrays = ssd_inputs(1, 75, 4, 16, 2, 32)[:5]
     s = {"chunk": 16, "ssm_heads": 4, "ssm_head": 16, "state": 32}
-    xla = {"path": "xla", "chunk": 16, "state_bytes_a_row": 4 * 16 * 32 * 4}
+    xla = {"path": "xla", "chunk": 16, "state_bytes_a_row": 4 * 16 * 32 * 4, "heads": [4, 16, 32]}
     assert falcon_h1.ssd_choice(75, s) == xla and "pallas_call" not in traced(arrays, 16)
     with sequence.serving_attention([], ssd=(notes := [])):
         falcon_h1.note_ssd(75, s)

@@ -497,6 +497,34 @@ def test_qwen3_nexts_eight_row_step_compiles_at_the_published_cut(one_chip, no_c
     assert accessed < 160e9  # 145 GB
 
 
+def test_nemotron_hs_eight_row_step_compiles_at_the_published_cut(one_chip, no_compile_cache, served_on_a_tpu):
+    """Nemotron-3-Super's share as `nemotron3_super_120b_rerank-bulk` serves it
+    (3.265 B parameters, 64 of 512 experts a routed layer, 8 rows of 2,048
+    tokens), the top bucket's step with its ten counters: the five Mamba-2
+    layers at all positions ONE SSD kernel each at 128 heads of 64 (a whole
+    group of 16 a step), the one attention layer the attention kernel at 16
+    query heads a key-value head, the five routed layers the two grouped
+    kernels each at the ungated form (`grouped_up` against one weight) over
+    1,024-wide rows and a layout of `T x 22` rows and a tile an expert (3.96 GB
+    between the kernels, one layer's at a time), and one loop of XLA's: the
+    last layer's state hand-overs, whose `y` is read at the last position.
+    Necessary, not sufficient: the chip decides (PERF.md section 6, PR 60)."""
+    compiled, accessed = sequence_cells_step("nemotron3_super_120b_rerank", "nemotron_h", one_chip)
+    memory, text = compiled.memory_analysis(), compiled.as_text()
+    assert 6.5e9 < memory.argument_size_in_bytes < 6.6e9  # 3,264.6 M parameters in bfloat16
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14 * GIB  # 6.51 GB of temporaries
+    assert memory.generated_code_size_in_bytes < 64 << 20  # 29 MB
+    assert len(re.findall(r"\) while\(", text)) == 1
+    assert text.count('custom_call_target="tpu_custom_call"') == 5 + 1 + 2 * 5 and "vmem_limit" not in text
+    assert len(kernels_vmem(text, "ssd_chunks")) == 5 and len(kernels_vmem(text, "attention")) == 1
+    # (a line that names `%grouped_up` is the kernel's own or the next one's, which reads its result)
+    assert len(kernels_vmem(text, "grouped_up")) == 2 * len(kernels_vmem(text, "grouped_down")) == 10
+    assert not kernels_vmem(text, "grouped_gate_up")
+    assert all(0 < size < DEFAULT_VMEM for name in ("ssd_chunks", "attention", "grouped_up", "grouped_down")
+               for size in kernels_vmem(text, name))  # 8.2, 10.4, 4.3 and 14.7 MB
+    assert accessed < 250e9  # 229 GB
+
+
 # ------------------------------------------- the Pallas attention (PR 48)
 #
 # What interpret mode cannot see: Mosaic's verdict on the kernel's slices,
@@ -519,6 +547,9 @@ ATTENTION_SHAPES = {
     "mimo_v2_full_sink": (((4, 64, 2048, 192),), ((4, 4, 2048, 192),), (4, 4, 2048, 128), None, 3, True),
     # falcon_h1_34b_rerank: 20 query heads over 4 key-value heads, 5 a group (the others' groups are 1, 2, 8 and 16)
     "falcon_h1": (((4, 20, 2048, 128),), ((4, 4, 2048, 128),), (4, 4, 2048, 128), None, 2),
+    # nemotron3_super_120b_rerank (PR 60): 32 query heads over 2 key-value heads, 16 a group at head 128 (mimo_v2's 16
+    # are 192 wide), 8 rows, three pieces
+    "nemotron_h": (((8, 32, 2048, 128),), ((8, 2, 2048, 128),), (8, 2, 2048, 128), None, 3),
 }
 
 
@@ -577,12 +608,20 @@ def test_delta_kernel_compiles_at_the_cells_rungs(one_chip, no_compile_cache, fo
 # states turned in and out at a row's ends (`[128, 256]` float32), eight
 # states in scratch beside the pipeline's blocks.
 
-@pytest.mark.parametrize("rows", [4, 2])
-@pytest.mark.parametrize("count", [2, 1])
-def test_ssd_kernel_compiles_at_the_cells_rungs(one_chip, no_compile_cache, rows, count):
+# (heads, groups, a head's width, the state's width, the rungs' rows, the pieces): falcon_h1_34b_rerank's 32 heads of
+# 128 over 2 groups, a `[128, 256]` state, 8 heads a step; nemotron3_super_120b_rerank's 128 heads of 64 (HALF a lane
+# tile: a head's tile a static 64-lane slice of the step's 1,024) over 8 groups, a `[64, 128]` state, a whole group of
+# 16 a step (PR 60), at its three pieces
+SSD_SHAPES = {"falcon_h1": (32, 2, 128, 256, (4, 2), (2, 1)), "nemotron_h": (128, 8, 64, 128, (8, 2), (3,))}
+
+
+@pytest.mark.parametrize("form,rows,count", [
+    (form, rows, count) for form, (*_, rungs, counts) in sorted(SSD_SHAPES.items()) for rows in rungs for count in counts])
+def test_ssd_kernel_compiles_at_the_cells_rungs(one_chip, no_compile_cache, form, rows, count):
     from distributed_tf_serving_tpu.ops.ssd_kernel import chunk_walk
 
-    heads, groups, length, width, wide, chunk = 32, 2, 2048, 128, 256, 128
+    heads, groups, width, wide = SSD_SHAPES[form][:4]
+    length, chunk = 2048, 128
     shaped = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)  # noqa: E731
     run = functools.partial(chunk_walk, heads=heads, groups=groups, cd=jnp.dtype(jnp.bfloat16), count=count)
     compiled = jax.jit(run).lower(
@@ -591,7 +630,7 @@ def test_ssd_kernel_compiles_at_the_cells_rungs(one_chip, no_compile_cache, rows
         shaped(rows, heads, width, wide)).compile()
     text = compiled.as_text()
     assert 'custom_call_target="tpu_custom_call"' in text and "vmem_limit" not in text
-    # 9.9 MiB alone, 8.1 MB in the step
+    # Falcon-H1's: 9.9 MiB alone, 8.1 MB in the step; Nemotron-H's 8.2 MB in the step
     assert all(0 < size < DEFAULT_VMEM * 3 // 4 for size in kernels_vmem(text, "ssd_chunks"))
     assert compiled.memory_analysis().generated_code_size_in_bytes < 2 << 20  # one kernel a layer
 
@@ -605,7 +644,7 @@ def test_ssd_kernel_compiles_at_the_cells_rungs(one_chip, no_compile_cache, rows
 # VMEM a kernel has by default; and what the served steps hold since.
 
 # (hidden, an expert's width, tokens a routed layer at all positions[, the compute dtype where not bfloat16
-# [, the experts held and the experts a token where not 8 and 8]])
+# [, the experts held and the experts a token where not 8 and 8[, the experts' form where not the gated one]]])
 GROUPED_SHAPES = {
     "exaone_moe": (6144, 2048, 8192), "pangu_moe": (7680, 2048, 8192), "mimo_v2": (4096, 2048, 8192),
     "exaone_moe_last_layer": (6144, 2048, 4), "pangu_moe_last_layer": (7680, 2048, 8),
@@ -619,6 +658,10 @@ GROUPED_SHAPES = {
     # qwen3_next_80b_rerank (PR 58): 128 of 512 experts held at top-10, the gates' lane row exactly full; the layout's
     # 1,408 tiles where `[held, T]` rows would be 16,384 tiles (4.3 GB between the kernels)
     "qwen3_next": (2048, 512, 16384, jnp.bfloat16, 128, 10), "qwen3_next_last_layer": (2048, 512, 8, jnp.bfloat16, 128, 10),
+    # nemotron3_super_120b_rerank (PR 60): UNGATED experts (two matrices, `grouped_up` against one weight) whose rows are
+    # the LATENT's 1,024 wide and not the residual's 4,096, 2,688 wide inside (21 lane tiles: blocks of 896), 64 of 512
+    # held at top-22: a layout of 2,880 tiles (3.96 GB between the kernels) of which the even share fills an eighth
+    "nemotron_h": (1024, 2688, 16384, jnp.bfloat16, 64, 22, "relu2"), "nemotron_h_bottom_rung": (1024, 2688, 4096, jnp.bfloat16, 64, 22, "relu2"),
 }
 
 
@@ -629,17 +672,18 @@ def test_grouped_kernels_compile_at_the_cells_top_rungs(one_chip, no_compile_cac
 
     hidden, width, tokens, *rest = GROUPED_SHAPES[form]
     cd = jnp.dtype(rest[0] if rest else jnp.bfloat16)
-    held, top_k = rest[1:] or (8, 8)
+    held, top_k, *form = rest[1:] or (8, 8)
     tiles = layout_tiles(tokens, top_k, held, TILE)
     shaped = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
     run = functools.partial(grouped_experts, cd=cd, count=3)
     compiled = jax.jit(run).lower(
-        shaped((held, hidden, width), cd), shaped((held, hidden, width), cd),
+        None if form == ["relu2"] else shaped((held, hidden, width), cd), shaped((held, hidden, width), cd),
         shaped((held, width, hidden), cd), shaped((tokens, hidden), jnp.float32),
         shaped((tokens, held), jnp.float32), shaped((tiles, TILE), jnp.int32), shaped((tiles,), jnp.int32),
         shaped((tiles,), jnp.int32), shaped((), jnp.int32)).compile()
     text, memory = compiled.as_text(), compiled.memory_analysis()
     assert text.count('custom_call_target="tpu_custom_call"') == 2 and "vmem_limit" not in text
+    assert ("grouped_up" if form else "grouped_gate_up") in text and "grouped_down" in text
     assert not re.findall(r"\) while\(", text)  # the routing decides the grids' length, and no loop of XLA's
     # Worst-case buffers: `[T x min(k, held) + held x TILE, F]` float32 between the kernels and nothing else the size
     # of the tokens (the sorted tokens and the sorted result are never made; the tokens' and the result's own bytes
@@ -698,6 +742,10 @@ LOWERED_TEXT = {
     # `Q K'` made for a key head and read by its two value heads); olmo_hybrid's two digests above, one key head a
     # value head, passed that change untouched. PR 59's own, held here for the next change to be seen against
     "qwen3_next_80b_rerank/qwen3_next/served": "7b7b2b0f57ed4461",
+    # PR 60: the eighth family's served step (the SSD and attention kernels, the grouped kernels at the ungated form
+    # over the latent's rows); the seven digests above passed PR 60's changes to `routed.held_experts`, `route`,
+    # `falcon_h1.ssm` and both kernels' files untouched. PR 60's own, held here for the next change to be seen against
+    "nemotron3_super_120b_rerank/nemotron_h/served": "aa7a7c3ae1dcfb18",
 }
 
 
