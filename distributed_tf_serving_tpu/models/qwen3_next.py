@@ -45,8 +45,11 @@ full layer (gated attention), `heads` query heads over `kv` key-value heads, d w
 
 `sequence.blocked_attention` computes it: the Pallas attention kernel where it
 serves AND its scratch fits the VMEM a kernel has (`sequence.attention_choice`:
-heads 256 wide at three pieces do not, so XLA's blocks of 512 queries serve
-the published widths; the servable's `startup.attention` says which and why).
+heads 256 wide at three pieces fit it held COMPACT, the keys' pieces once each
+and a head's float32 keys and values read from HBM a chunk at a time, and the
+shapes choose that by themselves: `ops/attention_kernel.py::held_compact`; the
+servable's `startup.attention` says which path serves, and why where XLA's
+blocks do).
 
 MOE (`models/routed.py`): `p = softmax(b W_r)` over ALL `num_experts`, float32;
 the `num_experts_per_tok` largest, normalised to sum 1 (`norm_topk_prob`), no

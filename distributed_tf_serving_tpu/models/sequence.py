@@ -307,14 +307,14 @@ def attention_choice(queries: int, keys: int, window: int | None, count: int, he
     run: `Model.attention_plan` states those) and the pieces an activation
     enters its products as. A servable's `startup.attention` stamp. Where the
     kernel would serve but its scratch and blocks at the `heads`' shapes are
-    past the VMEM a kernel has (`attention_kernel.vmem_bytes`: a shape that does
+    past the VMEM a kernel has (`attention_kernel.fits`: a shape that does
     not fit is refused by the chip at warm-up, not by the compiler), XLA's blocks
     serve, and the stamp says `"why": "vmem"`."""
     if not kernel_serves(queries):
         return {"kernel": "xla", "block": 0, "pieces": count}
-    from ..ops.attention_kernel import VMEM_LIMIT, tile, vmem_bytes
+    from ..ops.attention_kernel import fits, tile
 
-    if heads is not None and vmem_bytes(keys, window, *heads, count) > VMEM_LIMIT:
+    if heads is not None and not fits(keys, window, *heads, count):
         return {"kernel": "xla", "block": 0, "pieces": count, "why": "vmem"}
     return {"kernel": "pallas", "block": tile(keys, window), "pieces": count}
 

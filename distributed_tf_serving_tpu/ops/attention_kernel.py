@@ -25,6 +25,18 @@ rows through a product: what the MXU's weights cost to load again):
   into a float32 `[queries, d_v]` accumulator;
 - only that accumulator over the sum goes back to HBM.
 
+Where that does not fit the 16 MiB of VMEM a kernel has (heads 256 wide at
+three pieces: a head's keys in six pairs are 6.3 MB and its float32 keys and
+values in two buffers 8.4), the same kernel holds a head COMPACT, chosen from
+the shapes alone (`held_compact`, `vmem_bytes`): the keys' pieces stand ONCE
+each, and the tile is `pieces` products whose float32 results add, the
+queries' piece i (repeated) against the keys' pieces 0 .. pieces - 1 - i, the
+form `p v` has, mirrored: the same pairs in the same passes of the MXU
+(`columns` has both layouts); and the float32 keys and values stay in HBM,
+the cut copying PIECE_ROWS rows at a time into two small buffers, one on its
+way while the other is cut. No `vmem_limit` is raised either way (ROWS has
+why).
+
 Operands enter the MXU in the compute dtype, everything else is float32, and
 the pieces and pairs are `models/sequence.py::product`'s: the result is the
 XLA path's to float32 rounding (tests/test_attention_kernel.py, interpreted
@@ -128,81 +140,176 @@ def heads_a_step(shared: int, block: int) -> int:
     return max(h for h in range(1, shared + 1) if shared % h == 0 and h * block <= max(ROWS, block))
 
 
-def vmem_bytes(keys: int, window: int | None, widths: tuple[int, ...], dv: int, shared: int, cd, count: int) -> int:
+def columns(widths: tuple[int, ...], held: int, compact: bool):
+    """Where the pieces stand along the contracted axis of the queries' and
+    the keys' scratch, and the products that make a score tile of them:
+    `(the queries' columns, the keys', the queries' places, the keys', products)`,
+    a place `(part, piece, first column)` and a product `(the queries' first
+    column, the keys', columns)`, whose float32 results add up to the tile.
+
+    In PAIRS a column chunk is a pair (i, j), i + j < held, a part after the
+    other: the queries' piece i there and the keys' piece j, so ONE product
+    adds the pairs up in its own accumulation, and the keys' piece j stands
+    `held - j` times. COMPACT, the keys' pieces stand once each, piece after
+    piece (the parts side by side inside one): the queries' piece i, repeated
+    `held - i` times, meets pieces 0 .. held - 1 - i in one product, `held`
+    products a tile over the same pairs. A product's columns are whole lanes:
+    past its pairs the queries' are zeros (and the keys' another piece's, or
+    zeros past the last)."""
+    span = sum(widths)
+    if not compact:
+        pairs = [(i, j) for i in range(held) for j in range(held) if i + j < held]
+        q_places, k_places, column = [], [], 0
+        for part, width in enumerate(widths):
+            for c, (i, j) in enumerate(pairs):
+                q_places.append((part, i, column + c * width))
+                k_places.append((part, j, column + c * width))
+            column += len(pairs) * width
+        total = _round_up(column, LANES)
+        return total, total, q_places, k_places, [(0, 0, total)]
+    firsts = [sum(widths[:part]) for part in range(len(widths))]
+    k_places = [(part, j, j * span + first) for j in range(held) for part, first in enumerate(firsts)]
+    q_places, products, column = [], [], 0
+    for i in range(held):
+        q_places += [(part, i, column + r * span + first) for r in range(held - i) for part, first in enumerate(firsts)]
+        products.append((column, 0, _round_up((held - i) * span, LANES)))
+        column += products[-1][2]
+    return column, _round_up(held * span, LANES), q_places, k_places, products
+
+
+def _gaps(places, widths, total: int) -> list[tuple[int, int]]:
+    """The column ranges of `total` that no place fills: zeros stand there."""
+    out, at = [], 0
+    for first, stop in sorted((first, first + widths[part]) for part, _, first in places):
+        if first > at:
+            out.append((at, first))
+        at = max(at, stop)
+    return out + ([(at, total)] if at < total else [])
+
+
+def vmem_bytes(keys: int, window: int | None, widths: tuple[int, ...], dv: int, shared: int, cd, count: int,
+               compact: bool = False) -> int:
     """The VMEM bytes a call of `attention` asks for, from its shapes alone:
-    the scratch (the queries' and the keys' pieces side by side a pair, the
-    values' a piece, the running maximum and sum, a lane row each, and the
-    float32 accumulator), the pipeline's double-buffered float32 blocks
-    (the step's queries a part, a head's keys a part, its values, the result)
-    and one float32 score tile.
+    the scratch (the queries' and the keys' pieces as `columns` lays them out,
+    the values' side by side a piece, the running maximum and sum, a lane row
+    each, and the float32 accumulator), the float32 blocks in two buffers each
+    (the step's queries a part and the result; a head's keys a part and its
+    values, or, `compact`, a chunk of PIECE_ROWS of them in whole lanes) and
+    one float32 score tile; `compact`, also what else of a key block's work
+    Mosaic keeps on its stack: the tile's exponentials, a piece of them and
+    that piece's products with the values' pieces side by side.
     `keys` positions a row, the parts' `widths`, the values' `dv`, `shared`
     query heads a key-value head (the fewest over the parts and the values),
-    `count` pieces of `cd`. Mosaic's own count is not this one (it keeps a
-    stack of temporaries beside them and some blocks once: 12.5 MiB where this
-    counts 15.1, 13.9 where this counts 12.0); this one is read against
-    VMEM_LIMIT and agrees with the chip's verdict on every shape a cell or a
-    float32 stand-in of one has run (PERF.md section 6, PR 58)."""
+    `count` pieces of `cd`. Mosaic's own count is not this one. In pairs it
+    stands 1.9 MiB over this one at heads 128 wide and 1.0 to 2.7 under at 192
+    and 256 (its blocks of a head count less than two buffers there, its stack
+    more than one tile), and the limit's verdicts are the chip's all the same;
+    compact, nothing is over-counted to cover the stack, so it is counted, and
+    Mosaic stands from 1.2 MiB over this one (a window's tiles of 128 with a
+    sink at four pieces: 12.2 for 11.0) to 1.3 under at eleven shapes, and at
+    the one cell's that is held so 15.45 where this counts 15.5. This one is
+    read against VMEM_LIMIT and agrees with the chip's verdict on every shape
+    a cell or a float32 stand-in of one has run, in both forms (PERF.md
+    section 6, PR 58 and PR 61)."""
     held, block = pieces_held(cd, count), tile(keys, window)
     tall, k_len = heads_a_step(shared, block) * block, _round_up(keys, block)
-    width = _round_up(held * (held + 1) // 2 * sum(widths), LANES)
+    q_width, k_width = columns(widths, held, compact)[:2]
     item = jnp.dtype(cd).itemsize
-    scratch = (tall + k_len) * width * item + k_len * held * dv * item + tall * (2 * LANES + dv) * 4
-    blocks = 2 * 4 * ((tall + k_len) * sum(widths) + k_len * dv + tall * dv)
-    return scratch + blocks + tall * block * 4
+    scratch = (tall * q_width + k_len * k_width + k_len * held * dv) * item + tall * (2 * LANES + dv) * 4
+    a_head, stack = k_len * (sum(widths) + dv), tall * block * 4
+    if compact:
+        a_head = math.gcd(block, PIECE_ROWS) * sum(_round_up(d, LANES) for d in (*widths, dv))
+        stack += tall * block * (4 + item) + tall * held * dv * 4
+    return scratch + 2 * 4 * (tall * (sum(widths) + dv) + a_head) + stack
 
 
 # What a kernel has by default on a v5e, which this one never raises (ROWS has
-# why), and what `sequence.attention_choice` reads `vmem_bytes` against before
-# it says `pallas`. The widest head a cell runs, `mimo_v2`'s 192-wide keys over
-# 128-wide values at three pieces, counts 15.1 MiB and is taken by the chip;
-# 256 wide both ways counts 22.5 MiB at three pieces and 16.5 at one float32
-# piece (the readings' stand-in), and the chip refuses both at run time (16.99
-# and 16.20 MiB by its own count): PERF.md section 6, PR 50 (a) and PR 58.
+# why), and what `held_compact` and `fits` read `vmem_bytes` against. The
+# widest head a cell runs in pairs, `mimo_v2`'s 192-wide keys over 128-wide
+# values at three pieces, counts 15.1 MiB and is taken by the chip; 256 wide
+# both ways counts 22.5 MiB in pairs at three pieces and 16.5 at one float32
+# piece (the readings' stand-in), and the chip refused both at run time
+# (PERF.md section 6, PR 50 (a) and PR 58); compact, three pieces count 15.5
+# MiB (Mosaic: 15.45) and the chip takes them (PR 61).
 VMEM_LIMIT = 16 << 20
 
 
-def _kernel(*refs, widths, reps, rep_v, dv, held, cd, scale, window, offset, block, keys, stacked, sunk=False):
+def held_compact(keys: int, window: int | None, widths: tuple[int, ...], dv: int, shared: int, cd, count: int) -> bool:
+    """Whether a call holds a key-value head COMPACT (`columns`, `vmem_bytes`):
+    where the pairs' form is past VMEM_LIMIT. One kernel, its operands' layout
+    chosen from the shapes: every shape that fits in pairs keeps the program
+    it had."""
+    return vmem_bytes(keys, window, widths, dv, shared, cd, count) > VMEM_LIMIT
+
+
+def fits(keys: int, window: int | None, widths: tuple[int, ...], dv: int, shared: int, cd, count: int) -> bool:
+    """Whether a call fits VMEM_LIMIT as `held_compact` would have it held:
+    what `sequence.attention_choice` asks before it says `pallas`."""
+    compact = held_compact(keys, window, widths, dv, shared, cd, count)
+    return vmem_bytes(keys, window, widths, dv, shared, cd, count, compact) <= VMEM_LIMIT
+
+
+def _kernel(*refs, widths, reps, rep_v, dv, held, cd, scale, window, offset, block, keys, stacked, compact, sunk=False):
     parts = len(widths)
     tall = stacked * block
-    pairs = [(i, j) for i in range(held) for j in range(held) if i + j < held]
     q_refs, k_refs, v_ref = refs[:parts], refs[parts:2 * parts], refs[2 * parts]
     # With a sink: its logits [heads] in SMEM after the values, and the
     # share's block after the result's.
     sink_ref, o_ref, share_ref = (refs[2 * parts + 1:2 * parts + 4] if sunk else (None, refs[2 * parts + 1], None))
+    if compact:  # a chunk's two buffers an operand (the keys' parts, the values) and their copies' semaphores
+        *refs, sems = refs
+        refs, bufs = refs[:-(parts + 1)], refs[-(parts + 1):]
     qcat, kcat, vcat, m_ref, l_ref, acc_ref = refs[-6:]
-    head, qi = pl.program_id(1), pl.program_id(2)
-    used = len(pairs) * sum(widths)
+    row, head, qi = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    _, _, q_places, k_places, products = columns(widths, held, compact)
 
-    def cut(ref, rows, store):
-        # The whole key range of a head, PIECE_ROWS at a time.
+    def cut(operand, ref, rep, rows, store):
+        # The whole key range of a head, PIECE_ROWS at a time: from the head's
+        # block in VMEM, or, compact, from the array in HBM through two small
+        # buffers, a chunk on its way while the one before it is cut.
+        chunks = keys // rows
+
+        def copy(c, slot):
+            return pltpu.make_async_copy(
+                ref.at[row, head // rep, pl.ds(c * rows, rows), :], bufs[operand].at[slot],
+                sems.at[operand, slot])
+
         def chunk(c, carry):
             at = pl.ds(pl.multiple_of(c * rows, rows), rows)
-            store(at, _pieces(ref[at, :].astype(jnp.float32), cd, held))
+            if compact:
+                @pl.when(c + 1 < chunks)
+                def _next():
+                    copy(c + 1, (c + 1) % 2).start()
+
+                copy(c, c % 2).wait()
+                x = bufs[operand][c % 2][:, :(*widths, dv)[operand]]
+            else:
+                x = ref[at, :]
+            store(at, _pieces(x.astype(jnp.float32), cd, held))
             return carry
 
-        jax.lax.fori_loop(0, keys // rows, chunk, None)
+        if compact:
+            copy(0, 0).start()
+        jax.lax.fori_loop(0, chunks, chunk, None)
 
     rows = math.gcd(block, PIECE_ROWS)
-    column = 0
     for part, (width, rep) in enumerate(zip(widths, reps)):
-        first_column = column
-
         @pl.when((qi == 0) & (head % rep == 0))
-        def _keys(part=part, width=width, first_column=first_column):
+        def _keys(part=part, width=width, rep=rep):
             def store(at, ks):
-                for c, (_, j) in enumerate(pairs):
-                    kcat[at, first_column + c * width:first_column + (c + 1) * width] = ks[j]
-                if part == 0 and used < kcat.shape[1]:
-                    kcat[at, used:] = jnp.zeros((rows, kcat.shape[1] - used), cd)
+                for _, j, first in (place for place in k_places if place[0] == part):
+                    kcat[at, first:first + width] = ks[j]
+                if part == 0:
+                    for first, stop in _gaps(k_places, widths, kcat.shape[1]):
+                        kcat[at, first:stop] = jnp.zeros((rows, stop - first), cd)
 
-            cut(k_refs[part], rows, store)
+            cut(part, k_refs[part], rep, rows, store)
 
         qs = _pieces(q_refs[part][...].reshape(tall, width).astype(jnp.float32), cd, held)
-        for c, (i, _) in enumerate(pairs):
-            qcat[:, column + c * width:column + (c + 1) * width] = qs[i]
-        column += len(pairs) * width
-    if used < qcat.shape[1]:
-        qcat[:, used:] = jnp.zeros((tall, qcat.shape[1] - used), cd)
+        for _, i, first in (place for place in q_places if place[0] == part):
+            qcat[:, first:first + width] = qs[i]
+    for first, stop in _gaps(q_places, widths, qcat.shape[1]):
+        qcat[:, first:stop] = jnp.zeros((tall, stop - first), cd)
 
     @pl.when((qi == 0) & (head % rep_v == 0))
     def _values():
@@ -210,7 +317,7 @@ def _kernel(*refs, widths, reps, rep_v, dv, held, cd, scale, window, offset, blo
             for j, piece in enumerate(vs):
                 vcat[at, j * dv:(j + 1) * dv] = piece
 
-        cut(v_ref, rows, store)
+        cut(parts, v_ref, rep_v, rows, store)
 
     def sink_logits():
         # Row r is of the step's head r // block: each head's own logit.
@@ -234,8 +341,13 @@ def _kernel(*refs, widths, reps, rep_v, dv, held, cd, scale, window, offset, blo
     def key_block(kb, carry):
         start = pl.multiple_of(kb * block, block)
         at = pl.ds(start, block)
-        s = jax.lax.dot_general(
-            qcat[...], kcat[at, :], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
+        s = None
+        for q_first, k_first, depth in products:
+            pairs = jax.lax.dot_general(
+                qcat[:, q_first:q_first + depth], kcat[at, k_first:k_first + depth], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            s = pairs if s is None else s + pairs
+        s = s * scale
         k_pos = start + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
         seen = k_pos <= q_pos
         if window is not None:
@@ -266,9 +378,9 @@ def _kernel(*refs, widths, reps, rep_v, dv, held, cd, scale, window, offset, blo
         share_ref[...] = (jnp.exp(sink_logits() - m_ref[...]) / l_ref[...]).reshape(stacked, block, 1)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "window", "cd", "count", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "window", "cd", "count", "interpret", "compact"))
 def attention(qs, ks, v, *, scale: float, window: int | None, cd, count: int, interpret: bool = False,
-              sink: jax.Array | None = None):
+              sink: jax.Array | None = None, compact: bool | None = None):
     """softmax(sum over the parts of `q k'` * scale | causal, window) v.
 
     qs    a tuple of `[n, H, Lq, d_p]` float32, a part each: the queries stand
@@ -282,14 +394,18 @@ def attention(qs, ks, v, *, scale: float, window: int | None, cd, count: int, in
 
     Activations enter the products as `count` pieces of `cd`, in the pairs
     `i + j < count`; position t sees `t - window + 1 .. t` (all up to t
-    without a window)."""
+    without a window). `compact` is `held_compact`'s answer at these shapes
+    unless given (the tests and the chip's readings run both forms)."""
     n, heads, queries, _ = qs[0].shape
     keys, dv = v.shape[2], v.shape[3]
     widths = tuple(q.shape[-1] for q in qs)
     held = pieces_held(cd, count)
     pairs = held * (held + 1) // 2  # (i, j), i + j < held
     block = tile(keys, window)
-    stacked = heads_a_step(min(heads // x.shape[1] for x in (*ks, v)), block)
+    shared = min(heads // x.shape[1] for x in (*ks, v))
+    stacked = heads_a_step(shared, block)
+    if compact is None:
+        compact = held_compact(keys, window, widths, dv, shared, cd, count)
     q_len, k_len = _round_up(queries, block), _round_up(keys, block)
 
     def padded(x, length):  # along the positions, with zeros
@@ -300,13 +416,17 @@ def attention(qs, ks, v, *, scale: float, window: int | None, cd, count: int, in
     qs = tuple(padded(q, q_len).reshape(n, heads // stacked, stacked, q_len, q.shape[-1]) for q in qs)
     ks = tuple(padded(k, k_len) for k in ks)
     v = padded(v, k_len)
-    width = _round_up(pairs * sum(widths), LANES)
+    if compact:  # a chunk is copied in whole lanes
+        *ks, v = (jnp.pad(x, ((0, 0),) * 3 + ((0, -x.shape[-1] % LANES),)) for x in (*ks, v))
+    q_width, k_width = columns(widths, held, compact)[:2]
     computed = n * heads * tile_pairs(queries, keys, window)
 
     def stacked_heads(d):
         return pl.BlockSpec((None, None, stacked, block, d), lambda b, g, i: (b, g, 0, i, 0))
 
-    def a_head(x):  # of the keys or values: the one that group g's heads read
+    def a_head(x):  # of the keys or values: the one that group g's heads read; compact, the array where it lies
+        if compact:
+            return pl.BlockSpec(memory_space=pl.ANY)
         rep = heads // x.shape[1] // stacked
         return pl.BlockSpec((None, None, k_len, x.shape[-1]), lambda b, g, i: (b, g // rep, 0, 0))
 
@@ -316,7 +436,7 @@ def attention(qs, ks, v, *, scale: float, window: int | None, cd, count: int, in
     body = functools.partial(
         _kernel, widths=widths, reps=tuple(heads // k.shape[1] // stacked for k in ks),
         rep_v=heads // v.shape[1] // stacked, dv=dv, held=held, cd=cd, scale=scale, window=window,
-        offset=keys - queries, block=block, keys=k_len, stacked=stacked)
+        offset=keys - queries, block=block, keys=k_len, stacked=stacked, compact=compact)
     in_specs = [stacked_heads(d) for d in widths] + [a_head(k) for k in ks] + [a_head(v)]
     out_shape, out_specs, operands = result(dv), stacked_heads(dv), (*qs, *ks, v)
     if sink is not None:
@@ -324,20 +444,25 @@ def attention(qs, ks, v, *, scale: float, window: int | None, cd, count: int, in
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
         out_shape, out_specs = (out_shape, result(1)), (out_specs, stacked_heads(1))
         operands += (sink.astype(jnp.float32).reshape(heads),)
+    scratch = [
+        pltpu.VMEM((stacked * block, q_width), cd),
+        pltpu.VMEM((k_len, k_width), cd),
+        pltpu.VMEM((k_len, held * dv), cd),
+        pltpu.VMEM((stacked * block, 1), jnp.float32),
+        pltpu.VMEM((stacked * block, 1), jnp.float32),
+        pltpu.VMEM((stacked * block, dv), jnp.float32),
+    ]
+    if compact:
+        rows = math.gcd(block, PIECE_ROWS)
+        scratch += [pltpu.VMEM((2, rows, x.shape[-1]), jnp.float32) for x in (*ks, v)]
+        scratch.append(pltpu.SemaphoreType.DMA((len(widths) + 1, 2)))
     out = pl.pallas_call(
         body,
         out_shape=out_shape,
         grid=(n, heads // stacked, q_len // block),
         in_specs=in_specs,
         out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((stacked * block, width), cd),
-            pltpu.VMEM((k_len, width), cd),
-            pltpu.VMEM((k_len, held * dv), cd),
-            pltpu.VMEM((stacked * block, 1), jnp.float32),
-            pltpu.VMEM((stacked * block, 1), jnp.float32),
-            pltpu.VMEM((stacked * block, dv), jnp.float32),
-        ],
+        scratch_shapes=scratch,
         # The pieces of a head's keys and values are made at its first step
         # and read by the steps after it: every axis in order.
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),

@@ -101,25 +101,47 @@ def _head_pairs_window_512(count):
     return (halves,), (k.reshape(1, 1024, 2, 64),), v, 512, 64 ** -0.5, xla
 
 
-FORMS = [_grouped_full, _window_off_the_block, _nope_and_shared_rope, _head_pairs_window_512]
+def _wide_heads_eight_a_group(count):
+    # qwen3_next's full layer, short: heads 256 wide both ways, 8 query heads a key-value head, two of them a step.
+    q, k, v = _normal(15, 1, 256, 8, 256), _normal(16, 1, 256, 1, 256), _normal(17, 1, 256, 1, 256)
+
+    def xla():
+        return sequence.blocked_attention(q.reshape(1, 256, 1, 8, 256), k, v, None, CD, count).reshape(q.shape)
+
+    return (q,), (k,), v, None, 256 ** -0.5, xla
 
 
-def _kernel(qs, ks, v, window, scale, count):
-    with interpreted([]):
-        return sequence.attention(qs, ks, v, window, CD, count, scale)
+FORMS = [_grouped_full, _window_off_the_block, _nope_and_shared_rope, _head_pairs_window_512, _wide_heads_eight_a_group]
+# What the kernel holds of a key-value head: the keys' pieces a PAIR and the head's float32 block in VMEM, or COMPACT,
+# the pieces once each and the float32 rows from HBM a chunk at a time (`attention_kernel.columns`); the shapes choose
+# (`held_compact`), here every form runs in both.
+HELD = pytest.mark.parametrize("compact", [False, True], ids=["pairs", "compact"])
 
 
+def _kernel(qs, ks, v, window, scale, count, compact=None):
+    if compact is None:  # as a caller reaches it: the shapes choose
+        with interpreted([]):
+            return sequence.attention(qs, ks, v, window, CD, count, scale)
+    heads_first = functools.partial(jnp.transpose, axes=(0, 2, 1, 3))
+    return heads_first(attention_kernel.attention(
+        tuple(map(heads_first, qs)), tuple(map(heads_first, ks)), heads_first(v), scale=float(scale), window=window,
+        cd=jnp.dtype(CD), count=count, interpret=True, compact=compact))
+
+
+@HELD
 @pytest.mark.parametrize("count", [2, 3], ids=["two_pieces", "three_pieces"])
 @pytest.mark.parametrize("form", FORMS, ids=lambda f: f.__name__.strip("_"))
-def test_kernel_is_the_xla_path_to_float32_rounding(form, count):
+def test_kernel_is_the_xla_path_to_float32_rounding(form, count, compact):
     """Against the float32 result the kernel is where the XLA path is, to
     1e-6: three pieces hold a float32 value whole, so there the two agree to
     1e-6 themselves; two drop what is below 2 ** -17 of an operand, each path
     of ITS operands (the kernel cuts the exponentials before the sum divides
-    them), so there it is their errors that agree."""
+    them), so there it is their errors that agree. Either way of holding a
+    head: the same pieces in the same pairs, their products added in another
+    order."""
     qs, ks, v, window, scale, xla = form(count)
     want = _dense(qs, ks, v, window, scale)
-    got, stands = _kernel(qs, ks, v, window, scale, count), xla()
+    got, stands = _kernel(qs, ks, v, window, scale, count, compact), xla()
     assert got.shape == stands.shape == want.shape
     error = lambda x: float(jnp.max(jnp.abs(x - want)))  # noqa: E731
     assert error(got) <= error(stands) + 1e-6
@@ -129,14 +151,36 @@ def test_kernel_is_the_xla_path_to_float32_rounding(form, count):
         assert error(got) < 1e-4  # 2 ** -17 of operands of order one, not 2 ** -9
 
 
+@HELD
 @pytest.mark.parametrize("form", FORMS, ids=lambda f: f.__name__.strip("_"))
-def test_one_piece_is_told_apart(form):
+def test_one_piece_is_told_apart(form, compact):
     """The precision below the stated one, planted through the caller's piece
     count: bfloat16 operands alone are a thousand times further out."""
     qs, ks, v, window, scale, _ = form(3)
     want = _dense(qs, ks, v, window, scale)
-    error = lambda count: float(jnp.max(jnp.abs(_kernel(qs, ks, v, window, scale, count) - want)))  # noqa: E731
+    error = lambda count: float(jnp.max(jnp.abs(_kernel(qs, ks, v, window, scale, count, compact) - want)))  # noqa: E731
     assert error(1) > 1e-3 > 1e-5 > error(3)
+
+
+def test_the_shapes_choose_how_a_head_is_held_and_a_caller_gets_that_form():
+    """`sequence.attention` passes no form: at a shape that fits in pairs the
+    kernel it reaches is the pairs' one, bit for bit, and `columns` lays the
+    same pairs out either way (six column chunks of a part, or three pieces
+    met by three products 3, 2 and 1 chunks deep)."""
+    qs, ks, v, window, scale, _ = _grouped_full(3)
+    np.testing.assert_array_equal(_kernel(qs, ks, v, window, scale, 3), _kernel(qs, ks, v, window, scale, 3, False))
+    q_width, k_width, q_places, k_places, products = attention_kernel.columns((64,), 3, False)
+    assert (q_width, k_width, products) == (384, 384, [(0, 0, 384)])
+    assert [(i, j) for (_, i, _), (_, j, _) in zip(q_places, k_places)] == [(0, 0), (0, 1), (0, 2), (1, 0), (1, 1), (2, 0)]
+    q_width, k_width, q_places, k_places, products = attention_kernel.columns((64,), 3, True)
+    assert (q_width, k_width, products) == (512, 256, [(0, 0, 256), (256, 0, 128), (384, 0, 128)])
+    assert k_places == [(0, 0, 0), (0, 1, 64), (0, 2, 128)]
+    assert q_places == [(0, 0, 0), (0, 0, 64), (0, 0, 128), (0, 1, 256), (0, 1, 320), (0, 2, 384)]
+    # two parts stand side by side inside a piece, and the last product's zeros fill its lanes
+    assert attention_kernel.columns((128, 64), 2, True)[2:] == (
+        [(0, 0, 0), (1, 0, 128), (0, 0, 192), (1, 0, 320), (0, 1, 384), (1, 1, 512)],
+        [(0, 0, 0), (1, 0, 128), (0, 1, 192), (1, 1, 320)], [(0, 0, 384), (384, 0, 256)])
+    assert attention_kernel._gaps(attention_kernel.columns((128, 64), 2, True)[2], (128, 64), 640) == [(576, 640)]
 
 
 def test_the_kernels_tiles_skip_what_the_masks_throw_away():

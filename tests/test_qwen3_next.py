@@ -323,7 +323,12 @@ CELLS_ATTENTION = {
     "mimo_v2_5_rerank full": (2048, None, (192,), 128, 16, 3),
     "mimo_v2_5_rerank window": (2048, 128, (192,), 128, 8, 3),
     "falcon_h1_34b_rerank": (2048, None, (128,), 128, 5, 2),
+    "qwen3_next_80b_rerank full": (2048, None, (256,), 256, 8, 3),
+    "nemotron3_super_120b_rerank": (2048, None, (128,), 128, 16, 3),
 }
+# The one shape of a cell that does not fit VMEM with the keys' pieces a pair and a head's float32 block there: held
+# compact (PR 61); every other keeps the program it had.
+COMPACT = {"qwen3_next_80b_rerank full"}
 
 
 @pytest.mark.parametrize("cell", sorted(CELLS_ATTENTION))
@@ -335,28 +340,58 @@ def test_the_vmem_rule_answers_pallas_for_every_cells_shapes(cell, monkeypatch):
         choice = sequence.attention_choice(keys, keys, window, count, heads)
         assert choice == sequence.attention_choice(keys, keys, window, count)  # as before the rule
     assert choice == {"kernel": "pallas", "block": attention_kernel.tile(keys, window), "pieces": count}
-    assert attention_kernel.vmem_bytes(keys, window, widths, dv, shared, jnp.bfloat16, count) <= attention_kernel.VMEM_LIMIT
+    compact = attention_kernel.held_compact(keys, window, *heads, count)
+    assert attention_kernel.vmem_bytes(keys, window, *heads, count, compact=compact) <= attention_kernel.VMEM_LIMIT
 
 
-def test_the_vmem_rule_keeps_256_wide_heads_at_three_pieces_off_the_kernel(monkeypatch):
+@pytest.mark.parametrize("cell", sorted(CELLS_ATTENTION))
+def test_every_cell_keeps_the_form_it_had_and_the_one_that_had_none_is_held_compact(cell):
+    keys, window, widths, dv, shared, count = CELLS_ATTENTION[cell]
+    assert attention_kernel.held_compact(keys, window, widths, dv, shared, jnp.bfloat16, count) == (cell in COMPACT)
+
+
+def test_the_vmem_rule_takes_256_wide_heads_at_three_pieces_held_compact(monkeypatch):
     """The published full layer: keys and values 256 wide, 8 query heads a
-    key-value head, three pieces over 2,048 keys: 22.5 MiB of scratch, blocks
-    and a score tile, which the chip refuses at warm-up; so it does the
-    float32 stand-in of the readings (one piece, 16.5 MiB by this count, 16.20
-    by the chip's)."""
+    key-value head, three pieces over 2,048 keys. In pairs 22.5 MiB of
+    scratch, blocks and a score tile, which the chip refused at warm-up (PR
+    58); compact (the keys' pieces once each, the float32 keys and values
+    read from HBM a chunk at a time) 15.5 MiB by the same count, the stack of
+    a key block's work counted in (Mosaic's own: 15.45), which the chip takes (PERF.md section 6, PR 61): the rule answers `pallas`, the
+    note carries no `"why"`, and the counters count the kernel's tiles. So
+    does the float32 stand-in of the readings (one piece). The last layer's
+    one query keeps XLA's path as everywhere."""
     monkeypatch.setattr(sequence.jax, "default_backend", lambda: "tpu")
     heads = sequence.Heads((256,), 256, 8, jnp.bfloat16)
     assert attention_kernel.vmem_bytes(2048, None, (256,), 256, 8, jnp.bfloat16, 3) == 22544384 + (1 << 20)
+    assert attention_kernel.vmem_bytes(2048, None, (256,), 256, 8, jnp.bfloat16, 3, compact=True) == 31 << 19
+    took = {"kernel": "pallas", "block": 512, "pieces": 3}
     with sequence.serving_attention(notes := []):
-        assert sequence.attention_choice(2048, 2048, None, 3, heads) == {
-            "kernel": "xla", "block": 0, "pieces": 3, "why": "vmem"}
-        assert not sequence.takes_kernel(2048, 2048, None, 3, heads)
-        assert sequence.blocked_pairs(2048, 2048, None, 3, heads)[0] == sum(
-            (stop - start) * last for start, stop, _, last in sequence.query_blocks(2048, 2048))
-        assert sequence.attention_choice(2048, 2048, None, 3, heads._replace(cd=jnp.float32))["why"] == "vmem"
-        narrow = sequence.Heads((192,), 128, 16, jnp.float32)  # mimo_v2's stand-in has run the kernel (PR 50)
-        assert sequence.attention_choice(2048, 2048, None, 3, narrow)["kernel"] == "pallas"
+        assert sequence.attention_choice(2048, 2048, None, 3, heads) == took
+        assert sequence.takes_kernel(2048, 2048, None, 3, heads)
+        assert sequence.blocked_pairs(2048, 2048, None, 3, heads)[0] == attention_kernel.tile_pairs(2048, 2048) == 10 << 18
+        assert sequence.attention_choice(2048, 2048, None, 3, heads._replace(cd=jnp.float32)) == took
         assert sequence.attention_choice(1, 2048, None, 3, heads) == {"kernel": "xla", "block": 0, "pieces": 3}
+    assert notes == [took]
+
+
+# (keys, the parts' widths, the values' width, query heads a key-value head): what fits neither way at three pieces
+TOO_LARGE = {"512 wide": (2048, (512,), 512, 8), "384 wide": (2048, (384,), 384, 8), "3,072 keys 256 wide": (3072, (256,), 256, 8)}
+
+
+@pytest.mark.parametrize("shape", sorted(TOO_LARGE))
+def test_the_vmem_rule_keeps_what_fits_neither_way_off_the_kernel(shape, monkeypatch):
+    """Past the VMEM a kernel has even compact: XLA's blocks serve, the stamp
+    says why and the counters count XLA's blocks (a shape that does not fit
+    is refused by the chip at warm-up, not by the compiler)."""
+    keys, widths, dv, shared = TOO_LARGE[shape]
+    monkeypatch.setattr(sequence.jax, "default_backend", lambda: "tpu")
+    heads = sequence.Heads(widths, dv, shared, jnp.bfloat16)
+    assert attention_kernel.held_compact(keys, None, *heads, 3)
+    assert attention_kernel.vmem_bytes(keys, None, *heads, 3, compact=True) > attention_kernel.VMEM_LIMIT
+    with sequence.serving_attention(notes := []):
+        assert not sequence.takes_kernel(keys, keys, None, 3, heads)
+        assert sequence.blocked_pairs(keys, keys, None, 3, heads)[0] == sum(
+            (stop - start) * last for start, stop, _, last in sequence.query_blocks(keys, keys))
     assert notes == [{"kernel": "xla", "block": 0, "pieces": 3, "why": "vmem"}]
 
 
