@@ -319,10 +319,9 @@ def main() -> None:
             )
             say(f"requests: {json.dumps(counts)}")
             check_scores(scores, payloads)
-            for section in ("mesh", "kernels"):
-                block = monitoring(rest_port, section)
-                if block is not None:
-                    say(f"{section}: {json.dumps(block)}")
+            mesh_block = monitoring(rest_port, "mesh")
+            if mesh_block is not None:
+                say(f"mesh: {json.dumps(mesh_block)}")
             say("batcher: " + json.dumps(
                 monitoring(rest_port, "metrics").get("batcher")))
             runtime = monitoring(rest_port, "runtime")

@@ -106,9 +106,6 @@ class ResilienceCounters:
     # away from immediately, no ejection budget spent, and the
     # rebuilding retry window never cycled.
     draining_hints: int = 0
-    # int8 score response wire (ISSUE 12): responses whose score tensor
-    # arrived as DT_INT8 + sidecars and was dequantized locally.
-    int8_responses: int = 0
     # Integrity plane (ISSUE 20): responses whose score tensor failed
     # the x-dts-score-crc verify — caught BEFORE the merge, recorded
     # kind="corrupt" on the scoreboard, retried on another backend.
@@ -147,9 +144,6 @@ class _AttemptBudget:
 # dependency, so the literals live on both sides).
 _CRITICALITY_KEY = "x-dts-criticality"
 _RETRY_AFTER_KEY = "retry-after-ms"
-# int8 score response wire opt-in (ops/autotune.py SCORE_WIRE_KEY — the
-# literal lives on both sides for the same jax-free-import reason).
-_SCORE_WIRE_KEY = "x-dts-score-wire"
 # Substring a quarantined replica's UNAVAILABLE refusal carries
 # (serving/batcher.py DeviceQuarantinedError message: "replica
 # quarantined: device executor is being rebuilt ..."): the backend is
@@ -424,7 +418,6 @@ class ShardedPredictClient:
         criticality: str = "",
         stream_chunk_candidates: int = 0,
         max_attempts_total: int = 0,
-        score_wire_int8: bool = False,
         placement: str = "contiguous",
         integrity_checksums: bool = False,
     ):
@@ -539,12 +532,6 @@ class ShardedPredictClient:
         # per-group blobs with their homes + row indices pinned on the
         # PreparedRequest.
         self.placement = placement
-        # int8 score response wire (ISSUE 12): opt into DT_INT8 score
-        # tensors (+ scale/min sidecar outputs, dequantized locally) via
-        # x-dts-score-wire metadata — 4x fewer response bytes per score
-        # against a server with [kernels] int8_score_wire on; servers
-        # without the plane ignore the metadata and answer normally.
-        self.score_wire_int8 = bool(score_wire_int8)
         # Integrity wire checksums (ISSUE 20): stamp x-dts-input-crc
         # CRC32C sidecars over each shard's tensor bytes (an
         # [integrity]-armed server verifies at decode and fails ONLY the
@@ -691,8 +678,6 @@ class ShardedPredictClient:
                 # a fleet router caps its own attempt budget at
                 # min(local, advertised).
                 md.append((_RETRY_BUDGET_KEY, str(self.max_attempts_total)))
-            if self.score_wire_int8:
-                md.append((_SCORE_WIRE_KEY, "int8"))
             md.extend(extra_md)
             metadata = tuple(md) or None
             t0 = time.perf_counter()
@@ -1233,13 +1218,6 @@ class ShardedPredictClient:
                 ) from e
             if extract is not None:
                 return extract(resp)
-            if self.score_wire_int8:
-                tp = resp.outputs[self.output_key]
-                if tp.dtype == codec.DataType.DT_INT8:
-                    self.counters.int8_responses += 1
-                return codec.dequantize_response_output(
-                    resp.outputs, self.output_key
-                )
             return codec.to_ndarray(resp.outputs[self.output_key])
         assert last is not None, "exhaustion implies at least one failure"
         raise PredictClientError(
@@ -1894,8 +1872,8 @@ def _credentials_from_config(cfg):
 # Per-row stage provenance output a cascade-armed server appends to the
 # response (serving/cascade.py): 1 = the row was pruned after stage 1 and
 # carries its stage-1 score; 2 = the row survived and carries the full
-# model's score. Rides the response like the int8-wire sidecars — an
-# extra tensor beyond the signature, absent when the cascade is off.
+# model's score. Rides the response as an extra tensor beyond the
+# signature, absent when the cascade is off.
 CASCADE_STAGE_KEY = "cascade_stage"
 
 

@@ -173,20 +173,13 @@ def to_ndarray(tp: fw.TensorProto) -> np.ndarray:
     raise CodecError(f"{field} holds {nvals} elements, shape {dims} needs {n}")
 
 
-# ------------------------------------------------- int8 score response wire
+# ------------------------------------------------------ int8 score encoding
 #
-# ISSUE 12: the network twin of the batcher's int8 D2H compaction — a
-# client that opts in (x-dts-score-wire: int8 metadata, against a server
-# with [kernels] int8_score_wire enabled) receives the score tensor as
-# DT_INT8 plus two 1-element DT_FLOAT sidecar outputs carrying the affine
-# (scale, min) pair, and dequantizes locally: 4x fewer response bytes per
-# score than f32 tensor_content, 2x fewer than a bf16 wire. Same
-# 254-level affine scheme as ops/transfer.py (kept numerically identical
-# but implemented here in pure numpy — this module must stay jax-free).
+# The host side of the batcher's int8 D2H output wire (ops/transfer.py
+# quantize_output_device / restore_outputs_host): the same 254-level affine
+# scheme, in pure numpy (this module must stay jax-free).
 
 Q8_WIRE_LEVELS = 254.0
-Q8_WIRE_SCALE_SUFFIX = "/q8_scale"
-Q8_WIRE_MIN_SUFFIX = "/q8_min"
 
 
 def quantize_scores(arr: np.ndarray) -> tuple[np.ndarray, float, float]:
@@ -205,21 +198,6 @@ def dequantize_scores(q: np.ndarray, scale: float, mn: float) -> np.ndarray:
     return (np.asarray(q, np.float32) + 127.0) * float(scale) + float(mn)
 
 
-def dequantize_response_output(outputs_map, key: str) -> np.ndarray:
-    """Client-side decode of one response output that MAY ride the int8
-    score wire: a DT_INT8 tensor with its two sidecar outputs present is
-    dequantized to float32; anything else decodes normally. `outputs_map`
-    is a PredictResponse.outputs protobuf map."""
-    tp = outputs_map[key]
-    skey, mkey = key + Q8_WIRE_SCALE_SUFFIX, key + Q8_WIRE_MIN_SUFFIX
-    if tp.dtype == DataType.DT_INT8 and skey in outputs_map and mkey in outputs_map:
-        q = to_ndarray(tp)
-        scale = float(to_ndarray(outputs_map[skey])[0])
-        mn = float(to_ndarray(outputs_map[mkey])[0])
-        return dequantize_scores(q, scale, mn)
-    return to_ndarray(tp)
-
-
 # ---------------------------------------------------- wire integrity (CRC)
 #
 # ISSUE 20: CRC32C (Castagnoli — the polynomial every storage/RPC stack
@@ -227,8 +205,8 @@ def dequantize_response_output(outputs_map, key: str) -> np.ndarray:
 # gRPC metadata on both directions so silent wire corruption is DETECTED
 # instead of served. Both ends checksum the same canonical form — the
 # DECODED ndarray's dtype/shape header + contiguous payload bytes — so
-# the check is encoding-independent (tensor_content, repeated fields,
-# and the int8 score wire all verify identically). Lives here because
+# the check is encoding-independent (tensor_content and repeated fields
+# verify identically). Lives here because
 # this module is the one tensor-bytes authority both the client package
 # (jax-free) and the server share.
 

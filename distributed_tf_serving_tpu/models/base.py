@@ -220,10 +220,6 @@ class ModelConfig:
     # numerics
     compute_dtype: str = "bfloat16"  # "float32" for AUC-parity mode
     param_dtype: str = "float32"
-    # Fused Pallas cross-layer kernel (DCN-v2 only). Wins when F*embed_dim is
-    # 128-lane aligned (e.g. 1024): activations stay VMEM-resident across
-    # layers. At unaligned widths padding eats the gain — hence opt-in.
-    use_pallas_cross: bool = False
 
     @property
     def cdtype(self) -> jnp.dtype:
@@ -339,24 +335,7 @@ def dense_init(rng: jax.Array, in_dim: int, out_dim: int, dtype) -> dict[str, ja
 
 
 def dense_apply(p: dict[str, jax.Array], x: jax.Array, compute_dtype) -> jax.Array:
-    """x @ w + b in compute_dtype with f32 accumulation on the MXU.
-
-    Accepts both param forms: the float {"w", "b"} layer and the int8
-    weight-only quantized {"qw", "qscale", "b"} form ops/quantize.py mints
-    (per-channel symmetric). For the quantized form the matmul streams the
-    int8 weights cast to compute dtype (magnitudes <= 127 are exact in
-    bf16) and the per-OUTPUT-channel scale folds into the f32 accumulator
-    output — algebraically identical to dequantizing the weights first,
-    without materializing an [in, out] float matrix per call."""
-    qw = p.get("qw")
-    if qw is not None:
-        y = jax.lax.dot_general(
-            x.astype(compute_dtype),
-            qw.astype(compute_dtype),
-            (((x.ndim - 1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * p["qscale"].astype(jnp.float32)
-        return y + p["b"].astype(jnp.float32)
+    """x @ w + b in compute_dtype with f32 accumulation on the MXU."""
     y = jax.lax.dot_general(
         x.astype(compute_dtype),
         p["w"].astype(compute_dtype),

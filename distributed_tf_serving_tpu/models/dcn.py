@@ -13,8 +13,7 @@ Cross layers (per Wang et al.):
                    (torchrec LowRankCrossNet; the dlrm_dcnv2 family, models/dlrm.py)
 
 The v2 matmul is the MXU hot op; it runs in compute_dtype (bf16 default) with
-f32 accumulation. The fused-elementwise Pallas variant lives in
-ops/cross_kernel.py and is numerically identical.
+f32 accumulation.
 """
 
 from __future__ import annotations
@@ -48,12 +47,9 @@ def _cross_init(rng, num_layers: int, d: int, full_matrix: bool, dtype, low_rank
 
 def cross_apply(layers, x0: jax.Array, compute_dtype) -> jax.Array:
     """Apply the stack of cross layers; x0 is [n, d] in compute_dtype.
-    Accepts the float {"w","b"} layers, the low-rank {"v","w","b"} form
+    Accepts the {"w","b"} layers and the low-rank {"v","w","b"} form
     (xw = (x @ v) @ w, the [n, r] intermediate rounded to compute_dtype for
-    the second matmul) and the int8 weight-only quantized
-    {"qw","qscale","b"} form (ops/quantize.py): the per-channel
-    scale folds into the f32 xw before the elementwise update, so the
-    quantized stack differs from f32 only by the weight rounding."""
+    the second matmul)."""
     x = x0
     for p in layers:
         b = p["b"].astype(jnp.float32)
@@ -63,13 +59,6 @@ def cross_apply(layers, x0: jax.Array, compute_dtype) -> jax.Array:
                 x, p["v"].astype(compute_dtype),
                 (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
             ).astype(compute_dtype)
-        if "qw" in p:  # quantized DCN-v2 (v1 rank-1 layers never quantize)
-            xw = jax.lax.dot_general(
-                h, p["qw"].astype(compute_dtype),
-                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-            ) * p["qscale"].astype(jnp.float32)
-            x = (x0.astype(jnp.float32) * (xw + b) + x.astype(jnp.float32)).astype(compute_dtype)
-            continue
         w = p["w"].astype(compute_dtype)
         if w.ndim == 2:  # DCN-v2
             xw = jax.lax.dot_general(
@@ -106,32 +95,8 @@ def _build(config: ModelConfig) -> Model:
             params["embedding"], batch["feat_ids"], batch["feat_wts"], cd, config.embed_dim
         )
         x0 = emb.reshape(emb.shape[0], d)  # [n, F*D]
-        use_fused = (
-            config.use_pallas_cross
-            and config.cross_full_matrix
-            # The legacy cross-only kernel takes float stacked weights; a
-            # quantized tree (ops/quantize.py {"qw"} form) rides the XLA
-            # path here — the int8-operand FUSED kernel is the serving
-            # batcher's per-bucket variant, not this opt-in.
-            and "w" in params["cross"][0]
-        )
-        if use_fused:
-            from ..ops.cross_kernel import fits_vmem
-
-            # Oversized stacks (all L weight matrices are VMEM-resident in
-            # the fused kernel) fall back to the per-layer XLA path.
-            use_fused = fits_vmem(d, config.num_cross_layers, cd)
         with jax.named_scope("cross"):
-            if use_fused:
-                from ..ops.cross_kernel import cross_params_to_stacked, fused_cross_apply
-
-                w, b = cross_params_to_stacked(params["cross"])
-                # interpret mode keeps the kernel runnable on the CPU test mesh.
-                xc = fused_cross_apply(
-                    x0, w, b, compute_dtype=cd, interpret=jax.default_backend() == "cpu"
-                )
-            else:
-                xc = cross_apply(params["cross"], x0, cd)
+            xc = cross_apply(params["cross"], x0, cd)
         xd = mlp_apply(params["mlp"], x0, cd)
         h = jnp.concatenate([xc.astype(jnp.float32), xd.astype(jnp.float32)], axis=-1)
         logit = dense_apply(params["out"], h, cd)[:, 0]

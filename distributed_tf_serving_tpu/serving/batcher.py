@@ -53,12 +53,14 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import hashlib
 import re
 import threading
 import time
 import weakref
 from collections.abc import Callable
+from typing import NamedTuple
 from collections import OrderedDict, deque
 from concurrent.futures import Future, InvalidStateError, ThreadPoolExecutor
 
@@ -698,8 +700,7 @@ def _with_step_stats(apply_stats, finish):
     `apply` and the trace-time `finish` of _build_entry, such that the
     executable returns the counters under STEP_STATS_KEY beside the outputs
     that the selection and the wire downcast leave. A variant that returns
-    something else than `finish`'s (top-k, prune) or runs another apply (the
-    kernel plane's) carries none."""
+    something else than `finish`'s (top-k, prune) carries none."""
 
     def apply(params, batch):
         out, stats = apply_stats(params, batch)
@@ -762,6 +763,40 @@ class _Timeline:
             ))
 
 
+class ServedKernel(NamedTuple):
+    """A Pallas kernel the one-chip entry may run in place of XLA's path, and
+    every name its bookkeeping goes by. WHETHER it runs is the family's
+    `*_choice` (named beside each row), at trace time, from what the trace
+    sees; the batcher only notes what was chosen."""
+
+    kind: str
+    stamp: str  # /monitoring's `startup.<stamp>`: the choice as traced, a servable
+    counter: str  # BatcherStats' and the metrics block's count of the batches that ran it
+    phase: str  # the phase that counts the same
+    pallas_key: str  # the key of a note that reads "pallas" where the kernel runs
+    # Where rungs or layers differ, the stamp is the note of the highest
+    # (is pallas, *rank(note)).
+    rank: Callable[[dict], tuple]
+
+
+def _served(kind: str, stamp: str, pallas_key: str, rank) -> ServedKernel:
+    return ServedKernel(kind, stamp, f"{kind}_kernel_batches", f"batch.{kind}_kernel", pallas_key, rank)
+
+
+SERVED_KERNELS: tuple[ServedKernel, ...] = (
+    # models/embeddings.py gather_choice: the embedding rows' gather.
+    _served("gather", "gather", "kernel", lambda n: (n["in_flight"],)),
+    # models/sequence.py attention_choice: the attention at all positions.
+    _served("attention", "attention", "kernel", lambda n: (n["block"], "why" in n)),
+    # models/routed.py grouped_choice: a routed layer's held experts.
+    _served("grouped", "grouped", "kernel", lambda n: (n.get("rows", 0),)),
+    # models/olmo_hybrid.py delta_choice: the gated delta rule's chunk pass.
+    _served("delta", "delta_rule", "kernel", lambda n: ()),
+    # models/falcon_h1.py ssd_choice: a Mamba-2 mixer's SSD chunk walk.
+    _served("ssd", "ssd", "path", lambda n: (n["chunk"],)),
+)
+
+
 @dataclasses.dataclass
 class BatcherStats:
     """Occupancy/queueing gauges (SURVEY.md §5 metrics obligations)."""
@@ -774,22 +809,12 @@ class BatcherStats:
     # fold + pack + pad + concat in one pass an input, for any combined
     # layout, instead of 4 python/numpy passes + 3 temporaries).
     fused_batches: int = 0
-    # Batches that ran an entry whose embedding gather is the Pallas kernel
-    # (models/embeddings.py gather_choice; `startup.gather` names it).
+    # Batches that ran an entry whose step runs that Pallas kernel: a field a
+    # row of SERVED_KERNELS, by the row's `counter`.
     gather_kernel_batches: int = 0
-    # And those whose attention is the Pallas kernel (models/sequence.py
-    # attention_choice; `startup.attention` names it).
     attention_kernel_batches: int = 0
-    # Batches whose entry runs the routed layers' held experts through the
-    # Pallas grouped kernels (models/routed.py grouped_choice;
-    # `startup.grouped` names them).
     grouped_kernel_batches: int = 0
-    # Batches whose entry walks the gated delta rule's chunks in the Pallas
-    # kernel (models/olmo_hybrid.py delta_choice; `startup.delta_rule` names
-    # it).
     delta_kernel_batches: int = 0
-    # Batches whose entry walks a Mamba-2 mixer's SSD chunks in the Pallas
-    # kernel (models/falcon_h1.py ssd_choice; `startup.ssd` names the path).
     ssd_kernel_batches: int = 0
     # Batches of one request that its own handler thread closed and staged
     # (submit's direct crossing): no collector, no coalesce window, no
@@ -842,6 +867,10 @@ class BatcherStats:
     # to open before issuing the next batch (inflight_window armed).
     inflight_peak: int = 0
     inflight_window_waits: int = 0
+
+    def kernel_batches(self) -> dict[str, int]:
+        """The SERVED_KERNELS counters by name, as the metrics block holds them."""
+        return {k.counter: getattr(self, k.counter) for k in SERVED_KERNELS}
 
     @property
     def mean_occupancy(self) -> float:
@@ -930,13 +959,6 @@ class DynamicBatcher:
         # never reach the completer, so only freshly computed scores are
         # sketched. None (default) costs one attribute read per batch.
         self.quality = quality
-        # Kernel plane (ops/autotune.py, ISSUE 12): a KernelManager whose
-        # per-bucket decision table routes device execution to the int8
-        # weight-quantized params and/or the fused Pallas serving kernel —
-        # ONLY where the autotune harness measured a win and the accuracy
-        # gates passed. None (default) costs one attribute read per
-        # dispatch and behavior is bit-identical to the pre-plane stack.
-        self.kernels = None
         # Utilization plane (serving/utilization.py): an OccupancyLedger
         # fed one interval per completed batch from the existing
         # dispatch/readback sites, plus cheap wait-interval records while
@@ -1092,44 +1114,15 @@ class DynamicBatcher:
         self._upload_formats: weakref.WeakKeyDictionary[Servable, list] = (
             weakref.WeakKeyDictionary()
         )
-        # servable -> what lookup_rows chose for each gather its entry has
-        # been traced with (models/embeddings.py gather_choice):
-        # /monitoring's `startup.gather`; and the servables of them whose
-        # entry gathers with the Pallas kernel, whose batches are counted.
-        self._gathers: weakref.WeakKeyDictionary[Servable, list] = (
+        # servable -> {kind: what that kind's `*_choice` chose, each time its
+        # entry was traced} (SERVED_KERNELS): `startup.<stamp>`; and servable
+        # -> the rows whose kernel its entry runs, whose batches are counted.
+        self._kernel_notes: weakref.WeakKeyDictionary[Servable, dict[str, list]] = (
             weakref.WeakKeyDictionary()
         )
-        self._gather_kernel: weakref.WeakSet[Servable] = weakref.WeakSet()
-        # Likewise what each attention of its entry chose
-        # (models/sequence.py attention_choice): /monitoring's
-        # `startup.attention`; and the servables whose entry runs the Pallas
-        # attention kernel, whose batches are counted.
-        self._attentions: weakref.WeakKeyDictionary[Servable, list] = (
+        self._kernel_kinds: weakref.WeakKeyDictionary[Servable, tuple[ServedKernel, ...]] = (
             weakref.WeakKeyDictionary()
         )
-        self._attention_kernel: weakref.WeakSet[Servable] = weakref.WeakSet()
-        # And what each routed layer's held experts chose (models/routed.py
-        # grouped_choice): `startup.grouped`; and the servables whose entry
-        # runs the Pallas grouped kernels, whose batches are counted.
-        self._groupeds: weakref.WeakKeyDictionary[Servable, list] = (
-            weakref.WeakKeyDictionary()
-        )
-        self._grouped_kernel: weakref.WeakSet[Servable] = weakref.WeakSet()
-        # And what each gated delta rule's chunk pass chose
-        # (models/olmo_hybrid.py delta_choice): `startup.delta_rule`; and the
-        # servables whose entry runs the Pallas kernel, whose batches are
-        # counted.
-        self._deltas: weakref.WeakKeyDictionary[Servable, list] = (
-            weakref.WeakKeyDictionary()
-        )
-        self._delta_kernel: weakref.WeakSet[Servable] = weakref.WeakSet()
-        # And how each Mamba-2 mixer's SSD walks a row (models/falcon_h1.py
-        # ssd_choice): `startup.ssd`; and the servables whose entry runs the
-        # Pallas kernel, whose batches are counted.
-        self._ssds: weakref.WeakKeyDictionary[Servable, list] = (
-            weakref.WeakKeyDictionary()
-        )
-        self._ssd_kernel: weakref.WeakSet[Servable] = weakref.WeakSet()
         # And every product of an activation in pieces against a weight that
         # its entry was traced with, `(M, k, n, pieces, form)`
         # (models/sequence.py product): `startup.products`. Read at a
@@ -1137,7 +1130,7 @@ class DynamicBatcher:
         self._products: weakref.WeakKeyDictionary[Servable, list] = (
             weakref.WeakKeyDictionary()
         )
-        # _jit_for is reached from the batcher thread (fused-path
+        # jit_entry is reached from the batcher thread (fused-path
         # eligibility) AND the dispatch thread; one lock keeps the entry
         # build single-shot.
         self._jit_lock = threading.Lock()
@@ -1765,15 +1758,21 @@ class DynamicBatcher:
 
     def jit_entry(self, servable: Servable) -> tuple[Callable, dict[str, str], bool]:
         """The (jitted fn, transfer spec, combined) this batcher serves
-        `servable` with — public so the kernel autotune (ops/autotune.py)
-        and tests time and inspect the EXACT serving executable, warm caches
-        included, instead of compiling a lookalike. When
+        `servable` with — public so tests time and inspect the EXACT
+        serving executable, warm caches included, instead of compiling a
+        lookalike. When
         `combined` is True the fn signature is (params, uint32_buffer,
         layout) with layout static (ops/transfer.py combined_layout); both
         shapes accept optional keywords (out_keys, topk, n_valid)
         selecting the output-compaction variant — defaults reproduce the
         all-outputs entry (see _build_entry)."""
-        return self._jit_for(servable)
+        with self._jit_lock:
+            entry = self._jitted.get(servable)
+            if entry is None:
+                combined = self.compress_transfer and not servable.model.needs_x64
+                entry = self._build_entry(servable, combined)
+                self._jitted[servable] = entry
+        return entry
 
     def queue_load(self) -> tuple[int, int]:
         """(queued + staged candidates, configured queue capacity) — the
@@ -1797,72 +1796,32 @@ class DynamicBatcher:
                 for sv, formats in self._upload_formats.items()
             }
 
-    def gathers(self) -> dict[str, dict]:
-        """"name:version" -> the embedding gather of that servable's entry
-        as traced: `{"kernel": "pallas" | "xla", "row_bytes", "in_flight",
-        "picked_in_kernel"}` (the kernel's widest block where rungs differ),
-        for every servable whose step looked rows up. A custom run_fn traces
-        its own entries, outside serving_gathers: XLA's gather, no stamp."""
+    def _stamp(self, kind: str) -> dict[str, dict]:
+        """"name:version" -> that kind's choice in the servable's entry as
+        traced (the row's highest note where rungs or layers differ), for
+        every servable whose step has such an operation. A custom run_fn
+        traces its own entries, outside serving_gathers and
+        serving_attention: XLA's path, no stamp."""
+        row = next(k for k in SERVED_KERNELS if k.kind == kind)
         with self._jit_lock:
             return {
                 f"{sv.name}:{sv.version}": max(
-                    notes, key=lambda n: (n["kernel"] == "pallas", n["in_flight"])
+                    notes[kind], key=lambda n: (n[row.pallas_key] == "pallas", *row.rank(n))
                 )
-                for sv, notes in self._gathers.items() if notes
+                for sv, notes in self._kernel_notes.items() if notes[kind]
             }
 
-    def attentions(self) -> dict[str, dict]:
-        """"name:version" -> the attention of that servable's entry as
-        traced: `{"kernel": "pallas" | "xla", "block", "pieces"}` (the
-        kernel's, and its widest tile, where layers or rungs differ; with
-        `"why": "vmem"` where XLA's blocks serve because the kernel's scratch
-        does not fit at the layer's shapes), for
-        every servable whose step attends. A custom run_fn traces its own
-        entries, outside serving_attention: the XLA path, no stamp."""
-        with self._jit_lock:
-            return {
-                f"{sv.name}:{sv.version}": max(
-                    notes, key=lambda n: (n["kernel"] == "pallas", n["block"], "why" in n)
-                )
-                for sv, notes in self._attentions.items() if notes
-            }
+    # A kind's stamp by its older public name; the note's fields are its
+    # `*_choice`'s (SERVED_KERNELS names each).
+    gathers = functools.partialmethod(_stamp, "gather")
+    attentions = functools.partialmethod(_stamp, "attention")
+    groupeds = functools.partialmethod(_stamp, "grouped")
+    delta_rules = functools.partialmethod(_stamp, "delta")
+    ssds = functools.partialmethod(_stamp, "ssd")
 
-    def groupeds(self) -> dict[str, dict]:
-        """"name:version" -> the held experts' product of that servable's
-        entry as traced: `{"kernel": "pallas" | "xla", "tile", "pieces",
-        "held", "rows"}` (the kernels', then the layout of the most rows,
-        where layers or rungs differ), for every servable
-        whose step has a routed layer. A custom run_fn traces its own
-        entries, outside serving_attention: XLA's loop, no stamp."""
-        with self._jit_lock:
-            return {
-                f"{sv.name}:{sv.version}": max(notes, key=lambda n: (n["kernel"] == "pallas", n.get("rows", 0)))
-                for sv, notes in self._groupeds.items() if notes
-            }
-
-    def delta_rules(self) -> dict[str, dict]:
-        """"name:version" -> the gated delta rule's chunk pass of that
-        servable's entry as traced: `{"kernel": "pallas" | "xla", "chunk",
-        "pieces", "key_heads", "value_heads"}` (the kernel's where rungs differ), for every servable
-        whose step has a linear-attention layer. A custom run_fn traces its
-        own entries, outside serving_attention: XLA's scan, no stamp."""
-        with self._jit_lock:
-            return {
-                f"{sv.name}:{sv.version}": max(notes, key=lambda n: n["kernel"] == "pallas")
-                for sv, notes in self._deltas.items() if notes
-            }
-
-    def ssds(self) -> dict[str, dict]:
-        """"name:version" -> the SSD of that servable's entry as traced:
-        `{"path": "pallas" | "xla", "chunk", "state_bytes_a_row"}` (the
-        kernel's, then the longest chunk's, where rungs differ), for every
-        servable whose layers hold a Mamba-2 mixer. A custom run_fn traces its
-        own entries, outside serving_attention: XLA's scan, no stamp."""
-        with self._jit_lock:
-            return {
-                f"{sv.name}:{sv.version}": max(notes, key=lambda n: (n["path"] == "pallas", n["chunk"]))
-                for sv, notes in self._ssds.items() if notes
-            }
+    def kernel_stamps(self) -> dict[str, dict[str, dict]]:
+        """`startup.<stamp>` for every row of SERVED_KERNELS."""
+        return {k.stamp: self._stamp(k.kind) for k in SERVED_KERNELS}
 
     def products(self) -> dict[str, dict]:
         """"name:version" -> the products of an activation in pieces against
@@ -2104,15 +2063,6 @@ class DynamicBatcher:
 
     # ------------------------------------------------------------- internals
 
-    def _jit_for(self, servable: Servable) -> tuple[Callable, dict[str, str], bool]:
-        with self._jit_lock:
-            entry = self._jitted.get(servable)
-            if entry is None:
-                combined = self.compress_transfer and not servable.model.needs_x64
-                entry = self._build_entry(servable, combined)
-                self._jitted[servable] = entry
-        return entry
-
     def _build_entry(
         self, servable: Servable, combined: bool
     ) -> tuple[Callable, dict[str, str], bool]:
@@ -2128,9 +2078,10 @@ class DynamicBatcher:
           (score, index) pairs of the first n_valid rows come back.
           n_valid is traced, so executables key on (bucket, k) alone.
 
-        Each distinct (layout, out_keys, topk, k_apply, prune) is a
-        separate jit closure, cached here; the inner jax.jit trace cache
-        still keys on buffer shape. The variant count is bounded by the
+        Each distinct (layout, out_keys, topk, prune), or (out_keys, topk,
+        prune) off the combined buffer, is a separate jit closure, cached
+        here; the inner jax.jit trace cache still keys on buffer shape. The
+        variant count is bounded by the
         distinct output_filter subsets clients actually send (the service
         validates filters against the signature, so the space is subsets of
         the signature's outputs — a handful), not by traffic volume. All
@@ -2149,44 +2100,27 @@ class DynamicBatcher:
         # A list, appended to at trace time and read by upload_formats()
         # from another thread: an append never breaks a reader's iteration.
         formats = self._upload_formats[servable] = []
-        # Likewise what the step's embedding gather is, noted while an
+        # Likewise what each SERVED_KERNELS choice was, noted while an
         # executable is traced (every variant and every rung notes again).
         # This entry runs on one chip (a mesh executor is a run_fn and never
-        # gets here), so it is the one trace in which lookup_rows may take
-        # the Pallas gather kernel (models/embeddings.py serving_gathers).
-        gathers = self._gathers[servable] = []
-        self._gather_kernel.discard(servable)
-        # And the one in which an attention at all positions may take the
-        # Pallas attention kernel, a routed layer's held experts the grouped
-        # kernels, a gated delta rule's chunk pass its own and a Mamba-2
-        # mixer's SSD its own (models/sequence.py serving_attention).
-        attentions = self._attentions[servable] = []
-        self._attention_kernel.discard(servable)
-        groupeds = self._groupeds[servable] = []
-        self._grouped_kernel.discard(servable)
-        deltas = self._deltas[servable] = []
-        self._delta_kernel.discard(servable)
-        ssds = self._ssds[servable] = []
-        self._ssd_kernel.discard(servable)
+        # gets here), so it is the one trace in which a choice may take its
+        # kernel (embeddings.serving_gathers, sequence.serving_attention).
+        notes = self._kernel_notes[servable] = {k.kind: [] for k in SERVED_KERNELS}
+        self._kernel_kinds.pop(servable, None)
         products = self._products[servable] = []
 
-        def noting(ap):
-            def traced(p, batch):
-                with serving_gathers(gathers), serving_attention(
-                        attentions, grouped=groupeds, delta=deltas, ssd=ssds, products=products):
-                    out = ap(p, batch)
-                if any(note["kernel"] == "pallas" for note in gathers):
-                    self._gather_kernel.add(servable)
-                if any(note["kernel"] == "pallas" for note in attentions):
-                    self._attention_kernel.add(servable)
-                if any(note["kernel"] == "pallas" for note in groupeds):
-                    self._grouped_kernel.add(servable)
-                if any(note["kernel"] == "pallas" for note in deltas):
-                    self._delta_kernel.add(servable)
-                if any(note["path"] == "pallas" for note in ssds):
-                    self._ssd_kernel.add(servable)
-                return out
-            return traced
+        def traced(p, batch):
+            # `apply` as it stands when an executable is traced: the step
+            # that counts, where the model has one (below).
+            with serving_gathers(notes["gather"]), serving_attention(
+                    notes["attention"], grouped=notes["grouped"], delta=notes["delta"],
+                    ssd=notes["ssd"], products=products):
+                out = apply(p, batch)
+            self._kernel_kinds[servable] = tuple(
+                k for k in SERVED_KERNELS
+                if any(note[k.pallas_key] == "pallas" for note in notes[k.kind])
+            )
+            return out
 
         if not combined:
             formats.append("per key: " + (", ".join(
@@ -2216,18 +2150,31 @@ class DynamicBatcher:
 
         variants: dict[tuple, Callable] = {}
 
-        def named(run, topk, prune):
+        def variant_jit(unpack, out_keys, topk, prune):
+            """The jitted step of one variant: `unpack` (the transfer's
+            decompression, traced into the executable as its `unpack` scope),
+            the model, then the variant's selection."""
+            if topk:
+                select = cascade_prune_device if prune else topk_compact_device
+
+                def run(p, b, nv):
+                    out = traced(p, unpack(b))
+                    finish(out, None)  # records the baseline
+                    return select(out[score_key], nv, topk, wire)
+            else:
+                def run(p, b):
+                    return finish(traced(p, unpack(b)), out_keys)
             # The executable's name in a profiler trace (`jit_<name>`, the
             # host plane's `PjitFunction(<name>)`): model and variant.
             variant = "prune" if prune else "topk" if topk else "score"
             run.__name__ = re.sub(r"\W", "_", f"{servable.name}_{variant}")
-            return run
+            return step_jit(model, run)
 
         if combined:
             # One uint32 buffer per batch = ONE host->device transfer
             # instead of one per input; the layout split, the planes'
             # shifts and masks and the bitcasts are traced into the
-            # executable (its `unpack` scope; ops/transfer.py).
+            # executable (ops/transfer.py).
             # (x64 models keep the per-key path: their int64 inputs
             # must cross the boundary as int64, not raw bytes plus an
             # in-graph bitcast that enable_x64 scoping complicates.)
@@ -2239,61 +2186,26 @@ class DynamicBatcher:
             # every call cost ~175 us/batch of pure dispatch overhead
             # (round-4 microbench: 426 -> 251 us/call arg processing),
             # and each variant's jit compiles for its one buffer shape.
-            def fn(
-                params, buf, layout, out_keys=None,
-                topk=0, n_valid=None, k_apply=None, prune=False,
-                _cache=variants,
-            ):
-                # k_apply (kernel plane, ISSUE 12): an alternate apply
-                # callable — the fused Pallas serving kernel — swapped in
-                # per the per-bucket autotune decision. Its identity joins
-                # the variant key so the Pallas and XLA executables
-                # coexist; quantized params need no key (jax.jit retraces
-                # on the distinct param-tree structure).
-                key = (layout, out_keys, topk, k_apply, prune)
+            def fn(params, buf, layout, out_keys=None, topk=0, n_valid=None, prune=False,
+                   _cache=variants):
+                key = (layout, out_keys, topk, prune)
                 jfn = _cache.get(key)
                 if jfn is None:
                     if (fmt := describe_layout(layout)) not in formats:
                         formats.append(fmt)
-                    ap = noting(k_apply or apply)
-                    if topk:
-                        select = cascade_prune_device if prune \
-                            else topk_compact_device
-                        def run(p, b, nv, _l=layout, _k=topk, _ap=ap,
-                                _sel=select):
-                            out = _ap(p, unpack_device_combined(b, _l))
-                            finish(out, None)  # records the baseline
-                            return _sel(out[score_key], nv, _k, wire)
-                    else:
-                        def run(p, b, _l=layout, _ok=out_keys, _ap=ap):
-                            return finish(_ap(p, unpack_device_combined(b, _l)), _ok)
-                    jfn = _cache[key] = step_jit(model, named(run, topk, prune))
+                    jfn = _cache[key] = variant_jit(
+                        lambda b: unpack_device_combined(b, layout), out_keys, topk, prune)
                 return jfn(params, buf, n_valid) if topk else jfn(params, buf)
         else:
-            def fn(
-                params, packed, out_keys=None,
-                topk=0, n_valid=None, k_apply=None, prune=False,
-                _cache=variants,
-            ):
-                key = (out_keys, topk, k_apply, prune)
+            def unpack(b):
+                return unpack_device(b, spec) if spec else b
+
+            def fn(params, packed, out_keys=None, topk=0, n_valid=None, prune=False,
+                   _cache=variants):
+                key = (out_keys, topk, prune)
                 jfn = _cache.get(key)
                 if jfn is None:
-                    ap = noting(k_apply or apply)
-                    if topk:
-                        select = cascade_prune_device if prune \
-                            else topk_compact_device
-                        def run(p, b, nv, _k=topk, _ap=ap, _sel=select):
-                            batch = unpack_device(b, spec) if spec else b
-                            out = _ap(p, batch)
-                            finish(out, None)
-                            return _sel(out[score_key], nv, _k, wire)
-                    else:
-                        def run(p, b, _ok=out_keys, _ap=ap):
-                            # Transfer decompression is traced into the
-                            # executable (its `unpack` scope).
-                            batch = unpack_device(b, spec) if spec else b
-                            return finish(_ap(p, batch), _ok)
-                    jfn = _cache[key] = step_jit(model, named(run, topk, prune))
+                    jfn = _cache[key] = variant_jit(unpack, out_keys, topk, prune)
                 return jfn(params, packed, n_valid) if topk else jfn(params, packed)
 
         if model.needs_x64:
@@ -2323,7 +2235,7 @@ class DynamicBatcher:
             return "x64 model"
         if not native.available():
             return "no native library"
-        if not self._jit_for(servable)[2]:
+        if not self.jit_entry(servable)[2]:
             return "per-key upload"
         return None
 
@@ -2366,7 +2278,7 @@ class DynamicBatcher:
         if self._generic_reason(servable) is not None:
             return None
         model = servable.model
-        fn, spec, _combined = self._jit_for(servable)
+        fn, spec, _combined = self.jit_entry(servable)
         fold = (
             {"feat_ids": model.config.vocab_size}
             if model.folds_ids_on_host and "feat_ids" in parts else {}
@@ -2445,34 +2357,9 @@ class DynamicBatcher:
         # different jax aval (weak type) and would force a fresh trace on
         # the first live fused top-k batch despite warmup's precompile.
         n_valid = None if not topk else np.int32(n_valid)
-        # Kernel plane: the native assembler and the kernel variants
-        # compose — the packed buffer is variant-independent input bytes.
-        k_params, k_apply = self._kernel_variant(servable, bucket)
         with request_trace.span("batch.jitcall"):
-            return fn(
-                k_params, buf, layout,
-                out_keys=out_keys, topk=topk, n_valid=n_valid,
-                k_apply=k_apply, prune=prune,
-            )
-
-    def _kernel_variant(self, servable: Servable, rows: int, override=None):
-        """(params, k_apply) per the kernel plane's per-bucket decision —
-        the int8-quantized param tree and/or the fused Pallas serving
-        apply — or (servable.params, None) for the baseline. `override`
-        is the autotune harness's (quantized, pallas) pin, so measurement
-        runs through the EXACT entry (and jit cache) live traffic uses."""
-        kern = self.kernels
-        if kern is None or self._run_fn is not None:
-            return servable.params, None
-        dec = override if override is not None else kern.decision(servable, rows)
-        if not dec or dec == (False, False):
-            return servable.params, None
-        quantized, pallas = dec
-        params = (
-            kern.params_for(servable, True) if quantized else servable.params
-        )
-        k_apply = kern.pallas_apply_for(servable, quantized) if pallas else None
-        return params, k_apply
+            return fn(servable.params, buf, layout,
+                      out_keys=out_keys, topk=topk, n_valid=n_valid, prune=prune)
 
     @staticmethod
     def _fold_host(servable: Servable, arrays: dict) -> dict:
@@ -2497,13 +2384,11 @@ class DynamicBatcher:
         topk: int = 0,
         n_valid: int | None = None,
         prune: bool = False,
-        _kernel_override=None,
     ):
         """Device stage for one padded batch: fold, content cache, pack,
         upload, jit call. out_keys/topk/n_valid ride through to the jitted
         entry (output selection and top-k compaction are traced into the
-        executable); _kernel_override pins the kernel plane's (quantized,
-        pallas) variant for the autotune harness."""
+        executable)."""
         arrays = self._fold_host(servable, arrays)
         if self._run_fn is not None:
             if getattr(self._run_fn, "supports_out_keys", False):
@@ -2513,10 +2398,7 @@ class DynamicBatcher:
                 # — the same PR-1 compaction the single-chip entries get.
                 return self._run_fn(servable, arrays, out_keys=out_keys)
             return self._run_fn(servable, arrays)
-        k_params, k_apply = self._kernel_variant(
-            servable, next(iter(arrays.values())).shape[0], _kernel_override
-        )
-        fn, spec, combined = self._jit_for(servable)
+        fn, spec, combined = self.jit_entry(servable)
         if combined and not combined_supported(arrays):
             # Rare servable whose inputs cannot ride the word buffer (string/
             # bool/8-byte tensors): rebuild the per-key entry once and pin
@@ -2546,37 +2428,24 @@ class DynamicBatcher:
                         )
                 else:
                     buf = pack_host_combined(arrays, spec)
-                with request_trace.span("batch.jitcall"):
-                    return fn(
-                        k_params, buf, layout,
-                        out_keys=out_keys, topk=topk, n_valid=n_valid,
-                        k_apply=k_apply, prune=prune,
-                    )
-            if self.input_cache is not None:
+                inputs = (buf, layout)
+            elif self.input_cache is not None:
                 # Digest BEFORE packing: a content hit skips both the upload
                 # and the pack (u24/bf16) work.
                 with request_trace.span("batch.cache"):
-                    inputs = {
+                    inputs = ({
                         k: self.input_cache.get_or_put(
                             k, v,
                             pack=(lambda a, _k=k: pack_host({_k: a}, spec)[_k]) if spec else None,
                             pack_tag=spec.get(k, "") if spec else "",
                         )
                         for k, v in arrays.items()
-                    }
-                with request_trace.span("batch.jitcall"):
-                    return fn(
-                        k_params, inputs,
-                        out_keys=out_keys, topk=topk, n_valid=n_valid,
-                        k_apply=k_apply, prune=prune,
-                    )
-            packed = pack_host(arrays, spec) if spec else arrays
+                    },)
+            else:
+                inputs = (pack_host(arrays, spec) if spec else arrays,)
             with request_trace.span("batch.jitcall"):
-                return fn(
-                    k_params, packed,
-                    out_keys=out_keys, topk=topk, n_valid=n_valid,
-                    k_apply=k_apply, prune=prune,
-                )
+                return fn(servable.params, *inputs,
+                          out_keys=out_keys, topk=topk, n_valid=n_valid, prune=prune)
 
     def _shed_expired_locked(self, it: _WorkItem) -> bool:
         """True when `it`'s propagated client deadline already expired —
@@ -3496,21 +3365,9 @@ class DynamicBatcher:
                 # Phases by count, beside `batch.dispatch`'s: one take of
                 # the trace's lock a batch for all that apply.
                 counted = []
-                if servable in self._gather_kernel:
-                    self.stats.gather_kernel_batches += 1
-                    counted.append(("batch.gather_kernel", 0.0, 1))
-                if servable in self._attention_kernel:
-                    self.stats.attention_kernel_batches += 1
-                    counted.append(("batch.attention_kernel", 0.0, 1))
-                if servable in self._grouped_kernel:
-                    self.stats.grouped_kernel_batches += 1
-                    counted.append(("batch.grouped_kernel", 0.0, 1))
-                if servable in self._delta_kernel:
-                    self.stats.delta_kernel_batches += 1
-                    counted.append(("batch.delta_kernel", 0.0, 1))
-                if servable in self._ssd_kernel:
-                    self.stats.ssd_kernel_batches += 1
-                    counted.append(("batch.ssd_kernel", 0.0, 1))
+                for k in self._kernel_kinds.get(servable, ()):
+                    setattr(self.stats, k.counter, getattr(self.stats, k.counter) + 1)
+                    counted.append((k.phase, 0.0, 1))
                 if group[0].direct:
                     self.stats.direct_batches += 1
                     counted.append(("batch.direct", 0.0, 1))

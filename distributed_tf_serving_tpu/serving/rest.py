@@ -595,7 +595,6 @@ class RestGateway:
                 lifecycle=self.impl.lifecycle_stats(),
                 pipeline=self.impl.pipeline_stats(),
                 recovery=self.impl.recovery_stats(),
-                kernels=self.impl.kernels_stats(),
                 mesh=mesh,
                 elastic=self.impl.elastic_stats(mesh=mesh),
                 fleet=self.impl.fleet_stats(),
@@ -636,7 +635,6 @@ class RestGateway:
             "quality": self.impl.quality_stats,
             "lifecycle": self.impl.lifecycle_stats,
             "recovery": self.impl.recovery_stats,
-            "kernels": self.impl.kernels_stats,
             "mesh": self.impl.mesh_stats,
             "elastic": self.impl.elastic_stats,
             "fleet": self.impl.fleet_stats,
@@ -676,9 +674,9 @@ class RestGateway:
         # pass (its per-device attribution lifts from it — no second
         # waterfall merge).
         for name in ("cache", "row_cache", "overload", "utilization",
-                     "quality", "lifecycle", "recovery", "kernels", "mesh",
-                     "elastic", "fleet", "cascade", "integrity", "versions",
-                     "pipeline", "runtime", "threads"):
+                     "quality", "lifecycle", "recovery", "mesh", "elastic",
+                     "fleet", "cascade", "integrity", "versions", "pipeline",
+                     "runtime", "threads"):
             if name == "mesh":
                 block = self.impl.mesh_stats(
                     utilization=snap.get("utilization")

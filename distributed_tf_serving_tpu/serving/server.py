@@ -48,7 +48,6 @@ from ..utils import tracing
 from ..utils.tracing import request_trace
 from . import lifecycle as lifecycle_mod
 from . import overload as overload_mod
-from ..ops import autotune as kernels_mod
 from .batcher import DynamicBatcher
 from .service import PredictionServiceImpl, ServiceError
 
@@ -92,22 +91,6 @@ def _criticality_of(context) -> str | None:
     except Exception:  # noqa: BLE001 — a metadata quirk must not fail the RPC
         return None
     return None
-
-
-def _score_wire_of(context) -> bool:
-    """True when the request opted into the int8 score response wire
-    (x-dts-score-wire: int8) AND a kernels plane armed it — one module
-    bool read per RPC otherwise (the overload/lifecycle active()
-    precedent)."""
-    if not kernels_mod.wire_active():
-        return False
-    try:
-        for key, value in context.invocation_metadata() or ():
-            if key == kernels_mod.SCORE_WIRE_KEY:
-                return str(value).strip().lower() == "int8"
-    except Exception:  # noqa: BLE001 — a metadata quirk must not fail the RPC
-        return False
-    return False
 
 
 def _stream_chunk_of(context) -> int | None:
@@ -406,13 +389,12 @@ class GrpcPredictionService(_SyncServicerBase):
     def Predict(self, request, context):
         deadline_s = _deadline_of(context)
         crit = _criticality_of(context)
-        int8_wire = _score_wire_of(context)
         input_crc = _input_crc_of(context, self.impl)
 
         def handler(req):
             resp = self.impl.predict(
                 req, deadline_s=deadline_s, criticality=crit,
-                int8_wire=int8_wire, input_crc=input_crc,
+                input_crc=input_crc,
             )
             if self.impl.integrity is not None:
                 _stamp_response_crc(self.impl, context, resp)
@@ -882,10 +864,7 @@ def _servable_change_hook(score_cache, quality, row_cache=None):
     out to every armed plane that cares about registry mutations: the
     cache plane's generation invalidation (by model name) — BOTH tiers,
     the whole-request store and the row-granular store — and the quality
-    plane's version-change accounting. The kernel plane needs no hook:
-    its decision() is identity-guarded per tuned Servable (a hot-loaded
-    or reloaded version can never inherit another generation's
-    enablement, while the stable version keeps its measured win). None
+    plane's version-change accounting. None
     when nothing is armed, so the watcher keeps its no-hook fast path."""
     hooks = []
     if score_cache is not None:
@@ -1270,7 +1249,6 @@ def build_stack(
     batching_config=None,
     transport_config=None,
     recovery_config=None,
-    kernels_config=None,
     mesh_config=None,
     elastic_config=None,
     cascade_config=None,
@@ -1323,8 +1301,7 @@ def build_stack(
     embedding vocab over the model axis per the family's named partition
     rules, same wire protocol, one process spanning N chips. Mode
     conflicts are EXPLICIT build-time refusals, never runtime surprises:
-    [kernels] (per-bucket kernel routing owns the single-chip
-    executables), [recovery] scope='per_chip' (an SPMD executable spans
+    [recovery] scope='per_chip' (an SPMD executable spans
     every chip; whole-executor recovery COMPOSES — the mesh executor
     quarantines/reinits/replays as one unit), output_top_k (a
     single-chip jitted-entry variant), and the legacy [server]
@@ -1413,10 +1390,8 @@ def build_stack(
         # period was detected) would be re-served for their whole TTL
         # with no detection layer ever touching them again. Refuse the
         # combination instead of silently weakening the guarantee; the
-        # row cache and [kernels] COMPOSE (cold rows execute through the
-        # shadow-eligible path, and both shadow executions route through
-        # the same kernel-variant decision, so the compare stays within
-        # the enabled variant).
+        # row cache COMPOSES (cold rows execute through the
+        # shadow-eligible path).
         raise ValueError(
             "[integrity] shadow_fraction > 0 conflicts with [cache] "
             "enabled: exact-match cache hits re-serve cached score bytes "
@@ -1601,30 +1576,6 @@ def build_stack(
             quality_config.drift_threshold_psi,
             quality_config.reference_file or "<none>",
         )
-    from ..utils.config import KernelsConfig as _KernelsConfig
-
-    # build() with a disabled (or absent) section DISARMS the module-level
-    # int8 score-wire gate — a stack built without the plane must never
-    # inherit a previous stack's armed wire in the same process.
-    kernel_manager = (kernels_config or _KernelsConfig()).build()
-    if kernel_manager is not None:
-        if cfg.mesh_devices or mesh_armed:
-            raise ValueError(
-                "[kernels] enabled requires the single-chip batcher path: "
-                "the ShardedExecutor mirrors the int8 output wire but owns "
-                "its own executables (per-bucket kernel routing over a "
-                "mesh is future work) — disable [kernels] or [mesh]"
-            )
-        log.info(
-            "kernel plane on: quantize=%s pallas=%s autotune=%s "
-            "measure_only=%s gates(speedup>=%.2f |dScore|<=%.4f "
-            "|dAUC|<=%.4f) int8_score_wire=%s table=%s",
-            kernels_config.quantize, kernels_config.pallas,
-            kernels_config.autotune, kernels_config.measure_only,
-            kernels_config.min_speedup, kernels_config.max_abs_delta,
-            kernels_config.auc_margin, kernels_config.int8_score_wire,
-            kernels_config.table_file or "<none>",
-        )
     overload_ctrl = (
         overload_config.build() if overload_config is not None else None
     )
@@ -1717,28 +1668,6 @@ def build_stack(
             elastic_config.load_down_threshold,
             "wired" if overload_ctrl is not None else "absent (load-only)",
         )
-    if kernel_manager is not None:
-        # Attach the kernel plane: the batcher consults the per-bucket
-        # decision table at dispatch; /monitoring + Prometheus read
-        # impl.kernels. Decisions stay empty (= baseline) until the
-        # autotune below (or a persisted-table adoption) fills them.
-        batcher.kernels = kernel_manager
-        impl.kernels = kernel_manager
-
-    def _prepare_kernels(sv) -> None:
-        # Autotune at load time — the compile-storms-belong-at-warmup
-        # rule applies to variant measurement too. A persisted table for
-        # this exact (model, version, device, gates) is adopted without
-        # re-measuring; measure_only records without enabling.
-        if kernel_manager is None or sv is None:
-            return
-        try:
-            kernel_manager.prepare(batcher, sv)
-        except kernels_mod.KernelLoweringError:
-            raise  # the operator asked for a kernel this device refuses
-        except Exception:  # noqa: BLE001 — a failed tune means baseline
-            log.exception("kernel autotune failed; serving the baseline")
-
     if batching_config is not None:
         # Streamed sub-batch default ([batching] stream_chunk_candidates;
         # a request's x-dts-stream-chunk metadata overrides per call).
@@ -1952,7 +1881,6 @@ def build_stack(
         else:
             servable = registry.resolve(cfg.model_name)
             log.info("serving %s versions %s from %s", cfg.model_name, versions, model_base_path)
-        _prepare_kernels(servable)
         impl.warmup_complete = True
         return registry, batcher, impl, servable, mesh, watcher
     load_t0 = time.perf_counter()
@@ -2007,8 +1935,7 @@ def build_stack(
     for label, version in cfg.version_labels:
         registry.set_label(cfg.model_name, label, version)
         log.info("label %r -> %s v%d", label, cfg.model_name, version)
-    _prepare_kernels(servable)
-    # Load-time compilation (ladder warmup + kernel autotune) is set-up
+    # Load-time compilation (the ladder's warm-up) is set-up
     # time: reported in /monitoring's `runtime` block, never hidden.
     impl.warmup_s = round(time.perf_counter() - warmup_t0, 3)
     impl.warmup_complete = True
@@ -2046,7 +1973,7 @@ def serve(argv=None) -> None:
         "[mesh] enabled=true; with --mesh, --mesh-devices / "
         "--model-parallel / --tensor-parallel configure the MESH "
         "section (`mesh` block in /monitoring, dts_tpu_mesh_* series). "
-        "Refuses [kernels], [recovery] scope='per_chip', and "
+        "Refuses [recovery] scope='per_chip' and "
         "output_top_k at build time; whole-executor [recovery] and "
         "[elastic] compose",
     )
@@ -2155,18 +2082,6 @@ def serve(argv=None) -> None:
         "[recovery] section carries the watchdog/replay/bisection knobs "
         "(GET /recoveryz, `recovery` block in /monitoring, "
         "dts_tpu_recovery_* Prometheus series)",
-    )
-    parser.add_argument(
-        "--kernels", action="store_true", default=None,
-        help="kernel/quantization plane (ops/quantize.py + ops/autotune.py"
-        " + the fused Pallas serving kernel): post-training int8 weight "
-        "quantization and the fused cross+MLP+head kernel, each enabled "
-        "PER BUCKET only where the warmup autotune harness measured a "
-        "speedup > 1 on this device AND the accuracy gates passed "
-        "(max |dScore| bound; AUC margin when a labeled eval is supplied)."
-        " Equivalent to [kernels] enabled=true; the [kernels] section "
-        "carries the gate/table knobs (`kernels` block in /monitoring, "
-        "dts_tpu_kernel_* Prometheus series)",
     )
     parser.add_argument(
         "--fleet", action="store_true", default=None,
@@ -2287,7 +2202,6 @@ def serve(argv=None) -> None:
         ElasticConfig,
         FleetConfig,
         IntegrityConfig,
-        KernelsConfig,
         LifecycleConfig,
         MeshConfig,
         ObservabilityConfig,
@@ -2333,9 +2247,6 @@ def serve(argv=None) -> None:
     recovery_config = cfgs.get("recovery") or RecoveryConfig()
     if args.recovery:
         recovery_config = dataclasses.replace(recovery_config, enabled=True)
-    kernels_config = cfgs.get("kernels") or KernelsConfig()
-    if args.kernels:
-        kernels_config = dataclasses.replace(kernels_config, enabled=True)
     fleet_config = cfgs.get("fleet") or FleetConfig()
     if args.fleet:
         fleet_config = dataclasses.replace(fleet_config, enabled=True)
@@ -2476,7 +2387,6 @@ def serve(argv=None) -> None:
             batching_config=batching_config,
             transport_config=transport_config,
             recovery_config=recovery_config,
-            kernels_config=kernels_config,
             mesh_config=mesh_config,
             elastic_config=elastic_config,
             cascade_config=cascade_config,

@@ -36,6 +36,7 @@ from ..proto import serving_apis_pb2 as apis
 from ..proto import tf_framework_pb2 as fw
 from . import cascade as cascade_mod
 from .batcher import (
+    SERVED_KERNELS,
     BatchTooLargeError,
     DeviceWedgedError,
     DynamicBatcher,
@@ -116,15 +117,6 @@ class PredictionServiceImpl:
         # GET /recoveryz serves its snapshot. None (default) costs one
         # attribute read where consulted.
         self.recovery = None
-        # Kernel/quantization plane (ops/autotune.py, ISSUE 12): when a
-        # KernelManager is set (build_stack attaches the same object to
-        # the batcher), /monitoring's `kernels` block and the
-        # dts_tpu_kernel_* Prometheus series read it, and — with its
-        # int8_score_wire knob on — Predict responses for clients that
-        # sent x-dts-score-wire: int8 carry the score tensor as DT_INT8
-        # plus (scale, min) sidecar outputs. None (default) costs one
-        # attribute read where consulted.
-        self.kernels = None
         # Mesh serving mode (ISSUE 13): the ShardedExecutor installed as
         # the batcher's run_fn, when serving spans a device mesh.
         # /monitoring's `mesh` block and the dts_tpu_mesh_* Prometheus
@@ -171,8 +163,8 @@ class PredictionServiceImpl:
         self.response_arena = False
         self._arenas = threading.local()
         # Start-up facts for /monitoring's `runtime` block: build_stack
-        # records the load-time compile wall (ladder warmup + kernel
-        # autotune); serve() attaches the persistent-compile-cache counter
+        # records the load-time compile wall (the ladder's warm-up);
+        # serve() attaches the persistent-compile-cache counter
         # (utils/runtime.py CompileCacheStats). None = not recorded.
         self.warmup_s: float | None = None
         self.compile_cache = None
@@ -223,11 +215,7 @@ class PredictionServiceImpl:
         block["warmup_s"] = self.warmup_s
         upload_formats = getattr(self.batcher, "upload_formats", None)
         assemblers = getattr(self.batcher, "assemblers", None)
-        gathers = getattr(self.batcher, "gathers", None)
-        attentions = getattr(self.batcher, "attentions", None)
-        groupeds = getattr(self.batcher, "groupeds", None)
-        delta_rules = getattr(self.batcher, "delta_rules", None)
-        ssds = getattr(self.batcher, "ssds", None)
+        kernel_stamps = getattr(self.batcher, "kernel_stamps", None)
         products = getattr(self.batcher, "products", None)
         block["startup"] = {
             **self.startup,
@@ -240,11 +228,8 @@ class PredictionServiceImpl:
             "params_bytes": self.registry.per_servable("params_bytes"),
             "upload_format": upload_formats() if callable(upload_formats) else {},
             "assembler": assemblers() if callable(assemblers) else {},
-            "gather": gathers() if callable(gathers) else {},
-            "attention": attentions() if callable(attentions) else {},
-            "grouped": groupeds() if callable(groupeds) else {},
-            "delta_rule": delta_rules() if callable(delta_rules) else {},
-            "ssd": ssds() if callable(ssds) else {},
+            **(kernel_stamps() if callable(kernel_stamps)
+               else {k.stamp: {} for k in SERVED_KERNELS}),
             "products": products() if callable(products) else {},
         }
         block["embedding_pack"] = self.registry.per_servable("embedding_pack")
@@ -420,15 +405,6 @@ class PredictionServiceImpl:
         if integ is None or not integ.config.wire_checksums:
             return None
         return integ.response_sidecar(resp.outputs)
-
-    def kernels_stats(self) -> dict | None:
-        """Kernel-plane snapshot (per-bucket decision table, measured
-        speedups + accuracy-gate outcomes, quantized/pallas batch
-        counters) — the `kernels` block in /monitoring and the
-        dts_tpu_kernel_* Prometheus series. None when no manager is
-        armed ([kernels] enabled=false)."""
-        kern = self.kernels
-        return kern.snapshot() if kern is not None else None
 
     def mesh_stats(self, utilization: dict | None = None) -> dict | None:
         """Mesh-mode snapshot (mesh geometry + device list, executor
@@ -947,7 +923,7 @@ class PredictionServiceImpl:
 
     def predict(
         self, request: apis.PredictRequest, deadline_s: float | None = None,
-        criticality: str | None = None, int8_wire: bool = False,
+        criticality: str | None = None,
         input_crc: str | None = None,
     ) -> apis.PredictResponse:
         self._refuse_if_draining()
@@ -960,8 +936,8 @@ class PredictionServiceImpl:
             servable, fetch_keys, next(iter(arrays.values())).shape[0]
         ):
             # Multi-stage cascade (ISSUE 19): retrieval->rank in one RPC.
-            # The provenance output rides the response like the int8-wire
-            # sidecars — an extra tensor beyond the signature.
+            # The provenance output rides the response as an extra tensor
+            # beyond the signature.
             with request_trace.span("predict.execute"):
                 outputs = casc.run(
                     self, servable, arrays, fetch_keys, deadline_t,
@@ -975,9 +951,7 @@ class PredictionServiceImpl:
                     deadline_s=self._budget_left(deadline_t),
                     criticality=criticality,
                 )
-        resp = self._predict_finish(
-            request, servable, out_names, outputs, int8_wire=int8_wire
-        )
+        resp = self._predict_finish(request, servable, out_names, outputs)
         # Log only SUCCEEDED requests: the file's contract is direct
         # usability as a warmup file, and one malformed client request
         # must never poison a future version rollout (review finding).
@@ -986,7 +960,7 @@ class PredictionServiceImpl:
 
     async def predict_async(
         self, request: apis.PredictRequest, deadline_s: float | None = None,
-        criticality: str | None = None, int8_wire: bool = False,
+        criticality: str | None = None,
         input_crc: str | None = None,
     ) -> apis.PredictResponse:
         """Predict for the REST gateway's event loop: identical semantics,
@@ -1013,9 +987,7 @@ class PredictionServiceImpl:
                     deadline_s=self._budget_left(deadline_t),
                     criticality=criticality,
                 )
-        resp = self._predict_finish(
-            request, servable, out_names, outputs, int8_wire=int8_wire
-        )
+        resp = self._predict_finish(request, servable, out_names, outputs)
         self._log_request("predict", request)
         return resp
 
@@ -1090,7 +1062,7 @@ class PredictionServiceImpl:
 
     def _predict_finish(
         self, request: apis.PredictRequest, servable: Servable, out_names,
-        outputs, int8_wire: bool = False,
+        outputs,
     ) -> apis.PredictResponse:
         self._check_produced(out_names, outputs)
         with request_trace.span("predict.encode"):
@@ -1099,37 +1071,8 @@ class PredictionServiceImpl:
                 self._echo_spec(servable, request.model_spec.signature_name or "serving_default")
             )
             mirror = self._mirror_content(request)
-            names = out_names
-            score_key = servable.model.score_output
-            if (
-                int8_wire
-                and score_key in out_names
-                and getattr(outputs.get(score_key), "dtype", None)
-                == np.float32
-            ):
-                # int8 score response wire (ISSUE 12): the opted-in
-                # client receives the score tensor as DT_INT8 plus the
-                # (scale, min) sidecar outputs codec.dequantize_response_
-                # output inverts — 4x fewer response bytes per score.
-                # Non-f32 score outputs (imported-graph dtypes) fall
-                # through to the normal encode: the wire must never
-                # guess a quantization for a dtype it does not own.
-                names = [n for n in out_names if n != score_key]
-                q, scale, mn = codec.quantize_scores(outputs[score_key])
-                codec.from_ndarray(
-                    q, dtype_enum=fw.DataType.DT_INT8,
-                    use_tensor_content=mirror, out=resp.outputs[score_key],
-                )
-                codec.from_ndarray(
-                    np.asarray([scale], np.float32), use_tensor_content=mirror,
-                    out=resp.outputs[score_key + codec.Q8_WIRE_SCALE_SUFFIX],
-                )
-                codec.from_ndarray(
-                    np.asarray([mn], np.float32), use_tensor_content=mirror,
-                    out=resp.outputs[score_key + codec.Q8_WIRE_MIN_SUFFIX],
-                )
             self._encode_outputs(
-                request, servable, names, outputs, resp.outputs, mirror,
+                request, servable, out_names, outputs, resp.outputs, mirror,
             )
         return resp
 

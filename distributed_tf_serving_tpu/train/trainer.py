@@ -89,10 +89,10 @@ class Trainer:
     ):
         self.model = model
         self.mesh = mesh
-        # learning_rate may be an optax schedule (a cosine decay in
-        # tools/check_kernel_smoke.py: the synthetic task is id memorization
-        # from noisy Bernoulli views, where a hot constant LR stops short of
-        # the information limit — the tail needs decay to average the noise).
+        # learning_rate may be an optax schedule: the synthetic task is id
+        # memorization from noisy Bernoulli views, where a hot constant LR
+        # stops short of the information limit — the tail needs decay to
+        # average the noise.
         self.optimizer = optax.adamw(learning_rate)
         params = jax.jit(model.init)(jax.random.PRNGKey(seed))
         if mesh is not None:

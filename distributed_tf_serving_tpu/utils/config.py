@@ -269,7 +269,7 @@ class MeshConfig:
 
     Arming it installs a hardened parallel/executor.ShardedExecutor as
     the batcher's run_fn: same wire protocol, same client semantics, one
-    process spanning N chips. Mode conflicts ([kernels], [recovery], the
+    process spanning N chips. Mode conflicts ([recovery], the
     legacy [server] mesh_devices knob, output_top_k) are refused at
     build time — see build_stack."""
 
@@ -839,94 +839,6 @@ class RecoveryConfig:
 
 
 @dataclasses.dataclass(frozen=True)
-class KernelsConfig:
-    """Kernel/quantization plane knobs (ops/quantize.py + ops/autotune.py
-    + ops/cross_kernel.py fused serving kernel, ISSUE 12): post-training
-    int8 weight quantization, the fused Pallas cross+MLP+head serving
-    kernel, and the per-bucket autotune harness that enables each variant
-    ONLY where it measured faster than the XLA/f32 baseline on the live
-    device AND passed the accuracy gates. Everything defaults OFF; when
-    off the batcher pays one attribute read per dispatch and served
-    scores are bit-identical to the pre-plane stack."""
-
-    # Master switch: build a KernelManager, attach it to the batcher, and
-    # run the autotune harness at warmup.
-    enabled: bool = False
-    # Candidate families the autotune may consider (a family disabled
-    # here is never even measured).
-    quantize: bool = True
-    pallas: bool = True
-    # Run the measurement harness at servable warmup. False = serve only
-    # decisions adopted from a persisted table_file (none = baseline).
-    autotune: bool = True
-    # Measure and record everything, ENABLE nothing (the CI smoke's
-    # contract: the harness is exercised, live serving is untouched).
-    measure_only: bool = False
-    # Decision-table persistence: restarts with the same (model, version,
-    # device, gates) adopt their prior measurements instead of re-tuning.
-    # A relative path resolves against the checkout (utils/runtime.py
-    # CHECKOUT), never the working directory; "" disables persistence.
-    table_file: str = "artifacts/kernel_autotune.json"
-    # Enablement gates: a variant serves a bucket only when measured
-    # speedup >= min_speedup AND max |Δscore| vs the f32 baseline <=
-    # max_abs_delta AND (when a labeled eval set is supplied — bench/CI)
-    # |AUC_f32 - AUC_variant| <= auc_margin.
-    min_speedup: float = 1.0
-    max_abs_delta: float = 0.005
-    auc_margin: float = 0.005
-    # Timing iterations per (bucket, variant); 0 = auto (device-scaled).
-    measure_iters: int = 0
-    # Subset of the bucket ladder to tune; empty = the whole ladder.
-    autotune_buckets: tuple[int, ...] = ()
-    # int8 score RESPONSE wire: with this on, a client that sends
-    # x-dts-score-wire: int8 metadata receives the score tensor as
-    # DT_INT8 plus (scale, min) sidecar outputs and dequantizes locally —
-    # 4x fewer response bytes per score than f32 tensor_content. Clients
-    # that do not opt in are byte-identical to today.
-    int8_score_wire: bool = False
-
-    def __post_init__(self):
-        for name in ("min_speedup", "max_abs_delta", "auc_margin"):
-            v = getattr(self, name)
-            if not isinstance(v, (int, float)) or isinstance(v, bool) or v <= 0:
-                raise ValueError(
-                    f"[kernels] {name} must be a positive number, got {v!r}"
-                )
-        if not isinstance(self.measure_iters, int) or \
-                isinstance(self.measure_iters, bool) or self.measure_iters < 0:
-            raise ValueError(
-                "[kernels] measure_iters must be a non-negative integer, "
-                f"got {self.measure_iters!r}"
-            )
-        for b in self.autotune_buckets:
-            if not isinstance(b, int) or b <= 0:
-                raise ValueError(
-                    "[kernels] autotune_buckets must be positive integers, "
-                    f"got {self.autotune_buckets!r}"
-                )
-        if self.table_file:
-            from .runtime import CHECKOUT
-
-            object.__setattr__(
-                self, "table_file", str(CHECKOUT / self.table_file)
-            )
-
-    def build(self):
-        """KernelManager per this config, or None when disabled. The
-        module-level int8 score-wire gate tracks this build EITHER way:
-        a disabled plane DISARMS it, so a process that built an armed
-        stack earlier (tests, embedded use) cannot leak int8 responses
-        out of a later plane-less stack."""
-        from ..ops.autotune import KernelManager, set_wire_active
-
-        if not self.enabled:
-            set_wire_active(False)
-            return None
-        set_wire_active(self.int8_score_wire)
-        return KernelManager(self)
-
-
-@dataclasses.dataclass(frozen=True)
 class FleetConfig:
     """Fleet robustness plane knobs (fleet/ package, ISSUE 17): the
     cross-replica health gossip every member runs, and the shared rollout
@@ -1232,7 +1144,6 @@ _SECTIONS = {
     "quality": QualityConfig,
     "lifecycle": LifecycleConfig,
     "recovery": RecoveryConfig,
-    "kernels": KernelsConfig,
     "fleet": FleetConfig,
     "slo": SloConfig,
     "cascade": CascadeConfig,

@@ -432,17 +432,6 @@ _HELP = {
         "killers and failed alone (INVALID_ARGUMENT)",
     "dts_tpu_recovery_mttr_mean_seconds":
         "Mean recovery-cycle duration over the retained MTTR history ring",
-    "dts_tpu_kernel_quantized_batches_total":
-        "Batches served by the int8 weight-quantized executables",
-    "dts_tpu_kernel_pallas_batches_total":
-        "Batches served by the fused Pallas serving kernel",
-    "dts_tpu_kernel_bucket_quantized":
-        "Per-bucket autotune decision: 1 = int8 weight path enabled",
-    "dts_tpu_kernel_bucket_pallas":
-        "Per-bucket autotune decision: 1 = fused Pallas kernel enabled",
-    "dts_tpu_kernel_variant_speedup":
-        "Measured step-time speedup of a kernel variant vs the XLA/f32 "
-        "baseline at one bucket (autotune harness, live device)",
     "dts_tpu_recovery_last_cycle_seconds":
         "Duration of the last completed quarantine->reinit->replay "
         "cycle (the live MTTR evidence)",
@@ -749,21 +738,9 @@ class ServerMetrics:
                 # Of them, those the native assembler built (one pass from
                 # the requests' arrays to the upload's words).
                 "fused_batches": getattr(batcher_stats, "fused_batches", 0),
-                # And those whose entry gathers embedding rows with the
-                # Pallas kernel (`startup.gather`).
-                "gather_kernel_batches": getattr(batcher_stats, "gather_kernel_batches", 0),
-                # And those whose entry attends with the Pallas kernel
-                # (`startup.attention`).
-                "attention_kernel_batches": getattr(batcher_stats, "attention_kernel_batches", 0),
-                # And those whose entry runs the routed layers' held experts
-                # through the Pallas grouped kernels (`startup.grouped`).
-                "grouped_kernel_batches": getattr(batcher_stats, "grouped_kernel_batches", 0),
-                # And those whose entry walks the gated delta rule's chunks in
-                # the Pallas kernel (`startup.delta_rule`).
-                "delta_kernel_batches": getattr(batcher_stats, "delta_kernel_batches", 0),
-                # And those whose entry walks a Mamba-2 mixer's SSD chunks in
-                # the Pallas kernel (`startup.ssd`).
-                "ssd_kernel_batches": getattr(batcher_stats, "ssd_kernel_batches", 0),
+                # And those whose entry runs a Pallas kernel, a counter a row
+                # of the batcher's SERVED_KERNELS (`startup.<stamp>` names it).
+                **batcher_stats.kernel_batches(),
                 # And those of one request that its own handler thread
                 # closed and staged (the batcher's direct crossing).
                 "direct_batches": getattr(batcher_stats, "direct_batches", 0),
@@ -878,8 +855,8 @@ class ServerMetrics:
     def prometheus_text(
         self, batcher_stats=None, cache=None, row_cache=None, overload=None,
         utilization=None, quality=None, lifecycle=None, pipeline=None,
-        recovery=None, kernels=None, mesh=None, elastic=None, fleet=None,
-        cascade=None, integrity=None,
+        recovery=None, mesh=None, elastic=None, fleet=None, cascade=None,
+        integrity=None,
     ) -> str:
         """Prometheus exposition (text format 0.0.4) of the same data
         snapshot() serves as JSON. Metric names mirror tensorflow_model_
@@ -1201,8 +1178,6 @@ class ServerMetrics:
             lines.extend(_lifecycle_prometheus_lines(lifecycle))
         if recovery is not None:
             lines.extend(_recovery_prometheus_lines(recovery))
-        if kernels is not None:
-            lines.extend(_kernel_prometheus_lines(kernels))
         if mesh is not None:
             lines.extend(_mesh_prometheus_lines(mesh))
         if elastic is not None:
@@ -1437,75 +1412,6 @@ def _recovery_prometheus_lines(recovery: dict) -> list[str]:
     ):
         _family_lines(lines, metric, kind)
         lines.append(f"{metric} {value}")
-    return lines
-
-
-def _kernel_prometheus_lines(kernels: dict) -> list[str]:
-    """dts_tpu_kernel_* exposition from a KernelManager snapshot dict
-    (ISSUE 12): plane counters, the per-bucket decision gauges (which
-    variant each bucket serves), and the measured per-variant speedups —
-    the autotune evidence, scrapeable. Families grouped and declared once
-    — the exposition lint's invariants."""
-    esc = escape_label_value
-    lines: list[str] = []
-    counters = kernels.get("counters") or {}
-    for metric, kind, value in (
-        ("dts_tpu_kernel_autotunes_total", "counter",
-         counters.get("autotunes", 0)),
-        ("dts_tpu_kernel_table_reuses_total", "counter",
-         counters.get("table_reuses", 0)),
-        ("dts_tpu_kernel_quantized_batches_total", "counter",
-         counters.get("quantized_batches", 0)),
-        ("dts_tpu_kernel_pallas_batches_total", "counter",
-         counters.get("pallas_batches", 0)),
-        ("dts_tpu_kernel_measure_only", "gauge",
-         1 if kernels.get("measure_only") else 0),
-        ("dts_tpu_kernel_int8_score_wire", "gauge",
-         1 if kernels.get("int8_score_wire") else 0),
-    ):
-        _family_lines(lines, metric, kind)
-        lines.append(f"{metric} {value}")
-    decisions = kernels.get("decisions") or {}
-    if decisions:
-        q_lines, p_lines = [], []
-        for mv, per_bucket in sorted(decisions.items()):
-            for bucket, dec in sorted(
-                per_bucket.items(), key=lambda kv: int(kv[0])
-            ):
-                base = (
-                    f'model_version="{esc(mv)}",bucket="{esc(bucket)}"'
-                )
-                q_lines.append(
-                    f"dts_tpu_kernel_bucket_quantized{{{base}}} "
-                    f'{1 if dec.get("quantized") else 0}'
-                )
-                p_lines.append(
-                    f"dts_tpu_kernel_bucket_pallas{{{base}}} "
-                    f'{1 if dec.get("pallas") else 0}'
-                )
-        _family_lines(lines, "dts_tpu_kernel_bucket_quantized", "gauge")
-        lines.extend(q_lines)
-        _family_lines(lines, "dts_tpu_kernel_bucket_pallas", "gauge")
-        lines.extend(p_lines)
-    speed_lines = []
-    for mv, table in sorted((kernels.get("tables") or {}).items()):
-        for bucket, row in sorted(
-            (table.get("buckets") or {}).items(), key=lambda kv: int(kv[0])
-        ):
-            for variant, entry in row.items():
-                if not isinstance(entry, dict):
-                    continue
-                sp = entry.get("speedup")
-                if sp is None:
-                    continue
-                speed_lines.append(
-                    f'dts_tpu_kernel_variant_speedup{{model_version='
-                    f'"{esc(mv)}",bucket="{esc(bucket)}",variant='
-                    f'"{esc(variant)}"}} {sp}'
-                )
-    if speed_lines:
-        _family_lines(lines, "dts_tpu_kernel_variant_speedup", "gauge")
-        lines.extend(speed_lines)
     return lines
 
 

@@ -12,7 +12,7 @@ import os
 import pathlib
 
 # The checkout that holds this package. Paths the program creates at run
-# time (compile cache, autotune table) resolve against it, never against the
+# time (the compile cache) resolve against it, never against the
 # working directory: the cache key includes the path, so a cache that moves
 # with `cd` never hits.
 CHECKOUT = pathlib.Path(__file__).resolve().parents[2]

@@ -361,12 +361,3 @@ def test_a_ctr_servable_has_no_attention_stamp():
         assert batcher.attentions() == {} and batcher.stats.attention_kernel_batches == 0
     finally:
         batcher.stop()
-
-
-def test_metrics_block_counts_the_kernels_batches():
-    from distributed_tf_serving_tpu.serving.batcher import BatcherStats
-    from distributed_tf_serving_tpu.utils.metrics import ServerMetrics
-
-    stats = BatcherStats(batches=3, fused_batches=3, attention_kernel_batches=2)
-    block = ServerMetrics().snapshot(batcher_stats=stats)["batcher"]
-    assert block["batches"] == 3 and block["attention_kernel_batches"] == 2

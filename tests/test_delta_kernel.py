@@ -301,12 +301,3 @@ def test_batcher_stamps_the_delta_rule_and_counts_its_batches(monkeypatch):
     assert stats.batches == 2 and stats.delta_kernel_batches == 2 and counted == 2
     assert stamp == {"M:1": {"kernel": "pallas", "chunk": 64, "pieces": 2, "key_heads": 3, "value_heads": 3, "shared": 1}}
     np.testing.assert_allclose(got, want, atol=1e-4)
-
-
-def test_metrics_block_counts_the_delta_kernels_batches():
-    from distributed_tf_serving_tpu.serving.batcher import BatcherStats
-    from distributed_tf_serving_tpu.utils.metrics import ServerMetrics
-
-    stats = BatcherStats(batches=3, fused_batches=3, delta_kernel_batches=2)
-    block = ServerMetrics().snapshot(batcher_stats=stats)["batcher"]
-    assert block["batches"] == 3 and block["delta_kernel_batches"] == 2

@@ -170,15 +170,6 @@ def test_toml_reads_the_two_model_keys(tmp_path):
     assert model.kind == "dlrm_dcnv2" and model.takes_dense
 
 
-def test_weight_quantisation_leaves_low_rank_layers_whole():
-    from distributed_tf_serving_tpu.ops.quantize import quantize_params
-
-    params = build_model("dlrm_dcnv2", tiny_config()).init(jax.random.PRNGKey(0))
-    quantized = quantize_params(params)
-    assert all(set(layer) == {"v", "w", "b"} for layer in quantized["cross"])
-    assert "qw" in quantized["top_mlp"][0]
-
-
 @pytest.fixture(scope="module")
 def served():
     """The published 214 columns in 26 bags at tiny widths, behind the

@@ -514,15 +514,6 @@ def test_the_ssd_takes_the_kernel_inside_a_served_entry_on_a_tpu_and_nowhere_els
     assert notes == [dict(xla, path="pallas"), dict(xla, path="pallas", chunk=40), xla]
 
 
-def test_metrics_block_counts_the_ssd_kernels_batches():
-    from distributed_tf_serving_tpu.serving.batcher import BatcherStats
-    from distributed_tf_serving_tpu.utils.metrics import ServerMetrics
-
-    stats = BatcherStats(batches=3, fused_batches=3, ssd_kernel_batches=2)
-    block = ServerMetrics().snapshot(batcher_stats=stats)["batcher"]
-    assert block["batches"] == 3 and block["ssd_kernel_batches"] == 2
-
-
 def test_predict_answers_a_row_of_tokens_and_nothing_else(served):
     from distributed_tf_serving_tpu import codec
     from distributed_tf_serving_tpu.client import build_predict_request

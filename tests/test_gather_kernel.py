@@ -309,12 +309,3 @@ def test_batcher_stamps_the_gather_and_counts_its_batches(kind, fields, embed_di
     assert stamp == {f"{kind}:1": {
         "kernel": "pallas", "row_bytes": 512, "in_flight": 16 * fields, "picked_in_kernel": False}}
     np.testing.assert_array_equal(got, want)
-
-
-def test_metrics_block_counts_the_kernels_batches():
-    from distributed_tf_serving_tpu.serving.batcher import BatcherStats
-    from distributed_tf_serving_tpu.utils.metrics import ServerMetrics
-
-    stats = BatcherStats(batches=3, fused_batches=3, gather_kernel_batches=2)
-    block = ServerMetrics().snapshot(batcher_stats=stats)["batcher"]
-    assert block["batches"] == 3 and block["gather_kernel_batches"] == 2

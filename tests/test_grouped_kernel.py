@@ -334,12 +334,3 @@ def test_batcher_stamps_the_grouped_product_and_counts_its_batches(monkeypatch):
     assert stamp == {"M:1": {"kernel": "pallas", "tile": TILE, "pieces": 3, "held": held,
                              "form": "gated_silu", "width": width}}
     np.testing.assert_allclose(got, want, atol=1e-6)
-
-
-def test_metrics_block_counts_the_grouped_kernels_batches():
-    from distributed_tf_serving_tpu.serving.batcher import BatcherStats
-    from distributed_tf_serving_tpu.utils.metrics import ServerMetrics
-
-    stats = BatcherStats(batches=3, fused_batches=3, grouped_kernel_batches=2)
-    block = ServerMetrics().snapshot(batcher_stats=stats)["batcher"]
-    assert block["batches"] == 3 and block["grouped_kernel_batches"] == 2

@@ -33,7 +33,6 @@ from distributed_tf_serving_tpu.serving.server import build_stack
 from distributed_tf_serving_tpu.utils.config import (
     MeshConfig,
     RecoveryConfig,
-    KernelsConfig,
     ServerConfig,
     load_config,
 )
@@ -364,13 +363,6 @@ def test_build_stack_mesh_mode_serves_bit_identical(tmp_path):
 
 
 def test_build_stack_refusals():
-    # [mesh] x [kernels]
-    with pytest.raises(ValueError, match="single-chip batcher path"):
-        build_stack(
-            _server_cfg(), model_config=_model_cfg(),
-            mesh_config=_mesh_cfg(),
-            kernels_config=KernelsConfig(enabled=True),
-        )
     # [mesh] x [recovery]: the blanket refusal is LIFTED (ISSUE 15 — the
     # mesh executor recovers as one unit, default scope="executor");
     # only per-chip scope stays refused.

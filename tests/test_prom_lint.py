@@ -97,42 +97,6 @@ def _fully_armed_text() -> str:
         RecoveryConfig(enabled=True), _BatcherSlot(), clock=lambda: 12.0
     )
     recovery.auto_cycle = False
-    # Kernel plane (ISSUE 12, the tenth plane): a KernelManager snapshot
-    # with per-bucket decisions + a measured table, adversarial
-    # model_version label included.
-    from distributed_tf_serving_tpu.ops.autotune import KernelManager
-    from distributed_tf_serving_tpu.utils.config import KernelsConfig
-
-    kern = KernelManager(KernelsConfig(enabled=True, table_file=""))
-
-    class _Tuned:  # decisions are (weakref-to-tuned-servable, {bucket: dec})
-        pass
-
-    tuned = _Tuned()
-    _fully_armed_text._keepalive = tuned  # outlive the weakrefs below
-    import weakref as _weakref
-
-    with kern._lock:
-        kern._decisions = {
-            ("DCN", 3): (_weakref.ref(tuned),
-                         {256: (True, False), 1024: (True, True)}),
-            ('we"ird\\mo\ndel', 1): (_weakref.ref(tuned),
-                                     {32: (False, True)}),
-        }
-        kern._tables = {
-            ("DCN", 3): {
-                "buckets": {
-                    "256": {
-                        "xla_f32": {"step_us": 120.0},
-                        "xla_int8": {"step_us": 90.0, "speedup": 1.33,
-                                     "max_abs_delta": 0.001,
-                                     "enabled": True},
-                        "decision": "xla_int8",
-                    },
-                },
-            },
-        }
-    kern.quantized_batches = 7
     # Mesh serving mode (ISSUE 13, the eleventh plane): the shape
     # impl.mesh_stats() emits with the utilization ledger riding along —
     # per-device busy gauges with an adversarial device label.
@@ -294,7 +258,6 @@ def _fully_armed_text() -> str:
         lifecycle=lifecycle.snapshot(),
         pipeline=pipeline,
         recovery=recovery.snapshot(),
-        kernels=kern.snapshot(),
         mesh=mesh,
         elastic=elastic,
         fleet=fleet,
@@ -315,8 +278,7 @@ def test_fully_armed_snapshot_passes_lint():
         "dts_tpu_overload_", "dts_tpu_utilization_",
         "dts_tpu_quality_", "dts_tpu_lifecycle_", "dts_tpu_pipeline_",
         "dts_tpu_pipeline_bucket_in_flight", "buffer_ring",
-        "dts_tpu_recovery_", "dts_tpu_kernel_",
-        "dts_tpu_kernel_variant_speedup",
+        "dts_tpu_recovery_",
         "dts_tpu_mesh_", "dts_tpu_mesh_device_busy_fraction",
         "dts_tpu_elastic_", "dts_tpu_elastic_switches_total",
         "dts_tpu_elastic_split_in_flight",

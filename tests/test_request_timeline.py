@@ -28,6 +28,7 @@ from distributed_tf_serving_tpu.models import (
     ctr_signatures,
 )
 from distributed_tf_serving_tpu.serving import DynamicBatcher, PredictionServiceImpl
+from distributed_tf_serving_tpu.serving.batcher import SERVED_KERNELS
 from distributed_tf_serving_tpu.serving.utilization import OccupancyLedger
 from distributed_tf_serving_tpu.utils import tracing
 from distributed_tf_serving_tpu.utils.tracing import request_trace
@@ -493,7 +494,8 @@ def _grpc_predict(port, n=1):
 def test_handler_cpu_is_stamped_only_inside_a_capture(servable, capture):
     """`cpu.rpc_handler`: the pool thread's CPU from `t_taken` to `t_return`,
     one entry an RPC stamped while the gate was open, asked once an RPC; it
-    cannot pass the wall time between the same two stamps."""
+    cannot pass the wall time from `t_taken` to the RPC's end, which holds
+    both reads of the thread's clock."""
     from distributed_tf_serving_tpu.serving.server import create_server
 
     impl, batcher = _impl(servable, max_wait_us=0)
@@ -501,21 +503,32 @@ def test_handler_cpu_is_stamped_only_inside_a_capture(servable, capture):
     server, port = create_server(impl, "127.0.0.1:0")
     server.start()
 
-    def settled(want):
-        deadline = time.monotonic() + 10  # `done` runs after the client has its answer
-        while request_trace.snapshot().get("rpc.server", {}).get("count", 0) < want and \
-                time.monotonic() < deadline:
+    def count(snap, phase):
+        return snap.get(phase, {}).get("count", 0)
+
+    def settled(since, n):
+        """The snapshot once `n` Predicts after `since` are stamped whole.
+        Two threads stamp after the client has its answer: the poller's
+        `done` (`rpc.server` and `cpu.rpc_handler`, one add_many) and the
+        completer, which closes `batch.deliver` after the last set_result.
+        One request is one batch here (sequential, no window)."""
+        deadline = time.monotonic() + 10
+        while True:
+            snap = request_trace.snapshot()
+            if time.monotonic() > deadline or all(
+                    count(snap, p) >= count(since, p) + n for p in ("rpc.server", "batch.deliver")):
+                return snap
             time.sleep(0.005)
-        return request_trace.snapshot()
 
     try:
+        start = request_trace.snapshot()
         _grpc_predict(port)  # compiles
-        base = settled(1)
+        base = settled(start, 1)
         _grpc_predict(port, 3)
-        closed = settled(base["rpc.server"]["count"] + 3)
+        closed = settled(base, 3)
         capture.open = True
         _grpc_predict(port, 5)
-        opened = settled(closed["rpc.server"]["count"] + 5)
+        opened = settled(closed, 5)
         capture.open = False
     finally:
         server.stop(0).wait()
@@ -524,8 +537,10 @@ def test_handler_cpu_is_stamped_only_inside_a_capture(servable, capture):
     def rose(after, before, phase, field):
         return after.get(phase, {}).get(field, 0) - before.get(phase, {}).get(field, 0)
 
+    assert rose(closed, base, "rpc.server", "count") == 3
     assert rose(closed, base, "cpu.rpc_handler", "count") == 0
     assert not any(rose(closed, base, "offcpu." + p, "count") for p in SPLIT)
+    assert rose(opened, closed, "rpc.server", "count") == 5
     assert rose(opened, closed, "cpu.rpc_handler", "count") == 5
     # The same counts as their spans: one decode and one encode a request,
     # one dispatch and one delivery a batch.
@@ -534,9 +549,12 @@ def test_handler_cpu_is_stamped_only_inside_a_capture(servable, capture):
             rose(opened, closed, phase, "count") >= 5, phase
         assert rose(opened, closed, "offcpu." + phase, "total_ms") <= \
             rose(opened, closed, phase, "total_ms") + 1e-3
+    # `cpu_return` is read after `t_return` (the handler's metrics in
+    # between are CPU too) and before `done`, so the wall time that holds
+    # both reads runs to the RPC's end: `rpc.reply` beside the other two.
     cpu_ms = rose(opened, closed, "cpu.rpc_handler", "total_ms")
-    wall_ms = rose(opened, closed, "rpc.request_wait", "total_ms") + sum(
-        rose(opened, closed, name, "total_ms") for name in opened if name.startswith("rpc.listener"))
+    wall_ms = sum(rose(opened, closed, name, "total_ms") for name in opened
+                  if name in ("rpc.request_wait", "rpc.reply") or name.startswith("rpc.listener"))
     assert 0.0 < cpu_ms <= wall_ms + 1e-2
 
 
@@ -554,9 +572,7 @@ def test_the_kernels_counts_of_a_batch_are_one_add_many(servable, monkeypatch):
 
     try:
         impl._run(servable, _payload(seed=0))  # compiles
-        for kernel in ("_gather_kernel", "_attention_kernel", "_grouped_kernel",
-                       "_delta_kernel", "_ssd_kernel"):
-            getattr(batcher, kernel).add(servable)
+        batcher._kernel_kinds[servable] = SERVED_KERNELS
         before = request_trace.snapshot()
         stats0 = {k: getattr(batcher.stats, k) for k in (
             "gather_kernel_batches", "attention_kernel_batches", "grouped_kernel_batches",

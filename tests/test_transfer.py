@@ -11,6 +11,7 @@ import pytest
 from distributed_tf_serving_tpu.models import ModelConfig, Servable, build_model, ctr_signatures
 from distributed_tf_serving_tpu.ops.transfer import pack_host, transfer_spec, unpack_device
 from distributed_tf_serving_tpu.serving import DynamicBatcher
+from distributed_tf_serving_tpu.serving.batcher import fold_ids_host
 
 
 def test_u24_roundtrip_exact():
@@ -303,3 +304,98 @@ def test_batcher_combined_entry_scores_match_eager():
         np.testing.assert_array_equal(got, want[:10])
     finally:
         batcher.stop()
+
+
+# ------------------------------------------------- int8 D2H output wire
+# (ops/transfer.py quantize_output_device / restore_outputs_host, and the
+# host encoding they share with codec.py)
+
+CFG = ModelConfig(
+    num_fields=6, vocab_size=1009, embed_dim=8, mlp_dims=(32, 16),
+    num_cross_layers=2, cross_full_matrix=True, compute_dtype="float32",
+)
+
+
+@pytest.fixture(scope="module")
+def servable():
+    model = build_model("dcn_v2", CFG)
+    return Servable(
+        name="DCN", version=1, model=model,
+        params=model.init(jax.random.PRNGKey(0)),
+        signatures=ctr_signatures(CFG.num_fields),
+    )
+
+
+def make_arrays(n, seed=0):
+    rng = np.random.RandomState(seed)
+    return {
+        "feat_ids": rng.randint(0, 1 << 40, size=(n, CFG.num_fields)).astype(np.int64),
+        "feat_wts": rng.rand(n, CFG.num_fields).astype(np.float32),
+    }
+
+
+def golden(servable, arrays, params=None):
+    batch = {
+        "feat_ids": fold_ids_host(arrays["feat_ids"], CFG.vocab_size),
+        "feat_wts": arrays["feat_wts"],
+    }
+    return np.asarray(
+        servable.model.apply(params or servable.params, batch)["prediction_node"]
+    )
+
+
+def test_int8_d2h_wire_roundtrip_and_bytes(servable):
+    """output_wire_dtype="int8": scores cross D2H as int8 + two 4-byte
+    sidecars, the completer dequantizes to f32, and no sidecar key ever
+    reaches the caller."""
+    batcher = DynamicBatcher(
+        buckets=(32,), max_wait_us=0, output_wire_dtype="int8"
+    ).start()
+    try:
+        arrays = make_arrays(32, seed=2)
+        res = batcher.submit(
+            servable, arrays, output_keys=("prediction_node",)
+        ).result(timeout=30)
+        assert set(res) == {"prediction_node"}
+        got = res["prediction_node"]
+        assert got.dtype == np.float32
+        want = golden(servable, arrays)
+        # Affine over the live range: error <= range/508 (sigmoid: ~2e-3).
+        assert np.max(np.abs(got - want)) <= (want.max() - want.min()) / 254
+        # 1 byte/score + 8 sidecar bytes vs the 8 B/row f32 baseline.
+        assert batcher.stats.bytes_downloaded == 32 * 1 + 8
+        assert batcher.stats.bytes_download_full_f32 == 32 * 2 * 4
+    finally:
+        batcher.stop()
+
+
+def test_int8_wire_unfiltered_outputs(servable):
+    """All-outputs requests (no filter) quantize every f32 output — the
+    logits' unbounded range rides its own per-tensor (scale, min)."""
+    batcher = DynamicBatcher(
+        buckets=(32,), max_wait_us=0, output_wire_dtype="int8"
+    ).start()
+    try:
+        arrays = make_arrays(20, seed=3)
+        res = batcher.submit(servable, arrays).result(timeout=30)
+        assert set(res) == {"prediction_node", "logits"}
+        want = golden(servable, arrays)
+        rng = want.max() - want.min()
+        assert np.max(np.abs(res["prediction_node"] - want)) <= rng / 254
+    finally:
+        batcher.stop()
+
+
+def test_quantize_scores_numpy_roundtrip():
+    rng = np.random.RandomState(5)
+    from distributed_tf_serving_tpu import codec
+
+    v = rng.rand(257).astype(np.float32)
+    q, scale, mn = codec.quantize_scores(v)
+    assert q.dtype == np.int8
+    back = codec.dequantize_scores(q, scale, mn)
+    assert np.max(np.abs(back - v)) <= scale / 2 + 1e-9
+    # Constant vector: exact round-trip through the epsilon scale.
+    c = np.full(7, 0.25, np.float32)
+    q, scale, mn = codec.quantize_scores(c)
+    np.testing.assert_allclose(codec.dequantize_scores(q, scale, mn), c, atol=1e-6)

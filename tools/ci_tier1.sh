@@ -153,21 +153,6 @@ if [ "$rc" -eq 0 ] && [ "${TIER1_RECOVERY_SMOKE:-0}" = "1" ]; then
     python tools/check_recovery_smoke.py "$RECOVERY_LINE" || rc=1
 fi
 
-# Kernel smoke (TIER1_KERNEL_SMOKE=1): the ISSUE-12 safety gate — the
-# autotune harness runs end to end on CPU in MEASURE-ONLY mode against a
-# trained model: every variant measured per bucket with the max-|dScore|
-# and AUC accuracy gates evaluated, the persisted decision table
-# well-formed, NOTHING enabled (measure-only's contract), and with the
-# plane off served scores bit-identical to a plane-less batcher
-# (tools/check_kernel_smoke.py — CPU-safe: Pallas variants are recorded
-# as ineligible on the interpret backend, never timed as if real).
-if [ "$rc" -eq 0 ] && [ "${TIER1_KERNEL_SMOKE:-0}" = "1" ]; then
-    KERNEL_LINE="${TIER1_KERNEL_LINE:-/tmp/tier1_kernel_smoke.json}"
-    echo "tier1: kernel smoke (line $KERNEL_LINE)"
-    timeout -k 10 300 env JAX_PLATFORMS=cpu \
-        python tools/check_kernel_smoke.py | tee "$KERNEL_LINE" || rc=1
-fi
-
 # Elastic smoke (TIER1_ELASTIC_SMOKE=1): the ISSUE-15 serving-mode gate —
 # on 8 emulated CPU devices (the script forces the device count itself) a
 # pinned `pressure` fault escalates the overload state machine to
