@@ -61,7 +61,13 @@ states stay in VMEM from a row's first chunk to its last, x, B, C and y
 crossing as the convolution leaves them; the same pieces in the same pairs,
 float32 sums in another order (tests/test_ssd_kernel.py). A length that is no
 multiple of the chunk is padded with `dt = 0`, which leaves the state as it
-is.
+is. The convolution before it is ONE kernel too, in every layer
+(ops/conv_kernel.py, chosen by `conv_choice` from the shapes; the
+`startup.conv` stamp): it reads the channels x | B | C where they lie in the
+input projection's array and leaves them side by side in one array, which the
+SSD's kernel reads three windows of, so that between `in_proj` and the walk
+the channels are read once and written once
+(tests/test_conv_kernel.py); everywhere else `sequence.causal_conv`.
 
 The attention is `sequence.blocked_attention`: in a one-chip served entry on a
 TPU every layer but the last runs ONE Pallas kernel (ops/attention_kernel.py),
@@ -267,6 +273,35 @@ def note_ssd(length: int, s: dict, last_only: bool = False) -> None:
         served.ssd.append(choice)
 
 
+def conv_choice(length: int, s: dict) -> dict:
+    """`{"path": "pallas" | "xla", "lanes", "positions"}`: what convolves the
+    mixer's channels over rows of `length` positions: the Pallas kernel that
+    reads them where they lie in the input projection's array and leaves x, B
+    and C side by side in one (ops/conv_kernel.py: no array of their own on
+    either side), in blocks of `lanes` lanes and `positions` positions, where
+    a served entry's kernels run (`sequence.kernels_run`) and the shapes are
+    whole blocks; else XLA's form (`sequence.causal_conv`), with `"why"`
+    where a shape keeps the kernel out (`conv_kernel.whole_blocks` has the
+    rule and the reasons: `lanes`, `positions`, `taps`). A servable's
+    `startup.conv` stamp."""
+    from ..ops import conv_kernel
+
+    xla = {"path": "xla", "lanes": 0, "positions": 0}
+    if not sequence.kernels_run():
+        return xla
+    lanes, positions, why = conv_kernel.whole_blocks(s["d_ssm"], s["channels"], length, s["taps"])
+    return dict(xla, why=why) if why else {"path": "pallas", "lanes": lanes, "positions": positions}
+
+
+def note_conv(length: int, s: dict) -> dict:
+    """`conv_choice`, noted for the served entry being traced."""
+    served = sequence.served_entry()
+    choice = conv_choice(length, s)
+    if served is not None and served.conv is not None and choice not in served.conv:
+        served.conv.append(choice)
+    return choice
+
+
 def ssd(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array,
         initial_state: jax.Array | None = None, *, chunk: int, cd=jnp.float32,
         last_only: bool = False, count: int | None = None) -> tuple[jax.Array, jax.Array]:
@@ -310,9 +345,16 @@ def ssd(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array,
         with jax.named_scope("chunks"):
             dt = jnp.moveaxis(chunks(dt), 3, 1)  # [n, H, Z, C]: a chunk's positions in the lanes
             state = jnp.zeros((n, heads, width, state_width), jnp.float32) if initial_state is None else initial_state
+            flat = [padded(v).reshape(n, steps * chunk, -1) for v in (x, b, c)]
+            if ssd_kernel.windows_fit(heads, groups, width, state_width):
+                # Side by side in ONE array, which the kernel reads three windows of. `ssm` hands x, B and C as
+                # the three parts of the convolution's one array: cut and joined again is that array itself to
+                # XLA's simplifier, and the compiled step holds neither a slice nor a copy of them
+                # (tests/test_tpu_compile.py). Three arrays of their own (a test's, a planted fault's) are joined
+                # by a copy.
+                flat = [jnp.concatenate(flat, axis=-1), None, None]
             y, state = ssd_kernel.chunk_walk(
-                dt, jnp.cumsum(dt * a.astype(jnp.float32)[:, None, None], axis=3),
-                *(padded(v).reshape(n, steps * chunk, -1) for v in (x, b, c)),
+                dt, jnp.cumsum(dt * a.astype(jnp.float32)[:, None, None], axis=3), *flat,
                 state.astype(STATE_DTYPE).astype(jnp.float32), heads=heads, groups=groups, cd=jnp.dtype(cd),
                 count=count, state_dtype=jnp.dtype(STATE_DTYPE), interpret=sequence.served_entry().interpret)
         return y.reshape(n, steps * chunk, heads, width)[:, :length], state
@@ -362,11 +404,18 @@ def ssm(p: dict, a: jax.Array, s: dict, cd, eps: float, last_only: bool = False,
     n, length, _ = a.shape
     heads, width, groups, state = s["ssm_heads"], s["ssm_head"], s["groups"], s["state"]
     note_ssd(length, s, last_only)
+    conv = note_conv(length, s)
     with jax.named_scope("in_proj"):
         projected = _dot(a * s["ssm_in"], p["in"], cd, count) * slice_multipliers(s)
         z, mixed, dt = jnp.split(projected, (s["d_ssm"], s["d_ssm"] + s["channels"]), axis=-1)
     with jax.named_scope("conv"):
-        mixed = sequence.causal_conv(mixed, p["conv_w"], p["conv_b"])
+        if conv["path"] == "pallas":  # the channels read where they lie in the projection: no `mixed` of their own
+            from ..ops import conv_kernel
+
+            mixed = conv_kernel.causal_conv(projected, p["conv_w"], p["conv_b"], offset=s["d_ssm"],
+                                            channels=s["channels"], interpret=sequence.served_entry().interpret)
+        else:
+            mixed = sequence.causal_conv(mixed, p["conv_w"], p["conv_b"])
         x, b, c = jnp.split(mixed, (s["d_ssm"], s["d_ssm"] + groups * state), axis=-1)
         x = x.reshape(n, length, heads, width)
     with jax.named_scope("ssd"):

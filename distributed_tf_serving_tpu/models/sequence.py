@@ -218,14 +218,16 @@ def query_blocks(queries: int, keys: int, window: int | None = None, block: int 
 
 class Served(NamedTuple):
     """What `serving_attention` was entered with: the notes lists of the
-    attention, the routed layers, the delta rule, the SSD and the weight
-    products, and whether the kernels run interpreted."""
+    attention, the routed layers, the delta rule, the SSD, the weight
+    products and the Mamba-2 mixer's convolution, and whether the kernels run
+    interpreted."""
     notes: list
     interpret: bool
     grouped: list | None
     delta: list | None
     ssd: list | None
     products: list | None
+    conv: list | None
 
 
 _served = threading.local()  # .entry: a Served while serving_attention is entered
@@ -233,7 +235,8 @@ _served = threading.local()  # .entry: a Served while serving_attention is enter
 
 @contextlib.contextmanager
 def serving_attention(notes: list, interpret: bool = False, grouped: list | None = None,
-                      delta: list | None = None, ssd: list | None = None, products: list | None = None):
+                      delta: list | None = None, ssd: list | None = None, products: list | None = None,
+                      conv: list | None = None):
     """While the batcher traces a one-chip served entry in this thread
     (serving/batcher.py _build_entry, and nowhere else): an attention at all
     positions may take the Pallas kernel (ops/attention_kernel.py), and
@@ -247,7 +250,10 @@ def serving_attention(notes: list, interpret: bool = False, grouped: list | None
     dict to `delta`: the `startup.delta_rule` stamp); and a Mamba-2 mixer's
     SSD at all positions may take its own (ops/ssd_kernel.py, chosen by
     `falcon_h1.takes_kernel`, for falcon_h1's and nemotron_h's; `falcon_h1.note_ssd` appends
-    `falcon_h1.ssd_choice`'s dict to `ssd`: the `startup.ssd` stamp); and
+    `falcon_h1.ssd_choice`'s dict to `ssd`: the `startup.ssd` stamp), and the
+    convolution before it its own (ops/conv_kernel.py, chosen by
+    `falcon_h1.conv_choice`, whose dict `falcon_h1.note_conv` appends to
+    `conv`: the `startup.conv` stamp); and
     `product` appends to `products`, where one is given, every product of an
     activation in pieces against a weight that it traces, as `(M, k, n,
     pieces, form)` (the `startup.products` stamp: the form is chosen the same
@@ -261,7 +267,7 @@ def serving_attention(notes: list, interpret: bool = False, grouped: list | None
     trainer trace `model.apply` themselves, and a `tpu_custom_call` neither
     partitions nor has a gradient rule."""
     before = getattr(_served, "entry", None)
-    _served.entry = Served(notes, interpret, grouped, delta, ssd, products)
+    _served.entry = Served(notes, interpret, grouped, delta, ssd, products, conv)
     try:
         yield notes
     finally:

@@ -38,6 +38,14 @@ their group. The heads a step divide a group's, so a step never
 straddles two groups and none hangs over the array's edge; where no such
 count has whole lanes (a test's narrow heads) one step takes every head and
 every group, and the whole axis is a legal block whatever its width.
+
+Where the convolution's kernel leaves x, B and C side by side in ONE array
+`[n, L, H x P | G x N | G x N]` (ops/conv_kernel.py), that array crosses
+three times and the three blocks are windows of it: x's at the lanes it had,
+B's and C's offset by the whole blocks that lie before them (`windows_fit`:
+64 and 72 blocks of 128 lanes at Nemotron-H's widths, 16 and 18 of 256 at
+Falcon-H1's). No copy of x, B or C out of it exists (1.34 GB a layer read and
+written at Nemotron-H's widths: ISSUE 63); the kernel's body is the same.
 """
 
 from __future__ import annotations
@@ -138,6 +146,23 @@ def heads_a_step(heads: int, groups: int, width: int, wide: int) -> int:
     return max(fit) if fit else heads
 
 
+def groups_lanes(heads: int, groups: int, width: int, wide: int) -> int:
+    """Lanes of a grid step's B and C blocks: its heads' group's, or every
+    group's where a step takes every head."""
+    per = heads // groups
+    return -(-heads_a_step(heads, groups, width, wide) // per) * wide
+
+
+def windows_fit(heads: int, groups: int, width: int, wide: int) -> bool:
+    """Whether x, B and C can cross as three windows of ONE array
+    `[n, L, H x P | G x N | G x N]`: a step's blocks are whole lanes (no
+    whole-axis block, which in the one array is another axis) and B and C
+    start at whole blocks of theirs."""
+    lanes = groups_lanes(heads, groups, width, wide)
+    return (heads_a_step(heads, groups, width, wide) * width % LANES == 0 and lanes % LANES == 0
+            and heads * width % lanes == 0 and groups * wide % lanes == 0)
+
+
 @functools.partial(jax.jit, static_argnames=("heads", "groups", "cd", "count", "state_dtype", "interpret"))
 def chunk_walk(dt, total, x, b, c, start, *, heads: int, groups: int, cd, count: int, state_dtype=jnp.float32,
                interpret: bool = False):
@@ -148,13 +173,14 @@ def chunk_walk(dt, total, x, b, c, start, *, heads: int, groups: int, cd, count:
            dt = 0, which feeds nothing and forgets nothing)
     total  `[n, H, Z, C]` float32, the running sum of `dt a` inside each chunk
     x      `[n, L, H x P]` float32 as the convolution leaves it, L = Z x C
-    b, c   `[n, L, G x N]` float32; head h reads group `h // (H / G)`
+    b, c   `[n, L, G x N]` float32; head h reads group `h // (H / G)`. Or both None, and `x` is
+           `[n, L, H x P | G x N | G x N]`: x, B and C side by side, read where they lie (`windows_fit` asked first)
     start  `[n, H, P, N]` float32, the state before the first position
 
     Activations enter the products as `count` pieces of `cd` in the pairs
     `i + j < count`; the state is rounded to `state_dtype` after every chunk."""
     n, _, steps, chunk = total.shape
-    width, wide = x.shape[-1] // heads, b.shape[-1] // groups
+    width, wide = start.shape[2:]
     held, group = pieces_held(cd, count), heads_a_step(heads, groups, width, wide)
     per = min(group, heads // groups)  # heads of a step that share a group; the step spans `group // per` groups
     pairs = held * (held + 1) // 2
@@ -163,16 +189,23 @@ def chunk_walk(dt, total, x, b, c, start, *, heads: int, groups: int, cd, count:
         return pl.BlockSpec((None, group) + shape, lambda r, g, z: (r, g, 0, 0))
 
     heads_lanes = pl.BlockSpec((None, chunk, group * width), lambda r, g, z: (r, z, g))
-    # The step's groups: the one its heads lie in, or all of them
-    groups_lanes = pl.BlockSpec(
-        (None, chunk, group // per * wide), lambda r, g, z: (r, z, g * group // (heads // groups)))
+    lanes = groups_lanes(heads, groups, width, wide)
+
+    def of_groups(before: int):  # The step's groups, the one its heads lie in or all of them, `before` lanes in
+        return pl.BlockSpec((None, chunk, lanes), lambda r, g, z: (r, z, before // lanes + g * group // (heads // groups)))
+
+    one = b is None  # x | B | C in one array: three windows of it
+    sizes = 2 * n * steps * chunk * heads * width, 2 * n * steps * chunk * wide * heads // per
+    x, b, c = (x, x, x) if one else (x, b, c)
     products = chunk * chunk * width + 2 * chunk * width * wide
     return pl.pallas_call(
         functools.partial(_kernel, held=held, cd=cd, state_dtype=state_dtype, chunk=chunk, heads=group, per=per,
                           width=width, wide=wide),
-        out_shape=(jax.ShapeDtypeStruct(x.shape, jnp.float32), jax.ShapeDtypeStruct(start.shape, jnp.float32)),
+        out_shape=(jax.ShapeDtypeStruct((n, steps * chunk, heads * width), jnp.float32),
+                   jax.ShapeDtypeStruct(start.shape, jnp.float32)),
         grid=(n, heads // group, steps),
-        in_specs=[a_row(steps, chunk), a_row(steps, chunk), heads_lanes, groups_lanes, groups_lanes,
+        in_specs=[a_row(steps, chunk), a_row(steps, chunk), heads_lanes,
+                  of_groups(heads * width if one else 0), of_groups(heads * width + groups * wide if one else 0),
                   a_row(width, wide)],
         out_specs=(heads_lanes, a_row(width, wide)),
         scratch_shapes=[pltpu.VMEM((group, wide, width), jnp.float32)],
@@ -181,8 +214,7 @@ def chunk_walk(dt, total, x, b, c, start, *, heads: int, groups: int, cd, count:
         cost_estimate=pl.CostEstimate(
             flops=2 * pairs * n * steps * (heads * products + heads // per * chunk * chunk * wide),
             transcendentals=n * heads * steps * chunk * (chunk + 3),
-            bytes_accessed=4 * (dt.size + total.size + 2 * x.size + heads // per * (b.size + c.size) // groups
-                                + 2 * start.size)),
+            bytes_accessed=4 * (dt.size + total.size + sum(sizes) + 2 * start.size)),
         interpret=interpret,
         name="ssd_chunks",
     )(dt, total, x, b, c, start)

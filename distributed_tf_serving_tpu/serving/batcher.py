@@ -794,6 +794,8 @@ SERVED_KERNELS: tuple[ServedKernel, ...] = (
     _served("delta", "delta_rule", "kernel", lambda n: ()),
     # models/falcon_h1.py ssd_choice: a Mamba-2 mixer's SSD chunk walk.
     _served("ssd", "ssd", "path", lambda n: (n["chunk"],)),
+    # models/falcon_h1.py conv_choice: a Mamba-2 mixer's convolution.
+    _served("conv", "conv", "path", lambda n: (n["positions"], "why" in n)),
 )
 
 
@@ -816,6 +818,7 @@ class BatcherStats:
     grouped_kernel_batches: int = 0
     delta_kernel_batches: int = 0
     ssd_kernel_batches: int = 0
+    conv_kernel_batches: int = 0
     # Batches of one request that its own handler thread closed and staged
     # (submit's direct crossing): no collector, no coalesce window, no
     # dispatch thread. The phase `batch.direct` counts the same.
@@ -2114,7 +2117,7 @@ class DynamicBatcher:
             # that counts, where the model has one (below).
             with serving_gathers(notes["gather"]), serving_attention(
                     notes["attention"], grouped=notes["grouped"], delta=notes["delta"],
-                    ssd=notes["ssd"], products=products):
+                    ssd=notes["ssd"], products=products, conv=notes["conv"]):
                 out = apply(p, batch)
             self._kernel_kinds[servable] = tuple(
                 k for k in SERVED_KERNELS

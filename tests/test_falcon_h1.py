@@ -7,8 +7,10 @@ split with its state handed over, the extremes of decay, the last-position
 cut of both mixers, a padded row, what the benchmark's tolerance catches (each
 multiplier, each piece of the mixer, the precisions below), the step's
 counters and how they reach `/monitoring`, the plan and the `startup.ssd`
-stamp, the shapes at the published cut, and the configurations that are
-refused."""
+and `startup.conv` stamps, the mixer through its two kernels interpreted (the
+convolution's, which reads the projection where it lies, and the SSD's, which
+reads x, B and C as windows of the convolution's one array), the shapes at the
+published cut, and the configurations that are refused."""
 
 import dataclasses
 import importlib.util
@@ -488,6 +490,9 @@ def test_a_request_through_the_interpreted_kernels_scores_like_the_reference(ref
     assert startup["ssd"] == {"M:1": {"path": "pallas", "chunk": 64, "state_bytes_a_row": 8 * 16 * 32 * 4,
                                      "heads": [8, 16, 32]}}
     assert batcher.stats.batches == 1 and batcher.stats.ssd_kernel_batches == 1 and counted() - before == 1
+    # 150 positions are no whole sublane tiles: the convolution stays XLA's, and says why
+    assert startup["conv"] == {"M:1": {"path": "xla", "lanes": 0, "positions": 0, "why": "positions"}}
+    assert batcher.stats.conv_kernel_batches == 0
 
 
 def test_the_ssd_takes_the_kernel_inside_a_served_entry_on_a_tpu_and_nowhere_else(monkeypatch):
@@ -512,6 +517,102 @@ def test_the_ssd_takes_the_kernel_inside_a_served_entry_on_a_tpu_and_nowhere_els
         falcon_h1.note_ssd(40, s)
         falcon_h1.note_ssd(150, s, last_only=True)  # the last layer's hand-overs stay XLA's scan
     assert notes == [dict(xla, path="pallas"), dict(xla, path="pallas", chunk=40), xla]
+
+
+def test_the_convolution_takes_the_kernel_where_its_shapes_are_whole_blocks(monkeypatch):
+    """`conv_choice` from what a trace can see, as `ssd_choice`: `pallas`
+    inside the batcher's one-chip entry on a TPU (or interpreted) where
+    `d_ssm` and the channels are whole blocks of lanes and the length whole
+    sublane tiles, XLA's form with the reason elsewhere; `note_conv` notes
+    each choice once, and the last layer convolves like the others."""
+    s = falcon_h1._sizes(load_config(os.path.join(ROOT, "configs", "falcon_h1_small.toml"))["model"])
+    xla = {"path": "xla", "lanes": 0, "positions": 0}
+    assert (s["d_ssm"], s["channels"]) == (128, 256) and falcon_h1.conv_choice(160, s) == xla
+    with sequence.serving_attention([], interpret=True):
+        assert falcon_h1.conv_choice(160, s) == {"path": "pallas", "lanes": 128, "positions": 160}
+        assert falcon_h1.conv_choice(150, s) == dict(xla, why="positions")  # 18.75 sublane tiles
+        assert falcon_h1.conv_choice(160, dict(s, d_ssm=64, channels=192)) == dict(xla, why="lanes")
+        assert falcon_h1.conv_choice(160, dict(s, channels=320)) == dict(xla, why="lanes")  # x | B | C: 2.5 lane tiles
+        assert falcon_h1.conv_choice(160, dict(s, taps=10)) == dict(xla, why="taps")
+        # the published widths: 4 and 8 blocks of 1,024 lanes before the channels, 5 and 10 of them
+        assert falcon_h1.conv_choice(2048, dict(s, d_ssm=4096, channels=5120)) == {
+            "path": "pallas", "lanes": 1024, "positions": 512} == falcon_h1.conv_choice(2048, dict(s, d_ssm=8192, channels=10240))
+    monkeypatch.setattr(sequence.jax, "default_backend", lambda: "tpu")
+    assert falcon_h1.conv_choice(160, s) == xla  # outside an entry whatever the backend
+    with sequence.serving_attention([], conv=(notes := [])):
+        for length in (160, 160, 150, 4096):
+            falcon_h1.note_conv(length, s)
+    assert notes == [{"path": "pallas", "lanes": 128, "positions": 160}, dict(xla, why="positions"),
+                     {"path": "pallas", "lanes": 128, "positions": 4096}]
+
+
+# (heads, a head's width, groups, the state's width): whether x, B and C cross into the SSD's kernel as windows of one array
+MIXERS = {"windows of the one array": (4, 64, 2, 128, True), "three arrays split out of it": (8, 16, 2, 32, False)}
+
+
+@pytest.mark.parametrize("last_only", [False, True], ids=["all positions", "the last position"])
+@pytest.mark.parametrize("name", sorted(MIXERS))
+def test_the_mixer_through_its_two_kernels_is_the_mixer_through_xla(name, last_only):
+    """`ssm` inside a served entry whose kernels run interpreted: the
+    convolution's kernel reads the channels where they lie in the input
+    projection's array and the SSD's kernel its result (at all positions; the
+    last layer's hand-overs stay XLA's scan behind the same convolution),
+    against the same call outside any entry, to float32 rounding."""
+    from distributed_tf_serving_tpu.ops import ssd_kernel
+
+    heads, width, groups, state, windows = MIXERS[name]
+    config = tiny_config(num_fields=128, mamba_d_ssm=heads * width, mamba_n_heads=heads, mamba_d_head=width,
+                         mamba_d_state=state, mamba_n_groups=groups, mamba_chunk_size=64)
+    s = falcon_h1._sizes(config)
+    assert ssd_kernel.windows_fit(heads, groups, width, state) is windows
+    p = falcon_h1._ssm_init(jax.random.PRNGKey(1), s, jnp.float32)
+    a = jnp.asarray(np.random.default_rng(2).standard_normal((2, 128, 64)), jnp.float32)
+    mixer = lambda p, a: falcon_h1.ssm(p, a, s, jnp.float32, 1e-5, last_only)  # noqa: E731
+    want = jax.jit(mixer)(p, a)
+
+    def served(p, a):
+        with sequence.serving_attention([], interpret=True, ssd=ssds, conv=convs):
+            return mixer(p, a)
+
+    ssds, convs = [], []
+    text = str(jax.make_jaxpr(served)(p, a))
+    assert text.count("pallas_call") == (1 if last_only else 2) and "name=causal_conv" in text
+    assert [c["path"] for c in convs] == ["pallas"] and [c["path"] for c in ssds] == ["xla" if last_only else "pallas"]
+    got = jax.jit(served)(p, a)
+    assert got.shape == want.shape == (2, 1 if last_only else 128, 64)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5 * float(jnp.abs(want).max()))
+
+
+def test_a_request_through_an_entry_whose_mixers_run_both_kernels(reference, tolerance, monkeypatch):
+    """configs/falcon_h1_small.toml at 160 tokens a row, whole sublane tiles:
+    every layer's convolution is the kernel (the last layer's too), every
+    layer's SSD but the last's its own; the `startup.conv` stamp says so and
+    the batch is counted once under `batch.conv_kernel`."""
+    import functools
+
+    from distributed_tf_serving_tpu.serving import batcher as batcher_mod
+    from distributed_tf_serving_tpu.serving.server import build_stack
+    from distributed_tf_serving_tpu.utils.tracing import request_trace
+
+    monkeypatch.setattr(batcher_mod, "serving_attention", functools.partial(sequence.serving_attention, interpret=True))
+    cfgs = load_config(os.path.join(ROOT, "configs", "falcon_h1_small.toml"))
+    config = dataclasses.replace(cfgs["model"], name="M", num_fields=160)
+    cfg = dataclasses.replace(cfgs["server"], model_name="M", warmup=False, num_fields=160)
+    _registry, batcher, impl, servable, _mesh, _watcher = build_stack(cfg, model_config=config)
+    counted = lambda: request_trace.snapshot().get("batch.conv_kernel", {}).get("count", 0)  # noqa: E731
+    try:
+        arrays, before = rows(3, config, folded=False), counted()
+        got = batcher.submit(servable, arrays).result(timeout=600)
+        startup = impl.runtime_stats()["startup"]
+    finally:
+        batcher.stop()
+    batch = dict(arrays, feat_ids=(arrays["feat_ids"] % config.vocab_size).astype(np.int32))
+    want = reference_scores(reference, servable.params, batch, config)
+    assert np.max(np.abs(got["prediction_node"] - want)) < tolerance
+    assert startup["conv"] == {"M:1": {"path": "pallas", "lanes": 128, "positions": 160}}
+    assert startup["ssd"]["M:1"]["path"] == "pallas"
+    assert batcher.stats.batches == 1 == batcher.stats.conv_kernel_batches == batcher.stats.ssd_kernel_batches
+    assert counted() - before == 1
 
 
 def test_predict_answers_a_row_of_tokens_and_nothing_else(served):
@@ -539,6 +640,7 @@ def test_runtime_block_reports_the_plan_and_the_ssd_stamp(served):
     assert startup["ssd"] == {"M:1": {"path": "xla", "chunk": 64, "state_bytes_a_row": 16384, "heads": [8, 16, 32]}}
     assert startup["attention"] == {"M:1": {"kernel": "xla", "block": 0, "pieces": 2}}
     assert startup["expert_plan"] == {"M:1": None} and startup["delta_rule"] == {} and startup["grouped"] == {}
+    assert startup["conv"] == {"M:1": {"path": "xla", "lanes": 0, "positions": 0}}  # on a CPU: no reason to give
     assert startup["assembler"] == {"M:1": "native"} or not native.available()
     assert "feat_ids int32/24b" in startup["upload_format"]["M:1"]
 

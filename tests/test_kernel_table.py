@@ -1,6 +1,6 @@
 """The batcher's one table of served Pallas kernels (serving/batcher.py
 SERVED_KERNELS): every kind's stamp, counter and phase derive from its row,
-and the bookkeeping around a trace is one loop for all five.
+and the bookkeeping around a trace is one loop for all six.
 
 The model here is a small DCN-v2 whose `apply` also notes, as a family's
 `takes_kernel` would at trace time, what a kind's `*_choice` "chose" for the
@@ -40,6 +40,7 @@ PINNED = {
     "grouped": ("grouped", "grouped_kernel_batches", "batch.grouped_kernel", "grouped", "kernel"),
     "delta": ("delta_rule", "delta_kernel_batches", "batch.delta_kernel", "delta", "kernel"),
     "ssd": ("ssd", "ssd_kernel_batches", "batch.ssd_kernel", "ssd", "path"),
+    "conv": ("conv", "conv_kernel_batches", "batch.conv_kernel", "conv", "path"),
 }
 KINDS = list(PINNED)
 # A kind's note as its `*_choice` writes it: `which` under the row's key, `v`
@@ -50,6 +51,7 @@ NOTE = {
     "grouped": lambda which, v: {"kernel": which, "tile": 128, "pieces": 3, "held": 4, "rows": v},
     "delta": lambda which, v: {"kernel": which, "chunk": 64, "pieces": 2},
     "ssd": lambda which, v: {"path": which, "chunk": v, "state_bytes_a_row": 16384},
+    "conv": lambda which, v: {"path": which, "lanes": 1024, "positions": v},
 }
 CFG = ModelConfig(num_fields=6, vocab_size=509, embed_dim=8, mlp_dims=(16,), num_cross_layers=1,
                   cross_full_matrix=True, compute_dtype="float32")
@@ -93,7 +95,7 @@ def _row(kind):
     return next(k for k in SERVED_KERNELS if k.kind == kind)
 
 
-def test_the_table_holds_the_five_kinds_in_order():
+def test_the_table_holds_the_six_kinds_in_order():
     assert [k.kind for k in SERVED_KERNELS] == KINDS
 
 
@@ -108,7 +110,8 @@ def test_a_rows_names_are_the_ones_the_readers_pin(kind):
     batcher = DynamicBatcher(buckets=(4,), max_wait_us=0)
     assert set(batcher.kernel_stamps()) == {p[0] for p in PINNED.values()}
     method = {"gather": batcher.gathers, "attention": batcher.attentions, "grouped": batcher.groupeds,
-              "delta": batcher.delta_rules, "ssd": batcher.ssds}[kind]
+              "delta": batcher.delta_rules, "ssd": batcher.ssds,
+              "conv": lambda: batcher._stamp("conv")}[kind]  # the newest kind has no older name
     assert method() == {} == batcher.kernel_stamps()[stamp]
 
 
@@ -119,7 +122,7 @@ def test_the_metrics_block_counts_a_kinds_batches(kind):
     block = ServerMetrics().snapshot(batcher_stats=stats)["batcher"]
     assert block["batches"] == 3 and block[counter] == 2
     others = [PINNED[k][1] for k in KINDS if k != kind]
-    assert [block[c] for c in others] == [0] * 4 and stats.kernel_batches()[counter] == 2
+    assert [block[c] for c in others] == [0] * len(others) and stats.kernel_batches()[counter] == 2
 
 
 @pytest.mark.parametrize("kind", KINDS)
@@ -211,6 +214,6 @@ def test_the_stamps_reach_the_runtime_block_by_the_tables_walk():
         startup = impl.runtime_stats()["startup"]
         assert startup["ssd"] == {"M:1": NOTE["ssd"]("pallas", 64)}
         assert startup["gather"]["M:1"]["kernel"] == "xla"  # DCN's own lookup, on the CPU
-        assert [startup[s] for s in ("attention", "grouped", "delta_rule", "products")] == [{}] * 4
+        assert [startup[s] for s in ("attention", "grouped", "delta_rule", "conv", "products")] == [{}] * 5
     finally:
         batcher.stop()

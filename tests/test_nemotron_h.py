@@ -160,6 +160,56 @@ def test_the_served_step_in_bfloat16_is_within_the_tolerance(reference, toleranc
     assert np.abs(got - want).max() < tolerance
 
 
+@pytest.mark.parametrize("name,mixer", [
+    # 4 heads of 64 (half a lane tile, as published) over 2 groups of 128: x, B and C windows of the convolution's array
+    ("windows of the one array", {"mamba_d_ssm": 256, "mamba_n_heads": 4, "mamba_d_head": 64, "mamba_d_state": 128}),
+    ("three arrays split out of it", {}),
+])
+def test_the_served_step_through_the_convolutions_kernel_is_within_the_tolerance(reference, tolerance, name, mixer):
+    """160 tokens a row, whole sublane tiles: every Mamba-2 layer's
+    convolution is the kernel that reads the input projection where it lies
+    (interpreted), the SSD's kernel behind it, at three pieces; inside the
+    configuration's tolerance of the float32 reference's scores, and of the
+    same step through XLA's paths to the kernels' rounding."""
+    config = tiny_config(compute_dtype="bfloat16", param_dtype="bfloat16", moe_latent_size=128, num_fields=160, **mixer)
+    model, params = model_and_tree(config)
+    batch = rows(2, config)
+    convs, ssds = [], []
+
+    def served(p, b):
+        with interpreted([], grouped=[], ssd=ssds, conv=convs):
+            return model.apply(p, b)["prediction_node"]
+
+    got = np.asarray(jax.jit(served)(params, batch))
+    s = nemotron_h._sizes(config)
+    lanes = 256 if mixer else 128  # 256 | 512 channels; 128 | 256
+    assert convs == [{"path": "pallas", "lanes": lanes, "positions": 160}] and s["channels"] % lanes == 0
+    assert sorted(c["path"] for c in ssds) == ["pallas", "xla"]  # the last Mamba-2 layer's hand-overs are XLA's scan
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(jax.jit(lambda p, b: reference.forward(p, b, **sizes_of(config)))(params, batch))
+    assert np.abs(got - want).max() < tolerance
+    assert np.abs(got - np.asarray(jax.jit(model.apply)(params, batch)["prediction_node"])).max() < tolerance / 4
+
+
+def test_the_convolutions_choice_at_the_published_widths_and_where_it_says_no():
+    """Nemotron-H's mixer: 8,192 channels of z before 10,240 convolved ones,
+    8 and 10 blocks of 1,024 lanes, 2,048 positions four blocks of 512; the
+    small TOML's 150 tokens are no whole sublane tiles, and a `d_ssm` that is
+    no whole lane tile keeps XLA's form whatever the length."""
+    with open(os.path.join(CONFIG_DIR, "config.json")) as f:
+        shape = json.load(f)["toml"]["model"]
+    s = nemotron_h._sizes(ModelConfig(**{k: tuple(v) if isinstance(v, list) else v for k, v in shape.items()}))
+    small = nemotron_h._sizes(load_config(os.path.join(ROOT, "configs", "nemotron_h_small.toml"))["model"])
+    xla = {"path": "xla", "lanes": 0, "positions": 0}
+    assert (s["d_ssm"], s["channels"], small["d_ssm"], small["channels"]) == (8192, 10240, 256, 384)
+    assert falcon_h1.conv_choice(2048, s) == xla
+    with interpreted([]):
+        assert falcon_h1.conv_choice(2048, s) == {"path": "pallas", "lanes": 1024, "positions": 512}
+        assert falcon_h1.conv_choice(150, small) == dict(xla, why="positions")
+        assert falcon_h1.conv_choice(152, small) == {"path": "pallas", "lanes": 128, "positions": 152}
+        assert falcon_h1.conv_choice(2048, dict(s, d_ssm=8256, channels=10304)) == dict(xla, why="lanes")
+
+
 def test_one_bfloat16_piece_is_the_precision_below(reference, monkeypatch):
     """The served step at ONE piece an activation misses the reference by a
     bfloat16's rounding, far more than at this family's three."""
@@ -475,6 +525,8 @@ def test_the_batcher_stamps_the_three_choices_and_counts_the_steps_counters(monk
     assert grouped == {"kernel": "pallas", "tile": 128, "pieces": 3, "held": 4, "rows": grouped["rows"],
                        "form": "relu2", "width": 128}
     assert grouped["rows"] == routed.layout_tiles(4 * config.num_fields, 4, 4, 128) * 128
+    # 150 positions are no whole sublane tiles: the convolution stays XLA's, and the stamp says why
+    assert startup["conv"]["N:1"] == {"path": "xla", "lanes": 0, "positions": 0, "why": "positions"}
     assert startup["layer_plan"]["N:1"] == {"mamba": 3, "latent/moe": 3, "attention": 1}
     assert startup["expert_plan"]["N:1"]["held"] == 4
     assert counted["moe.tokens"] == 3 * 3 * config.num_fields and 8 <= counted["moe.experts_hit"] <= 12

@@ -576,7 +576,7 @@ def test_the_kernels_counts_of_a_batch_are_one_add_many(servable, monkeypatch):
         before = request_trace.snapshot()
         stats0 = {k: getattr(batcher.stats, k) for k in (
             "gather_kernel_batches", "attention_kernel_batches", "grouped_kernel_batches",
-            "delta_kernel_batches", "ssd_kernel_batches", "direct_batches")}
+            "delta_kernel_batches", "ssd_kernel_batches", "conv_kernel_batches", "direct_batches")}
         monkeypatch.setattr(request_trace, "add_many", add_many)
         with batcher._cv:  # a trickle, so the batch crosses direct
             batcher._arrival_gap_s, batcher._traversal_s = 1.0, 0.001
@@ -586,9 +586,9 @@ def test_the_kernels_counts_of_a_batch_are_one_add_many(servable, monkeypatch):
         monkeypatch.undo()
         batcher.stop()
     counted = ["batch.gather_kernel", "batch.attention_kernel", "batch.grouped_kernel",
-               "batch.delta_kernel", "batch.ssd_kernel", "batch.direct"]
+               "batch.delta_kernel", "batch.ssd_kernel", "batch.conv_kernel", "batch.direct"]
     assert [c for c in calls if any(n in counted for n in c)] == [counted]
-    assert [_delta(before, p, "count") for p in counted] == [1] * 6
+    assert [_delta(before, p, "count") for p in counted] == [1] * 7
     assert all(getattr(batcher.stats, k) - v == 1 for k, v in stats0.items())
 
 
@@ -668,6 +668,8 @@ def test_cli_server_reports_startup_stamps_and_raw_counters(tmp_path):
         assert startup.pop("delta_rule") == {}
         # Nor an SSD stamp (PR 54): no layer of it holds a Mamba-2 mixer.
         assert startup.pop("ssd") == {}
+        # Nor a convolution's stamp (PR 63), for the same reason.
+        assert startup.pop("conv") == {}
         # Nor a products stamp (PR 57): its step makes no product of an activation in pieces.
         assert startup.pop("products") == {}
         # And how many gRPC listeners share its port, from how many cores (PR 34).

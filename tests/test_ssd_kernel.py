@@ -199,6 +199,42 @@ def test_a_steps_heads_divide_a_groups_and_are_whole_lanes(heads, groups, width,
     assert got == want and (got == heads or (heads // groups % got == 0 and got * width % 128 == 0))
 
 
+@pytest.mark.parametrize("heads,groups,width,wide,lanes,fit", [
+    (32, 2, 128, 256, 256, True),  # Falcon-H1's: B 16 and C 18 blocks of 256 lanes in
+    (128, 8, 64, 128, 128, True),  # Nemotron-H's: 64 and 72 blocks of 128
+    (4, 2, 64, 128, 128, True), (16, 2, 128, 256, 256, True),
+    (8, 2, 16, 32, 64, False),  # every head a step: the whole axis is a block of its own array alone
+    (4, 2, 16, 32, 64, False), (12, 1, 128, 64, 64, False),  # B and C half a lane tile wide
+])
+def test_where_x_b_and_c_can_cross_as_windows_of_one_array(heads, groups, width, wide, lanes, fit):
+    """Whole lanes a block and B and C starting at whole blocks of theirs
+    (ops/conv_kernel.py leaves x | B | C side by side); where they do not,
+    `falcon_h1.ssd` hands the three arrays as before."""
+    assert ssd_kernel.groups_lanes(heads, groups, width, wide) == lanes
+    assert ssd_kernel.windows_fit(heads, groups, width, wide) is fit
+
+
+@pytest.mark.parametrize("name", ["the published head and state, two groups, a step a group",
+                                  "heads of 64, a whole group of 16 a step"])
+def test_three_windows_of_one_array_are_the_three_arrays(name):
+    """`chunk_walk` handed x | B | C in one array and no `b` or `c` reads
+    what it reads handed the three apart, to the bit: the same blocks, found
+    at other indices."""
+    n, length, heads, width, groups, wide, chunk, cd, _ = SHAPES[name]
+    length = -(-length // chunk) * chunk
+    x, dt, a, b, c, _ = ssd_inputs(n, length, heads, width, groups, wide, seed=3)
+    steps = jnp.moveaxis(jnp.asarray(dt).reshape(n, length // chunk, chunk, heads), 3, 1)
+    total = jnp.cumsum(steps * jnp.asarray(a)[:, None, None], axis=3)
+    flat = [jnp.asarray(v).reshape(n, length, -1) for v in (x, b, c)]
+    walk = functools.partial(ssd_kernel.chunk_walk, heads=heads, groups=groups, cd=jnp.dtype(cd), count=2, interpret=True)
+    start = jnp.zeros((n, heads, width, wide), jnp.float32)
+    apart = walk(steps, total, *flat, start)
+    whole = walk(steps, total, jnp.concatenate(flat, axis=-1), None, None, start)
+    assert ssd_kernel.windows_fit(heads, groups, width, wide) and whole[0].shape == (n, length, heads * width)
+    for got, want in zip(whole, apart):
+        np.testing.assert_array_equal(got, want)
+
+
 # --------------------------------------------------------- who takes the kernel
 
 

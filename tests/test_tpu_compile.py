@@ -333,8 +333,40 @@ DEFAULT_VMEM = 16 << 20  # what a kernel has where it asks for no more
 def kernels_vmem(text: str, name: str) -> list[int]:
     """The bytes of VMEM each compiled `tpu_custom_call` named `name` was given."""
     calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line and f"%{name}" in line]
+    if name == "causal_conv":  # its result is the SSD kernel's operand three times: the convolution's own lines only
+        calls = [line for line in calls if re.match(r"\s*(ROOT )?%causal_conv", line)]
     return [int(re.search(r'used_scoped_memory_configs":\[\{"memory_space":"1","offset":"0","size":"(\d+)"', line).group(1))
             for line in calls]
+
+
+def assert_the_mixers_channels_cross_where_they_lie(text: str, rows: int, width: int, d_ssm: int, channels: int, layers: int):
+    """PR 63, of a compiled step whose `layers` Mamba-2 mixers' last reads one
+    position: every mixer's convolution is the kernel, handed the input
+    projection's array `[rows, 2048, width]` WHOLE, twice (its blocks and the
+    tile before them), so no `f32[rows, 2048, channels]` slice of it exists;
+    and of every mixer at all positions the kernel's one result is read by
+    the SSD's kernel three times (x, B and C its windows) and by the fusion
+    that adds `D x`, by no slice and no copy. The last mixer's hand-overs are
+    XLA's scan, which cuts x, B and C out as before (ROADMAP S13(g))."""
+    entry = text[text.index("ENTRY"):].splitlines()
+    shape = lambda lanes: rf"f32\[{rows},2048,{lanes}\]"  # noqa: E731
+    convs = [re.match(rf"\s*%(causal_conv[.\d]*) = {shape(channels)}\S* custom-call\(%([\w.\-]+), %([\w.\-]+),", line)
+             for line in entry if line.lstrip().startswith("%causal_conv")]
+    assert len(convs) == layers and all(convs), len(convs)
+    made = {m.group(1): re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\(.*?\)|\S+) ([\w\-]+)\(", line)
+            for line in entry if (m := re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line))}
+    for conv in convs:
+        assert conv.group(2) == conv.group(3) and re.match(shape(width), made[conv.group(2)].group(1)), conv.group(0)
+    assert not [line for line in entry if re.match(rf"\s*%[\w.\-]+ = {shape(channels)}\S* (slice|copy|fusion)\(", line)]
+    read_whole = 0
+    for conv in convs:
+        users = [line for line in entry if re.search(rf"%{re.escape(conv.group(1))}[,)]", line) and line != conv.string]
+        kinds = sorted(made[re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line).group(1)].group(2) for line in users)
+        walks = [line for line in users if line.lstrip().startswith("%ssd_chunks")]
+        if walks:
+            assert kinds == ["custom-call", "fusion"] and walks[0].count(f"%{conv.group(1)},") == 3, (kinds, walks[0][:400])
+            read_whole += 1
+    assert read_whole == layers - 1
 
 
 @pytest.mark.parametrize("rows", [4, 2])
@@ -454,23 +486,29 @@ def test_falcon_h1s_steps_compile_at_the_published_cut(one_chip, no_compile_cach
     grouping no other cell has), the SSD's chunk walk ONE Pallas kernel a
     layer but the last too (PR 55; a `while` a layer before it, the state's
     hand-over through HBM), whose VMEM is inside the 16 MiB a kernel has by
-    default and no more asked for; the one loop left is the last layer's
-    hand-overs, whose `y` is read at the last position alone; and what the
-    step holds beside the 6.98 GB of weights fits the chip's 16 GB."""
+    default and no more asked for; the convolution before it ONE kernel in
+    every layer (PR 63), which reads the input projection where it lies and
+    whose one result the SSD's kernel reads as three windows; the one loop left
+    is the last layer's hand-overs, whose `y` is read at the last position
+    alone; and what the step holds beside the 6.98 GB of weights fits the
+    chip's 16 GB."""
     compiled, accessed = sequence_cells_step("falcon_h1_34b_rerank", "falcon_h1", one_chip, rows)
     memory, text = compiled.memory_analysis(), compiled.as_text()
     layers = len(cells_model("falcon_h1_34b_rerank", "falcon_h1")[0].layer_plan)
     assert layers in (4, 5) and {5: 6.9e9, 4: 6.0e9}[layers] < memory.argument_size_in_bytes < {5: 7.1e9, 4: 6.2e9}[layers]
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14 * GIB
     assert memory.generated_code_size_in_bytes < 64 << 20
-    # the attention's kernel and the SSD's, a layer but the last
-    assert text.count('custom_call_target="tpu_custom_call"') == 2 * (layers - 1) and "vmem_limit" not in text
+    # the attention's kernel and the SSD's, a layer but the last, and the convolution's in every layer
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 * (layers - 1) + layers and "vmem_limit" not in text
     vmem = kernels_vmem(text, "ssd_chunks")
     assert len(vmem) == layers - 1 and all(0 < size < DEFAULT_VMEM * 3 // 4 for size in vmem)  # 8.1 MB
+    assert [0 < size <= DEFAULT_VMEM - (1 << 19) for size in kernels_vmem(text, "causal_conv")] == [True] * layers  # 8.5 MB
+    assert_the_mixers_channels_cross_where_they_lie(text, rows, 9248, 4096, 5120, layers)
     assert len(re.findall(r"\) while\(", text)) == 1  # the last layer's hand-over scan, and no other loop
     assert not SCORE_TILE.search(text)
-    # 61.7 GB at 4 rows and 39.1 at 2, two pieces against a weight ONE product (PR 57): 87.4 a product a piece
-    assert accessed < {4: 66e9, 2: 42e9}[rows]
+    # 58.1 GB at 4 rows (61.7 before PR 63: the channels sliced out of the projection, and x, B and C copied out of the
+    # convolution's result); two pieces against a weight ONE product (PR 57): 87.4 a product a piece
+    assert accessed < {4: 60e9, 2: 42e9}[rows]
     assert_no_large_weight_is_copied(text, "falcon_h1_34b_rerank", "falcon_h1", count={4: 10, 2: 10}[rows])  # q, k and v of the layers at all positions
 
 
@@ -508,7 +546,9 @@ def test_nemotron_hs_eight_row_step_compiles_at_the_published_cut(one_chip, no_c
     (3.265 B parameters, 64 of 512 experts a routed layer, 8 rows of 2,048
     tokens), the top bucket's step with its ten counters: the five Mamba-2
     layers at all positions ONE SSD kernel each at 128 heads of 64 (a whole
-    group of 16 a step), the one attention layer the attention kernel at 16
+    group of 16 a step) behind the convolution's kernel (PR 63: all six
+    Mamba-2 layers', the input projection read where it lies and x, B and C
+    three windows of its one result), the one attention layer the attention kernel at 16
     query heads a key-value head, the five routed layers the two grouped
     kernels each at the ungated form (`grouped_up` against one weight) over
     1,024-wide rows and a layout of `T x 22` rows and a tile an expert (3.96 GB
@@ -521,14 +561,16 @@ def test_nemotron_hs_eight_row_step_compiles_at_the_published_cut(one_chip, no_c
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14 * GIB  # 6.51 GB of temporaries
     assert memory.generated_code_size_in_bytes < 64 << 20  # 29 MB
     assert len(re.findall(r"\) while\(", text)) == 1
-    assert text.count('custom_call_target="tpu_custom_call"') == 5 + 1 + 2 * 5 and "vmem_limit" not in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 5 + 6 + 1 + 2 * 5 and "vmem_limit" not in text
     assert len(kernels_vmem(text, "ssd_chunks")) == 5 and len(kernels_vmem(text, "attention")) == 1
+    assert [0 < size <= DEFAULT_VMEM - (1 << 19) for size in kernels_vmem(text, "causal_conv")] == [True] * 6  # 8.5 MB
+    assert_the_mixers_channels_cross_where_they_lie(text, 8, 18560, 8192, 10240, 6)
     # (a line that names `%grouped_up` is the kernel's own or the next one's, which reads its result)
     assert len(kernels_vmem(text, "grouped_up")) == 2 * len(kernels_vmem(text, "grouped_down")) == 10
     assert not kernels_vmem(text, "grouped_gate_up")
     assert all(0 < size < DEFAULT_VMEM for name in ("ssd_chunks", "attention", "grouped_up", "grouped_down")
                for size in kernels_vmem(text, name))  # 8.2, 10.4, 4.3 and 14.7 MB
-    assert accessed < 250e9  # 229 GB
+    assert accessed < 225e9  # 213.5 GB; 229 before PR 63
 
 
 # ------------------------------------------- the Pallas attention (PR 48)
@@ -648,6 +690,37 @@ def test_ssd_kernel_compiles_at_the_cells_rungs(one_chip, no_compile_cache, form
     assert compiled.memory_analysis().generated_code_size_in_bytes < 2 << 20  # one kernel a layer
 
 
+# ------------------------------------------- the convolution's kernel (PR 63)
+#
+# Mosaic's verdict on the sublane rolls, the walk's loop and the blocks at a lane offset of the projection's array, at
+# both cells' widths and rungs.
+
+# (the projection's width [z | x | B | C | dt], the channels' offset in it, the channels x | B | C, the rungs' rows)
+CONV_SHAPES = {"falcon_h1": (9248, 4096, 5120, (4, 2)), "nemotron_h": (18560, 8192, 10240, (8, 4, 2))}
+
+
+@pytest.mark.parametrize("form,rows", [(form, rows) for form, (*_, rungs) in sorted(CONV_SHAPES.items()) for rows in rungs])
+def test_conv_kernel_compiles_at_the_cells_rungs(one_chip, no_compile_cache, form, rows):
+    from distributed_tf_serving_tpu.ops.conv_kernel import causal_conv
+
+    width, offset, channels = CONV_SHAPES[form][:3]
+    shaped = lambda *shape, dtype=jnp.float32: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    run = functools.partial(causal_conv, offset=offset, channels=channels)
+    compiled = jax.jit(run).lower(shaped(rows, 2048, width), shaped(channels, 4, dtype=jnp.bfloat16),
+                                  shaped(channels, dtype=jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text and "vmem_limit" not in text
+    # the projection crosses whole, twice, and no slice of it is made (alone, the compiler lays Falcon-H1's 9,248-wide
+    # ARGUMENT out positions-minor and turns it round first; in the step the product writes it as the kernel reads it:
+    # `assert_the_mixers_channels_cross_where_they_lie`)
+    assert re.search(rf"%causal_conv[.\d]* = f32\[{rows},2048,{channels}\]\S* custom-call\(%(\S+), %\1, ", text)
+    assert " slice(" not in text[text.index("ENTRY"):]
+    # 8.45 MB by Mosaic's count (two 2 MiB blocks in and out, twice each for the pipeline): under the 16 MiB a kernel
+    # has by default with half a MiB to spare, as the compact attention is held (a 4 MiB block is refused by the chip)
+    assert [0 < size <= DEFAULT_VMEM - (1 << 19) for size in kernels_vmem(text, "causal_conv")] == [True]
+    assert compiled.memory_analysis().generated_code_size_in_bytes < 1 << 20
+
+
 # ------------------------------------------- the grouped kernels (PR 51)
 #
 # The held experts of a routed layer as one pass of two Pallas kernels over
@@ -761,7 +834,11 @@ LOWERED_TEXT = {
     # PR 60: the eighth family's served step (the SSD and attention kernels, the grouped kernels at the ungated form
     # over the latent's rows); the seven digests above passed PR 60's changes to `routed.held_experts`, `route`,
     # `falcon_h1.ssm` and both kernels' files untouched. PR 60's own, held here for the next change to be seen against
-    "nemotron3_super_120b_rerank/nemotron_h/served": "aa7a7c3ae1dcfb18",
+    # PR 63: the six Mamba-2 mixers' convolution is the kernel that reads the projection where it lies, and the five
+    # SSD kernels read x, B and C as windows of its one result (six more custom calls; aa7a7c3ae1dcfb18 before it); the
+    # nine other digests, `phi4flash`, `olmo_hybrid` and `qwen3_next` (`sequence.causal_conv`'s other callers) among
+    # them, passed that change untouched
+    "nemotron3_super_120b_rerank/nemotron_h/served": "b7516d9acbaf0e05",
 }
 
 
