@@ -2,8 +2,8 @@
 
 Model families cover every BASELINE.json config: dcn / dcn_v2 (the
 reference's served model, DCNClient.java:33), wide_deep, deepfm, two_tower,
-dlrm, dlrm_dcnv2; and eight sequence rankers, phi4flash, pangu_moe, exaone_moe, olmo_hybrid, mimo_v2, falcon_h1,
-qwen3_next and nemotron_h,
+dlrm, dlrm_dcnv2; and nine sequence rankers, phi4flash, pangu_moe, exaone_moe, olmo_hybrid, mimo_v2, falcon_h1,
+qwen3_next, nemotron_h and sdar_moe,
 whose row is F token ids.
 All share the reference serving contract feat_ids/feat_wts [n, F] -> prediction_node [n].
 """
@@ -22,7 +22,7 @@ from .registry import (
 )
 
 # Import model modules for their registration side effects.
-from . import dcn, deepfm, dlrm, exaone_moe, falcon_h1, generic, mimo_v2, nemotron_h, olmo_hybrid, pangu_moe, phi4flash, qwen3_next, two_tower, wide_deep  # noqa: E402,F401
+from . import dcn, deepfm, dlrm, exaone_moe, falcon_h1, generic, mimo_v2, nemotron_h, olmo_hybrid, pangu_moe, phi4flash, qwen3_next, sdar_moe, two_tower, wide_deep  # noqa: E402,F401
 
 __all__ = [
     "Batch",

@@ -32,8 +32,8 @@ Batch = dict[str, jax.Array]
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
-    """Knob set shared by the CTR model zoo and the eight sequence families
-    (phi4flash, pangu_moe, exaone_moe, olmo_hybrid, mimo_v2, falcon_h1, qwen3_next, nemotron_h), whose keys
+    """Knob set shared by the CTR model zoo and the nine sequence families
+    (phi4flash, pangu_moe, exaone_moe, olmo_hybrid, mimo_v2, falcon_h1, qwen3_next, nemotron_h, sdar_moe), whose keys
     carry the names of their published config.json and whose defaults build a
     small valid model.
 
@@ -217,6 +217,21 @@ class ModelConfig:
     hybrid_override_pattern: str = "MEMEMEM*EMEM"  # the published pattern's first twelve
     moe_latent_size: int = 32
     moe_shared_expert_intermediate_size: int = 64
+    # sdar_moe (models/sdar_moe.py): a row is num_fields token ids as above,
+    # and the shared keys mean what they mean elsewhere: embed_dim the hidden
+    # size, num_attention_heads / num_key_value_heads / head_dim / rope_theta
+    # the attention's (rotary on all of a head's dims, a learned RMS weight a
+    # query and a key head), num_experts the ROUTER's width (as qwen3_next
+    # reads it; a softmax, the family's own), num_experts_per_tok,
+    # norm_topk_prob, moe_intermediate_size, experts_held, first_expert_held,
+    # layer_norm_eps. Every layer is routed and there is no shared expert.
+    # The one key of its own: the positions of a block of the mask. A
+    # position sees its whole block and every block before it (block
+    # diffusion's one denoising pass). It divides num_fields, and nothing
+    # else chooses a mask. 1, the default (these defaults build a valid
+    # model at any num_fields), is the causal mask; the published family's
+    # is 4, and every configuration of it says so.
+    block_length: int = 1
     # numerics
     compute_dtype: str = "bfloat16"  # "float32" for AUC-parity mode
     param_dtype: str = "float32"
@@ -271,12 +286,12 @@ class Model:
     # beside the id/weight pair (the DLRM families).
     takes_dense: bool = False
     # The kind of every layer of a sequence family (phi4flash, pangu_moe, exaone_moe,
-    # olmo_hybrid, mimo_v2, falcon_h1, qwen3_next, nemotron_h), whose rows are num_fields TOKENS;
+    # olmo_hybrid, mimo_v2, falcon_h1, qwen3_next, nemotron_h, sdar_moe), whose rows are num_fields TOKENS;
     # empty for the CTR families.
     layer_plan: tuple[str, ...] = ()
     # What a family with a routed layer holds of it, as (name, number) pairs:
     # published, held, first, top_k, heads_published, heads_held,
-    # chips_sharing_layer (pangu_moe, exaone_moe, mimo_v2, qwen3_next, nemotron_h); empty
+    # chips_sharing_layer (pangu_moe, exaone_moe, mimo_v2, qwen3_next, nemotron_h, sdar_moe); empty
     # for every other family.
     expert_plan: tuple[tuple[str, int], ...] = ()
     # For a family whose mixer differs by layer, as (name, value) pairs a
@@ -290,12 +305,13 @@ class Model:
     # beside it `ssd`, the SSM's (kind, chunk, hand-overs and state bytes a
     # row); nemotron_h's layers, one mixer each, state a Mamba-2 layer's entry
     # as falcon_h1's `ssd`, an attention layer's as a full one's (rotary_dims
-    # 0) and a routed layer's kind, latent width and experts' form; empty for
-    # every other family.
+    # 0) and a routed layer's kind, latent width and experts' form; sdar_moe's
+    # layers, all alike, state the block mask's `span` beside a full layer's
+    # entries; empty for every other family.
     attention_plan: tuple[tuple[tuple[str, object], ...], ...] = ()
     # For a family whose step counts what it did on the device (pangu_moe's,
-    # exaone_moe's, mimo_v2's, qwen3_next's and nemotron_h's routing, exaone_moe's, olmo_hybrid's,
-    # mimo_v2's and qwen3_next's score tiles, olmo_hybrid's, qwen3_next's and falcon_h1's state hand-overs,
+    # exaone_moe's, mimo_v2's, qwen3_next's, nemotron_h's and sdar_moe's routing, exaone_moe's, olmo_hybrid's,
+    # mimo_v2's, qwen3_next's and sdar_moe's score tiles (sdar_moe's also the pairs its mask keeps AHEAD of the query), olmo_hybrid's, qwen3_next's and falcon_h1's state hand-overs,
     # falcon_h1's and nemotron_h's score tiles and state hand-overs, mimo_v2's sinks): `apply_stats(params, batch) -> (apply's outputs, int32
     # [len(step_stats)])`, the counters named by `step_stats` in order. The
     # batcher decides on it when it BUILDS the servable's entry: the counters
@@ -381,7 +397,7 @@ def register_model(kind: str):
 def build_model(kind: str, config: ModelConfig | None = None, **overrides) -> Model:
     """Instantiate a model family by kind: dcn, dcn_v2, wide_deep, deepfm,
     two_tower, dlrm, dlrm_dcnv2, phi4flash, pangu_moe, exaone_moe, olmo_hybrid, mimo_v2,
-    falcon_h1, qwen3_next, nemotron_h."""
+    falcon_h1, qwen3_next, nemotron_h, sdar_moe."""
     if kind not in _BUILDERS:
         raise KeyError(f"unknown model kind {kind!r}; have {sorted(_BUILDERS)}")
     if config is None:

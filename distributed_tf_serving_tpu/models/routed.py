@@ -1,17 +1,21 @@
 """What the routed sequence-ranker families (pangu_moe, exaone_moe, mimo_v2,
-qwen3_next, nemotron_h) share beside `sequence`'s products and blocks: the
+qwen3_next, nemotron_h, sdar_moe) share beside `sequence`'s products and blocks: the
 product with a weight under its own name (`dot`: `sequence.product` against a
 weight, the families' three pieces its stacked form), the RMSNorm, an expert
-of either form (the gated MLP, `silu(x G) * (x U)` through `D`: four
+of either form (the gated MLP, `silu(x G) * (x U)` through `D`: five
 families'; the ungated one, `relu(x U)^2 D`: nemotron_h's; `expert_form`
 reads which from the expert's own tree, a `gate` leaf or none), the rotary
 turn, the router (sigmoid scores or a softmax, as the family says; with a
 selection bias where the family has one) and the held experts' grouped
 product with its counters. One implementation, so that a change to any of
-them is measured on all five families' cells, whose rows into the experts
+them is measured on all six families' cells, whose rows into the experts
 (the residual's 7680, 6144, 4096, 2048; nemotron_h's a LATENT's 1024 under a
-residual of 4096), held experts (8, 8, 8, 128, 64), experts a token (8, 8, 8,
-10, 22) and loads an expert (256 tokens a step, 512, 256, 320, 704) differ.
+residual of 4096; sdar_moe's 2048), held experts (8, 8, 8, 128 of 512, 64 of
+512, and sdar_moe's 128 of 128: the one layer held WHOLE, where every one of a
+token's choices is here), experts a token (8, 8, 8, 10, 22, 8), their widths
+(2048, 2048, 2048, 512, 2688, 768) and loads an expert (256 tokens a step,
+512, 256, 320, 704, and a mean of 1,024, a deployment's mean, skewed five
+times over under seeded weights) differ.
 
 Every product takes the number of pieces (`count`) from its caller: a family
 keeps its own `OPERAND_PIECES` and hands it on at every call, so that its
@@ -266,7 +270,8 @@ def held_experts(p: dict, x: jax.Array, chosen: jax.Array, gates: jax.Array, fir
     stacked, of either form (`expert_form`: `gate`, `up`, `down`, or `up` and
     `down` alone), and H is whatever width they take and give: the residual's,
     or a latent's (nemotron_h: 1,024 of whole lanes under a residual of
-    4,096); `chosen` and `gates` are the router's `[T, k]`; `live [T]` is
+    4,096); `held` may be ALL the routed experts (sdar_moe: `first` 0, every
+    choice a held one, `T x k` pairs); `chosen` and `gates` are the router's `[T, k]`; `live [T]` is
     false for the tokens left out (a padded row's: their part is zero). The
     caller's `experts` scope.
 

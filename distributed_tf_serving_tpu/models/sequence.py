@@ -1,5 +1,5 @@
 """What the sequence-ranker families (phi4flash, pangu_moe, exaone_moe,
-olmo_hybrid, mimo_v2, falcon_h1, qwen3_next, nemotron_h) share: products whose float32 activations enter as pieces of the
+olmo_hybrid, mimo_v2, falcon_h1, qwen3_next, nemotron_h, sdar_moe) share: products whose float32 activations enter as pieces of the
 compute dtype (`product`: one product a call wherever a form exists that
 copies no large array; against a weight always, two pieces along a second
 contracted axis and three or more stacked, noted for the `startup.products`
@@ -7,10 +7,13 @@ stamp), the causal softmax of a block of queries, the blocks
 themselves, causal attention in those blocks (`blocked_attention`:
 exaone_moe's, olmo_hybrid's and qwen3_next's full layers, falcon_h1's,
 nemotron_h's (no rotary turn before it), both
-kinds of mimo_v2's, whose window layers' softmax holds a learned sink), the
+kinds of mimo_v2's, whose window layers' softmax holds a learned sink, and
+sdar_moe's, whose mask is the one that looks AHEAD: to the end of the query's
+block of `span` positions; `causal_softmax` names the three mask parameters,
+`window`, `sink` and `span`, and who asks for each), the
 attention at all positions as one Pallas kernel a layer where a one-chip
 served entry runs on a TPU and the kernel's scratch fits its VMEM at the
-layer's shapes (`attention`, `takes_kernel`, `attention_choice`: all eight
+layer's shapes (`attention`, `takes_kernel`, `attention_choice`: all nine
 families), the causal depthwise convolution (`causal_conv`: phi4flash's Mamba
 layers, olmo_hybrid's and qwen3_next's linear ones and falcon_h1's and
 nemotron_h's Mamba-2 mixers), and
@@ -178,11 +181,20 @@ def product(spec: str, x: jax.Array, y: jax.Array, cd, count: int = OPERAND_PIEC
     return sum(einsum(spec, xs[i], ys[j]) for i, j in pairs)
 
 
-def causal_softmax(scores: jax.Array, q_start: int, window: int | None = None, sink: jax.Array | None = None):
+def causal_softmax(scores: jax.Array, q_start: int, window: int | None = None, sink: jax.Array | None = None,
+                   span: int | None = None):
     """softmax over the keys of `scores [..., queries, keys]`, the queries at
     positions q_start .. against the keys at positions 0 ..: a query sees the
     keys up to its own position, and within `window` positions where one is
-    given (position t sees t - window + 1 .. t). float32 in, float32 out.
+    given (position t sees t - window + 1 .. t); with `span`, up to the END of
+    its block of `span` positions (`u // span <= t // span`: the one mask that
+    looks ahead). float32 in, float32 out.
+
+    The three mask parameters and who asks for each: `window` narrows the
+    causal reach (phi4flash's, exaone_moe's and mimo_v2's window layers),
+    `sink` widens the softmax by a term no key carries (mimo_v2's window
+    layers), `span` widens the reach to the query's own block (sdar_moe, every
+    layer). None of each is the plain causal softmax.
 
     `sink` (broadcast against `scores[..., :1]`) is a learned logit that no
     key carries: it joins the maximum and the denominator and gives no value,
@@ -190,7 +202,7 @@ def causal_softmax(scores: jax.Array, q_start: int, window: int | None = None, s
     (the probabilities, the sink's own share `[..., queries, 1]`)."""
     q_pos = q_start + jnp.arange(scores.shape[-2])[:, None]
     k_pos = jnp.arange(scores.shape[-1])[None, :]
-    seen = k_pos <= q_pos
+    seen = k_pos <= q_pos if span is None else k_pos < (q_pos // span + 1) * span
     if window is not None:
         seen &= q_pos - k_pos < window
     if sink is None:
@@ -208,7 +220,11 @@ def query_blocks(queries: int, keys: int, window: int | None = None, block: int 
     (start, stop, first, last): queries start .. stop - 1 read the keys
     first .. last - 1, which is all their causal reach (and their window's)
     holds. The block's first query stands at position
-    `keys - queries + start - first` among those keys."""
+    `keys - queries + start - first` among those keys. Under a block mask's
+    `span` the blocks are the same ones: a block that ends on a span's edge
+    reads nothing past its own last position, and `attention_choice`, which
+    every caller with a span asks first, refuses a span that would not
+    (`attention_kernel.check_span`)."""
     offset = keys - queries
     for start in range(0, queries, block):
         stop = min(start + block, queries)
@@ -307,28 +323,39 @@ class Heads(NamedTuple):
     cd: object
 
 
-def attention_choice(queries: int, keys: int, window: int | None, count: int, heads: Heads | None = None) -> dict:
+def attention_choice(queries: int, keys: int, window: int | None, count: int, heads: Heads | None = None,
+                     span: int | None = None) -> dict:
     """`{"kernel": "pallas" | "xla", "block", "pieces"}`: which path serves
     an attention, the side of the kernel's score tile (0 where XLA's blocks
     run: `Model.attention_plan` states those) and the pieces an activation
-    enters its products as. A servable's `startup.attention` stamp. Where the
+    enters its products as; and `"span"`, the block mask's, where the
+    attention has one (`attention_kernel.check_span` holds it to the path's
+    tiles, here where the path is chosen). A servable's `startup.attention` stamp. Where the
     kernel would serve but its scratch and blocks at the `heads`' shapes are
     past the VMEM a kernel has (`attention_kernel.fits`: a shape that does
     not fit is refused by the chip at warm-up, not by the compiler), XLA's blocks
     serve, and the stamp says `"why": "vmem"`."""
-    if not kernel_serves(queries):
-        return {"kernel": "xla", "block": 0, "pieces": count}
-    from ..ops.attention_kernel import fits, tile
+    choice = {"kernel": "xla", "block": 0, "pieces": count}
+    if kernel_serves(queries):
+        from ..ops.attention_kernel import fits, tile
 
-    if heads is not None and not fits(keys, window, *heads, count):
-        return {"kernel": "xla", "block": 0, "pieces": count, "why": "vmem"}
-    return {"kernel": "pallas", "block": tile(keys, window), "pieces": count}
+        if heads is not None and not fits(keys, window, *heads, count):
+            choice["why"] = "vmem"
+        else:
+            choice.update(kernel="pallas", block=tile(keys, window))
+    if span is not None:
+        from ..ops.attention_kernel import check_span
+
+        check_span(queries, keys, span, choice["block"] or ATTN_BLOCK, window)
+        choice["span"] = span
+    return choice
 
 
-def takes_kernel(queries: int, keys: int, window: int | None, count: int, heads: Heads | None = None) -> bool:
+def takes_kernel(queries: int, keys: int, window: int | None, count: int, heads: Heads | None = None,
+                 span: int | None = None) -> bool:
     """Whether `attention` serves this one (attention_choice has the rule),
     noted for the served entry being traced."""
-    choice = attention_choice(queries, keys, window, count, heads)
+    choice = attention_choice(queries, keys, window, count, heads, span)
     served = served_entry()
     if served is not None and choice not in served.notes:
         served.notes.append(choice)
@@ -336,12 +363,13 @@ def takes_kernel(queries: int, keys: int, window: int | None, count: int, heads:
 
 
 def attention(qs, ks, v: jax.Array, window: int | None, cd, count: int, scale: float,
-              sink: jax.Array | None = None):
+              sink: jax.Array | None = None, span: int | None = None):
     """Causal attention of the queries at the LAST positions of the keys'
     range as ONE Pallas kernel (ops/attention_kernel.py) that keeps the score
     tile in VMEM: for the callers `takes_kernel` said yes to. The score is
     the sum over the parts of `q k'`, times `scale`; position t sees
-    `t - window + 1 .. t` (all up to t without a window).
+    `t - window + 1 .. t` (all up to t without a window; with `span`, all up
+    to the end of t's block of `span` positions).
 
     qs    a tuple of `[n, Lq, H, d_p]` float32, a part each
     ks    a tuple of `[n, Lk, H_p, d_p]`: query head h reads head `h // (H / H_p)`
@@ -358,19 +386,21 @@ def attention(qs, ks, v: jax.Array, window: int | None, cd, count: int, scale: f
     heads_first = functools.partial(jnp.transpose, axes=(0, 2, 1, 3))
     out = kernel(
         tuple(map(heads_first, qs)), tuple(map(heads_first, ks)), heads_first(v),
-        scale=float(scale), window=window, cd=jnp.dtype(cd), count=count, interpret=served_entry().interpret, sink=sink)
+        scale=float(scale), window=window, cd=jnp.dtype(cd), count=count, interpret=served_entry().interpret, sink=sink,
+        span=span)
     if sink is None:
         return heads_first(out)
     return heads_first(out[0]), jnp.transpose(out[1][..., 0], (0, 2, 1))
 
 
 def blocked_pairs(queries: int, keys: int, window: int | None = None, count: int = OPERAND_PIECES,
-                  heads: Heads | None = None) -> tuple[int, int]:
+                  heads: Heads | None = None, span: int | None = None) -> tuple[int, int]:
     """((query, key) pairs the tiles of `blocked_attention` compute over a row,
     those its masks keep) for the last `queries` positions of `keys`: the
     kernel's tiles where it serves (`attention_choice`, at the `heads`' shapes
-    and `count` pieces where given)."""
-    if attention_choice(queries, keys, window, count, heads)["kernel"] == "pallas":
+    and `count` pieces where given). A `span` moves no tile and keeps
+    `ahead_pairs` more pairs."""
+    if attention_choice(queries, keys, window, count, heads, span)["kernel"] == "pallas":
         from ..ops.attention_kernel import tile_pairs
 
         computed = tile_pairs(queries, keys, window)
@@ -379,15 +409,27 @@ def blocked_pairs(queries: int, keys: int, window: int | None = None, count: int
             (stop - start) * (last - first) for start, stop, first, last in query_blocks(queries, keys, window))
     offset = keys - queries
     seen = sum(min(offset + t + 1, window or keys) for t in range(queries))
-    return computed, seen
+    return computed, seen + ahead_pairs(queries, keys, span)
+
+
+def ahead_pairs(queries: int, keys: int, span: int | None) -> int:
+    """The (query, key) pairs with the key AFTER the query that the mask keeps
+    over a row, for the last `queries` positions of `keys`: what a block mask
+    of `span` positions sees beyond the causal reach (`span - 1 - t % span` a
+    query: `keys (span - 1) / 2` at all positions of a row of whole spans, 0
+    for a lone last query) and 0 under every causal mask (`span` None)."""
+    return 0 if span is None else sum(span - 1 - t % span for t in range(keys - queries, keys))
 
 
 def blocked_attention(q: jax.Array, k: jax.Array, v: jax.Array, window: int | None, cd,
-                      count: int = OPERAND_PIECES, sink: jax.Array | None = None):
+                      count: int = OPERAND_PIECES, sink: jax.Array | None = None, span: int | None = None):
     """Causal attention of the queries at the LAST `q.shape[1]` positions of
     the keys' range in blocks of ATTN_BLOCK queries, each against the keys its
     causal reach (and its window's, where one is given) holds: a layer at
-    all positions, any layer at the last position alone. `q [n, Lq, G, J, d]`
+    all positions, any layer at the last position alone. With `span`, a query
+    sees up to the end of its block of `span` positions (`causal_softmax` has
+    the three mask parameters), in the same blocks and tiles
+    (`attention_kernel.check_span`). `q [n, Lq, G, J, d]`
     (J query heads a key-value head), `k [n, Lk, G, d]`, `v [n, Lk, G, d_v]`;
     returns `[n, Lq, G, J, d_v]` float32. Activations enter as `count` pieces.
     With `sink [G * J]` (a logit a query head beside its keys':
@@ -395,16 +437,16 @@ def blocked_attention(q: jax.Array, k: jax.Array, v: jax.Array, window: int | No
     softmax, `[n, Lq, G, J]`."""
     queries, keys, out, shares = q.shape[1], k.shape[1], [], []
     n, _, groups, per_group, head = q.shape
-    if takes_kernel(queries, keys, window, count, Heads((head,), v.shape[-1], per_group, cd)):
+    if takes_kernel(queries, keys, window, count, Heads((head,), v.shape[-1], per_group, cd), span):
         flat = q.reshape(n, queries, groups * per_group, head)
-        o = attention((flat,), (k,), v, window, cd, count, head ** -0.5, sink)
+        o = attention((flat,), (k,), v, window, cd, count, head ** -0.5, sink, span)
         if sink is None:
             return o.reshape(q.shape[:-1] + v.shape[-1:])
         return o[0].reshape(q.shape[:-1] + v.shape[-1:]), o[1].reshape(q.shape[:-1])
     aside = None if sink is None else sink.astype(jnp.float32).reshape(groups, per_group, 1, 1)
     for start, stop, first, last in query_blocks(queries, keys, window):
         scores = product("nqgjd,nkgd->ngjqk", q[:, start:stop], k[:, first:last], cd, count) * q.shape[-1] ** -0.5
-        probs = causal_softmax(scores, keys - queries + start - first, window, aside)
+        probs = causal_softmax(scores, keys - queries + start - first, window, aside, span)
         if sink is not None:
             probs, share = probs
             shares.append(jnp.transpose(share[..., 0], (0, 3, 1, 2)))

@@ -48,6 +48,14 @@ stand side by side in the same contracted axis. Each part of the keys, and
 the values, may have fewer heads than the queries: query head h reads head
 `h // (heads / theirs)` of each.
 
+The mask is "up to my own position" (`seen`: `k_pos <= q_pos`), narrowed by a
+`window` (phi4flash's, exaone_moe's and mimo_v2's window layers), widened by a
+`sink` (below), or widened by a `span` to the END of the query's block of
+`span` positions (`sdar_moe`'s block mask, `u // span <= t // span`: the one
+mask that looks ahead). The key blocks walked are the causal mask's either
+way: where the tile, the row and the queries' first position are whole spans a
+block of queries reads nothing past its own last position (`check_span`).
+
 A softmax may hold one more term a head, a learned logit that no key carries
 (`sink [heads]`, `mimo_v2`'s window layers): it is where a row's running state
 STARTS (maximum the logit, sum 1, accumulator 0, where without one it starts
@@ -104,6 +112,27 @@ def key_blocks(start: int, block: int, keys: int, window: int | None) -> tuple[i
     last = min(-(-(start + block) // block), -(-keys // block))
     first = 0 if window is None else max(0, start - window + 1) // block
     return first, last
+
+
+def check_span(queries: int, keys: int, span: int, block: int, window: int | None = None) -> None:
+    """Refuse a block mask of `span` positions (position t sees up to the end
+    of its span) that the causal mask's tiles would cut, for the last
+    `queries` positions of `keys` in blocks of `block` queries: a block of
+    queries reads the keys up to its own last position and no further, which
+    is all such a mask keeps only where the row, the queries' first position
+    and a block are whole spans (all positions of a row; a lone last query of
+    a row of whole spans is the last of its span and sees every key), and
+    where no window narrows the reach (no family asks for both: refused
+    rather than guessed at). Raised where a path is chosen
+    (`sequence.attention_choice`, for XLA's blocks and the kernel alike), by
+    the kernel for a caller that asks it directly, and by a family at build:
+    nothing falls back to the causal mask."""
+    if (window is not None or span <= 0 or keys % span
+            or (queries > 1 and ((keys - queries) % span or block % span))):
+        raise ValueError(
+            f"a span of {span} over the last {queries} of {keys} positions in blocks of {block}, window {window}: "
+            "the row, the queries' first position and a block of queries have to be whole spans, and no window "
+            "narrows a span's reach")
 
 
 def tile_pairs(queries: int, keys: int, window: int | None = None) -> int:
@@ -249,7 +278,8 @@ def fits(keys: int, window: int | None, widths: tuple[int, ...], dv: int, shared
     return vmem_bytes(keys, window, widths, dv, shared, cd, count, compact) <= VMEM_LIMIT
 
 
-def _kernel(*refs, widths, reps, rep_v, dv, held, cd, scale, window, offset, block, keys, stacked, compact, sunk=False):
+def _kernel(*refs, widths, reps, rep_v, dv, held, cd, scale, window, offset, block, keys, stacked, compact, sunk=False,
+            span=None):
     parts = len(widths)
     tall = stacked * block
     q_refs, k_refs, v_ref = refs[:parts], refs[parts:2 * parts], refs[2 * parts]
@@ -337,6 +367,9 @@ def _kernel(*refs, widths, reps, rep_v, dv, held, cd, scale, window, offset, blo
     q_first = offset + qi * block
     # A head's block under the last one's: row r is query r % block.
     q_pos = q_first + jax.lax.broadcasted_iota(jnp.int32, (stacked, block, 1), 1).reshape(tall, 1)
+    # Under a block mask, one past the last key a query sees: the end of its
+    # span (positions are not negative, so the division is the floor).
+    q_end = None if span is None else (jax.lax.div(q_pos, jnp.int32(span)) + 1) * span
 
     def key_block(kb, carry):
         start = pl.multiple_of(kb * block, block)
@@ -349,7 +382,7 @@ def _kernel(*refs, widths, reps, rep_v, dv, held, cd, scale, window, offset, blo
             s = pairs if s is None else s + pairs
         s = s * scale
         k_pos = start + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
-        seen = k_pos <= q_pos
+        seen = k_pos <= q_pos if span is None else k_pos < q_end
         if window is not None:
             seen &= q_pos - k_pos < window
         s = jnp.where(seen, s, MASKED)
@@ -378,10 +411,10 @@ def _kernel(*refs, widths, reps, rep_v, dv, held, cd, scale, window, offset, blo
         share_ref[...] = (jnp.exp(sink_logits() - m_ref[...]) / l_ref[...]).reshape(stacked, block, 1)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "window", "cd", "count", "interpret", "compact"))
+@functools.partial(jax.jit, static_argnames=("scale", "window", "cd", "count", "interpret", "compact", "span"))
 def attention(qs, ks, v, *, scale: float, window: int | None, cd, count: int, interpret: bool = False,
-              sink: jax.Array | None = None, compact: bool | None = None):
-    """softmax(sum over the parts of `q k'` * scale | causal, window) v.
+              sink: jax.Array | None = None, compact: bool | None = None, span: int | None = None):
+    """softmax(sum over the parts of `q k'` * scale | causal, window, span) v.
 
     qs    a tuple of `[n, H, Lq, d_p]` float32, a part each: the queries stand
           at the LAST Lq positions of the keys' range
@@ -394,14 +427,20 @@ def attention(qs, ks, v, *, scale: float, window: int | None, cd, count: int, in
 
     Activations enter the products as `count` pieces of `cd`, in the pairs
     `i + j < count`; position t sees `t - window + 1 .. t` (all up to t
-    without a window). `compact` is `held_compact`'s answer at these shapes
-    unless given (the tests and the chip's readings run both forms)."""
+    without a window). With `span`, position t sees every key up to the END
+    of its block of `span` positions, `u // span <= t // span` (`sdar_moe`'s
+    block mask, the one mask that looks ahead): the tiles walked are the
+    causal mask's, which hold all of it where the tile, the row and the
+    queries' first position are whole spans (`check_span` raises otherwise).
+    `compact` is `held_compact`'s answer at these shapes unless given (the tests and the chip's readings run both forms)."""
     n, heads, queries, _ = qs[0].shape
     keys, dv = v.shape[2], v.shape[3]
     widths = tuple(q.shape[-1] for q in qs)
     held = pieces_held(cd, count)
     pairs = held * (held + 1) // 2  # (i, j), i + j < held
     block = tile(keys, window)
+    if span is not None:
+        check_span(queries, keys, span, block, window)
     shared = min(heads // x.shape[1] for x in (*ks, v))
     stacked = heads_a_step(shared, block)
     if compact is None:
@@ -436,7 +475,7 @@ def attention(qs, ks, v, *, scale: float, window: int | None, cd, count: int, in
     body = functools.partial(
         _kernel, widths=widths, reps=tuple(heads // k.shape[1] // stacked for k in ks),
         rep_v=heads // v.shape[1] // stacked, dv=dv, held=held, cd=cd, scale=scale, window=window,
-        offset=keys - queries, block=block, keys=k_len, stacked=stacked, compact=compact)
+        offset=keys - queries, block=block, keys=k_len, stacked=stacked, compact=compact, span=span)
     in_specs = [stacked_heads(d) for d in widths] + [a_head(k) for k in ks] + [a_head(v)]
     out_shape, out_specs, operands = result(dv), stacked_heads(dv), (*qs, *ks, v)
     if sink is not None:

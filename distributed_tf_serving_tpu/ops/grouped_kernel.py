@@ -32,7 +32,7 @@ copied in HBM:
   expert; the tile's expert picks the lane.
 
 H is the width of the rows the experts take and give, any whole number of
-lanes: the residual's in four families, a latent's 1,024 in nemotron_h.
+lanes: the residual's in five families, a latent's 1,024 in nemotron_h.
 A row of a `[T, H]` float32 array is one sublane of each of H / 128 tiles of
 (8, 128), and a copy may not slice a tiled axis of a wider array off its
 tiling: the tokens and the result cross the kernels' boundary as
@@ -47,7 +47,10 @@ tokens, the sorted result) or sized for the worst case the shapes allow, every
 token on `min(k, held)` held experts and a tile of padding an expert
 (`routed.layout_tiles`): `[tiles x TILE, F]` float32 between the kernels (at
 128 held of 512 and top-10 a twelfth of `[held, T, F]`), of which only the
-tiles that hold a token are written or read, and a tile table as long. No token is
+tiles that hold a token are written or read, and a tile table as long (with
+ALL of 128 experts held at top-8, sdar_moe's, the bound is `T x 8` rows and a
+tile an expert, 147,456 rows of 768 at 16,384 tokens, and the routing fills
+nearly all of it). No token is
 dropped whatever the routing and nothing is approximated: `count` pieces of
 every activation, the weights rounded once, float32 accumulation and gating:
 `routed.gated_mlp`'s arithmetic to float32 rounding in another order of
@@ -70,8 +73,14 @@ from .attention_kernel import LANES, _pieces, _round_up, pieces_held
 
 # Rows a tile. An expert's last tile is padded to it, so the padding of a
 # layer is half a tile an expert on average: 128 rows keep it near a tenth of
-# the rows at the cells' 250-460 tokens an expert where 256 made it a fifth to
-# a third. With `count` pieces one under the other a weight block still meets
+# the rows at five cells' 250-704 tokens an expert where 256 made it a fifth to
+# a third. (Since PR 64 one cell, sdar_30b_a3b_rerank-bulk, holds a layer whole
+# at a MEAN of 1,024 tokens an expert, a deployment's mean; under its seeded
+# router the loads are skewed, the busiest expert at five times the mean, as
+# no trained router's are. Eight or nine tiles an expert on average, so an
+# expert's weights cross into VMEM that many times a layer and the padding is
+# a sixteenth; whether a larger tile pays there is that cell's open question,
+# to be read a load bucket at a time: PERF.md section 7.) With `count` pieces one under the other a weight block still meets
 # 384 rows, above the 240 operations a byte at which a v5e's MXU waits for
 # HBM, and a tile's gathered rows (`[TILE, H]` float32, 3.75 MiB at H 7680)
 # fit beside the weights' blocks in the 16 MiB a kernel has by default.

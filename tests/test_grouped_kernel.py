@@ -26,7 +26,8 @@ TILE = grouped_kernel.TILE
 interpreted = functools.partial(sequence.serving_attention, interpret=True)
 
 # name -> (tokens, held, first, k, the experts routed over, routing, live rows left out, compute dtype, pieces
-#          [, the experts' form where not the gated one [, the width of a row where not 256]])
+#          [, the experts' form where not the gated one [, the width of a row where not 256
+#          [, the width of an expert where not 128]]])
 ROUTINGS = {
     "uniform": (300, 3, 0, 2, 12, "uniform", False, jnp.bfloat16, 3),
     "an expert no token chose": (300, 3, 0, 2, 12, "one_empty", False, jnp.bfloat16, 3),
@@ -49,12 +50,14 @@ ROUTINGS = {
     "ungated, float32 compute dtype": (200, 3, 5, 2, 12, "uniform", True, jnp.float32, 3, "relu2"),
     "ungated, 1,024-wide rows, 16 held at top-22 of 128": (90, 16, 0, 22, 128, "uniform", False, jnp.bfloat16, 3, "relu2", 1024),
     "gated, 1,024-wide rows": (90, 4, 4, 3, 16, "uniform", False, jnp.bfloat16, 3, "gated_silu", 1024),
+    # PR 64 (sdar_moe): the layer held WHOLE (held == experts: every one of a token's 8 choices is here) and experts
+    # three lane tiles wide: `_block(384, N_BLOCK)` is 384, a block that is no power of two (the cell's 768 is six)
+    "held == experts at top-8, experts 384 wide": (150, 16, 0, 8, 16, "all_held", False, jnp.bfloat16, 3, "gated_silu", 256, 384),
 }
 
 
-def _operands(tokens, held, first, k, experts, routing, dead, cd, form="gated_silu", hidden=256, seed=0):
+def _operands(tokens, held, first, k, experts, routing, dead, cd, form="gated_silu", hidden=256, width=128, seed=0):
     rng = np.random.default_rng(seed)
-    width = 128
     p = {"gate": rng.standard_normal((held, hidden, width)) * 0.1, "up": rng.standard_normal((held, hidden, width)) * 0.1,
          "down": rng.standard_normal((held, width, hidden)) * 0.1}
     p = {name: jnp.asarray(w, cd) for name, w in p.items() if form == "gated_silu" or name != "gate"}
@@ -98,6 +101,8 @@ def test_the_pass_is_the_loops_on_the_same_operands(case):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-6, atol=2e-6 * float(jnp.max(jnp.abs(want)) + 1))
     if routing == "all_held":  # the worst case the buffers are sized for: nothing is dropped
         assert int(took.sum()) == tokens * min(k, held)
+    if len(form) == 3:
+        assert grouped_kernel._block(form[2], grouped_kernel.N_BLOCK) == form[2] == 3 * grouped_kernel.LANES
     if live is not None:  # a row left out is zero, to the bit
         assert not np.asarray(got)[~np.asarray(live)].any()
 

@@ -573,6 +573,28 @@ def test_nemotron_hs_eight_row_step_compiles_at_the_published_cut(one_chip, no_c
     assert accessed < 225e9  # 213.5 GB; 229 before PR 63
 
 
+def test_sdar_moes_eight_row_step_compiles_at_the_published_cut(one_chip, no_compile_cache, served_on_a_tpu):
+    """SDAR-30B-A3B's first pipeline stage as `sdar_30b_a3b_rerank-bulk` serves
+    it (3.427 B parameters, ALL 128 experts of every layer, 8 rows of 2,048
+    tokens), the top bucket's step with its eight counters: the four layers at
+    all positions ONE attention kernel each under the block mask (the same
+    kernel and tiles as a causal layer's: the mask is two lines of it), the
+    five routed layers the two grouped kernels each over a layout of 147,456
+    rows of 768, and no loop of XLA's. Necessary, not sufficient: the chip
+    decides (PERF.md section 6, PR 64)."""
+    compiled, _ = sequence_cells_step("sdar_30b_a3b_rerank", "sdar_moe", one_chip)
+    memory, text = compiled.memory_analysis(), compiled.as_text()
+    assert 6.8e9 < memory.argument_size_in_bytes < 6.9e9  # 3,426.8 M parameters in bfloat16
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14 * GIB
+    assert memory.generated_code_size_in_bytes < 64 << 20
+    assert not re.findall(r"\) while\(", text)
+    assert text.count('custom_call_target="tpu_custom_call"') == 4 + 2 * 5 and "vmem_limit" not in text
+    assert len(kernels_vmem(text, "attention")) == 4
+    assert len(kernels_vmem(text, "grouped_gate_up")) == 2 * len(kernels_vmem(text, "grouped_down")) == 10
+    assert all(0 < size < DEFAULT_VMEM for name in ("attention", "grouped_gate_up", "grouped_down")
+               for size in kernels_vmem(text, name))
+
+
 # ------------------------------------------- the Pallas attention (PR 48)
 #
 # What interpret mode cannot see: Mosaic's verdict on the kernel's slices,
@@ -605,7 +627,12 @@ ATTENTION_SHAPES = {
     # window's mask AND a sink, and the same at four pieces; the chip's verdicts are in PERF.md section 6, PR 61
     "mimo_v2_edge_window_sink": (((4, 64, 2048, 192),), ((4, 4, 2048, 192),), (4, 4, 2048, 128), 128, 3, True),
     "mimo_v2_edge_four_pieces": (((4, 64, 2048, 192),), ((4, 4, 2048, 192),), (4, 4, 2048, 128), 128, 4, True),
+    # sdar_30b_a3b_rerank (PR 64): 32 query heads over 4 key-value heads of 128, 8 rows, three pieces, under the BLOCK
+    # mask (ATTENTION_SPANS: a query sees to the end of its block of 4; the key blocks walked are the causal mask's)
+    "sdar_moe": (((8, 32, 2048, 128),), ((8, 4, 2048, 128),), (8, 4, 2048, 128), None, 3),
 }
+# The block mask's span, for the forms that have one (`attention(span=)`); every other form compiles with None.
+ATTENTION_SPANS = {"sdar_moe": 4}
 
 
 @pytest.mark.parametrize("form", sorted(ATTENTION_SHAPES))
@@ -615,7 +642,8 @@ def test_attention_kernel_compiles_at_the_cells_top_rungs(one_chip, no_compile_c
     qs, ks, v, window, count, *sunk = ATTENTION_SHAPES[form]
     shaped = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)  # noqa: E731
     run = functools.partial(
-        attention, scale=sum(q[-1] for q in qs) ** -0.5, window=window, cd=jnp.dtype(jnp.bfloat16), count=count)
+        attention, scale=sum(q[-1] for q in qs) ** -0.5, window=window, cd=jnp.dtype(jnp.bfloat16), count=count,
+        span=ATTENTION_SPANS.get(form))
     sink = {"sink": shaped((qs[0][1],))} if sunk else {}  # within the default VMEM: the kernel asks for no more
     compiled = jax.jit(run).lower(tuple(map(shaped, qs)), tuple(map(shaped, ks)), shaped(v), **sink).compile()
     assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
@@ -748,6 +776,10 @@ GROUPED_SHAPES = {
     # the LATENT's 1,024 wide and not the residual's 4,096, 2,688 wide inside (21 lane tiles: blocks of 896), 64 of 512
     # held at top-22: a layout of 2,880 tiles (3.96 GB between the kernels) of which the even share fills an eighth
     "nemotron_h": (1024, 2688, 16384, jnp.bfloat16, 64, 22, "relu2"), "nemotron_h_bottom_rung": (1024, 2688, 4096, jnp.bfloat16, 64, 22, "relu2"),
+    # sdar_30b_a3b_rerank (PR 64): the first layer held WHOLE, 128 of 128 experts at top-8 (every one of a token's choices
+    # is here: a layout of 131,072 + 128 x 128 = 147,456 rows, 453 MB between the kernels), and the narrowest experts
+    # yet, 768 wide: six lane tiles, `_block(768, N_BLOCK)` is 768, the first block that is no power of two
+    "sdar_moe": (2048, 768, 16384, jnp.bfloat16, 128, 8), "sdar_moe_last_layer": (2048, 768, 8, jnp.bfloat16, 128, 8),
 }
 
 
@@ -839,6 +871,11 @@ LOWERED_TEXT = {
     # nine other digests, `phi4flash`, `olmo_hybrid` and `qwen3_next` (`sequence.causal_conv`'s other callers) among
     # them, passed that change untouched
     "nemotron3_super_120b_rerank/nemotron_h/served": "b7516d9acbaf0e05",
+    # PR 64: the ninth family's served step (the attention kernel under the block mask, `span` 4; the grouped kernels over
+    # a layer held whole); the eleven digests above passed PR 64's `span` through `sequence.causal_softmax`,
+    # `query_blocks`, `attention_choice`, `blocked_attention` and `ops/attention_kernel.py` untouched: with `span=None`
+    # every other family lowers to the text it had. PR 64's own, held here for the next change to be seen against
+    "sdar_30b_a3b_rerank/sdar_moe/served": "7e268c6c2d5f5e92",
 }
 
 
